@@ -1,0 +1,541 @@
+#!/usr/bin/env python3
+"""Chip smoke: the quickest proof that the system still starts on a TPU.
+
+Drives the main path once through the entry points a user calls, at the
+full widths of ``SMOLLM3_3B_L8`` (hidden 2048, 16/4 GQA heads x 128, FFN
+11008, vocab 128,256; 8 of 36 layers), random weights from a seed, on
+every chip ``jax.devices()`` reports:
+
+  train    ``scripts/train_fsdp.py`` ``main([...])``: 8 explicit-FSDP steps
+           at seq 8192, one sequence per device, under the supervisor,
+           planner pre-flight, contract verdict, prefetcher + pump and the
+           telemetry run.  Losses finite and falling, the manifest names
+           the device, the compiled step holds the splash kernel, nothing
+           compiles after the second step.
+  layout   (several chips) every FSDP parameter and optimizer leaf holds
+           1/n on each device; ``busbench``'s five collectives return the
+           right values over the interconnect.
+  serve    ``scripts/serve_bench.py`` ``main([...])``: 8 requests through
+           the paged engine on the same widths; all complete, no retrace
+           after warm-up, tokens checked against one-shot ``generate``.
+  kernels  every ``pl.pallas_call`` site compiled at those widths and
+           compared with its own XLA reference, one line each.
+
+One process, because a chip belongs to one process.  Exit code 0 and a
+last stdout line ``{"ok": true, "device": {...}}`` only if every phase
+passed; no accelerator means exit 2 and no result line.  ``--rehearse-cpu``
+runs the same phases on the 8-device CPU simulator at ``TINY_LM`` widths
+(kernels interpreted) to debug the script itself; nothing but that flag
+selects it, and every line it prints says ``cpu``.
+
+    python chip_smoke.py                  # on a machine with a TPU
+    python chip_smoke.py --rehearse-cpu   # anywhere
+"""
+
+from __future__ import annotations
+
+import argparse
+import gc
+import json
+import math
+import os
+import sys
+import tempfile
+import time
+from pathlib import Path
+
+REPO = Path(__file__).resolve().parent
+OUT = REPO / "chiprun_out" / "smoke"
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+TRAIN_STEPS = 8
+SERVE_REQUESTS = 8
+SERVE_MAX_SEQ = 512
+SERVE_PAGE = 16
+SERVE_BATCH = 4
+
+
+class SmokeFailure(Exception):
+    """A phase ran to its end and what came out is wrong."""
+
+
+def check(cond, msg: str) -> None:
+    if not cond:
+        raise SmokeFailure(msg)
+
+
+def say(phase: str, msg: str) -> None:
+    print(f"[smoke:{phase}] {msg}", flush=True)
+
+
+def read_json(path):
+    with open(path) as f:
+        return json.load(f)
+
+
+def new_run_dir(runs: Path, before: set) -> Path:
+    """The one telemetry run directory a driver call added."""
+    new = sorted(set(runs.iterdir()) - before)
+    check(len(new) == 1, f"expected one new run dir under {runs}, "
+                         f"found {[p.name for p in new]}")
+    return new[0]
+
+
+# ------------------------------------------------------------------ train
+
+def train_phase(model: str, cfg, runs: Path, compiles: list) -> None:
+    import jax
+    import train_fsdp
+    from distributed_training_sandbox_tpu.ops.hlo import (
+        collective_instances)
+
+    dev = jax.devices()[0]
+    ndev = len(jax.devices())
+    before = set(runs.iterdir())
+    t0 = time.time()
+    with tempfile.TemporaryDirectory(prefix="smoke-traces-") as traces:
+        # the driver's default profiler window (steps 5..) lands in a
+        # throwaway dir: its reductions are read from the run dir, and the
+        # raw trace is too big for what the chip tool carries back
+        metrics = train_fsdp.main([
+            "--model", model, "--num-steps", str(TRAIN_STEPS),
+            "--results-dir", str(runs), "--trace-dir", traces,
+            "--run-name", "smoke"])
+    t1 = time.time()
+    losses = [float(x) for x in metrics["losses"]]
+    say("train", f"{dev.platform} losses " +
+        " ".join(f"{x:.4f}" for x in losses))
+    check(len(losses) == TRAIN_STEPS,
+          f"{len(losses)} losses for {TRAIN_STEPS} steps")
+    check(all(math.isfinite(x) for x in losses), f"non-finite loss: {losses}")
+    check(losses[-1] < losses[0],
+          f"loss did not fall: first {losses[0]} last {losses[-1]}")
+
+    run = new_run_dir(runs, before)
+    man = read_json(run / "manifest.json")
+    say("train", f"manifest platform={man['platform']} "
+                 f"device_kind={man['device_kind']!r} "
+                 f"device_count={man['device_count']} "
+                 f"mesh={man['mesh_shape']}")
+    check((man["platform"], man["device_kind"], man["device_count"])
+          == (dev.platform, dev.device_kind, ndev),
+          f"manifest names another device: {man['platform']} "
+          f"{man['device_kind']} x{man['device_count']}")
+    check(man["mesh_shape"] == {"dp": ndev},
+          f"mesh {man['mesh_shape']} does not span the {ndev} devices")
+    contract = man.get("contract")
+    check(contract and contract["ok"],
+          f"fsdp contract verdict missing or failed: {contract}")
+    say("train", f"contract[fsdp] ok, lowered sites {contract['observed']}")
+
+    hlo_path = run / "step.hlo.txt"
+    check(hlo_path.is_file(), "the run filed no compiled step HLO — "
+                              "attach_step_hlo failed (see its WARNING)")
+    hlo = hlo_path.read_text()
+    mosaic = hlo.count('custom_call_target="tpu_custom_call"')
+    sites = collective_instances(hlo)
+    moved = sum(i.bytes for i in sites
+                if i.replica_groups and len(i.replica_groups[0]) > 1)
+    say("train", f"compiled step: {mosaic} Mosaic custom calls, "
+                 f"{len(sites)} collective sites, {moved} payload bytes "
+                 f"over groups of more than one device")
+    if cfg.attention_impl == "flash":
+        check(mosaic > 0, "attention_impl='flash' but the compiled step "
+                          "holds no Mosaic custom call: the splash kernel "
+                          "gave way to another path")
+    if ndev > 1:
+        check(moved > 0, "several devices but no compiled collective "
+                         "moves a byte between them")
+    for key in ("ledger", "memory"):
+        verdict = man.get(key)
+        check(verdict is not None,
+              f"the run filed no {key} verdict — telemetry swallowed an "
+              f"error while reducing the trace")
+        say("train", f"{key} verdict: " + json.dumps(
+            {k: v for k, v in verdict.items()
+             if k not in ("violations", "residuals")}))
+
+    # iteration i asks the prefetcher for batch i first: the third
+    # prefetch/wait span is where step 2 begins
+    waits = sorted(json.loads(line)["ts_us"] / 1e6
+                   for line in (run / "spans.jsonl").read_text().splitlines()
+                   if '"prefetch/wait"' in line)
+    check(len(waits) >= TRAIN_STEPS, f"{len(waits)} prefetch/wait spans")
+    mine = [(s, e, n) for s, e, n in compiles if t0 <= s <= t1]
+    for s, e, n in mine:
+        step = sum(w <= s for w in waits) - 1
+        if e - s >= 1.0 or step >= 2:
+            say("train", f"compile {n}: {e - s:.1f}s " + (
+                "before step 0" if step < 0 else f"in step {step}"))
+    late = [n for s, e, n in mine if s >= waits[2]]
+    check(not late, f"compiled after the second step: {late}")
+    say("train", f"set-up (compile included) {waits[2] - t0:.1f}s, steps "
+                 f"2..{TRAIN_STEPS - 1} {t1 - waits[2]:.1f}s — smoke wall "
+                 f"time, not a metric")
+
+
+# ----------------------------------------------------------------- layout
+
+def layout_phase(cfg) -> None:
+    """What ``train_fsdp`` builds before its first step — init on the
+    default device, ``shard_params_fsdp``, optimizer on the shards — and
+    whether the RESULT is spread evenly."""
+    import jax
+    from distributed_training_sandbox_tpu.models import transformer as T
+    from distributed_training_sandbox_tpu.parallel import fsdp
+    from distributed_training_sandbox_tpu.utils import (
+        device_memory_stats, get_mesh)
+
+    mesh = get_mesh()
+    ndev = mesh.devices.size
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    shards = fsdp.shard_params_fsdp(params, mesh)
+    del params
+    opt = fsdp.init_fsdp_opt_state(shards)
+    leaves = [l for l in jax.tree.leaves((shards, opt)) if l.ndim]
+    for leaf in leaves:
+        sizes = {s.data.nbytes for s in leaf.addressable_shards}
+        check(len(leaf.addressable_shards) == ndev
+              and sizes == {leaf.nbytes // ndev},
+              f"leaf {leaf.shape} is not 1/{ndev} per device: {sizes}")
+    say("layout", f"{len(leaves)} param+optimizer leaves hold 1/{ndev} on "
+                  f"each of {ndev} devices")
+    jax.block_until_ready(leaves)
+    in_use = [device_memory_stats(d)["bytes_in_use"] for d in jax.devices()]
+    if any(in_use):
+        say("layout", "bytes_in_use per device " +
+            " ".join(f"{b / 2**30:.2f}G" for b in in_use))
+        check(max(in_use) <= 1.25 * min(in_use),
+              f"device memory is uneven after sharding: {in_use}")
+
+
+def busbench_phase() -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from distributed_training_sandbox_tpu.ops import busbench
+    from distributed_training_sandbox_tpu.utils import get_mesh
+
+    mesh = get_mesh()
+    n = mesh.devices.size
+    nelems = 256 * n
+    want = {
+        "all_reduce": lambda x: x.sum(0),
+        "all_gather": lambda x: x,
+        "reduce_scatter": lambda x: x.sum(0),
+        "ppermute": lambda x: np.roll(x, 1, axis=0),
+        "all_to_all": lambda x: x.reshape(n, n, -1).transpose(1, 0, 2)
+        .reshape(n, -1),
+    }
+    for name, ref in want.items():
+        fn, shape = busbench._build(name, mesh, "dp", nelems)
+        # small integers: every sum is exact in float32
+        x = np.arange(math.prod(shape), dtype=np.float32).reshape(shape) % 97
+        got = np.asarray(fn(jax.device_put(
+            jnp.asarray(x), NamedSharding(mesh, P("dp")))))
+        check(np.array_equal(got, ref(x)),
+              f"{name} over {n} devices returned wrong values")
+    say("busbench", f"five collectives correct over {n} devices")
+    for r in busbench.run_sweep(payloads=(1 << 20,), mesh=mesh):
+        say("busbench", f"{r.collective} {r.payload_bytes} B x{r.n_devices}: "
+                        f"{r.time_ms:.3f} ms, busbw {r.busbw_gbps:.2f} GB/s "
+                        f"— printed, not judged")
+
+
+# ------------------------------------------------------------------ serve
+
+def serve_phase(model_const: str, runs: Path, bitwise: bool) -> None:
+    import jax
+    import serve_bench
+
+    before = set(runs.iterdir())
+    rc = serve_bench.main([
+        "--model", model_const, "--requests", str(SERVE_REQUESTS),
+        "--max-seq-len", str(SERVE_MAX_SEQ), "--page-size", str(SERVE_PAGE),
+        "--max-batch", str(SERVE_BATCH), "--check-parity", "2"])
+    slo = read_json(new_run_dir(runs, before) / "summary.json")["serving"]
+    say("serve", f"{jax.devices()[0].platform} "
+                 f"{slo['completed']}/{slo['requests']} requests, "
+                 f"retraces after warm-up {slo['recompiles_after_warmup']}, "
+                 f"driver exit {rc}")
+    check(slo["completed"] == SERVE_REQUESTS,
+          f"only {slo['completed']}/{SERVE_REQUESTS} requests completed")
+    check(slo["recompiles_after_warmup"] == 0,
+          f"retraced after warm-up: {slo['recompiles_after_warmup']}")
+    parity = slo["parity"]
+    check(len(parity) == 2, f"parity checked {len(parity)} requests")
+    later = sum(p["tokens"] - 1 for p in parity)
+    later_eq = sum(p["tokens_equal"] - p["first_token_equal"]
+                   for p in parity)
+    say("serve", f"vs one-shot generate: first token equal in "
+                 f"{sum(p['first_token_equal'] for p in parity)}/2 requests, "
+                 f"later tokens equal {later_eq}/{later}")
+    check(all(p["first_token_equal"] for p in parity),
+          f"a request's first token differs from generate: {parity}")
+    if bitwise:
+        check(rc == 0 and later_eq == later,
+              f"tokens diverge from generate: {slo['failures']}")
+    else:
+        # the batch-4 decode program and batch-1 generate round bf16
+        # differently on the chip, and --param-scale 3 makes near-tie
+        # argmaxes common on purpose: sequences part ways mid-stream.
+        # Everything else serve_bench gates on must still hold.
+        check(all("diverge" in f for f in slo["failures"]),
+              f"serve_bench failed beyond token parity: {slo['failures']}")
+
+
+# ---------------------------------------------------------------- kernels
+
+def paged_attention_xla(qg, pk, pv, pages, apos, probs_dtype):
+    """The engine's gather-then-einsum attention core
+    (``serving.engine._paged_layer_body``), the reference both serving
+    kernels replace."""
+    import jax
+    import jax.numpy as jnp
+    B, S, nkv, rep, hd = qg.shape
+    V = pages.shape[1] * pk.shape[1]
+    vk = pk[pages].reshape(B, V, nkv, hd).transpose(0, 2, 1, 3)
+    vv = pv[pages].reshape(B, V, nkv, hd).transpose(0, 2, 1, 3)
+    scores = jnp.einsum("bsgrh,bgkh->bgrsk", qg, vk,
+                        preferred_element_type=jnp.float32) / math.sqrt(hd)
+    vis = jnp.arange(V)[None, None, :] <= apos[:, :, None]
+    scores = jnp.where(vis[:, None, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bgrsk,bgkh->bsgrh", probs.astype(probs_dtype), vv,
+                      preferred_element_type=jnp.float32)
+
+
+def kernel_cases(cfg, seq: int):
+    """name -> (kernel thunk, XLA reference thunk), at ``cfg``'s widths.
+    Each kernel goes in through the entry point the model or engine
+    calls."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from distributed_training_sandbox_tpu.models import transformer as T
+    from distributed_training_sandbox_tpu.ops import collectives as C
+    from distributed_training_sandbox_tpu.ops import quant as Q
+    from distributed_training_sandbox_tpu.ops.flash_prefill import (
+        paged_flash_prefill)
+    from distributed_training_sandbox_tpu.ops.paged_attention import (
+        paged_attention_decode)
+    from distributed_training_sandbox_tpu.utils import get_mesh
+
+    h, f = cfg.hidden_size, cfg.intermediate_size
+    nq, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                   cfg.resolved_head_dim)
+    bf = jnp.bfloat16
+    keys = iter(jax.random.split(jax.random.PRNGKey(7), 16))
+    rnd = lambda *shape: jax.random.normal(next(keys), shape, bf)
+    interpret = jax.default_backend() != "tpu"
+
+    # attention: the reference holds S x S float32 scores, hence S <= 2048
+    S = min(seq, 2048)
+    qkv = rnd(1, S, nq, hd), rnd(1, S, nkv, hd), rnd(1, S, nkv, hd)
+    attention = lambda fn: lambda: jax.jit(fn, static_argnums=3)(
+        *qkv, 1.0 / math.sqrt(hd))
+
+    # projections: the up (K = hidden) and down (K = ffn) matmuls of one
+    # sequence, both through each kernel
+    mats = ((rnd(seq, h), rnd(h, f)), (rnd(seq, f), rnd(f, h)))
+    both = lambda fn: lambda: jnp.concatenate(
+        [fn(a, w).ravel() for a, w in mats])
+    dense = lambda precision: both(jax.jit(
+        Q.resolve_quantized_dense(precision)))
+
+    def int8(matmul):
+        def run(a, w):
+            aq, a_s = Q.quantize_int8(a, axis=-1)
+            wq, w_s = Q.quantize_int8(w, axis=0)
+            return matmul(aq, a_s, wq, w_s)
+        return both(run)
+
+    mesh = get_mesh()
+    ring = lambda fn: lambda: jax.jit(C.smap(
+        lambda a, ws: fn(a, ws, "dp"), mesh, (P(), P("dp")), P()))(*mats[0])
+
+    # serving: the smoke engine's pool geometry
+    B, pages_per = SERVE_BATCH, SERVE_MAX_SEQ // SERVE_PAGE
+    pk, pv = (rnd(B * pages_per + 1, SERVE_PAGE, nkv, hd) for _ in "kv")
+    pages = jnp.arange(1, B * pages_per + 1, dtype=jnp.int32).reshape(
+        B, pages_per)
+    last = jnp.array([5, 100, 300, SERVE_MAX_SEQ - 1], jnp.int32)[:B]
+    chunk = 16
+    q_dec = rnd(B, 1, nkv, nq // nkv, hd), last[:, None]
+    q_pre = (rnd(B, chunk, nkv, nq // nkv, hd),
+             jnp.maximum(last[:, None] - chunk, 0) + jnp.arange(chunk)[None])
+    paged = lambda fn, q: lambda: jax.jit(
+        lambda qg, apos: fn(qg, pk, pv, pages, apos, probs_dtype=bf))(*q)
+
+    return {
+        "splash attention": (attention(T._attention_flash),
+                             attention(T._attention_xla)),
+        "int8 matmul": (int8(lambda *a: Q.int8_matmul_pallas(
+                            *a, interpret=interpret)),
+                        int8(jax.jit(Q.int8_matmul))),
+        "fused int8 matmul": (dense("int8_pallas"), dense("int8")),
+        "fp8 matmul": (dense("fp8_pallas"), dense("fp8")),
+        "ring chunk matmul": (ring(C.all_gather_matmul_pallas),
+                              ring(C.all_gather_matmul)),
+        "paged decode": (paged(paged_attention_decode, q_dec),
+                         paged(paged_attention_xla, q_dec)),
+        "flash prefill": (paged(paged_flash_prefill, q_pre),
+                          paged(paged_attention_xla, q_pre)),
+    }
+
+
+def kernels_phase(cfg, seq: int) -> None:
+    """One line per Pallas kernel.  A kernel off the default path may be
+    refused by the compiler — that is printed, not failed; one that
+    compiles and disagrees with its reference, or a refusal on the path
+    ``cfg`` takes by default, fails the smoke."""
+    import jax
+    import jax.numpy as jnp
+
+    on_tpu = jax.default_backend() == "tpu"
+    how = "compiled" if on_tpu else "interpreted on cpu"
+    default_path = {"splash attention"} if cfg.attention_impl == "flash" \
+        else set()
+    # every output is bf16 (or f32 from bf16 probabilities): agreement to
+    # 2^-6 of the reference's largest entry is two bf16 ulps there, and a
+    # wrong mask, scale or page lands far outside it
+    tol = 2.0 ** -6
+    bad = []
+    for name, (kernel, reference) in kernel_cases(cfg, seq).items():
+        # float32 matmuls are lower precision on a TPU unless asked
+        with jax.default_matmul_precision("highest"):
+            ref = jax.block_until_ready(reference()).astype(jnp.float32)
+        try:
+            out = jax.block_until_ready(kernel()).astype(jnp.float32)
+        except Exception as e:  # noqa: BLE001 - the refusal IS the finding
+            first = (str(e).strip().splitlines() or [""])[0]
+            say("kernel", f"{name}: refused: {type(e).__name__}: "
+                          f"{first[:400]}")
+            if name in default_path:
+                bad.append(f"{name} is on the default path and was refused")
+            continue
+        delta = float(jnp.max(jnp.abs(out - ref)))
+        scale = float(jnp.max(jnp.abs(ref)))
+        say("kernel", f"{name}: {how}, max|d| vs reference = {delta:.3e} "
+                      f"(reference max {scale:.3e})")
+        if not delta <= tol * scale:
+            bad.append(f"{name} disagrees with its reference: {delta:.3e} "
+                       f"> {tol * scale:.3e}")
+    check(not bad, "; ".join(bad))
+
+
+# ------------------------------------------------------------------- main
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rehearse-cpu", action="store_true",
+                    help="run the phases on 8 simulated CPU devices at "
+                         "TINY_LM widths (debugs this script; proves "
+                         "nothing about a chip)")
+    args = ap.parse_args(argv)
+    t_start = time.time()
+    # the synthetic token stream, deterministically: no DNS probe
+    os.environ["HF_HUB_OFFLINE"] = "1"
+    OUT.mkdir(parents=True, exist_ok=True)
+    runs = OUT / "runs"
+    runs.mkdir(exist_ok=True)
+    os.environ["RESULTS_DIR"] = str(runs)    # serve_bench reads it
+    sys.path[:0] = [str(REPO), str(REPO / "scripts")]
+
+    from distributed_training_sandbox_tpu.utils import use_cpu_devices
+    if args.rehearse_cpu:
+        use_cpu_devices(8)
+    import jax
+    import jaxlib
+    from importlib import metadata
+    from distributed_training_sandbox_tpu.models import (
+        MODEL_REGISTRY, transformer as T)
+
+    want = "cpu" if args.rehearse_cpu else "tpu"
+    model = "tiny" if args.rehearse_cpu else "smollm3-3b-l8"
+    model_const = MODEL_REGISTRY[model]
+    cfg = getattr(T, model_const)
+    seq = 256 if args.rehearse_cpu else 8192     # train_fsdp's defaults
+
+    backend = jax.default_backend()
+    dev = jax.devices()[0]
+    ndev = len(jax.devices())
+    try:
+        libtpu = metadata.version("libtpu")
+    except metadata.PackageNotFoundError:
+        libtpu = "not installed"
+    cache = jax.config.jax_compilation_cache_dir
+    cache_files = lambda: sum(1 for p in Path(cache).rglob("*")
+                              if p.is_file()) if cache else 0
+    say("device", f"python {sys.version.split()[0]} jax {jax.__version__} "
+                  f"jaxlib {jaxlib.__version__} libtpu {libtpu}")
+    say("device", f"backend={backend} device_kind={dev.device_kind!r} "
+                  f"count={ndev} order={[d.id for d in jax.devices()]} "
+                  f"coords={[getattr(d, 'coords', None) for d in jax.devices()]}")
+    say("device", f"compile cache: {cache} "
+                  f"({'JAX_COMPILATION_CACHE_DIR' if os.environ.get('JAX_COMPILATION_CACHE_DIR') else 'in-code default'}), "
+                  f"{cache_files()} files at start")
+    if backend != want:
+        print(f"[smoke] FAIL: jax.default_backend() is {backend!r}, this "
+              f"run needs {want!r} — no accelerator was found (or "
+              f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r} hides "
+              f"it); --rehearse-cpu is the only CPU mode", file=sys.stderr)
+        return 2
+    stats = dev.memory_stats()
+    say("device", "allocator stats: " + (
+        f"bytes_limit={stats['bytes_limit']} "
+        f"({stats['bytes_limit'] / 2**30:.2f} GiB)" if stats
+        else "none (memory_stats() is None)"))
+
+    compiles: list = []       # (start, end, jitted function) per XLA compile
+    cache_events = {"cache_hits": 0, "cache_misses": 0}
+
+    def on_span(event, start, end, **kw):
+        if event == BACKEND_COMPILE_EVENT:
+            compiles.append((start, end, kw.get("fun_name", "?")))
+
+    def on_event(event, **kw):
+        name = event.rsplit("/", 1)[-1]
+        if event.startswith("/jax/compilation_cache/") \
+                and name in cache_events:
+            cache_events[name] += 1
+
+    jax.monitoring.register_event_time_span_listener(on_span)
+    jax.monitoring.register_event_listener(on_event)
+
+    def memory_line(after: str) -> None:
+        # a driver's state dies with reference cycles still on it
+        gc.collect()
+        for d in jax.devices() if stats else ():
+            s = d.memory_stats()
+            say("device", f"after {after}, device {d.id}: " + " ".join(
+                f"{k}={s[k] / 2**30:.2f}G" for k in (
+                    "bytes_in_use", "peak_bytes_in_use",
+                    "peak_bytes_reserved", "bytes_limit")))
+
+    train_phase(model, cfg, runs, compiles)
+    memory_line("train")
+    if ndev > 1:
+        layout_phase(cfg)
+        busbench_phase()
+    # in float32 on the CPU tier the engine's tokens equal generate's
+    # bitwise; in bf16 on the chip they do not (PERF.md, chip bring-up)
+    serve_phase(model_const, runs, bitwise=args.rehearse_cpu)
+    memory_line("serve")
+    kernels_phase(cfg, seq)
+    say("device", f"compile cache: {cache_files()} files at end, "
+                  f"{cache_events['cache_hits']} hits "
+                  f"{cache_events['cache_misses']} misses, "
+                  f"{len(compiles)} backend compiles "
+                  f"({sum(e - s for s, e, _ in compiles):.1f}s)")
+    say("done", f"every phase passed on {backend} in "
+                f"{time.time() - t_start:.0f}s — smoke wall time, not a "
+                f"metric")
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind, "count": ndev}}),
+        flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -21,3 +21,6 @@ from . import utils, ops  # noqa: F401
 # `launch` is importable as a subpackage (`from distributed_training_sandbox_tpu
 # import launch`) but not imported eagerly: it is pure stdlib and must stay
 # importable before jax backend initialization.
+
+# every entry point imports this package before it compiles anything
+utils.configure_compile_cache()

@@ -119,7 +119,11 @@ def _fsdp_counts(rs: RuleSet) -> Callable[[ContractContext], dict]:
             hops = (unfused + 2 * N_PROJ_LEAVES) * (ws - 1)
             return {"all_reduce": 1, "reduce_scatter": unfused,
                     "collective_permute": (hops, 2 * hops)}
-        return {"all_reduce": 1, "all_gather": n, "reduce_scatter": n}
+        # a remat'd, resharding layer scan gathers its leaves again in
+        # the backward (contracts._fsdp_counts)
+        regather = int(c.extra.get("regather_leaves", 0))
+        return {"all_reduce": 1, "all_gather": n + regather,
+                "reduce_scatter": n}
 
     return counts
 

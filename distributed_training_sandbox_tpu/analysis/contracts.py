@@ -137,6 +137,19 @@ def _ddp_q8_counts(c: ContractContext) -> dict:
     return {"all_reduce": 2, "all_gather": 2 * n}
 
 
+def _fsdp_counts(c: ContractContext) -> dict:
+    """One gather + one reduce-scatter site per param leaf (the scan
+    collapses depth), one loss pmean.  A rematerialized layer scan that
+    reshards after forward runs the per-layer gathers a second time in
+    the backward — ``extra["regather_leaves"]``, the number of stacked
+    layer leaves then, 0 when remat is off or the gathers are hoisted
+    out of the scan."""
+    return {"all_reduce": 1,
+            "all_gather": c.n_leaves + int(c.extra.get("regather_leaves",
+                                                       0)),
+            "reduce_scatter": c.n_leaves}
+
+
 def _fsdp_ring_counts(c: ContractContext) -> dict:
     """fsdp with the gathers ring-decomposed: every all_gather site
     becomes ws-1 collective_permute hops (rank-order chunk placement);
@@ -281,14 +294,12 @@ CONTRACTS: dict[str, CollectiveContract] = {
     # per-leaf gather around compute (scan body: one site per stacked
     # leaf), reduce-scatter transposes, one loss mean (no barrier)
     "fsdp": CollectiveContract(
-        "fsdp", ("dp",),
-        lambda c: {"all_reduce": 1,
-                   "all_gather": c.n_leaves,
-                   "reduce_scatter": c.n_leaves},
+        "fsdp", ("dp",), _fsdp_counts,
         allows_full_param_gather=True,
         payload_bytes=lambda c: 3 * c.param_bytes,
         description="one gather + one reduce-scatter site per param leaf "
-                    "(scan collapses depth), one loss pmean"),
+                    "(scan collapses depth; remat re-gathers the layer "
+                    "leaves in the backward), one loss pmean"),
     # fsdp with --offload opt: identical collective choreography to fsdp
     # (the transfers are custom calls, not collectives) PLUS a declared
     # host-offload transfer budget — MoveToDevice streams the Adam
@@ -296,10 +307,7 @@ CONTRACTS: dict[str, CollectiveContract] = {
     # come from the build's OffloadPlan (zero on backends without a
     # pinned_host space: the fallback step must stay transfer-free).
     "fsdp_offload": CollectiveContract(
-        "fsdp_offload", ("dp",),
-        lambda c: {"all_reduce": 1,
-                   "all_gather": c.n_leaves,
-                   "reduce_scatter": c.n_leaves},
+        "fsdp_offload", ("dp",), _fsdp_counts,
         allows_full_param_gather=True,
         payload_bytes=lambda c: 3 * c.param_bytes,
         host_transfers=_offload_host_transfers,
@@ -310,10 +318,7 @@ CONTRACTS: dict[str, CollectiveContract] = {
     # fsdp's (the precision leg changes flops and working set, not
     # collectives), which is precisely what this contract pins down
     "fsdp_fp8": CollectiveContract(
-        "fsdp_fp8", ("dp",),
-        lambda c: {"all_reduce": 1,
-                   "all_gather": c.n_leaves,
-                   "reduce_scatter": c.n_leaves},
+        "fsdp_fp8", ("dp",), _fsdp_counts,
         allows_full_param_gather=True,
         payload_bytes=lambda c: 3 * c.param_bytes,
         description="fsdp choreography unchanged: fp8 scaling is local "

@@ -8,9 +8,8 @@ reference course never guards this; here it is a checkable property:
 run a few steps and assert the jit cache stopped growing after the
 first executed call.
 
-Uses the jitted callable's ``_cache_size()`` (present on jax's
-``PjitFunction`` since well before the pinned 0.4.x; absent attributes
-degrade to ``supported=False`` rather than failing the caller).
+Uses the jitted callable's ``_cache_size()``; a handle without one (not
+a jit wrapper) reports ``supported=False`` rather than failing the caller.
 """
 
 from __future__ import annotations

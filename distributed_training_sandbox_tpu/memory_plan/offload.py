@@ -10,8 +10,8 @@ used to classify every such custom call as a hot-path violation; with the
 :class:`OffloadPlan` below the transfers become *declared* — the lint
 count-checks them instead (see ``hlo_lint.check_host_transfers``).
 
-Backends without a ``pinned_host`` memory space (the 8-way CPU CI mesh:
-its only space IS host memory) degrade to an identity placement — the
+Backends whose only memory IS host memory (the 8-way CPU CI mesh)
+degrade to an identity placement — the
 step is bitwise-identical to no-offload, the plan records
 ``supported=False`` and declares zero transfers, and the contract lint
 then *forbids* transfer custom calls, so the fallback is still checked.
@@ -35,15 +35,15 @@ OFFLOADABLE_REMAT_NAMES = {
 
 
 def supports_host_offload(device=None) -> bool:
-    """True when the backend exposes a ``pinned_host`` memory space next
-    to device HBM (TPU; not the CPU sim, whose only space is host)."""
+    """True when the backend has a ``pinned_host`` memory space DISTINCT
+    from device memory (TPU).  The CPU backend lists ``pinned_host`` too,
+    but every kind it lists is the same host DRAM and XLA compiles the
+    transfers away — an offload plan there declares none."""
     import jax
     device = device or jax.devices()[0]
-    try:
-        kinds = {m.kind for m in device.addressable_memories()}
-    except Exception:
+    if device.platform == "cpu":
         return False
-    return HOST_KIND in kinds
+    return HOST_KIND in {m.kind for m in device.addressable_memories()}
 
 
 @dataclass(frozen=True)
@@ -134,11 +134,12 @@ def stream_tree(tree, kind: str):
     scheduler can hide.  Identity on scalars (the Adam step counter
     stays wherever jit wants it)."""
     import jax
+    space = {HOST_KIND: jax.memory.Space.Host,
+             DEVICE_KIND: jax.memory.Space.Device}[kind]
 
     def put(l):
         if getattr(l, "ndim", 0) == 0:
             return l
-        from jax._src.sharding_impls import TransferToMemoryKind
-        return jax.device_put(l, TransferToMemoryKind(kind))
+        return jax.device_put(l, space)
 
     return jax.tree.map(put, tree)

@@ -388,9 +388,12 @@ def _attention_flash(q, k, v, scale: float) -> jax.Array:
         splash_attention_kernel as sk, splash_attention_mask as sm)
     B, S, nq, hd = q.shape
     if S % 128:
-        # splash blocks must be lane-aligned (multiples of 128); odd
-        # lengths take the einsum path instead of crashing in the kernel.
-        return _attention_xla(q, k, v, scale)
+        # no quiet hand-over to the einsum path: a run configured for the
+        # kernel must not measure something else
+        raise ValueError(
+            f"attention_impl='flash' needs a sequence length that is a "
+            f"multiple of 128 (splash blocks are lane-aligned), got {S}; "
+            f"pad the sequence or use attention_impl='xla'")
     bq, bkv = min(512, S), min(1024, S)
     # block_kv_compute must itself be a multiple of 128
     bkv_c = bkv // 2 if bkv % 256 == 0 else bkv

@@ -31,18 +31,11 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6 top-level export (check_vma kwarg)
-    from jax import shard_map as _shard_map
-    _RELAX_KW = {"check_vma": False}
-except ImportError:  # pragma: no cover - older jax: experimental, check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _RELAX_KW = {"check_rep": False}
-
 
 def smap(f, mesh: Mesh, in_specs, out_specs, **kw):
     """shard_map with this repo's defaults (explicit collectives allowed)."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **_RELAX_KW, **kw)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False, **kw)
 
 
 def axis_rank(axis_name: str) -> jax.Array:
@@ -51,15 +44,9 @@ def axis_rank(axis_name: str) -> jax.Array:
 
 
 def axis_size(axis_name: str) -> int:
-    """Static size of the named mesh axis, usable inside shard_map/pmap.
-
-    ``lax.axis_size`` only exists on newer jax; older versions (this
-    substrate ships 0.4.x) get the classic ``psum(1, axis)`` trick, which
-    constant-folds to the same trace-time Python int — every call site
-    that uses the result as a shape/loop bound keeps working."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """Static size of the named mesh axis (a trace-time Python int),
+    usable inside shard_map/pmap."""
+    return lax.axis_size(axis_name)
 
 
 def all_reduce(x, axis_name: str, op: str = "sum", *, mean: bool = False):
@@ -331,22 +318,25 @@ def all_gather_matmul(a, w_shard, axis_name: str):
 
 
 def _agmm_chunk_kernel(a_ref, w_ref, o_ref):
+    # Mosaic's matmul accumulates in 32 bits or not at all
     o_ref[...] = jnp.dot(a_ref[...], w_ref[...],
-                         preferred_element_type=o_ref.dtype)
+                         preferred_element_type=jnp.float32
+                         ).astype(o_ref.dtype)
 
 
 def _agmm_tile_call(a2, w, out_dtype, block_m, block_n, interpret):
     """One ring chunk's matmul as a Pallas call: grid over (M/bm, N/bn)
     row/col tiles, each block carrying full K (the chunk's contraction
-    dim) so every output element's K-sum happens in ONE dot — which is
-    what keeps the default full-block configuration bitwise against the
-    traced ``a_chunk @ cur``."""
+    dim) so every output element's K-sum happens in ONE dot.  Blocks
+    default to the largest aligned tiles that fit VMEM."""
     from jax.experimental import pallas as pl
+    from .quant import _auto_blocks
 
     M, K = a2.shape
     N = w.shape[1]
-    bm = block_m or M
-    bn = block_n or N
+    auto_m, auto_n = _auto_blocks(M, K, N, 2 * a2.dtype.itemsize,
+                                  2 * w.dtype.itemsize, 512, 512)
+    bm, bn = block_m or auto_m, block_n or auto_n
     return pl.pallas_call(
         _agmm_chunk_kernel,
         grid=(M // bm, N // bn),
@@ -397,15 +387,12 @@ def all_gather_matmul_pallas(a, w_shard, axis_name: str, *,
     counts and the ledger prices), with each per-chunk tile matmul
     running as a Pallas kernel instead of a traced ``@``.
 
-    On the CPU tier (``interpret=True``, the default off-TPU) the ring
-    hops cannot become in-kernel remote DMAs — interpret mode has no
-    inter-device copy — so the decomposition point is the per-chunk
-    matmul, and the default whole-chunk block makes the kernel's dot
-    bit-identical to the XLA path's (pinned by test).  On TPU the same
-    call sites tile via ``block_m``/``block_n``; folding the hop itself
-    into the kernel (``pltpu.make_async_remote_copy`` double-buffered
-    against the tile loop) is the recorded next step once a TPU BENCH
-    round can measure it.
+    The ring hops are not in-kernel remote DMAs (interpret mode has no
+    inter-device copy), so the decomposition point is the per-chunk
+    matmul, tiled over M/N by ``block_m``/``block_n`` (default: the
+    largest tiles that fit VMEM) with K never split.  Folding the hop
+    itself into the kernel (``pltpu.make_async_remote_copy``
+    double-buffered against the tile loop) is the recorded next step.
 
     AD: the ring scaffold stays plain traceable code (its transpose is
     the reversed-ring matmul-reduce-scatter, as for the XLA variant);

@@ -19,8 +19,8 @@ Parity tiers:
   * ``kv_block_pages=None`` (default) — ONE tile covering the whole
     view.  The epilogue then follows the reference op order exactly
     (mask → ``jax.nn.softmax`` → probs cast → contraction), which makes
-    the output BITWISE equal to the engine's gather+einsum path — the
-    tier the serving parity gates run.
+    the output equal to the engine's gather+einsum path up to float32
+    summation order — the tier the serving parity gates run.
   * ``kv_block_pages=k`` — genuine multi-block online softmax.  The
     divide-at-end rescaling reassociates the denominator, so this tier
     is allclose-not-bitwise vs the reference (asserted in tests); it is
@@ -30,9 +30,8 @@ Float pools only: the int8 pool's per-row scale folding does not
 commute with the online rescale, and prefill is the bandwidth-bound
 leg where bf16 pools are the default anyway.
 
-CPU-tier note: ``interpret=True`` executes the page loads with jax.lax
-machinery; on real TPU the table row sits in SMEM and loads become
-VMEM DMAs — same kernel body.
+CPU tier only: the design shares ``paged_attention.py``'s page loads and
+does not lower on a TPU (``paged_attention.refuse_on_tpu``).
 """
 
 from __future__ import annotations
@@ -44,7 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .paged_attention import _gather_pool
+from .paged_attention import _gather_pool, refuse_on_tpu
 
 __all__ = ["paged_flash_prefill"]
 
@@ -85,11 +84,9 @@ def _prefill_kernel(pages_ref, q_ref, apos_ref, pk_ref, pv_ref, o_ref, *,
         acc0 = jnp.zeros((T,) + tail, pool_ref.dtype)
 
         def load(p, accv):
-            pg = pages_ref[0, i * kv_block_pages + p]
-            blk = pl.load(pool_ref, (pl.ds(pg, 1),)
-                          + (slice(None),) * (1 + len(tail)))
+            blk = pool_ref[pages_ref[0, i * kv_block_pages + p]]
             return jax.lax.dynamic_update_slice(
-                accv, blk[0], (p * page,) + (0,) * len(tail))
+                accv, blk, (p * page,) + (0,) * len(tail))
 
         return jax.lax.fori_loop(0, kv_block_pages, load, acc0)
 
@@ -125,7 +122,7 @@ def _prefill_kernel(pages_ref, q_ref, apos_ref, pk_ref, pv_ref, o_ref, *,
 
 def paged_flash_prefill(qg, pk, pv, pages, apos, *, probs_dtype=None,
                         kv_block_pages: int | None = None,
-                        interpret: bool | None = None):
+                        interpret: bool = True):
     """Chunked-prefill paged flash attention, pages read in place.
 
     qg (B, S, n_kv, rep, hd) grouped query (already rope'd); pk/pv
@@ -136,8 +133,7 @@ def paged_flash_prefill(qg, pk, pv, pages, apos, *, probs_dtype=None,
     applies the same ``astype`` epilogue).  ``kv_block_pages`` must
     divide P; passing P is the same as None.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    refuse_on_tpu("paged_flash_prefill")
     if pk.dtype == jnp.int8:
         raise ValueError("flash prefill is float-pool only (int8 "
                          "scale folding does not commute with the "

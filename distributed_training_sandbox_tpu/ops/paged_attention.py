@@ -15,14 +15,16 @@ traffic drops from (gather-write + gather-read) to a single pool read.
 The attention math on the in-kernel view is the exact op sequence of
 ``_paged_layer_body`` — same einsum specs, mask constant, softmax axis,
 probs cast, and (for int8 pools) the same quantize/scale-fold ordering
-with the per-page scales folded in-kernel — so the kernel output is
-BITWISE equal to the reference path on matched inputs (asserted in
+with the per-page scales folded in-kernel — so the kernel output equals
+the reference path on matched inputs: bitwise for int8 pools (integer
+accumulation), to float32 summation order for float pools (asserted in
 tests/test_kernels.py on the CPU interpret tier).
 
-CPU-tier note: ``interpret=True`` executes the dynamic page loads with
-jax.lax machinery; on real TPU the page table row would sit in SMEM
-(scalar prefetch) and the loads become VMEM DMAs — recorded as the
-hardware-tier evolution, same kernel body.
+CPU tier only.  The design does not lower on a TPU (:func:`refuse_on_tpu`
+carries what Pallas said on a v5e): the whole pool is one block, the page
+id is read from a vector-memory ref and the view is assembled with
+``dynamic_update_slice``.  The hardware kernel keeps the page table in
+SMEM (scalar prefetch) and DMAs pages — ROADMAP S3.
 """
 
 from __future__ import annotations
@@ -33,7 +35,31 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["paged_attention_decode"]
+__all__ = ["paged_attention_decode", "refuse_on_tpu"]
+
+# What Pallas' TPU lowering says to both serving kernels (jax 0.9.0,
+# libtpu 0.0.34, TPU v5 lite; ``.lower(lowering_platforms=("tpu",))``
+# reproduces it without a chip).
+TPU_REFUSAL = (
+    "\"The Pallas TPU lowering currently requires that the last two "
+    "dimensions of your block shape are divisible by 8 and 128 "
+    "respectively, or be equal to the respective dimensions of the overall "
+    "array\" for the (1, P) page-table row; and with the table and "
+    "positions passed whole, \"Unimplemented primitive in Pallas TPU "
+    "lowering for KernelType.TC: dynamic_update_slice\" for the in-kernel "
+    "KV view")
+
+
+def refuse_on_tpu(kernel: str) -> None:
+    """Raise on a TPU backend, whatever ``interpret`` says: the kernel
+    neither compiles there nor may run interpreted or give way to the
+    gather path behind the caller's back."""
+    if jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            f"{kernel} does not lower on a TPU — {TPU_REFUSAL}.  The "
+            f"kernel is CPU-tier (interpret mode) until it is rewritten "
+            f"for the hardware (ROADMAP S3); leave its flag off and the "
+            f"engine serves through its XLA gather path.")
 
 
 def _gather_pool(pool_ref, pages_ref, n_slot_pages: int, page: int):
@@ -44,11 +70,9 @@ def _gather_pool(pool_ref, pages_ref, n_slot_pages: int, page: int):
     acc0 = jnp.zeros((n_slot_pages * page,) + tail, pool_ref.dtype)
 
     def load(p, acc):
-        pg = pages_ref[0, p]
-        blk = pl.load(pool_ref,
-                      (pl.ds(pg, 1),) + (slice(None),) * (1 + len(tail)))
+        blk = pool_ref[pages_ref[0, p]]
         return jax.lax.dynamic_update_slice(
-            acc, blk[0], (p * page,) + (0,) * len(tail))
+            acc, blk, (p * page,) + (0,) * len(tail))
 
     return jax.lax.fori_loop(0, n_slot_pages, load, acc0)
 
@@ -103,7 +127,7 @@ def _decode_kernel_q8(pages_ref, q_ref, qs_ref, apos_ref, pk_ref, pv_ref,
 
 def paged_attention_decode(qg, pk, pv, pages, apos, *, q_scale=None,
                            pk_s=None, pv_s=None, probs_dtype=None,
-                           interpret: bool | None = None):
+                           interpret: bool = True):
     """Decode-step paged attention, pages read in place via the table.
 
     qg (B, 1, n_kv, rep, hd) — grouped query (already rope'd); int8
@@ -115,8 +139,7 @@ def paged_attention_decode(qg, pk, pv, pages, apos, *, q_scale=None,
     (caller applies the same ``astype`` epilogue).
     """
     import functools
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    refuse_on_tpu("paged_attention_decode")
     B, S, nkv, rep, hd = qg.shape
     if S != 1:
         raise ValueError(f"decode kernel is S==1 only, got S={S}")

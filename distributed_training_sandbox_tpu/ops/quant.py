@@ -170,7 +170,8 @@ def int8_matmul_pallas(xq, xs, wq, ws, *, out_dtype=jnp.bfloat16,
     M, K = xq.shape
     K2, N = wq.shape
     assert K == K2, (K, K2)
-    bm, bn = _auto_blocks(M, K, N, 1, block_m or 256, block_n or 512)
+    # 3: the int8 x block twice over plus Mosaic's staged copy of it
+    bm, bn = _auto_blocks(M, K, N, 3, 2, block_m or 256, block_n or 512)
     return pl.pallas_call(
         _qmm_kernel,
         grid=(M // bm, N // bn),
@@ -186,13 +187,16 @@ def int8_matmul_pallas(xq, xs, wq, ws, *, out_dtype=jnp.bfloat16,
     )(xq, xs, wq, ws)
 
 
-def _auto_blocks(M: int, K: int, N: int, x_itemsize: int,
+def _auto_blocks(M: int, K: int, N: int, x_resident: int, w_resident: int,
                  target_m: int, target_n: int,
                  budget: int = 10 << 20) -> tuple[int, int]:
     """Largest (block_m, block_n) ≤ targets whose working set fits VMEM:
-    double-buffered x block (bm, K), w block (K, bn) int8 and scales, plus
-    the f32 accumulator/output tile.  ~16 MB/core total; budget leaves
-    headroom for Mosaic scratch."""
+    the full-K x block (bm, K) and w block (K, bn), plus scales and the
+    f32 accumulator/output tile.  ``x_resident`` / ``w_resident``: bytes
+    one element of each block holds in VMEM while the kernel runs — two
+    copies of the input dtype (Pallas double-buffers) plus whatever the
+    body materializes from it.  ~16 MB/core total; budget leaves headroom
+    for Mosaic scratch."""
     candidates_m = [target_m, 512, 256, 128, 64, 32, 16, 8]
     candidates_n = [target_n, 512, 256, 128]
     for tm in candidates_m:
@@ -200,8 +204,8 @@ def _auto_blocks(M: int, K: int, N: int, x_itemsize: int,
             if tm > target_m or tn > target_n:
                 continue
             bm, bn = _pick_block(M, tm, 8), _pick_block(N, tn, 128)
-            need = 2 * (bm * K * x_itemsize + K * bn + bn * 4) \
-                + bm * bn * 4 + bm * K  # int8 xq scratch
+            need = bm * K * x_resident + K * bn * w_resident \
+                + 2 * bn * 4 + bm * bn * 4
             if need <= budget:
                 return bm, bn
     return _pick_block(M, 8, 8), _pick_block(N, 128, 128)
@@ -236,7 +240,8 @@ def int8_matmul_pallas_fused(x, wq, ws, *, out_dtype=jnp.bfloat16,
     M, K = x.shape
     K2, N = wq.shape
     assert K == K2, (K, K2)
-    bm, bn = _auto_blocks(M, K, N, x.dtype.itemsize,
+    # + 1: the int8 codes the body quantizes the block into
+    bm, bn = _auto_blocks(M, K, N, 2 * x.dtype.itemsize + 1, 2,
                           block_m or 256, block_n or 512)
     return pl.pallas_call(
         _fused_qmm_kernel,
@@ -622,7 +627,9 @@ def fp8_matmul_pallas(aq, a_scale, bq, b_scale, *, out_dtype=jnp.bfloat16,
     M, K = aq.shape
     K2, N = bq.shape
     assert K == K2, (K, K2)
-    bm, bn = _auto_blocks(M, K, N, 1, block_m or 256, block_n or 512)
+    # + 4: the body upcasts both fp8 blocks to float32 before the dot
+    bm, bn = _auto_blocks(M, K, N, 2 + 4, 2 + 4,
+                          block_m or 256, block_n or 512)
     return pl.pallas_call(
         _fp8_mm_kernel,
         grid=(M // bm, N // bn),

@@ -114,7 +114,8 @@ def q8_state_specs(params_sharded, specs):
 def init_fsdp_opt_state8(params_sharded, axis: str = "dp"):
     """int8-at-rest Adam moments (``parallel.optim8``) sharded like the
     params — cuts the largest resident block (mu/nu, 3.31 GB of the
-    flagship's 4.96 GB at rest, EXPERIMENTS.md) to ~half.  ``axis``
+    flagship's 4.96 GB at rest, ``scripts/memory_waterline.py``) to
+    ~half.  ``axis``
     must match the FSDP axis the params were sharded over."""
     from . import optim8
 

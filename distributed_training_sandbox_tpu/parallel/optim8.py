@@ -1,6 +1,6 @@
 """8-bit Adam moments: the at-rest optimizer state stored int8.
 
-The r4 memory accounting (EXPERIMENTS.md) put the flagship's Adam
+The r4 memory accounting (``scripts/memory_waterline.py``) put the flagship's Adam
 mu/nu at 3.31 GB of the 4.96 GB resident state — the largest block on
 the chip.  Storing both moments int8 with per-row fp32 scales cuts that
 to ~1.7 GB, which is the same order as the 2.3–2.7 GB OOM margins that

@@ -88,7 +88,10 @@ def _write_meta(directory: str, state: RunState,
         "fingerprint": fingerprint or {},
     }
     path = _meta_path(directory, state.step)
-    tmp = path + ".tmp"
+    # every rank of a multi-process run writes this (identical) sidecar
+    # into the shared directory: a per-process temp name keeps one
+    # rank's replace from moving the file another is still writing
+    tmp = f"{path}.{os.getpid()}.tmp"
     with open(tmp, "w") as f:
         json.dump(meta, f, indent=1)
         f.write("\n")

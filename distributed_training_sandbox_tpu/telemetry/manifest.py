@@ -111,13 +111,8 @@ class RunManifest:
         is the ``analysis.ContractVerdict.to_dict()`` of checking those
         counts against the strategy's choreography contract."""
         import jax
+        import jaxlib
         dev = jax.devices()[0]
-        jaxlib_version = None
-        try:
-            import jaxlib
-            jaxlib_version = getattr(jaxlib, "__version__", None)
-        except ImportError:
-            pass
         return cls(
             run_id=run_id,
             strategy=strategy,
@@ -125,7 +120,7 @@ class RunManifest:
             config=_config_dict(config),
             mesh_shape=dict(mesh.shape) if mesh is not None else {},
             mesh_axes=list(mesh.axis_names) if mesh is not None else [],
-            device_kind=getattr(dev, "device_kind", str(dev)),
+            device_kind=dev.device_kind,
             device_count=jax.device_count(),
             local_device_count=len(jax.local_devices()),
             process_index=jax.process_index(),
@@ -133,7 +128,7 @@ class RunManifest:
             pid=os.getpid(),
             platform=dev.platform,
             jax_version=jax.__version__,
-            jaxlib_version=jaxlib_version,
+            jaxlib_version=jaxlib.__version__,
             git_sha=_git_sha(),
             started_utc=datetime.datetime.now(
                 datetime.timezone.utc).isoformat(timespec="seconds"),

@@ -60,6 +60,9 @@ from .schema import step_event
 from .writer import MetricsWriter
 
 
+HLO_FILENAME = "step.hlo.txt"
+
+
 class TelemetryRun:
     def __init__(self, strategy: str, *, config=None, mesh=None,
                  model: str | None = None,
@@ -213,8 +216,13 @@ class TelemetryRun:
         finalize can join the profiler trace against it (the collective
         ledger needs instruction names + payload shapes).  Scripts call
         this only when profiling is on — lowering+compiling purely for
-        the text would otherwise double compile cost."""
+        the text would otherwise double compile cost.  The text is also
+        filed as ``step.hlo.txt``: what the device was asked to run
+        (which kernels, which collectives) stays readable after the
+        process is gone."""
         self._hlo_text = compiled_text
+        if self.writer is not None:
+            self.writer.write_text(HLO_FILENAME, compiled_text)
 
     def attach_step_hlo(self, jitted, *args, trees=None,
                         prediction=None) -> None:

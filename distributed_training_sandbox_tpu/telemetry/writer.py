@@ -9,6 +9,8 @@ Layout contract (read back by ``telemetry.report`` / ``scripts/report.py``):
                           the owned profiler sessions + ledger verdict
         steps.jsonl       appended once per optimizer step (schema.step_event)
         spans.jsonl       host-side phase spans (telemetry.spans), when any
+        step.hlo.txt      the compiled step program's HLO text, when the
+                          run attached it (TelemetryRun.attach_hlo)
         collectives.json  the CollectiveLedger (telemetry.ledger), when
                           profiling captured a trace and the run attached
                           its compiled HLO
@@ -80,6 +82,12 @@ class MetricsWriter:
         with open(path, "w") as f:
             json.dump(obj, f, indent=2, default=str)
             f.write("\n")
+        return path
+
+    def write_text(self, name: str, text: str) -> str:
+        path = os.path.join(self.run_dir, name)
+        with open(path, "w") as f:
+            f.write(text)
         return path
 
     def write_summary(self, summary: dict) -> str:

@@ -13,6 +13,7 @@ from .mesh import (  # noqa: F401
     local_scalar,
     use_cpu_devices,
 )
+from .compile_cache import configure_compile_cache  # noqa: F401
 from .prng import set_seed, key_for_axis  # noqa: F401
 from .memory import (  # noqa: F401
     tree_size_mb,

@@ -51,7 +51,7 @@ def save_state(mgr: "ocp.CheckpointManager", step: int, state: Any,
     """Save a pytree of (possibly sharded) arrays under ``step``.
     ``wait=False`` leaves the write async (overlap with the next train
     steps); call ``mgr.wait_until_finished()`` before exiting."""
-    mgr.save(step, args=_ocp().args.StandardSave(state))
+    mgr.save(step, args=_ocp().args.PyTreeSave(state))
     if wait:
         mgr.wait_until_finished()
 
@@ -84,7 +84,15 @@ def restore_state(mgr: "ocp.CheckpointManager", *, like: Any,
     dtypes, and shardings — placement happens during restore, so a
     dp-sharded param tree comes back dp-sharded without a host round
     trip (and reshards automatically if ``like``'s mesh differs from
-    the one that saved)."""
+    the one that saved).
+
+    ``strict=False``: a leaf whose stored shape differs from ``like``'s is
+    truncated or zero-padded to fit.  ZeRO's flat shards are padded to a
+    multiple of the SAVING world size (``parallel.zero._pad_flat``), so
+    the same state is (104,) from eight devices and (100,) on four; the
+    difference is always trailing zeros.  Which model a checkpoint
+    belongs to is the run fingerprint's check
+    (``resilience.state.Checkpointer.restore_latest``), not this one's."""
     if step is None:
         step = mgr.latest_step()
         if step is None:
@@ -92,7 +100,12 @@ def restore_state(mgr: "ocp.CheckpointManager", *, like: Any,
                 f"no checkpoint found under {mgr.directory}")
     ocp = _ocp()
     abstract = jax.tree.map(ocp.utils.to_shape_dtype_struct, like)
-    return mgr.restore(step, args=ocp.args.StandardRestore(abstract))
+    restore_args = jax.tree.map(
+        lambda a: ocp.ArrayRestoreArgs(
+            sharding=a.sharding, global_shape=a.shape, dtype=a.dtype,
+            strict=False), abstract)
+    return mgr.restore(step, args=ocp.args.PyTreeRestore(
+        item=abstract, restore_args=restore_args))
 
 
 def restore_params(ckpt_dir, params, *, tag: str = "restore"):

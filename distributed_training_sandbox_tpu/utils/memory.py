@@ -4,8 +4,11 @@ sizes in MB by tensor-walking) plus device-allocator stats from the XLA client
 reference, ``device.memory_stats()`` is here — reference
 ``DDP/training_utils/memory.py:8-50``, ``fsdp/utils.py:204-219``).
 
-CPU-simulated devices expose no allocator stats; every accessor degrades to
-zeros there so the same scripts run on the CI mesh.
+CPU-simulated devices expose no allocator stats (``memory_stats()`` is None);
+every accessor degrades to zeros there so the same scripts run on the CI
+mesh.  A TPU v5e reports them: ``bytes_limit`` 15.75 GiB, and
+``peak_bytes_in_use`` for live buffers only — a program's temporaries are
+counted under ``peak_bytes_reserved``, which these accessors do not read.
 """
 
 from __future__ import annotations
@@ -95,8 +98,7 @@ def device_memory_stats(device: jax.Device | None = None) -> dict[str, int]:
     """Allocator stats for one device: ``bytes_in_use`` / ``peak_bytes_in_use``
     / ``bytes_limit`` (zeros when the backend exposes none, e.g. CPU sim)."""
     device = device or jax.local_devices()[0]
-    stats = device.memory_stats() if hasattr(device, "memory_stats") else None
-    stats = stats or {}
+    stats = device.memory_stats() or {}
     return {
         "bytes_in_use": int(stats.get("bytes_in_use", 0)),
         "peak_bytes_in_use": int(stats.get("peak_bytes_in_use", 0)),

@@ -27,6 +27,8 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from .compile_cache import configure_compile_cache
+
 _MESHES: dict[str, Mesh] = {}
 DEFAULT_MESH = "default"
 
@@ -77,6 +79,7 @@ def use_cpu_devices(n: int = 8) -> None:
         os.environ["XLA_FLAGS"] = flags.replace(
             m.group(0), f"--xla_force_host_platform_device_count={n}")
     jax.config.update("jax_platforms", "cpu")
+    configure_compile_cache()
     auto_initialize_from_env()
 
 
@@ -207,8 +210,7 @@ def shutdown_distributed() -> None:
     the paths no ``finally`` reaches).  A failed shutdown is reported,
     not raised: teardown must never mask the error that caused it."""
     global _DTS_INITIALIZED
-    client = getattr(jax.distributed, "global_state", None)
-    if client is None or getattr(client, "client", None) is None:
+    if not jax.distributed.is_initialized():
         _DTS_INITIALIZED = False
         return
     try:

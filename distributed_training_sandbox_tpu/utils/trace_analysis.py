@@ -195,16 +195,19 @@ def split_from_trace(trace_dir: str, top_n: int = 5,
 
 # -------------------------------------------- per-instance collectives
 #
-# Trace event names of device ops ARE compiled-HLO instruction names
-# ("all-reduce.1", "all-gather-start.3"), one event per participating
-# device row per invocation — verified on the CPU-sim backend against
-# compile().as_text() for every contract strategy.  This extracts the
-# per-instruction stats the CollectiveLedger (telemetry.ledger) joins
-# against ops.hlo.collective_instances.
+# Trace event names of device ops ARE compiled-HLO instruction names, one
+# event per participating device row per invocation.  XLA names a
+# collective instruction after the JAX primitive that produced it
+# ("psum.7", "all_gather.42") and falls back to the opcode for the ones it
+# creates itself ("all-reduce.1", "all-gather-start.3"), so both
+# spellings are collective events.  This extracts the per-instruction
+# stats the CollectiveLedger (telemetry.ledger) joins against
+# ops.hlo.collective_instances.
 
 _COLLECTIVE_EVENT_RE = re.compile(
     r"^(all-reduce|all-gather|reduce-scatter|collective-permute|"
-    r"all-to-all)(-start|-done)?(\.\d+)?$")
+    r"all-to-all|psum|pmax|pmin|all_gather|reduce_scatter|ppermute|"
+    r"all_to_all)(-start|-done)?(\.\d+)?$")
 
 
 def normalize_event_name(name: str) -> str:

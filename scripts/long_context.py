@@ -5,8 +5,8 @@ there, ``fp8/modal_app.py:90``; SURVEY.md §5.7).  This sweep runs the
 flagship FSDP train step (AdamW, fused splash attention, streamed-vocab
 loss, full remat) at 16k/32k/64k on one chip — the combination of
 O(S)-memory attention and the spike-free loss is exactly what makes
-these lengths reachable at all (see EXPERIMENTS.md: the dense-loss
-design already fails to fit at 8192×2).
+these lengths reachable at all (``scripts/memory_waterline.py``: the
+dense-loss design already fails to fit at 8192×2).
 
 Writes ``longcontext_results/longcontext_<platform>.json`` (one row per
 seq, same schema as bench.py's matrix rows) and prints a markdown table.

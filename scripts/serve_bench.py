@@ -324,6 +324,7 @@ def main(argv=None) -> int:
             failures.append(f"only {slo['completed']}/{args.requests} "
                             f"requests completed")
 
+        parity = []
         for req in reqs[:args.check_parity]:
             ref = np.asarray(generate(
                 params, req.prompt[None], cfg,
@@ -331,7 +332,15 @@ def main(argv=None) -> int:
                 kv_quant=args.kv_quant,
                 cache_capacity=eng.view_capacity))[0]
             got = np.asarray(req.tokens, np.int32)
-            if got.shape != ref.shape or not (got == ref).all():
+            same = got.shape == ref.shape
+            agree = got == ref if same else np.zeros(0, bool)
+            # where the tokens part ways matters to a reader as much as
+            # whether they do: a first-token miss is a wrong prefill, a
+            # late one is two programs rounding a near-tie differently
+            parity.append({"rid": req.rid, "tokens": int(ref.size),
+                           "first_token_equal": bool(same and agree[0]),
+                           "tokens_equal": int(agree.sum())})
+            if not (same and agree.all()):
                 failures.append(
                     f"rid {req.rid}: tokens diverge from one-shot "
                     f"generate (got {got.tolist()[:8]}..., ref "
@@ -341,6 +350,7 @@ def main(argv=None) -> int:
                   f"{min(args.check_parity, len(reqs))} request(s) "
                   f"{'OK' if not failures else 'CHECKED (see failures)'}",
                   flush=True)
+        slo["parity"] = parity
         slo["parity_checked"] = min(args.check_parity, len(reqs))
         slo["failures"] = failures
         telem.finalize(serving=slo)

@@ -226,10 +226,15 @@ def _leg(args, rest, cfg, ctx):
     if args.variant == "explicit" and cfg.overlap != "ring_fused":
         from distributed_training_sandbox_tpu.analysis import (
             evaluate_contract)
+        # the remat'd scan body re-runs its layer_hook in the backward:
+        # with per-layer resharding every stacked leaf is gathered twice
+        regather = len(jax.tree.leaves(shards["layers"])) \
+            if mcfg.remat and args.reshard else 0
         verdict = evaluate_contract(cname, counts, params=shards,
                                     mesh=mesh,
                                     n_layers=mcfg.num_hidden_layers,
-                                    offload=oplan.to_dict())
+                                    offload=oplan.to_dict(),
+                                    regather_leaves=regather)
         print(f"[fsdp] contract[{cname}]: {verdict.summary()}")
     ctx.verify_contract(verdict)
 

@@ -14,22 +14,18 @@ from distributed_training_sandbox_tpu.utils import use_cpu_devices
 
 use_cpu_devices(8)
 
-# Telemetry runs from in-process script invocations go to a throwaway dir,
-# not ./runs in the checkout (subprocess-spawning tests inherit this too).
+# Telemetry runs and profiler traces from in-process script invocations go
+# to throwaway dirs, not ./runs and ./profiler_traces in the checkout
+# (subprocess-spawning tests inherit this too).
 os.environ.setdefault(
     "RESULTS_DIR", tempfile.mkdtemp(prefix="dts-telemetry-runs-"))
+os.environ.setdefault(
+    "TRACE_DIR", tempfile.mkdtemp(prefix="dts-profiler-traces-"))
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
-
-# NOTE: do NOT enable jax's persistent compilation cache here — on this
-# jaxlib (0.4.37 CPU) executables deserialized from the cache segfault
-# under the checkpoint suite (orbax block_until_ready on a cache-hit
-# executable's output while the prefetch producer thread runs).
-# Re-evaluate after a jaxlib bump; the suite recompiles many identical
-# TINY_LM programs and would win minutes from a working cache.
 
 REPO = Path(__file__).resolve().parent.parent
 

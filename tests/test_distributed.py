@@ -34,8 +34,13 @@ DDP_FLAGS = ["--", "--scale", "100", "--batch-size", "32",
 
 def test_distributed_ddp_bitwise_vs_single_process(procs2, tmp_path):
     """Two processes x 2 devices vs one process x 4 devices, same
-    global mesh — the loss logs must be bitwise-identical, proving the
-    per-process batch shards assemble into the same global batch."""
+    global mesh — the loss logs must agree, proving the per-process
+    batch shards assemble into the same global batch.  Agreement is to
+    a few float32 ulps, not bitwise: the gradient all-reduce sums the
+    four device contributions pairwise-within-then-across processes
+    under gloo and in one pass inside a single process, and float32
+    addition does not associate.  A mis-assembled batch moves the loss
+    in its second or third digit."""
     ra = procs2.launch(
         ["--script", "ddp", "--num-steps", "4", "--devices", "cpu:2",
          "--nprocs", "2", "--distributed",
@@ -52,7 +57,8 @@ def test_distributed_ddp_bitwise_vs_single_process(procs2, tmp_path):
     la = procs2.loss_log(tmp_path / "ckA")
     lb = procs2.loss_log(tmp_path / "ckB")
     assert len(la) == 4, (la, ra.stdout[-2000:])
-    assert la == lb, (la, lb)
+    assert [float(v) for v in la] == pytest.approx(
+        [float(v) for v in lb], rel=1e-6, abs=0), (la, lb)
 
 
 BRINGUP_ORPHAN = r"""

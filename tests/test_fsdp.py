@@ -114,6 +114,26 @@ def test_collective_choreography_in_hlo(setup, mesh8):
     assert counts["all_reduce"] >= 1  # loss mean
 
 
+def test_contract_counts_the_remat_regathers(setup, mesh8):
+    """Every real model rematerializes its layer scan (TINY_LM does not):
+    the per-layer gathers then run again in the backward, and the fsdp
+    contract has to expect them — the first full-width run reported
+    VIOLATED, 20 all_gather sites against 11, until it did."""
+    import dataclasses
+    from distributed_training_sandbox_tpu.analysis import evaluate_contract
+    _, shards, batch = setup
+    opt = fsdp.init_fsdp_opt_state(shards)
+    step = fsdp.make_fsdp_train_step(
+        shards, dataclasses.replace(CFG, remat=True), mesh8, donate=False)
+    counts = count_collectives(step, shards, opt, batch)
+    layer_leaves = len(jax.tree.leaves(shards["layers"]))
+    assert counts["all_gather"] == 11 + layer_leaves
+    assert evaluate_contract("fsdp", counts, params=shards, mesh=mesh8,
+                             regather_leaves=layer_leaves).ok
+    assert not evaluate_contract("fsdp", counts, params=shards,
+                                 mesh=mesh8).ok
+
+
 def test_divisibility_guard(mesh8):
     cfg = T.TransformerConfig(
         vocab_size=96, hidden_size=12, intermediate_size=36,

@@ -71,6 +71,14 @@ def test_causality(setup):
                            np.asarray(logits2[0, -1], np.float32))
 
 
+def test_flash_attention_does_not_give_way_to_xla():
+    """A length splash cannot tile is an error, not a quiet run of the
+    einsum path under the kernel's name."""
+    q = jnp.zeros((1, 100, 4, 16))
+    with pytest.raises(ValueError, match="multiple of 128"):
+        T._attention_flash(q, q, q, 0.25)
+
+
 def test_nope_schedule():
     flags = np.asarray(T._rope_flags(T.SMOLLM3_3B))
     # every 4th layer (3, 7, 11, ...) skips RoPE — SmolLM3's NoPE scheme
