@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops.collectives import axis_size
+from ..utils.profiling import scope
 
 
 @dataclass(frozen=True)
@@ -515,25 +516,27 @@ def _layer_body(x, layer, *, cfg: TransformerConfig, cos, sin, use_rope,
     nq = cfg.num_attention_heads // tp
     dense = _dense(cfg)
 
-    r = rms_norm(x, layer["ln1"], cfg.rms_norm_eps)
-    q, k, v = _qkv_proj(r, layer, cfg=cfg, cos=cos, sin=sin,
-                        use_rope=use_rope, tp=tp)
+    with scope("attn_qkv"):
+        r = rms_norm(x, layer["ln1"], cfg.rms_norm_eps)
+        q, k, v = _qkv_proj(r, layer, cfg=cfg, cos=cos, sin=sin,
+                            use_rope=use_rope, tp=tp)
     scale = 1.0 / math.sqrt(hd)
-    if cfg.attention_impl == "flash":
-        attn = _attention_flash(q, k, v, scale).astype(x.dtype)
-    elif cfg.attention_impl == "ring":  # sp_axis validated in __post_init__
-        from ..ops.ring_attention import ring_attention
-        attn = ring_attention(q, k, v, cfg.sp_axis, scale=scale,
-                              block_q=cfg.ring_block_q or None,
-                              layout=cfg.ring_layout)
-    else:
-        attn = _attention_xla(q, k, v, scale).astype(x.dtype)
+    with scope("attn_core"):
+        if cfg.attention_impl == "flash":
+            attn = _attention_flash(q, k, v, scale).astype(x.dtype)
+        elif cfg.attention_impl == "ring":  # sp_axis validated in __post_init__
+            from ..ops.ring_attention import ring_attention
+            attn = ring_attention(q, k, v, cfg.sp_axis, scale=scale,
+                                  block_q=cfg.ring_block_q or None,
+                                  layout=cfg.ring_layout)
+        else:
+            attn = _attention_xla(q, k, v, scale).astype(x.dtype)
     from jax.ad_checkpoint import checkpoint_name
     attn = checkpoint_name(attn, "attn_out")
-    attn_out = dense(attn.reshape(B, S, nq * hd), layer["wo"])
+    with scope("attn_out"):
+        attn_out = dense(attn.reshape(B, S, nq * hd), layer["wo"])
     if tp_axis:  # Megatron f/g: rejoin the row-parallel partial sums
         from ..ops import collectives as C
-        from ..utils.profiling import scope
         if tp_overlap == "ring":
             _rejoin = lambda v: C.decomposed_all_reduce(v, tp_axis,
                                                         axis=-1)
@@ -549,7 +552,6 @@ def _layer_body(x, layer, *, cfg: TransformerConfig, cos, sin, use_rope,
             attn_out = _rejoin(attn_out)
     x = x + attn_out
 
-    r = rms_norm(x, layer["ln2"], cfg.rms_norm_eps)
     if tp_axis and cfg.n_experts and cfg.ep_axis:
         raise ValueError("shard experts over ep OR split them over "
                          "tp, not both (ep_axis and tp_axis set)")
@@ -558,7 +560,9 @@ def _layer_body(x, layer, *, cfg: TransformerConfig, cos, sin, use_rope,
     # router are), the per-expert matmuls produce partial sums, and one
     # psum after combine rejoins them — the Megatron row/column pairing
     # applied inside each expert (dense MLP: the classic pairing).
-    mlp, aux = _mlp_block(r, layer, cfg=cfg)
+    with scope("mlp"):
+        r = rms_norm(x, layer["ln2"], cfg.rms_norm_eps)
+        mlp, aux = _mlp_block(r, layer, cfg=cfg)
     if tp_axis:
         with scope("tp_moe_psum" if cfg.n_experts else "tp_mlp_psum"):
             mlp = _rejoin(mlp)
@@ -627,7 +631,8 @@ def forward(params: dict, input_ids: jax.Array, cfg: TransformerConfig,
     """
     x = hidden_states(params, input_ids, cfg, layer_hook=layer_hook,
                       layer_body=layer_body)
-    return x @ _output_embedding(params, cfg).T
+    with scope("loss_head"):
+        return x @ _output_embedding(params, cfg).T
 
 
 def hidden_states(params: dict, input_ids: jax.Array,
@@ -638,19 +643,22 @@ def hidden_states(params: dict, input_ids: jax.Array,
     losses summed (the MoE load-balance term; 0 for dense layers)."""
     B, S = input_ids.shape
     apply_layer = layer_body or _layer_body
-    x = params["embed"].astype(cfg.dtype)[input_ids]
-    # Under sequence parallelism S is the LOCAL chunk; RoPE positions and
-    # the causal structure use this rank's GLOBAL positions — an offset
-    # for contiguous chunks, the stripe-pair position map for zigzag.
-    if cfg.sp_axis and cfg.ring_layout == "zigzag":
-        from ..ops.ring_attention import zigzag_positions
-        cos, sin = _rope_tables(S, cfg.resolved_head_dim, cfg.rope_theta,
-                                positions=zigzag_positions(cfg.sp_axis, S))
-    else:
-        offset = lax.axis_index(cfg.sp_axis) * S if cfg.sp_axis else 0
-        cos, sin = _rope_tables(S, cfg.resolved_head_dim, cfg.rope_theta,
-                                offset)
-    flags = _rope_flags(cfg)
+    with scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[input_ids]
+        # Under sequence parallelism S is the LOCAL chunk; RoPE positions
+        # and the causal structure use this rank's GLOBAL positions — an
+        # offset for contiguous chunks, the stripe-pair position map for
+        # zigzag.
+        if cfg.sp_axis and cfg.ring_layout == "zigzag":
+            from ..ops.ring_attention import zigzag_positions
+            cos, sin = _rope_tables(
+                S, cfg.resolved_head_dim, cfg.rope_theta,
+                positions=zigzag_positions(cfg.sp_axis, S))
+        else:
+            offset = lax.axis_index(cfg.sp_axis) * S if cfg.sp_axis else 0
+            cos, sin = _rope_tables(S, cfg.resolved_head_dim,
+                                    cfg.rope_theta, offset)
+        flags = _rope_flags(cfg)
 
     def body(carry, scanned):
         layer, use_rope = scanned
@@ -664,7 +672,8 @@ def hidden_states(params: dict, input_ids: jax.Array,
         body = jax.checkpoint(body, prevent_cse=False,
                               policy=resolve_remat_policy(cfg))
     x, aux = lax.scan(body, x, (params["layers"], flags))
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    with scope("loss_head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     return (x, jnp.sum(aux)) if return_aux else x
 
 
@@ -731,8 +740,9 @@ def lm_loss(params: dict, batch, cfg: TransformerConfig,
     input_ids, labels = batch
     x, aux = hidden_states(params, input_ids, cfg, layer_hook=layer_hook,
                            layer_body=layer_body, return_aux=True)
-    loss = xent_from_hidden(x, _output_embedding(params, cfg), labels,
-                            chunk=cfg.loss_vocab_chunk)
+    with scope("loss_head"):
+        loss = xent_from_hidden(x, _output_embedding(params, cfg), labels,
+                                chunk=cfg.loss_vocab_chunk)
     if cfg.n_experts:
         loss = loss + cfg.moe_aux_weight * aux
     return loss

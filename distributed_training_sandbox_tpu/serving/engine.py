@@ -52,6 +52,8 @@ import numpy as np
 from ..models import transformer as T
 from ..models.generate import _decode_cfg, _quant_kv
 from ..ops import collectives as C
+from ..telemetry.spans import maybe_span
+from ..utils.profiling import scope
 from .kv_pool import PagedKVPool, PoolBuffers, RadixPrefixCache
 from .scheduler import ContinuousBatcher, DECODE, PREFILL, Request
 
@@ -115,56 +117,66 @@ def _paged_layer_body(x, layer, *, cfg, cos, sin, use_rope, pk, pv,
     P = pages.shape[1]
     V = P * page
 
-    r = T.rms_norm(x, layer["ln1"], cfg.rms_norm_eps)
-    q = dense(r, layer["wq"]).reshape(B, S, nq, hd)
-    k = dense(r, layer["wk"]).reshape(B, S, nkv, hd)
-    v = dense(r, layer["wv"]).reshape(B, S, nkv, hd)
-    q = jnp.where(use_rope, _apply_rope_ragged(q, cos, sin), q)
-    k = jnp.where(use_rope, _apply_rope_ragged(k, cos, sin), k)
+    with scope("attn_qkv"):
+        r = T.rms_norm(x, layer["ln1"], cfg.rms_norm_eps)
+        q = dense(r, layer["wq"]).reshape(B, S, nq, hd)
+        k = dense(r, layer["wk"]).reshape(B, S, nkv, hd)
+        v = dense(r, layer["wv"]).reshape(B, S, nkv, hd)
+        q = jnp.where(use_rope, _apply_rope_ragged(q, cos, sin), q)
+        k = jnp.where(use_rope, _apply_rope_ragged(k, cos, sin), k)
 
     # scatter the new rows: target page from the slot's table, offset
     # within it; invalid rows all collapse onto page 0 (duplicate
     # scatter targets there are fine — it's the trash page)
-    pi = jnp.clip(apos // page, 0, P - 1)
-    pg = jnp.where(valid, jnp.take_along_axis(pages, pi, axis=1), 0)
-    off = apos % page
     quantized = pk.dtype == jnp.int8
-    if quantized:
-        kq, ks_new = _quant_kv(k)
-        vq, vs_new = _quant_kv(v)
-        pk = pk.at[pg, off].set(kq)
-        pv = pv.at[pg, off].set(vq)
-        pk_s = pk_s.at[pg, off].set(ks_new)
-        pv_s = pv_s.at[pg, off].set(vs_new)
-    else:
-        pk = pk.at[pg, off].set(k)
-        pv = pv.at[pg, off].set(v)
+    with scope("kv_write"):
+        pi = jnp.clip(apos // page, 0, P - 1)
+        pg = jnp.where(valid, jnp.take_along_axis(pages, pi, axis=1), 0)
+        off = apos % page
+        if quantized:
+            kq, ks_new = _quant_kv(k)
+            vq, vs_new = _quant_kv(v)
+            pk = pk.at[pg, off].set(kq)
+            pv = pv.at[pg, off].set(vq)
+            pk_s = pk_s.at[pg, off].set(ks_new)
+            pv_s = pv_s.at[pg, off].set(vs_new)
+        else:
+            pk = pk.at[pg, off].set(k)
+            pv = pv.at[pg, off].set(v)
 
+    def tail(attn):
+        """Output projection, residual, MLP: the same after every
+        attention path."""
+        with scope("attn_out"):
+            attn_out = dense(attn.astype(x.dtype).reshape(B, S, nq * hd),
+                             layer["wo"])
+            if tp_axis:
+                attn_out = C.all_reduce(attn_out, tp_axis)
+        h = x + attn_out
+        with scope("mlp"):
+            r = T.rms_norm(h, layer["ln2"], cfg.rms_norm_eps)
+            mlp, _aux = T._mlp_block(r, layer, cfg=cfg)
+            if tp_axis:
+                mlp = C.all_reduce(mlp, tp_axis)
+        return h + mlp, (pk, pv, pk_s, pv_s)
+
+    rep = nq // nkv
     if paged_kernel and S == 1:
         # Pallas decode kernel: pages are read IN PLACE via the table —
         # the (B, V, nkv, hd) gather view below never materializes.
         # Bitwise-equal to the gather path (ops/paged_attention.py).
         from ..ops.paged_attention import paged_attention_decode
-        rep = nq // nkv
-        qg = q.reshape(B, S, nkv, rep, hd)
-        if quantized:
-            qq, q_s = _quant_kv(qg)
-            attn = paged_attention_decode(
-                qq, pk, pv, pages, apos, q_scale=q_s,
-                pk_s=pk_s, pv_s=pv_s)
-        else:
-            attn = paged_attention_decode(qg, pk, pv, pages, apos,
-                                          probs_dtype=x.dtype)
-        attn = attn.astype(x.dtype).reshape(B, S, nq * hd)
-        attn_out = dense(attn, layer["wo"])
-        if tp_axis:
-            attn_out = C.all_reduce(attn_out, tp_axis)
-        x = x + attn_out
-        r = T.rms_norm(x, layer["ln2"], cfg.rms_norm_eps)
-        mlp, _aux = T._mlp_block(r, layer, cfg=cfg)
-        if tp_axis:
-            mlp = C.all_reduce(mlp, tp_axis)
-        return x + mlp, (pk, pv, pk_s, pv_s)
+        with scope("attn_core"):
+            qg = q.reshape(B, S, nkv, rep, hd)
+            if quantized:
+                qq, q_s = _quant_kv(qg)
+                attn = paged_attention_decode(
+                    qq, pk, pv, pages, apos, q_scale=q_s,
+                    pk_s=pk_s, pv_s=pv_s)
+            else:
+                attn = paged_attention_decode(qg, pk, pv, pages, apos,
+                                              probs_dtype=x.dtype)
+        return tail(attn)
 
     if flash_prefill and S > 1 and not quantized:
         # Pallas flash prefill: the whole chunk's attention in one
@@ -173,67 +185,51 @@ def _paged_layer_body(x, layer, *, cfg, cos, sin, use_rope, pk, pv,
         # bitwise-equal to the gather+einsum path below
         # (ops/flash_prefill.py pins the epilogue ordering).
         from ..ops.flash_prefill import paged_flash_prefill
-        rep = nq // nkv
-        qg = q.reshape(B, S, nkv, rep, hd)
-        attn = paged_flash_prefill(qg, pk, pv, pages, apos,
-                                   probs_dtype=x.dtype)
-        attn = attn.astype(x.dtype).reshape(B, S, nq * hd)
-        attn_out = dense(attn, layer["wo"])
-        if tp_axis:
-            attn_out = C.all_reduce(attn_out, tp_axis)
-        x = x + attn_out
-        r = T.rms_norm(x, layer["ln2"], cfg.rms_norm_eps)
-        mlp, _aux = T._mlp_block(r, layer, cfg=cfg)
-        if tp_axis:
-            mlp = C.all_reduce(mlp, tp_axis)
-        return x + mlp, (pk, pv, pk_s, pv_s)
+        with scope("attn_core"):
+            qg = q.reshape(B, S, nkv, rep, hd)
+            attn = paged_flash_prefill(qg, pk, pv, pages, apos,
+                                       probs_dtype=x.dtype)
+        return tail(attn)
 
     # gather the slot's pages into the contiguous head-major view the
     # attention contracts over — fixed extent V for every request, the
     # parity-bearing choice (see module docstring)
-    vk = pk[pages].reshape(B, V, nkv, hd).transpose(0, 2, 1, 3)
-    vv = pv[pages].reshape(B, V, nkv, hd).transpose(0, 2, 1, 3)
-
-    rep = nq // nkv
+    with scope("kv_gather"):
+        vk = pk[pages].reshape(B, V, nkv, hd).transpose(0, 2, 1, 3)
+        vv = pv[pages].reshape(B, V, nkv, hd).transpose(0, 2, 1, 3)
     qg = q.reshape(B, S, nkv, rep, hd)
     if quantized:
-        vk_s = pk_s[pages].reshape(B, V, nkv, 1).transpose(0, 2, 1, 3)
-        vv_s = pv_s[pages].reshape(B, V, nkv, 1).transpose(0, 2, 1, 3)
-        qq, q_s = _quant_kv(qg)
-        scores_i = jnp.einsum("bsgrh,bgkh->bgrsk", qq, vk,
-                              preferred_element_type=jnp.int32)
-        scores = (scores_i.astype(jnp.float32)
-                  * q_s[..., 0].transpose(0, 2, 3, 1)[..., None]
-                  * vk_s[..., 0][:, :, None, None, :]) / math.sqrt(hd)
-    else:
-        scores = jnp.einsum(
-            "bsgrh,bgkh->bgrsk", qg, vk,
-            preferred_element_type=jnp.float32) / math.sqrt(hd)
-    pos_kv = jnp.arange(V)
-    vis = pos_kv[None, None, :] <= apos[:, :, None]      # (B, S, V)
-    scores = jnp.where(vis[:, None, None], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    if quantized:
-        pvw = probs * vv_s[..., 0][:, :, None, None, :]
-        pvq, pv_sc = _quant_kv(pvw)
-        attn_i = jnp.einsum("bgrsk,bgkh->bsgrh", pvq, vv,
-                            preferred_element_type=jnp.int32)
-        attn = attn_i.astype(jnp.float32) \
-            * pv_sc[..., 0].transpose(0, 3, 1, 2)[..., None]
-    else:
-        attn = jnp.einsum("bgrsk,bgkh->bsgrh", probs.astype(x.dtype), vv,
-                          preferred_element_type=jnp.float32)
-    attn = attn.astype(x.dtype).reshape(B, S, nq * hd)
-    attn_out = dense(attn, layer["wo"])
-    if tp_axis:
-        attn_out = C.all_reduce(attn_out, tp_axis)
-    x = x + attn_out
+        with scope("kv_gather"):
+            vk_s = pk_s[pages].reshape(B, V, nkv, 1).transpose(0, 2, 1, 3)
+            vv_s = pv_s[pages].reshape(B, V, nkv, 1).transpose(0, 2, 1, 3)
 
-    r = T.rms_norm(x, layer["ln2"], cfg.rms_norm_eps)
-    mlp, _aux = T._mlp_block(r, layer, cfg=cfg)
-    if tp_axis:
-        mlp = C.all_reduce(mlp, tp_axis)
-    return x + mlp, (pk, pv, pk_s, pv_s)
+    with scope("attn_core"):
+        if quantized:
+            qq, q_s = _quant_kv(qg)
+            scores_i = jnp.einsum("bsgrh,bgkh->bgrsk", qq, vk,
+                                  preferred_element_type=jnp.int32)
+            scores = (scores_i.astype(jnp.float32)
+                      * q_s[..., 0].transpose(0, 2, 3, 1)[..., None]
+                      * vk_s[..., 0][:, :, None, None, :]) / math.sqrt(hd)
+        else:
+            scores = jnp.einsum(
+                "bsgrh,bgkh->bgrsk", qg, vk,
+                preferred_element_type=jnp.float32) / math.sqrt(hd)
+        pos_kv = jnp.arange(V)
+        vis = pos_kv[None, None, :] <= apos[:, :, None]      # (B, S, V)
+        scores = jnp.where(vis[:, None, None], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1)
+        if quantized:
+            pvw = probs * vv_s[..., 0][:, :, None, None, :]
+            pvq, pv_sc = _quant_kv(pvw)
+            attn_i = jnp.einsum("bgrsk,bgkh->bsgrh", pvq, vv,
+                                preferred_element_type=jnp.int32)
+            attn = attn_i.astype(jnp.float32) \
+                * pv_sc[..., 0].transpose(0, 3, 1, 2)[..., None]
+        else:
+            attn = jnp.einsum("bgrsk,bgkh->bsgrh", probs.astype(x.dtype),
+                              vv, preferred_element_type=jnp.float32)
+    return tail(attn)
 
 
 def _paged_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
@@ -242,9 +238,10 @@ def _paged_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
     """ids (B, S) → (hidden x (B, S, H), bufs') through the UNROLLED
     layer stack (static layer index into the per-layer pools, like
     ``generate._forward_cached``)."""
-    x = params["embed"].astype(cfg.dtype)[ids]
-    cos, sin = _ragged_rope_tables(apos, cfg.resolved_head_dim,
-                                   cfg.rope_theta)
+    with scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[ids]
+        cos, sin = _ragged_rope_tables(apos, cfg.resolved_head_dim,
+                                       cfg.rope_theta)
     flags = [(li + 1) % cfg.nope_interval != 0 if cfg.nope_interval
              else True for li in range(cfg.num_hidden_layers)]
     ks, vs = list(bufs.k), list(bufs.v)
@@ -302,8 +299,9 @@ def _decode_core(bufs, params, pages, toks, lengths, stop_at, active, *,
     x, bufs = _paged_forward(params, toks[:, None], cfg, bufs, pages,
                              apos, active[:, None], tp_axis=tp_axis,
                              paged_kernel=paged_kernel)
-    logits = _last_logits(params, x[:, -1:], cfg)
-    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with scope("sample"):
+        logits = _last_logits(params, x[:, -1:], cfg)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     nxt = jnp.where(active, nxt, toks)
     new_len = lengths + active.astype(jnp.int32)
     new_active = jnp.logical_and(active, new_len < stop_at)
@@ -323,10 +321,11 @@ def _prefill_core(bufs, params, pages_row, ids, pos, plen, *, cfg,
     valid = apos < plen
     x, bufs = _paged_forward(params, ids, cfg, bufs, pages_row, apos,
                              valid, tp_axis=tp_axis)
-    last = jnp.clip(plen - 1 - pos, 0, Ck - 1)
-    xl = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
-    logits = _last_logits(params, xl, cfg)
-    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with scope("sample"):
+        last = jnp.clip(plen - 1 - pos, 0, Ck - 1)
+        xl = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
+        logits = _last_logits(params, xl, cfg)
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return tok, bufs
 
 
@@ -346,10 +345,11 @@ def _prefill_batch_core(bufs, params, pages, ids, pos, plen, *, cfg,
     x, bufs = _paged_forward(params, ids, cfg, bufs, pages, apos, valid,
                              tp_axis=tp_axis,
                              flash_prefill=flash_prefill)
-    last = jnp.clip(plen - 1 - pos, 0, Ck - 1)
-    xl = jnp.take_along_axis(x, last[:, None, None], axis=1)
-    logits = _last_logits(params, xl, cfg)
-    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with scope("sample"):
+        last = jnp.clip(plen - 1 - pos, 0, Ck - 1)
+        xl = jnp.take_along_axis(x, last[:, None, None], axis=1)
+        logits = _last_logits(params, xl, cfg)
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return tok, bufs
 
 
@@ -373,8 +373,9 @@ def _spec_verify_core(bufs, params, pages, toks_blk, lengths, stop_at,
     valid = active[:, None] & (apos < stop_at[:, None])
     x, bufs = _paged_forward(params, toks_blk, cfg, bufs, pages, apos,
                              valid, tp_axis=tp_axis)
-    greedy = jnp.argmax(_all_logits(params, x, cfg),
-                        axis=-1).astype(jnp.int32)
+    with scope("sample"):
+        greedy = jnp.argmax(_all_logits(params, x, cfg),
+                            axis=-1).astype(jnp.int32)
     occ = jnp.sum(active.astype(jnp.int32))
     return greedy, bufs, occ
 
@@ -520,7 +521,20 @@ class ServingEngine:
     throughput the SLO table renders.  ``telem``: a
     ``telemetry.TelemetryRun`` to stream per-round events into
     (prefill events carry per-request TTFT, decode-burst events carry
-    occupancy/pool gauges and per-request latency at completion)."""
+    occupancy/pool gauges and per-request latency at completion).
+
+    The host loop names its own work through ``telemetry.spans.
+    maybe_span`` — profiler annotations always, ``spans.jsonl`` lines
+    too when ``telem`` carries a stream: ``serve/round`` (all of
+    :meth:`step_round`) holds ``serve/admit``, per prefill chunk
+    ``serve/prefill_stage`` (ids and pages to the device) /
+    ``serve/prefill_dispatch`` / on a final chunk ``serve/prefill_sync``
+    (the first-token read) and ``serve/bookkeep``, and per burst
+    ``serve/burst_stage`` (the five host mirrors) /
+    ``serve/burst_dispatch`` (the ``sync_every`` launches) /
+    ``serve/burst_sync`` (the read-backs) / ``serve/bookkeep``.  Each
+    carries the round number, and its ``rid`` where one request is
+    concerned."""
 
     def __init__(self, params, cfg, *, mesh=None, tp_axis: str = "tp",
                  max_batch: int = 4, page_size: int = 8,
@@ -804,7 +818,12 @@ class ServingEngine:
                       "occupancy_sum": 0, "peak_pool_util": 0.0,
                       "wall_s": 0.0, "host_sync_count": 0,
                       "draft_steps": 0, "spec_proposed": 0,
-                      "spec_accepted": 0}
+                      "spec_accepted": 0,
+                      # admission: requests seated and their summed
+                      # wait from submission (DUE) to a slot
+                      "admitted": 0, "queue_wait_s": 0.0}
+        # attributes every serve/* span of the current round carries
+        self._sp: dict = {}
 
     # ---- request intake ----------------------------------------------
     def submit(self, prompt, max_new_tokens: int,
@@ -859,6 +878,18 @@ class ServingEngine:
         return len(self.batcher.waiting) + sum(
             r is not None for r in self.batcher.slots)
 
+    # ---- spans ---------------------------------------------------------
+    @property
+    def _stream(self):
+        """The run's ``SpanStream`` (None without telemetry: the spans
+        are then profiler annotations only)."""
+        return getattr(self.telem, "spans", None)
+
+    def _req_attrs(self, req: Request) -> dict:
+        """The round's span attributes plus the one request a span is
+        about."""
+        return {**self._sp, "rid": req.rid, "trace_id": req.trace_id}
+
     # ---- device-put helpers ------------------------------------------
     def _put(self, x, device=None):
         if self.mesh is not None:
@@ -879,55 +910,64 @@ class ServingEngine:
     def _prefill_one_chunk(self, req: Request, t0: float) -> None:
         Ck = self.prefill_chunk
         pos = req.prefill_pos
-        chunk = req.prompt[pos:pos + Ck]
-        ids = np.zeros((1, Ck), np.int32)
-        ids[0, :chunk.shape[0]] = chunk
         dev = self._prefill_dev
-        if self.disaggregate:
-            row = self._padded_row(self._pre_pages[req.rid])
-            bufs = self.pool_pre.bufs
-        else:
-            row = self._padded_row(req.pages)
-            bufs = self.pool.bufs
+        stream, sp = self._stream, self._req_attrs(req)
         t_chunk = time.perf_counter()  # clock-ok
-        tok_d, bufs = self._prefill(
-            bufs, self._params_pre, self._put(row, dev),
-            self._put(ids, dev), self._put(np.int32(pos), dev),
-            self._put(np.int32(req.n_prompt), dev))
-        if self.disaggregate:
-            self.pool_pre.bufs = bufs
-        else:
-            self.pool.bufs = bufs
-        if self.spec_k:
-            # the draft needs the prompt's KV in ITS pool to propose —
-            # ride the same chunk schedule (same pages, draft params)
-            _dtok, dbufs = self._draft_prefill(
-                self.draft_pool.bufs, self._draft_params,
-                self._put(row, dev), self._put(ids, dev),
-                self._put(np.int32(pos), dev),
-                self._put(np.int32(req.n_prompt), dev))
-            self.draft_pool.bufs = dbufs
-        req.prefill_pos = min(pos + Ck, req.n_prompt)
-        self.stats["prefill_chunks"] += 1
-        if req.prefill_pos < req.n_prompt:
+        with maybe_span(stream, "serve/prefill_stage", **sp):
+            chunk = req.prompt[pos:pos + Ck]
+            ids = np.zeros((1, Ck), np.int32)
+            ids[0, :chunk.shape[0]] = chunk
+            if self.disaggregate:
+                row = self._padded_row(self._pre_pages[req.rid])
+                bufs = self.pool_pre.bufs
+            else:
+                row = self._padded_row(req.pages)
+                bufs = self.pool.bufs
+            args = (self._put(row, dev), self._put(ids, dev),
+                    self._put(np.int32(pos), dev),
+                    self._put(np.int32(req.n_prompt), dev))
+        with maybe_span(stream, "serve/prefill_dispatch", **sp):
+            tok_d, bufs = self._prefill(bufs, self._params_pre, *args)
+            if self.disaggregate:
+                self.pool_pre.bufs = bufs
+            else:
+                self.pool.bufs = bufs
+            if self.spec_k:
+                # the draft needs the prompt's KV in ITS pool to propose
+                # — ride the same chunk schedule (same pages, draft
+                # params)
+                _dtok, dbufs = self._draft_prefill(
+                    self.draft_pool.bufs, self._draft_params, *args)
+                self.draft_pool.bufs = dbufs
+            req.prefill_pos = min(pos + Ck, req.n_prompt)
+            self.stats["prefill_chunks"] += 1
+            final = req.prefill_pos >= req.n_prompt
+            if final and self.disaggregate:
+                # final chunk: hand the KV off to the decode slice
+                self._handoff(req, row)
+        if not final:
             self.stats["prefill_s"] += time.perf_counter() - t_chunk  # clock-ok
             return
-        # final chunk: hand off KV (disaggregated), resolve the first
-        # token — prefill is synchronous at admission, so this blocks
-        # the host by design and stamps TTFT at token resolution
-        if self.disaggregate:
-            dec_row = self._padded_row(req.pages)
-            blocks = self._extract(self.pool_pre.bufs,
-                                   self._put(row[0], self._prefill_dev))
-            blocks = jax.device_put(blocks, self._decode_dev)
-            self.pool.bufs = self._inject(
-                self.pool.bufs, blocks,
-                self._put(dec_row[0], self._decode_dev))
-            self.pool_pre.allocator.free(self._pre_pages.pop(req.rid))
-        first = int(np.asarray(tok_d)[0])   # sync-ok: TTFT resolution
-        self.stats["host_sync_count"] += 1
+        # resolve the first token — prefill is synchronous at admission,
+        # so this blocks the host by design and stamps TTFT at token
+        # resolution
+        with maybe_span(stream, "serve/prefill_sync", **sp):
+            first = int(np.asarray(tok_d)[0])   # sync-ok: TTFT resolution
+            self.stats["host_sync_count"] += 1
         self._finish_prefill(req, first, t_chunk, t0)
         self.stats["prefill_s"] += time.perf_counter() - t_chunk  # clock-ok
+
+    def _handoff(self, req: Request, row: np.ndarray) -> None:
+        """Disaggregated KV handoff of a request whose prefill is done:
+        its page blocks leave the prefill pool for its decode pages."""
+        dec_row = self._padded_row(req.pages)
+        blocks = self._extract(self.pool_pre.bufs,
+                               self._put(row[0], self._prefill_dev))
+        blocks = jax.device_put(blocks, self._decode_dev)
+        self.pool.bufs = self._inject(
+            self.pool.bufs, blocks,
+            self._put(dec_row[0], self._decode_dev))
+        self.pool_pre.allocator.free(self._pre_pages.pop(req.rid))
 
     def _finish_prefill(self, req: Request, first: int, t_chunk: float,
                         t0: float) -> None:
@@ -935,56 +975,52 @@ class ServingEngine:
         the prefix cache, stamp TTFT, emit telemetry, and flip the slot
         into DECODE (or retire it when ``max_new == 1``)."""
         now = time.perf_counter() - t0  # clock-ok
-        if self.prefix_cache is not None:
-            # insert at prefill COMPLETION: the request's full prompt
-            # pages hold committed KV now, so later arrivals sharing
-            # the prefix alias them.  A concurrent twin that finished
-            # first wins the trie slot — our duplicate page is freed
-            # and the page-table entry swaps to the cached twin
-            # (bitwise-identical content, invisible to decode).
-            nodes, swaps = self.prefix_cache.insert(
-                req.prompt, req.pages, req.cache_nodes)
-            req.cache_nodes = nodes
-            for i, pg in swaps.items():
-                req.pages[i] = pg
-                self._h_pages[req.slot, i] = pg
-        req.tokens.append(first)
-        req.t_first = now
-        prefill_s = time.perf_counter() - t_chunk  # clock-ok
-        spans = getattr(self.telem, "spans", None)
-        if spans is not None:
-            # t_submit/t_admit/t_first ride along (engine-clock seconds)
-            # so fleet_timeline can decompose TTFT into queue wait +
-            # prefill without re-deriving request state
-            spans.record("serve/prefill_chunk", start_perf=t_chunk,
-                         end_perf=time.perf_counter(), cat="serve",  # clock-ok
-                         rid=req.rid, n_prompt=int(req.n_prompt),
-                         request_id=req.rid, trace_id=req.trace_id,
-                         replica=self.replica,
-                         t_submit_s=req.t_submit, t_admit_s=req.t_admit,
-                         t_first_s=req.t_first)
-        if self.telem is not None:
-            self.telem.step(
-                loss=None, tokens=req.n_prompt,
-                tracker_metrics={"last_step_time_s": prefill_s},
-                phase="prefill", rid=req.rid,
-                request_id=req.rid, trace_id=req.trace_id,
-                ttft_ms=round(1e3 * (req.ttft_s or 0.0), 3),
-                pool_util=round(self.pool.utilization, 4))
-        b = req.slot
-        stop = req.n_prompt + req.max_new_tokens - 1
-        if req.n_prompt >= stop:      # max_new == 1: done at prefill
+        # t_submit/t_admit/t_first ride along (engine-clock seconds) so
+        # fleet_timeline can decompose TTFT into queue wait + prefill
+        # without re-deriving request state
+        with maybe_span(self._stream, "serve/bookkeep",
+                        n_prompt=int(req.n_prompt), request_id=req.rid,
+                        t_submit_s=req.t_submit, t_admit_s=req.t_admit,
+                        t_first_s=now, **self._req_attrs(req)):
+            if self.prefix_cache is not None:
+                # insert at prefill COMPLETION: the request's full prompt
+                # pages hold committed KV now, so later arrivals sharing
+                # the prefix alias them.  A concurrent twin that finished
+                # first wins the trie slot — our duplicate page is freed
+                # and the page-table entry swaps to the cached twin
+                # (bitwise-identical content, invisible to decode).
+                nodes, swaps = self.prefix_cache.insert(
+                    req.prompt, req.pages, req.cache_nodes)
+                req.cache_nodes = nodes
+                for i, pg in swaps.items():
+                    req.pages[i] = pg
+                    self._h_pages[req.slot, i] = pg
+            req.tokens.append(first)
+            req.t_first = now
+            if self.telem is not None:
+                self.telem.step(
+                    loss=None, tokens=req.n_prompt,
+                    tracker_metrics={
+                        "last_step_time_s":
+                            time.perf_counter() - t_chunk},  # clock-ok
+                    phase="prefill", rid=req.rid,
+                    request_id=req.rid, trace_id=req.trace_id,
+                    ttft_ms=round(1e3 * (req.ttft_s or 0.0), 3),
+                    pool_util=round(self.pool.utilization, 4))
+            b = req.slot
+            stop = req.n_prompt + req.max_new_tokens - 1
+            if req.n_prompt >= stop:      # max_new == 1: done at prefill
+                req.state = DECODE
+                self.batcher.retire(req, now)
+                self.completed.append(req)
+                self._h_active[b] = False
+                self._h_pages[b] = 0
+                return
             req.state = DECODE
-            self.batcher.retire(req, now)
-            self.completed.append(req)
-            self._h_active[b] = False
-            self._h_pages[b] = 0
-            return
-        req.state = DECODE
-        self._h_tokens[b] = first
-        self._h_lengths[b] = req.n_prompt
-        self._h_stop[b] = stop
-        self._h_active[b] = True
+            self._h_tokens[b] = first
+            self._h_lengths[b] = req.n_prompt
+            self._h_stop[b] = stop
+            self._h_active[b] = True
 
     def _prefill_batch_chunk(self, reqs: list[Request],
                              t0: float) -> None:
@@ -995,74 +1031,104 @@ class ServingEngine:
         position invalid); requests whose final chunk this is resolve
         their first token in ONE host sync."""
         B, Ck = self.max_batch, self.prefill_chunk
-        ids = np.zeros((B, Ck), np.int32)
-        pages = np.zeros((B, self.pages_per_request), np.int32)
-        pos = np.zeros(B, np.int32)
-        plen = np.zeros(B, np.int32)
-        for i, req in enumerate(reqs):
-            chunk = req.prompt[req.prefill_pos:req.prefill_pos + Ck]
-            ids[i, :chunk.shape[0]] = chunk
-            src = (self._pre_pages[req.rid] if self.disaggregate
-                   else req.pages)
-            pages[i, :len(src)] = src
-            pos[i] = req.prefill_pos
-            plen[i] = req.n_prompt
         dev = self._prefill_dev
-        bufs = self.pool_pre.bufs if self.disaggregate \
-            else self.pool.bufs
+        stream, sp = self._stream, self._sp
         t_chunk = time.perf_counter()  # clock-ok
-        tok_d, bufs = self._prefill_batch(
-            bufs, self._params_pre, self._put(pages, dev),
-            self._put(ids, dev), self._put(pos, dev),
-            self._put(plen, dev))
-        if self.disaggregate:
-            self.pool_pre.bufs = bufs
-        else:
-            self.pool.bufs = bufs
-        if self.spec_k:
-            _dt, dbufs = self._draft_prefill_batch(
-                self.draft_pool.bufs, self._draft_params,
-                self._put(pages, dev), self._put(ids, dev),
-                self._put(pos, dev), self._put(plen, dev))
-            self.draft_pool.bufs = dbufs
-        self.stats["prefill_chunks"] += 1
-        finishing = []
-        for i, req in enumerate(reqs):
-            req.prefill_pos = min(req.prefill_pos + Ck, req.n_prompt)
-            if req.prefill_pos >= req.n_prompt:
-                finishing.append((i, req))
+        with maybe_span(stream, "serve/prefill_stage", **sp):
+            ids = np.zeros((B, Ck), np.int32)
+            pages = np.zeros((B, self.pages_per_request), np.int32)
+            pos = np.zeros(B, np.int32)
+            plen = np.zeros(B, np.int32)
+            for i, req in enumerate(reqs):
+                chunk = req.prompt[req.prefill_pos:req.prefill_pos + Ck]
+                ids[i, :chunk.shape[0]] = chunk
+                src = (self._pre_pages[req.rid] if self.disaggregate
+                       else req.pages)
+                pages[i, :len(src)] = src
+                pos[i] = req.prefill_pos
+                plen[i] = req.n_prompt
+            bufs = self.pool_pre.bufs if self.disaggregate \
+                else self.pool.bufs
+            args = (self._put(pages, dev), self._put(ids, dev),
+                    self._put(pos, dev), self._put(plen, dev))
+        with maybe_span(stream, "serve/prefill_dispatch", **sp):
+            tok_d, bufs = self._prefill_batch(bufs, self._params_pre,
+                                              *args)
+            if self.disaggregate:
+                self.pool_pre.bufs = bufs
+            else:
+                self.pool.bufs = bufs
+            if self.spec_k:
+                _dt, dbufs = self._draft_prefill_batch(
+                    self.draft_pool.bufs, self._draft_params, *args)
+                self.draft_pool.bufs = dbufs
+            self.stats["prefill_chunks"] += 1
+            finishing = []
+            for i, req in enumerate(reqs):
+                req.prefill_pos = min(req.prefill_pos + Ck, req.n_prompt)
+                if req.prefill_pos >= req.n_prompt:
+                    finishing.append((i, req))
+            if self.disaggregate:
+                for i, req in finishing:
+                    self._handoff(
+                        req, self._padded_row(self._pre_pages[req.rid]))
         if not finishing:
             self.stats["prefill_s"] += time.perf_counter() - t_chunk  # clock-ok
             return
-        if self.disaggregate:
-            for i, req in finishing:
-                row = self._padded_row(self._pre_pages[req.rid])
-                dec_row = self._padded_row(req.pages)
-                blocks = self._extract(
-                    self.pool_pre.bufs,
-                    self._put(row[0], self._prefill_dev))
-                blocks = jax.device_put(blocks, self._decode_dev)
-                self.pool.bufs = self._inject(
-                    self.pool.bufs, blocks,
-                    self._put(dec_row[0], self._decode_dev))
-                self.pool_pre.allocator.free(
-                    self._pre_pages.pop(req.rid))
-        toks = np.asarray(tok_d)    # sync-ok: TTFT resolution, one
-        self.stats["host_sync_count"] += 1   # sync for all finishers
+        with maybe_span(stream, "serve/prefill_sync", **sp):
+            toks = np.asarray(tok_d)    # sync-ok: TTFT resolution, one
+            self.stats["host_sync_count"] += 1   # sync for all finishers
         for i, req in finishing:
             self._finish_prefill(req, int(toks[i]), t_chunk, t0)
         self.stats["prefill_s"] += time.perf_counter() - t_chunk  # clock-ok
 
     # ---- decode -------------------------------------------------------
+    def _stage_burst(self):
+        """Ship the host mirrors a burst starts from: tokens, lengths,
+        stop positions, active mask, page tables."""
+        with maybe_span(self._stream, "serve/burst_stage", **self._sp):
+            return (self._put(self._h_tokens), self._put(self._h_lengths),
+                    self._put(self._h_stop), self._put(self._h_active),
+                    self._put(self._h_pages))
+
+    def _sync_burst(self, arrs: list) -> list[np.ndarray]:
+        """The burst's one host sync: the pump just resolved the last
+        step's occupancy, so the burst's buffers are (near-)ready —
+        read them back.  Watchdog-guarded: a burst wedged here must
+        surface as StepTimeoutError for the fleet's failover, never a
+        silent hang."""
+        with maybe_span(self._stream, "serve/burst_sync", **self._sp):
+            if self.watchdog is not None:
+                mats = self.watchdog.block(
+                    lambda ts: [np.asarray(t) for t in ts],   # sync-ok
+                    arrs, step=self.stats["decode_steps"])
+            else:
+                mats = [np.asarray(t) for t in arrs]          # sync-ok
+            self.stats["host_sync_count"] += 1
+        return mats
+
+    def _retire_burst(self, active, lengths, t0: float) -> list[Request]:
+        """Install the replayed ``active``/``lengths`` chain as the host
+        mirrors and retire every DECODE slot the burst finished."""
+        self._h_lengths = lengths
+        self._h_active = active
+        now = time.perf_counter() - t0  # clock-ok
+        finished = []
+        for b in range(self.max_batch):
+            req = self.batcher.slot_request(b)
+            if req is not None and req.state == DECODE and not active[b]:
+                self.batcher.retire(req, now)
+                self._h_pages[b] = 0     # slot back to the null page
+                self.completed.append(req)
+                finished.append(req)
+        return finished
+
     def _decode_burst(self, pump, t0: float) -> None:
         sync = self.sync_every
+        stream, sp = self._stream, self._sp
         L0 = self._h_lengths.copy()
         A0 = self._h_active.copy()
-        toks_d = self._put(self._h_tokens)
-        len_d = self._put(self._h_lengths)
-        stop_d = self._put(self._h_stop)
-        act_d = self._put(self._h_active)
-        pages_d = self._put(self._h_pages)
+        toks_d, len_d, stop_d, act_d, pages_d = self._stage_burst()
         bufs = self.pool.bufs
         if self.telem is not None:
             # ledger join (no-op unless the run owns an enabled
@@ -1075,57 +1141,33 @@ class ServingEngine:
                                               "params": self._params},
                                        prediction=self._mem_prediction)
         t_burst = time.perf_counter()  # clock-ok
-        step_tokens = []
-        for _ in range(sync):
-            toks_d, len_d, act_d, bufs, occ = self._decode(
-                bufs, self._params, pages_d, toks_d, len_d, stop_d,
-                act_d)
-            pump.emit(occ)
-            step_tokens.append(toks_d)
-        self.pool.bufs = bufs
-        self.stats["decode_steps"] += sync
-        # sync point: the pump just resolved the last step's occupancy,
-        # so the burst's token buffers are (near-)ready — resolve and
-        # replay the device's deterministic active chain on the host.
-        # Watchdog-guarded: a burst wedged here must surface as
-        # StepTimeoutError for the fleet's failover, never a silent hang
-        if self.watchdog is not None:
-            mats = self.watchdog.block(
-                lambda ts: [np.asarray(t) for t in ts],   # sync-ok
-                step_tokens, step=self.stats["decode_steps"])
-        else:
-            mats = [np.asarray(t) for t in step_tokens]   # sync-ok
-        self.stats["host_sync_count"] += 1
+        with maybe_span(stream, "serve/burst_dispatch", steps=sync, **sp):
+            step_tokens = []
+            for _ in range(sync):
+                toks_d, len_d, act_d, bufs, occ = self._decode(
+                    bufs, self._params, pages_d, toks_d, len_d, stop_d,
+                    act_d)
+                pump.emit(occ)
+                step_tokens.append(toks_d)
+            self.pool.bufs = bufs
+            self.stats["decode_steps"] += sync
+        mats = self._sync_burst(step_tokens)
         burst_s = time.perf_counter() - t_burst  # clock-ok
         self.stats["decode_s"] += burst_s
-        spans = getattr(self.telem, "spans", None)
-        if spans is not None:
-            spans.record("serve/decode_burst", start_perf=t_burst,
-                         end_perf=time.perf_counter(), cat="serve",  # clock-ok
-                         steps=int(sync), replica=self.replica)
         t_book = time.perf_counter()  # clock-ok
-        active, lengths = A0.copy(), L0.copy()
-        occ_burst, emitted = [], 0
-        for j in range(sync):
-            occ_burst.append(int(active.sum()))
-            for b in np.nonzero(active)[0]:
-                self.batcher.slot_request(int(b)).tokens.append(
-                    int(mats[j][b]))
-                emitted += 1
-            lengths = lengths + active
-            active = active & (lengths < self._h_stop)
-        self._h_tokens = mats[-1].copy()
-        self._h_lengths = lengths
-        self._h_active = active
-        now = time.perf_counter() - t0  # clock-ok
-        finished = []
-        for b in range(self.max_batch):
-            req = self.batcher.slot_request(b)
-            if req is not None and req.state == DECODE and not active[b]:
-                self.batcher.retire(req, now)
-                self._h_pages[b] = 0     # slot back to the null page
-                self.completed.append(req)
-                finished.append(req)
+        with maybe_span(stream, "serve/bookkeep", **sp):
+            active, lengths = A0.copy(), L0.copy()
+            occ_burst, emitted = [], 0
+            for j in range(sync):
+                occ_burst.append(int(active.sum()))
+                for b in np.nonzero(active)[0]:
+                    self.batcher.slot_request(int(b)).tokens.append(
+                        int(mats[j][b]))
+                    emitted += 1
+                lengths = lengths + active
+                active = active & (lengths < self._h_stop)
+            self._h_tokens = mats[-1].copy()
+            finished = self._retire_burst(active, lengths, t0)
         self.stats["bookkeep_s"] += time.perf_counter() - t_book  # clock-ok
         if self.telem is not None:
             self.telem.step(
@@ -1158,13 +1200,10 @@ class ServingEngine:
         macro-step's scatter overwrites them before any read — in both
         the target and the draft pool."""
         sync, k = self.sync_every, self.spec_k
+        stream, sp = self._stream, self._sp
         L0 = self._h_lengths.copy()
         A0 = self._h_active.copy()
-        toks_d = self._put(self._h_tokens)
-        len_d = self._put(self._h_lengths)
-        stop_d = self._put(self._h_stop)
-        act_d = self._put(self._h_active)
-        pages_d = self._put(self._h_pages)
+        toks_d, len_d, stop_d, act_d, pages_d = self._stage_burst()
         bufs = self.pool.bufs
         dbufs = self.draft_pool.bufs
         if self.telem is not None:
@@ -1177,79 +1216,64 @@ class ServingEngine:
                                               "params": self._params},
                                        prediction=self._mem_prediction)
         t_burst = time.perf_counter()  # clock-ok
-        g_steps, e_steps = [], []
-        for _ in range(sync):
-            # k draft self-decode steps propose a token chain per slot;
-            # the draft runs against ITS pool at the same page table,
-            # with the same stop_at so it can never write past a grant
-            d_toks, d_len, d_act = toks_d, len_d, act_d
-            props = [toks_d]
-            for _i in range(k):
-                d_toks, d_len, d_act, dbufs, _docc = self._draft_decode(
-                    dbufs, self._draft_params, pages_d, d_toks, d_len,
-                    stop_d, d_act)
-                props.append(d_toks)
-            blk = jnp.stack(props, axis=1)          # (B, k+1)
-            g_d, bufs, occ = self._verify(bufs, self._params, pages_d,
-                                          blk, len_d, stop_d, act_d)
-            pump.emit(occ)
-            toks_d, len_d, act_d, e_d = self._accept(
-                blk, g_d, toks_d, len_d, stop_d, act_d)
-            g_steps.append(g_d)
-            e_steps.append(e_d)
-        self.pool.bufs = bufs
-        self.draft_pool.bufs = dbufs
-        self.stats["decode_steps"] += sync
-        self.stats["draft_steps"] += sync * k
-        arrs = g_steps + e_steps + [toks_d]
-        if self.watchdog is not None:
-            mats = self.watchdog.block(
-                lambda ts: [np.asarray(t) for t in ts],   # sync-ok
-                arrs, step=self.stats["decode_steps"])
-        else:
-            mats = [np.asarray(t) for t in arrs]          # sync-ok
-        self.stats["host_sync_count"] += 1
+        with maybe_span(stream, "serve/burst_dispatch", steps=sync, k=k,
+                        **sp):
+            g_steps, e_steps = [], []
+            for _ in range(sync):
+                # k draft self-decode steps propose a token chain per
+                # slot; the draft runs against ITS pool at the same page
+                # table, with the same stop_at so it can never write
+                # past a grant
+                d_toks, d_len, d_act = toks_d, len_d, act_d
+                props = [toks_d]
+                for _i in range(k):
+                    d_toks, d_len, d_act, dbufs, _docc = \
+                        self._draft_decode(
+                            dbufs, self._draft_params, pages_d, d_toks,
+                            d_len, stop_d, d_act)
+                    props.append(d_toks)
+                blk = jnp.stack(props, axis=1)          # (B, k+1)
+                g_d, bufs, occ = self._verify(
+                    bufs, self._params, pages_d, blk, len_d, stop_d,
+                    act_d)
+                pump.emit(occ)
+                toks_d, len_d, act_d, e_d = self._accept(
+                    blk, g_d, toks_d, len_d, stop_d, act_d)
+                g_steps.append(g_d)
+                e_steps.append(e_d)
+            self.pool.bufs = bufs
+            self.draft_pool.bufs = dbufs
+            self.stats["decode_steps"] += sync
+            self.stats["draft_steps"] += sync * k
+        mats = self._sync_burst(g_steps + e_steps + [toks_d])
         gs, es = mats[:sync], mats[sync:2 * sync]
         burst_s = time.perf_counter() - t_burst  # clock-ok
         self.stats["decode_s"] += burst_s
-        spans = getattr(self.telem, "spans", None)
-        if spans is not None:
-            spans.record("serve/spec_burst", start_perf=t_burst,
-                         end_perf=time.perf_counter(), cat="serve",  # clock-ok
-                         steps=int(sync), k=int(k),
-                         replica=self.replica)
         t_book = time.perf_counter()  # clock-ok
-        active, lengths = A0.copy(), L0.copy()
-        occ_burst, emitted = [], 0
-        proposed = accepted = 0
-        for j in range(sync):
-            occ_burst.append(int(active.sum()))
-            for b in np.nonzero(active)[0]:
-                e_b = int(es[j][b])
-                self.batcher.slot_request(int(b)).tokens.extend(
-                    int(t) for t in gs[j][b, :e_b])
-                emitted += e_b
-                proposed += k
-                accepted += e_b - 1
-            lengths = lengths + es[j]
-            active = active & (lengths < self._h_stop)
-        self.stats["spec_proposed"] += proposed
-        self.stats["spec_accepted"] += accepted
-        from ..telemetry.metrics import maybe_inc
-        maybe_inc(self.batcher.metrics, "spec_proposed_total", proposed)
-        maybe_inc(self.batcher.metrics, "spec_accepted_total", accepted)
-        self._h_tokens = mats[-1].copy()
-        self._h_lengths = lengths
-        self._h_active = active
-        now = time.perf_counter() - t0  # clock-ok
-        finished = []
-        for b in range(self.max_batch):
-            req = self.batcher.slot_request(b)
-            if req is not None and req.state == DECODE and not active[b]:
-                self.batcher.retire(req, now)
-                self._h_pages[b] = 0     # slot back to the null page
-                self.completed.append(req)
-                finished.append(req)
+        with maybe_span(stream, "serve/bookkeep", **sp):
+            active, lengths = A0.copy(), L0.copy()
+            occ_burst, emitted = [], 0
+            proposed = accepted = 0
+            for j in range(sync):
+                occ_burst.append(int(active.sum()))
+                for b in np.nonzero(active)[0]:
+                    e_b = int(es[j][b])
+                    self.batcher.slot_request(int(b)).tokens.extend(
+                        int(t) for t in gs[j][b, :e_b])
+                    emitted += e_b
+                    proposed += k
+                    accepted += e_b - 1
+                lengths = lengths + es[j]
+                active = active & (lengths < self._h_stop)
+            self.stats["spec_proposed"] += proposed
+            self.stats["spec_accepted"] += accepted
+            from ..telemetry.metrics import maybe_inc
+            maybe_inc(self.batcher.metrics, "spec_proposed_total",
+                      proposed)
+            maybe_inc(self.batcher.metrics, "spec_accepted_total",
+                      accepted)
+            self._h_tokens = mats[-1].copy()
+            finished = self._retire_burst(active, lengths, t0)
         self.stats["bookkeep_s"] += time.perf_counter() - t_book  # clock-ok
         if self.telem is not None:
             self.telem.step(
@@ -1307,56 +1331,61 @@ class ServingEngine:
         :class:`~..resilience.elastic.StepTimeoutError` propagates from
         the burst's watchdog-guarded sync points."""
         self.start()
-        t0 = self._t0
-        done_base = len(self.completed)
-        t_admit = time.perf_counter()  # clock-ok
-        admitted = self.batcher.admit(now)
-        for req in admitted:
-            # install the slot's page-table row in the host
-            # mirror the decode burst ships (unused entries
-            # point at the null page)
-            self._h_pages[req.slot] = 0
-            self._h_pages[req.slot, :len(req.pages)] = req.pages
-            if self.disaggregate:
-                n = -(-req.n_prompt // self.page_size)
-                pre = self.pool_pre.allocator.alloc(n)
-                if pre is None:
-                    raise RuntimeError(
-                        "prefill pool exhausted — it is sized "
-                        "like the decode pool, so this is a "
-                        "leak, not load")
-                self._pre_pages[req.rid] = pre
-        self.stats["admit_s"] += time.perf_counter() - t_admit  # clock-ok
-        if self.flash_prefill:
-            # batched multi-request prefill: all PREFILL residents
-            # advance together, one fixed-shape step per chunk round
-            for _ in range(self.prefill_chunks_per_round):
-                reqs = sorted(
-                    (r for r in self.batcher.slots
-                     if r is not None and r.state == PREFILL),
-                    key=lambda r: r.t_admit)
-                if not reqs:
-                    break
-                self._prefill_batch_chunk(reqs, t0)
-        else:
-            for _ in range(self.prefill_chunks_per_round):
-                req = self.batcher.next_prefill()
-                if req is None:
-                    break
-                self._prefill_one_chunk(req, t0)
-        if self._h_active.any():
-            if self.spec_k:
-                self._spec_burst(self._pump, t0)
+        self._sp = {"round": self.stats["rounds"], "replica": self.replica}
+        with maybe_span(self._stream, "serve/round", **self._sp):
+            t0 = self._t0
+            done_base = len(self.completed)
+            t_admit = time.perf_counter()  # clock-ok
+            with maybe_span(self._stream, "serve/admit", **self._sp):
+                admitted = self.batcher.admit(now)
+                for req in admitted:
+                    # install the slot's page-table row in the host
+                    # mirror the decode burst ships (unused entries
+                    # point at the null page)
+                    self._h_pages[req.slot] = 0
+                    self._h_pages[req.slot, :len(req.pages)] = req.pages
+                    self.stats["queue_wait_s"] += req.t_admit - req.t_submit
+                    if self.disaggregate:
+                        n = -(-req.n_prompt // self.page_size)
+                        pre = self.pool_pre.allocator.alloc(n)
+                        if pre is None:
+                            raise RuntimeError(
+                                "prefill pool exhausted — it is sized "
+                                "like the decode pool, so this is a "
+                                "leak, not load")
+                        self._pre_pages[req.rid] = pre
+                self.stats["admitted"] += len(admitted)
+            self.stats["admit_s"] += time.perf_counter() - t_admit  # clock-ok
+            if self.flash_prefill:
+                # batched multi-request prefill: all PREFILL residents
+                # advance together, one fixed-shape step per chunk round
+                for _ in range(self.prefill_chunks_per_round):
+                    reqs = sorted(
+                        (r for r in self.batcher.slots
+                         if r is not None and r.state == PREFILL),
+                        key=lambda r: r.t_admit)
+                    if not reqs:
+                        break
+                    self._prefill_batch_chunk(reqs, t0)
             else:
-                self._decode_burst(self._pump, t0)
-        self.stats["rounds"] += 1
-        self.stats["occupancy_sum"] += int(self._h_active.sum())
-        self.stats["peak_pool_util"] = max(
-            self.stats["peak_pool_util"], self.pool.utilization)
-        if self._warm_sizes is None \
-                and self.stats["decode_steps"] > 0:
-            self._warm_sizes = self._jit_sizes()
-        return self.completed[done_base:]
+                for _ in range(self.prefill_chunks_per_round):
+                    req = self.batcher.next_prefill()
+                    if req is None:
+                        break
+                    self._prefill_one_chunk(req, t0)
+            if self._h_active.any():
+                if self.spec_k:
+                    self._spec_burst(self._pump, t0)
+                else:
+                    self._decode_burst(self._pump, t0)
+            self.stats["rounds"] += 1
+            self.stats["occupancy_sum"] += int(self._h_active.sum())
+            self.stats["peak_pool_util"] = max(
+                self.stats["peak_pool_util"], self.pool.utilization)
+            if self._warm_sizes is None \
+                    and self.stats["decode_steps"] > 0:
+                self._warm_sizes = self._jit_sizes()
+            return self.completed[done_base:]
 
     def run(self) -> list[Request]:
         def vt(r):

@@ -152,6 +152,11 @@ def phase_for_span(name: str, cat: str | None = None) -> str | None:
         return "prefetch"
     if cat == "checkpoint" or name.startswith("checkpoint"):
         return "checkpoint"
+    if name.startswith("serve/"):
+        # the engine's round: one allocator read per device sync, not
+        # one per span
+        return {"serve/prefill_sync": "prefill",
+                "serve/burst_sync": "decode"}.get(name)
     if "prefill" in name:
         return "prefill"
     if "decode" in name:

@@ -1,12 +1,23 @@
-"""Host-side phase spans: the fourth telemetry artifact.
+"""Host-side phase spans: one call, two sinks.
 
 ``steps.jsonl`` says what each optimizer step cost; it cannot say *where
 the host spent the gaps* — blocked on the prefetch queue, barriered at a
 pump sync point, throttled on in-flight backpressure, inside an Orbax
-checkpoint write, or driving a serving prefill/decode burst.  Each of
-those sites records a :func:`maybe_span` here, appended to
-``spans.jsonl`` in the run dir, and ``scripts/export_timeline.py`` merges
-them with the device trace into one chrome-trace/Perfetto timeline.
+checkpoint write, or staging, launching and reading back a serving round.
+Each of those sites opens a :func:`maybe_span`, the program's one span
+primitive.  It writes to two sinks:
+
+  * **the profiler** — always: a ``jax.profiler.TraceAnnotation(name,
+    **attrs)``, so whenever a profiler session is active the span lands in
+    its trace on the device events' own clock (no session: one flag check).
+    No ``TelemetryRun`` is needed for that; ``pump/*``, ``prefetch/*``,
+    ``checkpoint/save`` and ``serve/*`` show in any ``jax.profiler`` trace,
+    and ``benchmarks/layer_metrics/_scopes.py`` attributes device idle
+    gaps to them.
+  * **``spans.jsonl``** — when a :class:`SpanStream` is wired (a
+    ``TelemetryRun`` does that): the span is also appended to the run dir,
+    and ``scripts/export_timeline.py`` merges the file with the device
+    trace into one chrome-trace/Perfetto timeline.
 
 Schema (one JSON line per span, ``schema.span_event``):
 
@@ -37,6 +48,8 @@ import json
 import os
 import threading
 import time
+
+import jax
 
 from .schema import span_event
 
@@ -85,9 +98,8 @@ class SpanStream:
 
     def record(self, name: str, *, start_perf: float, end_perf: float,
                cat: str | None = None, **attrs) -> None:
-        """File one completed span given its ``perf_counter`` bounds —
-        the form for call sites that already stopwatch themselves (the
-        serving engine's burst timers)."""
+        """File one completed span given its ``perf_counter`` bounds (the
+        file sink only: a span that is over cannot be annotated)."""
         ts = self._epoch_us + (start_perf - self._perf_anchor) * 1e6
         self._append(span_event(name, ts_us=ts,
                                 dur_us=(end_perf - start_perf) * 1e6,
@@ -95,11 +107,13 @@ class SpanStream:
 
     @contextlib.contextmanager
     def span(self, name: str, cat: str | None = None, **attrs):
-        """Context-manager form: times the body, files on exit (also on
-        exception — a crashed wait still shows in the timeline)."""
+        """Context-manager form: opens the profiler annotation, times the
+        body, files on exit (also on exception — a crashed wait still
+        shows in the timeline)."""
         t0 = time.perf_counter()
         try:
-            yield
+            with _annotation(name, attrs):
+                yield
         finally:
             self.record(name, start_perf=t0,
                         end_perf=time.perf_counter(), cat=cat, **attrs)
@@ -161,17 +175,22 @@ class SpanStream:
                 self._f = None
 
 
-@contextlib.contextmanager
+def _annotation(name: str, attrs: dict):
+    """The profiler sink: attributes become the event's stats (``None``
+    values are left out)."""
+    return jax.profiler.TraceAnnotation(
+        name, **{k: v for k, v in attrs.items() if v is not None})
+
+
 def maybe_span(stream, name: str, cat: str | None = None, **attrs):
-    """``stream.span(...)`` when a stream is wired, no-op when ``stream``
-    is None — the guard every runtime call site uses so spans never
-    impose a telemetry dependency."""
+    """The span primitive every call site uses: a profiler annotation
+    always, and ``stream.span(...)`` on top when a stream is wired
+    (``stream`` None: no file is touched) — so spans never impose a
+    telemetry dependency."""
     if stream is None:
-        yield
-        return
+        return _annotation(name, attrs)
     # forwarder: the caller's literal passes through (lint checks THEM)
-    with stream.span(name, cat=cat, **attrs):   # span-ok
-        yield
+    return stream.span(name, cat=cat, **attrs)   # span-ok
 
 
 def read_clock_anchor(run_dir: str) -> dict | None:

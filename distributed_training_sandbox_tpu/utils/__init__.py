@@ -25,6 +25,6 @@ from .memory import (  # noqa: F401
 )
 from .tracker import PerformanceTracker  # noqa: F401
 from .flops import get_model_flops_per_token  # noqa: F401
-from .profiling import ProfileSchedule, Profiler, annotate, scope  # noqa: F401
+from .profiling import SCOPES, ProfileSchedule, Profiler, scope  # noqa: F401
 from .config import TrainConfig, build_argparser, build_run_id  # noqa: F401
 from . import checkpoint  # noqa: F401  (orbax imported lazily inside)

@@ -8,13 +8,14 @@ marking phases with ``record_function``.  The TPU twin drives
 ``jax.profiler.start_trace / stop_trace`` from the same schedule state machine
 (warmup steps are traced too — they are how you *see* warmup in the timeline),
 writes TensorBoard/perfetto-compatible traces into the same ``TRACE_DIR``
-contract, and marks phases with ``jax.profiler.TraceAnnotation`` (host span) +
-``jax.named_scope`` (device-side op names).
+contract.  Phases are marked in two places: host spans through
+``telemetry.spans.maybe_span`` (a ``jax.profiler.TraceAnnotation``, so they
+share the device events' clock) and device ops through :func:`scope`
+(``jax.named_scope``) with the names of :data:`SCOPES`.
 """
 
 from __future__ import annotations
 
-import contextlib
 import os
 from dataclasses import dataclass
 
@@ -122,16 +123,37 @@ class Profiler:
             self._record_owned()
 
 
-@contextlib.contextmanager
-def annotate(name: str):
-    """Host-side phase marker, twin of ``record_function`` phase labels
-    ("data_movement", "forward", "sync_grads", "opt_step", … —
-    ``DDP/ddp.py:158-170``).  Shows as a span in the profiler timeline."""
-    with jax.profiler.TraceAnnotation(name):
-        yield
+#: The closed catalogue of device scope names a trace reader attributes op
+#: time to.  An op's ``op_name`` metadata is the path of the scopes open
+#: when it was traced (``jit(step)/forward_backward/transpose(jvp(mlp))/
+#: dot_general``); a reader takes the INNERMOST catalogue name anywhere in
+#: that path, so the backward pass and the remat re-run of a layer count
+#: under the layer's own names.  ``benchmarks/layer_metrics/_scopes.py``
+#: holds a copy, pinned to this tuple by a test.  Containers that only group
+#: other scopes (``forward_backward``) are deliberately not in it: what runs
+#: under them and under no name below is the tracing's own blind spot.
+SCOPES = (
+    # the shared model (models/transformer.py), training and serving alike
+    "embed",       # token lookup and the RoPE tables
+    "attn_qkv",    # pre-attention norm, q/k/v projections, RoPE
+    "attn_core",   # splash / XLA / ring attention; the engine's scores,
+                   # softmax and PV over the gathered view
+    "attn_out",    # output projection
+    "mlp",         # pre-MLP norm + SwiGLU, or the MoE block (its moe_* scopes)
+    "loss_head",   # final norm, unembedding, (streamed) cross-entropy
+    # the serving engine's programs (serving/engine.py)
+    "kv_write",    # scatter of the new K/V rows into their pages
+    "kv_gather",   # gather of a slot's pages into the contiguous view
+    "sample",      # final norm + unembedding at one position + argmax
+    # the FSDP strategy step (parallel/fsdp.py)
+    "fsdp_layer_gather", "fsdp_root_gather", "fsdp_pre_gather_layers",
+    "loss_mean", "grad_mean",
+    "opt_step",    # the optimizer update
+)
 
 
 def scope(name: str):
     """Device-side marker for code *inside* jit: prefixes XLA op names so
-    collectives/matmuls attribute to the phase in the trace."""
+    collectives/matmuls attribute to the phase in the trace.  Writes
+    ``op_name`` metadata only: the compiled program does not change."""
     return jax.named_scope(name)

@@ -10,8 +10,8 @@ plus the two reports single-run tooling cannot produce:
     peers arrive early and eat the lag — so blame lands on the last
     arrival, not the longest wait.
   * **request swimlanes** — serving spans carrying a ``trace_id`` are
-    grouped per request onto their own named tracks, and the final
-    prefill span's stamped ``t_submit/t_admit/t_first`` yield a TTFT
+    grouped per request onto their own named tracks, and the
+    ``t_submit/t_admit/t_first`` stamped where a prefill ends yield a TTFT
     decomposition (queue wait + prefill) per request, counting a
     failover replay ONCE (the last completed attempt wins) while still
     listing every replica the trace touched.
@@ -171,16 +171,17 @@ def straggler_report(streams: list[dict]) -> dict:
 # ---------------------------------------------------------------- requests
 
 def request_report(streams: list[dict]) -> list[dict]:
-    """Per-request TTFT decomposition from prefill spans carrying a
-    ``trace_id``.  A failover replay leaves prefill spans on >= 2
-    replicas under ONE trace_id; only the LAST attempt (the one that
-    reached first-token) is decomposed — the replay counts once — but
-    every replica the trace touched is listed, as is the attempt
-    count."""
+    """Per-request TTFT decomposition from the spans that stamp a
+    request's first token: the engine's ``serve/bookkeep`` span of a
+    finished prefill carries ``trace_id`` and ``t_first_s``.  A failover
+    replay leaves such spans on >= 2 replicas under ONE trace_id; only
+    the LAST attempt (the one that reached first-token) is decomposed —
+    the replay counts once — but every replica the trace touched is
+    listed, as is the attempt count."""
     by_tid: dict[str, list[dict]] = {}
     for st in streams:
         for sp in st["spans"]:
-            if sp.get("name") != "serve/prefill_chunk" \
+            if sp.get("t_first_s") is None \
                     or sp.get("trace_id") is None:
                 continue
             by_tid.setdefault(str(sp["trace_id"]), []).append(sp)

@@ -52,8 +52,13 @@ def test_phase_for_span_vocabulary():
     assert phase_for_span("prefetch/wait", "prefetch") == "prefetch"
     assert phase_for_span("prefetch/next", None) == "prefetch"
     assert phase_for_span("checkpoint/save", "checkpoint") == "checkpoint"
-    assert phase_for_span("serve/prefill", None) == "prefill"
-    assert phase_for_span("serve/decode_burst", None) == "decode"
+    assert phase_for_span("prefill/chunk", None) == "prefill"
+    assert phase_for_span("decode_burst", None) == "decode"
+    # the engine's round samples once per device sync, not per span
+    assert phase_for_span("serve/prefill_sync", None) == "prefill"
+    assert phase_for_span("serve/burst_sync", None) == "decode"
+    assert phase_for_span("serve/prefill_stage", None) is None
+    assert phase_for_span("serve/bookkeep", None) is None
     assert phase_for_span("pump/sync_every", "pump") == "sync"
     assert phase_for_span("pump/drain", "pump") == "sync"
     assert phase_for_span("pump/dispatch", "pump") == "dispatch"

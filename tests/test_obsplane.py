@@ -304,8 +304,11 @@ def test_failover_trace_join_and_ttft_decomposition(tmp_path):
     assert all(tid == f"tr-{r.rid:06d}" for tid, r in by_tid.items())
 
     spans = read_spans(t.run_dir)
-    prefills = [s for s in spans if s["name"] == "serve/prefill_chunk"]
-    assert all("trace_id" in s and "replica" in s for s in prefills)
+    # the span that ends a request's prefill stamps its first token
+    prefills = [s for s in spans if s.get("t_first_s") is not None]
+    assert prefills and all(s["name"] == "serve/bookkeep"
+                            and "trace_id" in s and "replica" in s
+                            for s in prefills)
     replicas_of = {}
     for s in prefills:
         replicas_of.setdefault(s["trace_id"], set()).add(s["replica"])
@@ -341,7 +344,7 @@ def test_failover_trace_join_and_ttft_decomposition(tmp_path):
     for tid in replayed:
         reps = {e["args"].get("replica") for e in req_events
                 if e["args"]["trace_id"] == tid
-                and e["name"] == "serve/prefill_chunk"}
+                and e["name"] == "serve/prefill_sync"}
         assert reps == {0, 1}
     assert doc["metadata"]["requests"]
     # and the steps.jsonl serving rows carry the optional tracing fields
