@@ -394,8 +394,11 @@ def kernels_phase(cfg, seq: int) -> None:
 
     on_tpu = jax.default_backend() == "tpu"
     how = "compiled" if on_tpu else "interpreted on cpu"
-    default_path = {"splash attention"} if cfg.attention_impl == "flash" \
-        else set()
+    # the engine's decode program takes the paged kernel by default on a
+    # TPU at the smoke's pool geometry (ServingEngine.paged_kernel=None)
+    default_path = {"paged decode"}
+    if cfg.attention_impl == "flash":
+        default_path.add("splash attention")
     # every output is bf16 (or f32 from bf16 probabilities): agreement to
     # 2^-6 of the reference's largest entry is two bf16 ulps there, and a
     # wrong mask, scale or page lands far outside it

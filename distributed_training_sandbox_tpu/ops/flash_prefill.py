@@ -30,8 +30,14 @@ Float pools only: the int8 pool's per-row scale folding does not
 commute with the online rescale, and prefill is the bandwidth-bound
 leg where bf16 pools are the default anyway.
 
-CPU tier only: the design shares ``paged_attention.py``'s page loads and
-does not lower on a TPU (``paged_attention.refuse_on_tpu``).
+CPU tier only, still fenced (``paged_attention.refuse_on_tpu``): the
+design shares the page loads of ``paged_attention.py``'s int8 kernel
+(``_gather_pool``: the page id read from a vector-memory ref, the view
+assembled with ``dynamic_update_slice``, the whole pool one block) and
+does not lower on a TPU.  The float DECODE kernel there was rewritten
+for the hardware (scalar-prefetched table, per-page DMAs); this kernel
+is the next one to follow it (ROADMAP S3), and until then prefill on
+the chip is the gather path.
 """
 
 from __future__ import annotations

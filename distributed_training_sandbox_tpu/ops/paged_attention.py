@@ -1,45 +1,64 @@
-"""Paged-attention decode kernel (Pallas).
+"""Paged-attention decode kernels (Pallas).
 
 ``serving/engine._paged_layer_body`` attends against the paged KV pool
 by first gathering every slot's pages into a contiguous
 ``(B, V, n_kv, hd)`` HBM view (``pk[pages]``) and then contracting over
 it.  That gather is pure data movement: for a decode step (S == 1) it
 re-materializes the entire visible KV window per layer per token just
-to feed one matvec-sized contraction.
+to feed one matvec-sized contraction, whatever the slots hold.
 
-This kernel reads the pages IN PLACE instead: the page table row rides
-into the kernel, and each page is dynamically loaded from the pool ref
-straight into kernel-local (VMEM-resident on TPU) storage — the
-``(B, V, n_kv, hd)`` intermediate never exists at the XLA level, so HBM
-traffic drops from (gather-write + gather-read) to a single pool read.
-The attention math on the in-kernel view is the exact op sequence of
-``_paged_layer_body`` — same einsum specs, mask constant, softmax axis,
-probs cast, and (for int8 pools) the same quantize/scale-fold ordering
-with the per-page scales folded in-kernel — so the kernel output equals
-the reference path on matched inputs: bitwise for int8 pools (integer
-accumulation), to float32 summation order for float pools (asserted in
-tests/test_kernels.py on the CPU interpret tier).
+The kernels here read the pages IN PLACE instead; the
+``(B, V, n_kv, hd)`` intermediate never exists at the XLA level.
 
-CPU tier only.  The design does not lower on a TPU (:func:`refuse_on_tpu`
-carries what Pallas said on a v5e): the whole pool is one block, the page
-id is read from a vector-memory ref and the view is assembled with
-``dynamic_update_slice``.  The hardware kernel keeps the page table in
-SMEM (scalar prefetch) and DMAs pages — ROADMAP S3.
+**Float pools: the hardware kernel** (:func:`_decode_kernel`, the
+engine's default decode path on a TPU; the design of
+``jax.experimental.pallas.ops.tpu.paged_attention``, adapted to this
+repo's page-major pool).  The page table and the per-slot lengths ride
+in by scalar prefetch (SMEM); the pools stay in HBM; one grid step per
+slot copies that slot's pages to VMEM by async DMA in double-buffered
+blocks of ``PAGES_PER_BLOCK`` pages and runs an online softmax over the
+blocks, in a loop bounded by the slot's own length: a slot of length 0
+starts no copy and emits zeros, and no block past a slot's length is
+read.  One page of the pool is one contiguous
+``(page_size * n_kv, hd)`` slab that carries every KV head, so it is one
+copy; the kernel contracts the slot's ``n_kv * rep`` query rows against
+ALL the slab's rows on the MXU and masks the columns of the other KV
+heads together with the positions past the length (no strided access,
+every operand natively tiled; the MXU streams the same K and V rows
+either way).  Scores are float32 from the pool-dtype operands,
+probabilities are cast to ``probs_dtype`` before the ``p @ V`` product,
+which accumulates in float32: the gather path's precision.  The online
+softmax changes the summation order, so the output equals the gather
+path to float32 summation order, not bitwise (tests/test_kernels.py,
+interpret mode on the CPU; chip_smoke.py on the chip).
+:func:`decode_kernel_takes` states which shapes it takes.
+
+**int8 pools: CPU tier only** (:func:`_decode_kernel_q8`; bitwise equal
+to the quantized gather path, integer accumulation).  Its design does
+not lower on a TPU (:func:`refuse_on_tpu` carries what Pallas said on a
+v5e): the whole pool is one block, the page id is read from a
+vector-memory ref and the view is assembled with
+``dynamic_update_slice``.  ``ops/flash_prefill.py`` shares that design
+and that fence.  Rewriting both for the hardware is ROADMAP S3.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["paged_attention_decode", "refuse_on_tpu"]
+__all__ = ["paged_attention_decode", "decode_kernel_takes",
+           "refuse_on_tpu"]
 
-# What Pallas' TPU lowering says to both serving kernels (jax 0.9.0,
-# libtpu 0.0.34, TPU v5 lite; ``.lower(lowering_platforms=("tpu",))``
-# reproduces it without a chip).
+# What Pallas' TPU lowering says to the int8 decode kernel and to the
+# flash-prefill kernel (jax 0.9.0, libtpu 0.0.34, TPU v5 lite;
+# ``.lower(lowering_platforms=("tpu",))`` reproduces it without a chip).
 TPU_REFUSAL = (
     "\"The Pallas TPU lowering currently requires that the last two "
     "dimensions of your block shape are divisible by 8 and 128 "
@@ -77,23 +96,101 @@ def _gather_pool(pool_ref, pages_ref, n_slot_pages: int, page: int):
     return jax.lax.fori_loop(0, n_slot_pages, load, acc0)
 
 
-def _decode_kernel(pages_ref, q_ref, apos_ref, pk_ref, pv_ref, o_ref, *,
-                   n_slot_pages: int, probs_dtype):
-    """Float pool: mirror of the non-quantized `_paged_layer_body`
-    attention core for one batch slot (S == 1)."""
-    page = pk_ref.shape[1]
+# Pages per DMA block of the float kernel: 16 pages of 16 tokens are 256
+# positions a block, 1,024 score columns with 4 KV heads, 1 MB of VMEM
+# for both pools' double buffers.  On a v5e 4 and 8 were slower
+# everywhere, 32 slower for chat's ~300-token slots (a block is read
+# whole) and faster only past ~4k tokens a slot (PERF.md §6, PR 24).
+# Tables shorter than that are one block.
+PAGES_PER_BLOCK = 16
+
+
+def decode_kernel_takes(dtype, head_dim: int, page_size: int) -> bool:
+    """The shapes :func:`_decode_kernel` compiles for on a TPU: a float
+    pool whose page is whole native tiles in VMEM, i.e. ``head_dim`` a
+    multiple of the 128 lanes and ``page_size`` a multiple of the
+    dtype's sublane tile (8 rows of 32 bits: 8 for float32, 16 for
+    bfloat16).  Interpret mode takes any float shape."""
+    dtype = jnp.dtype(dtype)
+    if not jnp.issubdtype(dtype, jnp.floating) or dtype.itemsize > 4:
+        return False
+    return head_dim % 128 == 0 and page_size % (32 // dtype.itemsize) == 0
+
+
+def _decode_kernel(len_ref, pages_ref, q_ref, col_ref, pk_hbm, pv_hbm,
+                   o_ref, k_buf, v_buf, sems, *, table_pages: int,
+                   block_pages: int, page: int, rep: int, probs_dtype):
+    """Float pool, one batch slot (S == 1).  ``len_ref`` (B,) and
+    ``pages_ref`` (B * P,) are in SMEM; q_ref (1, R, hd) holds the
+    slot's R = n_kv * rep query rows; pk_hbm/pv_hbm are the pools as
+    (n_pages, page * n_kv, hd), left in HBM; col_ref (2, T) says of each
+    of a block's T = block_pages * page * n_kv columns its position
+    within the block and its KV head; k_buf/v_buf (2, T, hd) are the two
+    halves of the double buffer; sems (2, 2) their K and V semaphores."""
+    b = pl.program_id(0)
+    length = len_ref[b]
+    rows = k_buf.shape[1] // block_pages       # pool rows a page
     hd = q_ref.shape[-1]
-    q = q_ref[0, 0]                                       # (g, r, hd)
-    a = apos_ref[0, 0]
-    kv = _gather_pool(pk_ref, pages_ref, n_slot_pages, page)   # (V, g, hd)
-    vv = _gather_pool(pv_ref, pages_ref, n_slot_pages, page)
-    scores = jnp.einsum("grh,kgh->grk", q, kv,
-                        preferred_element_type=jnp.float32) / math.sqrt(hd)
-    vis = jnp.arange(kv.shape[0]) <= a
-    scores = jnp.where(vis[None, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    o_ref[0, 0] = jnp.einsum("grk,kgh->grh", probs.astype(probs_dtype),
-                             vv, preferred_element_type=jnp.float32)
+    span = block_pages * page                  # positions a block
+    n_blocks = (length + span - 1) // span
+
+    def copies(blk, slot):
+        out = []
+        for i in range(block_pages):
+            # a table that is no multiple of the block ends in repeats of
+            # its last entry; their positions are past every length
+            at = jnp.minimum(blk * block_pages + i, table_pages - 1)
+            pid = pages_ref[b * table_pages + at]
+            dst = pl.ds(i * rows, rows)
+            out.append(pltpu.make_async_copy(
+                pk_hbm.at[pid], k_buf.at[slot, dst], sems.at[0, slot]))
+            out.append(pltpu.make_async_copy(
+                pv_hbm.at[pid], v_buf.at[slot, dst], sems.at[1, slot]))
+        return out
+
+    @pl.when(n_blocks > 0)
+    def _():
+        for c in copies(0, 0):
+            c.start()
+
+    q = q_ref[0]                                              # (R, hd)
+    R = q.shape[0]
+    row_head = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) // rep
+    own_head = col_ref[1:2, :] == row_head                    # (R, T)
+    col_pos = col_ref[0:1, :]                                 # (1, T)
+
+    def block(blk, carry):
+        m, l, acc = carry
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _():
+            for c in copies(blk + 1, 1 - slot):
+                c.start()
+
+        for c in copies(blk, slot):
+            c.wait()
+        s = jax.lax.dot_general(
+            q, k_buf[slot], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) / math.sqrt(hd)
+        vis = jnp.logical_and(own_head, blk * span + col_pos < length)
+        s = jnp.where(vis, s, -1e30)
+        # every block the loop runs starts at a position under the
+        # length, so each row sees a real score in it and the -1e30 of a
+        # masked column underflows to exactly 0
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        corr = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * corr + jnp.dot(p.astype(probs_dtype), v_buf[slot],
+                                   preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    m0 = jnp.full((R, 1), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((R, 1), jnp.float32)
+    a0 = jnp.zeros((R, hd), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, block, (m0, l0, a0))
+    o_ref[0] = acc / jnp.where(l == 0.0, 1.0, l)
 
 
 def _decode_kernel_q8(pages_ref, q_ref, qs_ref, apos_ref, pk_ref, pv_ref,
@@ -125,59 +222,107 @@ def _decode_kernel_q8(pages_ref, q_ref, qs_ref, apos_ref, pk_ref, pv_ref,
     o_ref[0, 0] = attn_i.astype(jnp.float32) * pv_sc
 
 
-def paged_attention_decode(qg, pk, pv, pages, apos, *, q_scale=None,
-                           pk_s=None, pv_s=None, probs_dtype=None,
-                           interpret: bool = True):
+def paged_attention_decode(qg, pk, pv, pages, apos, *, valid=None,
+                           q_scale=None, pk_s=None, pv_s=None,
+                           probs_dtype=None, interpret: bool | None = None):
     """Decode-step paged attention, pages read in place via the table.
 
     qg (B, 1, n_kv, rep, hd) — grouped query (already rope'd); int8
     codes with ``q_scale`` (B, 1, n_kv, rep, 1) f32 when the pool is
     int8.  pk/pv (n_pages, page, n_kv, hd); pk_s/pv_s their f32 scales
     for int8 pools.  pages (B, P) int32 page table; apos (B, 1) int32
-    absolute position of the new row.  Returns f32 (B, 1, n_kv, rep,
-    hd), the exact value of the reference gather-then-einsum path
-    (caller applies the same ``astype`` epilogue).
+    absolute position of the new row; valid (B, 1) bool, False for a
+    slot that holds no request (float pools: it reads no page and gets
+    zeros; default all True).  Returns f32 (B, 1, n_kv, rep, hd), the
+    value of the reference gather-then-einsum path: exactly for int8
+    pools, to float32 summation order for float pools (caller applies
+    the same ``astype`` epilogue).  ``interpret`` None: compiled on a
+    TPU, interpreted elsewhere.
     """
-    import functools
-    refuse_on_tpu("paged_attention_decode")
     B, S, nkv, rep, hd = qg.shape
     if S != 1:
         raise ValueError(f"decode kernel is S==1 only, got S={S}")
     P = pages.shape[1]
-    page = pk.shape[1]
-    quantized = pk.dtype == jnp.int8
+    n_pages, page = pk.shape[:2]
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
 
-    whole = lambda arr: pl.BlockSpec(
-        arr.shape, lambda b: (0,) * arr.ndim)
-    row = pl.BlockSpec((1, P), lambda b: (b, 0))
-    qspec = pl.BlockSpec((1, 1, nkv, rep, hd), lambda b: (b, 0, 0, 0, 0))
-    aspec = pl.BlockSpec((1, 1), lambda b: (b, 0))
-    out_spec = pl.BlockSpec((1, 1, nkv, rep, hd), lambda b: (b, 0, 0, 0, 0))
-    out_shape = jax.ShapeDtypeStruct((B, 1, nkv, rep, hd), jnp.float32)
-
-    if quantized:
+    if pk.dtype == jnp.int8:
+        refuse_on_tpu("paged_attention_decode (int8 pool)")
         if q_scale is None or pk_s is None or pv_s is None:
             raise ValueError("int8 pool needs q_scale, pk_s and pv_s")
-        kernel = functools.partial(_decode_kernel_q8, n_slot_pages=P)
-        sspec = pl.BlockSpec((1, 1, nkv, rep, 1), lambda b: (b, 0, 0, 0, 0))
+        whole = lambda arr: pl.BlockSpec(
+            arr.shape, lambda b: (0,) * arr.ndim)
+        wide = lambda last: pl.BlockSpec(
+            (1, 1, nkv, rep, last), lambda b: (b, 0, 0, 0, 0))
         return pl.pallas_call(
-            kernel,
+            functools.partial(_decode_kernel_q8, n_slot_pages=P),
             grid=(B,),
-            in_specs=[row, qspec, sspec, aspec, whole(pk), whole(pv),
-                      whole(pk_s), whole(pv_s)],
-            out_specs=out_spec,
-            out_shape=out_shape,
+            in_specs=[pl.BlockSpec((1, P), lambda b: (b, 0)), wide(hd),
+                      wide(1), pl.BlockSpec((1, 1), lambda b: (b, 0)),
+                      whole(pk), whole(pv), whole(pk_s), whole(pv_s)],
+            out_specs=wide(hd),
+            out_shape=jax.ShapeDtypeStruct((B, 1, nkv, rep, hd),
+                                           jnp.float32),
             interpret=interpret,
         )(pages, qg, q_scale, apos, pk, pv, pk_s, pv_s)
 
+    if not interpret and not decode_kernel_takes(pk.dtype, hd, page):
+        raise ValueError(
+            f"the paged decode kernel does not compile for a {pk.dtype} "
+            f"pool with head_dim {hd} and page_size {page} "
+            f"(decode_kernel_takes); the engine's gather path serves it")
+    lengths = apos[:, 0] + 1
+    if valid is not None:
+        lengths = jnp.where(valid[:, 0], lengths, 0)
+    return _decode_float(
+        qg, pk, pv, pages, lengths,
+        block_pages=min(PAGES_PER_BLOCK, P),
+        probs_dtype=jnp.dtype(probs_dtype or qg.dtype),
+        interpret=bool(interpret))
+
+
+# jitted so that the layers of one decode program, which call it with the
+# same shapes, share one trace and one Mosaic lowering: unshared, 36
+# layers added ~10 s to every engine's warm-up, compile cache or not
+@functools.partial(jax.jit, static_argnames=("block_pages", "probs_dtype",
+                                             "interpret"))
+def _decode_float(qg, pk, pv, pages, lengths, *, block_pages: int,
+                  probs_dtype, interpret: bool):
+    """The float kernel's call: qg (B, 1, n_kv, rep, hd), the pools as
+    the engine holds them, pages (B, P), lengths (B,) with 0 for a slot
+    that reads nothing."""
+    B, _, nkv, rep, hd = qg.shape
+    P = pages.shape[1]
+    n_pages, page = pk.shape[:2]
+    R, rows = nkv * rep, page * nkv
+    T = block_pages * rows
+    # row w of a page's (page * n_kv, hd) slab is token w // n_kv of the
+    # page, KV head w % n_kv (numpy: a literal of the program, not ops)
+    w = np.arange(T, dtype=np.int32) % rows
+    cols = np.stack([(np.arange(T, dtype=np.int32) // rows) * page
+                     + w // nkv, w % nkv])
     kernel = functools.partial(
-        _decode_kernel, n_slot_pages=P,
-        probs_dtype=probs_dtype or qg.dtype)
-    return pl.pallas_call(
+        _decode_kernel, table_pages=P, block_pages=block_pages, page=page,
+        rep=rep, probs_dtype=probs_dtype)
+    slot = pl.BlockSpec((1, R, hd), lambda b, *_: (b, 0, 0))
+    out = pl.pallas_call(
         kernel,
-        grid=(B,),
-        in_specs=[row, qspec, aspec, whole(pk), whole(pv)],
-        out_specs=out_spec,
-        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[slot, pl.BlockSpec((2, T), lambda b, *_: (0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=slot,
+            scratch_shapes=[pltpu.VMEM((2, T, hd), pk.dtype),
+                            pltpu.VMEM((2, T, hd), pv.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2))]),
+        out_shape=jax.ShapeDtypeStruct((B, R, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(pages, qg, apos, pk, pv)
+    )(lengths.astype(jnp.int32), pages.reshape(-1).astype(jnp.int32),
+      qg.reshape(B, R, hd), cols, pk.reshape(n_pages, rows, hd),
+      pv.reshape(n_pages, rows, hd))
+    return out.reshape(B, 1, nkv, rep, hd)

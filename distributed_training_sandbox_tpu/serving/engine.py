@@ -14,7 +14,10 @@ extent.  The engine therefore always contracts over the FIXED pool view
 and one-shot ``generate`` grew a static ``cache_capacity`` arg to pin
 the same extent.  With matched capacity, serving output is
 bitwise-identical to ``generate`` — the invariant the parity suite
-asserts per request.
+asserts per request.  (It holds on the gather path, which the CPU tier
+takes.  On a TPU the decode step's default is the paged kernel, whose
+contraction is bounded by each slot's length: equal to the gather path
+to float32 summation order, not bitwise.)
 
 **Zero retraces after warmup.**  The decode step has static shape:
 ``max_batch`` slots, an active mask, full-size page-table rows.
@@ -162,9 +165,11 @@ def _paged_layer_body(x, layer, *, cfg, cos, sin, use_rope, pk, pv,
 
     rep = nq // nkv
     if paged_kernel and S == 1:
-        # Pallas decode kernel: pages are read IN PLACE via the table —
-        # the (B, V, nkv, hd) gather view below never materializes.
-        # Bitwise-equal to the gather path (ops/paged_attention.py).
+        # Pallas decode kernel: pages are read IN PLACE via the table,
+        # bounded by each slot's length — the (B, V, nkv, hd) gather
+        # view below never materializes.  Equal to the gather path
+        # bitwise for int8 pools, to float32 summation order for float
+        # pools (ops/paged_attention.py).
         from ..ops.paged_attention import paged_attention_decode
         with scope("attn_core"):
             qg = q.reshape(B, S, nkv, rep, hd)
@@ -175,6 +180,7 @@ def _paged_layer_body(x, layer, *, cfg, cos, sin, use_rope, pk, pv,
                     pk_s=pk_s, pv_s=pv_s)
             else:
                 attn = paged_attention_decode(qg, pk, pv, pages, apos,
+                                              valid=valid,
                                               probs_dtype=x.dtype)
         return tail(attn)
 
@@ -413,7 +419,7 @@ def make_serve_decode_step(cfg, params=None, *, mesh=None,
     ``pool_spec`` the pool's PartitionSpec pytree).  ``paged_kernel``
     routes attention through the Pallas decode kernel
     (``ops/paged_attention.py`` — pages read in place via the table, no
-    contiguous gather view; bitwise-equal outputs)."""
+    contiguous gather view; compiled on a TPU, interpreted elsewhere)."""
     cfg = _decode_cfg(cfg)
     if mesh is None:
         return jax.jit(partial(_decode_core, cfg=cfg, tp_axis=None,
@@ -543,7 +549,7 @@ class ServingEngine:
                  prefill_chunks_per_round: int = 2,
                  sync_every: int = 4, max_in_flight: int = 8,
                  kv_quant: bool = False,
-                 paged_kernel: bool = False,
+                 paged_kernel: bool | None = None,
                  prefix_cache: bool = False,
                  spec_k: int = 0, draft_params=None, draft_cfg=None,
                  draft_layers: int | None = None,
@@ -564,8 +570,19 @@ class ServingEngine:
         self.max_in_flight = int(max_in_flight)
         self.kv_quant = bool(kv_quant)
         # decode attention through the Pallas paged kernel (pages read
-        # in place via the table — ops/paged_attention.py); prefill
-        # (S > 1) keeps the gather path
+        # in place via the table — ops/paged_attention.py); prefill and
+        # speculative verify (S > 1) keep the gather path.  None: on
+        # where the kernel compiles — a TPU, a float pool, a head_dim
+        # and page_size it takes — and off elsewhere, where it would
+        # run interpreted
+        if paged_kernel is None:
+            paged_kernel = (jax.default_backend() == "tpu"
+                            and not self.kv_quant)
+            if paged_kernel:
+                from ..ops.paged_attention import decode_kernel_takes
+                paged_kernel = decode_kernel_takes(
+                    self.cfg.dtype, self.cfg.resolved_head_dim,
+                    self.page_size)
         self.paged_kernel = bool(paged_kernel)
         # prefill through the BATCHED multi-request step with the
         # Pallas flash-attention kernel (ops/flash_prefill.py)
@@ -809,7 +826,10 @@ class ServingEngine:
         self._pump = None
         self._t0: float | None = None
         self._warm_sizes = None
-        self.stats = {"rounds": 0, "decode_steps": 0, "prefill_chunks": 0,
+        self.stats = {"rounds": 0, "decode_steps": 0,
+                      # decode steps whose attention read the pages in
+                      # place (the paged kernel) and built no gather view
+                      "decode_inplace_steps": 0, "prefill_chunks": 0,
                       "admit_s": 0.0, "bookkeep_s": 0.0,
                       # measured per-phase device time — the per-burst
                       # priors the virtual-clock simulator's cost model
@@ -1151,6 +1171,8 @@ class ServingEngine:
                 step_tokens.append(toks_d)
             self.pool.bufs = bufs
             self.stats["decode_steps"] += sync
+            if self.paged_kernel:
+                self.stats["decode_inplace_steps"] += sync
         mats = self._sync_burst(step_tokens)
         burst_s = time.perf_counter() - t_burst  # clock-ok
         self.stats["decode_s"] += burst_s

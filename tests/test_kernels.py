@@ -383,34 +383,187 @@ def test_paged_attention_rejects_multi_token():
         paged_attention_decode(qg, pk, pk, pages, apos)
 
 
-# ------------------------------------------- what a TPU makes of them
+# ----------------------------- the float decode kernel, on its own
 
-def test_serving_kernels_refuse_a_tpu(monkeypatch):
-    """The two serving kernels do not lower on a TPU.  There they raise
-    an error that names Pallas' refusal — whatever ``interpret`` says,
-    so they can neither run interpreted nor give way to the gather path
-    unseen."""
-    from distributed_training_sandbox_tpu.ops.flash_prefill import (
-        paged_flash_prefill)
+# name -> (page, n_kv, rep, hd, pages a slot, dtype): tiny widths, a
+# table longer than one DMA block that is no multiple of it, and the
+# serving cells' geometry
+_DECODE_GEOMETRY = {
+    "tiny": (4, 2, 2, 8, 3, jnp.float32),
+    "tiny-blocks": (4, 1, 4, 8, 19, jnp.float32),
+    "cell": (16, 4, 4, 128, 20, jnp.float32),
+}
+
+
+def _decode_case(geometry, seed=0):
+    """A pool, a page table and ragged lengths for ``geometry``: an
+    inactive slot (0), 1, a page boundary and its neighbours, a DMA
+    block boundary and its neighbours where the table has one, and the
+    full view."""
+    from distributed_training_sandbox_tpu.ops.paged_attention import (
+        PAGES_PER_BLOCK)
+    page, nkv, rep, hd, P, dt = _DECODE_GEOMETRY[geometry]
+    V, span = P * page, PAGES_PER_BLOCK * page
+    lens = [0, 1, page - 1, page, page + 1, V - 1, V]
+    if span < V:
+        lens += [span - 1, span, span + 1]
+    lens = np.asarray(lens, np.int32)
+    B = len(lens)
+    n_pages = B * P + 1
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    pk = jax.random.normal(ks[0], (n_pages, page, nkv, hd), dt)
+    pv = jax.random.normal(ks[1], (n_pages, page, nkv, hd), dt)
+    qg = jax.random.normal(ks[2], (B, 1, nkv, rep, hd), dt)
+    pages = np.random.RandomState(seed).permutation(
+        np.arange(1, n_pages)).reshape(B, P).astype(np.int32)
+    apos = jnp.asarray(np.maximum(lens - 1, 0)[:, None])
+    valid = jnp.asarray((lens > 0)[:, None])
+    return qg, pk, pv, jnp.asarray(pages), apos, valid, lens
+
+
+@pytest.mark.parametrize("geometry", list(_DECODE_GEOMETRY))
+def test_paged_decode_kernel_matches_gather(geometry):
+    """The float kernel (interpret mode) against the engine's
+    gather-then-einsum attention core, to float32 summation order, at
+    every ragged length; an inactive slot gets zeros."""
+    from chip_smoke import paged_attention_xla
     from distributed_training_sandbox_tpu.ops.paged_attention import (
         paged_attention_decode)
 
+    qg, pk, pv, pages, apos, valid, lens = _decode_case(geometry)
+    out = paged_attention_decode(qg, pk, pv, pages, apos, valid=valid)
+    ref = paged_attention_xla(qg, pk, pv, pages, apos, qg.dtype)
+    assert out.shape == ref.shape and out.dtype == jnp.float32
+    live = lens > 0
+    _assert_f32_dot_close(np.asarray(ref)[live], np.asarray(out)[live])
+    assert not np.asarray(out)[~live].any()
+    # without ``valid`` every slot is live at apos + 1
+    every = paged_attention_decode(qg, pk, pv, pages, apos)
+    _assert_f32_dot_close(ref, every)
+
+
+@pytest.mark.parametrize("geometry", list(_DECODE_GEOMETRY))
+def test_paged_decode_kernel_ignores_what_lies_past_a_length(geometry):
+    """Finite garbage in a slot's pages past its length, in its unused
+    pages and in the null page changes no bit of the output."""
+    from distributed_training_sandbox_tpu.ops.paged_attention import (
+        paged_attention_decode)
+
+    qg, pk, pv, pages, apos, valid, lens = _decode_case(geometry, seed=1)
+    page, P = pk.shape[1], pages.shape[1]
+    clean = paged_attention_decode(qg, pk, pv, pages, apos, valid=valid)
+    # position v of slot b lives at (pages[b, v // page], v % page)
+    past = np.arange(P * page)[None, :] >= lens[:, None]       # (B, V)
+    junk = np.zeros(pk.shape[:2], bool)
+    junk[np.asarray(pages), :] = past.reshape(len(lens), P, page)
+    junk[0] = True
+    noise = 1e4 * jax.random.normal(jax.random.PRNGKey(9), pk.shape,
+                                    pk.dtype)
+    dirty = lambda pool: jnp.where(jnp.asarray(junk)[:, :, None, None],
+                                   noise, pool)
+    out = paged_attention_decode(qg, dirty(pk), dirty(pv), pages, apos,
+                                 valid=valid)
+    np.testing.assert_array_equal(np.asarray(clean), np.asarray(out))
+
+
+# ------------------------------------------- what a TPU makes of them
+
+def test_serving_kernels_refuse_a_tpu(monkeypatch):
+    """The flash-prefill kernel and the int8 decode kernel do not lower
+    on a TPU.  There they raise an error that names Pallas' refusal —
+    whatever ``interpret`` says, so they can neither run interpreted nor
+    give way to the gather path unseen.  The float decode kernel no
+    longer refuses: it compiles there at the shapes
+    ``decode_kernel_takes`` names and says so at the others."""
+    from distributed_training_sandbox_tpu.ops.flash_prefill import (
+        paged_flash_prefill)
+    from distributed_training_sandbox_tpu.ops.paged_attention import (
+        decode_kernel_takes, paged_attention_decode)
+
     pk = jnp.zeros((8, 4, 1, 8))
+    pk8 = jnp.zeros((8, 4, 1, 8), jnp.int8)
+    scale = jnp.ones((8, 4, 1, 1))
     pages = jnp.zeros((2, 2), jnp.int32)
-    calls = (
-        (paged_attention_decode, jnp.zeros((2, 1, 1, 4, 8)),
-         jnp.zeros((2, 1), jnp.int32)),
-        (paged_flash_prefill, jnp.zeros((2, 4, 1, 4, 8)),
-         jnp.zeros((2, 4), jnp.int32)))
-    for fn, qg, apos in calls:      # fine where the backend is not a TPU
-        assert fn(qg, pk, pk, pages, apos).shape == qg.shape
+    q8 = dict(q_scale=jnp.ones((2, 1, 1, 4, 1)), pk_s=scale, pv_s=scale)
+    fenced = (
+        (paged_attention_decode, jnp.zeros((2, 1, 1, 4, 8), jnp.int8),
+         pk8, jnp.zeros((2, 1), jnp.int32), q8),
+        (paged_flash_prefill, jnp.zeros((2, 4, 1, 4, 8)), pk,
+         jnp.zeros((2, 4), jnp.int32), {}))
+    q = jnp.zeros((2, 1, 1, 4, 8))
+    apos = jnp.zeros((2, 1), jnp.int32)
+    for fn, qg, pool, ap, kw in fenced:   # fine where there is no TPU
+        assert fn(qg, pool, pool, pages, ap, **kw).shape == qg.shape
+    assert paged_attention_decode(q, pk, pk, pages, apos).shape == q.shape
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    for fn, qg, apos in calls:
-        for kw in ({}, {"interpret": True}, {"interpret": False}):
+    for fn, qg, pool, ap, kw in fenced:
+        for how in ({}, {"interpret": True}, {"interpret": False}):
             with pytest.raises(NotImplementedError,
                                match="dynamic_update_slice") as e:
-                fn(qg, pk, pk, pages, apos, **kw)
+                fn(qg, pool, pool, pages, ap, **kw, **how)
             assert fn.__name__ in str(e.value) and "S3" in str(e.value)
+    # the float decode kernel: a shape it does not compile for is named
+    assert not decode_kernel_takes(pk.dtype, 8, 4)
+    with pytest.raises(ValueError, match="decode_kernel_takes"):
+        paged_attention_decode(q, pk, pk, pages, apos)
+    # ... one it takes lowers to a Mosaic call, bf16 and float32
+    for dt, page in ((jnp.bfloat16, 16), (jnp.float32, 8)):
+        assert decode_kernel_takes(dt, 128, page)
+        sd = jax.ShapeDtypeStruct
+        text = jax.jit(
+            lambda qg, pool, pg, ap: paged_attention_decode(
+                qg, pool, pool, pg, ap)).trace(
+            sd((4, 1, 4, 4, 128), dt), sd((33, page, 4, 128), dt),
+            sd((4, 8), jnp.int32), sd((4, 1), jnp.int32)).lower(
+            lowering_platforms=("tpu",)).as_text()
+        assert "tpu_custom_call" in text
+    assert not decode_kernel_takes(jnp.bfloat16, 128, 8)
+    assert not decode_kernel_takes(jnp.bfloat16, 64, 16)
+    assert not decode_kernel_takes(jnp.int8, 128, 32)
+
+
+@pytest.mark.parametrize("batch,pages_per", [(32, 128), (8, 512)],
+                         ids=["chat-32x2048", "documents-8x8192"])
+def test_engine_decode_program_lowers_for_tpu_without_the_gather(
+        monkeypatch, batch, pages_per):
+    """The engine's decode program at the serving cells' shapes (SmolLM3
+    widths, two layers, bf16, page 16), lowered FOR a TPU on this host:
+    attention is a Mosaic call and nothing has the (B, V, n_kv, hd)
+    extent of the gathered view, which the gather path's program does."""
+    from distributed_training_sandbox_tpu.serving import (
+        make_serve_decode_step)
+    from distributed_training_sandbox_tpu.serving.kv_pool import (
+        PoolBuffers)
+
+    cfg = T.TransformerConfig(
+        vocab_size=128256, hidden_size=2048, intermediate_size=11008,
+        num_hidden_layers=2, num_attention_heads=16,
+        num_key_value_heads=4, rope_theta=5e6, nope_interval=4,
+        tie_word_embeddings=True, dtype=jnp.bfloat16, remat=False)
+    page, nkv, hd = 16, 4, 128
+    sd = jax.ShapeDtypeStruct
+    params = jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0),
+                                                  cfg))
+    pool = tuple(sd((batch * pages_per + 1, page, nkv, hd), jnp.bfloat16)
+                 for _ in range(cfg.num_hidden_layers))
+    args = (PoolBuffers(k=pool, v=pool, k_scale=None, v_scale=None),
+            params, sd((batch, pages_per), jnp.int32),
+            sd((batch,), jnp.int32), sd((batch,), jnp.int32),
+            sd((batch,), jnp.int32), sd((batch,), jnp.bool_))
+    view = (f"{batch}x{pages_per}x{page}x{nkv}x{hd}x",
+            f"{batch}x{pages_per * page}x{nkv}x{hd}x",
+            f"{batch}x{nkv}x{pages_per * page}x{hd}x")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def lowered(paged_kernel):
+        step = make_serve_decode_step(cfg, paged_kernel=paged_kernel)
+        return step.trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    text = lowered(True)
+    assert "tpu_custom_call" in text
+    assert not any(v in text for v in view)
+    assert any(v in lowered(False) for v in view)
 
 
 def test_matmul_kernels_lower_for_tpu(mesh8):

@@ -160,6 +160,67 @@ def test_ragged_batch_parity_and_zero_retraces():
     assert 0 < slo["pool"]["peak_util"] <= 1.0
 
 
+# ---- the decode path's default: in place where the kernel compiles ------
+
+@pytest.mark.parametrize("backend,engine_kw,cfg_kw,on", [
+    ("tpu", {}, dict(head_dim=128), True),
+    ("tpu", dict(kv_quant=True), dict(head_dim=128), False),
+    ("tpu", {}, dict(head_dim=64), False),
+    ("tpu", dict(page_size=8), dict(head_dim=128, dtype="bfloat16"),
+     False),
+    ("tpu", dict(paged_kernel=False), dict(head_dim=128), False),
+    ("cpu", {}, dict(head_dim=128), False),
+    ("cpu", dict(paged_kernel=True), {}, True),
+], ids=["tpu-float", "tpu-kv_quant", "tpu-head_dim-64", "tpu-bf16-page-8",
+        "tpu-told-off", "cpu", "cpu-told-on"])
+def test_paged_kernel_default_resolves_from_what_the_engine_sees(
+        monkeypatch, backend, engine_kw, cfg_kw, on):
+    """``paged_kernel=None`` (the default) turns the in-place decode
+    kernel on exactly where it compiles: a TPU backend, a float pool, a
+    head_dim and page_size ``decode_kernel_takes``; True/False are
+    taken as given."""
+    import dataclasses
+    import jax.numpy as jnp
+    cfg_kw = dict(cfg_kw)
+    if "dtype" in cfg_kw:
+        cfg_kw["dtype"] = jnp.dtype(cfg_kw["dtype"])
+    cfg = dataclasses.replace(T.TINY_LM, **cfg_kw)
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    kw = dict(max_batch=2, page_size=16, max_seq_len=64, prefill_chunk=16)
+    kw.update(engine_kw)
+    eng = ServingEngine(params, cfg, **kw)
+    assert eng.paged_kernel is on
+    assert eng.stats["decode_inplace_steps"] == 0
+
+
+@pytest.mark.parametrize("paged_kernel", [None, True],
+                         ids=["default-off-the-chip", "kernel"])
+def test_decode_inplace_steps_counts_the_kernels_steps(paged_kernel):
+    """``stats["decode_inplace_steps"]`` equals ``stats["decode_steps"]``
+    exactly when the decode program is the in-place kernel, and stays 0
+    on the gather path; both serve the same tokens."""
+    cfg = T.TINY_LM
+    params = _chaotic_params(cfg)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n).astype(np.int32)
+               for n in (5, 17, 9)]
+    eng = ServingEngine(params, cfg, max_batch=2, page_size=8,
+                        max_seq_len=48, prefill_chunk=16,
+                        paged_kernel=paged_kernel)
+    reqs = [eng.submit(p, max_new_tokens=7) for p in prompts]
+    eng.run()
+    assert eng.stats["decode_steps"] > 0
+    assert eng.stats["decode_inplace_steps"] == (
+        eng.stats["decode_steps"] if paged_kernel else 0)
+    for r in reqs:
+        ref = np.asarray(generate(
+            params, r.prompt[None], cfg, max_new_tokens=7,
+            cache_capacity=eng.view_capacity))[0]
+        assert np.asarray(r.tokens, np.int32).tolist() == ref.tolist()
+    assert eng.retraces_after_warmup() == 0
+
+
 def test_tp_sharded_engine_parity():
     """Heads sharded over tp=2: same tokens, bitwise."""
     from distributed_training_sandbox_tpu.utils import make_mesh
