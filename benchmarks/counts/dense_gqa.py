@@ -1,7 +1,8 @@
-"""Operations and bytes the algorithms REQUIRE, from shapes alone.  The
-yardstick: kept with the benchmark so that no PR that claims a gain can
-change it.  Recomputed operations (remat, a kernel re-deriving its scores
-in the backward) are never counted.
+"""Operations and bytes the dense GQA decoder (``reference/dense_gqa.py``)
+REQUIRES, from the configuration's ``fields`` alone.  The yardstick: kept
+with the benchmark so that no PR that claims a gain can change it.
+Recomputed operations (remat, a kernel re-deriving its scores in the
+backward) are never counted.
 
 ``model_flops_per_token`` is the program's ``utils/flops.py``
 ``get_model_flops_per_token`` copied (6N convention: forward + 2x backward,
@@ -35,13 +36,20 @@ def model_flops_per_token(fields: dict, seq_len: int) -> float:
     return 3.0 * fwd
 
 
-def param_count(fields: dict) -> int:
+def proj_mlp_weight_count(fields: dict) -> int:
+    """Parameters of q, k, v, o and the three MLP matrices, every layer."""
     h, nq, nkv, hd = _dims(fields)
     per_layer = h * hd * (2 * nq + 2 * nkv) \
-        + 3 * h * int(fields["intermediate_size"]) + 2 * h
+        + 3 * h * int(fields["intermediate_size"])
+    return int(fields["num_hidden_layers"]) * per_layer
+
+
+def param_count(fields: dict) -> int:
+    h = int(fields["hidden_size"])
+    norms = 2 * h * int(fields["num_hidden_layers"])
     embed = int(fields["vocab_size"]) * h
     head = 0 if fields.get("tie_word_embeddings", True) else embed
-    return int(fields["num_hidden_layers"]) * per_layer + embed + head + h
+    return proj_mlp_weight_count(fields) + norms + embed + head + h
 
 
 def attention_kernel_flops(fields: dict, seq_len: int, n_seqs: int) -> float:
@@ -76,12 +84,3 @@ def decode_step_bytes(fields: dict, valid_kv_tokens: float,
     the positions the batch's live requests actually hold."""
     return param_count(fields) * itemsize \
         + valid_kv_tokens * kv_bytes_per_token(fields, itemsize)
-
-
-def roofline_seconds(flops: float, nbytes: float,
-                     peaks: dict) -> tuple[float, str]:
-    """The least time one chip could take in bf16, and which peak bounds
-    it."""
-    t_c = flops / peaks["bf16_flops_per_s"]
-    t_m = nbytes / peaks["hbm_bytes_per_s"]
-    return (t_c, "compute") if t_c >= t_m else (t_m, "memory")

@@ -4,15 +4,53 @@ one result line.
 
 A cell is one entry of ``BENCHMARK.json`` ``workloads``: a configuration
 (``configs/<config>.json``) under a traffic mix (``workloads/<traffic>.json``).
-Nothing here names a cell, a configuration, a mix or a metric: they are
-found by the names in ``BENCHMARK.json``, so a later PR adds files and
-entries and edits no file that is there.
+Nothing here names a cell, a configuration, a mix, a metric or a model's
+block: they are found by the names in ``BENCHMARK.json`` and in the data
+files, so a later PR adds files and entries and edits no file that is
+there.
+
+What depends on the model's block is found by the configuration file's
+``"architecture": "<name>"``:
+
+``reference/<name>.py``, the plain float32 reference, handed to the runner.
+A runner lists what it calls in ``REFERENCE_EXPORTS``:
+
+* ``serve``: ``logits_at(params, ids, positions, fields, block)`` ->
+  ``(P, V)`` float32 logits at ``positions`` (P,) of the sequence ``ids``
+  (S,), each against its whole causal context, queries taken ``block`` rows
+  at a time; ``ids`` may be padded at the end.
+* ``train``: ``loss(params, ids, labels, fields, block=None)`` -> the mean
+  next-token cross-entropy of one sequence (S,), float32;
+  ``group_sumsq(grads)`` and ``group_norms(grads)`` -> a dict of float32
+  scalars per parameter group of a gradient tree shaped like ``params``,
+  whose group names are the keys of the configuration's
+  ``check.grad_norm_rel``.
+
+``params`` is the program's own tree (``init_params`` of
+``TransformerConfig(**fields)``), ``fields`` the configuration's ``fields``
+as run.  A reference imports nothing of the program.
+
+``counts/<name>.py``, the operations and bytes the block's algorithms
+require, from ``fields`` alone; the readers reach it as ``ctx.counts``:
+``param_count(fields)``, ``model_flops_per_token(fields, seq_len)``,
+``kv_bytes_per_token(fields, itemsize=2)``,
+``decode_step_bytes(fields, valid_kv_tokens, itemsize=2)``,
+``attention_kernel_flops(fields, seq_len, n_seqs)``,
+``attention_kernel_bytes(fields, seq_len, n_seqs, itemsize=2)``,
+``proj_mlp_weight_count(fields)``.  A reader lists what it calls in
+``COUNTS``; a module needs only what the readers of its cells list, and a
+cell none of whose readers counts needs no module (``cell_counts``).  The
+peaks (``peaks.json``) and ``roofline_seconds`` are shared by every
+architecture.
+
+A missing module or function is a ``BenchmarkError`` that names the file.
 
 Importing this module touches neither JAX nor the program under test.
 """
 
 from __future__ import annotations
 
+import copy
 import importlib.util
 import json
 import math
@@ -77,6 +115,26 @@ class Cell:
     def runner(self) -> str:
         return self.config["runner"]
 
+    @property
+    def architecture(self) -> str:
+        return self.config["architecture"]
+
+    @property
+    def check(self) -> dict:
+        return self.tolerances()
+
+    def tolerances(self, rehearse: bool = False) -> dict:
+        """The tolerances ``correct`` is held to: the configuration's
+        ``check`` with the traffic file's ``check`` merged over it, so a mix
+        at other sizes states the bands it read there.  A rehearsal's tiny
+        model has readings of its own: both files' ``rehearse.check`` merge
+        over that."""
+        out = deep_merge(copy.deepcopy(self.config["check"]),
+                         self.traffic.get("check", {}))
+        for src in (self.config, self.traffic) if rehearse else ():
+            deep_merge(out, src.get("rehearse", {}).get("check", {}))
+        return out
+
 
 def _read_json(path: Path) -> dict:
     try:
@@ -111,13 +169,33 @@ def _import_file(path: Path):
     return mod
 
 
-def find_module(kind_dir: str, name: str, bench: Path = BENCH):
-    """``<bench>/<kind_dir>/<name>.py`` — a reader, a generator or a
-    runner, found by the name a data file gives."""
+def module_path(kind_dir: str, name: str, bench: Path = BENCH) -> Path:
     path = bench / kind_dir / f"{name.replace('-', '_')}.py"
     if not path.is_file():
         raise BenchmarkError(f"no {kind_dir} module for {name!r}: {path}")
-    return _import_file(path)
+    return path
+
+
+def find_module(kind_dir: str, name: str, bench: Path = BENCH, needs=()):
+    """``<bench>/<kind_dir>/<name>.py`` — a reader, a generator, a runner,
+    a reference or a counts module, found by the name a data file gives.
+    A function of ``needs`` that it lacks is a ``BenchmarkError`` naming
+    the file."""
+    mod = _import_file(module_path(kind_dir, name, bench))
+    for need in needs:
+        if not hasattr(mod, need):
+            raise BenchmarkError(f"{mod.__file__} has no {need!r}, which "
+                                 f"this cell's runner or readers call")
+    return mod
+
+
+def cell_counts(cell: "Cell", bench: Path = BENCH):
+    """``counts/<architecture>.py`` with what the cell's readers list in
+    their ``COUNTS``; None for a cell none of whose readers counts."""
+    needs = sorted({n for m in cell.end_to_end + cell.per_layer
+                    for n in getattr(m.module, "COUNTS", ())})
+    return find_module("counts", cell.architecture, bench, needs) \
+        if needs else None
 
 
 def load_metrics(bm: dict, bench: Path = BENCH) -> dict[str, Metric]:
@@ -160,12 +238,17 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
                 config=_read_json(root / cfg_row["file"]),
                 traffic=_read_json(bench / "workloads"
                                    / f"{row['traffic']}.json"))
+    if "architecture" not in cell.config:
+        raise BenchmarkError(f"{root / cfg_row['file']} names no "
+                             f"\"architecture\"")
+    module_path("reference", cell.architecture, bench)
     metrics = load_metrics(bm, bench)
     cell.end_to_end = [m for m in metrics.values()
                        if m.kind == "end_to_end" and m.applies(cell, set())]
     e2e = {m.name for m in cell.end_to_end}
     cell.per_layer = [m for m in metrics.values()
                       if m.kind == "per_layer" and m.applies(cell, e2e)]
+    cell_counts(cell, bench)     # missing counts fail here, not after a run
     return cell
 
 
@@ -179,6 +262,7 @@ def list_cells(root: Path = ROOT) -> list[dict]:
         out.append({"cell": cell.name, "config": cell.config_name,
                     "traffic": cell.traffic_name, "chips": cell.chips,
                     "runner": cell.runner,
+                    "architecture": cell.architecture,
                     "end_to_end": ["setup_s"] + [m.name
                                                  for m in cell.end_to_end],
                     "per_layer": [m.name for m in cell.per_layer]})
@@ -256,6 +340,15 @@ def memory_peak_bytes(devices) -> int:
 
 
 # ------------------------------------------------------ arithmetic, results
+
+def roofline_seconds(flops: float, nbytes: float,
+                     peaks: dict) -> tuple[float, str]:
+    """The least time one chip could take in bf16 for these operations and
+    bytes, from the published peaks, and which peak bounds it."""
+    t_c = flops / peaks["bf16_flops_per_s"]
+    t_m = nbytes / peaks["hbm_bytes_per_s"]
+    return (t_c, "compute") if t_c >= t_m else (t_m, "memory")
+
 
 def percentile(values, q: float) -> float:
     """The q-th percentile (0..100) by linear interpolation between order
@@ -358,6 +451,7 @@ class Context:
     fields: dict                 # the configuration's fields as run
     counters: dict               # the runner's counts and host-clock times
     peaks: dict | None           # peaks.json row of this device
+    counts: object | None = None  # cell_counts(cell): the block's arithmetic
     trace: object | None = None  # reduce_trace.ReducedTrace, traced run only
 
     @property

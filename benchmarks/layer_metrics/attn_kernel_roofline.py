@@ -1,13 +1,14 @@
 """Kernels: the least time one chip could take for the causal attention a
-step requires (``benchmarks/flops.py``: compute bound at these shapes)
+step requires (the architecture's counts: compute bound at these shapes)
 over the time the splash kernels took."""
-from benchmarks import flops
+from benchmarks.harness import roofline_seconds
 from benchmarks.layer_metrics import _attn
 
 LAYER = "kernels"
 UNIT = "%"
 MOVES = "train_tokens_per_s"
 RUNNERS = ("train",)
+COUNTS = ("attention_kernel_flops", "attention_kernel_bytes")
 
 
 def read(ctx):
@@ -18,8 +19,8 @@ def read(ctx):
         return None
     c = ctx.counters
     seqs = c["global_batch"] / ctx.chips
-    least, _bound = flops.roofline_seconds(
-        flops.attention_kernel_flops(ctx.fields, c["seq_len"], seqs),
-        flops.attention_kernel_bytes(ctx.fields, c["seq_len"], seqs),
+    least, _bound = roofline_seconds(
+        ctx.counts.attention_kernel_flops(ctx.fields, c["seq_len"], seqs),
+        ctx.counts.attention_kernel_bytes(ctx.fields, c["seq_len"], seqs),
         ctx.peaks)
     return 100.0 * least / took

@@ -46,21 +46,25 @@ def _dims(fields):
     return h, nq, nkv, hd
 
 
-def _layer(x, lw, use_rope, fields, q_pos, kv_x, kv_pos):
+def _kv(x, lw, use_rope, fields, pos):
+    """Keys and values (S, nkv, hd) of the rows ``x`` (S, H) at ``pos``."""
+    _, _, nkv, hd = _dims(fields)
+    r = _rms_norm(x, lw["ln1"], float(fields["rms_norm_eps"]))
+    k = (r @ lw["wk"]).reshape(-1, nkv, hd)
+    v = (r @ lw["wv"]).reshape(-1, nkv, hd)
+    return jnp.where(use_rope, _rope(k, pos, float(fields["rope_theta"])),
+                     k), v
+
+
+def _layer(x, lw, use_rope, fields, q_pos, k, v, kpos):
     """One layer.  ``x`` (Sq, H) are the query rows at positions ``q_pos``;
-    ``kv_x`` (Sk, H) at ``kv_pos`` the rows keys and values come from (the
-    same rows for an unblocked forward)."""
+    ``k``, ``v`` (Sk, nkv, hd) at ``kpos`` the keys and values of the rows
+    they may attend to (their own among them)."""
     _, nq, nkv, hd = _dims(fields)
     eps, theta = float(fields["rms_norm_eps"]), float(fields["rope_theta"])
-    lw = jax.tree.map(lambda a: a.astype(F32), lw)
     r = _rms_norm(x, lw["ln1"], eps)
-    rk = _rms_norm(kv_x, lw["ln1"], eps)
-    kpos = kv_pos
     q = (r @ lw["wq"]).reshape(-1, nq, hd)
-    k = (rk @ lw["wk"]).reshape(-1, nkv, hd)
-    v = (rk @ lw["wv"]).reshape(-1, nkv, hd)
     q = jnp.where(use_rope, _rope(q, q_pos, theta), q)
-    k = jnp.where(use_rope, _rope(k, kpos, theta), k)
     rep = nq // nkv
     k = jnp.repeat(k, rep, axis=1)
     v = jnp.repeat(v, rep, axis=1)
@@ -80,21 +84,51 @@ def _rope_flags(fields):
     return (idx + 1) % n != 0 if n else jnp.ones_like(idx, dtype=bool)
 
 
+#: past this many blocks, blocks share a context: see ``hidden``
+MAX_CONTEXTS = 8
+
+
 def hidden(params, ids, fields, block: int | None = None):
     """ids (S,) -> final-norm hidden states (S, H), float32.  With
     ``block`` set, every layer runs its queries in blocks of that many rows
     (each block against its whole causal context), so a long context never
-    holds S x S scores at once; the result is the same."""
+    holds S x S scores at once; the result is the same.  Past
+    ``MAX_CONTEXTS`` blocks (of a length they divide), consecutive blocks
+    run as one loop against the keys up to the last of them, the later
+    ones masked: a few programs to compile instead of one a block."""
     S = ids.shape[0]
     block = min(block or S, S)
+    n_blocks = -(-S // block)
+    per = -(-n_blocks // MAX_CONTEXTS) if S % block == 0 else 1
     pos = jnp.arange(S)
     x = params["embed"][ids].astype(F32)
 
+    # several blocks: each is rematerialised on its own, so a backward pass
+    # holds one block's scores at a time; the weights are upcast once, so
+    # the blocks' gradients add up in float32
+    remat = jax.checkpoint if n_blocks > 1 else (lambda f, **kw: f)
+
+    def rows(x, k, v, lw, use_rope, s, e):
+        """Rows s..e: one block, or ``per`` blocks against the keys up to e."""
+        def layer(blk):
+            xb, pb = blk
+            return _layer(xb, lw, use_rope, fields, pb, k[:e], v[:e], pos[:e])
+
+        if e - s <= block:
+            return layer((x[s:e], pos[s:e]))
+        out = jax.lax.map(remat(layer), (x[s:e].reshape(-1, block, x.shape[1]),
+                                         pos[s:e].reshape(-1, block)))
+        return out.reshape(e - s, -1)
+
+    step = block * per
+    one = remat(rows, static_argnums=(5, 6)) if per == 1 else rows
+
     def body(x, scanned):
         lw, use_rope = scanned
-        outs = [_layer(x[s:s + block], lw, use_rope, fields,
-                       pos[s:s + block], x[:s + block], pos[:s + block])
-                for s in range(0, S, block)]
+        lw = jax.tree.map(lambda a: a.astype(F32), lw)
+        k, v = _kv(x, lw, use_rope, fields, pos)
+        outs = [one(x, k, v, lw, use_rope, s, min(s + step, S))
+                for s in range(0, S, step)]
         return jnp.concatenate(outs, 0), None
 
     # rematerialised per layer: the same arithmetic, but a backward pass
@@ -125,6 +159,9 @@ def loss(params, ids, labels, fields, block: int | None = None):
             gold = jnp.take_along_axis(lg, lb[:, None], axis=-1)[:, 0]
             return jnp.sum(logz - gold)
 
+        # several blocks: a backward pass re-derives each block's logits
+        if block < S:
+            nll_sum = jax.checkpoint(nll_sum)
         sums = jax.lax.map(nll_sum, (x.reshape(S // block, block, -1),
                                      labels.reshape(S // block, block)))
         return jnp.sum(sums) / S

@@ -75,9 +75,13 @@ def main(argv=None) -> int:
     phases = harness.Phases(T_ENTRY)
     phases.mark("imports")
     runner = harness.find_module("runners", cell.runner)
-    obs = runner.run(cell, seed=args.seed, seconds=seconds,
+    ref = harness.find_module("reference", cell.architecture,
+                              needs=runner.REFERENCE_EXPORTS)
+    obs = runner.run(cell, ref=ref, seed=args.seed, seconds=seconds,
                      trace=bool(args.trace), rehearse=args.rehearse_cpu,
                      watch=watch, phases=phases)
+    obs["check"]["reference"] = str(
+        Path(ref.__file__).relative_to(harness.ROOT))
     phases.mark("after_window")
     print(f"[bench] phases: {phases}", file=sys.stderr)
     if args.probe:
@@ -94,7 +98,8 @@ def main(argv=None) -> int:
         return 0 if obs["check"]["ok"] and not obs["failed"] else 1
 
     ctx = harness.Context(cell=cell, fields=obs["fields"],
-                          counters=obs["counters"], peaks=peaks)
+                          counters=obs["counters"], peaks=peaks,
+                          counts=harness.cell_counts(cell))
     device["memory_peak_bytes"] = harness.memory_peak_bytes(obs["devices"])
     breakdown = None
     if args.trace:

@@ -21,9 +21,10 @@ import time
 import numpy as np
 
 from benchmarks import harness
-from benchmarks.reference import dense_gqa as ref
 
 IDLE_SLEEP_S = 0.001
+#: what this runner calls of the architecture's reference (harness docstring)
+REFERENCE_EXPORTS = ("logits_at",)
 
 
 def init_weights(mcfg, seed: int, scale: float):
@@ -100,7 +101,7 @@ class Driver:
                     time.sleep(min(max(nxt - now, 0.0), IDLE_SLEEP_S))
 
 
-def check_against_reference(params, fields, done, seed: int, spec: dict,
+def check_against_reference(ref, params, fields, done, seed: int, spec: dict,
                             tol: dict, s_ref: int, n_pos: int) -> dict:
     """Teacher-force a seeded sample of completed requests through the
     plain float32 reference.  The engine exposes tokens, not logits, so the
@@ -148,6 +149,7 @@ def check_against_reference(params, fields, done, seed: int, spec: dict,
            "argmax_agreement": agree / n if n else float("nan")}
     out["ok"] = bool(n > 0 and worst <= float(tol["gap_sigma_max"])
                      and out["gap_sigma_mean"] <= float(tol["gap_sigma_mean"]))
+    out["limits"] = {k: tol[k] for k in ("gap_sigma_max", "gap_sigma_mean")}
     return out
 
 
@@ -288,8 +290,8 @@ def window(st, window_trace, seconds: float, drain_s: float,
     }
 
 
-def run(cell, *, seed: int, seconds: float, trace: bool, rehearse: bool,
-        watch, phases) -> dict:
+def run(cell, *, ref, seed: int, seconds: float, trace: bool,
+        rehearse: bool, watch, phases) -> dict:
     import jax
     st = setup(cell, seed, rehearse, harness.spans(trace), phases)
     params_t, engine = st["params_t"], st["engine"]
@@ -314,10 +316,10 @@ def run(cell, *, seed: int, seconds: float, trace: bool, rehearse: bool,
     # ---- correctness, outside the window
     olen = params_t["output_len"]
     check = check_against_reference(
-        st["params"], st["fields"], done, seed,
+        ref, st["params"], st["fields"], done, seed,
         cell.traffic["check"] if not rehearse
         else cell.traffic["rehearse"]["check"],
-        cell.config["check"], s_ref=int(params_t["max_total"]),
+        cell.check, s_ref=int(params_t["max_total"]),
         n_pos=int(olen.get("max", olen.get("value")))) \
         if done else {"ok": False, "requests_checked": 0}
     check["retraces_after_warmup"] = retraces

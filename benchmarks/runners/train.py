@@ -11,26 +11,39 @@ The window is a fixed amount of work: after warm-up the runner times two
 blocked steps, dispatches ``ceil(seconds / step time)`` whole steps through
 the pump, and closes the window when the last loss is ready.
 
-``correct`` holds two things to the plain reference, outside the window.
-The model: ``lm_loss`` and its gradient norms per group on one sequence's
-first positions.  And the program that is measured: the loss the real step
-returns for the stream's first batch on the initial weights agrees with the
-reference's float32 loss of that whole batch, and the same batch fed once
-more, after one update, loses what the configuration's ``step_drop`` band
-says one AdamW update takes off it (the step returns nothing but its loss,
-so the update is held by what it does to the loss).
+``correct`` holds the program that is measured to the plain reference,
+outside the window.  The loss the real step returns for the stream's first
+batch on the initial weights agrees with the reference's float32 loss of
+that whole batch, and the same batch fed once more, after one update, loses
+what the ``step_drop`` band says one AdamW update takes off it.  The
+gradient, per parameter group, one of two ways, as the cell's ``check``
+says:
+
+* ``"gradient": "step"``: the first gradient as the optimizer got it in the
+  timed step itself, at the cell's own batch and length.  Adam's first
+  moment after one update from zeros is ``(1 - b1)`` times that gradient,
+  so its norms are read from the step's state; the reference's are those
+  of its loss of the whole batch, taken ``block`` rows at a time.  With
+  it, the norms of the change that first update made to the parameters,
+  against one plain Adam update from the reference's gradient.
+* otherwise (``positions``): ``lm_loss`` and its gradient in a pass of
+  their own on the first ``positions`` of one sequence.
+
+A rehearsal merges the data files' ``rehearse.check`` over the tolerances.
 """
 
 from __future__ import annotations
 
+import inspect
 import itertools
 import math
 import time
 
 from benchmarks import harness
-from benchmarks.reference import dense_gqa as ref
 
 WARM_STEPS = 3        # one that compiles, then two that are timed
+#: what this runner calls of the architecture's reference (harness docstring)
+REFERENCE_EXPORTS = ("loss", "group_sumsq", "group_norms")
 
 
 def init_sharded(mcfg, mesh, seed: int):
@@ -50,7 +63,7 @@ def init_sharded(mcfg, mesh, seed: int):
     return params, shardings
 
 
-def system_loss_and_norms(params, mcfg, mesh, ids, labels):
+def system_loss_and_norms(ref, params, mcfg, mesh, ids, labels):
     """The system's ``lm_loss`` on one sequence, and its gradient norms per
     group, with the parameters sharded as they rest.  The splash kernel
     cannot be partitioned automatically, so this runs under ``shard_map``
@@ -96,7 +109,7 @@ def system_loss_and_norms(params, mcfg, mesh, ids, labels):
     return f(params, ids, labels)
 
 
-def reference_loss_and_norms(params, shardings, fields, ids, labels):
+def reference_loss_and_norms(ref, params, shardings, fields, ids, labels):
     """The plain float32 reference under ``jit`` with the parameters
     sharded as they rest and its gradients held to the same sharding, so
     that it fits beside the cell's state."""
@@ -111,14 +124,14 @@ def reference_loss_and_norms(params, shardings, fields, ids, labels):
     return jax.jit(f)(params, ids, labels)
 
 
-def check_against_reference(params, shardings, mcfg, mesh, fields, ids,
-                            labels, tol: dict) -> dict:
+def check_against_reference(ref, params, shardings, mcfg, mesh, fields,
+                            ids, labels, tol: dict) -> dict:
     """The system's loss and per-group gradient norms on one seeded
     sequence against the reference's, within the configuration's stated
     tolerances."""
-    sys_loss, sys_norms = system_loss_and_norms(params, mcfg, mesh, ids,
+    sys_loss, sys_norms = system_loss_and_norms(ref, params, mcfg, mesh, ids,
                                                 labels)
-    ref_loss, ref_norms = reference_loss_and_norms(params, shardings,
+    ref_loss, ref_norms = reference_loss_and_norms(ref, params, shardings,
                                                    fields, ids, labels)
     sys_loss, ref_loss = float(sys_loss), float(ref_loss)
     rel = {g: abs(float(sys_norms[g]) - float(ref_norms[g]))
@@ -134,7 +147,7 @@ def check_against_reference(params, shardings, mcfg, mesh, fields, ids,
     return out
 
 
-def reference_batch_loss(params, fields, batch, block: int) -> float:
+def reference_batch_loss(ref, params, fields, batch, block: int) -> float:
     """The reference's float32 loss of a whole batch (the mean over its
     sequences, as the step's own mean over chips and sequences), under
     ``jit`` with the parameters sharded as they rest and the batch sharded
@@ -147,6 +160,68 @@ def reference_batch_loss(params, fields, batch, block: int) -> float:
         return jnp.mean(jax.vmap(one)(ids, labels))
 
     return float(jax.jit(f)(params, *batch))
+
+
+def reference_first_update(ref, params, shardings, fields, batch, block: int,
+                           lr: float, eps: float):
+    """``reference_batch_loss`` with, per group, the norm of that loss's
+    gradient (held to the parameters' sharding) and the norm of the change
+    one plain Adam update from a zero state makes to the parameters as they
+    are stored: bias-corrected moments ``g`` and ``g * g``, so each weight
+    moves by ``lr * g / (|g| + eps)`` and is rounded to its own dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    def change(p, g):
+        # reduce_precision, not a cast there and back, which XLA may drop
+        g, was, fi = g.astype(jnp.float32), p.astype(jnp.float32), \
+            jnp.finfo(p.dtype)
+        return jax.lax.reduce_precision(
+            was - lr * g / (jnp.abs(g) + eps), fi.nexp, fi.nmant) - was
+
+    def f(p, ids, labels):
+        def mean_loss(q):
+            one = lambda i, l: ref.loss(q, i, l, fields, block=block)  # noqa: E731
+            return jnp.mean(jax.vmap(one)(ids, labels))
+
+        loss, grads = jax.value_and_grad(mean_loss)(p)
+        grads = jax.lax.with_sharding_constraint(grads, shardings)
+        return loss, ref.group_norms(grads), ref.group_norms(
+            jax.tree.map(change, p, grads))
+
+    loss, *norms = jax.jit(f)(params, *batch)
+    return (float(loss),
+            *({g: float(v) for g, v in n.items()} for n in norms))
+
+
+def check_first_update(ref, before, after, mu, b1: float, ref_grad: dict,
+                       ref_change: dict, tol: dict) -> dict:
+    """The timed step's first update against the reference's, per group:
+    the gradient its optimizer got, from Adam's first moment after it
+    (``mu = (1 - b1) x gradient``, from zeros, with ``1 - b1`` as the
+    moment's own dtype holds it: 0.1 is 0.1001 in bfloat16), and the change
+    it made to the parameters."""
+    import jax
+    import jax.numpy as jnp
+    grad = jax.jit(ref.group_norms)(mu)
+    kept = float(jnp.asarray(1.0 - b1, jax.tree.leaves(mu)[0].dtype))
+    moved = jax.jit(lambda a, b: ref.group_norms(jax.tree.map(
+        lambda x, y: x.astype(jnp.float32) - y.astype(jnp.float32), a, b))
+    )(after, before)
+    # a group that one update cannot move as it is stored (bf16 norm
+    # weights at 1.0) has a reference norm of 0: the gap is then absolute
+    rel = lambda have, want: abs(have - want) / (want or 1.0)  # noqa: E731
+    out = {"gradient": "step",
+           "grad_norm_reference": ref_grad, "update_norm_reference": ref_change,
+           "grad_norm_rel_diff": {g: rel(float(grad[g]) / kept, w)
+                                  for g, w in ref_grad.items()},
+           "update_norm_rel_diff": {g: rel(float(moved[g]), w)
+                                    for g, w in ref_change.items()}}
+    out["ok"] = all(
+        math.isfinite(v) and v <= float(tol[key][g])
+        for key in ("grad_norm_rel", "update_norm_rel")
+        for g, v in out[key + "_diff"].items())
+    return out
 
 
 def check_step(loss0: float, loss1: float, ref0: float, tol: dict,
@@ -166,8 +241,8 @@ def check_step(loss0: float, loss1: float, ref0: float, tol: dict,
     return out
 
 
-def run(cell, *, seed: int, seconds: float, trace: bool, rehearse: bool,
-        watch, phases) -> dict:
+def run(cell, *, ref, seed: int, seconds: float, trace: bool,
+        rehearse: bool, watch, phases) -> dict:
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -191,8 +266,6 @@ def run(cell, *, seed: int, seconds: float, trace: bool, rehearse: bool,
             f"{cell.chips} chip(s)")
 
     params, shardings = init_sharded(mcfg, mesh, seed)
-    opt_state = fsdp.init_fsdp_opt_state(params)
-    step = fsdp.make_fsdp_train_step(params, mcfg, mesh)
     jax.block_until_ready(params)
     phases.mark("weights")
 
@@ -200,22 +273,32 @@ def run(cell, *, seed: int, seconds: float, trace: bool, rehearse: bool,
     seq_len, gb = int(params_t["seq_len"]), int(params_t["global_batch"])
     tokens_per_step = seq_len * gb
 
-    # ---- correctness, outside the window, on the weights as initialised:
-    # the first positions of the stream's first sequence, then the
-    # reference's loss of the whole first batch, for the real step
-    tol = cell.config["check"]
+    # ---- correctness, outside the window, on the weights as initialised
+    # and before the optimizer's state is made: the reference's loss of the
+    # stream's whole first batch, for the real step, and its gradient norms
+    tol = cell.tolerances(rehearse)
+    from_step = tol.get("gradient") == "step"
     batch0 = next(traffic.batches(params_t, seed, mcfg.vocab_size))
     ids, labels = batch0
-    n_check = int(cell.traffic["check"]["positions"]
-                  if not rehearse else min(seq_len, 128))
-    check = check_against_reference(
-        params, shardings, mcfg, mesh, fields, jnp.asarray(ids[0, :n_check]),
-        jnp.asarray(labels[0, :n_check]), tol)
-    ref0 = reference_batch_loss(
-        params, fields,
-        jax.device_put(batch0, NamedSharding(mesh, P("dp"))),
-        block=n_check)
+    on_mesh = jax.device_put(batch0, NamedSharding(mesh, P("dp")))
+    adam = {k: v.default for k, v in inspect.signature(
+        fsdp.make_fsdp_train_step).parameters.items()
+        if k in ("lr", "b1", "eps")}     # the program's own defaults
+    if from_step:
+        ref0, ref_grad, ref_change = reference_first_update(
+            ref, params, shardings, fields, on_mesh, int(tol["block"]),
+            adam["lr"], adam["eps"])
+    else:
+        n_check = min(seq_len, 128) if rehearse else int(tol["positions"])
+        check = check_against_reference(
+            ref, params, shardings, mcfg, mesh, fields,
+            jnp.asarray(ids[0, :n_check]), jnp.asarray(labels[0, :n_check]),
+            tol)
+        ref0 = reference_batch_loss(ref, params, fields, on_mesh,
+                                    block=n_check)
     phases.mark("reference_check")
+    opt_state = fsdp.init_fsdp_opt_state(params)
+    step = fsdp.make_fsdp_train_step(params, mcfg, mesh)
 
     span = harness.spans(trace)
     waits: list[float] = []
@@ -239,18 +322,26 @@ def run(cell, *, seed: int, seconds: float, trace: bool, rehearse: bool,
         [batch0], traffic.batches(params_t, seed, mcfg.vocab_size))
     with DevicePrefetcher(stream, mesh=mesh, spec=P("dp")) as pref:
         # ---- warm-up: the one shape this cell uses
+        before = jax.tree.map(jnp.copy, params) if from_step else None
         with StepPump() as pump:
             loop(pref, pump, 1)
         loss0 = pump.losses[0]
+        if from_step:
+            check = check_first_update(ref, before, params, opt_state.mu,
+                                       adam["b1"], ref_grad, ref_change, tol)
+            del before
         phases.mark("first_step")
         t0 = time.perf_counter()
         with StepPump() as pump:
             loop(pref, pump, WARM_STEPS - 1)
         step_s = (time.perf_counter() - t0) / (WARM_STEPS - 1)
-        check.update(check_step(
-            loss0, pump.losses[0], ref0, tol,
-            None if rehearse else tol.get("step_drop")))
+        check.update(check_step(loss0, pump.losses[0], ref0, tol,
+                                tol.get("step_drop")))
         check["ok"] = bool(check["ok"] and check["step_ok"])
+        check["limits"] = {k: tol[k] for k in
+                           ("loss_abs", "grad_norm_rel", "update_norm_rel",
+                            "step_drop")
+                           if k in tol}
 
         if rehearse:
             n_steps = 3

@@ -1,5 +1,6 @@
 """Percentile and window arithmetic, the peaks table, the result line, and
-the yardstick's FLOP counts against the program's.  CPU only."""
+the yardstick's FLOP counts (``counts/<architecture>.py``, reached as the
+readers reach them) against the program's.  CPU only."""
 
 import json
 import math
@@ -12,7 +13,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from benchmarks import flops, harness  # noqa: E402
+from benchmarks import harness  # noqa: E402
 
 
 @pytest.mark.parametrize("values,q,want", [
@@ -212,28 +213,161 @@ def published():
                        ).read_text())["fields"]
 
 
-def test_flops_match_the_programs_own_arithmetic(published):
+@pytest.fixture(scope="module")
+def counts():
+    return harness.cell_counts(harness.load_cell("train-fsdp4-8k"))
+
+
+def test_flops_match_the_programs_own_arithmetic(published, counts):
     from distributed_training_sandbox_tpu.models import transformer as T
     from distributed_training_sandbox_tpu.utils.flops import (
         get_model_flops_per_token)
-    assert flops.param_count(published) == T.SMOLLM3_3B.param_count()
-    assert flops.model_flops_per_token(published, 8192) == pytest.approx(
+    assert counts.param_count(published) == T.SMOLLM3_3B.param_count()
+    assert counts.model_flops_per_token(published, 8192) == pytest.approx(
         get_model_flops_per_token(T.SMOLLM3_3B, 8192))
-    assert flops.kv_bytes_per_token(published) == 73728
+    assert counts.kv_bytes_per_token(published) == 73728
 
 
-def test_attention_and_decode_yardsticks_by_hand(published):
+def test_attention_and_decode_yardsticks_by_hand(published, counts):
     one = {**published, "num_hidden_layers": 1}
     # 6 matmuls x 2·S²·hd per head x 16 heads, halved by the causal mask
-    assert flops.attention_kernel_flops(one, 1024, 1) == pytest.approx(
+    assert counts.attention_kernel_flops(one, 1024, 1) == pytest.approx(
         6 * 2 * 1024 * 1024 * 128 * 16 * 0.5)
     peaks = harness.load_peaks("TPU v5 lite")
-    t, bound = flops.roofline_seconds(
-        flops.attention_kernel_flops(published, 8192, 1),
-        flops.attention_kernel_bytes(published, 8192, 1), peaks)
+    t, bound = harness.roofline_seconds(
+        counts.attention_kernel_flops(published, 8192, 1),
+        counts.attention_kernel_bytes(published, 8192, 1), peaks)
     assert bound == "compute"
-    n = flops.param_count(published)
-    assert flops.decode_step_bytes(published, 1000) == pytest.approx(
+    n = counts.param_count(published)
+    assert counts.decode_step_bytes(published, 1000) == pytest.approx(
         2 * n + 1000 * 73728)
-    t, bound = flops.roofline_seconds(0.0, 819e9, peaks)
+    t, bound = harness.roofline_seconds(0.0, 819e9, peaks)
     assert (t, bound) == (pytest.approx(1.0), "memory")
+
+
+# What ``benchmarks/flops.py`` returned at PR 24 (commit 14c8757) on the three
+# configuration files, before its block-dependent functions moved to
+# ``counts/dense_gqa.py``: param_count, model_flops_per_token(8192),
+# decode_step_bytes(10,000 KV tokens), kv_bytes_per_token,
+# attention_kernel_flops / _bytes (8192, 1 window), proj/MLP weights.
+PR24_COUNTS = {
+    "train-dense-8k": (887654400, 6131023872.0, 1939148800.0, 16384,
+                       6597069766656.0, 2013265920.0, 624951296),
+    "train-fsdp4-8k": (3075098624, 22073573376.0, 6887477248.0, 73728,
+                       29686813949952.0, 9059696640.0, 2812280832),
+    "serve-chat": (3075098624, 22073573376.0, 6887477248.0, 73728,
+                   29686813949952.0, 9059696640.0, 2812280832),
+}
+
+
+@pytest.mark.parametrize("cell_name", sorted(PR24_COUNTS))
+def test_the_counts_reached_through_the_context_are_pr24s(cell_name):
+    """The move cannot shift a roofline share: a reader's ``ctx.counts``
+    gives, bit for bit, what ``flops.py`` gave."""
+    cell = harness.load_cell(cell_name)
+    ctx = harness.Context(cell=cell, fields=cell.config["fields"],
+                          counters={}, peaks=None,
+                          counts=harness.cell_counts(cell))
+    f, c = ctx.fields, ctx.counts
+    got = (c.param_count(f), c.model_flops_per_token(f, 8192),
+           c.decode_step_bytes(f, 10000.0), c.kv_bytes_per_token(f),
+           c.attention_kernel_flops(f, 8192, 1),
+           c.attention_kernel_bytes(f, 8192, 1), c.proj_mlp_weight_count(f))
+    assert got == PR24_COUNTS[cell_name]
+    assert Path(c.__file__) == ROOT / "benchmarks/counts/dense_gqa.py"
+
+
+@pytest.mark.parametrize("name,counters,want", [
+    ("train_mfu_pct", {"seq_len": 8192, "tokens": 4 * 32768,
+                       "elapsed_s": 7.672},
+     100 * 6131023872.0 * 4 * 32768 / (7.672 * 197e12)),
+    ("decode_roofline", {"kv_valid_sum": 20000, "kv_samples": 2,
+                         "program_launches": {"decode": 2}},
+     100 * (1939148800.0 / 819e9) / 0.004),
+])
+def test_the_counting_readers_read_through_the_context(name, counters, want,
+                                                       monkeypatch):
+    """``train_mfu_pct`` and ``decode_roofline`` on the 8-layer fields: the
+    same arithmetic as at PR 24, with the counts taken from ``ctx.counts``."""
+    cell = harness.load_cell("train-dense-8k")
+    reader = _reader("layer_metrics", name)
+    if name == "decode_roofline":
+        from benchmarks.layer_metrics import _programs
+        monkeypatch.setattr(_programs, "device_seconds_per_launch",
+                            lambda ctx, program: 0.004)
+    ctx = SimpleNamespace(counters=counters, fields=cell.config["fields"],
+                          chips=1, trace=None, counts=harness.cell_counts(cell),
+                          peaks=harness.load_peaks("TPU v5 lite"))
+    assert reader.read(ctx) == pytest.approx(want)
+
+
+def test_a_missing_count_names_the_file(tmp_path):
+    (tmp_path / "counts").mkdir()
+    (tmp_path / "counts/half_block.py").write_text(
+        "def param_count(fields):\n    return 1\n")
+    counts = harness.find_module("counts", "half-block", tmp_path,
+                                 needs=("param_count",))
+    assert counts.param_count({}) == 1
+    with pytest.raises(harness.BenchmarkError,
+                       match=r"half_block\.py has no 'decode_step_bytes'"):
+        harness.find_module("counts", "half-block", tmp_path,
+                            needs=("param_count", "decode_step_bytes"))
+
+
+def test_a_cell_needs_only_the_counts_its_readers_list(tmp_path):
+    """``cell_counts`` asks a counts module for what the cell's readers
+    list in ``COUNTS`` and no more; a cell none of whose readers counts
+    needs no module at all."""
+    cell = harness.load_cell("train-dense-8k")
+    listed = {n for m in cell.per_layer for n in getattr(m.module, "COUNTS", ())}
+    assert listed == {"model_flops_per_token", "proj_mlp_weight_count",
+                      "attention_kernel_flops", "attention_kernel_bytes"}
+    cell.config["architecture"] = "no-such-block"
+    with pytest.raises(harness.BenchmarkError, match=r"no_such_block\.py"):
+        harness.cell_counts(cell)
+    cell.per_layer = [m for m in cell.per_layer
+                      if not hasattr(m.module, "COUNTS")]
+    assert harness.cell_counts(cell) is None
+
+
+def test_a_traffic_files_check_merges_over_the_configurations():
+    """A mix at other sizes states the bands it read there; what it does
+    not state stays the configuration's."""
+    cell = harness.load_cell("train-dense-32k")
+    assert cell.check["loss_abs"] == cell.config["check"]["loss_abs"]
+    cell.traffic["check"]["step_drop"] = [1.0, 2.0]
+    cell.traffic["check"]["grad_norm_rel"] = {"mlp": 0.5}
+    assert cell.check["step_drop"] == [1.0, 2.0]
+    assert cell.check["grad_norm_rel"] == {
+        **cell.config["check"]["grad_norm_rel"], "mlp": 0.5}
+    assert cell.config["check"]["grad_norm_rel"]["mlp"] != 0.5   # a copy
+
+
+@pytest.mark.parametrize("seq,block", [(512, 128), (512, 16), (352, 32)])
+def test_the_blocked_reference_has_the_unblocked_loss_and_gradient(seq, block):
+    """``reference/dense_gqa.py`` taken ``block`` rows at a time: every block
+    on its own context (4 blocks), consecutive blocks in one loop against a
+    shared masked context (32 blocks, 8 contexts), and a last context that
+    is shorter (11 blocks).  The loss and every gradient leaf are the
+    unblocked pass's, in float32 at the rehearsal's widths."""
+    import jax
+    import jax.numpy as jnp
+    from distributed_training_sandbox_tpu.models import transformer as T
+    cell = harness.load_cell("train-dense-32k")
+    fields = {**cell.config["fields"], **cell.config["rehearse"]["fields"]}
+    ref = harness.find_module("reference", cell.architecture)
+    params = T.init_params(jax.random.key(1), harness.model_config(fields))
+    ids = jax.random.randint(jax.random.key(2), (seq,), 0,
+                             fields["vocab_size"])
+    labels = jnp.roll(ids, -1)
+
+    def loss_and_grad(b):
+        return jax.jit(jax.value_and_grad(
+            lambda p: ref.loss(p, ids, labels, fields, block=b)))(params)
+
+    want, want_g = loss_and_grad(None)
+    got, got_g = loss_and_grad(block)
+    assert float(got) == pytest.approx(float(want), abs=5e-6)
+    for a, b in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-7
+

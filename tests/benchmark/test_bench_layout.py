@@ -3,6 +3,7 @@ a per-layer metric is added by adding files and entries, editing no file
 that is there.  And ``BENCHMARK.json`` keeps to its contract's limits."""
 
 import json
+import os
 import re
 import shutil
 import subprocess
@@ -89,6 +90,167 @@ def test_adding_files_and_entries_adds_a_cell_and_a_metric(tmp_path, bm):
     assert "queue_depth_max" not in cells["serve-chat"]["per_layer"]
     after = {p: p.read_bytes() for p in before}
     assert after == before
+
+
+# ---------------------------------------- a new architecture, as files only
+
+ONE_HOT_REFERENCE = '''"""A reference that disagrees with every program:
+zeros everywhere but token 0."""
+import jax.numpy as jnp
+
+
+def logits_at(params, ids, positions, fields, block=1024):
+    v = int(fields["vocab_size"])
+    return jnp.zeros((positions.shape[0], v), jnp.float32).at[:, 0].set(1.0)
+'''
+
+
+def _add_cell(new, name, arch):
+    new["configs"].append({"name": name, "source": "paper",
+                           "file": f"benchmarks/configs/{name}.json",
+                           "reduced": [], "why": f"architecture {arch}"})
+    cell = name.replace("-serve", "-chat")
+    new["workloads"].append({"name": cell, "config": name,
+                             "traffic": "chat-poisson", "chips": 1,
+                             "why": "a new cell"})
+    for m in new["end_to_end"] + new["per_layer"]:
+        if "serve-chat" in m.get("workloads", []):
+            m["workloads"].append(cell)
+
+
+@pytest.fixture(scope="module")
+def new_arch(tmp_path_factory, bm):
+    """A copy of the benchmark that gains ONLY new files: a reference and a
+    counts module under two new architecture names, configurations that
+    name them, entries.  ``root/BENCHMARK.json`` lists the two sound new
+    cells; ``root/faulty`` (the same ``benchmarks`` through a link) lists
+    the two configurations whose ``architecture`` is wrong or missing."""
+    root = tmp_path_factory.mktemp("new_arch")
+    b = root / "benchmarks"
+    shutil.copytree(ROOT / "benchmarks", b,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in b.rglob("*") if p.is_file()}
+    shutil.copy(b / "reference/dense_gqa.py", b / "reference/new_block.py")
+    (b / "reference/one_hot.py").write_text(ONE_HOT_REFERENCE)
+    for arch in ("new_block", "one_hot"):
+        shutil.copy(b / "counts/dense_gqa.py", b / f"counts/{arch}.py")
+    cfg = json.loads((b / "configs/smollm3-3b-serve.json").read_text())
+    sound, faulty = json.loads(json.dumps(bm)), json.loads(json.dumps(bm))
+    for arch, name, new in (("new-block", "new-block-serve", sound),
+                            ("one-hot", "one-hot-serve", sound),
+                            ("no-such-block", "no-module-serve", faulty),
+                            (None, "no-arch-serve", faulty)):
+        named = {**cfg, "architecture": arch, "why": f"architecture {arch}"}
+        if arch is None:
+            del named["architecture"]
+        (b / f"configs/{name}.json").write_text(json.dumps(named))
+        _add_cell(new, name, arch)
+    (root / "BENCHMARK.json").write_text(json.dumps(sound))
+    (root / "faulty").mkdir()
+    (root / "faulty/benchmarks").symlink_to(b, target_is_directory=True)
+    (root / "faulty/BENCHMARK.json").write_text(json.dumps(faulty))
+    return root, before
+
+
+def _rehearse(root, cell):
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT))   # the program
+    return subprocess.run(
+        [sys.executable, str(root / "benchmarks/run.py"), "--workload", cell,
+         "--rehearse-cpu"], capture_output=True, text=True, timeout=300,
+        cwd=str(root), env=env)
+
+
+def test_a_new_architecture_is_listed_with_the_shared_readers(new_arch):
+    root, _ = new_arch
+    cells = _list(root)
+    assert cells["new-block-chat"]["architecture"] == "new-block"
+    assert cells["one-hot-chat"]["architecture"] == "one-hot"
+    assert cells["serve-chat"]["architecture"] == "dense_gqa"
+    # the readers that count are shared, not copied: same entries, more cells
+    assert cells["new-block-chat"]["per_layer"] \
+        == cells["serve-chat"]["per_layer"]
+    assert "decode_roofline" in cells["new-block-chat"]["per_layer"]
+    counts = harness.cell_counts(harness.load_cell("new-block-chat", root),
+                                 root / "benchmarks")
+    assert Path(counts.__file__) == root / "benchmarks/counts/new_block.py"
+
+
+def test_a_new_architectures_rehearsal_uses_its_own_reference(new_arch):
+    root, _ = new_arch
+    out = _rehearse(root, "new-block-chat")
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert "cell=new-block-chat" in out.stdout
+    assert "reference_ok=True" in out.stdout
+    check = json.loads(out.stdout.split("rehearsal check: ", 1)[1])
+    assert check["reference"] == "benchmarks/reference/new_block.py"
+
+
+def test_the_reference_lookup_is_live(new_arch):
+    """The same program against a reference that puts token 0 first
+    everywhere: ``reference_ok`` turns False, so the module the
+    configuration names is the one that judges."""
+    root, _ = new_arch
+    out = _rehearse(root, "one-hot-chat")
+    assert out.returncode == 1, out.stdout + out.stderr
+    assert "failed=0 reference_ok=False" in out.stdout
+    check = json.loads(out.stdout.split("rehearsal check: ", 1)[1])
+    assert check["reference"] == "benchmarks/reference/one_hot.py"
+    assert check["gap_sigma_max"] > 3.0 and check["tokens_checked"] > 0
+
+
+def test_an_architecture_with_no_module_names_the_path(new_arch):
+    root, _ = new_arch
+    with pytest.raises(harness.BenchmarkError,
+                       match=r"reference/no_such_block\.py"):
+        harness.load_cell("no-module-chat", root / "faulty")
+    with pytest.raises(harness.BenchmarkError, match="no_such_block"):
+        harness.list_cells(root / "faulty")     # so --list fails too
+
+
+def test_a_configuration_without_an_architecture_is_refused(new_arch):
+    root, _ = new_arch
+    with pytest.raises(harness.BenchmarkError,
+                       match=r"no-arch-serve\.json names no \"architecture\""):
+        harness.load_cell("no-arch-chat", root / "faulty")
+
+
+def test_a_reference_without_what_its_runner_calls_names_the_file(new_arch):
+    """``one_hot.py`` has ``logits_at`` and nothing else: enough for
+    ``serve``, and for ``train`` a ``BenchmarkError`` before any work."""
+    root, _ = new_arch
+    cell = harness.load_cell("one-hot-chat", root)
+    bench = root / "benchmarks"
+    serve = harness.find_module("runners", "serve")
+    train = harness.find_module("runners", "train")
+    ref = harness.find_module("reference", cell.architecture, bench,
+                              serve.REFERENCE_EXPORTS)
+    assert Path(ref.__file__) == bench / "reference/one_hot.py"
+    with pytest.raises(harness.BenchmarkError,
+                       match=r"one_hot\.py has no 'loss'"):
+        harness.find_module("reference", cell.architecture, bench,
+                            train.REFERENCE_EXPORTS)
+
+
+def test_the_new_architecture_changed_no_file_that_was_there(new_arch):
+    root, before = new_arch
+    assert {p: p.read_bytes() for p in before} == before
+    added = {str(p.relative_to(root / "benchmarks"))
+             for p in (root / "benchmarks").rglob("*")
+             if p.is_file() and p not in before
+             and "__pycache__" not in p.parts}
+    assert added == {
+        "reference/new_block.py", "reference/one_hot.py",
+        "counts/new_block.py", "counts/one_hot.py",
+        "configs/new-block-serve.json", "configs/one-hot-serve.json",
+        "configs/no-module-serve.json", "configs/no-arch-serve.json"}
+
+
+def test_every_configuration_names_an_architecture_that_is_there(bm):
+    for c in bm["configs"]:
+        arch = json.loads((ROOT / c["file"]).read_text())["architecture"]
+        for kind in ("reference", "counts"):
+            assert harness.module_path(kind, arch).is_file()
 
 
 def test_a_reader_that_disagrees_with_its_entry_is_refused(tmp_path, bm):

@@ -193,12 +193,13 @@ def _ctx(monkeypatch, path, counters, **kw):
 TRAIN_FIELDS = {"hidden_size": 2048, "num_attention_heads": 16,
                 "num_key_value_heads": 4, "head_dim": 128,
                 "intermediate_size": 11008, "num_hidden_layers": 8}
+COUNTS = harness.find_module("counts", "dense_gqa")
 
 
 def test_training_readers(monkeypatch, capsys):
     c = {"steps": 2, "tokens": 2 * 32768}
     ctx = _ctx(monkeypatch, FIXTURE, c, chips=2, fields=TRAIN_FIELDS,
-               peaks={"bf16_flops_per_s": 197e12})
+               counts=COUNTS, peaks={"bf16_flops_per_s": 197e12})
     assert _reader("proj_mlp_ms").read(ctx) == pytest.approx(0.640 / 2)
     assert "serve/bookkeep" in capsys.readouterr().err    # the table, once
     assert _reader("loss_head_ms").read(ctx) is None      # no such scope
@@ -207,7 +208,7 @@ def test_training_readers(monkeypatch, capsys):
     assert _reader("scope_unattributed_pct").read(ctx) \
         == pytest.approx(100 * 75 / 925)
     roof = _reader("proj_mlp_roofline")
-    assert roof.weight_count(TRAIN_FIELDS) == 8 * 78_118_912
+    assert ctx.counts.proj_mlp_weight_count(TRAIN_FIELDS) == 8 * 78_118_912
     need = 6.0 * 8 * 78_118_912 * 32768 / 2        # per chip and step
     assert roof.read(ctx) == pytest.approx(100 * need / 197e12 / 0.320e-3)
 
@@ -238,6 +239,7 @@ NEW = ["proj_mlp_ms", "proj_mlp_roofline", "loss_head_ms", "optimizer_ms",
 @pytest.mark.parametrize("name", NEW)
 def test_a_reader_finds_nothing_in_an_untraced_run(name):
     ctx = SimpleNamespace(trace=None, chips=1, fields=TRAIN_FIELDS,
+                          counts=COUNTS,
                           counters={"steps": 2, "tokens": 8, "stats": {},
                                     "program_launches": {}})
     assert _reader(name).read(ctx) is None
@@ -261,7 +263,7 @@ def test_readers_find_nothing_on_a_program_without_the_names(monkeypatch,
          "program_launches": {"decode": 1},
          "stats": {"rounds": 1, "admit_s": 0.1, "bookkeep_s": 0.1}}
     ctx = _ctx(monkeypatch, parent, c, chips=2, fields=TRAIN_FIELDS,
-               peaks={"bf16_flops_per_s": 197e12})
+               counts=COUNTS, peaks={"bf16_flops_per_s": 197e12})
     got = {n: _reader(n).read(ctx) for n in NEW}
     assert got.pop("scope_unattributed_pct") == pytest.approx(100.0)
     assert set(got.values()) == {None}
