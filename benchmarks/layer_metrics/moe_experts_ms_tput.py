@@ -1,0 +1,13 @@
+"""Model step: device self time of the decode program's ``moe_experts``
+subscope per launch: the held experts' product over every row and its
+combine, all expert layers of one decode step."""
+from benchmarks.layer_metrics import _subscopes
+
+LAYER = "model step"
+UNIT = "ms"
+MOVES = "serve_tokens_per_s"
+RUNNERS = ("serve",)
+
+
+def read(ctx):
+    return _subscopes.subscope_ms_per_launch(ctx, ("moe_experts",), "decode")

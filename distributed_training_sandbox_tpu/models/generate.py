@@ -77,6 +77,7 @@ def init_cache(cfg: T.TransformerConfig, batch: int,
     """``tp`` > 1: the TENSOR-PARALLEL cache — each rank caches only its
     ``n_kv/tp`` local heads (the KV memory and the per-step cache read
     both shrink by tp, the point of TP-sharded decode)."""
+    T.require_dense_block(cfg, "models.generate.init_cache")
     L, nkv, hd = (cfg.num_hidden_layers, cfg.num_key_value_heads,
                   cfg.resolved_head_dim)
     shape = (batch, nkv // tp, max_len, hd)
@@ -296,6 +297,7 @@ def _generate_core(params, prompt_ids, rng, cfg: T.TransformerConfig,
                    max_new_tokens: int, temperature: float,
                    tp_axis=None, kv_quant: bool = False,
                    cache_capacity: int | None = None):
+    T.require_dense_block(cfg, "models.generate, the one-shot decoder,")
     B, S0 = prompt_ids.shape
     # ``cache_capacity`` pins the attention's contraction extent: XLA's
     # softmax-denominator reduction order depends on the K dimension, so

@@ -142,8 +142,40 @@ class TransformerConfig:
     moe_router_z_weight: float = 0.0
     moe_router_lr_mult: float = 1.0
     ep_axis: str | None = None
+    # The latent-attention + held-experts block (``models/mla_moe.py``;
+    # names as in the published configs of that family).
+    # ``kv_lora_rank`` > 0 selects it: multi-head latent attention
+    # (queries through a ``q_lora_rank`` bottleneck, keys and values
+    # through one ``kv_lora_rank`` latent plus one shared rotary key of
+    # ``qk_rope_head_dim``), sandwich norms, ``first_k_dense_replace``
+    # leading SwiGLU layers of ``intermediate_size`` and then expert
+    # layers: a router of ``router_width`` sigmoid scores (the experts of
+    # the WHOLE layer), ``num_experts_per_tok`` chosen, of which this
+    # program HOLDS ``n_routed_experts``, ids ``expert_offset`` onwards
+    # (one rank's share under expert parallelism), each of
+    # ``moe_intermediate_size``, plus ``n_shared_experts`` applied to
+    # every token.  Serving only (``serving/engine.py``) and the
+    # cache-less ``forward``; the training factories refuse it.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    first_k_dense_replace: int = 0
+    moe_intermediate_size: int = 0
+    router_width: int = 0
+    n_routed_experts: int = 0
+    expert_offset: int = 0
+    n_shared_experts: int = 0
+    num_experts_per_tok: int = 0
+    norm_topk_prob: bool = False
+    routed_scaling_factor: float = 1.0
+    sandwich_norm: bool = False
 
     def __post_init__(self):
+        if self.kv_lora_rank:
+            from .mla_moe import check_config
+            check_config(self)
         # Covers every construction path incl. dataclasses.replace: a
         # sequence-sharded config with a local-chunk attention impl would
         # silently never attend across chunk boundaries.
@@ -181,7 +213,16 @@ class TransformerConfig:
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.hidden_size // self.num_attention_heads
 
+    @property
+    def mla_moe(self) -> bool:
+        """The latent-attention + held-experts block (``models/mla_moe.py``)
+        instead of the dense GQA one."""
+        return self.kv_lora_rank > 0
+
     def param_count(self) -> int:
+        if self.mla_moe:
+            from .mla_moe import param_count
+            return param_count(self)
         h, hd = self.hidden_size, self.resolved_head_dim
         attn = h * hd * (self.num_attention_heads * 2
                          + self.num_key_value_heads * 2)
@@ -280,6 +321,16 @@ CORPUS_350M = TransformerConfig(
 TINY_LM_L8 = replace(TINY_LM, num_hidden_layers=8)
 
 
+def require_dense_block(cfg, what: str) -> None:
+    """Every path that is written for the dense GQA block alone (the
+    training factories of ``parallel/``, the one-shot decoder of
+    ``models/generate.py``) names itself here and refuses the latent
+    block, rather than run it as dense math or fail on a missing key."""
+    if getattr(cfg, "mla_moe", False):
+        from .mla_moe import refuse
+        refuse(cfg, what)
+
+
 # ------------------------------------------------------------------- init
 
 def init_params(key: jax.Array, cfg: TransformerConfig) -> dict:
@@ -287,6 +338,9 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> dict:
     (``fsdp/train_fsdp.py:61-64``), so neither does the default path here.
     Truncated-normal 0.02 (HF default), out-projections scaled by
     1/sqrt(2·layers) for depth-stable residuals."""
+    if cfg.mla_moe:
+        from .mla_moe import init_params as init_block
+        return init_block(key, cfg)
     h = cfg.hidden_size
     hd = cfg.resolved_head_dim
     nq, nkv = cfg.num_attention_heads, cfg.num_key_value_heads
@@ -641,6 +695,13 @@ def hidden_states(params: dict, input_ids: jax.Array,
     """Trunk only: (B, S) ids → final-norm hidden states (B, S, H).
     ``return_aux=True`` additionally returns the per-layer auxiliary
     losses summed (the MoE load-balance term; 0 for dense layers)."""
+    if cfg.mla_moe:
+        from . import mla_moe
+        if layer_hook is not None or layer_body is not None:
+            mla_moe.refuse(cfg, "a layer hook or a substituted layer body "
+                           "(FSDP, ZeRO-3, tensor parallelism)")
+        x = mla_moe.hidden_states(params, input_ids, cfg)
+        return (x, jnp.zeros((), jnp.float32)) if return_aux else x
     B, S = input_ids.shape
     apply_layer = layer_body or _layer_body
     with scope("embed"):
@@ -763,5 +824,6 @@ def xent_from_hidden(x: jax.Array, w_vocab: jax.Array, labels: jax.Array,
 
 
 def model_flops_per_token(cfg: TransformerConfig, seq_len: int) -> float:
+    require_dense_block(cfg, "utils.flops' dense-block arithmetic")
     from ..utils.flops import get_model_flops_per_token
     return get_model_flops_per_token(cfg, seq_len)

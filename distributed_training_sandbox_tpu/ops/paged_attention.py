@@ -53,8 +53,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["paged_attention_decode", "decode_kernel_takes",
-           "refuse_on_tpu"]
+__all__ = ["paged_attention_decode", "paged_latent_attention_decode",
+           "decode_kernel_takes", "refuse_on_tpu"]
 
 # What Pallas' TPU lowering says to the int8 decode kernel and to the
 # flash-prefill kernel (jax 0.9.0, libtpu 0.0.34, TPU v5 lite;
@@ -326,3 +326,151 @@ def _decode_float(qg, pk, pv, pages, lengths, *, block_pages: int,
       qg.reshape(B, R, hd), cols, pk.reshape(n_pages, rows, hd),
       pv.reshape(n_pages, rows, hd))
     return out.reshape(B, 1, nkv, rep, hd)
+
+
+# ------------------------------------------------------------ latent pools
+#
+# Multi-head latent attention (``models/mla_moe.py``): the float kernel's
+# design for a pool that holds ONE row ``[c_kv | k_rope]`` a token and no V
+# pool.  The slot's query rows are its heads' ABSORBED queries
+# ``[q~ | q_rope]``, every head reads the same rows (one "KV head", so no
+# column is another head's), and a block's first ``rank`` columns serve a
+# second time as the values: the output is ``o~``, the probabilities' sum of
+# latents, which the caller up-projects.  The cached rows never are.  (This
+# section stands at the end of the file so that the float kernel above keeps
+# its line numbers, which Mosaic serializes into the dense programs.)
+
+def _latent_decode_kernel(len_ref, pages_ref, q_ref, rows_hbm, o_ref, buf,
+                          sems, *, table_pages: int, block_pages: int,
+                          page: int, rank: int, scale: float, probs_dtype):
+    """Latent pool, one batch slot (S == 1).  ``len_ref`` (B,) and
+    ``pages_ref`` (B * P,) are in SMEM; q_ref (1, n, W) holds the slot's
+    absorbed queries ``[q~ | q_rope]``, W = rank + rope; rows_hbm is the
+    pool as (n_pages, page, W), left in HBM; buf (2, T, W) the double
+    buffer of T = block_pages * page cached rows, sems (2,) its
+    semaphores.  Writes ``o~`` (1, n, rank), float32."""
+    b = pl.program_id(0)
+    length = len_ref[b]
+    span = block_pages * page                  # positions a block
+    n_blocks = (length + span - 1) // span
+
+    def copies(blk, slot):
+        out = []
+        for i in range(block_pages):
+            # a table that is no multiple of the block ends in repeats of
+            # its last entry; their positions are past every length
+            at = jnp.minimum(blk * block_pages + i, table_pages - 1)
+            pid = pages_ref[b * table_pages + at]
+            out.append(pltpu.make_async_copy(
+                rows_hbm.at[pid], buf.at[slot, pl.ds(i * page, page)],
+                sems.at[slot]))
+        return out
+
+    @pl.when(n_blocks > 0)
+    def _():
+        for c in copies(0, 0):
+            c.start()
+
+    q = q_ref[0]                                              # (n, W)
+    n = q.shape[0]
+    col_pos = jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+
+    def block(blk, carry):
+        m, l, acc = carry
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _():
+            for c in copies(blk + 1, 1 - slot):
+                c.start()
+
+        for c in copies(blk, slot):
+            c.wait()
+        rows = buf[slot]                                      # (T, W)
+        s = jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        s = jnp.where(blk * span + col_pos < length, s, -1e30)
+        # every block the loop runs starts under the length, so each row
+        # sees a real score in it and a masked column's exp is exactly 0
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        corr = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * corr + jnp.dot(p.astype(probs_dtype), rows[:, :rank],
+                                   preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    m0 = jnp.full((n, 1), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((n, 1), jnp.float32)
+    a0 = jnp.zeros((n, rank), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, block, (m0, l0, a0))
+    o_ref[0] = acc / jnp.where(l == 0.0, 1.0, l)
+
+
+def paged_latent_attention_decode(qa, pool, pages, lengths, *, rank: int,
+                                  scale: float, probs_dtype=None,
+                                  interpret: bool | None = None):
+    """Decode-step latent attention in absorbed form, the cached rows read
+    in place via the table and never up-projected.
+
+    qa (B, n, rank + rope): each head's absorbed query ``[q~ | q_rope]``;
+    pool (n_pages, page, W): one row ``[c_kv | k_rope | 0...]`` a token,
+    padded with zero columns to W, whole 128-lane tiles (a v5e holds a
+    576-wide bf16 array in 640 columns anyway, and a page's copy must be
+    whole tiles); pages (B, P) int32; lengths (B,) int32, the positions a
+    slot may see (its new row among them), 0 for a slot that holds no
+    request: it reads no page and gets zeros.  Scores are
+    ``scale * qa . row`` in float32, probabilities are cast to
+    ``probs_dtype`` (default: the pool's) before they sum the rows' first
+    ``rank`` columns in float32.  Returns ``o~`` (B, n, rank) float32:
+    plain absorbed attention over the gathered rows, to float32 summation
+    order (``tests/test_mla_moe.py`` holds that oracle).  ``interpret``
+    None: compiled on a TPU, interpreted elsewhere."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    page, W = pool.shape[1:]
+    if qa.shape[-1] < W:     # the pool's rows end in zero columns: so do q's
+        qa = jnp.pad(qa, ((0, 0), (0, 0), (0, W - qa.shape[-1])))
+    if not interpret and not (decode_kernel_takes(pool.dtype, rank, page)
+                              and W % 128 == 0):
+        raise ValueError(
+            f"the latent decode kernel does not compile for a {pool.dtype} "
+            f"pool of rank {rank}, row width {W} and page_size {page} "
+            f"(decode_kernel_takes, rows of whole 128-lane tiles); the "
+            f"engine's XLA path serves it")
+    return _decode_latent(
+        qa, pool, pages, lengths, rank=int(rank), scale=float(scale),
+        block_pages=min(PAGES_PER_BLOCK, pages.shape[1]),
+        probs_dtype=jnp.dtype(probs_dtype or pool.dtype),
+        interpret=bool(interpret))
+
+
+# jitted for the reason ``_decode_float`` is: one trace a program
+@functools.partial(jax.jit, static_argnames=(
+    "rank", "scale", "block_pages", "probs_dtype", "interpret"))
+def _decode_latent(qa, pool, pages, lengths, *, rank: int, scale: float,
+                   block_pages: int, probs_dtype, interpret: bool):
+    B, n, W = qa.shape
+    P = pages.shape[1]
+    page = pool.shape[1]
+    kernel = functools.partial(
+        _latent_decode_kernel, table_pages=P, block_pages=block_pages,
+        page=page, rank=rank, scale=scale, probs_dtype=probs_dtype)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, n, W), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, n, rank), lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, block_pages * page, W),
+                                       pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((B, n, rank), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(lengths.astype(jnp.int32), pages.reshape(-1).astype(jnp.int32),
+      qa.astype(pool.dtype), pool)

@@ -346,6 +346,8 @@ def make_composable_train_step(
     combo runs the rule-driven 3-axis step (new code, new generated
     contract).
     """
+    T.require_dense_block(model_cfg,
+                          "parallel.composable.make_composable_train_step")
     p = plan.normalized()
     strategy = p.strategy_name()
     # the mesh must realize the plan exactly (axis names AND sizes)

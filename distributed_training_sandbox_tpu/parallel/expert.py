@@ -429,6 +429,7 @@ def make_moe_lm_train_step(
 
     from ..models import transformer as T
 
+    T.require_dense_block(cfg, "parallel.expert.make_moe_lm_train_step")
     if not cfg.n_experts:
         raise ValueError("cfg.n_experts must be > 0 for the MoE step")
     ws_dp = int(mesh.shape[dp_axis])

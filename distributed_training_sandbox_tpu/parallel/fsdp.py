@@ -316,6 +316,7 @@ def make_fsdp_train_step(
     backends without a pinned_host space the step is built transfer-free
     and is bitwise-identical to ``offload="none"``.
     """
+    T.require_dense_block(cfg, "parallel.fsdp.make_fsdp_train_step")
     ws = int(mesh.shape[axis])
     if overlap not in OVERLAP_MODES:
         raise ValueError(f"overlap={overlap!r}; choose from "
@@ -480,6 +481,7 @@ def make_fsdp_auto_train_step(
     schedules the collectives (its scheduler may prefetch gathers — this is
     the variant that can beat the explicit one, as torch FSDP2 is to the
     reference's hand-rolled zero3)."""
+    T.require_dense_block(cfg, "parallel.fsdp.make_fsdp_auto_train_step")
     specs = fsdp_specs(params_sharded, axis)
     check_divisibility(params_sharded, specs, mesh)
     pshard = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,

@@ -253,6 +253,7 @@ def build_transformer_pipeline(params: dict, cfg, n_stages: int,
 
     from ..models import transformer as T
 
+    T.require_dense_block(cfg, "parallel.pipeline.build_transformer_pipeline")
     if cfg.n_experts and cfg.ep_axis is not None:
         raise ValueError(
             "MoE×PP stages run one process per stage — experts must be "

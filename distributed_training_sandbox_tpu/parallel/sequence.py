@@ -106,5 +106,6 @@ def make_sp_train_step(
     (``fsdp.shard_params_fsdp`` — sp sees replicas).  Accepts every
     ``make_fsdp_train_step`` knob (reshard_after_forward, lr, donate, …).
     """
+    T.require_dense_block(cfg, "parallel.sequence.make_sp_train_step")
     return make_fsdp_train_step(params_sharded, cfg, mesh, axis=dp_axis,
                                 sp_axis=sp_axis, **kwargs)

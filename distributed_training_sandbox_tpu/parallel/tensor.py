@@ -140,6 +140,7 @@ def make_tp_train_step(
     ``tp_lm_loss`` only (a custom ``loss_fn`` owns its own
     collectives).  ``accum_steps``: microbatched gradient accumulation
     over leading-dim batch splits (``fsdp.microbatch_value_and_grad``)."""
+    T.require_dense_block(cfg, "parallel.tensor.make_tp_train_step")
     ws_dp = int(mesh.shape[dp_axis])
     ws_tp = int(mesh.shape[tp_axis])
     check_tp_divisibility(cfg, ws_tp)

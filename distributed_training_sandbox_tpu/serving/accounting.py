@@ -43,17 +43,14 @@ def weight_read_bytes(cfg, params, wb: int) -> int:
 
 def page_bytes(cfg, page_size: int, *, kv_quant: bool = False,
                tp: int = 1) -> int:
-    """Bytes ONE page occupies across every layer's K and V pool:
-    page_size × layers × (n_kv/tp local heads) × hd × 2 × itemsize,
-    plus the f32 per-row scales for the int8 pool.  This is the unit
-    the capacity planner divides the budget by."""
-    import jax.numpy as jnp
-    nkv = cfg.num_key_value_heads // tp
-    elems = page_size * cfg.num_hidden_layers * nkv \
-        * cfg.resolved_head_dim * 2
-    if kv_quant:
-        return elems + (elems // cfg.resolved_head_dim) * 4
-    return elems * jnp.dtype(cfg.dtype).itemsize
+    """Bytes ONE page occupies across every layer's pool(s): page_size ×
+    layers × the pool's own row bytes (``kv_pool.token_row_bytes``: for
+    the dense block (n_kv/tp local heads) × hd × 2 × itemsize plus the f32
+    per-row scales of an int8 pool; for the latent block its one padded
+    row).  This is the unit the capacity planner divides the budget by."""
+    from .kv_pool import token_row_bytes
+    return page_size * cfg.num_hidden_layers \
+        * token_row_bytes(cfg, kv_quant=kv_quant, tp=tp)
 
 
 def serve_waterline_gb(cfg, n_pages: int, page_size: int, *,

@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models import mla_moe as M
 from ..models import transformer as T
 from ..models.generate import _decode_cfg, _quant_kv
 from ..ops import collectives as C
@@ -238,12 +239,103 @@ def _paged_layer_body(x, layer, *, cfg, cos, sin, use_rope, pk, pv,
     return tail(attn)
 
 
+def _paged_latent_layer_body(x, layer, *, cfg, cos, sin, pool, pages,
+                             apos, valid, expert: bool,
+                             paged_kernel=False):
+    """One layer of the latent-attention + held-experts block
+    (``models/mla_moe.py`` holds its pieces) against the paged pool of
+    latent rows, under the catalogue's scopes:
+
+      * ``attn_qkv``: both down-projections, their norms, the queries'
+        up-projection, RoPE, and at decode the absorption of ``w_uk``
+        into the queries;
+      * ``kv_write``: ONE row ``[c_kv | k_rope]`` a token scatters into
+        its page (zero-padded to the pool's row); invalid rows divert to
+        the null page 0 as in the dense body;
+      * ``attn_core``: a decode step (S == 1) with ``paged_kernel`` runs
+        the ABSORBED form in the Pallas kernel, which reads the slot's
+        live pages in place and never up-projects them.  Otherwise
+        (prefill chunks; decode off the chip) the MATERIALISED form:
+        cached rows are up-projected to keys and values a block of pages
+        at a time, up to the last position a row can see.  Prefill is
+        materialised because it costs 320 multiply-adds a head and key
+        against the absorbed form's 1,088, and up-projecting a key once
+        serves all of a chunk's rows;
+      * ``attn_out``: at decode ``o~ . w_uv``, then ``wo`` and the
+        post-attention norm; ``mlp``: both MLP norms and the MLP, its
+        routing, held experts and shared expert under ``moe_route``,
+        ``moe_experts``, ``moe_shared`` (``profiling.SUBSCOPES``).
+
+    x (B, S, H); pool (n_pages, page, W); pages (B, P); apos, valid
+    (B, S).  Returns ``(x', pool', counts)``; ``counts`` is
+    ``mla_moe.moe_counts`` of the valid rows for an expert layer, else
+    None."""
+    page, P = pool.shape[1], pages.shape[1]
+    in_kernel = paged_kernel and x.shape[1] == 1
+    with scope("attn_qkv"):
+        q_nope, q_rope, rows = M.latent_qkv(x, layer, cfg=cfg, cos=cos,
+                                            sin=sin)
+        if in_kernel:
+            qa = M.absorb_queries(q_nope, q_rope, layer)
+    with scope("kv_write"):
+        pi = jnp.clip(apos // page, 0, P - 1)
+        pg = jnp.where(valid, jnp.take_along_axis(pages, pi, axis=1), 0)
+        rows = jnp.pad(rows, ((0, 0), (0, 0),
+                              (0, pool.shape[-1] - rows.shape[-1])))
+        pool = pool.at[pg, apos % page].set(rows)
+    with scope("attn_core"):
+        if in_kernel:
+            from ..ops.paged_attention import paged_latent_attention_decode
+            o_lat = paged_latent_attention_decode(
+                qa[:, 0], pool, pages,
+                jnp.where(valid[:, 0], apos[:, 0] + 1, 0),
+                rank=cfg.kv_lora_rank, probs_dtype=x.dtype,
+                scale=1.0 / math.sqrt(cfg.qk_nope_head_dim
+                                      + cfg.qk_rope_head_dim))[:, None]
+        else:
+            o = M.attend_paged(q_nope, q_rope, pool, pages, apos, layer,
+                               cfg)
+    with scope("attn_out"):
+        if in_kernel:
+            o = M.unabsorb_values(o_lat, layer, x.dtype)
+        h = M.attention_output(o, x, layer, cfg)
+    with scope("mlp"):
+        out, counts = M.mlp(h, layer, cfg=cfg, expert=expert, valid=valid)
+    return out, pool, counts
+
+
+def _paged_latent_forward(params, ids, cfg, bufs: PoolBuffers, pages,
+                          apos, valid, paged_kernel=False):
+    """``_paged_forward`` for the latent block: ``params["layers"]`` is
+    a tuple of per-layer dicts (dense ones, then expert ones; nothing
+    stacked), one pool of rows a layer, and the expert layers' counters
+    summed over the layers (int32 (4,), ``mla_moe.COUNTERS``)."""
+    with scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[ids]
+        cos, sin = _ragged_rope_tables(apos, cfg.qk_rope_head_dim,
+                                       cfg.rope_theta)
+    pools = list(bufs.k)
+    total = jnp.zeros((len(M.COUNTERS),), jnp.int32)
+    for li, layer in enumerate(params["layers"]):
+        x, pools[li], counts = _paged_latent_layer_body(
+            x, layer, cfg=cfg, cos=cos, sin=sin, pool=pools[li],
+            pages=pages, apos=apos, valid=valid,
+            expert=M.is_expert_layer(li, cfg), paged_kernel=paged_kernel)
+        if counts is not None:
+            total = total + counts
+    return x, bufs._replace(k=tuple(pools)), total
+
+
 def _paged_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
                    valid, tp_axis=None, paged_kernel=False,
                    flash_prefill=False):
-    """ids (B, S) → (hidden x (B, S, H), bufs') through the UNROLLED
-    layer stack (static layer index into the per-layer pools, like
-    ``generate._forward_cached``)."""
+    """ids (B, S) → (hidden x (B, S, H), bufs', counts) through the
+    UNROLLED layer stack (static layer index into the per-layer pools,
+    like ``generate._forward_cached``).  ``counts`` is None for the dense
+    block and the expert layers' counters for the latent one."""
+    if cfg.mla_moe:
+        return _paged_latent_forward(params, ids, cfg, bufs, pages, apos,
+                                     valid, paged_kernel=paged_kernel)
     with scope("embed"):
         x = params["embed"].astype(cfg.dtype)[ids]
         cos, sin = _ragged_rope_tables(apos, cfg.resolved_head_dim,
@@ -268,7 +360,7 @@ def _paged_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
     out = PoolBuffers(k=tuple(ks), v=tuple(vs),
                       k_scale=tuple(kss) if kss is not None else None,
                       v_scale=tuple(vss) if vss is not None else None)
-    return x, out
+    return x, out, None
 
 
 def _all_logits(params, x, cfg):
@@ -293,18 +385,21 @@ def _last_logits(params, x_last, cfg):
     return _all_logits(params, x_last, cfg)[:, 0]
 
 
-def _decode_core(bufs, params, pages, toks, lengths, stop_at, active, *,
-                 cfg, tp_axis=None, paged_kernel=False):
+def _decode_core(bufs, params, pages, toks, lengths, stop_at, active,
+                 moe=None, *, cfg, tp_axis=None, paged_kernel=False):
     """One fixed-shape decode step over every slot.  toks/lengths/
     stop_at (B,) int32, active (B,) bool.  Emits the next greedy token
     per ACTIVE slot (inactive slots freeze); a slot auto-retires ON
     DEVICE when its length reaches ``stop_at`` — the device can never
     write past a request's page grant even mid-burst, the host only
-    observes retirement at the next sync."""
+    observes retirement at the next sync.  The latent block also takes
+    ``moe``, the burst's running sum of its expert layers' counters,
+    and returns it with this step's added: a burst's steps chain it on
+    the device and its one sync reads it."""
     apos = lengths[:, None]
-    x, bufs = _paged_forward(params, toks[:, None], cfg, bufs, pages,
-                             apos, active[:, None], tp_axis=tp_axis,
-                             paged_kernel=paged_kernel)
+    x, bufs, counts = _paged_forward(
+        params, toks[:, None], cfg, bufs, pages, apos, active[:, None],
+        tp_axis=tp_axis, paged_kernel=paged_kernel)
     with scope("sample"):
         logits = _last_logits(params, x[:, -1:], cfg)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -312,6 +407,8 @@ def _decode_core(bufs, params, pages, toks, lengths, stop_at, active, *,
     new_len = lengths + active.astype(jnp.int32)
     new_active = jnp.logical_and(active, new_len < stop_at)
     occ = jnp.sum(active.astype(jnp.int32))
+    if counts is not None:
+        return nxt, new_len, new_active, bufs, occ, moe + counts
     return nxt, new_len, new_active, bufs, occ
 
 
@@ -325,8 +422,8 @@ def _prefill_core(bufs, params, pages_row, ids, pos, plen, *, cfg,
     Ck = ids.shape[1]
     apos = pos + jnp.arange(Ck, dtype=jnp.int32)[None, :]
     valid = apos < plen
-    x, bufs = _paged_forward(params, ids, cfg, bufs, pages_row, apos,
-                             valid, tp_axis=tp_axis)
+    x, bufs, _ = _paged_forward(params, ids, cfg, bufs, pages_row, apos,
+                                valid, tp_axis=tp_axis)
     with scope("sample"):
         last = jnp.clip(plen - 1 - pos, 0, Ck - 1)
         xl = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
@@ -348,9 +445,9 @@ def _prefill_batch_core(bufs, params, pages, ids, pos, plen, *, cfg,
     Bp, Ck = ids.shape
     apos = pos[:, None] + jnp.arange(Ck, dtype=jnp.int32)[None, :]
     valid = apos < plen[:, None]
-    x, bufs = _paged_forward(params, ids, cfg, bufs, pages, apos, valid,
-                             tp_axis=tp_axis,
-                             flash_prefill=flash_prefill)
+    x, bufs, _ = _paged_forward(params, ids, cfg, bufs, pages, apos, valid,
+                                tp_axis=tp_axis,
+                                flash_prefill=flash_prefill)
     with scope("sample"):
         last = jnp.clip(plen - 1 - pos, 0, Ck - 1)
         xl = jnp.take_along_axis(x, last[:, None, None], axis=1)
@@ -377,8 +474,8 @@ def _spec_verify_core(bufs, params, pages, toks_blk, lengths, stop_at,
     B, S = toks_blk.shape
     apos = lengths[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
     valid = active[:, None] & (apos < stop_at[:, None])
-    x, bufs = _paged_forward(params, toks_blk, cfg, bufs, pages, apos,
-                             valid, tp_axis=tp_axis)
+    x, bufs, _ = _paged_forward(params, toks_blk, cfg, bufs, pages, apos,
+                                valid, tp_axis=tp_axis)
     with scope("sample"):
         greedy = jnp.argmax(_all_logits(params, x, cfg),
                             axis=-1).astype(jnp.int32)
@@ -558,6 +655,17 @@ class ServingEngine:
                  disaggregate: bool = False, device=None,
                  watchdog=None, telem=None):
         self.cfg = _decode_cfg(cfg)
+        if self.cfg.mla_moe:
+            # built for the latent block: chunked prefill, the decode
+            # burst, the paged kernel.  The rest is refused by name,
+            # never run as dense math
+            for what, asked in (
+                    ("kv_quant", kv_quant), ("a tp mesh", mesh is not None),
+                    ("spec_k", spec_k), ("flash_prefill", flash_prefill),
+                    ("disaggregate", disaggregate),
+                    ("prefix_cache", prefix_cache)):
+                if asked:
+                    M.refuse(self.cfg, f"ServingEngine with {what}")
         self.max_batch = int(max_batch)
         self.page_size = int(page_size)
         self.pages_per_request = -(-int(max_seq_len) // self.page_size)
@@ -581,8 +689,8 @@ class ServingEngine:
             if paged_kernel:
                 from ..ops.paged_attention import decode_kernel_takes
                 paged_kernel = decode_kernel_takes(
-                    self.cfg.dtype, self.cfg.resolved_head_dim,
-                    self.page_size)
+                    self.cfg.dtype, self.cfg.kv_lora_rank
+                    or self.cfg.resolved_head_dim, self.page_size)
         self.paged_kernel = bool(paged_kernel)
         # prefill through the BATCHED multi-request step with the
         # Pallas flash-attention kernel (ops/flash_prefill.py)
@@ -842,6 +950,15 @@ class ServingEngine:
                       # admission: requests seated and their summed
                       # wait from submission (DUE) to a slot
                       "admitted": 0, "queue_wait_s": 0.0}
+        # the latent block's expert layers, counted on the device over
+        # the decode steps (mla_moe.moe_counts) and read at a burst's
+        # sync: (row, chosen expert) pairs over the router's whole
+        # width, those whose expert is held here, held experts that got
+        # a row (summed over layers and steps), expert layers x steps
+        self._moe_zero = None
+        if self.cfg.mla_moe:
+            self.stats.update(dict.fromkeys(M.COUNTERS, 0))
+            self._moe_zero = self._put(np.zeros(len(M.COUNTERS), np.int32))
         # attributes every serve/* span of the current round carries
         self._sp: dict = {}
 
@@ -1150,13 +1267,15 @@ class ServingEngine:
         A0 = self._h_active.copy()
         toks_d, len_d, stop_d, act_d, pages_d = self._stage_burst()
         bufs = self.pool.bufs
+        # the latent block's counters ride the burst as one more argument
+        moe = [] if self._moe_zero is None else [self._moe_zero]
         if self.telem is not None:
             # ledger join (no-op unless the run owns an enabled
             # profiler, and only compiles once): the decode program's
             # text at this burst's exact arg shardings
             self.telem.attach_step_hlo(self._decode, bufs, self._params,
                                        pages_d, toks_d, len_d, stop_d,
-                                       act_d,
+                                       act_d, *moe,
                                        trees={"kv_pool": bufs,
                                               "params": self._params},
                                        prediction=self._mem_prediction)
@@ -1164,16 +1283,19 @@ class ServingEngine:
         with maybe_span(stream, "serve/burst_dispatch", steps=sync, **sp):
             step_tokens = []
             for _ in range(sync):
-                toks_d, len_d, act_d, bufs, occ = self._decode(
+                toks_d, len_d, act_d, bufs, occ, *moe = self._decode(
                     bufs, self._params, pages_d, toks_d, len_d, stop_d,
-                    act_d)
+                    act_d, *moe)
                 pump.emit(occ)
                 step_tokens.append(toks_d)
             self.pool.bufs = bufs
             self.stats["decode_steps"] += sync
             if self.paged_kernel:
                 self.stats["decode_inplace_steps"] += sync
-        mats = self._sync_burst(step_tokens)
+        mats = self._sync_burst(step_tokens + moe)
+        if moe:
+            for name, count in zip(M.COUNTERS, mats.pop()):
+                self.stats[name] += int(count)
         burst_s = time.perf_counter() - t_burst  # clock-ok
         self.stats["decode_s"] += burst_s
         t_book = time.perf_counter()  # clock-ok
@@ -1537,6 +1659,8 @@ class ServingEngine:
             "devices": ndev,
             "pool": {"n_pages": self.n_pages,
                      "page_size": self.page_size,
+                     "bytes_per_token": self.pool.row_bytes
+                     * self.cfg.num_hidden_layers,
                      "peak_util": round(self.stats["peak_pool_util"], 4)},
             "scheduler": {
                 "rounds": self.stats["rounds"],

@@ -7,6 +7,7 @@ FIXED pool whose blocks are reassigned between requests without
 reallocating (or retracing) anything.  vLLM's paged layout, TPU-shaped:
 
   * per-layer POOLS of page blocks, ``(n_pages, page_size, n_kv, hd)``
+    (the latent block: ``(n_pages, page_size, W)``, see ``row_layout``)
     in ``cfg.dtype`` — or int8 codes + ``(n_pages, page_size, n_kv, 1)``
     f32 row scales via the same ``_quant_kv`` row quantizer the one-shot
     int8 cache uses;
@@ -25,24 +26,60 @@ reallocating (or retracing) anything.  vLLM's paged layout, TPU-shaped:
 The device arrays live in a :class:`PoolBuffers` namedtuple that the
 jitted decode/prefill steps DONATE and return — the pool object just
 tracks the current buffers plus the free list.
+
+What ONE token caches in one layer is the pool's ROW, and
+:func:`row_layout` is the one place that states it: K and V rows of
+``(n_kv, hd)`` each for the dense GQA block; for the latent block
+(``models/mla_moe.py``) ONE row ``[c_kv | k_rope]`` and no V pool.  Pages,
+the allocator and the prefix trie are page-granular and do not look inside
+a row.
 """
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+#: a latent row is padded with zero columns to whole 128-lane tiles: a TPU
+#: holds a bf16 array's minor dimension in tiles of 128 anyway (576 takes
+#: 640 columns), and the decode kernel copies a page as whole tiles
+LATENT_ROW_ALIGN = 128
+
+
+def row_layout(cfg, tp: int = 1) -> tuple[tuple[int, ...], bool]:
+    """``(row shape, has_v)`` of one token in one layer's pool(s): the
+    trailing dims of every pool array after ``(n_pages, page_size)``, and
+    whether a V pool of the same shape stands beside the K pool."""
+    if cfg.mla_moe:
+        from ..models.mla_moe import row_width
+        w = row_width(cfg)
+        return (w + -w % LATENT_ROW_ALIGN,), False
+    return (cfg.num_key_value_heads // tp, cfg.resolved_head_dim), True
+
+
+def token_row_bytes(cfg, *, kv_quant: bool = False, tp: int = 1) -> int:
+    """Bytes one token occupies in ONE layer's pool(s), row scales of an
+    int8 pool included: what every sizing of the pool multiplies."""
+    shape, has_v = row_layout(cfg, tp)
+    elems = math.prod(shape) * (2 if has_v else 1)
+    if kv_quant:
+        return elems + (elems // shape[-1]) * 4
+    return elems * jnp.dtype(cfg.dtype).itemsize
 
 
 class PoolBuffers(NamedTuple):
     """The device half of the pool: per-layer page-block arrays (tuples
     of L arrays, mirroring ``KVCache``'s per-layer-buffer decision — a
     stacked (L, ...) layout would pay a dynamic-slice copy per layer per
-    step).  ``k_scale``/``v_scale`` are the f32 row scales of the int8
+    step), each ``(n_pages, page_size) + row shape`` (:func:`row_layout`).
+    ``v`` is None for the latent block, whose one row a token lives in
+    ``k``.  ``k_scale``/``v_scale`` are the f32 row scales of the int8
     pool, None for the ``cfg.dtype`` pool."""
-    k: tuple            # L × (n_pages, page_size, n_kv, hd)
-    v: tuple
+    k: tuple            # L × (n_pages, page_size, n_kv, hd) | (.., .., W)
+    v: tuple | None
     k_scale: tuple | None   # L × (n_pages, page_size, n_kv, 1) f32
     v_scale: tuple | None
 
@@ -317,12 +354,17 @@ class PagedKVPool:
         self.tp_axis = tp_axis
         self.device = device
         L = cfg.num_hidden_layers
-        nkv, hd = cfg.num_key_value_heads, cfg.resolved_head_dim
-        shape = (self.n_pages, self.page_size, nkv, hd)
+        row, has_v = row_layout(cfg)
+        if kv_quant and not has_v:
+            raise NotImplementedError(
+                "an int8 pool of latent rows is not built (the row "
+                "quantizer scales per KV head)")
+        shape = (self.n_pages, self.page_size) + row
         dt = jnp.int8 if kv_quant else cfg.dtype
         put = self._put
         k = tuple(put(jnp.zeros(shape, dt)) for _ in range(L))
-        v = tuple(put(jnp.zeros(shape, dt)) for _ in range(L))
+        v = tuple(put(jnp.zeros(shape, dt)) for _ in range(L)) \
+            if has_v else None
         # scales init to ones like init_cache's — unwritten rows then
         # dequantize to exact zeros, matching the one-shot cache
         ks = vs = None
@@ -334,12 +376,21 @@ class PagedKVPool:
         self.bufs = PoolBuffers(k=k, v=v, k_scale=ks, v_scale=vs)
         self.allocator = PageAllocator(self.n_pages)
 
+    def _row_spec(self):
+        """One pool array's PartitionSpec: the KV-head axis over tp under
+        a mesh; a latent row has no head axis and stays whole."""
+        from jax.sharding import PartitionSpec as P
+        row, _ = row_layout(self.cfg)
+        if len(row) == 1:
+            return P(None, None, None)
+        return P(None, None, self.tp_axis if self.mesh is not None else None,
+                 None)
+
     def _put(self, x):
         if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
+            from jax.sharding import NamedSharding
             return jax.device_put(
-                x, NamedSharding(self.mesh,
-                                 P(None, None, self.tp_axis, None)))
+                x, NamedSharding(self.mesh, self._row_spec()))
         if self.device is not None:
             return jax.device_put(x, self.device)
         return x
@@ -349,13 +400,17 @@ class PagedKVPool:
         """PartitionSpec pytree matching ``bufs`` — the in/out spec the
         engine hands ``shard_map`` (heads sharded over tp, everything
         else replicated)."""
-        from jax.sharding import PartitionSpec as P
         L = self.cfg.num_hidden_layers
-        ps = P(None, None, self.tp_axis if self.mesh is not None else None,
-               None)
+        ps = self._row_spec()
         sc = (ps,) * L if self.kv_quant else None
-        return PoolBuffers(k=(ps,) * L, v=(ps,) * L, k_scale=sc,
-                           v_scale=sc)
+        return PoolBuffers(k=(ps,) * L,
+                           v=(ps,) * L if self.bufs.v is not None else None,
+                           k_scale=sc, v_scale=sc)
+
+    @property
+    def row_bytes(self) -> int:
+        """Bytes one token occupies in one layer of this pool."""
+        return token_row_bytes(self.cfg, kv_quant=self.kv_quant)
 
     @property
     def utilization(self) -> float:

@@ -139,7 +139,7 @@ SCOPES = (
     "attn_core",   # splash / XLA / ring attention; the engine's scores,
                    # softmax and PV over the gathered view
     "attn_out",    # output projection
-    "mlp",         # pre-MLP norm + SwiGLU, or the MoE block (its moe_* scopes)
+    "mlp",         # both MLP norms + SwiGLU, or an expert layer: SUBSCOPES
     "loss_head",   # final norm, unembedding, (streamed) cross-entropy
     # the serving engine's programs (serving/engine.py)
     "kv_write",    # scatter of the new K/V rows into their pages
@@ -149,6 +149,18 @@ SCOPES = (
     "fsdp_layer_gather", "fsdp_root_gather", "fsdp_pre_gather_layers",
     "loss_mean", "grad_mean",
     "opt_step",    # the optimizer update
+)
+
+#: A declared second level beneath ``mlp``, opened by the latent block's
+#: expert layers (``models/mla_moe.py``).  A reader of ``SCOPES`` still books
+#: these ops to ``mlp`` (the innermost CATALOGUE name); a reader of this
+#: tuple splits ``mlp`` by them (``benchmarks/layer_metrics/_subscopes.py``
+#: holds a copy, pinned by a test).  ``parallel/expert.py``'s older
+#: ``moe_*`` scopes are its own and are read by nothing.
+SUBSCOPES = (
+    "moe_route",    # router matmul, sigmoid, top-k, weights, the counters
+    "moe_experts",  # held experts: their product over every row, combine
+    "moe_shared",   # the shared expert
 )
 
 
