@@ -394,9 +394,10 @@ def kernels_phase(cfg, seq: int) -> None:
 
     on_tpu = jax.default_backend() == "tpu"
     how = "compiled" if on_tpu else "interpreted on cpu"
-    # the engine's decode program takes the paged kernel by default on a
-    # TPU at the smoke's pool geometry (ServingEngine.paged_kernel=None)
-    default_path = {"paged decode"}
+    # the engine's decode and prefill programs take the paged kernels by
+    # default on a TPU at the smoke's pool geometry and chunk
+    # (ServingEngine.paged_kernel=None)
+    default_path = {"paged decode", "flash prefill"}
     if cfg.attention_impl == "flash":
         default_path.add("splash attention")
     # every output is bf16 (or f32 from bf16 probabilities): agreement to

@@ -1,43 +1,53 @@
 """Flash prefill kernel over the paged KV pool (Pallas).
 
 The serving engine's prefill attends a whole chunk of S query rows
-against the slot's visible KV window.  The reference path gathers the
-page table into a contiguous ``(B, V, n_kv, hd)`` HBM view and runs two
-einsums with a full ``(g, r, S, V)`` score tensor in between — fine at
-toy scale, but the score tensor and the gather view are exactly the
-materializations a fused flash kernel exists to avoid.
+against the slot's visible KV window.  The reference path
+(``serving/engine._paged_layer_body``) gathers the slot's WHOLE page
+table into a contiguous ``(B, V, n_kv, hd)`` HBM view and runs two
+einsums with a full float32 ``(B, n_kv, rep, S, V)`` score tensor in
+between, whatever part of the view the chunk can see.
 
-This kernel reads pages IN PLACE via the table (same dynamic page loads
-as ``paged_attention.py``) and computes the chunk's attention with a
-tiled ONLINE softmax: KV is consumed in blocks of ``kv_block_pages``
-pages, carrying running per-row maxima ``m``, denominators ``l`` and a
-rescaled accumulator — the classic divide-at-the-end flash recurrence,
-so the full score tensor never exists at once.
+This kernel reads the slot's live pages IN PLACE and keeps the scores in
+VMEM: neither of those two tensors exists in the program.  It is the
+design of ``paged_attention._decode_kernel`` at S > 1 (the engine's
+default prefill path on a TPU since PR 27): the page table and, per
+batch row, the chunk's start and its live end ride in by scalar prefetch
+(SMEM); the pools stay in HBM in the layout they have; one grid step per
+batch row copies that row's pages to VMEM by async DMA in
+double-buffered blocks of ``PAGES_PER_BLOCK`` pages and runs an online
+softmax over the blocks, in a loop that ENDS AT THE CHUNK'S LAST VISIBLE
+POSITION and not at the table's end.  A row with nothing live reads no
+page and returns zeros.
 
-Parity tiers:
+What differs from the decode kernel is how the KV heads are told apart.
+A page of the pool is one ``(page_size * n_kv, hd)`` slab whose rows
+interleave the heads.  At S == 1 the decode kernel contracts every query
+row against every slab row and masks the other heads' columns, which is
+free when one row pays a weight load; at S = 512 it would be ``n_kv``
+times the MXU and the softmax work.  Here each KV head's ``S * rep``
+query rows meet only that head's keys: a block's slab is widened to
+float32 in VMEM once (packed bf16 rows cannot be read with a sublane
+stride, 32-bit rows can) and head ``g`` is the strided read
+``pl.ds(g, span, stride=n_kv)`` of it, cast back (exactly) to the pool's
+dtype for the MXU.
 
-  * ``kv_block_pages=None`` (default) — ONE tile covering the whole
-    view.  The epilogue then follows the reference op order exactly
-    (mask → ``jax.nn.softmax`` → probs cast → contraction), which makes
-    the output equal to the engine's gather+einsum path up to float32
-    summation order — the tier the serving parity gates run.
-  * ``kv_block_pages=k`` — genuine multi-block online softmax.  The
-    divide-at-end rescaling reassociates the denominator, so this tier
-    is allclose-not-bitwise vs the reference (asserted in tests); it is
-    the shape the hardware tier runs where VMEM can't hold the view.
+The scores are held transposed, keys on sublanes and query rows on
+lanes, so that the softmax's running maximum and sum are lane-dense
+``(1, S * rep)`` rows reduced along sublanes (elementwise across
+vregs), not ``(S * rep, 1)`` columns reduced across lanes.
 
-Float pools only: the int8 pool's per-row scale folding does not
-commute with the online rescale, and prefill is the bandwidth-bound
-leg where bf16 pools are the default anyway.
+Arithmetic: scores in float32 from pool-dtype operands, ``/ sqrt(hd)``,
+causal on absolute positions (``pos_kv <= apos``, masked = −1e30),
+online softmax across blocks in float32, probabilities cast to
+``probs_dtype`` before ``p @ V``, float32 accumulation, one divide at
+the end: the gather path's precision, reassociated only in float32
+summation order (tests/test_kernels.py, interpret mode on the CPU;
+chip_smoke.py on the chip).  The chunk's own K/V rows are in the pool
+when the kernel runs (the scatter precedes it by data dependence).
 
-CPU tier only, still fenced (``paged_attention.refuse_on_tpu``): the
-design shares the page loads of ``paged_attention.py``'s int8 kernel
-(``_gather_pool``: the page id read from a vector-memory ref, the view
-assembled with ``dynamic_update_slice``, the whole pool one block) and
-does not lower on a TPU.  The float DECODE kernel there was rewritten
-for the hardware (scalar-prefetched table, per-page DMAs); this kernel
-is the next one to follow it (ROADMAP S3), and until then prefill on
-the chip is the gather path.
+Float pools only: the int8 pool's per-row scale folding does not commute
+with the online rescale.  :func:`prefill_kernel_takes` states which
+shapes compile on a TPU; interpret mode takes any float shape.
 """
 
 from __future__ import annotations
@@ -48,131 +58,212 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .paged_attention import _gather_pool, refuse_on_tpu
+from .paged_attention import decode_kernel_takes
 
-__all__ = ["paged_flash_prefill"]
+__all__ = ["paged_flash_prefill", "prefill_kernel_takes"]
 
+# Pages per DMA block: 8 pages of 16 tokens are 128 positions a block, a
+# (128, S * rep) float32 score tile a KV head.  On a v5e at the serving
+# cells' shapes (PERF.md §6, PR 27) 8 and 16 read alike at long live
+# ends (0.099 | 0.100 ms a layer at 2,560 positions, 0.272 | 0.276 at
+# 8,192), 8 is faster at short ones (a block is read whole: 0.032 |
+# 0.042 at 300) and compiles in half the time, 4 is 30% slower
+# everywhere and 32 no faster.  Tables shorter than a block are one.
+PAGES_PER_BLOCK = 8
 
-def _prefill_kernel(pages_ref, q_ref, apos_ref, pk_ref, pv_ref, o_ref, *,
-                    n_slot_pages: int, kv_block_pages: int | None,
-                    probs_dtype):
-    """One batch slot's chunk attention: q (S, g, r, hd) against the
-    slot's pages, causal on absolute positions (``pos_kv <= apos[s]``,
-    masked positions scored −1e30 → exact-zero probability)."""
-    page = pk_ref.shape[1]
-    hd = q_ref.shape[-1]
-    q = q_ref[0]                                     # (S, g, r, hd)
-    a = apos_ref[0]                                  # (S,)
-
-    if kv_block_pages is None:
-        # single tile: the reference op order verbatim (softmax →
-        # probs cast → contraction) — bitwise tier
-        kv = _gather_pool(pk_ref, pages_ref, n_slot_pages, page)
-        vv = _gather_pool(pv_ref, pages_ref, n_slot_pages, page)
-        scores = jnp.einsum(
-            "sgrh,kgh->grsk", q, kv,
-            preferred_element_type=jnp.float32) / math.sqrt(hd)
-        vis = jnp.arange(kv.shape[0])[None, :] <= a[:, None]  # (S, V)
-        scores = jnp.where(vis[None, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        o_ref[0] = jnp.einsum("grsk,kgh->sgrh",
-                              probs.astype(probs_dtype), vv,
-                              preferred_element_type=jnp.float32)
-        return
-
-    # tiled online softmax: running (m, l, acc), divide at the end
-    T = kv_block_pages * page
-    S, g, r, _ = q.shape
-
-    def gather_blk(pool_ref, i):
-        tail = pool_ref.shape[2:]
-        acc0 = jnp.zeros((T,) + tail, pool_ref.dtype)
-
-        def load(p, accv):
-            blk = pool_ref[pages_ref[0, i * kv_block_pages + p]]
-            return jax.lax.dynamic_update_slice(
-                accv, blk, (p * page,) + (0,) * len(tail))
-
-        return jax.lax.fori_loop(0, kv_block_pages, load, acc0)
-
-    def block(i, carry):
-        m, l, acc = carry
-        kb = gather_blk(pk_ref, i)
-        vb = gather_blk(pv_ref, i)
-        s = jnp.einsum(
-            "sgrh,kgh->grsk", q, kb,
-            preferred_element_type=jnp.float32) / math.sqrt(hd)
-        pos = i * T + jnp.arange(T)
-        vis = pos[None, :] <= a[:, None]             # (S, T)
-        s = jnp.where(vis[None, None], s, -1e30)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))  # (g, r, S)
-        # block 0 always holds position 0, visible to every row, so
-        # m_new is a real score from the first iteration on and the
-        # −1e30 of fully-masked later blocks underflows to exactly 0
-        corr = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[..., None])
-        l = l * corr + jnp.sum(p, axis=-1)
-        pv_blk = jnp.einsum("grsk,kgh->sgrh", p.astype(probs_dtype),
-                            vb, preferred_element_type=jnp.float32)
-        acc = acc * corr.transpose(2, 0, 1)[..., None] + pv_blk
-        return m_new, l, acc
-
-    m0 = jnp.full((g, r, S), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((g, r, S), jnp.float32)
-    a0 = jnp.zeros((S, g, r, hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_slot_pages // kv_block_pages,
-                                  block, (m0, l0, a0))
-    o_ref[0] = acc / l.transpose(2, 0, 1)[..., None]
+# VMEM the kernel may use: the query and output blocks of all heads,
+# double-buffered by the pipeline, the accumulators and a block's score
+# tiles are ~20 MB at the document cell's shapes, past the compiler's
+# 16 MB default (a v5e core has 128 MB)
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
-def paged_flash_prefill(qg, pk, pv, pages, apos, *, probs_dtype=None,
-                        kv_block_pages: int | None = None,
-                        interpret: bool = True):
+def prefill_kernel_takes(dtype, head_dim: int, page_size: int,
+                         chunk: int) -> bool:
+    """The shapes :func:`_prefill_kernel` compiles for on a TPU: what
+    the decode kernel takes (a float pool, ``head_dim`` whole 128-lane
+    tiles, ``page_size`` whole sublane tiles of the dtype), and a chunk
+    of whole sublane tiles too (8 rows of 32 bits: 8 for float32, 16 for
+    bfloat16), so that a head's ``chunk * rep`` query rows are.
+    Interpret mode takes any float shape."""
+    return (decode_kernel_takes(dtype, head_dim, page_size)
+            and chunk > 1
+            and chunk % (32 // jnp.dtype(dtype).itemsize) == 0)
+
+
+def _prefill_kernel(start_ref, end_ref, pages_ref, q_ref, pk_hbm, pv_hbm,
+                    o_ref, k_buf, v_buf, k32, v32, acc_ref, sems, *,
+                    table_pages: int, block_pages: int, page: int,
+                    nkv: int, rep: int, probs_dtype):
+    """Float pool, one batch row's chunk.  ``start_ref``/``end_ref``
+    (B,) and ``pages_ref`` (B * P,) are in SMEM: the chunk's first
+    absolute position, one past the last position any of its rows may
+    see, the flattened page table.  q_ref (1, n_kv, R, hd) holds each KV
+    head's R = S * rep query rows, row ``s * rep + r``; pk_hbm/pv_hbm
+    are the pools as (n_pages, page * n_kv, hd), left in HBM;
+    k_buf/v_buf (2, T, hd) are the two halves of the double buffer of
+    T = block_pages * page * n_kv slab rows, k32/v32 (T, hd) their
+    float32 staging, acc_ref (n_kv, hd, R) the transposed float32
+    accumulators, sems (2, 2) the K and V semaphores.  o_ref is
+    (1, n_kv, R, hd) float32."""
+    b = pl.program_id(0)
+    start, end = start_ref[b], end_ref[b]
+    rows = page * nkv                          # pool rows a page
+    span = block_pages * page                  # positions a block
+    n_blocks = (end + span - 1) // span
+    R, hd = q_ref.shape[2:]
+
+    def copies(blk, slot):
+        out = []
+        for i in range(block_pages):
+            # a table that is no multiple of the block ends in repeats of
+            # its last entry; their positions are past every live end
+            at = jnp.minimum(blk * block_pages + i, table_pages - 1)
+            pid = pages_ref[b * table_pages + at]
+            dst = pl.ds(i * rows, rows)
+            out.append(pltpu.make_async_copy(
+                pk_hbm.at[pid], k_buf.at[slot, dst], sems.at[0, slot]))
+            out.append(pltpu.make_async_copy(
+                pv_hbm.at[pid], v_buf.at[slot, dst], sems.at[1, slot]))
+        return out
+
+    @pl.when(n_blocks > 0)
+    def _():
+        for c in copies(0, 0):
+            c.start()
+
+    # query row j (a lane) is chunk row j // rep: it sees positions up
+    # to its own, and none at or past the live end (a padding row's own
+    # position is past it: it sees what the last live row sees)
+    lane_row = jax.lax.broadcasted_iota(jnp.int32, (1, R), 1) // rep
+    q_last = jnp.minimum(start + lane_row, end - 1)           # (1, R)
+    key_pos = jax.lax.broadcasted_iota(jnp.int32, (span, 1), 0)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def block(blk, carry):
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _():
+            for c in copies(blk + 1, 1 - slot):
+                c.start()
+
+        for c in copies(blk, slot):
+            c.wait()
+        k32[...] = k_buf[slot].astype(jnp.float32)
+        v32[...] = v_buf[slot].astype(jnp.float32)
+        vis = blk * span + key_pos <= q_last                  # (span, R)
+        out = []
+        for g in range(nkv):
+            m, l = carry[g]
+            head = pl.ds(g, span, stride=nkv)
+            kg = k32[head, :].astype(k_buf.dtype)             # (span, hd)
+            vg = v32[head, :].T.astype(v_buf.dtype)           # (hd, span)
+            s = jax.lax.dot_general(
+                kg, q_ref[0, g], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) / math.sqrt(hd)
+            s = jnp.where(vis, s, -1e30)
+            # block 0 holds position 0, which every row sees, so m is a
+            # real score from the first block on and the -1e30 of a
+            # masked key underflows to exactly 0
+            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+            corr = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = l * corr + jnp.sum(p, axis=0, keepdims=True)
+            pv = jnp.dot(vg, p.astype(probs_dtype),
+                         preferred_element_type=jnp.float32)  # (hd, R)
+            acc_ref[g] = acc_ref[g] * corr + pv
+            out.append((m_new, l))
+        return tuple(out)
+
+    m0 = jnp.full((1, R), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((1, R), jnp.float32)
+    stats = jax.lax.fori_loop(0, n_blocks, block, ((m0, l0),) * nkv)
+    for g, (_, l) in enumerate(stats):
+        o_ref[0, g] = (acc_ref[g] / jnp.where(l == 0.0, 1.0, l)).T
+
+
+def paged_flash_prefill(qg, pk, pv, pages, apos, *, valid=None,
+                        probs_dtype=None, interpret: bool | None = None):
     """Chunked-prefill paged flash attention, pages read in place.
 
     qg (B, S, n_kv, rep, hd) grouped query (already rope'd); pk/pv
     (n_pages, page, n_kv, hd) float pools; pages (B, P) int32 page
-    table; apos (B, S) int32 absolute positions of the chunk's rows.
-    Returns f32 (B, S, n_kv, rep, hd) — with the default single tile,
-    the exact value of the reference gather-then-einsum path (caller
-    applies the same ``astype`` epilogue).  ``kv_block_pages`` must
-    divide P; passing P is the same as None.
-    """
-    refuse_on_tpu("paged_flash_prefill")
+    table; apos (B, S) int32 absolute positions of the chunk's rows,
+    CONSECUTIVE from ``apos[:, 0]`` (a chunk is); valid (B, S) bool, True
+    for the rows that belong to the prompt, a prefix of each batch row
+    (default all).  A batch row reads its pages up to its last valid
+    row's position and no further; one with no valid row reads nothing
+    and gets zeros; a padding row's output is finite and means nothing.
+    Returns f32 (B, S, n_kv, rep, hd), the value of the reference
+    gather-then-einsum path to float32 summation order (caller applies
+    the same ``astype`` epilogue).  ``interpret`` None: compiled on a
+    TPU, interpreted elsewhere."""
     if pk.dtype == jnp.int8:
         raise ValueError("flash prefill is float-pool only (int8 "
                          "scale folding does not commute with the "
                          "online rescale)")
     B, S, nkv, rep, hd = qg.shape
-    P = pages.shape[1]
-    if kv_block_pages is not None:
-        kv_block_pages = int(kv_block_pages)
-        if not 0 < kv_block_pages <= P:
-            raise ValueError(f"kv_block_pages={kv_block_pages} with "
-                             f"{P} pages per slot")
-        if P % kv_block_pages:
-            raise ValueError(f"kv_block_pages={kv_block_pages} must "
-                             f"divide the {P}-page table")
-        if kv_block_pages == P:
-            kv_block_pages = None          # degenerate → bitwise tier
+    page = pk.shape[1]
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if not interpret and not prefill_kernel_takes(pk.dtype, hd, page, S):
+        raise ValueError(
+            f"the flash prefill kernel does not compile for a {pk.dtype} "
+            f"pool with head_dim {hd}, page_size {page} and a chunk of "
+            f"{S} (prefill_kernel_takes); the engine's gather path "
+            f"serves it")
+    start = apos[:, 0]
+    n_valid = S if valid is None else jnp.sum(valid.astype(jnp.int32), 1)
+    return _prefill_float(
+        qg, pk, pv, pages, start, start + n_valid,
+        block_pages=min(PAGES_PER_BLOCK, pages.shape[1]),
+        probs_dtype=jnp.dtype(probs_dtype or qg.dtype),
+        interpret=bool(interpret))
 
+
+# jitted for the reason ``paged_attention._decode_float`` is: the layers
+# of one prefill program share one trace and one Mosaic lowering
+@functools.partial(jax.jit, static_argnames=("block_pages", "probs_dtype",
+                                             "interpret"))
+def _prefill_float(qg, pk, pv, pages, start, end, *, block_pages: int,
+                   probs_dtype, interpret: bool):
+    """The kernel's call: qg (B, S, n_kv, rep, hd), the pools as the
+    engine holds them, pages (B, P), start/end (B,): the chunk's first
+    position and one past the last position it may see (0: nothing)."""
+    B, S, nkv, rep, hd = qg.shape
+    P = pages.shape[1]
+    n_pages, page = pk.shape[:2]
+    R, rows = S * rep, page * nkv
+    T = block_pages * rows
     kernel = functools.partial(
-        _prefill_kernel, n_slot_pages=P,
-        kv_block_pages=kv_block_pages,
-        probs_dtype=probs_dtype or qg.dtype)
-    whole = lambda arr: pl.BlockSpec(arr.shape, lambda b: (0,) * arr.ndim)
-    row = pl.BlockSpec((1, P), lambda b: (b, 0))
-    qspec = pl.BlockSpec((1, S, nkv, rep, hd), lambda b: (b, 0, 0, 0, 0))
-    aspec = pl.BlockSpec((1, S), lambda b: (b, 0))
-    out_spec = pl.BlockSpec((1, S, nkv, rep, hd),
-                            lambda b: (b, 0, 0, 0, 0))
-    out_shape = jax.ShapeDtypeStruct((B, S, nkv, rep, hd), jnp.float32)
-    return pl.pallas_call(
+        _prefill_kernel, table_pages=P, block_pages=block_pages, page=page,
+        nkv=nkv, rep=rep, probs_dtype=probs_dtype)
+    heads = pl.BlockSpec((1, nkv, R, hd), lambda b, *_: (b, 0, 0, 0))
+    out = pl.pallas_call(
         kernel,
-        grid=(B,),
-        in_specs=[row, qspec, aspec, whole(pk), whole(pv)],
-        out_specs=out_spec,
-        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[heads, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=heads,
+            scratch_shapes=[pltpu.VMEM((2, T, hd), pk.dtype),
+                            pltpu.VMEM((2, T, hd), pv.dtype),
+                            pltpu.VMEM((T, hd), jnp.float32),
+                            pltpu.VMEM((T, hd), jnp.float32),
+                            pltpu.VMEM((nkv, hd, R), jnp.float32),
+                            pltpu.SemaphoreType.DMA((2, 2))]),
+        out_shape=jax.ShapeDtypeStruct((B, nkv, R, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(pages, qg, apos, pk, pv)
+    )(start.astype(jnp.int32), end.astype(jnp.int32),
+      pages.reshape(-1).astype(jnp.int32),
+      qg.transpose(0, 2, 1, 3, 4).reshape(B, nkv, R, hd),
+      pk.reshape(n_pages, rows, hd), pv.reshape(n_pages, rows, hd))
+    return out.reshape(B, nkv, S, rep, hd).transpose(0, 2, 1, 3, 4)

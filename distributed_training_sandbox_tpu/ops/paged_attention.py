@@ -38,8 +38,8 @@ to the quantized gather path, integer accumulation).  Its design does
 not lower on a TPU (:func:`refuse_on_tpu` carries what Pallas said on a
 v5e): the whole pool is one block, the page id is read from a
 vector-memory ref and the view is assembled with
-``dynamic_update_slice``.  ``ops/flash_prefill.py`` shares that design
-and that fence.  Rewriting both for the hardware is ROADMAP S3.
+``dynamic_update_slice``.  ``ops/flash_prefill.py`` shared that design
+and that fence until PR 27 rewrote it after the float kernel here.
 """
 
 from __future__ import annotations
@@ -56,9 +56,9 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["paged_attention_decode", "paged_latent_attention_decode",
            "decode_kernel_takes", "refuse_on_tpu"]
 
-# What Pallas' TPU lowering says to the int8 decode kernel and to the
-# flash-prefill kernel (jax 0.9.0, libtpu 0.0.34, TPU v5 lite;
-# ``.lower(lowering_platforms=("tpu",))`` reproduces it without a chip).
+# What Pallas' TPU lowering says to the int8 decode kernel, and said to
+# the flash-prefill kernel before PR 27 (jax 0.9.0, libtpu 0.0.34, TPU v5
+# lite; ``.lower(lowering_platforms=("tpu",))`` reproduces it, chip or not).
 TPU_REFUSAL = (
     "\"The Pallas TPU lowering currently requires that the last two "
     "dimensions of your block shape are divisible by 8 and 128 "
@@ -77,8 +77,8 @@ def refuse_on_tpu(kernel: str) -> None:
         raise NotImplementedError(
             f"{kernel} does not lower on a TPU — {TPU_REFUSAL}.  The "
             f"kernel is CPU-tier (interpret mode) until it is rewritten "
-            f"for the hardware (ROADMAP S3); leave its flag off and the "
-            f"engine serves through its XLA gather path.")
+            f"for the hardware as the float kernels were; leave its flag "
+            f"off and the engine serves through its XLA gather path.")
 
 
 def _gather_pool(pool_ref, pages_ref, n_slot_pages: int, page: int):

@@ -15,9 +15,10 @@ and one-shot ``generate`` grew a static ``cache_capacity`` arg to pin
 the same extent.  With matched capacity, serving output is
 bitwise-identical to ``generate`` — the invariant the parity suite
 asserts per request.  (It holds on the gather path, which the CPU tier
-takes.  On a TPU the decode step's default is the paged kernel, whose
-contraction is bounded by each slot's length: equal to the gather path
-to float32 summation order, not bitwise.)
+takes.  On a TPU the default of the decode step and of the prefill
+chunk is a paged kernel, whose contraction is bounded by what the slot
+holds: equal to the gather path to float32 summation order, not
+bitwise.)
 
 **Zero retraces after warmup.**  The decode step has static shape:
 ``max_batch`` slots, an active mask, full-size page-table rows.
@@ -94,7 +95,7 @@ def _apply_rope_ragged(x, cos, sin):
 
 def _paged_layer_body(x, layer, *, cfg, cos, sin, use_rope, pk, pv,
                       pk_s, pv_s, pages, apos, valid, tp_axis=None,
-                      paged_kernel=False, flash_prefill=False):
+                      paged_kernel=False):
     """One decoder layer against the PAGED pool — the numerics of
     ``generate._cached_layer_body`` with scatter/gather storage:
 
@@ -185,17 +186,18 @@ def _paged_layer_body(x, layer, *, cfg, cos, sin, use_rope, pk, pv,
                                               probs_dtype=x.dtype)
         return tail(attn)
 
-    if flash_prefill and S > 1 and not quantized:
+    if paged_kernel and not quantized:
         # Pallas flash prefill: the whole chunk's attention in one
-        # tiled online-softmax kernel reading pages via the table — no
-        # (B, V, nkv, hd) gather view.  Single-tile (the default) is
-        # bitwise-equal to the gather+einsum path below
-        # (ops/flash_prefill.py pins the epilogue ordering).
+        # online-softmax kernel that reads the slot's live pages in
+        # place, up to the chunk's last valid row — neither the gather
+        # view nor the (B, nkv, rep, S, V) float32 scores below exist.
+        # Equal to the gather path to float32 summation order
+        # (ops/flash_prefill.py); int8 pools keep the gather path.
         from ..ops.flash_prefill import paged_flash_prefill
         with scope("attn_core"):
             qg = q.reshape(B, S, nkv, rep, hd)
             attn = paged_flash_prefill(qg, pk, pv, pages, apos,
-                                       probs_dtype=x.dtype)
+                                       valid=valid, probs_dtype=x.dtype)
         return tail(attn)
 
     # gather the slot's pages into the contiguous head-major view the
@@ -327,8 +329,7 @@ def _paged_latent_forward(params, ids, cfg, bufs: PoolBuffers, pages,
 
 
 def _paged_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
-                   valid, tp_axis=None, paged_kernel=False,
-                   flash_prefill=False):
+                   valid, tp_axis=None, paged_kernel=False):
     """ids (B, S) → (hidden x (B, S, H), bufs', counts) through the
     UNROLLED layer stack (static layer index into the per-layer pools,
     like ``generate._forward_cached``).  ``counts`` is None for the dense
@@ -354,7 +355,7 @@ def _paged_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
             pk_s=kss[li] if kss is not None else None,
             pv_s=vss[li] if vss is not None else None,
             pages=pages, apos=apos, valid=valid, tp_axis=tp_axis,
-            paged_kernel=paged_kernel, flash_prefill=flash_prefill)
+            paged_kernel=paged_kernel)
         if kss is not None:
             kss[li], vss[li] = ksc, vsc
     out = PoolBuffers(k=tuple(ks), v=tuple(vs),
@@ -413,7 +414,7 @@ def _decode_core(bufs, params, pages, toks, lengths, stop_at, active,
 
 
 def _prefill_core(bufs, params, pages_row, ids, pos, plen, *, cfg,
-                  tp_axis=None):
+                  tp_axis=None, paged_kernel=False):
     """One prefill CHUNK for one request: ids (1, C) host-padded with
     zeros, pos/plen () int32 (chunk start, full prompt length).  Writes
     the chunk's K/V into the request's pages; rows past the prompt
@@ -423,7 +424,8 @@ def _prefill_core(bufs, params, pages_row, ids, pos, plen, *, cfg,
     apos = pos + jnp.arange(Ck, dtype=jnp.int32)[None, :]
     valid = apos < plen
     x, bufs, _ = _paged_forward(params, ids, cfg, bufs, pages_row, apos,
-                                valid, tp_axis=tp_axis)
+                                valid, tp_axis=tp_axis,
+                                paged_kernel=paged_kernel)
     with scope("sample"):
         last = jnp.clip(plen - 1 - pos, 0, Ck - 1)
         xl = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
@@ -447,7 +449,7 @@ def _prefill_batch_core(bufs, params, pages, ids, pos, plen, *, cfg,
     valid = apos < plen[:, None]
     x, bufs, _ = _paged_forward(params, ids, cfg, bufs, pages, apos, valid,
                                 tp_axis=tp_axis,
-                                flash_prefill=flash_prefill)
+                                paged_kernel=flash_prefill)
     with scope("sample"):
         last = jnp.clip(plen - 1 - pos, 0, Ck - 1)
         xl = jnp.take_along_axis(x, last[:, None, None], axis=1)
@@ -534,16 +536,21 @@ def make_serve_decode_step(cfg, params=None, *, mesh=None,
 
 
 def make_serve_prefill_step(cfg, params=None, *, mesh=None,
-                            tp_axis: str = "tp", pool_spec=None):
+                            tp_axis: str = "tp", pool_spec=None,
+                            paged_kernel: bool = False):
     """The jitted single-request prefill-chunk step (see
-    :func:`_prefill_core`)."""
+    :func:`_prefill_core`).  ``paged_kernel`` routes the chunk's
+    attention through the Pallas flash prefill kernel
+    (``ops/flash_prefill.py`` — the slot's live pages read in place)."""
     cfg = _decode_cfg(cfg)
     if mesh is None:
-        return jax.jit(partial(_prefill_core, cfg=cfg, tp_axis=None),
+        return jax.jit(partial(_prefill_core, cfg=cfg, tp_axis=None,
+                               paged_kernel=paged_kernel),
                        donate_argnums=(0,))
     from jax.sharding import PartitionSpec as P
     from ..parallel.tensor import tp_specs
-    core = partial(_prefill_core, cfg=cfg, tp_axis=tp_axis)
+    core = partial(_prefill_core, cfg=cfg, tp_axis=tp_axis,
+                   paged_kernel=paged_kernel)
     in_specs = (pool_spec, tp_specs(params, tp_axis), P(), P(), P(), P())
     out_specs = (P(), pool_spec)
     return jax.jit(C.smap(core, mesh, in_specs=in_specs,
@@ -557,7 +564,7 @@ def make_serve_prefill_batch_step(cfg, params=None, *, mesh=None,
     :func:`_prefill_batch_core`).  ``flash_prefill`` routes the chunk's
     attention through the Pallas flash kernel
     (``ops/flash_prefill.py``) instead of the gather+einsum path —
-    bitwise-equal in the default single-tile mode."""
+    equal to it to float32 summation order."""
     cfg = _decode_cfg(cfg)
     if mesh is None:
         return jax.jit(partial(_prefill_batch_core, cfg=cfg,
@@ -677,24 +684,35 @@ class ServingEngine:
         self.sync_every = max(int(sync_every), 1)
         self.max_in_flight = int(max_in_flight)
         self.kv_quant = bool(kv_quant)
-        # decode attention through the Pallas paged kernel (pages read
-        # in place via the table — ops/paged_attention.py); prefill and
-        # speculative verify (S > 1) keep the gather path.  None: on
-        # where the kernel compiles — a TPU, a float pool, a head_dim
-        # and page_size it takes — and off elsewhere, where it would
-        # run interpreted
+        # attention through the Pallas paged kernels (pages read in
+        # place via the table): decode steps (ops/paged_attention.py)
+        # and the dense block's prefill chunks (ops/flash_prefill.py);
+        # speculative verify keeps the gather path.  None: on where the
+        # kernels compile — a TPU, a float pool, a head_dim and
+        # page_size they take — and off elsewhere, where they would run
+        # interpreted
+        on_tpu = jax.default_backend() == "tpu"
         if paged_kernel is None:
-            paged_kernel = (jax.default_backend() == "tpu"
-                            and not self.kv_quant)
+            paged_kernel = on_tpu and not self.kv_quant
             if paged_kernel:
                 from ..ops.paged_attention import decode_kernel_takes
                 paged_kernel = decode_kernel_takes(
                     self.cfg.dtype, self.cfg.kv_lora_rank
                     or self.cfg.resolved_head_dim, self.page_size)
         self.paged_kernel = bool(paged_kernel)
-        # prefill through the BATCHED multi-request step with the
-        # Pallas flash-attention kernel (ops/flash_prefill.py)
+        # prefill through the BATCHED multi-request step, always with
+        # the flash prefill kernel
         self.flash_prefill = bool(flash_prefill)
+        # whether a prefill chunk's attention is the flash prefill
+        # kernel: as decode resolved, for a float pool of the dense
+        # block, and on a TPU for a chunk length it compiles for too
+        self.prefill_kernel = self.flash_prefill or (
+            self.paged_kernel and not (self.kv_quant or self.cfg.mla_moe))
+        if self.prefill_kernel and on_tpu and not self.flash_prefill:
+            from ..ops.flash_prefill import prefill_kernel_takes
+            self.prefill_kernel = prefill_kernel_takes(
+                self.cfg.dtype, self.cfg.resolved_head_dim,
+                self.page_size, self.prefill_chunk)
         self.spec_k = int(spec_k)
         if self.flash_prefill and kv_quant:
             raise ValueError("the flash prefill kernel is float-only — "
@@ -860,7 +878,7 @@ class ServingEngine:
         else:
             self._prefill = make_serve_prefill_step(
                 self.cfg, self._params_pre, mesh=mesh, tp_axis=tp_axis,
-                pool_spec=pool_spec)
+                pool_spec=pool_spec, paged_kernel=self.prefill_kernel)
         self._draft_decode = self._verify = self._accept = None
         self._draft_prefill = self._draft_prefill_batch = None
         if self.spec_k:
@@ -880,7 +898,8 @@ class ServingEngine:
             else:
                 self._draft_prefill = make_serve_prefill_step(
                     self.draft_cfg, self._draft_params, mesh=mesh,
-                    tp_axis=tp_axis, pool_spec=dspec)
+                    tp_axis=tp_axis, pool_spec=dspec,
+                    paged_kernel=self.prefill_kernel)
         if self.disaggregate:
             # KV handoff: gather the request's page blocks out of the
             # prefill pool, ship, scatter into its decode pages.  Full
@@ -938,6 +957,9 @@ class ServingEngine:
                       # decode steps whose attention read the pages in
                       # place (the paged kernel) and built no gather view
                       "decode_inplace_steps": 0, "prefill_chunks": 0,
+                      # prefill chunks whose attention was the flash
+                      # prefill kernel: likewise
+                      "prefill_inplace_chunks": 0,
                       "admit_s": 0.0, "bookkeep_s": 0.0,
                       # measured per-phase device time — the per-burst
                       # priors the virtual-clock simulator's cost model
@@ -1078,6 +1100,7 @@ class ServingEngine:
                 self.draft_pool.bufs = dbufs
             req.prefill_pos = min(pos + Ck, req.n_prompt)
             self.stats["prefill_chunks"] += 1
+            self.stats["prefill_inplace_chunks"] += self.prefill_kernel
             final = req.prefill_pos >= req.n_prompt
             if final and self.disaggregate:
                 # final chunk: hand the KV off to the decode slice
@@ -1200,6 +1223,7 @@ class ServingEngine:
                     self.draft_pool.bufs, self._draft_params, *args)
                 self.draft_pool.bufs = dbufs
             self.stats["prefill_chunks"] += 1
+            self.stats["prefill_inplace_chunks"] += self.prefill_kernel
             finishing = []
             for i, req in enumerate(reqs):
                 req.prefill_pos = min(req.prefill_pos + Ck, req.n_prompt)

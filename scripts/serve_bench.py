@@ -35,8 +35,9 @@ The decode-speed-frontier legs ride the same trace and gates:
 --overlap-frac F`` for the tenant-skewed trace whose requests share
 system prompts), ``--spec-k K --draft-layers N`` (speculative decoding
 via a truncated-target draft), ``--flash-prefill`` (batched prefill
-through the Pallas flash kernel).  All three keep the bitwise parity
-gate — temp-0 speculation and the single-tile flash kernel are exact.
+through the Pallas flash kernel).  All three keep the parity gate —
+temp-0 speculation is exact, and the flash kernel equals the gather
+path to float32 summation order (the same tokens in float32).
 
     python scripts/serve_bench.py --requests 64 --rate 16 --tp 2
     python scripts/serve_bench.py --requests 8 --disaggregate

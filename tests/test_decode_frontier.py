@@ -254,6 +254,8 @@ def test_spec_rollback_with_shallow_draft_stays_bitwise():
 # ---- flash prefill kernel ----------------------------------------------
 
 def _flash_fixture(seed=0, B=3, S=8, P=4, page=4, nkv=2, rep=2, hd=8):
+    """A pool, a table and one chunk a batch row, each at a start of its
+    own (the kernel takes a chunk: consecutive positions)."""
     rng = np.random.default_rng(seed)
     n_pages = B * P + 1
     f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
@@ -261,16 +263,14 @@ def _flash_fixture(seed=0, B=3, S=8, P=4, page=4, nkv=2, rep=2, hd=8):
     pk, pv = f(n_pages, page, nkv, hd), f(n_pages, page, nkv, hd)
     pages = jnp.asarray(np.arange(1, B * P + 1, dtype=np.int32)
                         .reshape(B, P))
-    apos = jnp.asarray(rng.integers(0, P * page, size=(B, S)), jnp.int32)
+    starts = rng.integers(0, P * page - S + 1, size=(B, 1))
+    apos = jnp.asarray(starts + np.arange(S)[None], jnp.int32)
     return qg, pk, pv, pages, apos
 
 
 @jax.jit
 def _flash_reference(qg, pk, pv, pages, apos):
-    """The engine's gather+einsum prefill attention, op for op.  Jitted:
-    the bitwise tier is defined within a compiled computation (the
-    regime every engine step runs in) — eager op-by-op execution fuses
-    differently and drifts by an ulp."""
+    """The engine's gather+einsum prefill attention, op for op."""
     B, S = qg.shape[:2]
     V = pages.shape[1] * pk.shape[1]
     gk = pk[pages].reshape(B, V, *pk.shape[2:])
@@ -285,44 +285,48 @@ def _flash_reference(qg, pk, pv, pages, apos):
                       gv, preferred_element_type=jnp.float32)
 
 
-def test_flash_prefill_single_tile_is_bitwise():
-    from distributed_training_sandbox_tpu.ops.flash_prefill import (
-        paged_flash_prefill)
+def test_flash_prefill_single_block_equals_the_gather_path():
+    """A table of no more pages than one DMA block: the online softmax
+    runs once, and what is left against the gather path is the divide
+    at the end and float32 summation order (the old kernel's bitwise
+    tier went with its whole-view tile)."""
+    from distributed_training_sandbox_tpu.ops import flash_prefill as F
     qg, pk, pv, pages, apos = _flash_fixture()
+    assert pages.shape[1] <= F.PAGES_PER_BLOCK
     ref = np.asarray(_flash_reference(qg, pk, pv, pages, apos))
-    out = np.asarray(paged_flash_prefill(qg, pk, pv, pages, apos,
-                                         probs_dtype=jnp.float32,
-                                         interpret=True))
-    assert out.shape == ref.shape and (out == ref).all()
+    out = np.asarray(F.paged_flash_prefill(qg, pk, pv, pages, apos,
+                                           probs_dtype=jnp.float32,
+                                           interpret=True))
+    assert out.shape == ref.shape and out.dtype == np.float32
+    np.testing.assert_allclose(out, ref, rtol=0,
+                               atol=1e-6 * np.abs(ref).max())
 
 
-def test_flash_prefill_multi_tile_online_softmax_allclose():
-    from distributed_training_sandbox_tpu.ops.flash_prefill import (
-        paged_flash_prefill)
+@pytest.mark.parametrize("block_pages", [1, 2, 3])
+def test_flash_prefill_multi_block_online_softmax_allclose(monkeypatch,
+                                                           block_pages):
+    """Several blocks a slot, also where the block does not divide the
+    4-page table: the running maximum, the rescaled sum and accumulator
+    across blocks give the gather path's value."""
+    from distributed_training_sandbox_tpu.ops import flash_prefill as F
+    monkeypatch.setattr(F, "PAGES_PER_BLOCK", block_pages)
     qg, pk, pv, pages, apos = _flash_fixture(seed=1)
     ref = np.asarray(_flash_reference(qg, pk, pv, pages, apos))
-    for blk in (1, 2):
-        out = np.asarray(paged_flash_prefill(
-            qg, pk, pv, pages, apos, probs_dtype=jnp.float32,
-            kv_block_pages=blk, interpret=True))
-        np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
-    # kv_block_pages == P degenerates to the bitwise single tile
-    out = np.asarray(paged_flash_prefill(
-        qg, pk, pv, pages, apos, probs_dtype=jnp.float32,
-        kv_block_pages=4, interpret=True))
-    assert (out == ref).all()
+    out = np.asarray(F.paged_flash_prefill(
+        qg, pk, pv, pages, apos, probs_dtype=jnp.float32, interpret=True))
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
 
 
-def test_flash_prefill_rejects_int8_and_ragged_blocks():
+def test_flash_prefill_rejects_int8_and_shapes_a_tpu_refuses():
     from distributed_training_sandbox_tpu.ops.flash_prefill import (
         paged_flash_prefill)
     qg, pk, pv, pages, apos = _flash_fixture()
     with pytest.raises(ValueError, match="float-pool"):
         paged_flash_prefill(qg, pk.astype(jnp.int8), pv.astype(jnp.int8),
                             pages, apos, interpret=True)
-    with pytest.raises(ValueError, match="divide"):
-        paged_flash_prefill(qg, pk, pv, pages, apos, kv_block_pages=3,
-                            interpret=True)
+    # compiled (not interpreted), the tiny head_dim is named, not tried
+    with pytest.raises(ValueError, match="prefill_kernel_takes"):
+        paged_flash_prefill(qg, pk, pv, pages, apos, interpret=False)
     with pytest.raises(ValueError, match="flash"):
         ServingEngine(_chaotic_params(T.TINY_LM), T.TINY_LM,
                       flash_prefill=True, kv_quant=True)
@@ -337,7 +341,7 @@ _ALL_LEGS = dict(prefix_cache=True, spec_k=2, draft_layers=1,
 @pytest.mark.parametrize("legs,base", [
     # each leg alone on the plain base, then the full stack against
     # every base config — kv_quant runs cache+spec (flash is float-pool
-    # only, pinned by test_flash_prefill_rejects_int8_and_ragged_blocks)
+    # only, pinned by test_flash_prefill_rejects_int8_and_shapes_a_tpu_refuses)
     (dict(prefix_cache=True), "plain"),
     (dict(spec_k=2, draft_layers=1), "plain"),
     (dict(flash_prefill=True), "plain"),

@@ -466,17 +466,152 @@ def test_paged_decode_kernel_ignores_what_lies_past_a_length(geometry):
     np.testing.assert_array_equal(np.asarray(clean), np.asarray(out))
 
 
+# ----------------------------- the flash prefill kernel, on its own
+
+# name -> (page, n_kv, rep, hd, pages a slot, chunk, dtype; the chunk
+# divides the view, as the engine's does): tiny widths in one DMA block,
+# a table longer than one block that is no multiple of it, and the two
+# serving cells' geometry (chat: chunk 256 against a
+# 2,048 view; documents: chunk 512 against 8,192)
+_PREFILL_GEOMETRY = {
+    "tiny": (4, 2, 2, 8, 3, 4, jnp.float32),
+    "tiny-blocks": (4, 1, 4, 8, 19, 4, jnp.float32),
+    "chat-cell": (16, 4, 4, 128, 128, 256, jnp.float32),
+    "documents-cell": (16, 4, 4, 128, 512, 512, jnp.float32),
+}
+
+
+def _prefill_case(geometry, seed=0):
+    """A pool, a page table and one chunk a batch row for ``geometry``,
+    rows ragged in (chunk start, prompt length): a pad row (length 0), a
+    prompt shorter than a page, prompts that end at a page boundary and
+    at a DMA block boundary and their neighbours (each one's final
+    chunk, so rows past the prompt are padding), a first chunk and a
+    mid-prompt chunk of a longer prompt, and the full view."""
+    from distributed_training_sandbox_tpu.ops.flash_prefill import (
+        PAGES_PER_BLOCK)
+    page, nkv, rep, hd, P, S, dt = _PREFILL_GEOMETRY[geometry]
+    V, span = P * page, PAGES_PER_BLOCK * page
+    plens = [0, page - 1, page, page + 1, V]
+    if span < V:
+        plens += [span - 1, span, span + 1]
+    if geometry == "documents-cell":      # a 268 MB reference a row
+        plens = [0, page - 1, span + 1, V]
+    final = lambda n: max(n - 1, 0) // S * S      # its last chunk's start
+    rows = [(final(n), n) for n in plens]
+    if V >= 3 * S:
+        rows += [(0, V - 3), (S, V - 3)]
+    starts, plens = (np.asarray(c, np.int32) for c in zip(*rows))
+    B = len(rows)
+    n_pages = B * P + 1
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    pk = jax.random.normal(ks[0], (n_pages, page, nkv, hd), dt)
+    pv = jax.random.normal(ks[1], (n_pages, page, nkv, hd), dt)
+    qg = jax.random.normal(ks[2], (B, S, nkv, rep, hd), dt)
+    pages = np.random.RandomState(seed).permutation(
+        np.arange(1, n_pages)).reshape(B, P).astype(np.int32)
+    apos = starts[:, None] + np.arange(S, dtype=np.int32)[None]
+    valid = apos < plens[:, None]
+    return (qg, pk, pv, jnp.asarray(pages), jnp.asarray(apos),
+            jnp.asarray(valid))
+
+
+def _prefill_reference(qg, pk, pv, pages, apos):
+    """The engine's gather-then-einsum attention core, a batch row at a
+    time (its float32 scores are 268 MB a row at the documents cell)."""
+    from chip_smoke import paged_attention_xla
+    row = jax.jit(lambda q, pg, ap: paged_attention_xla(
+        q, pk, pv, pg, ap, q.dtype))
+    return np.concatenate([
+        np.asarray(row(qg[b:b + 1], pages[b:b + 1], apos[b:b + 1]))
+        for b in range(qg.shape[0])])
+
+
+@pytest.mark.parametrize("geometry", list(_PREFILL_GEOMETRY))
+def test_flash_prefill_kernel_matches_gather(geometry):
+    """The kernel (interpret mode) against the engine's gather path, to
+    float32 summation order, at every valid row of every ragged chunk; a
+    batch row with nothing live gets zeros, and padding rows are
+    finite."""
+    from distributed_training_sandbox_tpu.ops.flash_prefill import (
+        paged_flash_prefill)
+
+    qg, pk, pv, pages, apos, valid = _prefill_case(geometry)
+    out = paged_flash_prefill(qg, pk, pv, pages, apos, valid=valid)
+    ref = _prefill_reference(qg, pk, pv, pages, apos)
+    assert out.shape == ref.shape and out.dtype == jnp.float32
+    out, valid = np.asarray(out), np.asarray(valid)
+    assert valid[1:].any(axis=1).all() and not valid[0].any()
+    _assert_f32_dot_close(ref[valid], out[valid])
+    assert not out[0].any() and np.isfinite(out).all()
+    if geometry.startswith("tiny"):
+        # without ``valid`` every row is live: the chunk's own last
+        # position bounds the read
+        every = paged_flash_prefill(qg, pk, pv, pages, apos)
+        _assert_f32_dot_close(ref, every)
+
+
+@pytest.mark.parametrize("geometry", ["tiny", "tiny-blocks", "chat-cell"])
+def test_flash_prefill_kernel_ignores_what_lies_past_the_live_end(geometry):
+    """Finite garbage in a slot's pages past its chunk's live end (where
+    the engine would have diverted the padding rows' K/V, and whatever an
+    earlier request left), in its unused pages and the null page, and in
+    the padding rows' queries changes no bit of a valid row's output."""
+    from distributed_training_sandbox_tpu.ops.flash_prefill import (
+        paged_flash_prefill)
+
+    qg, pk, pv, pages, apos, valid = _prefill_case(geometry, seed=1)
+    page, P = pk.shape[1], pages.shape[1]
+    clean = paged_flash_prefill(qg, pk, pv, pages, apos, valid=valid)
+    v = np.asarray(valid)
+    ends = np.where(v.any(1), (np.asarray(apos) * v).max(1) + 1, 0)
+    # position w of row b lives at (pages[b, w // page], w % page)
+    past = np.arange(P * page)[None, :] >= ends[:, None]       # (B, V)
+    junk = np.zeros(pk.shape[:2], bool)
+    junk[np.asarray(pages), :] = past.reshape(len(ends), P, page)
+    junk[0] = True
+    noise = 1e4 * jax.random.normal(jax.random.PRNGKey(9), pk.shape,
+                                    pk.dtype)
+    dirty = lambda pool: jnp.where(jnp.asarray(junk)[:, :, None, None],
+                                   noise, pool)
+    q_dirty = jnp.where(valid[:, :, None, None, None], qg, 1e4)
+    out = paged_flash_prefill(q_dirty, dirty(pk), dirty(pv), pages, apos,
+                              valid=valid)
+    np.testing.assert_array_equal(np.asarray(clean)[v], np.asarray(out)[v])
+    assert not np.asarray(out)[0].any()
+
+
+@pytest.mark.parametrize("dtype,head_dim,page,chunk,takes", [
+    (jnp.bfloat16, 128, 16, 512, True),     # the documents cell
+    (jnp.bfloat16, 128, 16, 256, True),     # the chat cell
+    (jnp.bfloat16, 128, 16, 16, True),
+    (jnp.float32, 128, 8, 8, True),
+    (jnp.bfloat16, 256, 32, 128, True),
+    (jnp.bfloat16, 128, 16, 8, False),      # half a bf16 sublane tile
+    (jnp.bfloat16, 128, 16, 5, False),      # speculative verify's k + 1
+    (jnp.bfloat16, 128, 16, 1, False),      # a decode step
+    (jnp.bfloat16, 64, 16, 256, False),     # head_dim under a lane tile
+    (jnp.bfloat16, 128, 8, 256, False),     # half a page tile
+    (jnp.int8, 128, 32, 256, False),
+], ids=lambda v: getattr(v, "__name__", str(v)))
+def test_prefill_kernel_takes(dtype, head_dim, page, chunk, takes):
+    from distributed_training_sandbox_tpu.ops.flash_prefill import (
+        prefill_kernel_takes)
+    assert prefill_kernel_takes(dtype, head_dim, page, chunk) is takes
+
+
 # ------------------------------------------- what a TPU makes of them
 
 def test_serving_kernels_refuse_a_tpu(monkeypatch):
-    """The flash-prefill kernel and the int8 decode kernel do not lower
-    on a TPU.  There they raise an error that names Pallas' refusal —
-    whatever ``interpret`` says, so they can neither run interpreted nor
-    give way to the gather path unseen.  The float decode kernel no
-    longer refuses: it compiles there at the shapes
-    ``decode_kernel_takes`` names and says so at the others."""
+    """The int8 decode kernel does not lower on a TPU.  There it raises
+    an error that names Pallas' refusal — whatever ``interpret`` says, so
+    it can neither run interpreted nor give way to the gather path
+    unseen.  The float decode kernel and, since PR 27, the flash prefill
+    kernel do not refuse: they compile there at the shapes
+    ``decode_kernel_takes`` / ``prefill_kernel_takes`` name and say so
+    at the others."""
     from distributed_training_sandbox_tpu.ops.flash_prefill import (
-        paged_flash_prefill)
+        paged_flash_prefill, prefill_kernel_takes)
     from distributed_training_sandbox_tpu.ops.paged_attention import (
         decode_kernel_takes, paged_attention_decode)
 
@@ -484,42 +619,105 @@ def test_serving_kernels_refuse_a_tpu(monkeypatch):
     pk8 = jnp.zeros((8, 4, 1, 8), jnp.int8)
     scale = jnp.ones((8, 4, 1, 1))
     pages = jnp.zeros((2, 2), jnp.int32)
-    q8 = dict(q_scale=jnp.ones((2, 1, 1, 4, 1)), pk_s=scale, pv_s=scale)
-    fenced = (
-        (paged_attention_decode, jnp.zeros((2, 1, 1, 4, 8), jnp.int8),
-         pk8, jnp.zeros((2, 1), jnp.int32), q8),
-        (paged_flash_prefill, jnp.zeros((2, 4, 1, 4, 8)), pk,
-         jnp.zeros((2, 4), jnp.int32), {}))
+    q8 = jnp.zeros((2, 1, 1, 4, 8), jnp.int8)
+    kw8 = dict(q_scale=jnp.ones((2, 1, 1, 4, 1)), pk_s=scale, pv_s=scale)
     q = jnp.zeros((2, 1, 1, 4, 8))
+    q_pre = jnp.zeros((2, 4, 1, 4, 8))
     apos = jnp.zeros((2, 1), jnp.int32)
-    for fn, qg, pool, ap, kw in fenced:   # fine where there is no TPU
-        assert fn(qg, pool, pool, pages, ap, **kw).shape == qg.shape
+    apos_pre = jnp.zeros((2, 4), jnp.int32)
+    # fine where there is no TPU
+    assert paged_attention_decode(q8, pk8, pk8, pages, apos,
+                                  **kw8).shape == q8.shape
     assert paged_attention_decode(q, pk, pk, pages, apos).shape == q.shape
+    assert paged_flash_prefill(q_pre, pk, pk, pages,
+                               apos_pre).shape == q_pre.shape
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    for fn, qg, pool, ap, kw in fenced:
-        for how in ({}, {"interpret": True}, {"interpret": False}):
-            with pytest.raises(NotImplementedError,
-                               match="dynamic_update_slice") as e:
-                fn(qg, pool, pool, pages, ap, **kw, **how)
-            assert fn.__name__ in str(e.value) and "S3" in str(e.value)
-    # the float decode kernel: a shape it does not compile for is named
+    for how in ({}, {"interpret": True}, {"interpret": False}):
+        with pytest.raises(NotImplementedError,
+                           match="dynamic_update_slice") as e:
+            paged_attention_decode(q8, pk8, pk8, pages, apos, **kw8, **how)
+        assert "paged_attention_decode" in str(e.value)
+        assert "ROADMAP" not in str(e.value)
+    # the float kernels: a shape one does not compile for is named
     assert not decode_kernel_takes(pk.dtype, 8, 4)
     with pytest.raises(ValueError, match="decode_kernel_takes"):
         paged_attention_decode(q, pk, pk, pages, apos)
-    # ... one it takes lowers to a Mosaic call, bf16 and float32
+    assert not prefill_kernel_takes(pk.dtype, 8, 4, 4)
+    with pytest.raises(ValueError, match="prefill_kernel_takes"):
+        paged_flash_prefill(q_pre, pk, pk, pages, apos_pre)
+    # ... one they take lowers to a Mosaic call, bf16 and float32
+    sd = jax.ShapeDtypeStruct
     for dt, page in ((jnp.bfloat16, 16), (jnp.float32, 8)):
         assert decode_kernel_takes(dt, 128, page)
-        sd = jax.ShapeDtypeStruct
-        text = jax.jit(
-            lambda qg, pool, pg, ap: paged_attention_decode(
-                qg, pool, pool, pg, ap)).trace(
-            sd((4, 1, 4, 4, 128), dt), sd((33, page, 4, 128), dt),
-            sd((4, 8), jnp.int32), sd((4, 1), jnp.int32)).lower(
-            lowering_platforms=("tpu",)).as_text()
-        assert "tpu_custom_call" in text
+        assert prefill_kernel_takes(dt, 128, page, 32)
+        for fn, S in ((paged_attention_decode, 1),
+                      (paged_flash_prefill, 32)):
+            text = jax.jit(
+                lambda qg, pool, pg, ap: fn(qg, pool, pool, pg, ap)).trace(
+                sd((4, S, 4, 4, 128), dt), sd((33, page, 4, 128), dt),
+                sd((4, 8), jnp.int32), sd((4, S), jnp.int32)).lower(
+                lowering_platforms=("tpu",)).as_text()
+            assert "tpu_custom_call" in text
     assert not decode_kernel_takes(jnp.bfloat16, 128, 8)
     assert not decode_kernel_takes(jnp.bfloat16, 64, 16)
     assert not decode_kernel_takes(jnp.int8, 128, 32)
+
+
+def _cell_widths_cfg():
+    """SmolLM3's widths at two layers, bf16: what the serving cells'
+    programs are lowered at."""
+    return T.TransformerConfig(
+        vocab_size=128256, hidden_size=2048, intermediate_size=11008,
+        num_hidden_layers=2, num_attention_heads=16,
+        num_key_value_heads=4, rope_theta=5e6, nope_interval=4,
+        tie_word_embeddings=True, dtype=jnp.bfloat16, remat=False)
+
+
+@pytest.mark.parametrize("batch,pages_per,chunk", [(32, 128, 256),
+                                                   (8, 512, 512)],
+                         ids=["chat-256x2048", "documents-512x8192"])
+def test_engine_prefill_program_lowers_for_tpu_without_the_gather(
+        monkeypatch, batch, pages_per, chunk):
+    """The engine's prefill-chunk program at the serving cells' shapes,
+    lowered FOR a TPU on this host: attention is ONE Mosaic call that the
+    layers share, nothing has the (1, V, n_kv, hd) extent of the gathered view and nothing the
+    (1, n_kv, rep, S, V) float32 extent of its scores, both of which the
+    gather path's program holds."""
+    from distributed_training_sandbox_tpu.serving import (
+        make_serve_prefill_step)
+    from distributed_training_sandbox_tpu.serving.kv_pool import (
+        PoolBuffers)
+
+    cfg = _cell_widths_cfg()
+    page, nkv, hd = 16, 4, 128
+    rep = cfg.num_attention_heads // nkv
+    V = pages_per * page
+    sd = jax.ShapeDtypeStruct
+    params = jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0),
+                                                  cfg))
+    pool = tuple(sd((batch * pages_per + 1, page, nkv, hd), jnp.bfloat16)
+                 for _ in range(cfg.num_hidden_layers))
+    args = (PoolBuffers(k=pool, v=pool, k_scale=None, v_scale=None),
+            params, sd((1, pages_per), jnp.int32),
+            sd((1, chunk), jnp.int32), sd((), jnp.int32),
+            sd((), jnp.int32))
+    view = (f"1x{pages_per}x{page}x{nkv}x{hd}x", f"1x{V}x{nkv}x{hd}x",
+            f"1x{nkv}x{V}x{hd}x")
+    scores = f"1x{nkv}x{rep}x{chunk}x{V}xf32"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def lowered(paged_kernel):
+        step = make_serve_prefill_step(cfg, paged_kernel=paged_kernel)
+        return step.trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    text = lowered(True)
+    # the layers share one trace of the kernel's call, so one lowering
+    assert text.count("tpu_custom_call") == 1
+    assert text.count("call @_prefill_float") == cfg.num_hidden_layers
+    assert not any(v in text for v in view) and scores not in text
+    gather = lowered(False)
+    assert any(v in gather for v in view) and scores in gather
 
 
 @pytest.mark.parametrize("batch,pages_per", [(32, 128), (8, 512)],
@@ -535,11 +733,7 @@ def test_engine_decode_program_lowers_for_tpu_without_the_gather(
     from distributed_training_sandbox_tpu.serving.kv_pool import (
         PoolBuffers)
 
-    cfg = T.TransformerConfig(
-        vocab_size=128256, hidden_size=2048, intermediate_size=11008,
-        num_hidden_layers=2, num_attention_heads=16,
-        num_key_value_heads=4, rope_theta=5e6, nope_interval=4,
-        tie_word_embeddings=True, dtype=jnp.bfloat16, remat=False)
+    cfg = _cell_widths_cfg()
     page, nkv, hd = 16, 4, 128
     sd = jax.ShapeDtypeStruct
     params = jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0),

@@ -162,6 +162,22 @@ def test_ragged_batch_parity_and_zero_retraces():
 
 # ---- the decode path's default: in place where the kernel compiles ------
 
+def _engine_as_seen_from(monkeypatch, backend, engine_kw, cfg_kw):
+    """A TINY_LM engine (page 16, chunk 16) built while
+    ``jax.default_backend()`` says ``backend``."""
+    import dataclasses
+    import jax.numpy as jnp
+    cfg_kw = dict(cfg_kw)
+    if "dtype" in cfg_kw:
+        cfg_kw["dtype"] = jnp.dtype(cfg_kw["dtype"])
+    cfg = dataclasses.replace(T.TINY_LM, **cfg_kw)
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    kw = dict(max_batch=2, page_size=16, max_seq_len=64, prefill_chunk=16)
+    kw.update(engine_kw)
+    return ServingEngine(params, cfg, **kw)
+
+
 @pytest.mark.parametrize("backend,engine_kw,cfg_kw,on", [
     ("tpu", {}, dict(head_dim=128), True),
     ("tpu", dict(kv_quant=True), dict(head_dim=128), False),
@@ -179,19 +195,90 @@ def test_paged_kernel_default_resolves_from_what_the_engine_sees(
     kernel on exactly where it compiles: a TPU backend, a float pool, a
     head_dim and page_size ``decode_kernel_takes``; True/False are
     taken as given."""
-    import dataclasses
-    import jax.numpy as jnp
-    cfg_kw = dict(cfg_kw)
-    if "dtype" in cfg_kw:
-        cfg_kw["dtype"] = jnp.dtype(cfg_kw["dtype"])
-    cfg = dataclasses.replace(T.TINY_LM, **cfg_kw)
-    params = T.init_params(jax.random.PRNGKey(0), cfg)
-    monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    kw = dict(max_batch=2, page_size=16, max_seq_len=64, prefill_chunk=16)
-    kw.update(engine_kw)
-    eng = ServingEngine(params, cfg, **kw)
+    eng = _engine_as_seen_from(monkeypatch, backend, engine_kw, cfg_kw)
     assert eng.paged_kernel is on
     assert eng.stats["decode_inplace_steps"] == 0
+
+
+@pytest.mark.parametrize("backend,engine_kw,cfg_kw,on", [
+    ("tpu", {}, dict(head_dim=128), True),
+    ("tpu", dict(kv_quant=True), dict(head_dim=128), False),
+    ("tpu", {}, dict(head_dim=64), False),
+    ("tpu", dict(prefill_chunk=8), dict(head_dim=128, dtype="bfloat16"),
+     False),
+    ("tpu", dict(paged_kernel=False), dict(head_dim=128), False),
+    ("cpu", {}, dict(head_dim=128), False),
+    ("cpu", dict(paged_kernel=True), {}, True),
+    ("cpu", dict(paged_kernel=True, kv_quant=True), {}, False),
+    ("cpu", dict(flash_prefill=True), {}, True),
+], ids=["tpu-float", "tpu-kv_quant", "tpu-head_dim-64", "tpu-bf16-chunk-8",
+        "tpu-told-off", "cpu", "cpu-told-on", "cpu-told-on-kv_quant",
+        "cpu-batched-step"])
+def test_prefill_kernel_resolves_as_the_decode_kernel_does(
+        monkeypatch, backend, engine_kw, cfg_kw, on):
+    """A prefill chunk's attention (S > 1) is the flash prefill kernel
+    exactly where the ``paged_kernel`` resolution turned the decode
+    kernel on, the pool is float and, on a TPU, the chunk is one
+    ``prefill_kernel_takes``; elsewhere the chunk keeps the gather path.
+    The batched step (``flash_prefill``) is the kernel by definition."""
+    eng = _engine_as_seen_from(monkeypatch, backend, engine_kw, cfg_kw)
+    assert eng.prefill_kernel is on
+    assert eng.stats["prefill_inplace_chunks"] == 0
+
+
+@pytest.mark.parametrize("engine_kw,inplace", [
+    ({}, False), (dict(paged_kernel=True), True),
+    (dict(paged_kernel=True, kv_quant=True), False),
+    (dict(flash_prefill=True), True),
+], ids=["default-off-the-chip", "kernel", "kernel-int8-pool",
+        "batched-step"])
+def test_prefill_inplace_chunks_counts_the_kernels_chunks(engine_kw,
+                                                          inplace):
+    """``stats["prefill_inplace_chunks"]`` equals
+    ``stats["prefill_chunks"]`` exactly when the prefill program's
+    attention is the flash prefill kernel, and stays 0 on the gather
+    path (an int8 pool keeps it, whatever the decode step does)."""
+    cfg = T.TINY_LM
+    params = _chaotic_params(cfg)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n).astype(np.int32)
+               for n in (5, 21, 9)]
+    eng = ServingEngine(params, cfg, max_batch=2, page_size=8,
+                        max_seq_len=48, prefill_chunk=8, **engine_kw)
+    for p in prompts:
+        eng.submit(p, max_new_tokens=3)
+    eng.run()
+    assert eng.stats["prefill_chunks"] >= 3
+    assert eng.stats["prefill_inplace_chunks"] == (
+        eng.stats["prefill_chunks"] if inplace else 0)
+
+
+def test_engine_with_the_prefill_kernel_serves_the_gather_engines_tokens():
+    """Token for token: ragged prompts (shorter than a page, ending
+    inside a chunk, several chunks long, with admit/evict churn) through
+    the engine whose prefill and decode attention are the kernels
+    (interpreted), against the gather engine."""
+    cfg = T.TINY_LM
+    params = _chaotic_params(cfg)
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n).astype(np.int32)
+               for n in (3, 8, 13, 30, 17)]
+
+    def run(**kw):
+        eng = ServingEngine(params, cfg, max_batch=2, page_size=4,
+                            max_seq_len=48, prefill_chunk=8,
+                            sync_every=2, **kw)
+        reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
+        eng.run()
+        assert eng.retraces_after_warmup() == 0
+        return eng, [np.asarray(r.tokens, np.int32).tolist() for r in reqs]
+
+    gather, want = run(paged_kernel=False)
+    kernel, got = run(paged_kernel=True)
+    assert got == want
+    assert gather.stats["prefill_inplace_chunks"] == 0
+    assert kernel.stats["prefill_inplace_chunks"] \
+        == kernel.stats["prefill_chunks"] == gather.stats["prefill_chunks"]
 
 
 @pytest.mark.parametrize("paged_kernel", [None, True],
