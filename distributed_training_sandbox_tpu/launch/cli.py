@@ -112,9 +112,9 @@ def build_parser() -> argparse.ArgumentParser:
     lst = sub.add_parser("list", help="known strategies and past runs")
     _add_common(lst)
 
-    # closed-loop autotuner: `dts-launch tune ...` forwards everything
-    # after the subcommand to scripts/tune.py (enumerate / prune / rank /
-    # measure -> plan.json; --check = the CI staleness gate)
+    # autotuner: `dts-launch tune ...` forwards everything after the
+    # subcommand to scripts/tune.py (enumerate / prune / rank ->
+    # plan.json; --check = the staleness gate)
     tune = sub.add_parser(
         "tune", add_help=False,
         help="autotune knobs -> plan.json (scripts/tune.py)")

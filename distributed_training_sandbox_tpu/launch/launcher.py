@@ -76,8 +76,8 @@ STRATEGY_SCRIPTS = {
     "train_composable": "train_composable.py",
     "ddp_utilization": "ddp_utilization.py",
 }
-# (ops_demo / long_context / memory_waterline / analyze_results /
-# moe_bench / moe_profile / zigzag_flops / make_ops_notebook are NOT
+# (ops_demo / memory_waterline / analyze_results / moe_profile /
+# zigzag_flops / make_ops_notebook are NOT
 # registered: they don't speak the strategy CLI contract the launcher
 # injects (--num-steps/--cpu-devices) — run them directly.)
 

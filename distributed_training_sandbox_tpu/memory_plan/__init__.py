@@ -2,7 +2,7 @@
 over remat × accumulation × quantization × offload, and contracted host
 offload of optimizer state / remat activations.
 
-Three layers (ROADMAP open item 4 — the BENCH_r03–r05 OOM wall):
+Three layers (the OOM wall a 16 GB chip puts across the knob space):
 
   * ``predictor`` — per-config waterline without running a step:
     compile-based (``memory_analysis()`` / the compiler's own

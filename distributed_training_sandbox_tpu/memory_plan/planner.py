@@ -14,14 +14,13 @@ predicts each candidate's waterline with the *analytic* predictor (no
 lowering — rejection is pre-compile by construction), drops everything
 over budget, and ranks the survivors by modeled throughput: measured
 step-time priors from bench JSON artifacts when a row with the same knobs
-exists, a relative-speed model calibrated on BENCH_r01–r05 otherwise.
+exists, a relative-speed model otherwise.
 An optional ``verify`` hook re-checks the winner with the compile-based
 predictor (``predict_from_step``) before anyone commits real time to it.
 """
 
 from __future__ import annotations
 
-import glob
 import json
 import re
 from dataclasses import dataclass, field, replace as _dc_replace
@@ -33,8 +32,9 @@ QUANT_CHOICES = ("bf16", "int8_bwd", "fp8")
 STATE_CHOICES = ("full", "int8")
 OFFLOAD_CHOICES = ("none", "opt")
 
-# Relative step-speed multipliers, calibrated on the measured BENCH_r03–r05
-# matrix (SMOLLM3_3B_L8 @ seq 8192, v5e): save_dots 110.1 vs full 103.6
+# Relative step-speed multipliers, calibrated on a knob matrix taken
+# before the ledger (SMOLLM3_3B_L8 @ seq 8192, v5e; rows of it are
+# tests/fixtures/bench_priors/): save_dots 110.1 vs full 103.6
 # bf16 TFLOPS; int8_bwd 122.0 vs 103.6; s8 state ~parity (126.2 vs 125.7);
 # q8-saved dots give ~most of save_dots' win back to the round-trip.
 _REMAT_SPEED = {"full": 1.00, "save_attn": 1.03, "save_dots": 1.06,
@@ -185,7 +185,7 @@ def modeled_speed(c: Candidate, prior: dict | None = None) -> float:
 
 # ---------------------------------------------------------- bench priors
 
-# bench.py row names: explicit[_reshard|_noreshard][_save_*]
+# bench row names: explicit[_reshard|_noreshard][_save_*]
 # [_int8(_bwd)|_fp8(_delayed|_pallas)][_s8][_b{N}x][_mesh{D}x{F}x{T}] —
 # parsed back into candidate knobs so measured rows can anchor the
 # planner's throughput model.
@@ -240,16 +240,12 @@ def parse_bench_config_name(name: str) -> dict | None:
 
 
 def load_bench_priors(paths=None) -> list[dict]:
-    """Measured matrix rows from bench JSON artifacts (the checked-in
-    ``BENCH_*.json`` / ``bench_matrix_tpu.json``), each annotated with
-    its parsed knobs — the planner's step-time priors."""
-    if paths is None:
-        paths = sorted(glob.glob("BENCH_*.json")) \
-            + [p for p in ("bench_matrix_tpu.json",)
-               if glob.glob(p)]
+    """Measured matrix rows from the bench JSON files the caller names
+    (none named = none loaded), each annotated with its parsed knobs —
+    the planner's step-time priors."""
     rows = []
     from ..telemetry.report import load_baseline_rows
-    for p in paths:
+    for p in paths or ():
         try:
             loaded = load_baseline_rows(str(p))
         except Exception:  # noqa: BLE001 - priors are best-effort

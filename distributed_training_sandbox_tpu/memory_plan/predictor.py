@@ -14,11 +14,11 @@ Two sources, in order of authority:
   * **analytic** (:func:`analytic_waterline`): a tensor-walk model over
     the architecture — params/grads/optimizer at rest plus a phase model
     of activations per remat policy and the streamed-loss buffers.  No
-    lowering, no compile: this is what lets ``bench.py`` and the planner
+    lowering, no compile: this is what lets the planner and the tuner
     reject a config in microseconds instead of burning the compile that
-    would OOM anyway.  Calibrated against the BENCH_r03–r05 compiler
-    verdicts (see RESULTS.md); the compile-based source supersedes it
-    whenever a compile is affordable.
+    would OOM anyway.  Calibrated against the compiler's OOM verdicts
+    on a v5e (``tests/test_tuner.py:OOM_WALL``); the compile-based
+    source supersedes it whenever a compile is affordable.
 """
 
 from __future__ import annotations

@@ -249,8 +249,8 @@ SMOLLM3_3B_L8 = TransformerConfig(
     num_hidden_layers=8, attention_impl="flash", loss_vocab_chunk=16_032)
 
 # Switch-MoE flagship: the 3B-L8 geometry with its MLP split into 8
-# experts of ffn 2752 (dense MLP FLOPs 4-ways active) — the bench/MoE-A/B
-# configuration as a named constant (scripts/moe_bench.py BASE).
+# experts of ffn 2752 (dense MLP FLOPs 4-ways active) — the MoE A/B's
+# configuration as a named constant.
 SMOLLM3_3B_L8_MOE = TransformerConfig(
     num_hidden_layers=8, attention_impl="flash", loss_vocab_chunk=16_032,
     n_experts=8, moe_ffn=2752, moe_dispatch="grouped")

@@ -108,7 +108,7 @@ def grouped_drop_fraction(expert: jax.Array, n_experts: int,
                           group_size: int, capacity_factor: float):
     """Fraction of (token, assignment) pairs the grouped dispatch would
     drop — computed with the SAME helpers as ``moe_mlp``'s "grouped"
-    branch, so reports (scripts/moe_bench.py) cannot drift from the
+    branch, so reports (scripts/moe_quality_ab.py) cannot drift from the
     timed path's semantics.  ``expert``: (N,) top-1 assignments or
     (N, k) top-k (choice-major priority, capacity cf·k·G/E — exactly the
     dispatch's rule)."""

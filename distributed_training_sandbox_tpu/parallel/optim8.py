@@ -4,7 +4,7 @@ The r4 memory accounting (``scripts/memory_waterline.py``) put the flagship's Ad
 mu/nu at 3.31 GB of the 4.96 GB resident state — the largest block on
 the chip.  Storing both moments int8 with per-row fp32 scales cuts that
 to ~1.7 GB, which is the same order as the 2.3–2.7 GB OOM margins that
-killed the save_dots×int8 knob crossings (BENCH_r04) — the state-side
+killed the save_dots×int8 knob crossings — the state-side
 attack on the 125.8 TFLOPS ceiling the r4 verdict prescribed (#4).
 
 Scheme (bitsandbytes-style blockwise, TPU-shaped):

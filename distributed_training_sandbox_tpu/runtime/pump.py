@@ -25,7 +25,7 @@ schema is unchanged), to ``PerformanceTracker.record_loss`` (so
 the drivers pass for their console prints.
 
 ``mode="sync"`` reproduces the old strictly synchronous loop through
-the same code path — the A/B lever the smoke test and ``bench.py`` use.
+the same code path — the A/B lever the smoke test uses.
 """
 
 from __future__ import annotations

@@ -8,8 +8,8 @@ BASELINE.md's NCCL-vs-ICI goal needs on the ICI side.
 
 Baselines for the regression check come in two shapes:
   * another run dir / runs root / ``summary.json`` (same schema), or
-  * a bench-style JSON (``bench_matrix_tpu.json``'s ``{"matrix": [...]}``
-    rows, a bare row list, or a ``BENCH_*.json`` driver artifact whose
+  * a bench-style JSON (``{"matrix": [...]}`` rows, a bare row list,
+    or a ``BENCH_*.json`` driver artifact whose
     ``tail`` string embeds the row list) — field aliases are normalized
     (``step_ms``/``step_time_ms``, ``tokens_per_sec``/``tokens_per_second``).
 """

@@ -1,16 +1,14 @@
-"""Closed-loop autotuner (ROADMAP item 5): turn the telemetry the stack
-already produces — waterline predictions (``memory_plan``), measured
-per-collective busbw (``telemetry/ledger`` via the run-registry export),
-bench priors (``BENCH_*.json``) — into the knobs a human used to pick by
-hand.
+"""Autotuner: turn the telemetry the stack already produces —
+waterline predictions (``memory_plan``), measured per-collective busbw
+(``telemetry/ledger`` via the run-registry export), bench priors (JSON
+files the caller names) — into the knobs a human used to pick by hand.
 
-Four stages behind one entry point (``scripts/tune.py`` /
+Stages behind one entry point (``scripts/tune.py`` /
 ``dts-launch tune``):
 
   1. **enumerate** — a declarative :class:`KnobSpace` over strategy ×
      batch × accum × remat × quantization × opt-state precision × host
-     offload × overlap/sync knobs (the same axes ``bench.py:run_matrix``
-     hand-enumerates), deterministic under a fixed seed.
+     offload × overlap/sync knobs, deterministic under a fixed seed.
   2. **prune** — reject over-HBM candidates *pre-compile* via the
      analytic waterline model; every rejection is reported with its
      predicted GB.
@@ -19,10 +17,12 @@ Four stages behind one entry point (``scripts/tune.py`` /
      knobs exists, the calibrated multiplier model otherwise, plus
      ledger-measured comm cost per (kind, payload bucket, axis) from
      the run-registry ``cost_model.json`` export.
-  4. **measure** — compile + short-measure only the top-k, and emit a
-     versioned, reproducible ``plan.json`` (chosen knobs + predicted and
-     measured numbers + provenance hashes of the cost model and knob
-     space) that the drivers replay exactly via ``--plan``.
+  4. **plan** — emit a versioned, reproducible ``plan.json`` (chosen
+     knobs + predicted numbers + provenance hashes of the cost model
+     and knob space) that the drivers replay exactly via ``--plan``.
+     The throughput objective compiles and measures nothing (its choice
+     is the predicted best); the serving objective measures its top-k
+     pool-knob candidates through the engine.
 """
 
 from .knobs import KnobSpace, ServingKnobSpace, TunerCandidate

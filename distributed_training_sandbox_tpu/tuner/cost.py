@@ -5,10 +5,9 @@ Two ingredient streams, both already produced by the stack:
 
   * **bench priors** (``memory_plan.planner.load_bench_priors``): a
     measured matrix row with the same (remat, quant, state) knobs anchors
-    a candidate's TFLOPS directly; the calibrated multiplier model
-    (BENCH_r03–r05) covers the unmeasured rest of the space, scaled by
-    the best measured baseline row so anchored and unanchored scores are
-    the same unit.
+    a candidate's TFLOPS directly; the multiplier model covers the
+    unmeasured rest of the space, scaled by the best measured baseline
+    row so anchored and unanchored scores are the same unit.
   * **run-registry cost model** (``scripts/runs.py export-cost-model``):
     ledger-measured bus bandwidth per (collective kind, payload bucket,
     mesh axis), loaded through the registry's own schema-validated
@@ -81,15 +80,15 @@ class TunerCostModel:
     def from_artifacts(cls, *, cost_model_path: str | None = None,
                        prior_paths: list | None = None
                        ) -> "TunerCostModel":
-        """Load from the checked-in artifacts: ``BENCH_*.json`` bench
-        priors and (when present) the registry's ``cost_model.json``.
+        """Load from the files the caller names: bench-prior JSONs
+        (none named = no priors, the multiplier model alone) and, when
+        present, the registry's ``cost_model.json``.
         A cost model that exists but fails schema validation raises —
         drift must not silently degrade to compute-only ranking."""
         cm = None
         if cost_model_path and Path(cost_model_path).is_file():
             cm = _registry_mod().load_cost_model(str(cost_model_path))
-        priors = load_bench_priors(
-            [str(p) for p in prior_paths] if prior_paths else None)
+        priors = load_bench_priors(prior_paths)
         return cls(cost_model=cm, priors=priors,
                    prior_paths=prior_paths, cost_model_path=cost_model_path)
 
@@ -140,7 +139,7 @@ class TunerCostModel:
         semantics), else EVERY row at the minimal knob distance — the
         caller extrapolates from each and keeps the most pessimistic,
         so a measured contradiction (save_dots×int8 measured SLOWER
-        than the multipliers claim, BENCH_r03) overrides a sibling
+        than the multipliers claim) overrides a sibling
         anchor's optimistic extrapolation.  A pure multiplier model
         makes exactly that mistake: it ranks unmeasured crossings above
         the measured champion.  Returns ``(priors, knob_distance)``."""

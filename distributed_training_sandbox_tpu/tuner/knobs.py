@@ -1,5 +1,5 @@
-"""Declarative knob space: the axes ``bench.py:KNOB_MATRIX``
-hand-enumerates, as data.
+"""Declarative knob space: the axes a hand-written knob matrix
+enumerates, as data.
 
 A :class:`TunerCandidate` is one point — a superset of the memory
 planner's :class:`~..memory_plan.planner.Candidate` (which covers the
@@ -59,7 +59,7 @@ class TunerCandidate:
 
     # ------------------------------------------------------------ names
     def bench_name(self) -> str:
-        """The ``bench.py`` row name for this candidate, in the grammar
+        """The bench-prior row name for this candidate, in the grammar
         ``parse_bench_config_name`` reads back (explicit[_remat]
         [_int8_bwd|_fp8(_delayed|_pallas)][_s8][_b{N}x]).  Knobs the
         bench grammar has no token for

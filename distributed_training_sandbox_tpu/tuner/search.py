@@ -1,43 +1,19 @@
-"""Stage orchestration: enumerate → prune → rank → measure → plan.
+"""Stage orchestration: enumerate → prune → rank → plan.
 
-``tune()`` is the subsystem's one programmatic entry point; it never
-compiles anything outside stage 4, and stage 4 compiles at most
-``top_k`` candidates — the whole point (BENCH_r01–r05 burnt ~6 compiles
-on OOMs alone before the planner existed, and dozens measuring rows a
-cost model would have ranked out).
+``tune()`` is the subsystem's one programmatic entry point.  The
+throughput objective compiles nothing: it ranks, and the plan's chosen
+candidate is the predicted best.  The serving objective runs its
+``top_k`` pool-knob candidates through the engine.
 """
 
 from __future__ import annotations
 
-import sys
 import time
-from pathlib import Path
 
 from ..memory_plan.predictor import analytic_waterline
 from .cost import TunerCostModel, _planner_candidate
 from .knobs import KnobSpace, ServingKnobSpace
 from .plan import PLAN_SCHEMA
-
-_REPO = Path(__file__).resolve().parents[2]
-
-
-def _default_measure(model_name: str, seq: int, base_batch: int,
-                     ws: int, num_steps: int):
-    """bench.py's own ``measure()`` as the stage-4 harness — the same
-    timed loop the hand-written matrix rows go through, so an
-    ``autotuned`` number is comparable to every hand row by
-    construction."""
-    if str(_REPO) not in sys.path:
-        sys.path.insert(0, str(_REPO))
-    import bench
-
-    def fn(c):
-        return bench.measure(
-            model_name, seq, base_batch * c.batch_scale * ws,
-            num_steps=num_steps, cfg_overrides=c.cfg_overrides(),
-            step_kwargs=c.step_kwargs(),
-            mesh_shape=getattr(c, "mesh_shape", None))
-    return fn
 
 
 def _candidate_mesh_plan(c):
@@ -82,15 +58,16 @@ def prune_candidates(cands, cfg, *, base_batch: int, seq: int, ws: int,
 def tune(model_name: str, seq: int, base_batch: int, *,
          objective: str = "throughput", space=None,
          budget_gb: float | None = None, top_k: int = 5,
-         num_steps: int = 4, cost_model_path: str | None = None,
-         prior_paths: list | None = None, measure_fn=None,
+         cost_model_path: str | None = None,
+         prior_paths: list | None = None,
          cost: TunerCostModel | None = None, log=None) -> dict:
-    """Run all four stages and return the plan document (the caller
-    decides whether to ``save_plan`` it).  ``top_k=0`` stops after
-    ranking (no compiles) — the transfer-prediction mode where the
-    chosen candidate is the predicted argmax.  ``base_batch`` is the
-    per-device batch at scale 1; global batch for a candidate is
-    ``base_batch × batch_scale × ws``."""
+    """Run the stages and return the plan document (the caller decides
+    whether to ``save_plan`` it).  ``objective="throughput"`` enumerates,
+    prunes and ranks without a compile, and the chosen candidate is the
+    predicted argmax (``"measured": None``); ``top_k`` belongs to
+    ``objective="p99_latency"``, which measures that many pool-knob
+    candidates.  ``base_batch`` is the per-device batch at scale 1;
+    global batch for a candidate is ``base_batch × batch_scale × ws``."""
     import jax
     log = log or (lambda *a: None)
     if objective == "p99_latency":
@@ -131,35 +108,7 @@ def tune(model_name: str, seq: int, base_batch: int, *,
     log(f"[tune] stage 3: ranked {len(ranked)} "
         f"(top: {ranking_rows[0]['config'] if ranking_rows else '-'})")
 
-    # 4. measure top-k
-    measured, compiles = [], 0
-    if top_k > 0 and ranked:
-        fn = measure_fn or _default_measure(model_name, seq, base_batch,
-                                            ws, num_steps)
-        for c, pred in ranked[:top_k]:
-            t0 = time.perf_counter()
-            try:
-                row = fn(c)
-            except Exception as e:  # noqa: BLE001 - a row must not kill the plan
-                row = {"error": f"{type(e).__name__}: {str(e)[:200]}"}
-            compiles += 1
-            measured.append({"config": c.bench_name(),
-                             "knobs": c.to_dict(), "predicted": pred,
-                             "measure_s": round(
-                                 time.perf_counter() - t0, 2), **row})
-            log(f"[tune] stage 4: {c.bench_name()} -> "
-                f"{row.get('tflops_per_device', row.get('error'))}")
-
-    good = [m for m in measured if "error" not in m]
-    if good:
-        best = max(good, key=lambda m: m.get("tokens_per_sec") or 0.0)
-        chosen = {"config": best["config"], "knobs": best["knobs"],
-                  "predicted": best["predicted"],
-                  "measured": {k: best[k] for k in
-                               ("tokens_per_sec", "step_ms",
-                                "tflops_per_device")
-                               if k in best}}
-    elif ranking_rows:
+    if ranking_rows:
         top = ranking_rows[0]
         chosen = {"config": top["config"], "knobs": top["knobs"],
                   "predicted": {k: top[k] for k in top
@@ -183,8 +132,10 @@ def tune(model_name: str, seq: int, base_batch: int, *,
         "enumerated": len(cands),
         "pruned": pruned,
         "ranking": ranking_rows,
-        "measured": measured,
-        "compiles_spent": compiles,
+        # nothing is measured or compiled for this objective; the keys
+        # stay so that plans written before and after read alike
+        "measured": [],
+        "compiles_spent": 0,
         "chosen": chosen,
     }
 
@@ -210,7 +161,7 @@ def _serving_proxy(k: dict) -> float:
 
 def _measure_serving_knobs(knobs: dict, n_requests: int = 16) -> dict:
     """Closed seeded burst through the real ServingEngine — the p99
-    objective's stage-4 harness (mirrors ``bench.measure_serving``)."""
+    objective's measuring stage."""
     import numpy as np
     import jax
     from ..models import transformer as T

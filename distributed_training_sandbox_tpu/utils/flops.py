@@ -13,8 +13,8 @@ formula term-for-term.  Differences:
 
 Both conventions are self-consistent for A/B ratios; absolute TFLOPS printed
 by this repo are computed under THIS convention, including when converting
-the reference's published tok/s baselines for the ``vs_baseline`` ratio (see
-``bench.py``), so the ratio remains apples-to-apples.
+the reference's published tok/s baselines for a ``vs_baseline`` ratio, so
+the ratio remains apples-to-apples.
 """
 
 from __future__ import annotations

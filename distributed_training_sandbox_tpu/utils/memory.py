@@ -40,9 +40,9 @@ def parse_hbm_oom(msg: str) -> tuple[float, float] | None:
     """``(needed_gb, capacity_gb)`` from XLA's HBM verdict — the
     ``Used X.XXG of Y.YYG hbm`` clause its compile- and runtime-OOM
     messages both carry — or None when the text carries no such verdict.
-    The ONE place this regex lives: ``scripts/memory_waterline.py``,
-    ``bench.py``'s structured OOM rows and the memory planner's
-    compiler-OOM fallback all parse through here."""
+    The ONE place this regex lives: ``scripts/memory_waterline.py``
+    and the memory planner's compiler-OOM fallback both parse through
+    here."""
     import re
     m = re.search(r"Used ([\d.]+)G(?:iB)? of ([\d.]+)G(?:iB)? hbm", msg)
     if m:

@@ -232,8 +232,8 @@ def decode_table(rows: list[dict]) -> str:
 
 
 def moe_drop_note(dirname: str) -> str:
-    """Grouped-dispatch drop rates from the bench artifact (written by
-    ``moe_bench.measure_drop_rates`` next to the rows it describes)."""
+    """Grouped-dispatch drop rates from the bench artifact (written
+    next to the rows it describes)."""
     drops = []
     for f in sorted(glob.glob(f"{dirname}/*.json")):
         d = json.load(open(f))
@@ -559,20 +559,6 @@ def write_plots(prec: list[dict], longctx: list[dict], moe: list[dict],
             ax.annotate(f"{v / 1e3:.1f}k", (i, v), ha="center",
                         xytext=(0, 4), textcoords="offset points",
                         fontsize=8, color=_INK2)
-        # dense bf16 reference from the committed knob matrix (same model,
-        # seq and batch: the explicit_reshard_b2x row), never hardcoded
-        dense = None
-        try:
-            mtx = json.load(open("bench_matrix_tpu.json"))["matrix"]
-            dense = next(r["tokens_per_sec"] for r in mtx
-                         if r.get("config") == "explicit_reshard_b2x")
-        except (OSError, KeyError, StopIteration):
-            pass
-        if dense:
-            ax.axhline(dense, color=_INK2, linewidth=1.2,
-                       linestyle=(0, (4, 3)))
-            ax.annotate(f"dense bf16: {dense / 1e3:.1f}k", (-0.45, dense),
-                        ha="left", va="bottom", fontsize=8, color=_INK2)
         ax.set_xticks(range(len(labels)), labels, fontsize=8)
         ax.set_ylabel("tokens / s", color=_INK2, fontsize=9)
         ax.set_title("MoE throughput by dispatch — 3B-L8, 8 experts, "
@@ -605,6 +591,18 @@ def main(argv=None):
     doc = [
         "# Benchmark results",
         "",
+        "**Provenance (PR 28).** Everything below was taken before "
+        "this repo had a benchmark and a ledger, by a knob-matrix "
+        "script and one-off scripts on shapes of their own, with no "
+        "correctness check and no spread between runs; the "
+        "knob-matrix script, its record files, `scripts/long_context.py` "
+        "and `scripts/moe_bench.py` have left the tree, so several "
+        "tables can no longer be re-taken. Tables timed on the CPU "
+        "simulator are control-flow counts and say nothing of a "
+        "device. Read every rate, utilisation and speed-up here as "
+        "a place to look, not as this system's speed: that is in "
+        "`PERF.md` and `PERF_LEDGER.jsonl`.",
+        "",
         "Regenerated from committed JSON artifacts by "
         "`python scripts/analyze_results.py` — the twin of the reference's "
         "`fp8/visualize_code.ipynb` analysis pass.",
@@ -632,14 +630,14 @@ def main(argv=None):
         "## Pipeline schedules (GPipe vs 1F1B)",
         "",
         pp_table(pp),
-        "## Long-context single-chip sweep (`scripts/long_context.py`)",
+        "## Long-context single-chip sweep (`longcontext_results/`)",
         "",
         "The reference's longest trained sequence is 8192; these rows "
         "are one-chip training steps of the 3B-geometry flagship "
         "(splash attention + streamed-vocab loss + full remat).",
         "",
         longctx_table(longctx),
-        "## MoE transformer (`scripts/moe_bench.py`)",
+        "## MoE transformer (`moe_results/`)",
         "",
         "Switch-MoE flagship geometry (8 experts × 2752 ffn — the dense "
         "3B-L8 MLP split 4-ways active), FSDP train step.  Dispatch "

@@ -45,8 +45,8 @@ def analyze(step, *args) -> dict:
     """Compile-time memory plan; where the compiler validates HBM fit,
     an over-budget plan comes back as the compiler's own
     used-vs-capacity numbers instead (parsed by the shared
-    ``utils.memory.parse_hbm_oom`` — the same helper ``bench.py`` and
-    the memory planner's compiler-OOM fallback use)."""
+    ``utils.memory.parse_hbm_oom`` — the same helper the memory
+    planner's compiler-OOM fallback uses)."""
     from distributed_training_sandbox_tpu.utils.memory import parse_hbm_oom
     try:
         c = step.lower(*args).compile()
@@ -216,8 +216,7 @@ log-probs, grad-wrt-log-probs).
    if variants['streamed_save_dots'].get('oom') else
    'it plans ' + format(variants['streamed_save_dots']['temp_gb'], '.2f') + ' GB of temp'}
   — the FLOPs-vs-HBM middle point between full remat and no remat
-  (throughput for each policy is measured separately by `bench.py`;
-  see `bench_matrix_tpu.json`).
+  (this script measures no throughput).
 
 ## Reading guide vs the reference
 

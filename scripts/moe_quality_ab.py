@@ -40,7 +40,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 def _base_moe() -> dict:
-    # the ONE named MoE flagship geometry (shared with moe_bench/decode)
+    # the ONE named MoE flagship geometry
     from distributed_training_sandbox_tpu.models.transformer import (
         SMOLLM3_3B_L8_MOE as M)
     return {"n_experts": M.n_experts, "moe_ffn": M.moe_ffn,

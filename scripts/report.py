@@ -9,13 +9,13 @@ tokens/s, TFLOPS/device, the memory column — compiler-reported or
 comm %, per-step collective counts), and —
 with ``--baseline`` — computes regression deltas against a prior run
 dir, a runs root, a ``summary.json``, or a bench-style JSON
-(``bench_matrix_tpu.json`` / ``BENCH_*.json``), exiting nonzero when
+(``{"matrix": [...]}`` rows or a row list), exiting nonzero when
 any comparable metric regresses beyond ``--tolerance``.
 
 Usage:
   python scripts/report.py [runs_root ...]           # default ./runs
   python scripts/report.py runs --baseline old_runs --tolerance 0.15
-  python scripts/report.py runs --baseline bench_matrix_tpu.json
+  python scripts/report.py runs --baseline matrix_rows.json
   python scripts/report.py runs --steps               # per-step tail
   python scripts/report.py runs --json                # machine-readable
   python scripts/report.py runs --baseline base_runs \

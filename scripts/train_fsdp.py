@@ -126,8 +126,7 @@ def _leg(args, rest, cfg, ctx):
                              "(remat/accum/quant/offload); drop "
                              "--variant auto")
         mplan = MP.plan(mcfg, batch=cfg.batch_size, seq=cfg.sequence_length,
-                        ws=ws, hbm_budget_gb=budget,
-                        priors=MP.load_bench_priors())
+                        ws=ws, hbm_budget_gb=budget)
         chosen = mplan.best.candidate
         print(f"[fsdp] memory plan: {mplan.summary()}")
         mcfg = chosen.apply_to(mcfg)

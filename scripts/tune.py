@@ -1,26 +1,27 @@
-"""Closed-loop autotuner entry point (ROADMAP item 5).
+"""Autotuner entry point.
 
     python scripts/tune.py --model TINY_LM --seq 256 --batch 1 \
-        --out plans/plan_TINY_LM_cpu.json
-    python scripts/tune.py --check plans/plan_TINY_LM_cpu.json
+        --out plan.json
+    python scripts/tune.py --check plan.json
     dts-launch tune --model TINY_LM ...
 
-Four stages (``distributed_training_sandbox_tpu/tuner``): enumerate the
-knob space, prune over-HBM candidates analytically (predicted GB per
-rejection, zero compiles), rank survivors via bench priors + the
-run-registry ledger cost model, measure only the top-k, and emit a
-versioned ``plan.json`` the drivers replay via ``--plan``.
+Stages (``distributed_training_sandbox_tpu/tuner``): enumerate the knob
+space, prune over-HBM candidates analytically (predicted GB per
+rejection), rank survivors via the bench priors ``--priors`` names + the
+run-registry ledger cost model, and emit a versioned ``plan.json`` the
+drivers replay via ``--plan``.  The throughput objective compiles and
+measures nothing; ``--objective p99_latency`` measures its ``--top-k``
+pool-knob candidates through the engine.
 
-``--check PLAN`` is the CI staleness gate (wired next to
-``lint_sharding.py``): exit 0 when the committed plan's knob-space and
-cost-model provenance hashes still match what today's code + artifacts
-would re-derive, 1 when stale, 2 when unreadable.
+``--check PLAN`` is the staleness gate: exit 0 when the plan's
+knob-space and cost-model provenance hashes still match what today's
+code + the files the plan names would re-derive, 1 when stale, 2 when
+unreadable.
 """
 
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import sys
 from pathlib import Path
@@ -52,8 +53,7 @@ def _check(path: str) -> int:
 
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
-        description="closed-loop autotuner: enumerate / prune / rank / "
-                    "measure -> plan.json")
+        description="autotuner: enumerate / prune / rank -> plan.json")
     p.add_argument("--model", type=str, default="TINY_LM",
                    help="TransformerConfig name (default TINY_LM)")
     p.add_argument("--seq", type=int, default=256)
@@ -66,20 +66,19 @@ def build_parser() -> argparse.ArgumentParser:
                    help="HBM budget for analytic pruning (default: the "
                         "device's own capacity when exposed)")
     p.add_argument("--top-k", type=int, default=5,
-                   help="candidates to compile+measure (0 = rank only, "
-                        "no compiles)")
-    p.add_argument("--num-steps", type=int, default=4,
-                   help="timed steps per measured candidate")
+                   help="pool-knob candidates --objective p99_latency "
+                        "measures (the throughput objective ranks and "
+                        "measures none)")
     p.add_argument("--cost-model", type=str, default="cost_model.json",
                    help="run-registry export (scripts/runs.py "
                         "export-cost-model); missing file = "
                         "compute-only ranking")
     p.add_argument("--priors", type=str, nargs="*", default=None,
-                   help="bench prior JSONs (default: BENCH_*.json + "
-                        "bench_matrix_tpu.json in the cwd)")
+                   help="bench prior JSONs (default: none, the "
+                        "relative-speed multipliers alone)")
     p.add_argument("--out", type=str, default="plan.json")
     p.add_argument("--check", type=str, default=None, metavar="PLAN",
-                   help="staleness-gate mode: validate a committed plan "
+                   help="staleness-gate mode: validate a plan "
                         "against current hashes and exit")
     p.add_argument("--cpu-devices", type=int, default=None,
                    help="force N simulated CPU devices before the "
@@ -96,19 +95,13 @@ def main(argv=None) -> int:
         use_cpu_devices(args.cpu_devices)
     from distributed_training_sandbox_tpu.tuner import save_plan, tune
 
-    prior_paths = args.priors
-    if prior_paths is None:
-        prior_paths = sorted(glob.glob("BENCH_*.json")) \
-            + sorted(glob.glob("bench_matrix_tpu.json"))
-
     def log(msg):
         print(msg, file=sys.stderr, flush=True)
 
     doc = tune(args.model, args.seq, args.batch,
                objective=args.objective, budget_gb=args.budget_gb,
-               top_k=args.top_k, num_steps=args.num_steps,
-               cost_model_path=args.cost_model,
-               prior_paths=prior_paths, log=log)
+               top_k=args.top_k, cost_model_path=args.cost_model,
+               prior_paths=args.priors, log=log)
     save_plan(doc, args.out)
     chosen = doc.get("chosen") or {}
     print(json.dumps({

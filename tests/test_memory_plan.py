@@ -3,7 +3,7 @@ prediction (compile-based == ``memory_analysis()``, compiler-OOM
 fallback, analytic ordering across remat policies), auto-fit under a
 synthetic tight budget, contracted host offload (bitwise parity on the
 CPU fallback + declared-count lint), the shared OOM parser, and the
-bench/priors plumbing."""
+priors plumbing."""
 
 import dataclasses
 import json
@@ -18,7 +18,7 @@ from distributed_training_sandbox_tpu import memory_plan as MP
 from distributed_training_sandbox_tpu.models import transformer as T
 from distributed_training_sandbox_tpu.parallel import fsdp
 from distributed_training_sandbox_tpu.utils.memory import (
-    GB, parse_hbm_oom)
+    GB, classify_failure, parse_hbm_oom)
 
 pytestmark = pytest.mark.memplan
 
@@ -39,17 +39,14 @@ def test_parse_hbm_oom_none_on_other_errors():
     assert parse_hbm_oom("") is None
 
 
-def test_bench_failure_row_is_structured():
-    import bench
-    row = bench._failure_row("save_dots_x", RuntimeError(OOM_MSG),
-                             predicted_gb=17.9)
-    assert row["failure_kind"] == "oom"
-    assert row["needed_gb"] == 18.41
-    assert row["capacity_gb"] == 15.75
-    assert row["predicted_gb"] == 17.9
-    plain = bench._failure_row("save_dots_x", ValueError("nope"))
-    assert plain["failure_kind"] == "error"
-    assert "needed_gb" not in plain
+def test_classify_failure_separates_oom_from_error():
+    """What a sweep script's failure row is built from: the OOM's own
+    clause, which ``parse_hbm_oom`` reads back, or the error's name."""
+    kind, msg = classify_failure(RuntimeError(OOM_MSG))
+    assert kind == "oom"
+    assert parse_hbm_oom(msg) == (18.41, 15.75)
+    assert classify_failure(ValueError("nope")) == ("error",
+                                                    "ValueError: nope")
 
 
 # ------------------------------------------------------------- prediction
@@ -123,7 +120,7 @@ def test_analytic_vs_compiled_same_ballpark(fsdp_setup, mesh8):
 
 
 def test_analytic_tracks_bench_r05_oom_verdicts():
-    """Re-read the BENCH_r05 OOM wall through the predictor: each
+    """Re-read a v5e's recorded OOM wall through the predictor: each
     compiler-reported used-HBM verdict is matched within the calibrated
     band (±20%; the measured mean is ~6%, RESULTS.md)."""
     rows = [
@@ -235,9 +232,10 @@ def test_parse_bench_config_name():
         "explicit_reshard_syncstep") is None
 
 
-def test_bench_priors_anchor_modeled_speed(tmp_path):
+def test_bench_priors_anchor_modeled_speed(tmp_path, monkeypatch):
     """A measured bench row with matching knobs anchors the score
-    directly (its TFLOPS), beating the multiplier model's guess."""
+    directly (its TFLOPS), beating the multiplier model's guess; a file
+    nobody names is not read, wherever the process stands."""
     rows = {"matrix": [
         {"config": "explicit_int8_bwd_b4x", "tflops_per_device": 125.7,
          "step_ms": 3100.0, "batch": 8},
@@ -246,6 +244,8 @@ def test_bench_priors_anchor_modeled_speed(tmp_path):
     ]}
     p = tmp_path / "BENCH_x.json"
     p.write_text(json.dumps(rows))
+    monkeypatch.chdir(tmp_path)
+    assert MP.load_bench_priors() == []
     priors = MP.load_bench_priors([str(p)])
     assert len(priors) == 1
     assert priors[0]["knobs"]["matmul_precision"] == "int8_bwd"

@@ -1,12 +1,14 @@
-"""Closed-loop autotuner suite: knob-space determinism, analytic
-pruning vs the recorded BENCH_r05 OOM wall, cost-model champion
-rediscovery on the checked-in priors, bitwise ``--plan`` replay through
-the zero driver, and the bench matrix's ``autotuned`` row.
+"""Autotuner suite: knob-space determinism, analytic pruning vs a
+recorded OOM wall, cost-model champion rediscovery on the fixture
+priors, priors that come only from the paths a caller names, and
+bitwise ``--plan`` replay through the zero driver.
 
 Everything runs on the 8-device simulated CPU mesh; the only compiles
 are the two tiny zero-driver replays in the bitwise test."""
 
 import glob
+import inspect
+import json
 
 import pytest
 
@@ -20,9 +22,12 @@ from conftest import REPO
 
 pytestmark = pytest.mark.tuner
 
-PRIORS = sorted(glob.glob(str(REPO / "BENCH_*.json")))
+# knob-matrix rows taken on a v5e before the ledger existed: fixtures of
+# the cost model's tests, not a record of this system's speed
+PRIORS = sorted(glob.glob(
+    str(REPO / "tests" / "fixtures" / "bench_priors" / "BENCH_*.json")))
 
-# the v5e single-chip HBM capacity every BENCH round ran against
+# the v5e single-chip HBM capacity those rows ran against
 CAPACITY_GB = 15.75
 
 
@@ -56,7 +61,7 @@ def test_knob_space_respects_feasibility_rules():
 
 # ------------------------------------------------------------ stage 2
 
-# the BENCH_r05 OOM wall: (remat, matmul, state, global batch at ws=1,
+# the fixtures' OOM wall: (remat, matmul, state, global batch at ws=1,
 # compiler-reported needed GB) — every row actually OOMed a 15.75 GB chip
 OOM_WALL = [
     ("save_dots_q8", "int8_bwd", "full", 4, 18.41),
@@ -67,7 +72,7 @@ OOM_WALL = [
 
 
 def test_prune_agrees_with_recorded_oom_verdicts():
-    """Stage-2 analytic pruning rejects every candidate the BENCH_r05
+    """Stage-2 analytic pruning rejects every candidate the recorded
     round actually OOMed on, pre-compile, and reports each rejection
     with its predicted GB."""
     cfg = T.SMOLLM3_3B_L8
@@ -100,8 +105,8 @@ def test_prune_without_capacity_keeps_everything():
 def test_champion_rediscovered_in_top5_on_checked_in_priors():
     """The acceptance rediscovery: enumerate the full space at the
     flagship's operating point, prune against the real chip capacity,
-    rank on the checked-in BENCH priors — the hand-found champion
-    (explicit_int8_bwd_s8_b4x, BENCH_r05) must sit in the predicted
+    rank on the fixture priors — the hand-found champion
+    (explicit_int8_bwd_s8_b4x) must sit in the predicted
     top-5, i.e. the tuner would have measured it."""
     cfg = T.SMOLLM3_3B_L8
     cost = TunerCostModel.from_artifacts(prior_paths=PRIORS)
@@ -125,7 +130,75 @@ def test_cost_model_hash_tracks_priors():
     assert c.hash() != a.hash()
 
 
+@pytest.fixture
+def cwd_with_prior(tmp_path, monkeypatch):
+    """A working directory that holds a prior file nobody named."""
+    (tmp_path / "BENCH_x.json").write_text(json.dumps({"matrix": [
+        {"config": "explicit_save_dots", "tflops_per_device": 999.0,
+         "step_ms": 100.0, "batch": 2}]}))
+    monkeypatch.chdir(tmp_path)
+    return tmp_path
+
+
+def test_from_artifacts_reads_no_prior_it_was_not_given(cwd_with_prior):
+    """Priors are the paths a caller names: a cost model built with none
+    holds none, whatever the working directory holds, and ranks and
+    hashes as one built from an empty list."""
+    cfg = T.SMOLLM3_3B_L8
+    unnamed = TunerCostModel.from_artifacts()
+    empty = TunerCostModel.from_artifacts(prior_paths=[])
+    assert unnamed.priors == [] and unnamed.prior_paths == []
+    assert unnamed.hash() == empty.hash()
+    cands = KnobSpace().enumerate(2)[:40]
+
+    def order(cost):
+        return [pred["config"] for _, pred in cost.rank(
+            cands, cfg, seq=8192, base_batch=2, ws=1)]
+    assert order(unnamed) == order(empty)
+    named = TunerCostModel.from_artifacts(
+        prior_paths=[str(cwd_with_prior / "BENCH_x.json")])
+    assert named.priors and named.hash() != empty.hash()
+
+
+def test_tune_script_ignores_working_directory(cwd_with_prior,
+                                               tmp_path_factory):
+    """``scripts/tune.py`` started where a prior file lies writes the
+    plan it writes from an empty directory: same ranking, same
+    provenance hashes."""
+    from scripts.tune import main as tune_main
+
+    def plan_from(cwd):
+        out = cwd / "plan.json"
+        assert tune_main(["--model", "TINY_LM", "--cpu-devices", "8",
+                          "--out", str(out)]) == 0
+        return load_plan(str(out))
+    here = plan_from(cwd_with_prior)
+    elsewhere = plan_from(tmp_path_factory.mktemp("empty"))
+    assert ([r["config"] for r in here["ranking"]]
+            == [r["config"] for r in elsewhere["ranking"]])
+    for key in ("cost_model_hash", "priors_hash", "knob_space_hash",
+                "provenance"):
+        assert here[key] == elsewhere[key]
+    assert here["provenance"]["prior_paths"] == []
+
+
 # ------------------------------------------------------- plan + replay
+
+
+def test_throughput_objective_ranks_and_measures_nothing():
+    """``tune`` with ``top_k`` left alone compiles and measures nothing:
+    the chosen candidate is the predicted best, and the plan keeps the
+    keys a plan written with a measuring stage had."""
+    params = inspect.signature(tune).parameters
+    assert "measure_fn" not in params and "num_steps" not in params
+    space = KnobSpace(batch_scale=(2,), accum_steps=(1,),
+                      remat_policy=("full",), matmul_precision=("bf16",),
+                      state_precision=("full",), offload=("none",))
+    doc = tune("TINY_LM", 32, 2, space=space)
+    assert doc["compiles_spent"] == 0 and doc["measured"] == []
+    assert doc["chosen"]["measured"] is None
+    assert doc["chosen"]["config"] == doc["ranking"][0]["config"]
+
 
 def test_plan_replay_is_bitwise_deterministic(tmp_path):
     """A plan chosen by the tuner replays exactly: two zero-driver runs
@@ -167,49 +240,8 @@ def test_check_plan_flags_drift():
 
 
 def test_load_plan_rejects_wrong_schema(tmp_path):
-    import json
     p = tmp_path / "bad.json"
     p.write_text(json.dumps({"schema_version": 99,
                              "chosen": {"knobs": {}}}))
     with pytest.raises(ValueError, match="schema_version"):
         load_plan(str(p))
-
-
-# ------------------------------------------------------ bench closure
-
-def test_autotuned_row_ties_best_covered_hand_row():
-    """The matrix's ``autotuned`` row reuses the run's own measured
-    numbers, so it ties the best hand-written explicit row by
-    construction — and records whether the pre-measurement ranking
-    already had the winner on top."""
-    import bench
-    rows = [
-        {"config": "explicit", "tokens_per_sec": 1000.0,
-         "tflops_per_device": 1.0, "step_ms": 10.0},
-        {"config": "explicit_int8_bwd", "tokens_per_sec": 1180.0,
-         "tflops_per_device": 1.18, "step_ms": 9.0},
-        {"config": "explicit_save_dots", "tokens_per_sec": 900.0,
-         "tflops_per_device": 0.9, "step_ms": 11.0},
-        # outside the explicit grammar — not a tuner-coverable row
-        {"config": "ring", "tokens_per_sec": 2000.0},
-        # errored rows never win
-        {"config": "explicit_b2x", "error": "boom"},
-    ]
-    auto = bench._autotuned_row("TINY_LM", 32, 8, rows)
-    assert auto["config"] == "autotuned"
-    assert auto["chosen_from"] == "explicit_int8_bwd"
-    covered = set(auto["tuner"]["covered"])
-    assert covered == {"explicit", "explicit_int8_bwd",
-                       "explicit_save_dots"}
-    best = max(r["tokens_per_sec"] for r in rows
-               if r["config"] in covered)
-    assert auto["tokens_per_sec"] >= best
-    assert isinstance(auto["tuner"]["predicted_hit"], bool)
-    assert auto["tuner"]["knob_space_hash"] == KnobSpace().space_hash()
-
-
-def test_autotuned_row_none_when_nothing_covered():
-    import bench
-    assert bench._autotuned_row(
-        "TINY_LM", 32, 8, [{"config": "ring", "tokens_per_sec": 1.0}]) \
-        is None
