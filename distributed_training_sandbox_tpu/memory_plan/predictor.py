@@ -219,6 +219,11 @@ def analytic_waterline(cfg, *, batch: int, seq: int, ws: int = 1,
     lp_mm = precision.startswith("int8") or precision.startswith("fp8")
     if lp_mm and policy == "save_dots":
         saved *= 1.5
+    if remat_on and getattr(cfg, "attention_impl", "xla") == "flash":
+        # the splash kernel's own residuals (output + fp32 log-sum-exp)
+        # stay on the device under every policy, so that the backward
+        # does not re-run the kernel (transformer.resolve_remat_policy)
+        saved += L * micro * seq * nq * (hd * itemsize + 4)
 
     # one layer's transient working set (freed before the loss phase);
     # low-precision matmuls add the live microbatch's quantize buffers

@@ -57,9 +57,14 @@ class TransformerConfig:
     nope_interval: int = 4
     dtype: Any = jnp.bfloat16
     remat: bool = True
-    # "full" recomputes the whole layer in backward; "save_attn" keeps each
-    # layer's attention output resident (+S·nq·hd bf16 per layer) so the
-    # fused-attention forward doesn't run twice; "save_dots" keeps every
+    # "full" recomputes the whole layer in backward, except that under
+    # attention_impl="flash" the splash kernel's own residuals stay
+    # resident under EVERY policy (its output, S·nq·hd in the model dtype,
+    # and its log-sum-exp, S·nq fp32, per layer: FLASH_RESIDUALS), so the
+    # recomputation re-runs norm, QKV and RoPE but not the O(S²) kernel;
+    # "save_attn" also keeps each layer's tagged attention output
+    # (+S·nq·hd per layer, the tensor after the kernel's transposes);
+    # "save_dots" keeps every
     # matmul output resident — the backward recomputes only cheap
     # elementwise ops, trading ~all of remat's extra forward FLOPs for
     # O(layers · S · (heads+ffn)) activation memory.  The
@@ -434,6 +439,13 @@ def _attention_xla(q, k, v, scale: float) -> jax.Array:
     return jnp.einsum("bnqk,bknh->bqnh", probs, v)
 
 
+# Checkpoint name the splash kernel tags its forward residuals with (its
+# output and its log-sum-exp); resolve_remat_policy keeps it under every
+# policy, so a rematerialised layer's backward finds them and the
+# kernel's forward runs once a step.
+FLASH_RESIDUALS = "splash_residuals"
+
+
 def _attention_flash(q, k, v, scale: float) -> jax.Array:
     """Fused Pallas TPU attention (splash kernel): never materializes the
     S×S score matrix in HBM, handles GQA natively (no kv repeat), causal
@@ -459,7 +471,8 @@ def _attention_flash(q, k, v, scale: float) -> jax.Array:
             block_q=bq, block_kv=bkv, block_kv_compute=bkv_c,
             block_q_dkv=bq, block_kv_dkv=bkv,
             block_kv_dkv_compute=bkv_c,
-            block_q_dq=bq, block_kv_dq=bkv))
+            block_q_dq=bq, block_kv_dq=bkv),
+        residual_checkpoint_name=FLASH_RESIDUALS)
 
     def one(q1, k1, v1):  # (S, n, hd) -> kernel layout (n, S, hd)
         out = kernel(q1.swapaxes(0, 1) * scale, k1.swapaxes(0, 1),
@@ -628,32 +641,39 @@ def resolve_remat_policy(cfg: TransformerConfig):
     every scaffold that remats the layer scan — hidden_states and
     parallel/pipeline's stage bodies).
 
+    Under ``attention_impl="flash"`` every policy also keeps the splash
+    kernel's forward residuals (``FLASH_RESIDUALS``): re-running the
+    kernel costs O(S²), what it saves is O(S) and no larger than the
+    boundary activation the scan keeps anyway.
+
     With ``cfg.offload_activations`` (and a backend that has a
     pinned_host space) the named-save policies become
     ``save_and_offload_only_these_names``: the same tensors survive the
     backward, but parked in host DRAM instead of HBM — the
-    remat-activation leg of the memory planner's host offload."""
+    remat-activation leg of the memory planner's host offload (the
+    kernel's residuals stay on the device)."""
+    policies = jax.checkpoint_policies
+    kept = [FLASH_RESIDUALS] if cfg.attention_impl == "flash" else []
     if cfg.offload_activations:
         from ..memory_plan.offload import (
             OFFLOADABLE_REMAT_NAMES, supports_host_offload)
         names = OFFLOADABLE_REMAT_NAMES[cfg.remat_policy]
         if supports_host_offload():
-            return jax.checkpoint_policies.save_and_offload_only_these_names(
-                names_which_can_be_saved=[],
+            return policies.save_and_offload_only_these_names(
+                names_which_can_be_saved=kept,
                 names_which_can_be_offloaded=list(names),
                 offload_src="device", offload_dst="pinned_host")
         # CPU sim: no host space distinct from device — keep the plain
         # save policy (bitwise-identical math, zero transfers declared)
-    return {
-        "save_attn":
-            jax.checkpoint_policies.save_only_these_names("attn_out"),
-        "save_dots":
-            jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-        # the saved tensors are the int8 pairs _dense's round-trip tagged
-        "save_dots_q8":
-            jax.checkpoint_policies.save_only_these_names("dot_q8"),
-        "full": None,
-    }[cfg.remat_policy]
+    if cfg.remat_policy == "save_dots":
+        dots = policies.dots_with_no_batch_dims_saveable
+        return policies.save_from_both_policies(
+            dots, policies.save_only_these_names(*kept)) if kept else dots
+    # the tensors a policy saves by name; save_dots_q8's are the int8
+    # pairs _dense's round-trip tagged
+    names = {"save_attn": ["attn_out"], "save_dots_q8": ["dot_q8"],
+             "full": []}[cfg.remat_policy] + kept
+    return policies.save_only_these_names(*names) if names else None
 
 
 def _rope_flags(cfg: TransformerConfig) -> jax.Array:

@@ -119,6 +119,22 @@ def test_engine_programs_keep_no_name_of_their_own():
         assert "unknown" in _lower_engine(which).as_text().split("\n")[0]
 
 
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_engine_programs_never_reach_the_remat_path(which, monkeypatch):
+    """Neither engine program calls the splash attention, the remat
+    policy mapping or ``jax.checkpoint`` (each lowering is a fresh
+    engine's fresh trace): a change to those cannot move its StableHLO."""
+    before = _lower_engine(which).as_text()
+
+    def boom(*a, **kw):
+        raise AssertionError("an engine program reached the remat path")
+
+    monkeypatch.setattr(T, "_attention_flash", boom)
+    monkeypatch.setattr(T, "resolve_remat_policy", boom)
+    monkeypatch.setattr(jax, "checkpoint", boom)
+    assert _lower_engine(which).as_text() == before
+
+
 # ------------------------------------------------------------- host spans
 
 def test_maybe_span_without_a_stream_annotates_and_writes_nothing(
