@@ -176,10 +176,28 @@ class TransformerConfig:
     norm_topk_prob: bool = False
     routed_scaling_factor: float = 1.0
     sandwich_norm: bool = False
+    # The gated delta-rule hybrid block (``models/gdn_hybrid.py``; the
+    # ``linear_*`` names as in the published configs of that family).
+    # ``linear_key_head_dim`` > 0 selects it: layer i (0-based) is a
+    # full-attention layer where (i + 1) % ``full_attention_interval`` == 0
+    # (the published ``layer_types`` as a period) and a linear-attention
+    # layer otherwise, whose per-request state is one float32
+    # (heads, key dim, value dim) matrix and a conv tail, whatever the
+    # request's length.  Serving only and the cache-less ``forward``.
+    full_attention_interval: int = 0
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 0
+    linear_allow_neg_eigval: bool = False
 
     def __post_init__(self):
         if self.kv_lora_rank:
             from .mla_moe import check_config
+            check_config(self)
+        if self.linear_key_head_dim:
+            from .gdn_hybrid import check_config
             check_config(self)
         # Covers every construction path incl. dataclasses.replace: a
         # sequence-sharded config with a local-chunk attention impl would
@@ -224,10 +242,27 @@ class TransformerConfig:
         instead of the dense GQA one."""
         return self.kv_lora_rank > 0
 
-    def param_count(self) -> int:
+    @property
+    def gdn_hybrid(self) -> bool:
+        """The gated delta-rule hybrid block (``models/gdn_hybrid.py``)."""
+        return self.linear_key_head_dim > 0
+
+    @property
+    def block_module(self):
+        """The module that holds a block other than the dense GQA one
+        (``init_params``, ``param_count``, ``hidden_states``, ``refuse``);
+        None for the dense block."""
         if self.mla_moe:
-            from .mla_moe import param_count
-            return param_count(self)
+            from . import mla_moe
+            return mla_moe
+        if self.gdn_hybrid:
+            from . import gdn_hybrid
+            return gdn_hybrid
+        return None
+
+    def param_count(self) -> int:
+        if self.block_module is not None:
+            return self.block_module.param_count(self)
         h, hd = self.hidden_size, self.resolved_head_dim
         attn = h * hd * (self.num_attention_heads * 2
                          + self.num_key_value_heads * 2)
@@ -330,10 +365,11 @@ def require_dense_block(cfg, what: str) -> None:
     """Every path that is written for the dense GQA block alone (the
     training factories of ``parallel/``, the one-shot decoder of
     ``models/generate.py``) names itself here and refuses the latent
-    block, rather than run it as dense math or fail on a missing key."""
-    if getattr(cfg, "mla_moe", False):
-        from .mla_moe import refuse
-        refuse(cfg, what)
+    block and the gated delta-rule hybrid, rather than run them as dense
+    math or fail on a missing key."""
+    block = getattr(cfg, "block_module", None)
+    if block is not None:
+        block.refuse(cfg, what)
 
 
 # ------------------------------------------------------------------- init
@@ -343,9 +379,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> dict:
     (``fsdp/train_fsdp.py:61-64``), so neither does the default path here.
     Truncated-normal 0.02 (HF default), out-projections scaled by
     1/sqrt(2·layers) for depth-stable residuals."""
-    if cfg.mla_moe:
-        from .mla_moe import init_params as init_block
-        return init_block(key, cfg)
+    if cfg.block_module is not None:
+        return cfg.block_module.init_params(key, cfg)
     h = cfg.hidden_size
     hd = cfg.resolved_head_dim
     nq, nkv = cfg.num_attention_heads, cfg.num_key_value_heads
@@ -715,12 +750,12 @@ def hidden_states(params: dict, input_ids: jax.Array,
     """Trunk only: (B, S) ids → final-norm hidden states (B, S, H).
     ``return_aux=True`` additionally returns the per-layer auxiliary
     losses summed (the MoE load-balance term; 0 for dense layers)."""
-    if cfg.mla_moe:
-        from . import mla_moe
+    block = cfg.block_module
+    if block is not None:
         if layer_hook is not None or layer_body is not None:
-            mla_moe.refuse(cfg, "a layer hook or a substituted layer body "
-                           "(FSDP, ZeRO-3, tensor parallelism)")
-        x = mla_moe.hidden_states(params, input_ids, cfg)
+            block.refuse(cfg, "a layer hook or a substituted layer body "
+                         "(FSDP, ZeRO-3, tensor parallelism)")
+        x = block.hidden_states(params, input_ids, cfg)
         return (x, jnp.zeros((), jnp.float32)) if return_aux else x
     B, S = input_ids.shape
     apply_layer = layer_body or _layer_body

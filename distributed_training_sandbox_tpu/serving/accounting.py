@@ -43,20 +43,22 @@ def weight_read_bytes(cfg, params, wb: int) -> int:
 
 def page_bytes(cfg, page_size: int, *, kv_quant: bool = False,
                tp: int = 1) -> int:
-    """Bytes ONE page occupies across every layer's pool(s): page_size ×
-    layers × the pool's own row bytes (``kv_pool.token_row_bytes``: for
-    the dense block (n_kv/tp local heads) × hd × 2 × itemsize plus the f32
-    per-row scales of an int8 pool; for the latent block its one padded
-    row).  This is the unit the capacity planner divides the budget by."""
-    from .kv_pool import token_row_bytes
-    return page_size * cfg.num_hidden_layers \
+    """Bytes ONE page occupies across every PAGED layer's pool(s):
+    page_size × ``kv_pool.paged_layers`` (all of them, but for the gated
+    delta-rule hybrid's linear layers) × the pool's own row bytes
+    (``kv_pool.token_row_bytes``: for the dense block (n_kv/tp local
+    heads) × hd × 2 × itemsize plus the f32 per-row scales of an int8
+    pool; for the latent block its one padded row).  This is the unit the
+    capacity planner divides the budget by."""
+    from .kv_pool import paged_layers, token_row_bytes
+    return page_size * paged_layers(cfg) \
         * token_row_bytes(cfg, kv_quant=kv_quant, tp=tp)
 
 
 def serve_waterline_gb(cfg, n_pages: int, page_size: int, *,
                        weight_bytes: int = 0, kv_quant: bool = False,
                        tp: int = 1, draft_weight_bytes: int = 0,
-                       draft_cfg=None) -> float:
+                       draft_cfg=None, max_batch: int = 0) -> float:
     """Static serving HBM waterline: resident weights + the paged KV
     pool.  Decode-step activations are a few (B, 1, H) rows — noise next
     to these two, so they are the whole ledger (the serving counterpart
@@ -68,8 +70,13 @@ def serve_waterline_gb(cfg, n_pages: int, page_size: int, *,
     draft cfg's shallower layer stack), so its bytes scale with the same
     page count.  Prefix sharing adds nothing here: aliased pages are the
     same physical pages, refcounts are host-side metadata — the waterline
-    is a function of pool CAPACITY, not of how requests share it."""
-    pool = n_pages * page_bytes(cfg, page_size, kv_quant=kv_quant, tp=tp)
+    is a function of pool CAPACITY, not of how requests share it.
+
+    A block with state slots (``kv_pool.slot_state_bytes``) adds
+    ``max_batch`` of them, whatever the pages hold."""
+    from .kv_pool import slot_state_bytes
+    pool = n_pages * page_bytes(cfg, page_size, kv_quant=kv_quant, tp=tp) \
+        + max_batch * slot_state_bytes(cfg)
     if draft_cfg is not None:
         pool += n_pages * page_bytes(draft_cfg, page_size,
                                      kv_quant=kv_quant, tp=tp)
@@ -81,7 +88,7 @@ def pool_capacity_pages(cfg, page_size: int, *, budget_gb: float,
                         tp: int = 1,
                         headroom_fraction: float = 0.10,
                         draft_weight_bytes: int = 0,
-                        draft_cfg=None) -> int:
+                        draft_cfg=None, max_batch: int = 0) -> int:
     """Pages that fit ``budget_gb`` once the weights are resident, with
     ``headroom_fraction`` of the budget held back for the decode step's
     working set and allocator slack — the pool-sizing inverse of
@@ -92,9 +99,12 @@ def pool_capacity_pages(cfg, page_size: int, *, budget_gb: float,
     weights come off the top and each page's marginal cost is the
     target page PLUS its draft-pool twin, keeping the inverse exact:
     ``serve_waterline_gb(cfg, N, p, ..., draft_cfg=d)`` at the returned
-    N stays within budget."""
+    N stays within budget.  ``max_batch`` state slots, where the block
+    has them, come off the top like the weights."""
+    from .kv_pool import slot_state_bytes
     usable = budget_gb * GB * (1.0 - headroom_fraction) \
-        - weight_bytes - draft_weight_bytes
+        - weight_bytes - draft_weight_bytes \
+        - max_batch * slot_state_bytes(cfg)
     if usable <= 0:
         return 0
     per_page = page_bytes(cfg, page_size, kv_quant=kv_quant, tp=tp)

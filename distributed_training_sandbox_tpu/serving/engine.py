@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models import gdn_hybrid as G
 from ..models import mla_moe as M
 from ..models import transformer as T
 from ..models.generate import _decode_cfg, _quant_kv
@@ -97,7 +98,49 @@ def _paged_layer_body(x, layer, *, cfg, cos, sin, use_rope, pk, pv,
                       pk_s, pv_s, pages, apos, valid, tp_axis=None,
                       paged_kernel=False):
     """One decoder layer against the PAGED pool — the numerics of
-    ``generate._cached_layer_body`` with scatter/gather storage:
+    ``generate._cached_layer_body`` with scatter/gather storage
+    (:func:`_paged_attend` holds the storage and the attention).
+
+    x (B, S, H); pages (B, P) int32; apos (B, S) int32 absolute
+    positions of x's rows; valid (B, S) bool."""
+    B, S, H = x.shape
+    hd = cfg.resolved_head_dim
+    tp = C.axis_size(tp_axis) if tp_axis else 1
+    nq = cfg.num_attention_heads // tp
+    nkv = cfg.num_key_value_heads // tp
+    dense = T._dense(cfg)
+
+    with scope("attn_qkv"):
+        r = T.rms_norm(x, layer["ln1"], cfg.rms_norm_eps)
+        q = dense(r, layer["wq"]).reshape(B, S, nq, hd)
+        k = dense(r, layer["wk"]).reshape(B, S, nkv, hd)
+        v = dense(r, layer["wv"]).reshape(B, S, nkv, hd)
+        q = jnp.where(use_rope, _apply_rope_ragged(q, cos, sin), q)
+        k = jnp.where(use_rope, _apply_rope_ragged(k, cos, sin), k)
+
+    attn, pools = _paged_attend(
+        q, k, v, dtype=x.dtype, pk=pk, pv=pv, pk_s=pk_s, pv_s=pv_s,
+        pages=pages, apos=apos, valid=valid, paged_kernel=paged_kernel)
+
+    # output projection, residual, MLP: the same after every attention path
+    with scope("attn_out"):
+        attn_out = dense(attn.astype(x.dtype).reshape(B, S, nq * hd),
+                         layer["wo"])
+        if tp_axis:
+            attn_out = C.all_reduce(attn_out, tp_axis)
+    h = x + attn_out
+    with scope("mlp"):
+        r = T.rms_norm(h, layer["ln2"], cfg.rms_norm_eps)
+        mlp, _aux = T._mlp_block(r, layer, cfg=cfg)
+        if tp_axis:
+            mlp = C.all_reduce(mlp, tp_axis)
+    return h + mlp, pools
+
+
+def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
+                  valid, paged_kernel=False):
+    """The new K/V rows into their pages, then causal attention of the
+    rows at ``apos`` against their slots' pages:
 
       * new K/V rows scatter token-granularly into their page table
         slots; rows with ``valid`` False (prompt padding, inactive
@@ -108,27 +151,20 @@ def _paged_layer_body(x, layer, *, cfg, cos, sin, use_rope, pk, pv,
         ``pos_kv <= apos`` is unchanged and masked stale/garbage
         positions contribute exact zeros (finite garbage → −1e30 score
         → 0.0 prob), which is what keeps the paged path bitwise equal
-        to the contiguous cache at matched contraction extent.
+        to the contiguous cache at matched contraction extent;
+      * or, with ``paged_kernel``, reads the pages in place through the
+        Pallas kernels.
 
-    x (B, S, H); pages (B, P) int32; apos (B, S) int32 absolute
-    positions of x's rows; valid (B, S) bool."""
-    B, S, H = x.shape
-    hd = cfg.resolved_head_dim
-    tp = C.axis_size(tp_axis) if tp_axis else 1
-    nq = cfg.num_attention_heads // tp
-    nkv = cfg.num_key_value_heads // tp
-    dense = T._dense(cfg)
+    q (B, S, nq, hd), k, v (B, S, nkv, hd) in ``dtype``; the pools
+    (n_pages, page, nkv, hd) (+ scales of an int8 pool); pages (B, P);
+    apos, valid (B, S).  Returns the heads' outputs float32
+    (B, S, nkv, nq / nkv, hd) and the pools
+    ``(pk, pv, pk_s, pv_s)``."""
+    B, S, nq, hd = q.shape
+    nkv = k.shape[2]
     page = pk.shape[1]
     P = pages.shape[1]
     V = P * page
-
-    with scope("attn_qkv"):
-        r = T.rms_norm(x, layer["ln1"], cfg.rms_norm_eps)
-        q = dense(r, layer["wq"]).reshape(B, S, nq, hd)
-        k = dense(r, layer["wk"]).reshape(B, S, nkv, hd)
-        v = dense(r, layer["wv"]).reshape(B, S, nkv, hd)
-        q = jnp.where(use_rope, _apply_rope_ragged(q, cos, sin), q)
-        k = jnp.where(use_rope, _apply_rope_ragged(k, cos, sin), k)
 
     # scatter the new rows: target page from the slot's table, offset
     # within it; invalid rows all collapse onto page 0 (duplicate
@@ -149,22 +185,6 @@ def _paged_layer_body(x, layer, *, cfg, cos, sin, use_rope, pk, pv,
             pk = pk.at[pg, off].set(k)
             pv = pv.at[pg, off].set(v)
 
-    def tail(attn):
-        """Output projection, residual, MLP: the same after every
-        attention path."""
-        with scope("attn_out"):
-            attn_out = dense(attn.astype(x.dtype).reshape(B, S, nq * hd),
-                             layer["wo"])
-            if tp_axis:
-                attn_out = C.all_reduce(attn_out, tp_axis)
-        h = x + attn_out
-        with scope("mlp"):
-            r = T.rms_norm(h, layer["ln2"], cfg.rms_norm_eps)
-            mlp, _aux = T._mlp_block(r, layer, cfg=cfg)
-            if tp_axis:
-                mlp = C.all_reduce(mlp, tp_axis)
-        return h + mlp, (pk, pv, pk_s, pv_s)
-
     rep = nq // nkv
     if paged_kernel and S == 1:
         # Pallas decode kernel: pages are read IN PLACE via the table,
@@ -183,8 +203,8 @@ def _paged_layer_body(x, layer, *, cfg, cos, sin, use_rope, pk, pv,
             else:
                 attn = paged_attention_decode(qg, pk, pv, pages, apos,
                                               valid=valid,
-                                              probs_dtype=x.dtype)
-        return tail(attn)
+                                              probs_dtype=dtype)
+        return attn, (pk, pv, pk_s, pv_s)
 
     if paged_kernel and not quantized:
         # Pallas flash prefill: the whole chunk's attention in one
@@ -197,8 +217,8 @@ def _paged_layer_body(x, layer, *, cfg, cos, sin, use_rope, pk, pv,
         with scope("attn_core"):
             qg = q.reshape(B, S, nkv, rep, hd)
             attn = paged_flash_prefill(qg, pk, pv, pages, apos,
-                                       valid=valid, probs_dtype=x.dtype)
-        return tail(attn)
+                                       valid=valid, probs_dtype=dtype)
+        return attn, (pk, pv, pk_s, pv_s)
 
     # gather the slot's pages into the contiguous head-major view the
     # attention contracts over — fixed extent V for every request, the
@@ -236,9 +256,9 @@ def _paged_layer_body(x, layer, *, cfg, cos, sin, use_rope, pk, pv,
             attn = attn_i.astype(jnp.float32) \
                 * pv_sc[..., 0].transpose(0, 3, 1, 2)[..., None]
         else:
-            attn = jnp.einsum("bgrsk,bgkh->bsgrh", probs.astype(x.dtype),
+            attn = jnp.einsum("bgrsk,bgkh->bsgrh", probs.astype(dtype),
                               vv, preferred_element_type=jnp.float32)
-    return tail(attn)
+    return attn, (pk, pv, pk_s, pv_s)
 
 
 def _paged_latent_layer_body(x, layer, *, cfg, cos, sin, pool, pages,
@@ -328,15 +348,124 @@ def _paged_latent_forward(params, ids, cfg, bufs: PoolBuffers, pages,
     return x, bufs._replace(k=tuple(pools)), total
 
 
+def _paged_hybrid_forward(params, ids, cfg, bufs: PoolBuffers, pages,
+                          apos, valid, paged_kernel=False, slot=None):
+    """``_paged_forward`` for the gated delta-rule hybrid block
+    (``models/gdn_hybrid.py`` holds its pieces): ``params["layers"]`` is a
+    tuple of per-layer dicts of two kinds, and so is the per-request state.
+
+    A FULL-ATTENTION layer caches K/V rows in its own page pools
+    (``bufs.k[f]``/``bufs.v[f]``, ``f`` counting the full layers only)
+    through :func:`_paged_attend`: the dense block's storage and kernels,
+    over a row that may end in zero heads (``kv_pool.padded_kv_heads``).
+
+    A LINEAR layer reads and writes its STATE SLOTS ``bufs.state[j]``
+    (n_slots, n, dk, dv) float32 and ``bufs.conv[j]`` (n_slots, K - 1, C),
+    ``j`` counting the linear layers only:
+
+      * a decode step (``slot`` None; row ``b`` of x IS slot ``b``) runs
+        ``gdn_hybrid.recurrent_step`` on every slot's state in place; a
+        row with ``valid`` False has beta = 0 and alpha = 1 and leaves its
+        state and its tail bit-unchanged;
+      * a prefill chunk of one request (``slot`` () int32; x is (1, C, H))
+        takes the slot's state and tail, or ZEROS when the chunk is the
+        request's first (``apos[0, 0] == 0``: a granted slot never
+        inherits what its last request left), runs
+        ``gdn_hybrid.chunked_scan`` over the chunk and writes both back;
+        rows past the prompt's end change neither.
+
+    Under the catalogue's scopes: ``attn_qkv`` (projections, conv, SiLU,
+    the norms of q and k, beta and alpha; the conv under ``lin_conv``),
+    ``kv_write``, ``attn_core`` (attention; the scan under ``lin_scan``,
+    the step under ``lin_step``), ``attn_out`` (gated norm, ``w_o`` /
+    ``wo``, the post-mixer norm), ``mlp``.  Returns ``(x', bufs',
+    counts)``; ``counts`` is int32 (1,): the rows of this call whose
+    state was live, a decode step's ``state_slot_steps``."""
+    decode = slot is None
+    with scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[ids]
+    B, S, _ = x.shape
+    ks, vs = list(bufs.k), list(bufs.v)
+    states, tails = list(bufs.state), list(bufs.conv)
+    fresh = None if decode else apos[0, 0] == 0
+    f = j = 0
+    for li, layer in enumerate(params["layers"]):
+        if G.is_full_layer(li, cfg):
+            with scope("attn_qkv"):
+                q, k, v = G.attention_qkv(x, layer, cfg=cfg)
+                # the pool's row may hold zero heads after the real ones
+                # (kv_pool.padded_kv_heads): their keys and values are 0,
+                # their queries' outputs are dropped
+                nkv, rep = k.shape[2], q.shape[2] // k.shape[2]
+                extra = ks[f].shape[2] - nkv
+                if extra:
+                    k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, extra), (0, 0)))
+                            for a in (k, v))
+                    q = jnp.pad(q, ((0, 0), (0, 0), (0, extra * rep),
+                                    (0, 0)))
+            attn, (ks[f], vs[f], _, _) = _paged_attend(
+                q, k, v, dtype=x.dtype, pk=ks[f], pv=vs[f], pk_s=None,
+                pv_s=None, pages=pages, apos=apos, valid=valid,
+                paged_kernel=paged_kernel)
+            with scope("attn_out"):
+                h = G.add_mixer(x, T._dense(cfg)(
+                    attn[:, :, :nkv].astype(x.dtype).reshape(B, S, -1),
+                    layer["wo"]), layer, cfg=cfg)
+            f += 1
+        else:
+            if decode:
+                s0, t0 = states[j], tails[j]
+            else:
+                s0 = jax.lax.dynamic_slice_in_dim(states[j], slot, 1)
+                t0 = jax.lax.dynamic_slice_in_dim(tails[j], slot, 1)
+                s0 = jnp.where(fresh, jnp.zeros_like(s0), s0)
+                t0 = jnp.where(fresh, jnp.zeros_like(t0), t0)
+            with scope("attn_qkv"):
+                q, k, v, g, beta, t1 = G.linear_inputs(
+                    x, layer, t0, valid, cfg=cfg)
+            with scope("attn_core"):
+                if decode:
+                    with scope("lin_step"):
+                        o, s1 = G.recurrent_step(
+                            q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                            beta[:, 0], s0)
+                        o = o[:, None]
+                else:
+                    with scope("lin_scan"):
+                        o, s1 = G.chunked_scan(q, k, v, g, beta, s0)
+            if decode:
+                states[j], tails[j] = s1, t1
+            else:
+                states[j] = jax.lax.dynamic_update_slice_in_dim(
+                    states[j], s1, slot, axis=0)
+                tails[j] = jax.lax.dynamic_update_slice_in_dim(
+                    tails[j], t1.astype(tails[j].dtype), slot, axis=0)
+            with scope("attn_out"):
+                h = G.add_mixer(x, G.linear_output(o, x, layer, cfg=cfg),
+                                layer, cfg=cfg)
+            j += 1
+        with scope("mlp"):
+            x = G.mlp(h, layer, cfg=cfg)
+    live = jnp.sum(jnp.any(valid, axis=1).astype(jnp.int32))
+    return x, bufs._replace(k=tuple(ks), v=tuple(vs), state=tuple(states),
+                            conv=tuple(tails)), live[None]
+
+
 def _paged_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
-                   valid, tp_axis=None, paged_kernel=False):
+                   valid, tp_axis=None, paged_kernel=False, slot=None):
     """ids (B, S) → (hidden x (B, S, H), bufs', counts) through the
     UNROLLED layer stack (static layer index into the per-layer pools,
     like ``generate._forward_cached``).  ``counts`` is None for the dense
-    block and the expert layers' counters for the latent one."""
+    block, the expert layers' counters for the latent one and the live
+    rows for the gated delta-rule hybrid, whose prefill chunk also names
+    the batch ``slot`` whose state it carries."""
     if cfg.mla_moe:
         return _paged_latent_forward(params, ids, cfg, bufs, pages, apos,
                                      valid, paged_kernel=paged_kernel)
+    if cfg.gdn_hybrid:
+        return _paged_hybrid_forward(params, ids, cfg, bufs, pages, apos,
+                                     valid, paged_kernel=paged_kernel,
+                                     slot=slot)
     with scope("embed"):
         x = params["embed"].astype(cfg.dtype)[ids]
         cos, sin = _ragged_rope_tables(apos, cfg.resolved_head_dim,
@@ -387,16 +516,17 @@ def _last_logits(params, x_last, cfg):
 
 
 def _decode_core(bufs, params, pages, toks, lengths, stop_at, active,
-                 moe=None, *, cfg, tp_axis=None, paged_kernel=False):
+                 counted=None, *, cfg, tp_axis=None, paged_kernel=False):
     """One fixed-shape decode step over every slot.  toks/lengths/
     stop_at (B,) int32, active (B,) bool.  Emits the next greedy token
     per ACTIVE slot (inactive slots freeze); a slot auto-retires ON
     DEVICE when its length reaches ``stop_at`` — the device can never
     write past a request's page grant even mid-burst, the host only
-    observes retirement at the next sync.  The latent block also takes
-    ``moe``, the burst's running sum of its expert layers' counters,
-    and returns it with this step's added: a burst's steps chain it on
-    the device and its one sync reads it."""
+    observes retirement at the next sync.  A block that counts on the
+    device (the latent block's expert layers, the gated delta-rule
+    hybrid's live states) also takes ``counted``, the burst's running sum of
+    its counters, and returns it with this step's added: a burst's steps
+    chain it on the device and its one sync reads it."""
     apos = lengths[:, None]
     x, bufs, counts = _paged_forward(
         params, toks[:, None], cfg, bufs, pages, apos, active[:, None],
@@ -409,23 +539,26 @@ def _decode_core(bufs, params, pages, toks, lengths, stop_at, active,
     new_active = jnp.logical_and(active, new_len < stop_at)
     occ = jnp.sum(active.astype(jnp.int32))
     if counts is not None:
-        return nxt, new_len, new_active, bufs, occ, moe + counts
+        return nxt, new_len, new_active, bufs, occ, counted + counts
     return nxt, new_len, new_active, bufs, occ
 
 
-def _prefill_core(bufs, params, pages_row, ids, pos, plen, *, cfg,
-                  tp_axis=None, paged_kernel=False):
+def _prefill_core(bufs, params, pages_row, ids, pos, plen, slot=None, *,
+                  cfg, tp_axis=None, paged_kernel=False):
     """One prefill CHUNK for one request: ids (1, C) host-padded with
     zeros, pos/plen () int32 (chunk start, full prompt length).  Writes
     the chunk's K/V into the request's pages; rows past the prompt
-    divert to the null page.  Returns the greedy first token — only
-    meaningful on the FINAL chunk (position plen-1 falls inside it)."""
+    divert to the null page.  A block with state slots also takes
+    ``slot`` () int32, the request's batch slot, whose state the chunk
+    carries on (from zeros when ``pos`` is 0).  Returns the greedy first
+    token — only meaningful on the FINAL chunk (position plen-1 falls
+    inside it)."""
     Ck = ids.shape[1]
     apos = pos + jnp.arange(Ck, dtype=jnp.int32)[None, :]
     valid = apos < plen
     x, bufs, _ = _paged_forward(params, ids, cfg, bufs, pages_row, apos,
                                 valid, tp_axis=tp_axis,
-                                paged_kernel=paged_kernel)
+                                paged_kernel=paged_kernel, slot=slot)
     with scope("sample"):
         last = jnp.clip(plen - 1 - pos, 0, Ck - 1)
         xl = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
@@ -662,17 +795,21 @@ class ServingEngine:
                  disaggregate: bool = False, device=None,
                  watchdog=None, telem=None):
         self.cfg = _decode_cfg(cfg)
-        if self.cfg.mla_moe:
-            # built for the latent block: chunked prefill, the decode
-            # burst, the paged kernel.  The rest is refused by name,
-            # never run as dense math
+        if self.cfg.block_module is not None:
+            # built for the latent block and for the gated delta-rule
+            # hybrid: chunked prefill, the decode burst, the paged
+            # kernels.  The rest is refused by name, never run as dense
+            # math (the hybrid's state slots have no snapshot for a
+            # prefix to share, no rollback for a rejected draft, no int8
+            # form, no head axis to shard and no hand-over between pools)
             for what, asked in (
                     ("kv_quant", kv_quant), ("a tp mesh", mesh is not None),
                     ("spec_k", spec_k), ("flash_prefill", flash_prefill),
                     ("disaggregate", disaggregate),
                     ("prefix_cache", prefix_cache)):
                 if asked:
-                    M.refuse(self.cfg, f"ServingEngine with {what}")
+                    self.cfg.block_module.refuse(
+                        self.cfg, f"ServingEngine with {what}")
         self.max_batch = int(max_batch)
         self.page_size = int(page_size)
         self.pages_per_request = -(-int(max_seq_len) // self.page_size)
@@ -786,7 +923,8 @@ class ServingEngine:
                     kv_quant=self.kv_quant, tp=tp,
                     draft_weight_bytes=(tree_size_bytes(draft_params)
                                         if self.spec_k else 0),
-                    draft_cfg=self.draft_cfg) + 1
+                    draft_cfg=self.draft_cfg,
+                    max_batch=self.max_batch) + 1
                 n_pages = min(n_pages, fit)
         if n_pages < self.pages_per_request + 1:
             raise ValueError(
@@ -824,7 +962,8 @@ class ServingEngine:
 
         self.pool = PagedKVPool(self.cfg, self.n_pages, self.page_size,
                                 kv_quant=self.kv_quant, mesh=mesh,
-                                tp_axis=tp_axis, device=self._decode_dev)
+                                tp_axis=tp_axis, device=self._decode_dev,
+                                n_slots=self.max_batch)
         # the draft model's own pool, addressed by the SAME page tables
         # as the target pool (no second allocator): position p of a
         # request's draft KV lives at the same (page, offset) as its
@@ -855,7 +994,8 @@ class ServingEngine:
             "predicted_gb": round(serve_waterline_gb(
                 self.cfg, self.n_pages, self.page_size, weight_bytes=_wb,
                 kv_quant=self.kv_quant, tp=tp,
-                draft_weight_bytes=_dwb, draft_cfg=self.draft_cfg), 3),
+                draft_weight_bytes=_dwb, draft_cfg=self.draft_cfg,
+                max_batch=self.max_batch), 3),
             "source": "serve_accounting",
             "components": comps,
         }
@@ -972,15 +1112,26 @@ class ServingEngine:
                       # admission: requests seated and their summed
                       # wait from submission (DUE) to a slot
                       "admitted": 0, "queue_wait_s": 0.0}
-        # the latent block's expert layers, counted on the device over
-        # the decode steps (mla_moe.moe_counts) and read at a burst's
-        # sync: (row, chosen expert) pairs over the router's whole
-        # width, those whose expert is held here, held experts that got
-        # a row (summed over layers and steps), expert layers x steps
-        self._moe_zero = None
+        # what a block counts on the device over the decode steps and a
+        # burst's sync reads.  The latent block's expert layers
+        # (mla_moe.moe_counts): (row, chosen expert) pairs over the
+        # router's whole width, those whose expert is held here, held
+        # experts that got a row (summed over layers and steps), expert
+        # layers x steps.  The gated delta-rule hybrid: the live states a
+        # step read and wrote (``state_slot_steps``); its other two
+        # counters are the host's (slots reset at a grant, valid rows the
+        # prefill chunks scanned)
+        self._device_counters: tuple = ()
         if self.cfg.mla_moe:
-            self.stats.update(dict.fromkeys(M.COUNTERS, 0))
-            self._moe_zero = self._put(np.zeros(len(M.COUNTERS), np.int32))
+            self._device_counters = M.COUNTERS
+        elif self.cfg.gdn_hybrid:
+            self._device_counters = G.COUNTERS[:1]
+            self.stats.update(dict.fromkeys(G.COUNTERS[1:], 0))
+        self._counted_zero = None
+        if self._device_counters:
+            self.stats.update(dict.fromkeys(self._device_counters, 0))
+            self._counted_zero = self._put(
+                np.zeros(len(self._device_counters), np.int32))
         # attributes every serve/* span of the current round carries
         self._sp: dict = {}
 
@@ -1085,6 +1236,10 @@ class ServingEngine:
             args = (self._put(row, dev), self._put(ids, dev),
                     self._put(np.int32(pos), dev),
                     self._put(np.int32(req.n_prompt), dev))
+            if self.cfg.gdn_hybrid:
+                # the batch slot whose state the chunk carries on
+                args += (self._put(np.int32(req.slot), dev),)
+                self.stats["lin_scan_rows"] += min(Ck, req.n_prompt - pos)
         with maybe_span(stream, "serve/prefill_dispatch", **sp):
             tok_d, bufs = self._prefill(bufs, self._params_pre, *args)
             if self.disaggregate:
@@ -1291,15 +1446,16 @@ class ServingEngine:
         A0 = self._h_active.copy()
         toks_d, len_d, stop_d, act_d, pages_d = self._stage_burst()
         bufs = self.pool.bufs
-        # the latent block's counters ride the burst as one more argument
-        moe = [] if self._moe_zero is None else [self._moe_zero]
+        # a block's device-side counters ride the burst as one more argument
+        counted = [] if self._counted_zero is None \
+            else [self._counted_zero]
         if self.telem is not None:
             # ledger join (no-op unless the run owns an enabled
             # profiler, and only compiles once): the decode program's
             # text at this burst's exact arg shardings
             self.telem.attach_step_hlo(self._decode, bufs, self._params,
                                        pages_d, toks_d, len_d, stop_d,
-                                       act_d, *moe,
+                                       act_d, *counted,
                                        trees={"kv_pool": bufs,
                                               "params": self._params},
                                        prediction=self._mem_prediction)
@@ -1307,18 +1463,18 @@ class ServingEngine:
         with maybe_span(stream, "serve/burst_dispatch", steps=sync, **sp):
             step_tokens = []
             for _ in range(sync):
-                toks_d, len_d, act_d, bufs, occ, *moe = self._decode(
+                toks_d, len_d, act_d, bufs, occ, *counted = self._decode(
                     bufs, self._params, pages_d, toks_d, len_d, stop_d,
-                    act_d, *moe)
+                    act_d, *counted)
                 pump.emit(occ)
                 step_tokens.append(toks_d)
             self.pool.bufs = bufs
             self.stats["decode_steps"] += sync
             if self.paged_kernel:
                 self.stats["decode_inplace_steps"] += sync
-        mats = self._sync_burst(step_tokens + moe)
-        if moe:
-            for name, count in zip(M.COUNTERS, mats.pop()):
+        mats = self._sync_burst(step_tokens + counted)
+        if counted:
+            for name, count in zip(self._device_counters, mats.pop()):
                 self.stats[name] += int(count)
         burst_s = time.perf_counter() - t_burst  # clock-ok
         self.stats["decode_s"] += burst_s
@@ -1513,6 +1669,10 @@ class ServingEngine:
                     self._h_pages[req.slot] = 0
                     self._h_pages[req.slot, :len(req.pages)] = req.pages
                     self.stats["queue_wait_s"] += req.t_admit - req.t_submit
+                    if self.cfg.gdn_hybrid:
+                        # the granted slot's state: its first prefill
+                        # chunk starts from zeros (_paged_hybrid_forward)
+                        self.stats["state_resets"] += 1
                     if self.disaggregate:
                         n = -(-req.n_prompt // self.page_size)
                         pre = self.pool_pre.allocator.alloc(n)
@@ -1683,8 +1843,8 @@ class ServingEngine:
             "devices": ndev,
             "pool": {"n_pages": self.n_pages,
                      "page_size": self.page_size,
-                     "bytes_per_token": self.pool.row_bytes
-                     * self.cfg.num_hidden_layers,
+                     "bytes_per_token": self.pool.token_bytes,
+                     "state_slot_bytes": self.pool.state_bytes,
                      "peak_util": round(self.stats["peak_pool_util"], 4)},
             "scheduler": {
                 "rounds": self.stats["rounds"],

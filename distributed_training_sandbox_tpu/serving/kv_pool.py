@@ -33,6 +33,17 @@ What ONE token caches in one layer is the pool's ROW, and
 (``models/mla_moe.py``) ONE row ``[c_kv | k_rope]`` and no V pool.  Pages,
 the allocator and the prefix trie are page-granular and do not look inside
 a row.
+
+Not every layer has pages, and pages are not the only per-request state.
+In the gated delta-rule hybrid block (``models/gdn_hybrid.py``) only the
+full-attention layers cache rows (:func:`paged_layers`); a linear layer
+keeps, per REQUEST and whatever its length, one float32 state matrix and a
+conv tail.  Those live beside the pages in the same :class:`PoolBuffers`
+as fixed-size STATE SLOTS indexed by the batch slot the scheduler grants:
+``state`` ``(n_slots, heads, key dim, value dim)`` and ``conv``
+``(n_slots, K - 1, channels)`` per linear layer.  A slot's state never
+survives its request: the first prefill chunk of the next one starts from
+zeros whatever the slot held (``engine._prefill_core``).
 """
 
 from __future__ import annotations
@@ -49,15 +60,54 @@ import jax.numpy as jnp
 LATENT_ROW_ALIGN = 128
 
 
+def padded_kv_heads(n_kv: int, dtype) -> int:
+    """KV heads a row of the gated delta-rule hybrid's full-attention
+    pools holds: ``n_kv``, rounded up to whole sublane tiles of the dtype
+    (8 rows of 32 bits: 16 heads of bfloat16) unless it divides one.  The
+    paged kernels read a page as ONE ``(page_size * heads, hd)`` slab; a TPU
+    holds the pool's ``(heads, hd)`` minor dims in such tiles, so with 30
+    heads of bfloat16 (32 rows a token in memory) that slab is no view of
+    the pool and XLA copies the whole pool, several times a layer and step,
+    to make one (compiled for a v5e: four 755 MB copies a pool).  Two zero
+    heads make the slab a bitcast, as the latent row's zero columns do."""
+    tile = 32 // jnp.dtype(dtype).itemsize
+    if tile % n_kv == 0:
+        return n_kv
+    return n_kv + -n_kv % tile
+
+
 def row_layout(cfg, tp: int = 1) -> tuple[tuple[int, ...], bool]:
-    """``(row shape, has_v)`` of one token in one layer's pool(s): the
-    trailing dims of every pool array after ``(n_pages, page_size)``, and
-    whether a V pool of the same shape stands beside the K pool."""
+    """``(row shape, has_v)`` of one token in one PAGED layer's pool(s):
+    the trailing dims of every pool array after ``(n_pages, page_size)``,
+    and whether a V pool of the same shape stands beside the K pool."""
     if cfg.mla_moe:
         from ..models.mla_moe import row_width
         w = row_width(cfg)
         return (w + -w % LATENT_ROW_ALIGN,), False
+    if cfg.gdn_hybrid:
+        return (padded_kv_heads(cfg.num_key_value_heads, cfg.dtype),
+                cfg.resolved_head_dim), True
     return (cfg.num_key_value_heads // tp, cfg.resolved_head_dim), True
+
+
+def paged_layers(cfg) -> int:
+    """Layers whose tokens cache a row in pages: what every sizing of the
+    pool multiplies :func:`token_row_bytes` by.  All of them, but for the
+    gated delta-rule hybrid's linear layers, which hold a state slot
+    instead."""
+    if cfg.gdn_hybrid:
+        from ..models.gdn_hybrid import full_layers
+        return len(full_layers(cfg))
+    return cfg.num_hidden_layers
+
+
+def slot_state_bytes(cfg) -> int:
+    """Bytes ONE batch slot holds in state slots over all layers (0 for a
+    block whose every layer is paged)."""
+    if cfg.gdn_hybrid:
+        from ..models import gdn_hybrid as G
+        return len(G.linear_layers(cfg)) * G.slot_state_bytes(cfg)
+    return 0
 
 
 def token_row_bytes(cfg, *, kv_quant: bool = False, tp: int = 1) -> int:
@@ -72,16 +122,20 @@ def token_row_bytes(cfg, *, kv_quant: bool = False, tp: int = 1) -> int:
 
 class PoolBuffers(NamedTuple):
     """The device half of the pool: per-layer page-block arrays (tuples
-    of L arrays, mirroring ``KVCache``'s per-layer-buffer decision — a
-    stacked (L, ...) layout would pay a dynamic-slice copy per layer per
-    step), each ``(n_pages, page_size) + row shape`` (:func:`row_layout`).
-    ``v`` is None for the latent block, whose one row a token lives in
-    ``k``.  ``k_scale``/``v_scale`` are the f32 row scales of the int8
-    pool, None for the ``cfg.dtype`` pool."""
+    of L arrays, one per PAGED layer, mirroring ``KVCache``'s
+    per-layer-buffer decision — a stacked (L, ...) layout would pay a
+    dynamic-slice copy per layer per step), each ``(n_pages, page_size) +
+    row shape`` (:func:`row_layout`).  ``v`` is None for the latent block,
+    whose one row a token lives in ``k``.  ``k_scale``/``v_scale`` are the
+    f32 row scales of the int8 pool, None for the ``cfg.dtype`` pool.
+    ``state``/``conv`` are the state slots of the gated delta-rule
+    hybrid's linear layers, one array a linear layer, None elsewhere."""
     k: tuple            # L × (n_pages, page_size, n_kv, hd) | (.., .., W)
     v: tuple | None
     k_scale: tuple | None   # L × (n_pages, page_size, n_kv, 1) f32
     v_scale: tuple | None
+    state: tuple | None = None  # (n_slots, heads, dk, dv) f32 a linear layer
+    conv: tuple | None = None   # (n_slots, K - 1, channels) a linear layer
 
 
 class PageAllocator:
@@ -343,9 +397,15 @@ class PagedKVPool:
 
     def __init__(self, cfg, n_pages: int, page_size: int, *,
                  kv_quant: bool = False, mesh=None, tp_axis: str = "tp",
-                 device=None):
+                 device=None, n_slots: int = 0):
         if mesh is not None and device is not None:
             raise ValueError("pass mesh or device, not both")
+        if cfg.gdn_hybrid and (kv_quant or mesh is not None
+                               or n_slots < 1):
+            raise ValueError(
+                "the gated delta-rule hybrid's pool holds a float state "
+                "slot per batch slot: pass n_slots >= 1, and neither "
+                "kv_quant nor a mesh")
         self.cfg = cfg
         self.n_pages = int(n_pages)
         self.page_size = int(page_size)
@@ -353,7 +413,7 @@ class PagedKVPool:
         self.mesh = mesh
         self.tp_axis = tp_axis
         self.device = device
-        L = cfg.num_hidden_layers
+        L = paged_layers(cfg)
         row, has_v = row_layout(cfg)
         if kv_quant and not has_v:
             raise NotImplementedError(
@@ -373,7 +433,17 @@ class PagedKVPool:
                        for _ in range(L))
             vs = tuple(put(jnp.ones(shape[:-1] + (1,), jnp.float32))
                        for _ in range(L))
-        self.bufs = PoolBuffers(k=k, v=v, k_scale=ks, v_scale=vs)
+        state = conv = None
+        self.n_slots = int(n_slots) if cfg.gdn_hybrid else 0
+        if self.n_slots:
+            from ..models import gdn_hybrid as G
+            n_lin = len(G.linear_layers(cfg))
+            state = tuple(put(jnp.zeros((self.n_slots,) + G.state_shape(cfg),
+                                        jnp.float32)) for _ in range(n_lin))
+            conv = tuple(put(jnp.zeros((self.n_slots,) + G.tail_shape(cfg),
+                                       cfg.dtype)) for _ in range(n_lin))
+        self.bufs = PoolBuffers(k=k, v=v, k_scale=ks, v_scale=vs,
+                                state=state, conv=conv)
         self.allocator = PageAllocator(self.n_pages)
 
     def _row_spec(self):
@@ -400,7 +470,7 @@ class PagedKVPool:
         """PartitionSpec pytree matching ``bufs`` — the in/out spec the
         engine hands ``shard_map`` (heads sharded over tp, everything
         else replicated)."""
-        L = self.cfg.num_hidden_layers
+        L = paged_layers(self.cfg)
         ps = self._row_spec()
         sc = (ps,) * L if self.kv_quant else None
         return PoolBuffers(k=(ps,) * L,
@@ -409,8 +479,18 @@ class PagedKVPool:
 
     @property
     def row_bytes(self) -> int:
-        """Bytes one token occupies in one layer of this pool."""
+        """Bytes one token occupies in one paged layer of this pool."""
         return token_row_bytes(self.cfg, kv_quant=self.kv_quant)
+
+    @property
+    def token_bytes(self) -> int:
+        """Bytes one token occupies over all the layers that have pages."""
+        return self.row_bytes * paged_layers(self.cfg)
+
+    @property
+    def state_bytes(self) -> int:
+        """Bytes of the state slots, all slots and layers (0 without)."""
+        return self.n_slots * slot_state_bytes(self.cfg)
 
     @property
     def utilization(self) -> float:

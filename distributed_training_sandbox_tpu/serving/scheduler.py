@@ -17,6 +17,15 @@ trade at this repo's tier and keeps the engine's device loop free of
 page-fault paths.  Eviction (page + slot release) happens at the sync
 point where a request's emission count reaches ``max_new``.
 
+A granted SLOT is also the grant of that slot's fixed-size state in a
+block that keeps some (the gated delta-rule hybrid's linear layers:
+``kv_pool.PoolBuffers.state/conv``, one entry a batch slot).  Nothing is
+copied or zeroed on the host: the request's first prefill chunk starts
+from zeros whatever the slot's last request left
+(``engine._paged_hybrid_forward``), and the engine counts the grant as a
+``state_resets``.  The state needs no page, so ``pages_needed`` is the
+full-attention layers' alone.
+
 Timestamps are elapsed seconds on the engine's clock: ``t_submit`` is
 the request's (virtual) arrival, ``t_first`` when its first token
 resolved on the host (prefill is synchronous at admission, so TTFT is

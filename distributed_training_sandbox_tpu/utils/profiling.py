@@ -163,6 +163,18 @@ SUBSCOPES = (
     "moe_shared",   # the shared expert
 )
 
+#: A declared second level of the gated delta-rule hybrid's linear layers
+#: (``models/gdn_hybrid.py``, ``serving/engine._paged_hybrid_forward``):
+#: ``lin_conv`` beneath ``attn_qkv``, ``lin_scan`` and ``lin_step`` beneath
+#: ``attn_core``.  A reader of ``SCOPES`` books these ops to the catalogue
+#: name above them; ``benchmarks/layer_metrics/_linscopes.py`` holds a copy
+#: of this tuple, pinned by a test, and splits them out.
+LINEAR_SUBSCOPES = (
+    "lin_conv",   # q/k/v projections' depthwise causal conv, the tail update
+    "lin_scan",   # a prefill chunk's chunked scan from the carried state
+    "lin_step",   # a decode step's recurrence on every slot's state
+)
+
 
 def scope(name: str):
     """Device-side marker for code *inside* jit: prefixes XLA op names so

@@ -1,0 +1,541 @@
+"""The gated delta-rule hybrid block (``models/gdn_hybrid.py``) on the
+serving path, at a tiny size, float32, seeded weights, on the CPU: the
+three forms of the recurrence against each other, the cache-less forward
+and the engine's own programs against the benchmark's plain reference on
+logits, the state slots beside the pages (reset at grant, frozen while
+inactive, untouched by padding rows), the pool's layout and sizing, what
+is refused by name, and both engine programs lowered for a TPU at the
+published widths."""
+
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
+
+from benchmarks.reference import gdn_hybrid as R  # noqa: E402
+from distributed_training_sandbox_tpu.models import gdn_hybrid as G  # noqa: E402
+from distributed_training_sandbox_tpu.models import transformer as T  # noqa: E402
+from distributed_training_sandbox_tpu.serving import ServingEngine  # noqa: E402
+from distributed_training_sandbox_tpu.serving import accounting  # noqa: E402
+from distributed_training_sandbox_tpu.serving import engine as E  # noqa: E402
+from distributed_training_sandbox_tpu.serving.kv_pool import (  # noqa: E402
+    PagedKVPool, PoolBuffers, padded_kv_heads, paged_layers, row_layout,
+    slot_state_bytes, token_row_bytes)
+
+FIELDS = dict(
+    vocab_size=256, hidden_size=64, intermediate_size=160,
+    num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=4,
+    rms_norm_eps=1e-6, tie_word_embeddings=False, nope_interval=0,
+    full_attention_interval=4, linear_num_key_heads=3,
+    linear_num_value_heads=3, linear_key_head_dim=8,
+    linear_value_head_dim=16, linear_conv_kernel_dim=4,
+    linear_allow_neg_eigval=True)
+
+
+def make(seed=0, scale=2.0, **over):
+    fields = {**FIELDS, **over}
+    cfg = T.TransformerConfig(**fields, dtype=jnp.float32, remat=False)
+    params = jax.tree.map(lambda x: x * scale,
+                          T.init_params(jax.random.key(seed), cfg))
+    return fields, cfg, params
+
+
+@pytest.fixture(scope="module")
+def model():
+    return make()
+
+
+@pytest.fixture(autouse=True)
+def small_sub_chunks(monkeypatch):
+    """Sub-chunks of 4 rows, so that a 16-row prefill chunk scans four of
+    them in sequence and a prompt ends inside one."""
+    monkeypatch.setattr(G, "SCAN_CHUNK", 4)
+
+
+def test_two_kinds_of_layer_and_the_count(model):
+    _, cfg, params = model
+    assert set(params) == {"embed", "layers", "final_norm", "lm_head"}
+    assert G.full_layers(cfg) == (3,) and G.linear_layers(cfg) == (0, 1, 2)
+    lin, full = params["layers"][0], params["layers"][3]
+    assert lin["w_q"].shape == (64, 24) and lin["w_v"].shape == (64, 48)
+    assert lin["conv_w"].shape == (4, 96) and lin["o_norm"].shape == (16,)
+    assert lin["A_log"].shape == (3,) and "wq" not in lin
+    assert full["wq"].shape == (64, 64) and full["q_norm"].shape == (64,)
+    assert "ln1" not in full and "w_q" not in full      # no pre-norm
+    assert cfg.param_count() == sum(x.size for x in jax.tree.leaves(params))
+    assert G.state_shape(cfg) == (3, 8, 16) and G.tail_shape(cfg) == (3, 96)
+
+
+# ----------------------------------------- the three forms of the recurrence
+
+def _recurrence_inputs(seed, B=2, S=13, n=3, dk=8, dv=16):
+    ks = jax.random.split(jax.random.key(seed), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(ks[0], (B, S, n, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(ks[1], (B, S, n, dk)))
+    v = jax.random.normal(ks[2], (B, S, n, dv))
+    g = -jax.random.uniform(ks[3], (B, S, n), minval=0.0, maxval=2.0)
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, n)))
+    s0 = jax.random.normal(ks[5], (B, n, dk, dv))       # NON-zero state
+    return q, k, v, g, beta, s0
+
+
+def _token_by_token(q, k, v, g, beta, s):
+    out = []
+    for t in range(q.shape[1]):
+        o, s = G.recurrent_step(q[:, t], k[:, t], v[:, t], g[:, t],
+                                beta[:, t], s)
+        out.append(o)
+    return jnp.stack(out, axis=1), s
+
+
+@pytest.mark.parametrize("rows", [13, 16, 1, 5])
+def test_chunked_scan_is_the_token_recurrence_from_a_carried_state(rows):
+    """Sub-chunks of 4: 13 rows end inside the fourth, 16 are four whole
+    ones, 1 and 5 a one-row chunk and a one-row tail.  float32 on both
+    sides; the chunked form re-associates the sums and solves a triangular
+    system (measured 3e-6 on outputs of size ~1)."""
+    q, k, v, g, beta, s0 = _recurrence_inputs(1, S=rows)
+    with jax.default_matmul_precision("highest"):
+        o, s = G.chunked_scan(q, k, v, g, beta, s0)
+        o_want, s_want = _token_by_token(q, k, v, g, beta, s0)
+    np.testing.assert_allclose(o, o_want, atol=3e-5)
+    np.testing.assert_allclose(s, s_want, atol=3e-5)
+
+
+def test_rows_past_the_end_change_no_state():
+    """beta = 0 and g = 0 (alpha = 1) at padding rows: the state after 16
+    rows of which 9 are valid is the state after those 9, in the scan and
+    in the step, whatever q, k, v the padding holds."""
+    q, k, v, g, beta, s0 = _recurrence_inputs(2, S=16)
+    keep = (jnp.arange(16) < 9)[None, :, None]
+    g, beta = jnp.where(keep, g, 0.0), jnp.where(keep, beta, 0.0)
+    with jax.default_matmul_precision("highest"):
+        _, s = G.chunked_scan(q, k, v, g, beta, s0)
+        _, s9 = G.chunked_scan(q[:, :9], k[:, :9], v[:, :9], g[:, :9],
+                               beta[:, :9], s0)
+        _, s_step = G.recurrent_step(q[:, 12], k[:, 12], v[:, 12],
+                                     g[:, 12], beta[:, 12], s0)
+    np.testing.assert_allclose(s, s9, atol=1e-6)
+    assert np.array_equal(np.asarray(s_step), np.asarray(s0))   # bitwise
+
+
+def test_the_conv_carries_its_tail_and_stops_at_the_last_valid_row():
+    u = jax.random.normal(jax.random.key(3), (2, 12, 6))
+    w = jax.random.normal(jax.random.key(4), (4, 6))
+    zero = jnp.zeros((2, 3, 6))
+    whole, tail = G.causal_conv(u, zero, w, jnp.array([12, 12]))
+    a, t1 = G.causal_conv(u[:, :5], zero, w, jnp.array([5, 5]))
+    b, t2 = G.causal_conv(u[:, 5:], t1, w, jnp.array([7, 7]))
+    np.testing.assert_allclose(jnp.concatenate([a, b], 1), whole, atol=1e-6)
+    np.testing.assert_array_equal(t2, tail)
+    np.testing.assert_array_equal(tail, u[:, 9:])
+    # a chunk whose rows end at 2 and at 0: the tail ends there
+    _, t3 = G.causal_conv(u[:, 5:], t1, w, jnp.array([2, 0]))
+    np.testing.assert_array_equal(t3[0], u[0, 4:7])
+    np.testing.assert_array_equal(t3[1], t1[1])
+    want = sum(w[j] * jnp.pad(u, ((0, 0), (3, 0), (0, 0)))[:, j:j + 12]
+               for j in range(4))
+    np.testing.assert_allclose(whole, want, atol=1e-6)
+
+
+# ------------------------------------------------- against the reference
+
+def test_cacheless_forward_is_the_reference(model):
+    """``T.forward`` (chunked scan from a zero state, materialised
+    attention) against the plain reference's token-by-token recurrence:
+    float32 on both sides (measured 3e-5 on logits of std 0.57)."""
+    fields, cfg, params = model
+    ids = jax.random.randint(jax.random.key(1), (2, 37), 1, 256)
+    with jax.default_matmul_precision("highest"):
+        z = T.forward(params, ids, cfg)
+    for b in range(2):
+        want = R.logits_at(params, ids[b], jnp.arange(37), fields, block=37)
+        np.testing.assert_allclose(z[b], want, atol=2e-4)
+
+
+def _pool(cfg, slots, page=8, seq=64):
+    P = seq // page
+    return PagedKVPool(cfg, slots * P + 1, page, n_slots=slots), P
+
+
+def _serve_logits(params, cfg, prompt, n_new, *, kernel, chunk=16, slots=3,
+                  slot=1, bufs=None):
+    """Chunked prefill and then decode of ONE request through the engine's
+    own cores (``_paged_forward`` is what ``_prefill_core`` and
+    ``_decode_core`` run), tapped for logits: the (n_new, V) logits of the
+    positions a server samples from, greedy tokens fed back; and the
+    pool's buffers afterwards."""
+    pool, P = _pool(cfg, slots)
+    pages = np.zeros((slots, P), np.int32)
+    pages[slot] = pool.allocator.alloc(P)
+    bufs = pool.bufs if bufs is None else bufs
+
+    @jax.jit
+    def prefill(bufs, ids, pos, plen):
+        apos = pos + jnp.arange(chunk, dtype=jnp.int32)[None, :]
+        x, bufs, _ = E._paged_forward(
+            params, ids, cfg, bufs, jnp.asarray(pages[slot:slot + 1]), apos,
+            apos < plen, paged_kernel=kernel, slot=jnp.int32(slot))
+        return E._all_logits(params, x, cfg), bufs
+
+    @jax.jit
+    def decode(bufs, toks, lengths, active):
+        x, bufs, counts = E._paged_forward(
+            params, toks[:, None], cfg, bufs, jnp.asarray(pages),
+            lengths[:, None], active[:, None], paged_kernel=kernel)
+        return E._last_logits(params, x, cfg), bufs, counts
+
+    n = len(prompt)
+    for pos in range(0, n, chunk):
+        ids = np.zeros((1, chunk), np.int32)
+        part = prompt[pos:pos + chunk]
+        ids[0, :len(part)] = part
+        z, bufs = prefill(bufs, jnp.asarray(ids), jnp.int32(pos),
+                          jnp.int32(n))
+    out = [z[0, (n - 1) % chunk]]
+    active = np.zeros(slots, bool)
+    active[slot] = True
+    live = 0
+    for i in range(n_new - 1):
+        toks = np.full(slots, 7, np.int32)      # inactive slots: any token
+        toks[slot] = int(jnp.argmax(out[-1]))
+        lengths = np.zeros(slots, np.int32)
+        lengths[slot] = n + i
+        z, bufs, counts = decode(bufs, jnp.asarray(toks),
+                                 jnp.asarray(lengths), jnp.asarray(active))
+        out.append(z[slot])
+        live += int(counts[0])
+    return jnp.stack(out), bufs, live
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernels"])
+@pytest.mark.parametrize("n_prompt", [37, 33, 16, 5])
+def test_engine_prefill_then_decode_is_the_reference_on_logits(
+        model, kernel, n_prompt):
+    """Prefill in chunks of 16 (37 = two chunks and five rows, ending
+    inside a sub-chunk of 4; 33 = a ONE-token last chunk; 16 = one whole
+    chunk; 5 = less than one) carrying state and conv tail from chunk to
+    chunk, then six decode steps through state slots and pages, with the
+    full-attention layers on the gather path and on the paged kernels
+    (interpret mode), against the reference's whole forward pass of the
+    same tokens.  float32 everywhere: the paths differ from the reference
+    in summation order (chunked scan, online softmax), measured 4e-5 on
+    logits of std 0.57; 3e-4 catches a lost tail, a stale state, a wrong
+    page or mask and not the rounding."""
+    fields, cfg, params = model
+    prompt = np.random.default_rng(n_prompt).integers(
+        1, 256, n_prompt).astype(np.int32)
+    n_new = 7
+    with jax.default_matmul_precision("highest"):
+        z, _, live = _serve_logits(params, cfg, prompt, n_new, kernel=kernel)
+    toks = np.asarray(jnp.argmax(z, axis=-1))
+    seq = np.concatenate([prompt, toks[:-1]]).astype(np.int32)
+    pos = n_prompt - 1 + np.arange(n_new)
+    want = R.logits_at(params, jnp.asarray(seq), jnp.asarray(pos), fields,
+                       block=len(seq))
+    np.testing.assert_allclose(z, want, atol=3e-4)
+    assert live == n_new - 1        # one live state a decode step
+
+
+def test_a_slots_first_chunk_starts_from_zeros_whatever_it_held(model):
+    """State reset at grant: the same request through a pool whose slots
+    hold garbage (another request's leftovers) gives bitwise the logits a
+    fresh pool gives, and leaves the OTHER slots' garbage bit-unchanged."""
+    _, cfg, params = model
+    prompt = np.random.default_rng(5).integers(1, 256, 21).astype(np.int32)
+    pool, _ = _pool(cfg, 3)
+    dirty = pool.bufs._replace(
+        state=tuple(jax.random.normal(jax.random.key(i), s.shape)
+                    for i, s in enumerate(pool.bufs.state)),
+        conv=tuple(jax.random.normal(jax.random.key(9 + i), c.shape)
+                   for i, c in enumerate(pool.bufs.conv)))
+    z0, _, _ = _serve_logits(params, cfg, prompt, 4, kernel=False)
+    z1, after, _ = _serve_logits(params, cfg, prompt, 4, kernel=False,
+                                 bufs=dirty)
+    np.testing.assert_array_equal(z0, z1)
+    for before, now in zip(dirty.state + dirty.conv,
+                           after.state + after.conv):
+        # slot 1 served the request; slots 0 and 2 were inactive throughout
+        np.testing.assert_array_equal(before[0], now[0])
+        np.testing.assert_array_equal(before[2], now[2])
+        assert not np.array_equal(np.asarray(before[1]), np.asarray(now[1]))
+
+
+def test_a_reused_slot_serves_what_a_fresh_engine_serves(model):
+    """Six requests through two slots: every slot is granted three times.
+    Each request's tokens are those of an engine that serves it alone, and
+    the reference's argmax; the counters count grants, live states and
+    valid rows."""
+    fields, cfg, params = model
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(1, 256, size=n).astype(np.int32)
+               for n in (37, 17, 16, 5, 33, 9)]
+    kw = dict(page_size=8, max_seq_len=64, prefill_chunk=16)
+    served = {}
+    for kernel in (False, True):
+        eng = ServingEngine(params, cfg, max_batch=2, paged_kernel=kernel,
+                            **kw)
+        reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
+        eng.run()
+        served[kernel] = [r.tokens for r in reqs]
+        s = eng.stats
+        assert s["state_resets"] == s["admitted"] == 6
+        assert s["state_slot_steps"] == 6 * 5       # one token is prefill's
+        assert s["lin_scan_rows"] == sum(map(len, prompts))
+        assert s["prefill_chunks"] == 3 + 2 + 1 + 1 + 3 + 1
+        assert s["decode_inplace_steps"] == (s["decode_steps"] if kernel
+                                             else 0)
+        assert s["prefill_inplace_chunks"] == (s["prefill_chunks"] if kernel
+                                               else 0)
+        assert eng.retraces_after_warmup() == 0
+        rep = eng.slo_report()["pool"]
+        assert rep["bytes_per_token"] == 1 * 2 * 4 * 16 * 4   # ONE full layer
+        assert rep["state_slot_bytes"] == 2 * 3 * (3 * 8 * 16 * 4
+                                                   + 3 * 96 * 4)
+    assert served[False] == served[True]
+    # the last two were granted slots that two requests had used before
+    alone = ServingEngine(params, cfg, max_batch=1, paged_kernel=False, **kw)
+    for p, toks in zip(prompts, served[False]):
+        if len(p) in (33, 9):
+            r = alone.submit(p, max_new_tokens=6)
+            alone.run()
+            assert r.tokens == toks
+        seq = np.concatenate([p, np.asarray(toks[:-1], np.int32)])
+        z = R.logits_at(params, jnp.asarray(seq),
+                        jnp.asarray(len(p) - 1 + np.arange(6)), fields,
+                        block=len(seq))
+        assert list(np.asarray(jnp.argmax(z, -1))) == toks
+
+
+def test_an_inactive_slots_state_is_bit_unchanged_by_a_burst(model):
+    """A decode burst over three slots of which one is active: the other
+    two slots' state and conv tail come back bit for bit, and the active
+    one's moved."""
+    _, cfg, params = model
+    pool, P = _pool(cfg, 3)
+    bufs = pool.bufs._replace(
+        state=tuple(jax.random.normal(jax.random.key(i), s.shape)
+                    for i, s in enumerate(pool.bufs.state)),
+        conv=tuple(jax.random.normal(jax.random.key(7 + i), c.shape)
+                   for i, c in enumerate(pool.bufs.conv)))
+    before = jax.tree.map(np.asarray, (bufs.state, bufs.conv))
+    pages = np.zeros((3, P), np.int32)
+    pages[2] = pool.allocator.alloc(P)
+    step = E.make_serve_decode_step(cfg, paged_kernel=False)
+    toks, lengths = jnp.array([3, 4, 5], jnp.int32), jnp.array([0, 0, 9])
+    stop, active = jnp.array([0, 0, 40]), jnp.array([False, False, True])
+    ctr = jnp.zeros((1,), jnp.int32)
+    for _ in range(4):
+        toks, lengths, active, bufs, occ, ctr = step(
+            bufs, params, jnp.asarray(pages), toks, lengths, stop, active,
+            ctr)
+    assert int(ctr[0]) == 4 and int(lengths[2]) == 13
+    for old, new in zip(before[0] + before[1], bufs.state + bufs.conv):
+        np.testing.assert_array_equal(old[:2], np.asarray(new)[:2])
+        assert not np.array_equal(old[2], np.asarray(new)[2])
+
+
+# ---------------------------------------------------------------- the pool
+
+def test_the_pool_has_pages_for_the_full_layers_only_and_slots_beside(model):
+    _, cfg, _ = model
+    pool = PagedKVPool(cfg, 9, 8, n_slots=5)
+    b = pool.bufs
+    assert len(b.k) == len(b.v) == 1 == paged_layers(cfg)       # of 4 layers
+    assert b.k[0].shape == (9, 8, 4, 16) and b.k_scale is None
+    assert len(b.state) == len(b.conv) == 3
+    assert b.state[0].shape == (5, 3, 8, 16) and b.state[0].dtype == jnp.float32
+    assert b.conv[0].shape == (5, 3, 96)
+    assert row_layout(cfg) == ((4, 16), True)
+    assert pool.row_bytes == token_row_bytes(cfg) == 2 * 4 * 16 * 4
+    assert pool.token_bytes == pool.row_bytes           # one paged layer
+    assert slot_state_bytes(cfg) == 3 * (3 * 8 * 16 * 4 + 3 * 96 * 4)
+    assert pool.state_bytes == 5 * slot_state_bytes(cfg)
+    # the sizing follows: a page is one layer's rows, the slots come off
+    # the top whatever the pages hold
+    assert accounting.page_bytes(cfg, 8) == 8 * pool.row_bytes
+    line = accounting.serve_waterline_gb(cfg, 9, 8, max_batch=5)
+    assert line * accounting.GB == 9 * 8 * pool.row_bytes + pool.state_bytes
+    fit = accounting.pool_capacity_pages(cfg, 8, budget_gb=1e-3,
+                                         headroom_fraction=0.0, max_batch=5)
+    assert fit == int((1e-3 * accounting.GB - pool.state_bytes)
+                      // (8 * pool.row_bytes))
+    # a block whose every layer is paged is sized as before
+    dense = T.TINY_LM
+    assert paged_layers(dense) == 4 and slot_state_bytes(dense) == 0
+    assert PagedKVPool(dense, 9, 8).bufs.state is None
+    assert PoolBuffers(k=(), v=(), k_scale=None, v_scale=None).conv is None
+    with pytest.raises(ValueError, match="n_slots >= 1"):
+        PagedKVPool(cfg, 9, 8)
+
+
+def test_a_pools_row_holds_whole_sublane_tiles_of_kv_heads():
+    """30 heads of bfloat16 are stored as 32 (two zero heads), so that a
+    page is one (page x heads, hd) slab to the paged kernels; head counts
+    that divide a tile or fill tiles stay."""
+    assert padded_kv_heads(30, jnp.bfloat16) == 32
+    assert [padded_kv_heads(n, jnp.bfloat16) for n in (1, 4, 8, 16, 32)] \
+        == [1, 4, 8, 16, 32]
+    assert padded_kv_heads(3, jnp.float32) == 8
+    # three KV heads of float32: the engine pads q, k, v and drops the rest
+    fields, cfg, params = make(seed=3, num_attention_heads=6,
+                               num_key_value_heads=3, head_dim=16)
+    assert row_layout(cfg) == ((8, 16), True)
+    prompt = np.random.default_rng(1).integers(1, 256, 19).astype(np.int32)
+    for kernel in (False, True):
+        with jax.default_matmul_precision("highest"):
+            z, bufs, _ = _serve_logits(params, cfg, prompt, 4, kernel=kernel)
+        assert bufs.k[0].shape[2:] == (8, 16)
+        toks = np.asarray(jnp.argmax(z, axis=-1))
+        seq = np.concatenate([prompt, toks[:-1]]).astype(np.int32)
+        want = R.logits_at(params, jnp.asarray(seq),
+                           jnp.asarray(18 + np.arange(4)), fields,
+                           block=len(seq))
+        np.testing.assert_allclose(z, want, atol=3e-4)
+
+
+# ------------------------------------------------------------ the refusals
+
+@pytest.mark.parametrize("kw,what", [
+    ({"kv_quant": True}, "kv_quant"),
+    ({"spec_k": 2, "draft_layers": 1}, "spec_k"),
+    ({"flash_prefill": True}, "flash_prefill"),
+    ({"disaggregate": True}, "disaggregate"),
+    ({"prefix_cache": True}, "prefix_cache"),
+    ({"mesh": "a mesh"}, "a tp mesh"),
+])
+def test_the_engine_refuses_what_is_not_built_for_the_block(model, kw, what):
+    _, cfg, params = model
+    with pytest.raises(NotImplementedError,
+                       match=f"gated delta-rule hybrid.*ServingEngine with "
+                             f"{what} is not built"):
+        ServingEngine(params, cfg, **kw)
+
+
+@pytest.mark.parametrize("name", [
+    "fsdp", "fsdp_auto", "sp", "tp", "pipeline", "moe_lm", "composable",
+    "generate", "init_cache", "layer_hook", "flops"])
+def test_training_and_the_one_shot_decoder_refuse_the_block(model, name):
+    import importlib
+    gen = importlib.import_module(
+        "distributed_training_sandbox_tpu.models.generate")
+    from distributed_training_sandbox_tpu.parallel import (
+        composable, expert, fsdp, pipeline, sequence, tensor)
+    _, cfg, params = model
+    ids = jnp.ones((1, 4), jnp.int32)
+    call = {
+        "fsdp": lambda: fsdp.make_fsdp_train_step(params, cfg, None),
+        "fsdp_auto": lambda: fsdp.make_fsdp_auto_train_step(params, cfg,
+                                                            None),
+        "sp": lambda: sequence.make_sp_train_step(params, cfg, None),
+        "tp": lambda: tensor.make_tp_train_step(params, cfg, None),
+        "pipeline": lambda: pipeline.build_transformer_pipeline(params, cfg,
+                                                                2),
+        "moe_lm": lambda: expert.make_moe_lm_train_step(params, cfg, None),
+        "composable": lambda: composable.make_composable_train_step(
+            params, None, None, model_cfg=cfg),
+        "generate": lambda: gen.generate(params, ids, cfg, max_new_tokens=2),
+        "init_cache": lambda: gen.init_cache(cfg, 1, 8),
+        "layer_hook": lambda: T.hidden_states(params, ids, cfg,
+                                              layer_hook=lambda lw: lw),
+        "flops": lambda: T.model_flops_per_token(cfg, 128),
+    }[name]
+    with pytest.raises(NotImplementedError,
+                       match="gated delta-rule hybrid.*not built for it"):
+        call()
+
+
+@pytest.mark.parametrize("over,match", [
+    ({"full_attention_interval": 0}, r"needs \['full_attention_interval'\]"),
+    ({"linear_value_head_dim": 0}, r"needs \['linear_value_head_dim'\]"),
+    ({"linear_num_value_heads": 6}, "one value head a key head"),
+    ({"linear_conv_kernel_dim": 1}, "linear_conv_kernel_dim must be >= 2"),
+    ({"linear_allow_neg_eigval": False}, "linear_allow_neg_eigval=True only"),
+    ({"nope_interval": 4}, "nope_interval=0 only"),
+    ({"tie_word_embeddings": True}, "tie_word_embeddings=False only"),
+    ({"attention_impl": "flash"}, "attention_impl='xla' only"),
+])
+def test_a_variant_the_block_does_not_build_is_refused_by_name(over, match):
+    with pytest.raises(ValueError, match=match):
+        T.TransformerConfig(**{**FIELDS, **over})
+
+
+# ------------------------------------- for a TPU, at the published widths
+
+def test_both_programs_lower_for_tpu_at_published_widths(monkeypatch):
+    """The engine's decode and prefill programs at the cell's shapes
+    (published widths, one period of four layers, bf16, 64 slots x 1,536
+    positions, a 512-row chunk), lowered FOR a TPU on this host: every
+    full-attention layer is one Mosaic call over a pool whose rows hold 32
+    heads, nothing gathers the view, and the state comes back in the shape
+    it went in."""
+    from distributed_training_sandbox_tpu.ops.flash_prefill import (
+        prefill_kernel_takes)
+    from distributed_training_sandbox_tpu.ops.paged_attention import (
+        decode_kernel_takes)
+    monkeypatch.setattr(G, "SCAN_CHUNK", 64)
+    cfg = T.TransformerConfig(
+        vocab_size=100352, hidden_size=3840, intermediate_size=11008,
+        num_hidden_layers=4, num_attention_heads=30, num_key_value_heads=30,
+        rms_norm_eps=1e-6, tie_word_embeddings=False, nope_interval=0,
+        full_attention_interval=4, linear_num_key_heads=30,
+        linear_num_value_heads=30, linear_key_head_dim=96,
+        linear_value_head_dim=192, linear_conv_kernel_dim=4,
+        linear_allow_neg_eigval=True, dtype=jnp.bfloat16, remat=False)
+    assert row_layout(cfg) == ((32, 128), True)
+    assert decode_kernel_takes(cfg.dtype, 128, 16)
+    assert prefill_kernel_takes(cfg.dtype, 128, 16, 512)
+    assert G.slot_state_bytes(cfg) == 2_211_840 + 3 * 11_520 * 2
+    B, page, P, chunk = 64, 16, 96, 512
+    sd = jax.ShapeDtypeStruct
+    params = jax.eval_shape(lambda: T.init_params(jax.random.key(0), cfg))
+    bufs = jax.eval_shape(
+        lambda: PagedKVPool(cfg, B * P + 1, page, n_slots=B).bufs)
+    assert bufs.state[0].shape == (64, 30, 96, 192) and len(bufs.k) == 1
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = E.make_serve_decode_step(cfg, paged_kernel=True).trace(
+        bufs, params, sd((B, P), jnp.int32), sd((B,), jnp.int32),
+        sd((B,), jnp.int32), sd((B,), jnp.int32), sd((B,), jnp.bool_),
+        sd((1,), jnp.int32)).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text and "_decode_float" in text
+    assert f"{B}x{P * page}x" not in text           # no gathered view
+    assert "tensor<64x30x96x192xf32>" in text
+    text = E.make_serve_prefill_step(cfg, paged_kernel=True).trace(
+        bufs, params, sd((1, P), jnp.int32), sd((1, chunk), jnp.int32),
+        sd((), jnp.int32), sd((), jnp.int32),
+        sd((), jnp.int32)).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text and "_prefill_float" in text
+    assert f"1x{P * page}x" not in text
+    assert "triangular_solve" in text               # the chunked scan
+
+
+# ------------------------------------------ the planted faults, on logits
+
+@pytest.mark.parametrize("fault", [
+    "state_in_bf16", "beta_without_its_factor_2", "conv_tail_not_carried",
+    "padding_rows_update_state"])
+def test_a_planted_fault_leaves_the_reference_on_logits(model, fault):
+    """Each fault of ``tests/benchmark/gdn_hybrid_faults.py`` moves the
+    logits of prefill-in-chunks-then-decode off the reference by more than
+    the 3e-4 the sound program is held to (37 rows: the prompt spans three
+    chunks and ends inside the last, so a lost tail and a padding row's
+    update both show; the decode faults show from the second token on)."""
+    from tests.benchmark import gdn_hybrid_faults
+    fields, cfg, params = model
+    prompt = np.random.default_rng(37).integers(1, 256, 37).astype(np.int32)
+    with gdn_hybrid_faults.FAULTS[fault][0](), \
+            jax.default_matmul_precision("highest"):
+        z, _, _ = _serve_logits(params, cfg, prompt, 7, kernel=False)
+    toks = np.asarray(jnp.argmax(z, axis=-1))
+    seq = np.concatenate([prompt, toks[:-1]]).astype(np.int32)
+    want = R.logits_at(params, jnp.asarray(seq),
+                       jnp.asarray(36 + np.arange(7)), fields, block=len(seq))
+    worst = float(jnp.max(jnp.abs(z - want)))
+    assert worst > 1e-3, worst
