@@ -325,7 +325,7 @@ def kernel_cases(cfg, seq: int):
     nq, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.resolved_head_dim)
     bf = jnp.bfloat16
-    keys = iter(jax.random.split(jax.random.PRNGKey(7), 16))
+    keys = iter(jax.random.split(jax.random.PRNGKey(7), 24))
     rnd = lambda *shape: jax.random.normal(next(keys), shape, bf)
     interpret = jax.default_backend() != "tpu"
 
@@ -367,6 +367,28 @@ def kernel_cases(cfg, seq: int):
     paged = lambda fn, q: lambda: jax.jit(
         lambda qg, apos: fn(qg, pk, pv, pages, apos, probs_dtype=bf))(*q)
 
+    # the gated delta rule's decode step at Olmo-Hybrid-7B's widths (30
+    # heads x 96 x 192, whatever ``cfg`` is), eight state slots of which
+    # five are live; the step's output and the new states, side by side
+    from distributed_training_sandbox_tpu.models import gdn_hybrid as G
+    from distributed_training_sandbox_tpu.ops.gdn_step import gdn_decode_step
+    n, dk, dv = 30, 96, 192
+    f32 = lambda *shape: jax.random.normal(next(keys), shape, jnp.float32)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    live = (jnp.arange(8) % 3 != 1)[:, None]
+    step_args = (unit(f32(8, n, dk)) * dk ** -0.5, unit(f32(8, n, dk)),
+                 f32(8, n, dv),
+                 jnp.where(live, -jnp.abs(f32(8, n)) - 0.01, 0.0),
+                 jnp.where(live, 2 * jax.nn.sigmoid(f32(8, n)), 0.0),
+                 f32(8, dk, n * dv))
+
+    def step(fn):
+        def run():      # the kernel gives a dead slot o = 0
+            o, s = jax.jit(fn)(*step_args)
+            return jnp.concatenate(
+                [jnp.where(live[..., None], o, 0.0).ravel(), s.ravel()])
+        return run
+
     return {
         "splash attention": (attention(T._attention_flash),
                              attention(T._attention_xla)),
@@ -381,6 +403,7 @@ def kernel_cases(cfg, seq: int):
                          paged(paged_attention_xla, q_dec)),
         "flash prefill": (paged(paged_flash_prefill, q_pre),
                           paged(paged_attention_xla, q_pre)),
+        "gdn decode step": (step(gdn_decode_step), step(G.recurrent_step)),
     }
 
 
@@ -396,8 +419,9 @@ def kernels_phase(cfg, seq: int) -> None:
     how = "compiled" if on_tpu else "interpreted on cpu"
     # the engine's decode and prefill programs take the paged kernels by
     # default on a TPU at the smoke's pool geometry and chunk
-    # (ServingEngine.paged_kernel=None)
-    default_path = {"paged decode", "flash prefill"}
+    # (ServingEngine.paged_kernel=None), and the hybrid block's engine the
+    # step kernel at its widths
+    default_path = {"paged decode", "flash prefill", "gdn decode step"}
     if cfg.attention_impl == "flash":
         default_path.add("splash attention")
     # every output is bf16 (or f32 from bf16 probabilities): agreement to

@@ -48,6 +48,15 @@ agree: :func:`recurrent_step` (one token: decode), :func:`chunked_scan`
 built on the second from a zero state, the whole-sequence form of
 :func:`hidden_states`.
 
+A state AT REST, in the engine's slots, is stored lane-dense
+(:func:`slot_shape`, :func:`pack_state`): ``(dk, n * dv)``, the heads'
+value dims side by side, so that at the published widths a slot is whole
+(8, 128) tiles (96 x 5,760) where ``(n, dk, dv)`` pads every row of 192 to
+256 lanes.  :func:`recurrent_step` takes and returns that layout (a decode
+step touches every slot in place and converts none); the scan keeps
+``(n, dk, dv)`` and the engine converts the one slot a prefill chunk
+carries at the chunk's boundary.
+
 The chunked scan, per sub-chunk of ``SCAN_CHUNK`` rows from the carried
 state ``S_0`` (``g_t = log alpha_t``, ``G_i = exp(sum_{t<=i} g_t)``): with
 ``u_i = beta_i (v_i - alpha_i S_{i-1}^T k_i)`` the recurrence is ``S_i =
@@ -75,6 +84,7 @@ Every layer holds ``post_attn_norm``, ``post_mlp_norm``, ``w_gate``,
 
 from __future__ import annotations
 
+import contextlib
 import math
 
 import jax
@@ -160,6 +170,25 @@ def state_shape(cfg) -> tuple[int, int, int]:
     """One slot's recurrent state in one linear layer (float32)."""
     return (cfg.linear_num_key_heads, cfg.linear_key_head_dim,
             cfg.linear_value_head_dim)
+
+
+def slot_shape(cfg) -> tuple[int, int]:
+    """One slot's recurrent state in one linear layer AS STORED: ``(dk,
+    n * dv)`` float32 (module docstring: lane-dense at rest)."""
+    n, dk, dv = state_shape(cfg)
+    return (dk, n * dv)
+
+
+def pack_state(s):
+    """(B, n, dk, dv) -> the stored layout (B, dk, n * dv)."""
+    B, n, dk, dv = s.shape
+    return s.transpose(0, 2, 1, 3).reshape(B, dk, n * dv)
+
+
+def unpack_state(s, n: int):
+    """The stored layout (B, dk, n * dv) -> (B, n, dk, dv)."""
+    B, dk, width = s.shape
+    return s.reshape(B, dk, n, width // n).transpose(0, 2, 1, 3)
 
 
 def tail_shape(cfg) -> tuple[int, int]:
@@ -288,20 +317,64 @@ def linear_inputs(x, layer, tail, valid, *, cfg):
         new_tail
 
 
+_STEP_KERNEL = False
+
+
+@contextlib.contextmanager
+def step_kernel(on: bool):
+    """While tracing inside this context, :func:`recurrent_step` is the
+    Pallas step kernel (``ops/gdn_step.py``) when ``on``: how the engine's
+    decode program says which form it was built with, the function's six
+    arguments being all it may take (a planted fault wraps it by that
+    signature)."""
+    global _STEP_KERNEL
+    old, _STEP_KERNEL = _STEP_KERNEL, bool(on)
+    try:
+        yield
+    finally:
+        _STEP_KERNEL = old
+
+
+def step_kernel_engages(n: int, dk: int, dv: int) -> bool:
+    """Whether a decode step over states of ``state_shape`` ``(n, dk, dv)``
+    that asks for the step kernel gets it: on a TPU for the shapes it
+    compiles for, elsewhere always (interpreted).  The engine resolves its
+    counter with this and :func:`recurrent_step` its form."""
+    from ..ops.gdn_step import step_kernel_takes
+    return jax.default_backend() != "tpu" or step_kernel_takes(n, dk, dv)
+
+
 def recurrent_step(q, k, v, g, beta, state):
     """One token of the recurrence for every slot: q, k (B, n, dk), v
-    (B, n, dv), g, beta (B, n), ``state`` (B, n, dk, dv) float32.  Returns
-    ``o`` (B, n, dv) and the new state.  Elementwise products and sums in
+    (B, n, dv), g, beta (B, n), ``state`` (B, dk, n * dv) float32, the
+    slots as stored (:func:`slot_shape`).  Returns ``o`` (B, n, dv) and
+    the new state in the same layout.  Elementwise products and sums in
     float32: a state is read as it is stored, never rounded for an MXU
     pass.  ``beta = 0, g = 0`` leaves a state bit for bit as it was.
-    (Written the other way round, ``S^T k`` and ``S^T q`` from one pass
-    over the OLD state and ``o = alpha S^T q + (k . q) u``, XLA built two
-    fusions a layer as asked and the step got slower on the v5e, 7.89
-    against 7.46 ms: PERF.md, PR 30.)"""
-    s = jnp.exp(g)[..., None, None] * state
-    u = beta[..., None] * (v - jnp.sum(k[..., :, None] * s, axis=-2))
-    s = s + k[..., :, None] * u[..., None, :]
-    return jnp.sum(q[..., :, None] * s, axis=-2), s
+
+    Two forms.  Inside :func:`step_kernel` (the engine's decode program
+    on a TPU) the Pallas kernel of ``ops/gdn_step.py``: every slot with a
+    non-zero ``g`` or ``beta`` read once and written once in place, the
+    others not touched (their ``o`` is 0).  Otherwise this XLA form, the
+    tests' reference, which passes over every slot three times (read for
+    ``S^T k``, read and write for the update) and, on a TPU, first writes
+    out k, q and alpha spread over the state's lanes: 25.8 ms a step on
+    the v5e at 64 slots of the published widths against the kernel's 4.1
+    (PR 30's form over ``(n, dk, dv)`` took 7.5 and a hand-ordered one,
+    both reductions from one pass over the OLD state, 7.89: PERF.md)."""
+    B, n, dk = q.shape
+    dv = v.shape[-1]
+    if _STEP_KERNEL and step_kernel_engages(n, dk, dv):
+        from ..ops.gdn_step import gdn_decode_step
+        return gdn_decode_step(q, k, v, g, beta, state)
+    # every state-sized operand in the stored layout (B, dk, n dv): a
+    # head's scalar repeated over its dv lanes, its key vector down dk
+    lanes = lambda a: jnp.repeat(a, dv, axis=-1)  # noqa: E731
+    col = lambda a: lanes(a.transpose(0, 2, 1))  # noqa: E731
+    s = lanes(jnp.exp(g))[:, None] * state
+    u = lanes(beta) * (v.reshape(B, -1) - jnp.sum(col(k) * s, axis=1))
+    s = s + col(k) * u[:, None]
+    return jnp.sum(col(q) * s, axis=1).reshape(B, n, dv), s
 
 
 def chunked_scan(q, k, v, g, beta, state):

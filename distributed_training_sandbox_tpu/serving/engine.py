@@ -360,17 +360,22 @@ def _paged_hybrid_forward(params, ids, cfg, bufs: PoolBuffers, pages,
     over a row that may end in zero heads (``kv_pool.padded_kv_heads``).
 
     A LINEAR layer reads and writes its STATE SLOTS ``bufs.state[j]``
-    (n_slots, n, dk, dv) float32 and ``bufs.conv[j]`` (n_slots, K - 1, C),
-    ``j`` counting the linear layers only:
+    (n_slots, dk, n * dv) float32, lane-dense (``gdn_hybrid.slot_shape``),
+    and ``bufs.conv[j]`` (n_slots, K - 1, C), ``j`` counting the linear
+    layers only:
 
       * a decode step (``slot`` None; row ``b`` of x IS slot ``b``) runs
-        ``gdn_hybrid.recurrent_step`` on every slot's state in place; a
-        row with ``valid`` False has beta = 0 and alpha = 1 and leaves its
-        state and its tail bit-unchanged;
+        ``gdn_hybrid.recurrent_step`` on the slots as they are stored, in
+        place; a row with ``valid`` False has beta = 0 and alpha = 1 and
+        leaves its state and its tail bit-unchanged.  With
+        ``paged_kernel`` the step is the Pallas kernel of
+        ``ops/gdn_step.py`` (``gdn_hybrid.step_kernel``), which neither
+        reads nor writes such a slot;
       * a prefill chunk of one request (``slot`` () int32; x is (1, C, H))
-        takes the slot's state and tail, or ZEROS when the chunk is the
-        request's first (``apos[0, 0] == 0``: a granted slot never
-        inherits what its last request left), runs
+        takes the slot's state (unpacked to ``(1, n, dk, dv)``: the one
+        conversion, 2.2 MB a layer a chunk) and tail, or ZEROS when the
+        chunk is the request's first (``apos[0, 0] == 0``: a granted slot
+        never inherits what its last request left), runs
         ``gdn_hybrid.chunked_scan`` over the chunk and writes both back;
         rows past the prompt's end change neither.
 
@@ -416,7 +421,9 @@ def _paged_hybrid_forward(params, ids, cfg, bufs: PoolBuffers, pages,
             if decode:
                 s0, t0 = states[j], tails[j]
             else:
-                s0 = jax.lax.dynamic_slice_in_dim(states[j], slot, 1)
+                s0 = G.unpack_state(
+                    jax.lax.dynamic_slice_in_dim(states[j], slot, 1),
+                    cfg.linear_num_key_heads)
                 t0 = jax.lax.dynamic_slice_in_dim(tails[j], slot, 1)
                 s0 = jnp.where(fresh, jnp.zeros_like(s0), s0)
                 t0 = jnp.where(fresh, jnp.zeros_like(t0), t0)
@@ -425,7 +432,7 @@ def _paged_hybrid_forward(params, ids, cfg, bufs: PoolBuffers, pages,
                     x, layer, t0, valid, cfg=cfg)
             with scope("attn_core"):
                 if decode:
-                    with scope("lin_step"):
+                    with scope("lin_step"), G.step_kernel(paged_kernel):
                         o, s1 = G.recurrent_step(
                             q[:, 0], k[:, 0], v[:, 0], g[:, 0],
                             beta[:, 0], s0)
@@ -437,7 +444,7 @@ def _paged_hybrid_forward(params, ids, cfg, bufs: PoolBuffers, pages,
                 states[j], tails[j] = s1, t1
             else:
                 states[j] = jax.lax.dynamic_update_slice_in_dim(
-                    states[j], s1, slot, axis=0)
+                    states[j], G.pack_state(s1), slot, axis=0)
                 tails[j] = jax.lax.dynamic_update_slice_in_dim(
                     tails[j], t1.astype(tails[j].dtype), slot, axis=0)
             with scope("attn_out"):
@@ -850,6 +857,12 @@ class ServingEngine:
             self.prefill_kernel = prefill_kernel_takes(
                 self.cfg.dtype, self.cfg.resolved_head_dim,
                 self.page_size, self.prefill_chunk)
+        # whether the gated delta rule's decode step is the Pallas step
+        # kernel (ops/gdn_step.py): as decode resolved, for the shapes it
+        # takes
+        self.lin_step_kernel = bool(
+            self.cfg.gdn_hybrid and self.paged_kernel
+            and G.step_kernel_engages(*G.state_shape(self.cfg)))
         self.spec_k = int(spec_k)
         if self.flash_prefill and kv_quant:
             raise ValueError("the flash prefill kernel is float-only — "
@@ -1120,13 +1133,16 @@ class ServingEngine:
         # layers x steps.  The gated delta-rule hybrid: the live states a
         # step read and wrote (``state_slot_steps``); its other two
         # counters are the host's (slots reset at a grant, valid rows the
-        # prefill chunks scanned)
+        # prefill chunks scanned), as is ``lin_step_inplace_steps``: decode
+        # steps whose recurrence was the step kernel, which moves a live
+        # state once in and once out in place and no other
         self._device_counters: tuple = ()
         if self.cfg.mla_moe:
             self._device_counters = M.COUNTERS
         elif self.cfg.gdn_hybrid:
             self._device_counters = G.COUNTERS[:1]
-            self.stats.update(dict.fromkeys(G.COUNTERS[1:], 0))
+            self.stats.update(dict.fromkeys(
+                G.COUNTERS[1:] + ("lin_step_inplace_steps",), 0))
         self._counted_zero = None
         if self._device_counters:
             self.stats.update(dict.fromkeys(self._device_counters, 0))
@@ -1472,6 +1488,8 @@ class ServingEngine:
             self.stats["decode_steps"] += sync
             if self.paged_kernel:
                 self.stats["decode_inplace_steps"] += sync
+            if self.lin_step_kernel:
+                self.stats["lin_step_inplace_steps"] += sync
         mats = self._sync_burst(step_tokens + counted)
         if counted:
             for name, count in zip(self._device_counters, mats.pop()):

@@ -40,8 +40,12 @@ full-attention layers cache rows (:func:`paged_layers`); a linear layer
 keeps, per REQUEST and whatever its length, one float32 state matrix and a
 conv tail.  Those live beside the pages in the same :class:`PoolBuffers`
 as fixed-size STATE SLOTS indexed by the batch slot the scheduler grants:
-``state`` ``(n_slots, heads, key dim, value dim)`` and ``conv``
-``(n_slots, K - 1, channels)`` per linear layer.  A slot's state never
+``state`` ``(n_slots, key dim, heads * value dim)`` float32, lane-dense
+(``gdn_hybrid.slot_shape``: at the published widths 96 x 5,760, whole
+(8, 128) tiles, so that a slot occupies its 2,211,840 bytes and a kernel's
+block copy moves no padding; ``(heads, 96, 192)`` held every row of 192 in
+256 lanes, 2,949,120 bytes), and ``conv`` ``(n_slots, K - 1, channels)``
+per linear layer.  A slot's state never
 survives its request: the first prefill chunk of the next one starts from
 zeros whatever the slot held (``engine._prefill_core``).
 """
@@ -134,7 +138,7 @@ class PoolBuffers(NamedTuple):
     v: tuple | None
     k_scale: tuple | None   # L × (n_pages, page_size, n_kv, 1) f32
     v_scale: tuple | None
-    state: tuple | None = None  # (n_slots, heads, dk, dv) f32 a linear layer
+    state: tuple | None = None  # (n_slots, dk, heads * dv) f32 a linear layer
     conv: tuple | None = None   # (n_slots, K - 1, channels) a linear layer
 
 
@@ -438,7 +442,7 @@ class PagedKVPool:
         if self.n_slots:
             from ..models import gdn_hybrid as G
             n_lin = len(G.linear_layers(cfg))
-            state = tuple(put(jnp.zeros((self.n_slots,) + G.state_shape(cfg),
+            state = tuple(put(jnp.zeros((self.n_slots,) + G.slot_shape(cfg),
                                         jnp.float32)) for _ in range(n_lin))
             conv = tuple(put(jnp.zeros((self.n_slots,) + G.tail_shape(cfg),
                                        cfg.dtype)) for _ in range(n_lin))

@@ -7,6 +7,7 @@ inactive, untouched by padding rows), the pool's layout and sizing, what
 is refused by name, and both engine programs lowered for a TPU at the
 published widths."""
 
+import math
 import sys
 from pathlib import Path
 
@@ -87,12 +88,14 @@ def _recurrence_inputs(seed, B=2, S=13, n=3, dk=8, dv=16):
 
 
 def _token_by_token(q, k, v, g, beta, s):
-    out = []
+    """``recurrent_step`` row by row; it takes the state as the slots
+    store it, (B, dk, n dv)."""
+    out, s = [], G.pack_state(s)
     for t in range(q.shape[1]):
         o, s = G.recurrent_step(q[:, t], k[:, t], v[:, t], g[:, t],
                                 beta[:, t], s)
         out.append(o)
-    return jnp.stack(out, axis=1), s
+    return jnp.stack(out, axis=1), G.unpack_state(s, q.shape[2])
 
 
 @pytest.mark.parametrize("rows", [13, 16, 1, 5])
@@ -121,9 +124,11 @@ def test_rows_past_the_end_change_no_state():
         _, s9 = G.chunked_scan(q[:, :9], k[:, :9], v[:, :9], g[:, :9],
                                beta[:, :9], s0)
         _, s_step = G.recurrent_step(q[:, 12], k[:, 12], v[:, 12],
-                                     g[:, 12], beta[:, 12], s0)
+                                     g[:, 12], beta[:, 12],
+                                     G.pack_state(s0))
     np.testing.assert_allclose(s, s9, atol=1e-6)
-    assert np.array_equal(np.asarray(s_step), np.asarray(s0))   # bitwise
+    assert np.array_equal(np.asarray(s_step),
+                          np.asarray(G.pack_state(s0)))         # bitwise
 
 
 def test_the_conv_carries_its_tail_and_stops_at_the_last_valid_row():
@@ -244,7 +249,9 @@ def test_engine_prefill_then_decode_is_the_reference_on_logits(
     assert live == n_new - 1        # one live state a decode step
 
 
-def test_a_slots_first_chunk_starts_from_zeros_whatever_it_held(model):
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernels"])
+def test_a_slots_first_chunk_starts_from_zeros_whatever_it_held(model,
+                                                                kernel):
     """State reset at grant: the same request through a pool whose slots
     hold garbage (another request's leftovers) gives bitwise the logits a
     fresh pool gives, and leaves the OTHER slots' garbage bit-unchanged."""
@@ -256,8 +263,8 @@ def test_a_slots_first_chunk_starts_from_zeros_whatever_it_held(model):
                     for i, s in enumerate(pool.bufs.state)),
         conv=tuple(jax.random.normal(jax.random.key(9 + i), c.shape)
                    for i, c in enumerate(pool.bufs.conv)))
-    z0, _, _ = _serve_logits(params, cfg, prompt, 4, kernel=False)
-    z1, after, _ = _serve_logits(params, cfg, prompt, 4, kernel=False,
+    z0, _, _ = _serve_logits(params, cfg, prompt, 4, kernel=kernel)
+    z1, after, _ = _serve_logits(params, cfg, prompt, 4, kernel=kernel,
                                  bufs=dirty)
     np.testing.assert_array_equal(z0, z1)
     for before, now in zip(dirty.state + dirty.conv,
@@ -294,6 +301,10 @@ def test_a_reused_slot_serves_what_a_fresh_engine_serves(model):
                                              else 0)
         assert s["prefill_inplace_chunks"] == (s["prefill_chunks"] if kernel
                                                else 0)
+        # ... and whose recurrence was the step kernel (ops/gdn_step.py)
+        assert eng.lin_step_kernel is kernel
+        assert s["lin_step_inplace_steps"] == (s["decode_steps"] if kernel
+                                               else 0)
         assert eng.retraces_after_warmup() == 0
         rep = eng.slo_report()["pool"]
         assert rep["bytes_per_token"] == 1 * 2 * 4 * 16 * 4   # ONE full layer
@@ -314,10 +325,12 @@ def test_a_reused_slot_serves_what_a_fresh_engine_serves(model):
         assert list(np.asarray(jnp.argmax(z, -1))) == toks
 
 
-def test_an_inactive_slots_state_is_bit_unchanged_by_a_burst(model):
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernels"])
+def test_an_inactive_slots_state_is_bit_unchanged_by_a_burst(model, kernel):
     """A decode burst over three slots of which one is active: the other
     two slots' state and conv tail come back bit for bit, and the active
-    one's moved."""
+    one's moved.  In the XLA form because alpha = 1 and beta = 0 there; in
+    the step kernel because it does not visit them."""
     _, cfg, params = model
     pool, P = _pool(cfg, 3)
     bufs = pool.bufs._replace(
@@ -328,7 +341,7 @@ def test_an_inactive_slots_state_is_bit_unchanged_by_a_burst(model):
     before = jax.tree.map(np.asarray, (bufs.state, bufs.conv))
     pages = np.zeros((3, P), np.int32)
     pages[2] = pool.allocator.alloc(P)
-    step = E.make_serve_decode_step(cfg, paged_kernel=False)
+    step = E.make_serve_decode_step(cfg, paged_kernel=kernel)
     toks, lengths = jnp.array([3, 4, 5], jnp.int32), jnp.array([0, 0, 9])
     stop, active = jnp.array([0, 0, 40]), jnp.array([False, False, True])
     ctr = jnp.zeros((1,), jnp.int32)
@@ -351,7 +364,8 @@ def test_the_pool_has_pages_for_the_full_layers_only_and_slots_beside(model):
     assert len(b.k) == len(b.v) == 1 == paged_layers(cfg)       # of 4 layers
     assert b.k[0].shape == (9, 8, 4, 16) and b.k_scale is None
     assert len(b.state) == len(b.conv) == 3
-    assert b.state[0].shape == (5, 3, 8, 16) and b.state[0].dtype == jnp.float32
+    assert b.state[0].shape == (5, 8, 3 * 16) == (5,) + G.slot_shape(cfg)
+    assert b.state[0].dtype == jnp.float32
     assert b.conv[0].shape == (5, 3, 96)
     assert row_layout(cfg) == ((4, 16), True)
     assert pool.row_bytes == token_row_bytes(cfg) == 2 * 4 * 16 * 4
@@ -469,17 +483,11 @@ def test_a_variant_the_block_does_not_build_is_refused_by_name(over, match):
 
 # ------------------------------------- for a TPU, at the published widths
 
-def test_both_programs_lower_for_tpu_at_published_widths(monkeypatch):
-    """The engine's decode and prefill programs at the cell's shapes
-    (published widths, one period of four layers, bf16, 64 slots x 1,536
-    positions, a 512-row chunk), lowered FOR a TPU on this host: every
-    full-attention layer is one Mosaic call over a pool whose rows hold 32
-    heads, nothing gathers the view, and the state comes back in the shape
-    it went in."""
-    from distributed_training_sandbox_tpu.ops.flash_prefill import (
-        prefill_kernel_takes)
-    from distributed_training_sandbox_tpu.ops.paged_attention import (
-        decode_kernel_takes)
+def _published(monkeypatch, B=64, page=16, P=96):
+    """The cell's shapes (published widths, one period of four layers,
+    bf16, 64 slots x 1,536 positions), as shapes only, with the process
+    made to look like a TPU's: the engine's programs then resolve their
+    kernels as on the chip."""
     monkeypatch.setattr(G, "SCAN_CHUNK", 64)
     cfg = T.TransformerConfig(
         vocab_size=100352, hidden_size=3840, intermediate_size=11008,
@@ -489,31 +497,127 @@ def test_both_programs_lower_for_tpu_at_published_widths(monkeypatch):
         linear_num_value_heads=30, linear_key_head_dim=96,
         linear_value_head_dim=192, linear_conv_kernel_dim=4,
         linear_allow_neg_eigval=True, dtype=jnp.bfloat16, remat=False)
-    assert row_layout(cfg) == ((32, 128), True)
-    assert decode_kernel_takes(cfg.dtype, 128, 16)
-    assert prefill_kernel_takes(cfg.dtype, 128, 16, 512)
-    assert G.slot_state_bytes(cfg) == 2_211_840 + 3 * 11_520 * 2
-    B, page, P, chunk = 64, 16, 96, 512
     sd = jax.ShapeDtypeStruct
     params = jax.eval_shape(lambda: T.init_params(jax.random.key(0), cfg))
     bufs = jax.eval_shape(
         lambda: PagedKVPool(cfg, B * P + 1, page, n_slots=B).bufs)
-    assert bufs.state[0].shape == (64, 30, 96, 192) and len(bufs.k) == 1
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    text = E.make_serve_decode_step(cfg, paged_kernel=True).trace(
-        bufs, params, sd((B, P), jnp.int32), sd((B,), jnp.int32),
-        sd((B,), jnp.int32), sd((B,), jnp.int32), sd((B,), jnp.bool_),
-        sd((1,), jnp.int32)).lower(lowering_platforms=("tpu",)).as_text()
+    decode_args = (bufs, params, sd((B, P), jnp.int32), sd((B,), jnp.int32),
+                   sd((B,), jnp.int32), sd((B,), jnp.int32),
+                   sd((B,), jnp.bool_), sd((1,), jnp.int32))
+    return cfg, params, bufs, decode_args
+
+
+def _lowered_for_tpu(step, args) -> str:
+    return step.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+
+
+def test_both_programs_lower_for_tpu_at_published_widths(monkeypatch):
+    """The engine's decode and prefill programs at the cell's shapes
+    (a 512-row chunk), lowered FOR a TPU on this host: every
+    full-attention layer is one Mosaic call over a pool whose rows hold 32
+    heads, nothing gathers the view; every linear layer's decode step is
+    one Mosaic call under ``lin_step`` on the state as it is stored, in
+    place, and no XLA reduction passes over the state; the state comes
+    back in the shape it went in."""
+    from distributed_training_sandbox_tpu.ops.flash_prefill import (
+        prefill_kernel_takes)
+    from distributed_training_sandbox_tpu.ops.gdn_step import (
+        step_kernel_takes)
+    from distributed_training_sandbox_tpu.ops.paged_attention import (
+        decode_kernel_takes)
+    B, page, P, chunk = 64, 16, 96, 512
+    cfg, params, bufs, decode_args = _published(monkeypatch, B, page, P)
+    assert row_layout(cfg) == ((32, 128), True)
+    assert decode_kernel_takes(cfg.dtype, 128, 16)
+    assert prefill_kernel_takes(cfg.dtype, 128, 16, 512)
+    assert step_kernel_takes(*G.state_shape(cfg))
+    assert G.slot_state_bytes(cfg) == 2_211_840 + 3 * 11_520 * 2
+    assert bufs.state[0].shape == (64, 96, 5760) and len(bufs.k) == 1
+    sd = jax.ShapeDtypeStruct
+    text = _lowered_for_tpu(
+        E.make_serve_decode_step(cfg, paged_kernel=True), decode_args)
     assert "tpu_custom_call" in text and "_decode_float" in text
     assert f"{B}x{P * page}x" not in text           # no gathered view
-    assert "tensor<64x30x96x192xf32>" in text
-    text = E.make_serve_prefill_step(cfg, paged_kernel=True).trace(
-        bufs, params, sd((1, P), jnp.int32), sd((1, chunk), jnp.int32),
-        sd((), jnp.int32), sd((), jnp.int32),
-        sd((), jnp.int32)).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tensor<64x96x5760xf32>" in text
+    # one call of the step kernel a linear layer, each aliasing the state
+    # it is given; the XLA form's state-sized operands (k, q, alpha spread
+    # over 64 x 96 x (30 x 192)) and its reductions over them nowhere
+    assert text.count("call @_step(") == 3 == len(G.linear_layers(cfg))
+    assert text.count('kernel_name = "_step_kernel"') == 1   # one lowering
+    assert "output_tuple_indices = [1], operand_index = 4" in text
+    assert "64x96x30x192" not in text
+    # the program that does not ask for the kernels keeps the XLA form
+    xla = _lowered_for_tpu(
+        E.make_serve_decode_step(cfg, paged_kernel=False), decode_args)
+    assert "_step_kernel" not in xla and "64x96x30x192" in xla
+    text = _lowered_for_tpu(
+        E.make_serve_prefill_step(cfg, paged_kernel=True),
+        (bufs, params, sd((1, P), jnp.int32), sd((1, chunk), jnp.int32),
+         sd((), jnp.int32), sd((), jnp.int32), sd((), jnp.int32)))
     assert "tpu_custom_call" in text and "_prefill_float" in text
     assert f"1x{P * page}x" not in text
     assert "triangular_solve" in text               # the chunked scan
+    assert "_step_kernel" not in text               # the scan keeps XLA
+    assert "tensor<64x96x5760xf32>" in text
+
+
+def _tiled_bytes(shape, dtype) -> int:
+    """Bytes a TPU holds an array in: its two minor dims in tiles of 128
+    lanes by 8 rows of 32 bits (16 rows of bfloat16)."""
+    item = jnp.dtype(dtype).itemsize
+    *lead, rows, lanes = shape
+    sub = 8 * 4 // item
+    return math.prod(lead) * (rows + -rows % sub) * (lanes + -lanes % 128) \
+        * item
+
+
+def test_the_state_at_rest_is_unpadded_and_counted_as_it_is_held(
+        monkeypatch):
+    """At the published widths a stored state is whole (8, 128) tiles, so
+    the device holds exactly the 2,211,840 bytes a slot a layer that
+    ``PagedKVPool.state_bytes`` and ``accounting.serve_waterline_gb``
+    count (PR 30's ``(30, 96, 192)`` took 2,949,120: every row of 192 in
+    256 lanes); the pool's account is the sum of its buffers' bytes."""
+    B, page, P = 64, 16, 96
+    cfg, _, bufs, _ = _published(monkeypatch, B, page, P)
+    for st in bufs.state:
+        assert st.dtype == jnp.float32 and st.shape == (B,) + G.slot_shape(cfg)
+        assert st.shape[-1] % 128 == 0 and st.shape[-2] % 8 == 0
+        assert _tiled_bytes(st.shape, st.dtype) == st.size * 4 \
+            == B * 2_211_840
+    assert _tiled_bytes((B,) + G.state_shape(cfg), jnp.float32) \
+        == B * 2_949_120
+    nbytes = lambda arrs: sum(a.size * a.dtype.itemsize for a in arrs)  # noqa: E731
+    pool = PagedKVPool.__new__(PagedKVPool)      # the account, no buffers
+    pool.cfg, pool.n_slots, pool.kv_quant = cfg, B, False
+    assert pool.state_bytes == nbytes(bufs.state + bufs.conv)
+    assert pool.state_bytes == B * slot_state_bytes(cfg)
+    n_pages = B * P + 1
+    line = accounting.serve_waterline_gb(cfg, n_pages, page, max_batch=B)
+    assert line * accounting.GB == nbytes(bufs.k + bufs.v + bufs.state
+                                          + bufs.conv)
+
+
+def test_the_bf16_state_fault_reaches_the_decode_program_with_the_kernel_on(
+        monkeypatch):
+    """``state_in_bf16`` of ``tests/benchmark/gdn_hybrid_faults.py`` wraps
+    ``gdn_hybrid.recurrent_step`` by name, six arguments; the engine's
+    decode program looks that name up when it is traced, kernel or not.
+    Lowered for a TPU with the step kernel on, the faulty program differs
+    from the sound one and rounds what the kernel returns, so the chip's
+    probe of that fault cannot go silent."""
+    from tests.benchmark import gdn_hybrid_faults
+    cfg, _, _, decode_args = _published(monkeypatch)
+    sound = _lowered_for_tpu(
+        E.make_serve_decode_step(cfg, paged_kernel=True), decode_args)
+    with gdn_hybrid_faults.FAULTS["state_in_bf16"][0]():
+        faulty = _lowered_for_tpu(
+            E.make_serve_decode_step(cfg, paged_kernel=True), decode_args)
+    assert "reduce_precision" not in sound
+    assert faulty.count("reduce_precision") == 3    # a linear layer each
+    assert faulty.count("call @_step(") == sound.count("call @_step(") == 3
+    assert faulty != sound
 
 
 # ------------------------------------------ the planted faults, on logits

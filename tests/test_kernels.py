@@ -600,6 +600,148 @@ def test_prefill_kernel_takes(dtype, head_dim, page, chunk, takes):
     assert prefill_kernel_takes(dtype, head_dim, page, chunk) is takes
 
 
+# --------------------------- the gated delta rule's decode-step kernel
+
+#: name -> (slots, heads, key dim, value dim): the tests' rehearsal dims
+#: (a group is all three heads and ends inside a lane tile), sixteen
+#: slots (two row blocks) and twelve (the rows padded up to two), and
+#: slots of the published dims (head pairs of 384 lanes, fifteen to a slot)
+_STEP_GEOMETRY = {"rehearsal": (5, 3, 8, 16), "two-row-blocks": (16, 3, 8, 16),
+                  "a-block-and-a-half": (12, 3, 8, 16),
+                  "published-slot": (2, 30, 96, 192)}
+_STEP_LIVE = {"all": lambda B: np.ones(B, bool),
+              "some": lambda B: np.arange(B) % 3 == 1,
+              "last-only": lambda B: np.arange(B) == B - 1,
+              "none": lambda B: np.zeros(B, bool)}
+
+
+def _step_inputs(seed, B, n, dk, dv, live, steps=1):
+    """What ``gdn_hybrid.linear_inputs`` hands a decode step: unit k, q
+    scaled, g < 0 and beta in (0, 2) where ``live``, both 0 elsewhere; and
+    a NON-zero stored state (B, dk, n dv)."""
+    ks = jax.random.split(jax.random.key(seed), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(ks[0], (steps, B, n, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(ks[1], (steps, B, n, dk)))
+    v = jax.random.normal(ks[2], (steps, B, n, dv))
+    g = -jax.random.uniform(ks[3], (steps, B, n), minval=0.01, maxval=2.0)
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (steps, B, n)))
+    keep = jnp.asarray(live)[None, :, None]
+    return (q, k, v, jnp.where(keep, g, 0.0), jnp.where(keep, beta, 0.0),
+            jax.random.normal(ks[5], (B, dk, n * dv)))
+
+
+@pytest.mark.parametrize("live", list(_STEP_LIVE))
+@pytest.mark.parametrize("geometry", list(_STEP_GEOMETRY))
+def test_gdn_step_kernel_is_the_xla_step_on_live_slots(geometry, live):
+    """The Pallas step kernel against ``gdn_hybrid.recurrent_step``'s XLA
+    form from a non-zero state: ``o`` and the new state of a live slot to
+    float32 summation order (the kernel sums a head's 8 or 96 products in
+    another order), a dead slot's state bit for bit what it was and its
+    ``o`` zero."""
+    from distributed_training_sandbox_tpu.models import gdn_hybrid as G
+    from distributed_training_sandbox_tpu.ops.gdn_step import gdn_decode_step
+    B, n, dk, dv = _STEP_GEOMETRY[geometry]
+    mask = _STEP_LIVE[live](B)
+    q, k, v, g, beta, s0 = _step_inputs(7, B, n, dk, dv, mask)
+    args = (q[0], k[0], v[0], g[0], beta[0], s0)
+    o_want, s_want = G.recurrent_step(*args)
+    o, s = gdn_decode_step(*args, interpret=INTERP)
+    assert o.shape == (B, n, dv) and s.shape == s0.shape
+    np.testing.assert_allclose(
+        o, jnp.where(jnp.asarray(mask)[:, None, None], o_want, 0.0),
+        atol=2e-6)
+    np.testing.assert_allclose(s, s_want, atol=2e-6)
+    np.testing.assert_array_equal(np.asarray(s)[~mask],
+                                  np.asarray(s0)[~mask])
+    if mask.any():
+        assert not np.array_equal(np.asarray(s)[mask], np.asarray(s0)[mask])
+
+
+def test_gdn_step_kernel_chained_stays_inside_float32_rounding():
+    """Eight steps chained through the kernel and through the XLA form,
+    slots of the published dims of which one is live throughout, one
+    joins at the fifth step and one never: outputs and states stay within
+    float32 rounding of each other (states of size ~1)."""
+    from distributed_training_sandbox_tpu.models import gdn_hybrid as G
+    from distributed_training_sandbox_tpu.ops.gdn_step import gdn_decode_step
+    B, n, dk, dv = 3, 30, 96, 192
+    q, k, v, g, beta, s0 = _step_inputs(11, B, n, dk, dv, np.ones(B, bool),
+                                        steps=8)
+    late = (jnp.arange(8) >= 4)[:, None]
+    on = jnp.stack([jnp.ones((8, 1), bool), late, jnp.zeros((8, 1), bool)],
+                   axis=1)                                  # (8, B, 1)
+    g, beta = jnp.where(on, g, 0.0), jnp.where(on, beta, 0.0)
+    s_k = s_x = s0
+    for t in range(8):
+        o_x, s_x = G.recurrent_step(q[t], k[t], v[t], g[t], beta[t], s_x)
+        o_k, s_k = gdn_decode_step(q[t], k[t], v[t], g[t], beta[t], s_k,
+                                   interpret=INTERP)
+        np.testing.assert_allclose(o_k, jnp.where(on[t][..., None], o_x, 0),
+                                   atol=2e-6)
+    np.testing.assert_allclose(s_k, s_x, atol=4e-6)
+    np.testing.assert_array_equal(s_k[2], s0[2])        # never live
+    assert not np.array_equal(np.asarray(s_k[1]), np.asarray(s0[1]))
+
+
+@pytest.mark.parametrize("live", [
+    [0, 0, 0, 0, 0, 0, 0, 0], [1, 1, 1, 1, 1, 1, 1, 1],
+    [0, 0, 1, 0, 1, 1, 0, 0], [0, 0, 0, 0, 0, 0, 0, 1],
+    [1, 0, 0, 0, 0, 0, 0, 0]], ids=lambda v: "".join(map(str, v)))
+def test_gdn_step_kernel_never_writes_a_dead_slot(live):
+    """What the kernel's grid visits, and what its pipeline writes back.
+    ``visit_list`` names a live slot's own block at its step and, at a
+    dead slot's step, the block the pipeline already holds (the last live
+    slot's; before the first live slot, that one's), so no dead slot is
+    ever a block of the grid; and under Pallas' TPU interpreter, whose
+    buffers start as NaN and which copies a block back only when the grid
+    moves off it, every state comes back whole: live ones updated, dead
+    ones bit for bit, also when NOTHING is live and the one block the
+    grid holds is written back untouched."""
+    from jax.experimental.pallas import tpu as pltpu
+    from distributed_training_sandbox_tpu.models import gdn_hybrid as G
+    from distributed_training_sandbox_tpu.ops import gdn_step as K
+    mask = np.asarray(live, bool)
+    src = np.asarray(K.visit_list(jnp.asarray(mask)))
+    if mask.any():
+        assert set(src) <= set(np.nonzero(mask)[0])
+        assert (src[mask] == np.nonzero(mask)[0]).all()
+        assert (np.diff(src) >= 0).all()        # a block is never revisited
+    else:
+        assert not src.any()
+    B, n, dk, dv = 8, 3, 8, 16
+    q, k, v, g, beta, s0 = _step_inputs(13, B, n, dk, dv, mask)
+    _, s_want = G.recurrent_step(q[0], k[0], v[0], g[0], beta[0], s0)
+    kq = jnp.concatenate([k[0], q[0]], axis=1).transpose(0, 2, 1)
+    rows = jnp.stack([jnp.repeat(jnp.exp(g[0]), dv, axis=-1),
+                      jnp.repeat(beta[0], dv, axis=-1),
+                      v[0].reshape(B, n * dv)])
+    o, s = K._step(jnp.asarray(src), jnp.asarray(mask, jnp.int32), kq, rows,
+                   s0, n=n, interpret=pltpu.InterpretParams())
+    assert not np.isnan(np.asarray(s)).any()
+    assert not np.isnan(np.asarray(o)).any()
+    np.testing.assert_allclose(s, s_want, atol=2e-6)
+    np.testing.assert_array_equal(np.asarray(s)[~mask],
+                                  np.asarray(s0)[~mask])
+
+
+@pytest.mark.parametrize("n,dk,dv,takes", [
+    (30, 96, 192, True),        # the hybrid cell: head pairs of 384 lanes
+    (16, 128, 128, True),       # a head is a lane tile
+    (4, 64, 64, True),          # pairs of heads
+    (6, 8, 320, True),          # pairs of heads, five tiles
+    (15, 96, 192, False),       # an odd head count: no whole pairs
+    (30, 100, 192, False),      # the key dim is no whole sublane tiles
+    (3, 8, 16, False),          # the tests' dims: interpret mode only
+])
+def test_gdn_step_kernel_takes(n, dk, dv, takes):
+    from distributed_training_sandbox_tpu.ops.gdn_step import (
+        head_group, step_kernel_takes)
+    assert step_kernel_takes(n, dk, dv) is takes
+    if takes:
+        assert (head_group(n, dv) * dv) % 128 == 0 and n % head_group(n, dv) == 0
+
+
 # ------------------------------------------- what a TPU makes of them
 
 def test_serving_kernels_refuse_a_tpu(monkeypatch):
