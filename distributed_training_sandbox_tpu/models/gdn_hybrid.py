@@ -26,19 +26,28 @@ Full-attention mixer (``n`` heads of ``hd``, ``n_kv`` KV heads)::
 What one token caches in such a layer is its K and V rows ``(n_kv, hd)``,
 in pages, as the dense block does.
 
-Linear mixer, the gated delta rule (``n`` heads, key dim ``dk``, value dim
-``dv``; ``C = n (2 dk + dv)`` conv channels)::
+Linear mixer, the gated delta rule (``n_k`` key heads of ``dk``, ``n``
+value heads of ``dv``, ``n`` a multiple of ``n_k``: value head ``r`` reads
+the ``q`` and ``k`` of key head ``r // (n / n_k)``, GROUPED value heads;
+this block's published config has ``n = n_k``; ``C = 2 n_k dk + n dv`` conv
+channels)::
 
     u = [x w_q | x w_k | x w_v]                                   (B, S, C)
     c_t = sum_{j<K} conv_w[j] * u_{t-K+1+j}      depthwise, CAUSAL, width K
     q~, k~, v~ = silu(c) split per head
     q_t = q~_t / |q~_t| / sqrt(dk),  k_t = k~_t / |k~_t|,  v_t = v~_t
                        (|.|: sqrt(sum of squares + 1e-6) over a head's dk)
-    beta_t  = 2 sigmoid(x_t w_b)                  a head (the 2: allow_neg_eigval)
-    alpha_t = exp(-exp(A_log) softplus(x_t w_a + dt_bias))      a head, in (0, 1)
+    beta_t  = b sigmoid(x_t w_b)    a value head; b = 2 with
+                                    ``linear_allow_neg_eigval``, else 1
+    alpha_t = exp(-exp(A_log) softplus(x_t w_a + dt_bias))   a value head, in (0, 1)
     S_t = alpha_t S_{t-1} + beta_t k_t (v_t - alpha_t S_{t-1}^T k_t)^T     S_0 = 0
     o_t = S_t^T q_t
     Mixer(x)_t = [norm(o_t; o_norm) * silu(x_t w_g)] w_o    norm over a head's dv
+
+The linear mixer (everything from :func:`causal_conv` to
+:func:`linear_output`, the state's layouts and the mixer's leaves) also
+serves ``models/gdn_moe.py``, whose block has two value heads a key head
+and ``b = 1``.
 
 What one REQUEST keeps in such a layer does not grow with its length: the
 state ``S`` (n, dk, dv) in float32 and the conv's tail, the last ``K - 1``
@@ -77,7 +86,7 @@ Parameter tree: ``embed`` (V, H), ``lm_head`` (H, V), ``final_norm`` (H,)
 and ``layers``, a tuple of one dict a layer (two kinds, nothing stacked).
 Every layer holds ``post_attn_norm``, ``post_mlp_norm``, ``w_gate``,
 ``w_up``, ``w_down``; a full-attention layer adds ``wq``, ``wk``, ``wv``,
-``wo``, ``q_norm``, ``k_norm``; a linear layer ``w_q``, ``w_k`` (H, n dk),
+``wo``, ``q_norm``, ``k_norm``; a linear layer ``w_q``, ``w_k`` (H, n_k dk),
 ``w_v``, ``w_g`` (H, n dv), ``w_a``, ``w_b`` (H, n), ``A_log``, ``dt_bias``
 (n,), ``conv_w`` (K, C), ``o_norm`` (dv,), ``w_o`` (n dv, H).
 """
@@ -117,10 +126,8 @@ def refuse(cfg, what: str):
         f"the system cannot run yet)")
 
 
-def check_config(cfg) -> None:
-    """Called from ``TransformerConfig.__post_init__`` when the block is
-    selected: the block is what the module docstring writes down, and a
-    field that asks for another variant is refused by name."""
+def check_linear(cfg) -> None:
+    """What every block with this linear mixer needs of its config."""
     need = ("full_attention_interval", "linear_num_key_heads",
             "linear_num_value_heads", "linear_value_head_dim",
             "linear_conv_kernel_dim")
@@ -129,20 +136,33 @@ def check_config(cfg) -> None:
         raise ValueError(f"linear_key_head_dim={cfg.linear_key_head_dim} "
                          f"selects the gated delta-rule hybrid block, which "
                          f"also needs {missing} > 0")
-    if cfg.linear_num_key_heads != cfg.linear_num_value_heads:
-        raise ValueError("the linear mixer is built with one value head a "
-                         "key head (linear_num_value_heads == "
-                         "linear_num_key_heads)")
+    if cfg.linear_num_value_heads % cfg.linear_num_key_heads:
+        raise ValueError("the linear mixer groups whole value heads under "
+                         "a key head: linear_num_value_heads must be a "
+                         "multiple of linear_num_key_heads")
     if cfg.linear_conv_kernel_dim < 2:
         raise ValueError("linear_conv_kernel_dim must be >= 2 (a conv of "
                          "width 1 carries no tail)")
-    for key, want in (("linear_allow_neg_eigval", True), ("nope_interval", 0),
-                      ("tie_word_embeddings", False), ("n_experts", 0),
-                      ("kv_lora_rank", 0), ("attention_impl", "xla")):
+    for key, want in (("nope_interval", 0), ("tie_word_embeddings", False),
+                      ("n_experts", 0), ("kv_lora_rank", 0),
+                      ("attention_impl", "xla")):
         if getattr(cfg, key) != want:
             raise ValueError(f"the gated delta-rule hybrid block is built "
                              f"with {key}={want!r} only, got "
                              f"{getattr(cfg, key)!r}")
+
+
+def check_config(cfg) -> None:
+    """Called from ``TransformerConfig.__post_init__`` when the block is
+    selected: the block is what the module docstring writes down, and a
+    field that asks for another variant is refused by name."""
+    check_linear(cfg)
+    for key, want in (("shared_expert_intermediate_size", 0),
+                      ("partial_rotary_factor", 1.0)):
+        if getattr(cfg, key) != want:
+            raise ValueError(f"{key}={getattr(cfg, key)!r} belongs to the "
+                             f"hybrid with expert layers (models/gdn_moe."
+                             f"py), which num_experts > 0 selects")
 
 
 def is_full_layer(li: int, cfg) -> bool:
@@ -162,13 +182,14 @@ def linear_layers(cfg) -> tuple[int, ...]:
 
 
 def conv_channels(cfg) -> int:
-    return cfg.linear_num_key_heads * (2 * cfg.linear_key_head_dim
-                                       + cfg.linear_value_head_dim)
+    return 2 * cfg.linear_num_key_heads * cfg.linear_key_head_dim \
+        + cfg.linear_num_value_heads * cfg.linear_value_head_dim
 
 
 def state_shape(cfg) -> tuple[int, int, int]:
-    """One slot's recurrent state in one linear layer (float32)."""
-    return (cfg.linear_num_key_heads, cfg.linear_key_head_dim,
+    """One slot's recurrent state in one linear layer (float32): a matrix
+    a VALUE head."""
+    return (cfg.linear_num_value_heads, cfg.linear_key_head_dim,
             cfg.linear_value_head_dim)
 
 
@@ -202,15 +223,21 @@ def slot_state_bytes(cfg) -> int:
         + math.prod(tail_shape(cfg)) * jnp.dtype(cfg.dtype).itemsize
 
 
+def linear_mixer_param_count(cfg) -> int:
+    """The leaves :func:`linear_mixer_params` makes."""
+    h = cfg.hidden_size
+    n, dk, dv = state_shape(cfg)
+    return h * (conv_channels(cfg) + n * dv) + n * dv * h \
+        + 2 * h * n + 2 * n \
+        + cfg.linear_conv_kernel_dim * conv_channels(cfg) + dv
+
+
 def param_count(cfg) -> int:
     h, hd = cfg.hidden_size, cfg.resolved_head_dim
     nq, nkv = cfg.num_attention_heads, cfg.num_key_value_heads
-    n, dk, dv = state_shape(cfg)
     common = 3 * h * cfg.intermediate_size + 2 * h
     full = common + h * hd * (2 * nq + 2 * nkv) + hd * (nq + nkv)
-    linear = common + h * n * (2 * dk + 2 * dv) + n * dv * h \
-        + 2 * h * n + 2 * n \
-        + cfg.linear_conv_kernel_dim * conv_channels(cfg) + dv
+    linear = common + linear_mixer_param_count(cfg)
     n_full = len(full_layers(cfg))
     return n_full * full + (cfg.num_hidden_layers - n_full) * linear \
         + 2 * cfg.vocab_size * h + h
@@ -218,17 +245,36 @@ def param_count(cfg) -> int:
 
 # ------------------------------------------------------------------- init
 
+def linear_mixer_params(cfg, tn, uniform, out_std: float) -> dict:
+    """A linear layer's mixer leaves (module docstring), drawn through the
+    caller's ``tn(shape, std=0.02)`` and ``uniform(shape, lo, hi)``: the
+    conv uniform in +-1/sqrt(K); ``exp(A_log)`` uniform in [1, 16] and
+    ``softplus(dt_bias)`` log-uniform in [1e-3, 1e-1], so a head's decay a
+    token runs from about 0.2 to nearly 1."""
+    h = cfg.hidden_size
+    n, dk, dv = state_shape(cfg)
+    nk, K = cfg.linear_num_key_heads, cfg.linear_conv_kernel_dim
+    dt = jnp.exp(uniform((n,), math.log(1e-3), math.log(1e-1)))
+    return {"w_q": tn((h, nk * dk)), "w_k": tn((h, nk * dk)),
+            "w_v": tn((h, n * dv)), "w_g": tn((h, n * dv)),
+            "w_a": tn((h, n)), "w_b": tn((h, n)),
+            "A_log": jnp.log(uniform((n,), 1.0, 16.0)).astype(cfg.dtype),
+            # softplus^-1(dt)
+            "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(cfg.dtype),
+            "conv_w": uniform((K, conv_channels(cfg)), -K ** -0.5,
+                              K ** -0.5).astype(cfg.dtype),
+            "o_norm": jnp.ones((dv,), cfg.dtype),
+            "w_o": tn((n * dv, h), out_std)}
+
+
 def init_params(key: jax.Array, cfg) -> dict:
     """``transformer.init_params`` for this block: truncated normal 0.02,
     the projections back into the residual stream scaled by
-    1/sqrt(2 . layers), norms at one; the conv uniform in +-1/sqrt(K);
-    ``exp(A_log)`` uniform in [1, 16] and ``softplus(dt_bias)`` log-uniform
-    in [1e-3, 1e-1], so a head's decay a token runs from about 0.2 to
-    nearly 1."""
+    1/sqrt(2 . layers), norms at one; the linear mixer's leaves as
+    :func:`linear_mixer_params` draws them."""
     h, hd = cfg.hidden_size, cfg.resolved_head_dim
     nq, nkv = cfg.num_attention_heads, cfg.num_key_value_heads
-    n, dk, dv = state_shape(cfg)
-    K, F = cfg.linear_conv_kernel_dim, cfg.intermediate_size
+    F = cfg.intermediate_size
     out_std = 0.02 / math.sqrt(2 * cfg.num_hidden_layers)
     keys = iter(jax.random.split(key, 2 + 13 * cfg.num_hidden_layers))
 
@@ -249,16 +295,7 @@ def init_params(key: jax.Array, cfg) -> dict:
             return {**out, "wq": tn((h, nq * hd)), "wk": tn((h, nkv * hd)),
                     "wv": tn((h, nkv * hd)), "wo": tn((nq * hd, h), out_std),
                     "q_norm": ones(nq * hd), "k_norm": ones(nkv * hd)}
-        dt = jnp.exp(uniform((n,), math.log(1e-3), math.log(1e-1)))
-        return {**out, "w_q": tn((h, n * dk)), "w_k": tn((h, n * dk)),
-                "w_v": tn((h, n * dv)), "w_g": tn((h, n * dv)),
-                "w_a": tn((h, n)), "w_b": tn((h, n)),
-                "A_log": jnp.log(uniform((n,), 1.0, 16.0)).astype(cfg.dtype),
-                # softplus^-1(dt)
-                "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(cfg.dtype),
-                "conv_w": uniform((K, conv_channels(cfg)), -K ** -0.5,
-                                  K ** -0.5).astype(cfg.dtype),
-                "o_norm": ones(dv), "w_o": tn((n * dv, h), out_std)}
+        return {**out, **linear_mixer_params(cfg, tn, uniform, out_std)}
 
     return {
         "embed": tn((cfg.vocab_size, h)),
@@ -292,12 +329,13 @@ def _l2norm(x):
 
 def linear_inputs(x, layer, tail, valid, *, cfg):
     """The residual stream to what the recurrence takes: ``q``, ``k``
-    (B, S, n, dk) and ``v`` (B, S, n, dv) float32, normalised and scaled;
+    (B, S, n_k, dk) and ``v`` (B, S, n, dv) float32, normalised and scaled;
     ``g`` = log alpha and ``beta`` (B, S, n) float32, both 0 where ``valid``
     (B, S) is False; and the conv's new tail."""
     from .transformer import _dense
     B, S, _ = x.shape
     n, dk, dv = state_shape(cfg)
+    nk = cfg.linear_num_key_heads
     dense = _dense(cfg)
     with scope("lin_conv"):
         u = jnp.concatenate([dense(x, layer["w_q"]), dense(x, layer["w_k"]),
@@ -305,13 +343,15 @@ def linear_inputs(x, layer, tail, valid, *, cfg):
         c, new_tail = causal_conv(u, tail, layer["conv_w"],
                                   jnp.sum(valid.astype(jnp.int32), axis=1))
         c = jax.nn.silu(c).astype(x.dtype)
-    q = _l2norm(c[..., :n * dk].reshape(B, S, n, dk)) * dk ** -0.5
-    k = _l2norm(c[..., n * dk:2 * n * dk].reshape(B, S, n, dk))
-    v = c[..., 2 * n * dk:].reshape(B, S, n, dv).astype(jnp.float32)
+    q = _l2norm(c[..., :nk * dk].reshape(B, S, nk, dk)) * dk ** -0.5
+    k = _l2norm(c[..., nk * dk:2 * nk * dk].reshape(B, S, nk, dk))
+    v = c[..., 2 * nk * dk:].reshape(B, S, n, dv).astype(jnp.float32)
     a = dense(x, layer["w_a"]).astype(jnp.float32)
     g = -jnp.exp(layer["A_log"].astype(jnp.float32)) * jax.nn.softplus(
         a + layer["dt_bias"].astype(jnp.float32))
-    beta = 2.0 * jax.nn.sigmoid(dense(x, layer["w_b"]).astype(jnp.float32))
+    beta = jax.nn.sigmoid(dense(x, layer["w_b"]).astype(jnp.float32))
+    if cfg.linear_allow_neg_eigval:
+        beta = 2.0 * beta
     keep = valid[..., None]
     return q, k, v, jnp.where(keep, g, 0.0), jnp.where(keep, beta, 0.0), \
         new_tail
@@ -345,10 +385,11 @@ def step_kernel_engages(n: int, dk: int, dv: int) -> bool:
 
 
 def recurrent_step(q, k, v, g, beta, state):
-    """One token of the recurrence for every slot: q, k (B, n, dk), v
+    """One token of the recurrence for every slot: q, k (B, n_k, dk), v
     (B, n, dv), g, beta (B, n), ``state`` (B, dk, n * dv) float32, the
-    slots as stored (:func:`slot_shape`).  Returns ``o`` (B, n, dv) and
-    the new state in the same layout.  Elementwise products and sums in
+    slots as stored (:func:`slot_shape`); value head ``r`` reads key head
+    ``r // (n / n_k)``.  Returns ``o`` (B, n, dv) and the new state in
+    the same layout.  Elementwise products and sums in
     float32: a state is read as it is stored, never rounded for an MXU
     pass.  ``beta = 0, g = 0`` leaves a state bit for bit as it was.
 
@@ -362,15 +403,17 @@ def recurrent_step(q, k, v, g, beta, state):
     the v5e at 64 slots of the published widths against the kernel's 4.1
     (PR 30's form over ``(n, dk, dv)`` took 7.5 and a hand-ordered one,
     both reductions from one pass over the OLD state, 7.89: PERF.md)."""
-    B, n, dk = q.shape
-    dv = v.shape[-1]
+    B, n, dv = v.shape
+    dk = q.shape[-1]
     if _STEP_KERNEL and step_kernel_engages(n, dk, dv):
         from ..ops.gdn_step import gdn_decode_step
         return gdn_decode_step(q, k, v, g, beta, state)
-    # every state-sized operand in the stored layout (B, dk, n dv): a
-    # head's scalar repeated over its dv lanes, its key vector down dk
+    # every state-sized operand in the stored layout (B, dk, n dv): a value
+    # head's scalar repeated over its dv lanes, a key head's vector down dk
+    # and over the lanes of all its value heads
     lanes = lambda a: jnp.repeat(a, dv, axis=-1)  # noqa: E731
-    col = lambda a: lanes(a.transpose(0, 2, 1))  # noqa: E731
+    col = lambda a: jnp.repeat(a.transpose(0, 2, 1),  # noqa: E731
+                               n // q.shape[1] * dv, axis=-1)
     s = lanes(jnp.exp(g))[:, None] * state
     u = lanes(beta) * (v.reshape(B, -1) - jnp.sum(col(k) * s, axis=1))
     s = s + col(k) * u[:, None]
@@ -379,12 +422,16 @@ def recurrent_step(q, k, v, g, beta, state):
 
 def chunked_scan(q, k, v, g, beta, state):
     """The recurrence over S rows from a carried ``state`` (B, n, dk, dv):
-    q, k (B, S, n, dk), v (B, S, n, dv), g, beta (B, S, n), all float32.
+    q, k (B, S, n_k, dk), v (B, S, n, dv), g, beta (B, S, n), all float32.
     Returns ``o`` (B, S, n, dv) and the state after the last row
     (module docstring: the chunked form; ``SCAN_CHUNK`` rows a sub-chunk,
-    S padded up to whole sub-chunks with rows that change nothing)."""
-    B, S, n, dk = q.shape
-    dv = v.shape[-1]
+    S padded up to whole sub-chunks with rows that change nothing).  Under
+    grouped value heads q and k are repeated a value head first: decay and
+    beta are a value head's own, so no triangular system is shared."""
+    B, S, n, dv = v.shape
+    dk = q.shape[-1]
+    if q.shape[2] != n:
+        q, k = (jnp.repeat(a, n // a.shape[2], axis=2) for a in (q, k))
     C = min(SCAN_CHUNK, S)
     pad = -S % C
     if pad:
@@ -439,11 +486,37 @@ def linear_output(o, x, layer, *, cfg):
     return dense((y * gate).astype(x.dtype), layer["w_o"])
 
 
-# ----------------------------------------------- the full-attention mixer
+# ------------------------------------------------- what a block brings
+#
+# The engine's ``_paged_hybrid_forward`` and :func:`hidden_states` run the
+# layer loop once for every block built on this linear mixer; what differs
+# between them (the residual path, the full-attention mixer, the MLP) they
+# call through ``cfg.block_module``, which is this module or
+# ``models/gdn_moe.py``: ``rope_tables``, ``mixer_input``,
+# ``attention_qkv``, ``attention_output``, ``linear_mixer_output``, ``mlp``,
+# ``final_norm``, and ``PAGED_ATTENTION_SCOPE``: the scope beneath
+# ``attn_core`` that the engine opens round the full-attention layers'
+# paged attention (``profiling.ATTENTION_SUBSCOPES``), or None for none.
 
-def attention_qkv(x, layer, *, cfg):
+PAGED_ATTENTION_SCOPE = None
+
+
+def rope_tables(positions, cfg):
+    """No rotary embedding: position reaches the full-attention layers
+    through the recurrent ones."""
+    return None
+
+
+def mixer_input(x, layer, *, cfg):
+    """What a mixer reads: the residual stream itself (no norm before a
+    mixer)."""
+    return x
+
+
+def attention_qkv(x, layer, *, cfg, rope=None):
     """``q`` (B, S, n, hd), ``k``, ``v`` (B, S, n_kv, hd): projections, the
-    whole-projection norms of q and k, no rotary embedding."""
+    whole-projection norms of q and k, no rotary embedding; and the heads'
+    output gate, which this block has not (None)."""
     from .transformer import _dense, rms_norm
     B, S, _ = x.shape
     hd = cfg.resolved_head_dim
@@ -453,10 +526,8 @@ def attention_qkv(x, layer, *, cfg):
     v = dense(x, layer["wv"])
     return (q.reshape(B, S, cfg.num_attention_heads, hd),
             k.reshape(B, S, cfg.num_key_value_heads, hd),
-            v.reshape(B, S, cfg.num_key_value_heads, hd))
+            v.reshape(B, S, cfg.num_key_value_heads, hd), None)
 
-
-# ----------------------------------------------- residual path and the MLP
 
 def add_mixer(x, mixed, layer, *, cfg):
     """``h = x + norm(Mixer(x))``."""
@@ -464,13 +535,34 @@ def add_mixer(x, mixed, layer, *, cfg):
     return x + rms_norm(mixed, layer["post_attn_norm"], cfg.rms_norm_eps)
 
 
-def mlp(h, layer, *, cfg):
-    """``y = h + norm(MLP(h))``."""
+def attention_output(attn, gate, x, layer, *, cfg):
+    """The heads' outputs ``attn`` (B, S, ..heads.., hd) float32 through
+    ``wo`` onto the residual stream: ``h``."""
+    from .transformer import _dense
+    B, S = attn.shape[:2]
+    return add_mixer(x, _dense(cfg)(
+        attn.astype(x.dtype).reshape(B, S, -1), layer["wo"]), layer, cfg=cfg)
+
+
+def linear_mixer_output(o, r, x, layer, *, cfg):
+    """A linear layer's ``h`` from the recurrence's outputs ``o``; ``r`` is
+    what the mixer read (:func:`mixer_input`)."""
+    return add_mixer(x, linear_output(o, r, layer, cfg=cfg), layer, cfg=cfg)
+
+
+def mlp(h, layer, *, cfg, valid=None):
+    """``y = h + norm(MLP(h))``; and the layer's device-side counters,
+    which a dense MLP has not (None)."""
     from .transformer import _dense, rms_norm
     dense = _dense(cfg)
     m = dense(jax.nn.silu(dense(h, layer["w_gate"]))
               * dense(h, layer["w_up"]), layer["w_down"])
-    return h + rms_norm(m, layer["post_mlp_norm"], cfg.rms_norm_eps)
+    return h + rms_norm(m, layer["post_mlp_norm"], cfg.rms_norm_eps), None
+
+
+def final_norm(x, params, cfg):
+    from .transformer import rms_norm
+    return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
 
 
 # ------------------------------------------------- the cache-less forward
@@ -478,36 +570,39 @@ def mlp(h, layer, *, cfg):
 def hidden_states(params, input_ids, cfg):
     """(B, S) ids -> final-norm hidden states (B, S, H): the whole
     sequence at once, the chunked scan from a zero state and an empty
-    tail, materialised causal attention, no cache."""
-    from .transformer import _attention_xla, _dense, rms_norm
+    tail, materialised causal attention, no cache.  For every block built
+    on this linear mixer (``cfg.block_module``)."""
+    from .transformer import _attention_xla
+    blk = cfg.block_module
     B, S = input_ids.shape
     with scope("embed"):
         x = params["embed"].astype(cfg.dtype)[input_ids]
+        rope = blk.rope_tables(jnp.broadcast_to(jnp.arange(S), (B, S)), cfg)
     valid = jnp.ones((B, S), jnp.bool_)
     for li, layer in enumerate(params["layers"]):
         if is_full_layer(li, cfg):
             with scope("attn_qkv"):
-                q, k, v = attention_qkv(x, layer, cfg=cfg)
+                q, k, v, gate = blk.attention_qkv(
+                    blk.mixer_input(x, layer, cfg=cfg), layer, cfg=cfg,
+                    rope=rope)
             with scope("attn_core"):
                 a = _attention_xla(q, k, v,
                                    1.0 / math.sqrt(cfg.resolved_head_dim))
             with scope("attn_out"):
-                h = add_mixer(x, _dense(cfg)(
-                    a.astype(x.dtype).reshape(B, S, -1), layer["wo"]),
-                    layer, cfg=cfg)
+                h = blk.attention_output(a, gate, x, layer, cfg=cfg)
         else:
             with scope("attn_qkv"):
+                r = blk.mixer_input(x, layer, cfg=cfg)
                 q, k, v, g, beta, _ = linear_inputs(
-                    x, layer, jnp.zeros((B,) + tail_shape(cfg), cfg.dtype),
+                    r, layer, jnp.zeros((B,) + tail_shape(cfg), cfg.dtype),
                     valid, cfg=cfg)
             with scope("attn_core"), scope("lin_scan"):
                 o, _ = chunked_scan(
                     q, k, v, g, beta,
                     jnp.zeros((B,) + state_shape(cfg), jnp.float32))
             with scope("attn_out"):
-                h = add_mixer(x, linear_output(o, x, layer, cfg=cfg), layer,
-                              cfg=cfg)
+                h = blk.linear_mixer_output(o, r, x, layer, cfg=cfg)
         with scope("mlp"):
-            x = mlp(h, layer, cfg=cfg)
+            x, _ = blk.mlp(h, layer, cfg=cfg)
     with scope("loss_head"):
-        return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        return blk.final_norm(x, params, cfg)

@@ -32,6 +32,15 @@ MLP: ``r2 = norm(x; ln2)``; a leading dense layer is SwiGLU of
     T = top-k(s);  w_e = routed_scaling_factor . s_e / (sum_T s + 1e-20)
     m = SwiGLU_shared(r2) + sum_{e in T, e held here} w_e . SwiGLU_e(r2)
 
+The expert layer (:func:`route`, :func:`moe_counts`, :func:`expert_mlp`)
+also serves ``models/gdn_moe.py``, whose router scores with a softmax over
+all ``router_width`` experts (a block module says which in its
+``ROUTER_SCORING``) and whose shared expert is gated a token,
+``sigmoid(r2 . ws_sigmoid) * SwiGLU_shared(r2)`` (an expert layer that
+holds the leaf ``ws_sigmoid`` (H, 1)); it counts its
+held experts as ``num_experts``, this block as ``n_routed_experts``
+(``cfg.held_experts`` reads either).
+
 ``w_e`` is normalised over all of ``T`` whether or not its experts are held
 here; what absent experts would add is left out (one rank's part under
 expert parallelism; on one chip the layer runs without its exchange).  No
@@ -73,6 +82,11 @@ COUNTERS = ("moe_assignments", "moe_assignments_held",
             "moe_experts_touched", "moe_expert_layer_steps")
 
 
+#: how this block's router scores an expert: each its own sigmoid
+#: (:func:`route` reads ``cfg.block_module.ROUTER_SCORING``)
+ROUTER_SCORING = "sigmoid"
+
+
 def refuse(cfg, what: str):
     raise NotImplementedError(
         f"the latent-attention + held-experts block (kv_lora_rank="
@@ -95,22 +109,30 @@ def check_config(cfg) -> None:
     if not 0 <= cfg.first_k_dense_replace <= cfg.num_hidden_layers:
         raise ValueError("first_k_dense_replace must lie in "
                          "[0, num_hidden_layers]")
-    if cfg.expert_offset < 0 or \
-            cfg.expert_offset + cfg.n_routed_experts > cfg.router_width:
-        raise ValueError(
-            f"held experts {cfg.expert_offset}.."
-            f"{cfg.expert_offset + cfg.n_routed_experts - 1} are not among "
-            f"the router's {cfg.router_width}")
-    if cfg.num_experts_per_tok > cfg.router_width:
-        raise ValueError("num_experts_per_tok exceeds router_width")
+    check_held_experts(cfg)
     if cfg.qk_rope_head_dim % 2:
         raise ValueError("qk_rope_head_dim must be even (rotary pairs)")
     for key, want in (("sandwich_norm", True), ("norm_topk_prob", True),
                       ("nope_interval", 0), ("tie_word_embeddings", False),
-                      ("n_experts", 0), ("attention_impl", "xla")):
+                      ("n_experts", 0), ("num_experts", 0),
+                      ("attention_impl", "xla")):
         if getattr(cfg, key) != want:
             raise ValueError(f"the latent block is built with {key}="
                              f"{want!r} only, got {getattr(cfg, key)!r}")
+
+
+def check_held_experts(cfg) -> None:
+    """What :func:`route` needs of a config, whichever block opens the
+    expert layer: the held experts lie among the router's, which has at
+    least as many as a token chooses."""
+    if cfg.expert_offset < 0 or \
+            cfg.expert_offset + cfg.held_experts > cfg.router_width:
+        raise ValueError(
+            f"held experts {cfg.expert_offset}.."
+            f"{cfg.expert_offset + cfg.held_experts - 1} are not among "
+            f"the router's {cfg.router_width}")
+    if cfg.num_experts_per_tok > cfg.router_width:
+        raise ValueError("num_experts_per_tok exceeds router_width")
 
 
 def row_width(cfg) -> int:
@@ -317,15 +339,20 @@ def attention_output(o, x, layer, cfg):
 
 def route(r2, w_router, cfg):
     """``r2`` (T, H) -> the routing weights of the HELD experts
-    (T, n_routed_experts) float32, zero where a held expert was not among
-    the row's ``num_experts_per_tok``, and the chosen ids (T, k)."""
+    (T, held experts) float32, zero where a held expert was not among
+    the row's ``num_experts_per_tok``, and the chosen ids (T, k).  An
+    expert's score is the block's ``ROUTER_SCORING``: its own
+    ``"sigmoid"``, or a ``"softmax"`` over the router's whole width; the
+    chosen scores are renormalised to sum to ``routed_scaling_factor``
+    either way."""
     with jax.default_matmul_precision("highest"):
-        s = jax.nn.sigmoid(r2.astype(jnp.float32)
-                           @ w_router.astype(jnp.float32))
+        s = r2.astype(jnp.float32) @ w_router.astype(jnp.float32)
+    s = jax.nn.sigmoid(s) if cfg.block_module.ROUTER_SCORING == "sigmoid" \
+        else jax.nn.softmax(s, axis=-1)
     top, idx = lax.top_k(s, cfg.num_experts_per_tok)
     w = cfg.routed_scaling_factor * top \
         / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
-    held = cfg.expert_offset + jnp.arange(cfg.n_routed_experts)
+    held = cfg.expert_offset + jnp.arange(cfg.held_experts)
     hit = idx[:, :, None] == held[None, None, :]                # (T, k, E)
     return jnp.sum(jnp.where(hit, w[:, :, None], 0.0), axis=1), idx
 
@@ -348,9 +375,10 @@ def _swiglu(r, gate, up, down, dense):
 
 def expert_mlp(r2, layer, *, cfg, valid=None):
     """An expert layer's MLP on the normed rows ``r2`` (B, S, H): the
-    shared expert plus this program's held experts' part of the routed sum.
-    Returns it (before the post-MLP norm) and ``moe_counts`` of the rows
-    ``valid`` (B, S) marks (all rows when None)."""
+    shared expert (gated a token where the layer holds ``ws_sigmoid``) plus
+    this program's held experts' part of the routed sum.  Returns it
+    (before any post-MLP norm) and ``moe_counts`` of the rows ``valid``
+    (B, S) marks (all rows when None)."""
     from .transformer import _dense
     B, S, H = r2.shape
     rows = r2.reshape(B * S, H)
@@ -368,6 +396,10 @@ def expert_mlp(r2, layer, *, cfg, valid=None):
     with scope("moe_shared"):
         shared = _swiglu(r2, layer["ws_gate"], layer["ws_up"],
                          layer["ws_down"], _dense(cfg))
+        if "ws_sigmoid" in layer:
+            shared = (jax.nn.sigmoid(_dense(cfg)(
+                r2, layer["ws_sigmoid"]).astype(jnp.float32))
+                * shared).astype(r2.dtype)
     return shared + routed.astype(r2.dtype).reshape(B, S, H), counts
 
 

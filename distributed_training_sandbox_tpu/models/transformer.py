@@ -191,14 +191,23 @@ class TransformerConfig:
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 0
     linear_allow_neg_eigval: bool = False
+    # The gated delta-rule hybrid with an expert layer under every mixer
+    # (``models/gdn_moe.py``; names as in the published configs of that
+    # family).  ``num_experts`` > 0 beside ``linear_key_head_dim`` > 0
+    # selects it: ``num_experts`` routed experts are HELD here (ids
+    # ``expert_offset`` onwards of the router's ``router_width``, each of
+    # ``moe_intermediate_size``, ``num_experts_per_tok`` chosen a token)
+    # beside one sigmoid-gated shared expert of
+    # ``shared_expert_intermediate_size``; the full-attention layers gate
+    # their heads' outputs and rotate the first ``partial_rotary_factor``
+    # of each head's dims.  Serving only and the cache-less ``forward``.
+    num_experts: int = 0
+    shared_expert_intermediate_size: int = 0
+    partial_rotary_factor: float = 1.0
 
     def __post_init__(self):
-        if self.kv_lora_rank:
-            from .mla_moe import check_config
-            check_config(self)
-        if self.linear_key_head_dim:
-            from .gdn_hybrid import check_config
-            check_config(self)
+        if self.block_module is not None:
+            self.block_module.check_config(self)
         # Covers every construction path incl. dataclasses.replace: a
         # sequence-sharded config with a local-chunk attention impl would
         # silently never attend across chunk boundaries.
@@ -244,17 +253,35 @@ class TransformerConfig:
 
     @property
     def gdn_hybrid(self) -> bool:
-        """The gated delta-rule hybrid block (``models/gdn_hybrid.py``)."""
+        """A block of gated delta-rule layers and full-attention layers,
+        whose requests hold state slots beside pages: the one of
+        ``models/gdn_hybrid.py`` or, with :attr:`gdn_moe`, of
+        ``models/gdn_moe.py``."""
         return self.linear_key_head_dim > 0
+
+    @property
+    def gdn_moe(self) -> bool:
+        """The gated delta-rule hybrid with an expert layer under every
+        mixer (``models/gdn_moe.py``)."""
+        return self.gdn_hybrid and self.num_experts > 0
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts of an expert layer that this program holds, under
+        whichever name the block's published config counts them."""
+        return self.n_routed_experts or self.num_experts
 
     @property
     def block_module(self):
         """The module that holds a block other than the dense GQA one
-        (``init_params``, ``param_count``, ``hidden_states``, ``refuse``);
-        None for the dense block."""
+        (``check_config``, ``init_params``, ``param_count``,
+        ``hidden_states``, ``refuse``); None for the dense block."""
         if self.mla_moe:
             from . import mla_moe
             return mla_moe
+        if self.gdn_moe:
+            from . import gdn_moe
+            return gdn_moe
         if self.gdn_hybrid:
             from . import gdn_hybrid
             return gdn_hybrid

@@ -93,6 +93,36 @@ def prefill_kernel_takes(dtype, head_dim: int, page_size: int,
             and chunk % (32 // jnp.dtype(dtype).itemsize) == 0)
 
 
+# ------------------------------------------------- heads wider than a tile
+
+def _staging(T: int, hd: int):
+    """The float32 staging of one block's slab, from which a KV head's rows
+    are read with a sublane stride: ``(T, hd)`` at a head dim of one lane
+    tile; at a wider one ``(hd / 128, T, 128)``, a plane a lane tile,
+    because Mosaic reads with a stride only from a buffer whose rows are
+    128 lanes ("The last dim size is not 128 in original base memref")."""
+    wide = hd > 128 and hd % 128 == 0   # other widths: interpret mode only
+    return pltpu.VMEM((hd // 128, T, 128) if wide else (T, hd), jnp.float32)
+
+
+def _stage(dst, slab):
+    """The slab (T, hd), widened to float32, into its staging."""
+    if len(dst.shape) == 2:
+        dst[...] = slab.astype(jnp.float32)
+        return
+    for c in range(dst.shape[0]):
+        dst[c] = slab[:, c * 128:(c + 1) * 128].astype(jnp.float32)
+
+
+def _head_rows(src, head):
+    """The rows ``head`` (a strided ``pl.ds``) of a staged slab: (span,
+    hd)."""
+    if len(src.shape) == 2:
+        return src[head, :]
+    return jnp.concatenate([src.at[c][head, :] for c in range(src.shape[0])],
+                           axis=1)
+
+
 def _prefill_kernel(start_ref, end_ref, pages_ref, q_ref, pk_hbm, pv_hbm,
                     o_ref, k_buf, v_buf, k32, v32, acc_ref, sems, *,
                     table_pages: int, block_pages: int, page: int,
@@ -104,8 +134,8 @@ def _prefill_kernel(start_ref, end_ref, pages_ref, q_ref, pk_hbm, pv_hbm,
     head's R = S * rep query rows, row ``s * rep + r``; pk_hbm/pv_hbm
     are the pools as (n_pages, page * n_kv, hd), left in HBM;
     k_buf/v_buf (2, T, hd) are the two halves of the double buffer of
-    T = block_pages * page * n_kv slab rows, k32/v32 (T, hd) their
-    float32 staging, acc_ref (n_kv, hd, R) the transposed float32
+    T = block_pages * page * n_kv slab rows, k32/v32 their float32
+    staging (:func:`_staging`), acc_ref (n_kv, hd, R) the transposed float32
     accumulators, sems (2, 2) the K and V semaphores.  o_ref is
     (1, n_kv, R, hd) float32."""
     b = pl.program_id(0)
@@ -153,15 +183,15 @@ def _prefill_kernel(start_ref, end_ref, pages_ref, q_ref, pk_hbm, pv_hbm,
 
         for c in copies(blk, slot):
             c.wait()
-        k32[...] = k_buf[slot].astype(jnp.float32)
-        v32[...] = v_buf[slot].astype(jnp.float32)
+        _stage(k32, k_buf[slot])
+        _stage(v32, v_buf[slot])
         vis = blk * span + key_pos <= q_last                  # (span, R)
         out = []
         for g in range(nkv):
             m, l = carry[g]
             head = pl.ds(g, span, stride=nkv)
-            kg = k32[head, :].astype(k_buf.dtype)             # (span, hd)
-            vg = v32[head, :].T.astype(v_buf.dtype)           # (hd, span)
+            kg = _head_rows(k32, head).astype(k_buf.dtype)    # (span, hd)
+            vg = _head_rows(v32, head).T.astype(v_buf.dtype)  # (hd, span)
             s = jax.lax.dot_general(
                 kg, q_ref[0, g], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) / math.sqrt(hd)
@@ -253,8 +283,8 @@ def _prefill_float(qg, pk, pv, pages, start, end, *, block_pages: int,
             out_specs=heads,
             scratch_shapes=[pltpu.VMEM((2, T, hd), pk.dtype),
                             pltpu.VMEM((2, T, hd), pv.dtype),
-                            pltpu.VMEM((T, hd), jnp.float32),
-                            pltpu.VMEM((T, hd), jnp.float32),
+                            _staging(T, hd),
+                            _staging(T, hd),
                             pltpu.VMEM((nkv, hd, R), jnp.float32),
                             pltpu.SemaphoreType.DMA((2, 2))]),
         out_shape=jax.ShapeDtypeStruct((B, nkv, R, hd), jnp.float32),

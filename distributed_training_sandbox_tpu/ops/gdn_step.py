@@ -8,7 +8,7 @@ side by side on the lanes, so that a slot is whole (8, 128) tiles at the
 published widths (96 x 5,760) and a block copy moves no padding.  One grid
 step is one slot: the pipeline copies its state to VMEM, the body does, in
 float32 on the VPU, a group of heads at a time (``head_group``: the fewest
-heads whose value dims fill whole lane tiles, two of 192)::
+heads whose value dims fill whole lane tiles, two of 192 or of 128)::
 
     s = alpha * S;  u = beta * (v - k^T s);  S' = s + k u^T;  o = q^T S'
 
@@ -26,10 +26,12 @@ neither fetches nor writes back a second time, and its body only writes
 zeros to the slot's row of ``o``.
 
 The small operands come prepared by XLA, inside the caller's scope:
-``k | q`` transposed to ``(B, dk, 2 n)`` (a head's vector down the
-sublanes, broadcast along its lanes in the kernel) and ``alpha``, ``beta``
-(repeated over a head's lanes) and ``v`` as rows ``(3, B, n * dv)``, taken
-eight slots a block (B padded up to whole blocks).  Equal to the XLA form of
+``k | q`` transposed to ``(B, dk, 2 n_k)`` (a KEY head's vector down the
+sublanes, broadcast in the kernel along the lanes of each value head it
+serves: value head ``r`` reads column ``r // (n / n_k)``, grouped value
+heads) and ``alpha``, ``beta`` (repeated over a value head's lanes) and
+``v`` as rows ``(3, B, n * dv)``, taken eight slots a block (B padded up to
+whole blocks).  Equal to the XLA form of
 ``gdn_hybrid.recurrent_step`` to float32 summation order
 (tests/test_kernels.py, interpret mode on the CPU; chip_smoke.py on the
 chip).  :func:`step_kernel_takes` states which shapes compile on a TPU;
@@ -62,10 +64,15 @@ VMEM_LIMIT_BYTES = 48 * 2 ** 20
 
 def head_group(n: int, dv: int) -> int:
     """Heads the body takes at a time: the fewest whose value dims fill
-    whole 128-lane tiles, where the head count is a multiple of that; else
-    all of them (a group then ends inside a tile, which only interpret
-    mode takes)."""
+    whole 128-lane tiles, and more than one of them (Mosaic refuses the
+    body's read of ONE slot's row of ``alpha | beta | v`` at a width of a
+    single tile: "dynamic load with unaligned indices"; two heads of 128
+    compile, as two of 192 do), where the head count is a multiple of
+    that; else all of them (a group then ends inside a tile, which only
+    interpret mode takes)."""
     g = math.lcm(dv, 128) // dv
+    if g * dv == 128:
+        g *= 2
     return g if n % g == 0 else n
 
 
@@ -90,30 +97,35 @@ def visit_list(live):
 def _step_kernel(src_ref, live_ref, kq_ref, rows_ref, s_ref, o_ref,
                  s_out_ref, *, n: int, dv: int, group: int):
     """One batch slot.  ``src_ref``, ``live_ref`` (B,) int32 in SMEM;
-    kq_ref (1, dk, 2 n): k then q of slot ``src_ref[i]``, a head a column;
-    rows_ref (3, R, n dv): alpha, beta, v of the R slots of this row block;
-    s_ref / s_out_ref (1, dk, n dv): the state of slot ``src_ref[i]``, in
-    and out (one buffer in HBM); o_ref (R, n dv)."""
+    kq_ref (1, dk, 2 n_k): k then q of slot ``src_ref[i]``, a key head a
+    column; rows_ref (3, R, n dv): alpha, beta, v of the R slots of this
+    row block; s_ref / s_out_ref (1, dk, n dv): the state of slot
+    ``src_ref[i]``, in and out (one buffer in HBM); o_ref (R, n dv)."""
     i = pl.program_id(0)
     r = i % o_ref.shape[0]
     row = pl.ds(r, 1)
     W = group * dv
     lane = lax.broadcasted_iota(jnp.int32, (1, W), 1)
+    n_k = kq_ref.shape[2] // 2
+    per_key = n // n_k          # value heads that read one key head's column
 
-    def columns(first):
-        """Heads ``first .. first + group`` of kq_ref, each broadcast
-        along its own dv lanes: (dk, W)."""
-        out = kq_ref[0, :, first:first + 1]
+    def columns(base, first):
+        """The columns of kq_ref (k from ``base`` 0, q from ``n_k``) that
+        value heads ``first .. first + group`` read, each broadcast along
+        its head's own dv lanes: (dk, W)."""
+        col = lambda h: base + h // per_key  # noqa: E731
+        out = kq_ref[0, :, col(first):col(first) + 1]
         for t in range(1, group):
-            out = jnp.where(lane >= t * dv,
-                            kq_ref[0, :, first + t:first + t + 1], out)
+            if col(first + t) != col(first + t - 1):
+                c = col(first + t)
+                out = jnp.where(lane >= t * dv, kq_ref[0, :, c:c + 1], out)
         return out
 
     @pl.when(live_ref[i] != 0)
     def _():
         for p in range(n // group):
             cols = slice(p * W, (p + 1) * W)
-            k, q = columns(p * group), columns(n + p * group)
+            k, q = columns(0, p * group), columns(n_k, p * group)
             s = rows_ref[0, row, cols] * s_ref[0, :, cols]
             u = rows_ref[1, row, cols] * (
                 rows_ref[2, row, cols] - jnp.sum(k * s, axis=0,
@@ -139,15 +151,16 @@ def gdn_decode_step(q, k, v, g, beta, state, *,
     """One token of the gated delta rule for every slot, live slots' state
     moved once in and once out, in place.
 
-    q, k (B, n, dk), v (B, n, dv), g, beta (B, n), all float32; ``state``
-    (B, dk, n * dv) float32, the slots as stored
+    q, k (B, n_k, dk), v (B, n, dv), g, beta (B, n), all float32, ``n`` a
+    multiple of ``n_k`` (value head ``r`` reads key head ``r // (n /
+    n_k)``); ``state`` (B, dk, n * dv) float32, the slots as stored
     (``gdn_hybrid.slot_shape``).  Returns ``o`` (B, n, dv) and the new
     state, which is ``state``'s buffer where the caller donates it.  A
     slot whose ``g`` and ``beta`` are all 0 is not read or written and
     gets ``o = 0``.  ``interpret`` None: compiled on a TPU, interpreted
     elsewhere."""
-    B, n, dk = q.shape
-    dv = v.shape[-1]
+    B, n, dv = v.shape
+    dk = q.shape[-1]
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if not interpret and not step_kernel_takes(n, dk, dv):
@@ -181,7 +194,7 @@ def _step(src, live, kq, rows, state, *, n: int, interpret: bool):
             num_scalar_prefetch=2,
             grid=(B,),
             in_specs=[
-                pl.BlockSpec((1, dk, 2 * n),
+                pl.BlockSpec((1, dk, kq.shape[2]),
                              lambda i, src, live: (src[i], 0, 0)),
                 pl.BlockSpec((3, R, width), lambda i, *_: (0, i // R, 0)),
                 slot],

@@ -44,6 +44,7 @@ future work).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import time
@@ -60,7 +61,8 @@ from ..models.generate import _decode_cfg, _quant_kv
 from ..ops import collectives as C
 from ..telemetry.spans import maybe_span
 from ..utils.profiling import scope
-from .kv_pool import PagedKVPool, PoolBuffers, RadixPrefixCache
+from .kv_pool import (PagedKVPool, PoolBuffers, RadixPrefixCache,
+                      row_layout, slab_pool)
 from .scheduler import ContinuousBatcher, DECODE, PREFILL, Request
 
 __all__ = ["ServingEngine", "serve", "make_serve_decode_step",
@@ -92,6 +94,15 @@ def _apply_rope_ragged(x, cos, sin):
     s = sin[:, :, None, :]
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
                            axis=-1).astype(dt)
+
+
+@contextlib.contextmanager
+def _scopes(*names):
+    """Nested ``scope``s, outermost first."""
+    with contextlib.ExitStack() as stack:
+        for name in names:
+            stack.enter_context(scope(name))
+        yield
 
 
 def _paged_layer_body(x, layer, *, cfg, cos, sin, use_rope, pk, pv,
@@ -138,7 +149,7 @@ def _paged_layer_body(x, layer, *, cfg, cos, sin, use_rope, pk, pv,
 
 
 def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
-                  valid, paged_kernel=False):
+                  valid, paged_kernel=False, kernel_scope=None, slab=False):
     """The new K/V rows into their pages, then causal attention of the
     rows at ``apos`` against their slots' pages:
 
@@ -156,13 +167,18 @@ def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
         Pallas kernels.
 
     q (B, S, nq, hd), k, v (B, S, nkv, hd) in ``dtype``; the pools
-    (n_pages, page, nkv, hd) (+ scales of an int8 pool); pages (B, P);
-    apos, valid (B, S).  Returns the heads' outputs float32
+    (n_pages, page, nkv, hd) (+ scales of an int8 pool), or with ``slab``
+    (the caller's ``kv_pool.slab_pool(cfg)``) stored as the kernels' slab
+    (n_pages, page * nkv, hd): a float pool's row ``w`` of a page is token
+    ``w // nkv``, head ``w % nkv``; pages (B, P);
+    apos, valid (B, S).  ``kernel_scope`` names one more scope beneath
+    ``attn_core`` round the attention itself (a block that wants its paged
+    attention read apart; None opens none).  Returns the heads' outputs float32
     (B, S, nkv, nq / nkv, hd) and the pools
     ``(pk, pv, pk_s, pv_s)``."""
     B, S, nq, hd = q.shape
     nkv = k.shape[2]
-    page = pk.shape[1]
+    page = pk.shape[1] // nkv if slab else pk.shape[1]
     P = pages.shape[1]
     V = P * page
 
@@ -181,11 +197,20 @@ def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
             pv = pv.at[pg, off].set(vq)
             pk_s = pk_s.at[pg, off].set(ks_new)
             pv_s = pv_s.at[pg, off].set(vs_new)
+        elif slab:
+            row = (off * nkv)[..., None] + jnp.arange(nkv)
+            pk = pk.at[pg[..., None], row].set(k)
+            pv = pv.at[pg[..., None], row].set(v)
         else:
             pk = pk.at[pg, off].set(k)
             pv = pv.at[pg, off].set(v)
 
     rep = nq // nkv
+    # the kernels take (n_pages, page, nkv, hd) and read it as the slab:
+    # of a pool stored as the slab, a view and back
+    as_pages = (lambda a: a.reshape(-1, page, nkv, hd)) if slab \
+        else (lambda a: a)
+    core = _scopes(*filter(None, ("attn_core", kernel_scope)))
     if paged_kernel and S == 1:
         # Pallas decode kernel: pages are read IN PLACE via the table,
         # bounded by each slot's length — the (B, V, nkv, hd) gather
@@ -193,7 +218,7 @@ def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
         # bitwise for int8 pools, to float32 summation order for float
         # pools (ops/paged_attention.py).
         from ..ops.paged_attention import paged_attention_decode
-        with scope("attn_core"):
+        with core:
             qg = q.reshape(B, S, nkv, rep, hd)
             if quantized:
                 qq, q_s = _quant_kv(qg)
@@ -201,9 +226,9 @@ def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
                     qq, pk, pv, pages, apos, q_scale=q_s,
                     pk_s=pk_s, pv_s=pv_s)
             else:
-                attn = paged_attention_decode(qg, pk, pv, pages, apos,
-                                              valid=valid,
-                                              probs_dtype=dtype)
+                attn = paged_attention_decode(
+                    qg, as_pages(pk), as_pages(pv), pages, apos,
+                    valid=valid, probs_dtype=dtype)
         return attn, (pk, pv, pk_s, pv_s)
 
     if paged_kernel and not quantized:
@@ -214,10 +239,11 @@ def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
         # Equal to the gather path to float32 summation order
         # (ops/flash_prefill.py); int8 pools keep the gather path.
         from ..ops.flash_prefill import paged_flash_prefill
-        with scope("attn_core"):
+        with core:
             qg = q.reshape(B, S, nkv, rep, hd)
-            attn = paged_flash_prefill(qg, pk, pv, pages, apos,
-                                       valid=valid, probs_dtype=dtype)
+            attn = paged_flash_prefill(qg, as_pages(pk), as_pages(pv),
+                                       pages, apos, valid=valid,
+                                       probs_dtype=dtype)
         return attn, (pk, pv, pk_s, pv_s)
 
     # gather the slot's pages into the contiguous head-major view the
@@ -232,7 +258,7 @@ def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
             vk_s = pk_s[pages].reshape(B, V, nkv, 1).transpose(0, 2, 1, 3)
             vv_s = pv_s[pages].reshape(B, V, nkv, 1).transpose(0, 2, 1, 3)
 
-    with scope("attn_core"):
+    with core:
         if quantized:
             qq, q_s = _quant_kv(qg)
             scores_i = jnp.einsum("bsgrh,bgkh->bgrsk", qq, vk,
@@ -350,9 +376,13 @@ def _paged_latent_forward(params, ids, cfg, bufs: PoolBuffers, pages,
 
 def _paged_hybrid_forward(params, ids, cfg, bufs: PoolBuffers, pages,
                           apos, valid, paged_kernel=False, slot=None):
-    """``_paged_forward`` for the gated delta-rule hybrid block
-    (``models/gdn_hybrid.py`` holds its pieces): ``params["layers"]`` is a
-    tuple of per-layer dicts of two kinds, and so is the per-request state.
+    """``_paged_forward`` for the blocks of gated delta-rule layers and
+    full-attention layers: ``models/gdn_hybrid.py`` holds the linear mixer
+    and the layer pattern for both, and ``cfg.block_module`` (that module,
+    or ``models/gdn_moe.py`` with an expert layer under every mixer) what
+    differs between them: the residual path, the full-attention mixer's
+    projections and output, the MLP.  ``params["layers"]`` is a tuple of
+    per-layer dicts of two kinds, and so is the per-request state.
 
     A FULL-ATTENTION layer caches K/V rows in its own page pools
     (``bufs.k[f]``/``bufs.v[f]``, ``f`` counting the full layers only)
@@ -379,30 +409,39 @@ def _paged_hybrid_forward(params, ids, cfg, bufs: PoolBuffers, pages,
         ``gdn_hybrid.chunked_scan`` over the chunk and writes both back;
         rows past the prompt's end change neither.
 
-    Under the catalogue's scopes: ``attn_qkv`` (projections, conv, SiLU,
-    the norms of q and k, beta and alpha; the conv under ``lin_conv``),
-    ``kv_write``, ``attn_core`` (attention; the scan under ``lin_scan``,
-    the step under ``lin_step``), ``attn_out`` (gated norm, ``w_o`` /
-    ``wo``, the post-mixer norm), ``mlp``.  Returns ``(x', bufs',
-    counts)``; ``counts`` is int32 (1,): the rows of this call whose
-    state was live, a decode step's ``state_slot_steps``."""
+    Under the catalogue's scopes: ``attn_qkv`` (a pre-mixer norm,
+    projections, conv, SiLU, the norms of q and k, rotary embedding, beta
+    and alpha; the conv under ``lin_conv``), ``kv_write``, ``attn_core``
+    (attention, for the block with expert layers under ``attn_paged``; the
+    scan under ``lin_scan``, the step under ``lin_step``), ``attn_out``
+    (gates, ``w_o`` / ``wo``, a post-mixer norm), ``mlp`` (an expert layer
+    under ``moe_route`` / ``moe_experts`` / ``moe_shared``).  Returns
+    ``(x', bufs', counts)``; ``counts`` is int32: the expert layers'
+    ``mla_moe.moe_counts`` summed, where the block has them, and then the
+    rows of this call whose state was live, a decode step's
+    ``state_slot_steps``."""
+    blk = cfg.block_module
     decode = slot is None
     with scope("embed"):
         x = params["embed"].astype(cfg.dtype)[ids]
+        rope = blk.rope_tables(apos, cfg)
     B, S, _ = x.shape
     ks, vs = list(bufs.k), list(bufs.v)
     states, tails = list(bufs.state), list(bufs.conv)
     fresh = None if decode else apos[0, 0] == 0
+    moe = None      # the expert layers' counters, where the block has them
     f = j = 0
     for li, layer in enumerate(params["layers"]):
         if G.is_full_layer(li, cfg):
             with scope("attn_qkv"):
-                q, k, v = G.attention_qkv(x, layer, cfg=cfg)
+                q, k, v, gate = blk.attention_qkv(
+                    blk.mixer_input(x, layer, cfg=cfg), layer, cfg=cfg,
+                    rope=rope)
                 # the pool's row may hold zero heads after the real ones
                 # (kv_pool.padded_kv_heads): their keys and values are 0,
                 # their queries' outputs are dropped
                 nkv, rep = k.shape[2], q.shape[2] // k.shape[2]
-                extra = ks[f].shape[2] - nkv
+                extra = row_layout(cfg)[0][0] - nkv
                 if extra:
                     k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, extra), (0, 0)))
                             for a in (k, v))
@@ -411,11 +450,11 @@ def _paged_hybrid_forward(params, ids, cfg, bufs: PoolBuffers, pages,
             attn, (ks[f], vs[f], _, _) = _paged_attend(
                 q, k, v, dtype=x.dtype, pk=ks[f], pv=vs[f], pk_s=None,
                 pv_s=None, pages=pages, apos=apos, valid=valid,
-                paged_kernel=paged_kernel)
+                paged_kernel=paged_kernel,
+                kernel_scope=blk.PAGED_ATTENTION_SCOPE, slab=slab_pool(cfg))
             with scope("attn_out"):
-                h = G.add_mixer(x, T._dense(cfg)(
-                    attn[:, :, :nkv].astype(x.dtype).reshape(B, S, -1),
-                    layer["wo"]), layer, cfg=cfg)
+                h = blk.attention_output(attn[:, :, :nkv], gate, x, layer,
+                                         cfg=cfg)
             f += 1
         else:
             if decode:
@@ -423,13 +462,14 @@ def _paged_hybrid_forward(params, ids, cfg, bufs: PoolBuffers, pages,
             else:
                 s0 = G.unpack_state(
                     jax.lax.dynamic_slice_in_dim(states[j], slot, 1),
-                    cfg.linear_num_key_heads)
+                    cfg.linear_num_value_heads)
                 t0 = jax.lax.dynamic_slice_in_dim(tails[j], slot, 1)
                 s0 = jnp.where(fresh, jnp.zeros_like(s0), s0)
                 t0 = jnp.where(fresh, jnp.zeros_like(t0), t0)
             with scope("attn_qkv"):
+                r = blk.mixer_input(x, layer, cfg=cfg)
                 q, k, v, g, beta, t1 = G.linear_inputs(
-                    x, layer, t0, valid, cfg=cfg)
+                    r, layer, t0, valid, cfg=cfg)
             with scope("attn_core"):
                 if decode:
                     with scope("lin_step"), G.step_kernel(paged_kernel):
@@ -448,14 +488,17 @@ def _paged_hybrid_forward(params, ids, cfg, bufs: PoolBuffers, pages,
                 tails[j] = jax.lax.dynamic_update_slice_in_dim(
                     tails[j], t1.astype(tails[j].dtype), slot, axis=0)
             with scope("attn_out"):
-                h = G.add_mixer(x, G.linear_output(o, x, layer, cfg=cfg),
-                                layer, cfg=cfg)
+                h = blk.linear_mixer_output(o, r, x, layer, cfg=cfg)
             j += 1
         with scope("mlp"):
-            x = G.mlp(h, layer, cfg=cfg)
-    live = jnp.sum(jnp.any(valid, axis=1).astype(jnp.int32))
+            x, counts = blk.mlp(h, layer, cfg=cfg, valid=valid)
+            if counts is not None:
+                moe = counts if moe is None else moe + counts
+    live = jnp.sum(jnp.any(valid, axis=1).astype(jnp.int32))[None]
+    if moe is not None:
+        live = jnp.concatenate([moe, live])
     return x, bufs._replace(k=tuple(ks), v=tuple(vs), state=tuple(states),
-                            conv=tuple(tails)), live[None]
+                            conv=tuple(tails)), live
 
 
 def _paged_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
@@ -506,7 +549,10 @@ def _all_logits(params, x, cfg):
     unembedding are per-row ops, so row ``i`` is bitwise the
     single-position tail evaluated at that position — what lets the
     speculative verify step read k+1 greedy tokens from one forward."""
-    x = T.rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    if cfg.gdn_hybrid:      # the block's own: plain, or zero-centred
+        x = cfg.block_module.final_norm(x, params, cfg)
+    else:
+        x = T.rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     uq = params.get("unembed_q")
     if uq is not None:
         from ..ops.quant import prequantized_dense
@@ -520,6 +566,15 @@ def _last_logits(params, x_last, cfg):
     """(B, 1, H) hidden → (B, vocab) fp32 logits, same tail as
     ``generate._forward_cached``."""
     return _all_logits(params, x_last, cfg)[:, 0]
+
+
+def device_counters(cfg) -> tuple:
+    """The names of what the block's decode program sums on the device,
+    in the order of ``_decode_core``'s ``counted``: the expert layers'
+    ``mla_moe.COUNTERS``, then the hybrids' ``state_slot_steps``; () for
+    the dense block, whose program takes no ``counted``."""
+    out = M.COUNTERS if cfg.mla_moe or cfg.gdn_moe else ()
+    return out + (G.COUNTERS[:1] if cfg.gdn_hybrid else ())
 
 
 def _decode_core(bufs, params, pages, toks, lengths, stop_at, active,
@@ -1136,11 +1191,8 @@ class ServingEngine:
         # prefill chunks scanned), as is ``lin_step_inplace_steps``: decode
         # steps whose recurrence was the step kernel, which moves a live
         # state once in and once out in place and no other
-        self._device_counters: tuple = ()
-        if self.cfg.mla_moe:
-            self._device_counters = M.COUNTERS
-        elif self.cfg.gdn_hybrid:
-            self._device_counters = G.COUNTERS[:1]
+        self._device_counters = device_counters(self.cfg)
+        if self.cfg.gdn_hybrid:
             self.stats.update(dict.fromkeys(
                 G.COUNTERS[1:] + ("lin_step_inplace_steps",), 0))
         self._counted_zero = None

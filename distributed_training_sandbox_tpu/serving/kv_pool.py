@@ -94,6 +94,34 @@ def row_layout(cfg, tp: int = 1) -> tuple[tuple[int, ...], bool]:
     return (cfg.num_key_value_heads // tp, cfg.resolved_head_dim), True
 
 
+def slab_pool(cfg) -> bool:
+    """Whether a paged layer's pools are STORED as the slab the paged
+    kernels read, ``(n_pages, page_size * heads, hd)``, and not as
+    ``(n_pages, page_size, heads, hd)``: where a head is wider than one
+    128-lane tile and the row's heads do not fill whole sublane tiles.  A
+    TPU holds the 4-D array's ``(heads, hd)`` minor dims in tiles of
+    ``heads`` rows by 128 lanes, one lane tile after the other, so with 2
+    heads of 256 a page is no ``(32, 256)`` slab of it and XLA copies the
+    whole pool to make one, for every layer in every step (compiled for a
+    v5e: twelve 268 MB copies a decode step).  At a head dim of 128 the
+    4-D array's bytes ARE the slab's, whatever the head count, and the
+    pool stays 4-D.  Only for the hybrid blocks, whose engine refuses what
+    still indexes a pool by ``(page, offset, head)``: int8 rows with their
+    scales, the hand-over between pools, a tp mesh over the head axis."""
+    if not cfg.gdn_hybrid:
+        return False
+    (heads, hd), _ = row_layout(cfg)
+    return hd > 128 and heads % (32 // jnp.dtype(cfg.dtype).itemsize) != 0
+
+
+def pool_shape(cfg, n_pages: int, page_size: int) -> tuple[int, ...]:
+    """The shape of one paged layer's pool array as stored."""
+    row, _ = row_layout(cfg)
+    if slab_pool(cfg):
+        return (n_pages, page_size * row[0], row[1])
+    return (n_pages, page_size) + row
+
+
 def paged_layers(cfg) -> int:
     """Layers whose tokens cache a row in pages: what every sizing of the
     pool multiplies :func:`token_row_bytes` by.  All of them, but for the
@@ -135,6 +163,7 @@ class PoolBuffers(NamedTuple):
     ``state``/``conv`` are the state slots of the gated delta-rule
     hybrid's linear layers, one array a linear layer, None elsewhere."""
     k: tuple            # L × (n_pages, page_size, n_kv, hd) | (.., .., W)
+    #                     | (n_pages, page_size * n_kv, hd): slab_pool
     v: tuple | None
     k_scale: tuple | None   # L × (n_pages, page_size, n_kv, 1) f32
     v_scale: tuple | None
@@ -423,7 +452,7 @@ class PagedKVPool:
             raise NotImplementedError(
                 "an int8 pool of latent rows is not built (the row "
                 "quantizer scales per KV head)")
-        shape = (self.n_pages, self.page_size) + row
+        shape = pool_shape(cfg, self.n_pages, self.page_size)
         dt = jnp.int8 if kv_quant else cfg.dtype
         put = self._put
         k = tuple(put(jnp.zeros(shape, dt)) for _ in range(L))

@@ -176,6 +176,20 @@ LINEAR_SUBSCOPES = (
 )
 
 
+#: A declared second level beneath ``attn_core`` round the paged attention
+#: of the full-attention layers of the hybrid with expert layers
+#: (``models/gdn_moe.py``; ``serving/engine._paged_attend`` opens it where
+#: that block's forward asks, in a decode step and in a prefill chunk, so
+#: the other blocks' programs keep their op names): the paged kernels'
+#: calls apart from the linear layers' step or scan, which share
+#: ``attn_core``.  ``benchmarks/layer_metrics/_attnscopes.py`` holds a copy
+#: of this tuple, pinned by a test.
+ATTENTION_SUBSCOPES = (
+    "attn_paged",  # the paged decode / flash prefill kernel's call (or,
+                   # off the chip, the gather path's scores, softmax and PV)
+)
+
+
 def scope(name: str):
     """Device-side marker for code *inside* jit: prefixes XLA op names so
     collectives/matmuls attribute to the phase in the trace.  Writes

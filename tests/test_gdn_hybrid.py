@@ -433,6 +433,31 @@ def test_the_engine_refuses_what_is_not_built_for_the_block(model, kw, what):
         ServingEngine(params, cfg, **kw)
 
 
+@pytest.mark.parametrize("over", [
+    {"linear_num_value_heads": 6}, {"linear_allow_neg_eigval": False},
+    {"linear_num_value_heads": 6, "linear_allow_neg_eigval": False}],
+    ids=["grouped", "beta01", "both"])
+def test_grouped_value_heads_and_a_beta_in_0_1_are_served(over):
+    """What ``check_config`` refused until the linear mixer was generalised
+    (two value heads a key head; ``beta = sigmoid`` without the factor 2):
+    the engine's prefill in chunks and decode through slots (the step
+    kernel interpreted) against the cache-less whole-sequence forward at
+    those settings (the plain reference of THIS block is written for its
+    published variant; ``tests/test_gdn_moe.py`` holds grouped heads and
+    ``beta = sigmoid`` to a reference's token scan)."""
+    fields, cfg, params = make(seed=4, **over)
+    assert G.state_shape(cfg)[0] == fields["linear_num_value_heads"]
+    assert G.conv_channels(cfg) == 2 * 3 * 8 \
+        + fields["linear_num_value_heads"] * 16
+    prompt = np.random.default_rng(9).integers(1, 256, 21).astype(np.int32)
+    with jax.default_matmul_precision("highest"):
+        z, _, _ = _serve_logits(params, cfg, prompt, 4, kernel=True)
+        toks = np.asarray(jnp.argmax(z, axis=-1))
+        ids = np.concatenate([prompt, toks[:-1]]).astype(np.int32)[None]
+        want = T.forward(params, jnp.asarray(ids), cfg)[0, 20:]
+    np.testing.assert_allclose(z, want, atol=3e-4)
+
+
 @pytest.mark.parametrize("name", [
     "fsdp", "fsdp_auto", "sp", "tp", "pipeline", "moe_lm", "composable",
     "generate", "init_cache", "layer_hook", "flops"])
@@ -469,12 +494,16 @@ def test_training_and_the_one_shot_decoder_refuse_the_block(model, name):
 @pytest.mark.parametrize("over,match", [
     ({"full_attention_interval": 0}, r"needs \['full_attention_interval'\]"),
     ({"linear_value_head_dim": 0}, r"needs \['linear_value_head_dim'\]"),
-    ({"linear_num_value_heads": 6}, "one value head a key head"),
+    ({"linear_num_value_heads": 4}, "a multiple of linear_num_key_heads"),
     ({"linear_conv_kernel_dim": 1}, "linear_conv_kernel_dim must be >= 2"),
-    ({"linear_allow_neg_eigval": False}, "linear_allow_neg_eigval=True only"),
     ({"nope_interval": 4}, "nope_interval=0 only"),
     ({"tie_word_embeddings": True}, "tie_word_embeddings=False only"),
     ({"attention_impl": "flash"}, "attention_impl='xla' only"),
+    ({"n_experts": 4}, "n_experts=0 only"),
+    ({"shared_expert_intermediate_size": 32},
+     "belongs to the hybrid with expert layers"),
+    ({"partial_rotary_factor": 0.5},
+     "belongs to the hybrid with expert layers"),
 ])
 def test_a_variant_the_block_does_not_build_is_refused_by_name(over, match):
     with pytest.raises(ValueError, match=match):

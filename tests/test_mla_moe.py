@@ -455,6 +455,11 @@ def test_training_and_the_one_shot_decoder_refuse_the_block(model, name):
     ({"q_lora_rank": 0}, r"needs \['q_lora_rank'\]"),
     ({"expert_offset": 14}, "not among the router's 16"),
     ({"num_experts_per_tok": 17}, "exceeds router_width"),
+    ({"first_k_dense_replace": 9}, "first_k_dense_replace must lie in"),
+    ({"qk_rope_head_dim": 7}, "qk_rope_head_dim must be even"),
+    ({"n_experts": 4}, "n_experts=0 only"),
+    ({"num_experts": 4}, "num_experts=0 only"),
+    ({"attention_impl": "flash"}, "attention_impl='xla' only"),
 ])
 def test_a_variant_the_block_does_not_build_is_refused_by_name(over, match):
     with pytest.raises(ValueError, match=match):
