@@ -78,9 +78,21 @@ k_j u_j^T`` and the ``u`` solve the unit lower-triangular system::
 
 ``T = (I + A)^-1`` does not depend on ``S_0``: it, ``T (beta V)`` and
 ``T (beta G K)`` are computed for all sub-chunks at once and only the
-three products with the state run in sequence.  A row past the prompt's
-end has ``beta = 0`` and ``g = 0``: its ``u`` is 0 and it changes no
-state.
+three products with the state run in sequence.  ``T`` is obtained by
+forward substitution (:func:`unit_lower_inverse`): row ``i`` of ``T`` is
+``e_i - sum_{j<i} A_ij T_j``, ``SCAN_CHUNK - 1`` row updates in sequence,
+each one an elementwise float32 pass over every system of the chunk at
+once (sub-chunks x heads of them, side by side on the lanes: 240 a layer
+at Olmo-Hybrid's widths, 128 at Qwen3-Next's).  These are a triangular
+solve's own operations in its own order, so ``T`` is exact where the solve
+is: with ``beta = 2`` and a repeated key ``A`` is 2 everywhere below the
+diagonal, the entries of ``T`` stay at 2 (``|1 - beta k.k| <= 1``: the
+recurrence does not grow) while the POWERS of ``A`` reach 1e6 in 16 rows,
+which is why no series in ``A`` and no product of partial inverses is
+used (PERF.md, PR 34: XLA's ``triangular_solve`` ran these rows one system
+block after another, 10.8 ms a 512-row chunk on a v5e; this loop takes
+0.7).  A row past the prompt's end has ``beta = 0`` and ``g = 0``: its
+``u`` is 0 and it changes no state.
 
 Parameter tree: ``embed`` (V, H), ``lm_head`` (H, V), ``final_norm`` (H,)
 and ``layers``, a tuple of one dict a layer (two kinds, nothing stacked).
@@ -94,6 +106,7 @@ Every layer holds ``post_attn_norm``, ``post_mlp_norm``, ``w_gate``,
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 
 import jax
@@ -420,6 +433,31 @@ def recurrent_step(q, k, v, g, beta, state):
     return jnp.sum(col(q) * s, axis=1).reshape(B, n, dv), s
 
 
+def unit_lower_inverse(A):
+    """``(I + A)^-1`` for strictly lower-triangular ``A`` (..., C, C)
+    float32, by forward substitution: row ``i`` of the inverse is ``e_i``
+    less the rows above it weighted by ``A``'s row ``i``, ``C - 1``
+    sequential row updates, each over ALL the systems at once (they lie
+    side by side on the last axis, the lanes, a system's own (C, C)
+    leading).  Elementwise float32 products and sums: no matrix product, no
+    power of ``A``, the operations of a triangular solve against the
+    identity in their order (module docstring: how ``T`` is obtained)."""
+    C = A.shape[-1]
+    L = jnp.moveaxis(A.reshape(-1, C, C), 0, -1)          # (C, C, M)
+    index = functools.partial(lax.dynamic_index_in_dim, axis=0,
+                              keepdims=False)
+
+    def row(i, T):
+        # A[i, j] is 0 from the diagonal on, so the rows of T not yet
+        # substituted (still the identity's) add nothing
+        above = jnp.sum(index(L, i)[:, None, :] * T, axis=0)
+        return lax.dynamic_update_index_in_dim(T, index(T, i) - above, i, 0)
+
+    T = lax.fori_loop(1, C, row, jnp.broadcast_to(
+        jnp.eye(C, dtype=A.dtype)[:, :, None], L.shape))
+    return jnp.moveaxis(T, -1, 0).reshape(A.shape)
+
+
 def chunked_scan(q, k, v, g, beta, state):
     """The recurrence over S rows from a carried ``state`` (B, n, dk, dv):
     q, k (B, S, n_k, dk), v (B, S, n, dv), g, beta (B, S, n), all float32.
@@ -451,10 +489,7 @@ def chunked_scan(q, k, v, g, beta, state):
     D = jnp.where(tri, jnp.exp(jnp.where(tri, diff, 0.0)), 0.0)
     kk = jnp.einsum("...ik,...jk->...ij", k, k)
     A = jnp.where(jnp.tril(tri, -1), beta[..., None] * D * kk, 0.0)
-    T = jax.scipy.linalg.solve_triangular(
-        A + jnp.eye(C, dtype=A.dtype), jnp.broadcast_to(
-            jnp.eye(C, dtype=A.dtype), A.shape),
-        lower=True, unit_diagonal=True)
+    T = unit_lower_inverse(A)
     G = jnp.exp(cum)
     u0 = T @ (beta[..., None] * v)                        # (.., C, dv)
     w = T @ ((beta * G)[..., None] * k)                   # (.., C, dk)

@@ -28,6 +28,9 @@ from distributed_training_sandbox_tpu.serving import engine as E  # noqa: E402
 from distributed_training_sandbox_tpu.serving.kv_pool import (  # noqa: E402
     PagedKVPool, PoolBuffers, padded_kv_heads, paged_layers, row_layout,
     slot_state_bytes, token_row_bytes)
+from tests.gdn_scan_cases import (  # noqa: E402
+    CASES, assert_as_exact_as_the_solve, errors, neumann_inverse,
+    recurrence64, scan_case)
 
 FIELDS = dict(
     vocab_size=256, hidden_size=64, intermediate_size=160,
@@ -110,6 +113,63 @@ def test_chunked_scan_is_the_token_recurrence_from_a_carried_state(rows):
         o_want, s_want = _token_by_token(q, k, v, g, beta, s0)
     np.testing.assert_allclose(o, o_want, atol=3e-5)
     np.testing.assert_allclose(s, s_want, atol=3e-5)
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_the_scan_at_64_rows_is_as_exact_as_the_solve_on_repeated_keys(case):
+    """``chunked_scan`` at the sub-chunk the cells run (64 rows) against
+    the recurrence in float64, on keys that repeat with beta at 2
+    (``tests/gdn_scan_cases.py``): on ``o`` and on the final state, both of
+    size ~1, the error is no more than twice what the same scan reads with
+    ``(I + A)^-1`` from ``jax.scipy.linalg.solve_triangular`` (which the
+    scan called until PR 34) and never over 1e-3.  Measured, float32, this
+    CPU, three heads, largest error of o | of the state: identical keys
+    2.0e-5 | 2.4e-5 (solve 2.0e-5 | 2.4e-5), with alpha 0.999 2.8e-5 |
+    4.6e-5 (3.1e-5 | 3.7e-5), runs of 16 6.5e-6 | 6.1e-6 (6.5e-6 | 4.5e-6),
+    alternating 9.4e-6 | 1.8e-5 (the same), random 6.4e-7 | 6.1e-7 (6.6e-7
+    | 6.1e-7): forward substitution is the solve's own arithmetic.  PR
+    33's form (16-row blocks by their Neumann series) reads 0.46 | 0.90,
+    0.50 | 0.82, 0.052 | 0.091, 0.49 | 0.82 on the first four and FAILS
+    this test (the next test holds that reading); 16-row blocks by
+    substitution merged by float32 products, what ISSUE 34 proposed, read
+    up to 4 x the solve's error on (a) and (d) over sixteen seeds: the
+    merge's two products cancel terms of size 4 to entries of size 2."""
+    assert_as_exact_as_the_solve(G, scan_case(case, nk=3, n=3))
+
+
+@pytest.mark.parametrize("case", CASES[:4])
+def test_the_repeated_keys_refuse_an_inverse_by_neumann_series(
+        monkeypatch, case):
+    """The test above has the power it was asked for: with the 16-row
+    blocks inverted by their finite Neumann series (what PR 33 measured
+    and its review took out) the same inputs read errors of 0.05 to 0.9,
+    where the series' partial terms reach 1e6 before they cancel.  On
+    random keys (the fifth case) the series passes, which is why random
+    tokens never showed it."""
+    monkeypatch.setattr(G, "SCAN_CHUNK", 64)
+    monkeypatch.setattr(G, "unit_lower_inverse", neumann_inverse)
+    args = scan_case(case, nk=3, n=3)
+    with jax.default_matmul_precision("highest"):
+        err = errors(G.chunked_scan(*args), recurrence64(*args))
+    assert min(err) > 1e-2
+
+
+@pytest.mark.parametrize("rows", [1, 4, 13, 64])
+def test_the_inverse_is_the_inverse_at_any_size(rows):
+    A = jnp.tril(0.5 * jax.random.normal(jax.random.key(rows),
+                                          (2, 3, rows, rows)), -1)
+    T = G.unit_lower_inverse(A)
+    want = np.linalg.inv(np.eye(rows) + np.asarray(A, np.float64))
+    np.testing.assert_allclose(T, want, atol=1e-4 * np.abs(want).max())
+    assert np.array_equal(np.triu(np.asarray(T), 1), np.zeros_like(T))
+
+
+def test_the_scan_calls_no_triangular_solve():
+    """One path: ``gdn_hybrid`` neither imports nor calls
+    ``solve_triangular``, and names no switch back to it."""
+    src = Path(G.__file__).read_text()
+    assert "solve_triangular" not in src and "scipy" not in src
+    assert "os.environ" not in src
 
 
 def test_rows_past_the_end_change_no_state():
@@ -586,7 +646,10 @@ def test_both_programs_lower_for_tpu_at_published_widths(monkeypatch):
          sd((), jnp.int32), sd((), jnp.int32), sd((), jnp.int32)))
     assert "tpu_custom_call" in text and "_prefill_float" in text
     assert f"1x{P * page}x" not in text
-    assert "triangular_solve" in text               # the chunked scan
+    # the chunked scan: its inverse is a loop of row updates over the 240
+    # systems side by side (PR 34), no triangular solve in the program
+    assert "triangular_solve" not in text and "triangular-solve" not in text
+    assert "tensor<64x64x240xf32>" in text
     assert "_step_kernel" not in text               # the scan keeps XLA
     assert "tensor<64x96x5760xf32>" in text
 

@@ -32,6 +32,8 @@ from distributed_training_sandbox_tpu.serving import engine as E  # noqa: E402
 from distributed_training_sandbox_tpu.serving.kv_pool import (  # noqa: E402
     PagedKVPool, paged_layers, pool_shape, row_layout, slab_pool,
     slot_state_bytes, token_row_bytes)
+from tests.gdn_scan_cases import (  # noqa: E402
+    CASES, assert_as_exact_as_the_solve, recurrence64, scan_case)
 
 FIELDS = dict(
     vocab_size=256, hidden_size=64, intermediate_size=160,
@@ -124,26 +126,6 @@ def _token_by_token(q, k, v, g, beta, s, step=G.recurrent_step):
     return jnp.stack(out, axis=1), G.unpack_state(s, v.shape[2])
 
 
-def _reference_scan(q, k, v, g, beta, s0):
-    """The reference's recurrence, value head r on key head r // (n / n_k),
-    written out head by head in numpy float64."""
-    q, k, v, g, beta, s = (np.asarray(a, np.float64)
-                           for a in (q, k, v, g, beta, s0))
-    B, S, n, dv = v.shape
-    per = n // q.shape[2]
-    out = np.zeros((B, S, n, dv))
-    s = s.copy()
-    for b in range(B):
-        for t in range(S):
-            for h in range(n):
-                kt, qt = k[b, t, h // per], q[b, t, h // per]
-                sh = np.exp(g[b, t, h]) * s[b, h]
-                u = beta[b, t, h] * (v[b, t, h] - kt @ sh)
-                s[b, h] = sh + np.outer(kt, u)
-                out[b, t, h] = qt @ s[b, h]
-    return out, s
-
-
 @pytest.mark.parametrize("zero_state", [False, True], ids=["carried", "zero"])
 @pytest.mark.parametrize("rows", [13, 16, 1, 5])
 def test_the_three_forms_agree_at_grouped_heads(rows, zero_state):
@@ -152,7 +134,7 @@ def test_the_three_forms_agree_at_grouped_heads(rows, zero_state):
     q, k, v, g, beta, s0 = _inputs(1, S=rows)
     if zero_state:
         s0 = jnp.zeros_like(s0)
-    o_want, s_want = _reference_scan(q, k, v, g, beta, s0)
+    o_want, s_want = recurrence64(q, k, v, g, beta, s0)
     with jax.default_matmul_precision("highest"):
         o, s = G.chunked_scan(q, k, v, g, beta, s0)
         o_t, s_t = _token_by_token(q, k, v, g, beta, s0)
@@ -166,7 +148,7 @@ def test_a_wrong_key_head_is_another_recurrence():
     """The grouping is visible to the test: value heads paired with the
     other key head give other outputs."""
     q, k, v, g, beta, s0 = _inputs(2)
-    o_want, _ = _reference_scan(q, k, v, g, beta, s0)
+    o_want, _ = recurrence64(q, k, v, g, beta, s0)
     o, _ = _token_by_token(q[:, :, ::-1], k[:, :, ::-1], v, g, beta, s0)
     assert float(np.max(np.abs(np.asarray(o) - o_want))) > 1e-2
 
@@ -228,11 +210,24 @@ def test_the_scan_at_the_published_sub_chunk_is_the_token_recurrence(
     and a part, from a carried state."""
     monkeypatch.setattr(G, "SCAN_CHUNK", 64)
     q, k, v, g, beta, s0 = _inputs(5, B=1, S=rows)
-    o_want, s_want = _reference_scan(q, k, v, g, beta, s0)
+    o_want, s_want = recurrence64(q, k, v, g, beta, s0)
     with jax.default_matmul_precision("highest"):
         o, s = G.chunked_scan(q, k, v, g, beta, s0)
     np.testing.assert_allclose(o, o_want, atol=1e-4)
     np.testing.assert_allclose(s, s_want, atol=1e-4)
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_the_grouped_scan_is_as_exact_as_the_solve_on_repeated_keys(case):
+    """``tests/test_gdn_hybrid.py``'s test on repeated keys at this
+    block's heads and gate: two value heads a key head (the repeated keys
+    reach BOTH heads' systems, whose decay and beta are their own), beta at
+    its bound of 1, 64-row sub-chunks, against the recurrence in float64.
+    Measured: 2.5e-7 to 4.1e-7 on ``o``, 1.6e-7 to 2.4e-7 on the state,
+    the solve's own readings to 20% (at beta 1 a repeated key has ``1 -
+    beta k.k = 0``, and the system is tame)."""
+    assert_as_exact_as_the_solve(
+        G, scan_case(case, nk=2, n=4, beta_max=1.0))
 
 
 # ------------------------------------------------- against the reference
@@ -615,6 +610,9 @@ def test_both_programs_lower_for_tpu_at_published_widths(monkeypatch):
          sd((), jnp.int32), sd((), jnp.int32), sd((), jnp.int32)))
     assert "_prefill_float" in text
     assert f"tensor<1x{P * page}x2x256" not in text
-    assert "triangular_solve" in text               # the chunked scan
+    # the chunked scan: no triangular solve (PR 34), its inverse a loop of
+    # row updates over the 4 x 32 systems of the sub-chunks side by side
+    assert "triangular_solve" not in text and "triangular-solve" not in text
+    assert "tensor<64x64x128xf32>" in text
     assert "_step_kernel" not in text               # the scan keeps XLA
     assert "tensor<4x1x32x64x64xf32>" in text       # the scan's sub-chunks
