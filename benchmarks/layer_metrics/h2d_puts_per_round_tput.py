@@ -1,0 +1,6 @@
+"""``h2d_puts_per_round``, in a serving cell that is judged on tokens per
+second."""
+from benchmarks.layer_metrics.h2d_puts_per_round import (  # noqa: F401
+    LAYER, RUNNERS, UNIT, read)
+
+MOVES = "serve_tokens_per_s"

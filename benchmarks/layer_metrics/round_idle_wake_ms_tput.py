@@ -1,0 +1,6 @@
+"""``round_idle_wake_ms``, in a serving cell that is judged on tokens per
+second."""
+from benchmarks.layer_metrics.round_idle_wake_ms import (  # noqa: F401
+    LAYER, RUNNERS, UNIT, read)
+
+MOVES = "serve_tokens_per_s"

@@ -12,6 +12,10 @@ at three policy points:
   * every ``sync_every`` steps (the ``--sync-every`` flag);
   * loop exit (``close()`` / the ``with`` exit, crash included).
 
+A wait at such a point is a ``pump/<reason>`` span; the reads that follow
+it (the losses retired since the last point, one blocking device-to-host
+read each) are one ``pump/resolve`` span that says how many (``reads``).
+
 Plus a fourth, non-policy wait: when ``max_in_flight`` losses are
 pending, the oldest is retired before dispatching further (backpressure,
 so an unbounded host can't race arbitrarily far ahead of the device).
@@ -164,8 +168,15 @@ class StepPump:
                       else "profile_boundary" if boundary
                       else "sync_every")
             self._block(loss, step=i, reason=reason)
-            self._drain()
-            lf = self._resolve_one(i, loss, log)
+            # the wait is over; every retired loss is now read back, one
+            # blocking device-to-host read each, which a trace would
+            # otherwise show as the caller's own time
+            from ..telemetry.spans import maybe_span
+            with maybe_span(getattr(self.telem, "spans", None),
+                            "pump/resolve", cat="pump", step=i,
+                            reads=len(self._pending) + 1):
+                self._drain()
+                lf = self._resolve_one(i, loss, log)
             self._count(reason)
             if self.telem is not None:
                 self.telem.step(loss=lf, tokens=tokens,

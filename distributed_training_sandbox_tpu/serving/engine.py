@@ -839,7 +839,13 @@ class ServingEngine:
     ``serve/burst_dispatch`` (the ``sync_every`` launches) /
     ``serve/burst_sync`` (the read-backs) / ``serve/bookkeep``.  Each
     carries the round number, and its ``rid`` where one request is
-    concerned."""
+    concerned.  Every crossing of the host-device boundary goes through
+    one method that counts it: :meth:`_launch` (a compiled program's
+    call, a ``serve/launch_dispatch`` span of its own inside its
+    ``*_dispatch``, with ``program`` and ``k``), :meth:`_put` and
+    :meth:`_read` (one blocking read an array); the stage and sync
+    spans say what they moved (``arrays``, ``bytes``), a dispatch span
+    the work it carries (``live`` slots, valid ``rows``)."""
 
     def __init__(self, params, cfg, *, mesh=None, tp_axis: str = "tp",
                  max_batch: int = 4, page_size: int = 8,
@@ -1179,7 +1185,13 @@ class ServingEngine:
                       "spec_accepted": 0,
                       # admission: requests seated and their summed
                       # wait from submission (DUE) to a slot
-                      "admitted": 0, "queue_wait_s": 0.0}
+                      "admitted": 0, "queue_wait_s": 0.0,
+                      # the three crossings of the host-device boundary,
+                      # counted where they happen: compiled programs
+                      # called (_launch), arrays shipped (_put) and
+                      # arrays read back, one blocking read each (_read)
+                      "launches": 0, "h2d_puts": 0, "h2d_bytes": 0,
+                      "d2h_reads": 0, "d2h_bytes": 0}
         # what a block counts on the device over the decode steps and a
         # burst's sync reads.  The latent block's expert layers
         # (mla_moe.moe_counts): (row, chosen expert) pairs over the
@@ -1268,8 +1280,27 @@ class ServingEngine:
         about."""
         return {**self._sp, "rid": req.rid, "trace_id": req.trace_id}
 
-    # ---- device-put helpers ------------------------------------------
+    # ---- the host-device boundary -------------------------------------
+    def _launch(self, sp: dict, program: str, k: int, fn, *args):
+        """Host -> device, a compiled program: the call alone is a
+        ``serve/launch_dispatch`` span naming the program and its index
+        ``k`` in its burst or chunk round, so a trace puts a launch's
+        start on the host beside its program's start on the chip."""
+        self.stats["launches"] += 1
+        with maybe_span(self._stream, "serve/launch_dispatch",
+                        program=program, k=k, **sp):
+            return fn(*args)
+
+    def _read(self, arrs: list) -> list[np.ndarray]:
+        """Device -> host: one blocking read an array."""
+        self.stats["d2h_reads"] += len(arrs)
+        self.stats["d2h_bytes"] += sum(a.nbytes for a in arrs)
+        return [np.asarray(a) for a in arrs]   # sync-ok: the one read site
+
     def _put(self, x, device=None):
+        """Host -> device, an array (a put does not block: no span)."""
+        self.stats["h2d_puts"] += 1
+        self.stats["h2d_bytes"] += x.nbytes
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
             return jax.device_put(x, NamedSharding(self.mesh, P()))
@@ -1285,13 +1316,20 @@ class ServingEngine:
         row[0, :len(pages)] = pages
         return row
 
-    def _prefill_one_chunk(self, req: Request, t0: float) -> None:
+    def _prefill_one_chunk(self, req: Request, t0: float, k: int) -> None:
+        """The ``k``-th prefill chunk of this round, of one request."""
         Ck = self.prefill_chunk
         pos = req.prefill_pos
         dev = self._prefill_dev
         stream, sp = self._stream, self._req_attrs(req)
+        rows = min(Ck, req.n_prompt - pos)
+        # what the stage ships: page row, ids, two scalars, and the
+        # hybrids' batch slot
+        n_put = 5 if self.cfg.gdn_hybrid else 4
         t_chunk = time.perf_counter()  # clock-ok
-        with maybe_span(stream, "serve/prefill_stage", **sp):
+        with maybe_span(stream, "serve/prefill_stage", arrays=n_put,
+                        bytes=4 * (self.pages_per_request + Ck + n_put - 2),
+                        **sp):
             chunk = req.prompt[pos:pos + Ck]
             ids = np.zeros((1, Ck), np.int32)
             ids[0, :chunk.shape[0]] = chunk
@@ -1307,9 +1345,10 @@ class ServingEngine:
             if self.cfg.gdn_hybrid:
                 # the batch slot whose state the chunk carries on
                 args += (self._put(np.int32(req.slot), dev),)
-                self.stats["lin_scan_rows"] += min(Ck, req.n_prompt - pos)
-        with maybe_span(stream, "serve/prefill_dispatch", **sp):
-            tok_d, bufs = self._prefill(bufs, self._params_pre, *args)
+                self.stats["lin_scan_rows"] += rows
+        with maybe_span(stream, "serve/prefill_dispatch", rows=rows, **sp):
+            tok_d, bufs = self._launch(sp, "prefill", k, self._prefill,
+                                       bufs, self._params_pre, *args)
             if self.disaggregate:
                 self.pool_pre.bufs = bufs
             else:
@@ -1318,7 +1357,8 @@ class ServingEngine:
                 # the draft needs the prompt's KV in ITS pool to propose
                 # — ride the same chunk schedule (same pages, draft
                 # params)
-                _dtok, dbufs = self._draft_prefill(
+                _dtok, dbufs = self._launch(
+                    sp, "draft_prefill", k, self._draft_prefill,
                     self.draft_pool.bufs, self._draft_params, *args)
                 self.draft_pool.bufs = dbufs
             req.prefill_pos = min(pos + Ck, req.n_prompt)
@@ -1327,28 +1367,31 @@ class ServingEngine:
             final = req.prefill_pos >= req.n_prompt
             if final and self.disaggregate:
                 # final chunk: hand the KV off to the decode slice
-                self._handoff(req, row)
+                self._handoff(req, row, sp, k)
         if not final:
             self.stats["prefill_s"] += time.perf_counter() - t_chunk  # clock-ok
             return
         # resolve the first token — prefill is synchronous at admission,
         # so this blocks the host by design and stamps TTFT at token
         # resolution
-        with maybe_span(stream, "serve/prefill_sync", **sp):
-            first = int(np.asarray(tok_d)[0])   # sync-ok: TTFT resolution
+        with maybe_span(stream, "serve/prefill_sync", arrays=1,
+                        bytes=tok_d.nbytes, **sp):
+            first = int(self._read([tok_d])[0][0])     # TTFT resolution
             self.stats["host_sync_count"] += 1
         self._finish_prefill(req, first, t_chunk, t0)
         self.stats["prefill_s"] += time.perf_counter() - t_chunk  # clock-ok
 
-    def _handoff(self, req: Request, row: np.ndarray) -> None:
+    def _handoff(self, req: Request, row: np.ndarray, sp: dict,
+                 k: int) -> None:
         """Disaggregated KV handoff of a request whose prefill is done:
         its page blocks leave the prefill pool for its decode pages."""
         dec_row = self._padded_row(req.pages)
-        blocks = self._extract(self.pool_pre.bufs,
-                               self._put(row[0], self._prefill_dev))
+        blocks = self._launch(sp, "extract", k, self._extract,
+                              self.pool_pre.bufs,
+                              self._put(row[0], self._prefill_dev))
         blocks = jax.device_put(blocks, self._decode_dev)
-        self.pool.bufs = self._inject(
-            self.pool.bufs, blocks,
+        self.pool.bufs = self._launch(
+            sp, "inject", k, self._inject, self.pool.bufs, blocks,
             self._put(dec_row[0], self._decode_dev))
         self.pool_pre.allocator.free(self._pre_pages.pop(req.rid))
 
@@ -1361,8 +1404,7 @@ class ServingEngine:
         # t_submit/t_admit/t_first ride along (engine-clock seconds) so
         # fleet_timeline can decompose TTFT into queue wait + prefill
         # without re-deriving request state
-        with maybe_span(self._stream, "serve/bookkeep",
-                        n_prompt=int(req.n_prompt), request_id=req.rid,
+        with maybe_span(self._stream, "serve/bookkeep", request_id=req.rid,
                         t_submit_s=req.t_submit, t_admit_s=req.t_admit,
                         t_first_s=now, **self._req_attrs(req)):
             if self.prefix_cache is not None:
@@ -1405,8 +1447,8 @@ class ServingEngine:
             self._h_stop[b] = stop
             self._h_active[b] = True
 
-    def _prefill_batch_chunk(self, reqs: list[Request],
-                             t0: float) -> None:
+    def _prefill_batch_chunk(self, reqs: list[Request], t0: float,
+                             k: int) -> None:
         """One BATCHED prefill chunk: every in-flight PREFILL request
         advances one chunk through a single fixed-shape
         (max_batch, C) step — the multi-request prefill the flash
@@ -1417,7 +1459,9 @@ class ServingEngine:
         dev = self._prefill_dev
         stream, sp = self._stream, self._sp
         t_chunk = time.perf_counter()  # clock-ok
-        with maybe_span(stream, "serve/prefill_stage", **sp):
+        with maybe_span(stream, "serve/prefill_stage", arrays=4,
+                        bytes=4 * B * (self.pages_per_request + Ck + 2),
+                        **sp):
             ids = np.zeros((B, Ck), np.int32)
             pages = np.zeros((B, self.pages_per_request), np.int32)
             pos = np.zeros(B, np.int32)
@@ -1434,16 +1478,21 @@ class ServingEngine:
                 else self.pool.bufs
             args = (self._put(pages, dev), self._put(ids, dev),
                     self._put(pos, dev), self._put(plen, dev))
-        with maybe_span(stream, "serve/prefill_dispatch", **sp):
-            tok_d, bufs = self._prefill_batch(bufs, self._params_pre,
-                                              *args)
+        with maybe_span(stream, "serve/prefill_dispatch",
+                        rows=sum(min(Ck, r.n_prompt - r.prefill_pos)
+                                 for r in reqs), **sp):
+            tok_d, bufs = self._launch(
+                sp, "prefill_batch", k, self._prefill_batch, bufs,
+                self._params_pre, *args)
             if self.disaggregate:
                 self.pool_pre.bufs = bufs
             else:
                 self.pool.bufs = bufs
             if self.spec_k:
-                _dt, dbufs = self._draft_prefill_batch(
-                    self.draft_pool.bufs, self._draft_params, *args)
+                _dt, dbufs = self._launch(
+                    sp, "draft_prefill_batch", k,
+                    self._draft_prefill_batch, self.draft_pool.bufs,
+                    self._draft_params, *args)
                 self.draft_pool.bufs = dbufs
             self.stats["prefill_chunks"] += 1
             self.stats["prefill_inplace_chunks"] += self.prefill_kernel
@@ -1455,12 +1504,14 @@ class ServingEngine:
             if self.disaggregate:
                 for i, req in finishing:
                     self._handoff(
-                        req, self._padded_row(self._pre_pages[req.rid]))
+                        req, self._padded_row(self._pre_pages[req.rid]),
+                        sp, k)
         if not finishing:
             self.stats["prefill_s"] += time.perf_counter() - t_chunk  # clock-ok
             return
-        with maybe_span(stream, "serve/prefill_sync", **sp):
-            toks = np.asarray(tok_d)    # sync-ok: TTFT resolution, one
+        with maybe_span(stream, "serve/prefill_sync", arrays=1,
+                        bytes=tok_d.nbytes, **sp):
+            toks, = self._read([tok_d])      # TTFT resolution, one
             self.stats["host_sync_count"] += 1   # sync for all finishers
         for i, req in finishing:
             self._finish_prefill(req, int(toks[i]), t_chunk, t0)
@@ -1470,24 +1521,28 @@ class ServingEngine:
     def _stage_burst(self):
         """Ship the host mirrors a burst starts from: tokens, lengths,
         stop positions, active mask, page tables."""
-        with maybe_span(self._stream, "serve/burst_stage", **self._sp):
-            return (self._put(self._h_tokens), self._put(self._h_lengths),
-                    self._put(self._h_stop), self._put(self._h_active),
-                    self._put(self._h_pages))
+        mirrors = (self._h_tokens, self._h_lengths, self._h_stop,
+                   self._h_active, self._h_pages)
+        with maybe_span(self._stream, "serve/burst_stage",
+                        arrays=len(mirrors),
+                        bytes=sum(m.nbytes for m in mirrors), **self._sp):
+            return tuple(self._put(m) for m in mirrors)
 
     def _sync_burst(self, arrs: list) -> list[np.ndarray]:
-        """The burst's one host sync: the pump just resolved the last
-        step's occupancy, so the burst's buffers are (near-)ready —
-        read them back.  Watchdog-guarded: a burst wedged here must
-        surface as StepTimeoutError for the fleet's failover, never a
-        silent hang."""
-        with maybe_span(self._stream, "serve/burst_sync", **self._sp):
+        """The burst's one sync POINT, which is ``len(arrs)`` blocking
+        reads, one an array (a plain burst: ``sync_every`` token rows
+        and the block's device counters): the pump just resolved the
+        last step's occupancy, so the buffers are ready and the chip
+        idles through every read.  Watchdog-guarded: a burst wedged
+        here must surface as StepTimeoutError for the fleet's failover,
+        never a silent hang."""
+        with maybe_span(self._stream, "serve/burst_sync", arrays=len(arrs),
+                        bytes=sum(a.nbytes for a in arrs), **self._sp):
             if self.watchdog is not None:
                 mats = self.watchdog.block(
-                    lambda ts: [np.asarray(t) for t in ts],   # sync-ok
-                    arrs, step=self.stats["decode_steps"])
+                    self._read, arrs, step=self.stats["decode_steps"])
             else:
-                mats = [np.asarray(t) for t in arrs]          # sync-ok
+                mats = self._read(arrs)
             self.stats["host_sync_count"] += 1
         return mats
 
@@ -1528,12 +1583,13 @@ class ServingEngine:
                                               "params": self._params},
                                        prediction=self._mem_prediction)
         t_burst = time.perf_counter()  # clock-ok
-        with maybe_span(stream, "serve/burst_dispatch", steps=sync, **sp):
+        with maybe_span(stream, "serve/burst_dispatch", live=int(A0.sum()),
+                        **sp):
             step_tokens = []
-            for _ in range(sync):
-                toks_d, len_d, act_d, bufs, occ, *counted = self._decode(
-                    bufs, self._params, pages_d, toks_d, len_d, stop_d,
-                    act_d, *counted)
+            for j in range(sync):
+                toks_d, len_d, act_d, bufs, occ, *counted = self._launch(
+                    sp, "decode", j, self._decode, bufs, self._params,
+                    pages_d, toks_d, len_d, stop_d, act_d, *counted)
                 pump.emit(occ)
                 step_tokens.append(toks_d)
             self.pool.bufs = bufs
@@ -1610,29 +1666,30 @@ class ServingEngine:
                                               "params": self._params},
                                        prediction=self._mem_prediction)
         t_burst = time.perf_counter()  # clock-ok
-        with maybe_span(stream, "serve/burst_dispatch", steps=sync, k=k,
+        with maybe_span(stream, "serve/burst_dispatch", live=int(A0.sum()),
                         **sp):
             g_steps, e_steps = [], []
-            for _ in range(sync):
+            for j in range(sync):
                 # k draft self-decode steps propose a token chain per
                 # slot; the draft runs against ITS pool at the same page
                 # table, with the same stop_at so it can never write
                 # past a grant
                 d_toks, d_len, d_act = toks_d, len_d, act_d
                 props = [toks_d]
-                for _i in range(k):
-                    d_toks, d_len, d_act, dbufs, _docc = \
-                        self._draft_decode(
-                            dbufs, self._draft_params, pages_d, d_toks,
-                            d_len, stop_d, d_act)
+                for i in range(k):
+                    d_toks, d_len, d_act, dbufs, _docc = self._launch(
+                        sp, "draft_decode", j * k + i, self._draft_decode,
+                        dbufs, self._draft_params, pages_d, d_toks,
+                        d_len, stop_d, d_act)
                     props.append(d_toks)
                 blk = jnp.stack(props, axis=1)          # (B, k+1)
-                g_d, bufs, occ = self._verify(
-                    bufs, self._params, pages_d, blk, len_d, stop_d,
-                    act_d)
+                g_d, bufs, occ = self._launch(
+                    sp, "verify", j, self._verify, bufs, self._params,
+                    pages_d, blk, len_d, stop_d, act_d)
                 pump.emit(occ)
-                toks_d, len_d, act_d, e_d = self._accept(
-                    blk, g_d, toks_d, len_d, stop_d, act_d)
+                toks_d, len_d, act_d, e_d = self._launch(
+                    sp, "accept", j, self._accept, blk, g_d, toks_d,
+                    len_d, stop_d, act_d)
                 g_steps.append(g_d)
                 e_steps.append(e_d)
             self.pool.bufs = bufs
@@ -1757,20 +1814,20 @@ class ServingEngine:
             if self.flash_prefill:
                 # batched multi-request prefill: all PREFILL residents
                 # advance together, one fixed-shape step per chunk round
-                for _ in range(self.prefill_chunks_per_round):
+                for k in range(self.prefill_chunks_per_round):
                     reqs = sorted(
                         (r for r in self.batcher.slots
                          if r is not None and r.state == PREFILL),
                         key=lambda r: r.t_admit)
                     if not reqs:
                         break
-                    self._prefill_batch_chunk(reqs, t0)
+                    self._prefill_batch_chunk(reqs, t0, k)
             else:
-                for _ in range(self.prefill_chunks_per_round):
+                for k in range(self.prefill_chunks_per_round):
                     req = self.batcher.next_prefill()
                     if req is None:
                         break
-                    self._prefill_one_chunk(req, t0)
+                    self._prefill_one_chunk(req, t0, k)
             if self._h_active.any():
                 if self.spec_k:
                     self._spec_burst(self._pump, t0)
@@ -1934,6 +1991,11 @@ class ServingEngine:
                     self.stats["occupancy_sum"]
                     / max(self.stats["rounds"], 1), 3),
                 "host_syncs": self.stats["host_sync_count"],
+                # sync POINTS above; what crossed the host-device
+                # boundary, each crossing counted, here
+                "crossings": {k: self.stats[k] for k in (
+                    "launches", "h2d_puts", "h2d_bytes", "d2h_reads",
+                    "d2h_bytes")},
                 "decode_steps_per_token": round(
                     self.stats["decode_steps"] / dec_toks, 4),
             },

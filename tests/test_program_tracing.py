@@ -32,11 +32,24 @@ STEP_SCOPES = MODEL_SCOPES | {"fsdp_layer_gather", "fsdp_root_gather",
 ENGINE_SCOPES = {"embed", "attn_qkv", "kv_write", "kv_gather", "attn_core",
                  "attn_out", "mlp", "sample"}
 
-# in engine order: what one round of one single-chunk request opens
+# in engine order: what one round of one single-chunk request opens, at
+# ``sync_every`` 2: every call of a compiled program is a span of its own
 ROUND_SPANS = ["serve/round", "serve/admit", "serve/prefill_stage",
-               "serve/prefill_dispatch", "serve/prefill_sync",
-               "serve/bookkeep", "serve/burst_stage", "serve/burst_dispatch",
-               "serve/burst_sync", "serve/bookkeep"]
+               "serve/prefill_dispatch", "serve/launch_dispatch",
+               "serve/prefill_sync", "serve/bookkeep", "serve/burst_stage",
+               "serve/burst_dispatch", "serve/launch_dispatch",
+               "serve/launch_dispatch", "serve/burst_sync", "serve/bookkeep"]
+
+# the gated delta-rule hybrid of ``tests/test_gdn_hybrid.py``: its prefill
+# program takes a fifth argument and its decode program counts on the device
+HYBRID = T.TransformerConfig(
+    vocab_size=256, hidden_size=64, intermediate_size=160,
+    num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=4,
+    rms_norm_eps=1e-6, tie_word_embeddings=False, nope_interval=0,
+    full_attention_interval=4, linear_num_key_heads=3,
+    linear_num_value_heads=3, linear_key_head_dim=8,
+    linear_value_head_dim=16, linear_conv_kernel_dim=4,
+    linear_allow_neg_eigval=True, dtype=jnp.float32, remat=False)
 
 
 def _lower_train_step():
@@ -52,10 +65,10 @@ def _lower_train_step():
     return step.lower(shards, fsdp.init_fsdp_opt_state(shards), batch)
 
 
-def _engine(**kw):
+def _engine(cfg=TINY, **kw):
     params = jax.tree.map(lambda x: (x * 3.0).astype(x.dtype),
-                          T.init_params(jax.random.key(0), TINY))
-    return ServingEngine(params, TINY, max_batch=2, page_size=8,
+                          T.init_params(jax.random.key(0), cfg))
+    return ServingEngine(params, cfg, max_batch=2, page_size=8,
                          max_seq_len=32, prefill_chunk=8, sync_every=2, **kw)
 
 
@@ -187,7 +200,10 @@ def test_a_bare_profiler_trace_holds_the_program_spans(tmp_path):
         jax.profiler.stop_trace()
     ev = _host_events(tmp_path)
     assert set(ROUND_SPANS) <= set(ev), sorted(set(ROUND_SPANS) - set(ev))
-    assert {"pump/sync_every", "prefetch/stage", "prefetch/wait"} <= set(ev)
+    assert {"pump/sync_every", "pump/resolve", "prefetch/stage",
+            "prefetch/wait"} <= set(ev)
+    # a sync point resolves the burst's occupancies, one read a step
+    assert {s["reads"] for s in ev["pump/resolve"]} == {2}
     assert len(ev["serve/round"]) == eng.stats["rounds"]
     assert sorted(s["round"] for s in ev["serve/round"]) \
         == list(range(eng.stats["rounds"]))
@@ -213,7 +229,7 @@ def test_a_round_emits_the_span_set_in_order_nested_in_the_round(tmp_path):
     for s in first[1:]:
         assert lo <= s["ts_us"] and s["ts_us"] + s["dur_us"] <= hi + 1e-3
     # one request is concerned: its rid rides along
-    assert all(s["rid"] == 0 for s in first[2:6])
+    assert all(s["rid"] == 0 for s in first[2:7])
     stamped = [s for s in spans if "t_first_s" in s]
     assert len(stamped) == 1 and stamped[0]["name"] == "serve/bookkeep"
 
@@ -241,3 +257,137 @@ def test_queue_wait_and_admitted_add_up():
     waits = [r.t_admit - r.t_submit for r in reqs]
     assert eng.stats["queue_wait_s"] == pytest.approx(sum(waits))
     assert max(waits) > 0 and min(waits) >= 0
+
+
+# ------------------------------------- the crossings of the host-device boundary
+
+def _served(cfg=TINY, lengths=(5, 7, 13, 3), max_new=4, **kw):
+    """An engine that has served ``lengths`` (13 tokens: two chunks of 8),
+    with its spans when a telemetry run is wired through ``kw``."""
+    eng = _engine(cfg, **kw)
+    rng = np.random.default_rng(0)
+    for n in lengths:
+        eng.submit(rng.integers(1, 256, size=n).astype(np.int32),
+                   max_new_tokens=max_new)
+    eng.run()
+    return eng
+
+
+@pytest.fixture
+def small_scan(monkeypatch):
+    from distributed_training_sandbox_tpu.models import gdn_hybrid
+    monkeypatch.setattr(gdn_hybrid, "SCAN_CHUNK", 4)
+
+
+@pytest.mark.parametrize("block", ["dense", "hybrid"])
+def test_crossings_are_counted_where_they_happen(block, small_scan):
+    """Launches, reads and puts are what the round structure implies: one
+    launch a decode step and a prefill chunk; a burst's sync point reads
+    one array a step and one for the device's counters, a finished prompt
+    one; a burst ships five mirrors and a chunk four arrays, five where
+    the prefill program takes the batch slot."""
+    cfg = {"dense": TINY, "hybrid": HYBRID}[block]
+    eng = _served(cfg)
+    s, counters = eng.stats, bool(eng._device_counters)
+    assert counters == (block == "hybrid")
+    bursts, rem = divmod(s["decode_steps"], eng.sync_every)
+    assert rem == 0 and bursts > 0 and s["prefill_chunks"] == 5
+    assert s["launches"] == s["decode_steps"] + s["prefill_chunks"]
+    assert s["d2h_reads"] == bursts * (eng.sync_every + counters) + 4
+    per_chunk = 5 if block == "hybrid" else 4
+    # the zeros a burst's device counters start from were put once, at
+    # construction
+    assert s["h2d_puts"] == 5 * bursts + per_chunk * s["prefill_chunks"] \
+        + counters
+    B, Pn = eng.max_batch, eng.pages_per_request
+    assert s["h2d_bytes"] == bursts * (4 * B * 3 + B + 4 * B * Pn) \
+        + s["prefill_chunks"] * 4 * (Pn + 8 + per_chunk - 2) \
+        + 4 * len(eng._device_counters)
+    assert s["d2h_bytes"] == 4 * (bursts * (eng.sync_every * B
+                                            + len(eng._device_counters)) + 4)
+    assert s["host_sync_count"] >= bursts + 4     # one a sync POINT, as before
+
+
+def test_a_speculative_burst_launches_draft_verify_and_accept():
+    k = 2
+    eng = _served(lengths=(5, 7), spec_k=k, draft_layers=1)
+    s = eng.stats
+    assert s["launches"] == 2 * s["prefill_chunks"] \
+        + s["decode_steps"] * (k + 2)
+    bursts = s["decode_steps"] // eng.sync_every
+    assert s["d2h_reads"] == bursts * (2 * eng.sync_every + 1) + 2
+
+
+@pytest.mark.parametrize("block", ["dense", "hybrid"])
+def test_spans_carry_what_crossed_and_every_launch_is_in_a_dispatch(
+        block, small_scan, tmp_path):
+    cfg = {"dense": TINY, "hybrid": HYBRID}[block]
+    t = TelemetryRun("serving", config={"num_steps": 0},
+                     results_dir=str(tmp_path), run_name="crossings")
+    with t as telem:
+        eng = _served(cfg, telem=telem)
+        telem.finalize()
+    spans = [s for s in read_spans(t.run_dir) if s["cat"] == "serve"]
+    by = lambda name: [s for s in spans if s["name"] == name]  # noqa: E731
+    stats = eng.stats
+    # puts and reads: the spans' attributes add up to the counters
+    stages = by("serve/burst_stage") + by("serve/prefill_stage")
+    syncs = by("serve/burst_sync") + by("serve/prefill_sync")
+    made = bool(eng._device_counters)         # the construction's one put
+    assert sum(s["arrays"] for s in stages) == stats["h2d_puts"] - made
+    assert sum(s["bytes"] for s in stages) == stats["h2d_bytes"] \
+        - 4 * len(eng._device_counters)
+    assert sum(s["arrays"] for s in syncs) == stats["d2h_reads"]
+    assert sum(s["bytes"] for s in syncs) == stats["d2h_bytes"]
+    # the work a launch carries
+    assert sum(s["rows"] for s in by("serve/prefill_dispatch")) \
+        == 5 + 7 + 13 + 3
+    assert all(1 <= s["live"] <= eng.max_batch
+               for s in by("serve/burst_dispatch"))
+    # launches: one span each, inside a dispatch span of its own round
+    launches = by("serve/launch_dispatch")
+    assert len(launches) == stats["launches"]
+    outer = by("serve/burst_dispatch") + by("serve/prefill_dispatch")
+    for s in launches:
+        assert s["program"] in ("decode", "prefill") and s["k"] >= 0
+        lo, hi = s["ts_us"], s["ts_us"] + s["dur_us"]
+        assert [o["name"] for o in outer
+                if o["round"] == s["round"] and o["ts_us"] <= lo
+                and hi <= o["ts_us"] + o["dur_us"] + 1e-3] == [
+            "serve/burst_dispatch" if s["program"] == "decode"
+            else "serve/prefill_dispatch"], s
+    for rnd in {s["round"] for s in launches}:
+        ks = [s["k"] for s in launches
+              if s["round"] == rnd and s["program"] == "decode"]
+        assert ks in ([], list(range(eng.sync_every)))
+    # a prompt of two chunks in one round: k counts the round's chunks
+    assert {s["k"] for s in launches if s["program"] == "prefill"} == {0, 1}
+    # attributes nobody read are gone
+    assert not any("steps" in s or "n_prompt" in s for s in spans)
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_the_launch_helper_leaves_the_programs_as_they_were(which):
+    """No jitted function changed: after rounds served through
+    ``_launch`` each program has the one executable it warmed up with,
+    and lowers to the text a fresh engine's does."""
+    eng = _served()
+    assert eng.retraces_after_warmup() == 0
+    assert {"decode": eng._decode, "prefill": eng._prefill}[
+        which]._cache_size() == 1
+    B, Pn = eng.max_batch, eng.pages_per_request
+    z = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
+    args = (eng._decode, eng.pool.bufs, eng._params, z(B, Pn), z(B), z(B),
+            z(B), jnp.zeros((B,), bool)) if which == "decode" else (
+        eng._prefill, eng.pool.bufs, eng._params_pre, z(1, Pn), z(1, 8),
+        jnp.int32(0), jnp.int32(5))
+    assert args[0].lower(*args[1:]).as_text() \
+        == _lower_engine(which).as_text()
+
+
+def test_the_report_carries_the_crossings():
+    eng = _served(lengths=(5,))
+    crossings = eng.slo_report()["scheduler"]["crossings"]
+    assert crossings == {k: eng.stats[k] for k in (
+        "launches", "h2d_puts", "h2d_bytes", "d2h_reads", "d2h_bytes")}
+    assert crossings["launches"] and crossings["d2h_reads"]
