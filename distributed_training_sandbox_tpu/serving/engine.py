@@ -26,12 +26,16 @@ Admit/evict between bursts rewrites host arrays and ``device_put``s the
 same shapes/dtypes/shardings — the jit cache stays at one entry per
 program over a whole traffic trace (``slo_report`` carries the watch).
 
-**Host blocks only at sync points.**  Decode bursts chain
-``sync_every`` donated-buffer steps through ``runtime.StepPump``'s
-bounded in-flight dispatch; the host resolves tokens, retires finished
-requests and admits new ones once per burst.  Prefill is synchronous at
-admission (TTFT is measured at first-token resolution) and CHUNKED so a
-long prompt shares rounds with decode instead of stalling it.
+**Host blocks only at sync points.**  A decode burst launches
+``sync_every`` donated-buffer steps back to back and then makes ONE
+blocking read: every step's token row (and the block's device counters)
+rides the decode program as one small int32 array, so the host resolves
+tokens, retires finished requests and admits new ones once per burst,
+for one round trip.  (A speculative burst still hands its verify steps
+to ``runtime.StepPump`` and reads an array a macro-step.)  Prefill is
+synchronous at admission (TTFT is measured at first-token resolution)
+and CHUNKED so a long prompt shares rounds with decode instead of
+stalling it.
 
 Modes: single-program (default, one jit per device set), tensor-parallel
 (``mesh`` + ``tp_axis``: params via ``parallel.tensor.tp_specs``, pool
@@ -570,25 +574,33 @@ def _last_logits(params, x_last, cfg):
 
 def device_counters(cfg) -> tuple:
     """The names of what the block's decode program sums on the device,
-    in the order of ``_decode_core``'s ``counted``: the expert layers'
-    ``mla_moe.COUNTERS``, then the hybrids' ``state_slot_steps``; () for
-    the dense block, whose program takes no ``counted``."""
+    in the order they lead ``_decode_core``'s ``carry``: the expert
+    layers' ``mla_moe.COUNTERS``, then the hybrids' ``state_slot_steps``;
+    () for the dense block, whose ``carry`` is token rows alone."""
     out = M.COUNTERS if cfg.mla_moe or cfg.gdn_moe else ()
     return out + (G.COUNTERS[:1] if cfg.gdn_hybrid else ())
 
 
 def _decode_core(bufs, params, pages, toks, lengths, stop_at, active,
-                 counted=None, *, cfg, tp_axis=None, paged_kernel=False):
+                 carry=None, *, cfg, tp_axis=None, paged_kernel=False):
     """One fixed-shape decode step over every slot.  toks/lengths/
     stop_at (B,) int32, active (B,) bool.  Emits the next greedy token
     per ACTIVE slot (inactive slots freeze); a slot auto-retires ON
     DEVICE when its length reaches ``stop_at`` — the device can never
     write past a request's page grant even mid-burst, the host only
-    observes retirement at the next sync.  A block that counts on the
-    device (the latent block's expert layers, the gated delta-rule
-    hybrid's live states) also takes ``counted``, the burst's running sum of
-    its counters, and returns it with this step's added: a burst's steps
-    chain it on the device and its one sync reads it."""
+    observes retirement at the next sync.
+
+    ``carry`` is what a burst brings back to the host, one flat int32
+    array chained through its steps on the device and read once at its
+    sync: first the running sums of what the block counts on the device
+    (``device_counters(cfg)``: the expert layers' four, the hybrids' live
+    states; none for the dense block), then the token rows of the last
+    ``S`` steps, oldest first, ``S`` being whatever its length leaves
+    room for.  A step adds its counts and shifts its row in, so after a
+    burst of ``S`` steps that started from zeros the array holds the
+    burst's counters and every step's row in step order.  Without it (a
+    speculative draft's step, whose rows nobody reads) nothing more is
+    returned and nothing is counted."""
     apos = lengths[:, None]
     x, bufs, counts = _paged_forward(
         params, toks[:, None], cfg, bufs, pages, apos, active[:, None],
@@ -600,9 +612,14 @@ def _decode_core(bufs, params, pages, toks, lengths, stop_at, active,
     new_len = lengths + active.astype(jnp.int32)
     new_active = jnp.logical_and(active, new_len < stop_at)
     occ = jnp.sum(active.astype(jnp.int32))
-    if counts is not None:
-        return nxt, new_len, new_active, bufs, occ, counted + counts
-    return nxt, new_len, new_active, bufs, occ
+    if carry is None:
+        return nxt, new_len, new_active, bufs, occ
+    n = 0 if counts is None else counts.shape[0]
+    # this step's row in at the end, the oldest out at the front
+    out = jnp.concatenate([carry[n:], nxt])[nxt.shape[0]:]
+    if n:
+        out = jnp.concatenate([carry[:n] + counts, out])
+    return nxt, new_len, new_active, bufs, occ, out
 
 
 def _prefill_core(bufs, params, pages_row, ids, pos, plen, slot=None, *,
@@ -726,8 +743,14 @@ def make_serve_decode_step(cfg, params=None, *, mesh=None,
     in_specs = (pool_spec, tp_specs(params, tp_axis), P(), P(), P(),
                 P(), P())
     out_specs = (P(), P(), P(), pool_spec, P())
-    return jax.jit(C.smap(core, mesh, in_specs=in_specs,
-                          out_specs=out_specs), donate_argnums=(0,))
+
+    def step(*args):
+        # with the burst's carry or without: replicated in, replicated out
+        carry = (P(),) * (len(args) - len(in_specs))
+        return C.smap(core, mesh, in_specs=in_specs + carry,
+                      out_specs=out_specs + carry)(*args)
+
+    return jax.jit(step, donate_argnums=(0,))
 
 
 def make_serve_prefill_step(cfg, params=None, *, mesh=None,
@@ -837,7 +860,8 @@ class ServingEngine:
     (the first-token read) and ``serve/bookkeep``, and per burst
     ``serve/burst_stage`` (the five host mirrors) /
     ``serve/burst_dispatch`` (the ``sync_every`` launches) /
-    ``serve/burst_sync`` (the read-backs) / ``serve/bookkeep``.  Each
+    ``serve/burst_sync`` (the wait and the read-back: one array for a
+    plain burst) / ``serve/bookkeep``.  Each
     carries the round number, and its ``rid`` where one request is
     concerned.  Every crossing of the host-device boundary goes through
     one method that counts it: :meth:`_launch` (a compiled program's
@@ -887,6 +911,8 @@ class ServingEngine:
         self.prefill_chunk = int(prefill_chunk)
         self.prefill_chunks_per_round = int(prefill_chunks_per_round)
         self.sync_every = max(int(sync_every), 1)
+        # the speculative burst's pump alone: a plain burst has
+        # ``sync_every`` launches in flight and then waits
         self.max_in_flight = int(max_in_flight)
         self.kv_quant = bool(kv_quant)
         # attention through the Pallas paged kernels (pages read in
@@ -959,10 +985,10 @@ class ServingEngine:
         self.replica = None
         self.disaggregate = bool(disaggregate)
         # collective watchdog (resilience.elastic.Watchdog): every
-        # blocking point in the decode path — the pump's sync sites and
-        # the burst's token resolution — routes through it, so a wedged
-        # burst becomes a StepTimeoutError the fleet's failover path
-        # can consume instead of a hung server
+        # blocking point in the decode path — the burst's one read, and
+        # a speculative burst's pump sync sites — routes through it, so a
+        # wedged burst becomes a StepTimeoutError the fleet's failover
+        # path can consume instead of a hung server
         self.watchdog = watchdog
 
         tp = 1
@@ -1193,7 +1219,7 @@ class ServingEngine:
                       "launches": 0, "h2d_puts": 0, "h2d_bytes": 0,
                       "d2h_reads": 0, "d2h_bytes": 0}
         # what a block counts on the device over the decode steps and a
-        # burst's sync reads.  The latent block's expert layers
+        # burst's one read brings back.  The latent block's expert layers
         # (mla_moe.moe_counts): (row, chosen expert) pairs over the
         # router's whole width, those whose expert is held here, held
         # experts that got a row (summed over layers and steps), expert
@@ -1207,11 +1233,13 @@ class ServingEngine:
         if self.cfg.gdn_hybrid:
             self.stats.update(dict.fromkeys(
                 G.COUNTERS[1:] + ("lin_step_inplace_steps",), 0))
-        self._counted_zero = None
-        if self._device_counters:
-            self.stats.update(dict.fromkeys(self._device_counters, 0))
-            self._counted_zero = self._put(
-                np.zeros(len(self._device_counters), np.int32))
+        self.stats.update(dict.fromkeys(self._device_counters, 0))
+        # what every plain burst's carry starts from (_decode_core): the
+        # counters at zero, then ``sync_every`` token rows that the
+        # burst's steps shift out.  One put, here: no step donates it
+        self._carry_zero = self._put(np.zeros(
+            len(self._device_counters)
+            + self.sync_every * self.max_batch, np.int32))
         # attributes every serve/* span of the current round carries
         self._sp: dict = {}
 
@@ -1292,7 +1320,11 @@ class ServingEngine:
             return fn(*args)
 
     def _read(self, arrs: list) -> list[np.ndarray]:
-        """Device -> host: one blocking read an array."""
+        """Device -> host: one blocking read an array.  What is read: a
+        finished prompt's first token; a plain burst's carry (its
+        counters and every step's token row: one array); a speculative
+        burst's greedy rows and acceptance counts, an array a macro-step,
+        and its last tokens."""
         self.stats["d2h_reads"] += len(arrs)
         self.stats["d2h_bytes"] += sum(a.nbytes for a in arrs)
         return [np.asarray(a) for a in arrs]   # sync-ok: the one read site
@@ -1529,13 +1561,14 @@ class ServingEngine:
             return tuple(self._put(m) for m in mirrors)
 
     def _sync_burst(self, arrs: list) -> list[np.ndarray]:
-        """The burst's one sync POINT, which is ``len(arrs)`` blocking
-        reads, one an array (a plain burst: ``sync_every`` token rows
-        and the block's device counters): the pump just resolved the
-        last step's occupancy, so the buffers are ready and the chip
-        idles through every read.  Watchdog-guarded: a burst wedged
-        here must surface as StepTimeoutError for the fleet's failover,
-        never a silent hang."""
+        """The burst's one sync POINT: the host's wait for the burst's
+        last step and ``len(arrs)`` blocking reads, one an array.  A
+        plain burst hands it ONE array, the carry its steps chained on
+        the device (``_decode_core``), so the wait and the transfer are
+        one round trip whatever ``sync_every`` is; a speculative burst
+        ``2 * sync_every + 1``.  Watchdog-guarded: a burst wedged here
+        must surface as StepTimeoutError for the fleet's failover, never
+        a silent hang."""
         with maybe_span(self._stream, "serve/burst_sync", arrays=len(arrs),
                         bytes=sum(a.nbytes for a in arrs), **self._sp):
             if self.watchdog is not None:
@@ -1562,46 +1595,45 @@ class ServingEngine:
                 finished.append(req)
         return finished
 
-    def _decode_burst(self, pump, t0: float) -> None:
+    def _decode_burst(self, t0: float) -> None:
+        """A plain decode burst: ``sync_every`` launches back to back,
+        one blocking read of what they carried (``_sync_burst``), and the
+        host's replay of the ``active`` chain over the rows it holds."""
         sync = self.sync_every
         stream, sp = self._stream, self._sp
         L0 = self._h_lengths.copy()
         A0 = self._h_active.copy()
         toks_d, len_d, stop_d, act_d, pages_d = self._stage_burst()
         bufs = self.pool.bufs
-        # a block's device-side counters ride the burst as one more argument
-        counted = [] if self._counted_zero is None \
-            else [self._counted_zero]
+        carry = self._carry_zero
         if self.telem is not None:
             # ledger join (no-op unless the run owns an enabled
             # profiler, and only compiles once): the decode program's
             # text at this burst's exact arg shardings
             self.telem.attach_step_hlo(self._decode, bufs, self._params,
                                        pages_d, toks_d, len_d, stop_d,
-                                       act_d, *counted,
+                                       act_d, carry,
                                        trees={"kv_pool": bufs,
                                               "params": self._params},
                                        prediction=self._mem_prediction)
         t_burst = time.perf_counter()  # clock-ok
         with maybe_span(stream, "serve/burst_dispatch", live=int(A0.sum()),
                         **sp):
-            step_tokens = []
             for j in range(sync):
-                toks_d, len_d, act_d, bufs, occ, *counted = self._launch(
+                toks_d, len_d, act_d, bufs, _occ, carry = self._launch(
                     sp, "decode", j, self._decode, bufs, self._params,
-                    pages_d, toks_d, len_d, stop_d, act_d, *counted)
-                pump.emit(occ)
-                step_tokens.append(toks_d)
+                    pages_d, toks_d, len_d, stop_d, act_d, carry)
             self.pool.bufs = bufs
             self.stats["decode_steps"] += sync
             if self.paged_kernel:
                 self.stats["decode_inplace_steps"] += sync
             if self.lin_step_kernel:
                 self.stats["lin_step_inplace_steps"] += sync
-        mats = self._sync_burst(step_tokens + counted)
-        if counted:
-            for name, count in zip(self._device_counters, mats.pop()):
-                self.stats[name] += int(count)
+        mat, = self._sync_burst([carry])
+        n = len(self._device_counters)
+        for name, count in zip(self._device_counters, mat[:n]):
+            self.stats[name] += int(count)
+        rows = mat[n:].reshape(sync, self.max_batch)    # step order
         burst_s = time.perf_counter() - t_burst  # clock-ok
         self.stats["decode_s"] += burst_s
         t_book = time.perf_counter()  # clock-ok
@@ -1612,11 +1644,11 @@ class ServingEngine:
                 occ_burst.append(int(active.sum()))
                 for b in np.nonzero(active)[0]:
                     self.batcher.slot_request(int(b)).tokens.append(
-                        int(mats[j][b]))
+                        int(rows[j, b]))
                     emitted += 1
                 lengths = lengths + active
                 active = active & (lengths < self._h_stop)
-            self._h_tokens = mats[-1].copy()
+            self._h_tokens = rows[-1].copy()
             finished = self._retire_burst(active, lengths, t0)
         self.stats["bookkeep_s"] += time.perf_counter() - t_book  # clock-ok
         if self.telem is not None:
@@ -1748,13 +1780,15 @@ class ServingEngine:
 
     # ---- round loop ---------------------------------------------------
     def start(self, t0: float | None = None) -> None:
-        """Arm the engine clock and the persistent pump without driving
-        the loop.  ``run()`` calls it implicitly; the fleet calls it
-        explicitly with a SHARED ``t0`` so every replica's timestamps
-        live on one clock, then drives rounds via :meth:`step_round`."""
+        """Arm the engine clock, and a speculative engine's persistent
+        pump (a plain burst is ``sync_every`` launches and one read: it
+        hands the pump nothing), without driving the loop.  ``run()``
+        calls it implicitly; the fleet calls it explicitly with a SHARED
+        ``t0`` so every replica's timestamps live on one clock, then
+        drives rounds via :meth:`step_round`."""
         if self._t0 is None:
             self._t0 = time.perf_counter() if t0 is None else t0  # clock-ok
-        if self._pump is None:
+        if self.spec_k and self._pump is None:
             from ..runtime.pump import StepPump
             self._pump = StepPump(mode="async",
                                   sync_every=self.sync_every,
@@ -1832,7 +1866,7 @@ class ServingEngine:
                 if self.spec_k:
                     self._spec_burst(self._pump, t0)
                 else:
-                    self._decode_burst(self._pump, t0)
+                    self._decode_burst(t0)
             self.stats["rounds"] += 1
             self.stats["occupancy_sum"] += int(self._h_active.sum())
             self.stats["peak_pool_util"] = max(

@@ -30,8 +30,8 @@ Timestamps are elapsed seconds on the engine's clock: ``t_submit`` is
 the request's (virtual) arrival, ``t_first`` when its first token
 resolved on the host (prefill is synchronous at admission, so TTFT is
 measured at token resolution), ``t_done`` at the retiring sync point —
-so per-token latency is measured at sync granularity, the price of the
-pump's bounded-async dispatch.
+so per-token latency is measured at sync granularity, the price of a
+burst's one read.
 """
 
 from __future__ import annotations

@@ -5,14 +5,15 @@ lowered FOR a TPU on this host (no chip), at the cell's shapes.
 
     JAX_PLATFORMS=cpu python scripts/serving_program_hash.py [--root DIR]
 
-prints one line a program (``--root`` another checkout, e.g. a ``git
-archive`` of the parent: run both and compare).  The hash is of the
-StableHLO with every Mosaic kernel's serialized module replaced by the hash
-of its MLIR printed WITHOUT debug locations: a Pallas kernel's payload
-embeds the source lines of the kernel and of every caller up to the engine,
-so an edit that shifts a line in ``serving/engine.py`` would change the
-hash of every program as lowered.  Equal hashes mean the same ops and the
-same kernels.
+prints one line a program (``--root`` another checkout of THIS program;
+for a parent whose programs take other arguments run the parent's own copy
+of this script, which lowers what its engine launches, and compare).  The
+hash is of the StableHLO with every Mosaic kernel's serialized module
+replaced by the hash of its MLIR printed WITHOUT debug locations: a Pallas
+kernel's payload embeds the source lines of the kernel and of every caller
+up to the engine, so an edit that shifts a line in ``serving/engine.py``
+would change the hash of every program as lowered.  Equal hashes mean the
+same ops and the same kernels.
 """
 
 from __future__ import annotations
@@ -20,6 +21,7 @@ from __future__ import annotations
 import argparse
 import base64
 import hashlib
+import inspect
 import json
 import os
 import re
@@ -64,6 +66,8 @@ def programs(root: Path):
     jax.default_backend = lambda: "tpu"     # the engine's kernels, as there
     sd = jax.ShapeDtypeStruct
     i32 = lambda *s: sd(s, jnp.int32)  # noqa: E731
+    sync_default = inspect.signature(
+        E.ServingEngine).parameters["sync_every"].default
     for row in harness.load_benchmark(root)["workloads"]:
         cell = harness.load_cell(row["name"], root)
         if cell.runner != "serve":
@@ -77,10 +81,12 @@ def programs(root: Path):
         slots = {"n_slots": B} if mcfg.gdn_hybrid else {}
         bufs = jax.eval_shape(
             lambda: PagedKVPool(mcfg, B * P + 1, page, **slots).bufs)
-        counted = E.device_counters(mcfg)
+        # the burst's carry, as the engine sizes it (a configuration may
+        # set ``sync_every``)
+        sync = cell.config["serve"]["engine"].get("sync_every", sync_default)
         decode = E.make_serve_decode_step(mcfg, paged_kernel=True).trace(
             bufs, params, i32(B, P), i32(B), i32(B), i32(B),
-            sd((B,), jnp.bool_), *((i32(len(counted)),) if counted else ()))
+            sd((B,), jnp.bool_), i32(len(E.device_counters(mcfg)) + sync * B))
         prefill = E.make_serve_prefill_step(
             mcfg, paged_kernel=not mcfg.mla_moe).trace(
             bufs, params, i32(1, P), i32(1, eng["prefill_chunk"]), i32(),

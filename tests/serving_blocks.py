@@ -1,0 +1,79 @@
+"""The four blocks the serving engine builds, at a tiny size, float32,
+seeded weights: the configurations that the blocks' own test files
+(``tests/test_mla_moe.py``, ``tests/test_gdn_hybrid.py``,
+``tests/test_gdn_moe.py``) and the tests that run over ALL blocks share,
+keyed as the benchmark keys its plain float32 references
+(``benchmarks/reference/<architecture>.py``)."""
+
+import importlib
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+
+from distributed_training_sandbox_tpu.models import transformer as T  # noqa: E402
+
+_HYBRID = dict(
+    vocab_size=256, hidden_size=64, intermediate_size=160,
+    num_hidden_layers=4, rms_norm_eps=1e-6, tie_word_embeddings=False,
+    nope_interval=0, full_attention_interval=4, linear_key_head_dim=8,
+    linear_value_head_dim=16, linear_conv_kernel_dim=4)
+
+#: block kind -> the fields ``TransformerConfig`` gets
+FIELDS = {
+    "dense_gqa": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+        nope_interval=2, rms_norm_eps=1e-6, rope_theta=5e6),
+    "mla_moe": dict(
+        vocab_size=512, hidden_size=64, intermediate_size=160,
+        num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=4,
+        rope_theta=10000.0, rms_norm_eps=1e-5, tie_word_embeddings=False,
+        nope_interval=0, q_lora_rank=48, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        first_k_dense_replace=1, moe_intermediate_size=24, router_width=16,
+        n_routed_experts=4, expert_offset=4, n_shared_experts=1,
+        num_experts_per_tok=3, norm_topk_prob=True,
+        routed_scaling_factor=2.5, sandwich_norm=True),
+    "gdn_hybrid": dict(
+        _HYBRID, num_attention_heads=4, num_key_value_heads=4,
+        linear_num_key_heads=3, linear_num_value_heads=3,
+        linear_allow_neg_eigval=True),
+    "gdn_moe": dict(
+        _HYBRID, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        rope_theta=1e4, linear_num_key_heads=2, linear_num_value_heads=4,
+        num_experts=4, router_width=16, expert_offset=4,
+        num_experts_per_tok=3, moe_intermediate_size=32,
+        shared_expert_intermediate_size=32, norm_topk_prob=True,
+        partial_rotary_factor=0.25),
+}
+BLOCKS = tuple(FIELDS)
+
+
+def make(block: str, seed: int = 0, scale: float = 3.0):
+    """``(fields, cfg, params)`` of ``block``, weights scaled as the
+    benchmark scales them (greedy tokens then sit far from a tie)."""
+    fields = FIELDS[block]
+    cfg = T.TransformerConfig(**fields, dtype=jnp.float32, remat=False)
+    params = jax.tree.map(lambda x: (x * scale).astype(x.dtype),
+                          T.init_params(jax.random.key(seed), cfg))
+    return fields, cfg, params
+
+
+def reference_tokens(block: str, fields, params, prompt, tokens) -> list:
+    """What the plain float32 reference decodes greedily after ``prompt``,
+    as far as ``tokens`` goes: its argmax at every position of ``prompt +
+    tokens``, so equal to ``tokens`` exactly when they are its own greedy
+    continuation (by induction over the positions)."""
+    ref = importlib.import_module(f"benchmarks.reference.{block}")
+    seq = np.concatenate([prompt, np.asarray(tokens[:-1], np.int32)])
+    pos = len(prompt) - 1 + np.arange(len(tokens))
+    z = ref.logits_at(params, jnp.asarray(seq, jnp.int32), jnp.asarray(pos),
+                      fields, block=len(seq))
+    return [int(t) for t in np.asarray(jnp.argmax(z, axis=-1))]

@@ -23,6 +23,7 @@ from benchmarks.reference import gdn_hybrid as R  # noqa: E402
 from distributed_training_sandbox_tpu.models import gdn_hybrid as G  # noqa: E402
 from distributed_training_sandbox_tpu.models import transformer as T  # noqa: E402
 from distributed_training_sandbox_tpu.serving import ServingEngine  # noqa: E402
+from tests.serving_blocks import FIELDS as BLOCK_FIELDS  # noqa: E402
 from distributed_training_sandbox_tpu.serving import accounting  # noqa: E402
 from distributed_training_sandbox_tpu.serving import engine as E  # noqa: E402
 from distributed_training_sandbox_tpu.serving.kv_pool import (  # noqa: E402
@@ -32,14 +33,7 @@ from tests.gdn_scan_cases import (  # noqa: E402
     CASES, assert_as_exact_as_the_solve, errors, neumann_inverse,
     recurrence64, scan_case)
 
-FIELDS = dict(
-    vocab_size=256, hidden_size=64, intermediate_size=160,
-    num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=4,
-    rms_norm_eps=1e-6, tie_word_embeddings=False, nope_interval=0,
-    full_attention_interval=4, linear_num_key_heads=3,
-    linear_num_value_heads=3, linear_key_head_dim=8,
-    linear_value_head_dim=16, linear_conv_kernel_dim=4,
-    linear_allow_neg_eigval=True)
+FIELDS = BLOCK_FIELDS["gdn_hybrid"]
 
 
 def make(seed=0, scale=2.0, **over):

@@ -28,6 +28,7 @@ from distributed_training_sandbox_tpu.models import transformer as T  # noqa: E4
 from distributed_training_sandbox_tpu.ops.gdn_step import (  # noqa: E402
     gdn_decode_step, head_group, step_kernel_takes)
 from distributed_training_sandbox_tpu.serving import ServingEngine  # noqa: E402
+from tests.serving_blocks import FIELDS as BLOCK_FIELDS  # noqa: E402
 from distributed_training_sandbox_tpu.serving import engine as E  # noqa: E402
 from distributed_training_sandbox_tpu.serving.kv_pool import (  # noqa: E402
     PagedKVPool, paged_layers, pool_shape, row_layout, slab_pool,
@@ -35,17 +36,7 @@ from distributed_training_sandbox_tpu.serving.kv_pool import (  # noqa: E402
 from tests.gdn_scan_cases import (  # noqa: E402
     CASES, assert_as_exact_as_the_solve, recurrence64, scan_case)
 
-FIELDS = dict(
-    vocab_size=256, hidden_size=64, intermediate_size=160,
-    num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=2,
-    head_dim=16, rms_norm_eps=1e-6, rope_theta=1e4,
-    tie_word_embeddings=False, nope_interval=0,
-    full_attention_interval=4, linear_num_key_heads=2,
-    linear_num_value_heads=4, linear_key_head_dim=8,
-    linear_value_head_dim=16, linear_conv_kernel_dim=4,
-    num_experts=4, router_width=16, expert_offset=4, num_experts_per_tok=3,
-    moe_intermediate_size=32, shared_expert_intermediate_size=32,
-    norm_topk_prob=True, partial_rotary_factor=0.25)
+FIELDS = BLOCK_FIELDS["gdn_moe"]
 
 
 def make(seed=0, scale=2.0, **over):

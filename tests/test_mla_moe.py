@@ -21,20 +21,13 @@ from benchmarks.reference import mla_moe as R  # noqa: E402
 from distributed_training_sandbox_tpu.models import mla_moe as M  # noqa: E402
 from distributed_training_sandbox_tpu.models import transformer as T  # noqa: E402
 from distributed_training_sandbox_tpu.serving import ServingEngine  # noqa: E402
+from tests.serving_blocks import FIELDS as BLOCK_FIELDS  # noqa: E402
 from distributed_training_sandbox_tpu.serving import engine as E  # noqa: E402
 from distributed_training_sandbox_tpu.serving.kv_pool import (  # noqa: E402
     PagedKVPool, RadixPrefixCache, row_layout,
     token_row_bytes)
 
-FIELDS = dict(
-    vocab_size=512, hidden_size=64, intermediate_size=160,
-    num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=4,
-    rope_theta=10000.0, rms_norm_eps=1e-5, tie_word_embeddings=False,
-    nope_interval=0, q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
-    qk_rope_head_dim=8, v_head_dim=16, first_k_dense_replace=1,
-    moe_intermediate_size=24, router_width=16, n_routed_experts=4,
-    expert_offset=4, n_shared_experts=1, num_experts_per_tok=3,
-    norm_topk_prob=True, routed_scaling_factor=2.5, sandwich_norm=True)
+FIELDS = BLOCK_FIELDS["mla_moe"]
 
 
 def attend_absorbed(qa, rows, vis, cfg):

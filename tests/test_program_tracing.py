@@ -14,11 +14,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from distributed_training_sandbox_tpu.analysis.pitfalls import lint_source
 from distributed_training_sandbox_tpu.models import transformer as T
 from distributed_training_sandbox_tpu.parallel import fsdp
-from distributed_training_sandbox_tpu.runtime import DevicePrefetcher
+from distributed_training_sandbox_tpu.runtime import DevicePrefetcher, StepPump
 from distributed_training_sandbox_tpu.serving import ServingEngine
 from distributed_training_sandbox_tpu.telemetry import (
     TelemetryRun, maybe_span, read_spans)
 from distributed_training_sandbox_tpu.utils import make_mesh, profiling
+from tests.serving_blocks import BLOCKS, FIELDS, make
 
 TINY = T.TransformerConfig(
     vocab_size=256, hidden_size=64, intermediate_size=128,
@@ -42,14 +43,8 @@ ROUND_SPANS = ["serve/round", "serve/admit", "serve/prefill_stage",
 
 # the gated delta-rule hybrid of ``tests/test_gdn_hybrid.py``: its prefill
 # program takes a fifth argument and its decode program counts on the device
-HYBRID = T.TransformerConfig(
-    vocab_size=256, hidden_size=64, intermediate_size=160,
-    num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=4,
-    rms_norm_eps=1e-6, tie_word_embeddings=False, nope_interval=0,
-    full_attention_interval=4, linear_num_key_heads=3,
-    linear_num_value_heads=3, linear_key_head_dim=8,
-    linear_value_head_dim=16, linear_conv_kernel_dim=4,
-    linear_allow_neg_eigval=True, dtype=jnp.float32, remat=False)
+HYBRID = T.TransformerConfig(**FIELDS["gdn_hybrid"], dtype=jnp.float32,
+                             remat=False)
 
 
 def _lower_train_step():
@@ -78,7 +73,8 @@ def _lower_engine(which: str):
     z = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
     if which == "decode":
         return eng._decode.lower(eng.pool.bufs, eng._params, z(B, Pn), z(B),
-                                 z(B), z(B), jnp.zeros((B,), bool))
+                                 z(B), z(B), jnp.zeros((B,), bool),
+                                 eng._carry_zero)
     return eng._prefill.lower(eng.pool.bufs, eng._params_pre, z(1, Pn),
                               z(1, 8), jnp.int32(0), jnp.int32(5))
 
@@ -176,13 +172,24 @@ def _host_events(trace_dir) -> dict[str, list]:
         if plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
-                    out.setdefault(e.name, []).append(dict(e.stats))
+                    out.setdefault(e.name, []).append(
+                        dict(e.stats, _start=e.start_ns,
+                             _end=e.start_ns + e.duration_ns))
     return out
+
+
+def _inside(spans: list, outer: list) -> list:
+    """Those of ``spans`` that lie inside one of ``outer``."""
+    return [s for s in spans if any(o["_start"] <= s["_start"]
+                                    and s["_end"] <= o["_end"] for o in outer)]
 
 
 def test_a_bare_profiler_trace_holds_the_program_spans(tmp_path):
     """No ``TelemetryRun``: ``serve/*``, ``pump/*`` and ``prefetch/*`` land
-    in a plain ``jax.profiler`` trace, with their attributes."""
+    in a plain ``jax.profiler`` trace, with their attributes.  A plain
+    serving round opens no ``pump/`` span (its burst is one read, the
+    engine's own); the pump's two come from a loop of its own, as the
+    training drivers and the speculative burst drive it."""
     eng = _engine()
     rng = np.random.default_rng(0)
     for n in (5, 7):
@@ -193,6 +200,10 @@ def test_a_bare_profiler_trace_holds_the_program_spans(tmp_path):
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
         eng.run()
+        with StepPump(sync_every=2) as pump:
+            for loss in (1.0, 2.0, 3.0, 4.0):
+                pump.emit(jnp.float32(loss))
+        assert pump.losses == [1.0, 2.0, 3.0, 4.0]
         with DevicePrefetcher(iter([np.zeros((2, 4), np.int32)] * 2),
                               transform=jnp.asarray) as pref:
             assert len(list(pref)) == 2
@@ -202,8 +213,13 @@ def test_a_bare_profiler_trace_holds_the_program_spans(tmp_path):
     assert set(ROUND_SPANS) <= set(ev), sorted(set(ROUND_SPANS) - set(ev))
     assert {"pump/sync_every", "pump/resolve", "prefetch/stage",
             "prefetch/wait"} <= set(ev)
-    # a sync point resolves the burst's occupancies, one read a step
-    assert {s["reads"] for s in ev["pump/resolve"]} == {2}
+    # a sync point resolves the losses retired since the last, one read each
+    assert [s["reads"] for s in ev["pump/resolve"]] == [2, 2]
+    assert {s["arrays"] for s in ev["serve/burst_sync"]} == {1}
+    pumped = [s for name in ev if name.startswith("pump/") for s in ev[name]]
+    assert pumped and not _inside(pumped, ev["serve/round"])
+    assert len(_inside(ev["serve/burst_sync"], ev["serve/round"])) \
+        == len(ev["serve/burst_sync"]) > 0
     assert len(ev["serve/round"]) == eng.stats["rounds"]
     assert sorted(s["round"] for s in ev["serve/round"]) \
         == list(range(eng.stats["rounds"]))
@@ -283,9 +299,9 @@ def small_scan(monkeypatch):
 def test_crossings_are_counted_where_they_happen(block, small_scan):
     """Launches, reads and puts are what the round structure implies: one
     launch a decode step and a prefill chunk; a burst's sync point reads
-    one array a step and one for the device's counters, a finished prompt
-    one; a burst ships five mirrors and a chunk four arrays, five where
-    the prefill program takes the batch slot."""
+    ONE array (the carry: the device's counters and a token row a step), a
+    finished prompt one; a burst ships five mirrors and a chunk four
+    arrays, five where the prefill program takes the batch slot."""
     cfg = {"dense": TINY, "hybrid": HYBRID}[block]
     eng = _served(cfg)
     s, counters = eng.stats, bool(eng._device_counters)
@@ -293,19 +309,110 @@ def test_crossings_are_counted_where_they_happen(block, small_scan):
     bursts, rem = divmod(s["decode_steps"], eng.sync_every)
     assert rem == 0 and bursts > 0 and s["prefill_chunks"] == 5
     assert s["launches"] == s["decode_steps"] + s["prefill_chunks"]
-    assert s["d2h_reads"] == bursts * (eng.sync_every + counters) + 4
+    assert s["d2h_reads"] == bursts + 4
     per_chunk = 5 if block == "hybrid" else 4
-    # the zeros a burst's device counters start from were put once, at
+    # the zeros every burst's carry starts from were put once, at
     # construction
-    assert s["h2d_puts"] == 5 * bursts + per_chunk * s["prefill_chunks"] \
-        + counters
+    assert s["h2d_puts"] == 5 * bursts + per_chunk * s["prefill_chunks"] + 1
     B, Pn = eng.max_batch, eng.pages_per_request
+    carry = 4 * (len(eng._device_counters) + eng.sync_every * B)
+    assert eng._carry_zero.nbytes == carry
     assert s["h2d_bytes"] == bursts * (4 * B * 3 + B + 4 * B * Pn) \
-        + s["prefill_chunks"] * 4 * (Pn + 8 + per_chunk - 2) \
-        + 4 * len(eng._device_counters)
-    assert s["d2h_bytes"] == 4 * (bursts * (eng.sync_every * B
-                                            + len(eng._device_counters)) + 4)
-    assert s["host_sync_count"] >= bursts + 4     # one a sync POINT, as before
+        + s["prefill_chunks"] * 4 * (Pn + 8 + per_chunk - 2) + carry
+    assert s["d2h_bytes"] == bursts * carry + 4 * 4
+    # one a sync POINT and no more: the pump counts none in a plain burst
+    assert s["host_sync_count"] == bursts + 4
+
+
+def _parent_burst(self, t0):
+    """A decode burst as it crossed the boundary before the carry held the
+    token rows: every step's row an array of its own and the device's
+    counters a chain beside them, each read by itself."""
+    L0, A0 = self._h_lengths.copy(), self._h_active.copy()
+    toks_d, len_d, stop_d, act_d, pages_d = self._stage_burst()
+    bufs = self.pool.bufs
+    counted = jnp.zeros(len(self._device_counters), jnp.int32)
+    rows = []
+    for _ in range(self.sync_every):
+        toks_d, len_d, act_d, bufs, _occ, counted = self._decode(
+            bufs, self._params, pages_d, toks_d, len_d, stop_d, act_d,
+            counted)
+        rows.append(np.asarray(toks_d))
+    self.pool.bufs = bufs
+    self.stats["decode_steps"] += self.sync_every
+    for name, count in zip(self._device_counters, np.asarray(counted)):
+        self.stats[name] += int(count)
+    active, lengths = A0.copy(), L0.copy()
+    for row in rows:
+        for b in np.nonzero(active)[0]:
+            self.batcher.slot_request(int(b)).tokens.append(int(row[b]))
+        lengths = lengths + active
+        active = active & (lengths < self._h_stop)
+    self._h_tokens = rows[-1].copy()
+    self._retire_burst(active, lengths, t0)
+
+
+@pytest.mark.parametrize("sync_every", [1, 2, 8])
+@pytest.mark.parametrize("block", BLOCKS)
+def test_a_plain_burst_is_one_read_of_one_array(block, sync_every, small_scan,
+                                                monkeypatch):
+    """Whatever the block and the burst's length: ``serve/burst_sync``
+    holds exactly one ``_read`` of one array, no ``pump/`` span opens (a
+    plain burst hands the pump nothing), and tokens and device counters
+    are what the parent's crossing, a read a step, gives."""
+    _, cfg, params = make(block)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n).astype(np.int32)
+               for n in (5, 19, 7)]
+
+    def serve(**patch):
+        eng = ServingEngine(params, cfg, max_batch=2, page_size=8,
+                            max_seq_len=32, prefill_chunk=16,
+                            sync_every=sync_every)
+        for name, fn in patch.items():
+            setattr(eng, name, fn.__get__(eng))
+        reqs = [eng.submit(p, max_new_tokens=new)
+                for p, new in zip(prompts, (6, 3, 11))]
+        eng.run()
+        return eng, [r.tokens for r in reqs]
+
+    want, want_tokens = serve(_decode_burst=_parent_burst)
+
+    stack, opened, reads = [], [], []
+
+    class Recorder:
+        def __init__(self, name, **kw):
+            self.name = name
+            opened.append((name, kw))
+
+        def __enter__(self):
+            stack.append(self.name)
+
+        def __exit__(self, *exc):
+            stack.pop()
+
+    def counting_read(self, arrs):
+        reads.append((stack[-1], [a.shape for a in arrs]))
+        return ServingEngine._read(self, arrs)
+
+    with monkeypatch.context() as m:
+        m.setattr(jax.profiler, "TraceAnnotation", Recorder)
+        eng, tokens = serve(_read=counting_read)
+    bursts = eng.stats["decode_steps"] // sync_every
+    n = len(eng._device_counters)
+    assert (n > 0) == (block != "dense_gqa")
+    assert [r for r in reads if r[0] == "serve/burst_sync"] \
+        == [("serve/burst_sync", [(n + sync_every * eng.max_batch,)])] * bursts
+    assert len(reads) == bursts + len(prompts) == eng.stats["d2h_reads"]
+    syncs = [kw for name, kw in opened if name == "serve/burst_sync"]
+    assert len(syncs) == bursts > 0 and {kw["arrays"] for kw in syncs} == {1}
+    assert not [name for name, _ in opened if name.startswith("pump/")]
+    assert eng.stats["host_sync_count"] == bursts + len(prompts)
+    # against the parent's crossing
+    assert tokens == want_tokens
+    assert eng.stats["decode_steps"] == want.stats["decode_steps"]
+    for name in eng._device_counters:
+        assert eng.stats[name] == want.stats[name] > 0, name
 
 
 def test_a_speculative_burst_launches_draft_verify_and_accept():
@@ -316,6 +423,8 @@ def test_a_speculative_burst_launches_draft_verify_and_accept():
         + s["decode_steps"] * (k + 2)
     bursts = s["decode_steps"] // eng.sync_every
     assert s["d2h_reads"] == bursts * (2 * eng.sync_every + 1) + 2
+    # its verify steps still go through the pump, which counts its own
+    assert s["host_sync_count"] == 2 * bursts + 2
 
 
 @pytest.mark.parametrize("block", ["dense", "hybrid"])
@@ -333,12 +442,13 @@ def test_spans_carry_what_crossed_and_every_launch_is_in_a_dispatch(
     # puts and reads: the spans' attributes add up to the counters
     stages = by("serve/burst_stage") + by("serve/prefill_stage")
     syncs = by("serve/burst_sync") + by("serve/prefill_sync")
-    made = bool(eng._device_counters)         # the construction's one put
-    assert sum(s["arrays"] for s in stages) == stats["h2d_puts"] - made
+    # but for the construction's one put, the zeros of the carry
+    assert sum(s["arrays"] for s in stages) == stats["h2d_puts"] - 1
     assert sum(s["bytes"] for s in stages) == stats["h2d_bytes"] \
-        - 4 * len(eng._device_counters)
+        - eng._carry_zero.nbytes
     assert sum(s["arrays"] for s in syncs) == stats["d2h_reads"]
     assert sum(s["bytes"] for s in syncs) == stats["d2h_bytes"]
+    assert {s["arrays"] for s in by("serve/burst_sync")} == {1}
     # the work a launch carries
     assert sum(s["rows"] for s in by("serve/prefill_dispatch")) \
         == 5 + 7 + 13 + 3
@@ -378,7 +488,8 @@ def test_the_launch_helper_leaves_the_programs_as_they_were(which):
     B, Pn = eng.max_batch, eng.pages_per_request
     z = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
     args = (eng._decode, eng.pool.bufs, eng._params, z(B, Pn), z(B), z(B),
-            z(B), jnp.zeros((B,), bool)) if which == "decode" else (
+            z(B), jnp.zeros((B,), bool), eng._carry_zero) \
+        if which == "decode" else (
         eng._prefill, eng.pool.bufs, eng._params_pre, z(1, Pn), z(1, 8),
         jnp.int32(0), jnp.int32(5))
     assert args[0].lower(*args[1:]).as_text() \
