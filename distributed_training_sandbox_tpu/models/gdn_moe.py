@@ -246,14 +246,21 @@ def attention_qkv(r, layer, *, cfg, rope):
     return q, k, v, qg[..., hd:]
 
 
+def gate_heads(attn, gate):
+    """The heads' outputs ``attn`` (B, S, ..heads.., hd), each times
+    ``sigmoid`` of its ``gate`` (B, S, n, hd), in float32 (also
+    ``models/swa_moe.py``'s output gate)."""
+    return attn.astype(jnp.float32).reshape(gate.shape) \
+        * jax.nn.sigmoid(gate.astype(jnp.float32))
+
+
 def attention_output(attn, gate, x, layer, *, cfg):
     """The heads' outputs ``attn`` (B, S, ..heads.., hd) float32, each
     gated by ``sigmoid(gate)``, through ``wo`` onto the residual stream:
     ``h``."""
     from .transformer import _dense
     B, S = attn.shape[:2]
-    a = attn.astype(jnp.float32).reshape(gate.shape) \
-        * jax.nn.sigmoid(gate.astype(jnp.float32))
+    a = gate_heads(attn, gate)
     return x + _dense(cfg)(a.astype(x.dtype).reshape(B, S, -1), layer["wo"])
 
 

@@ -39,7 +39,10 @@ all ``router_width`` experts (a block module says which in its
 ``sigmoid(r2 . ws_sigmoid) * SwiGLU_shared(r2)`` (an expert layer that
 holds the leaf ``ws_sigmoid`` (H, 1)); it counts its
 held experts as ``num_experts``, this block as ``n_routed_experts``
-(``cfg.held_experts`` reads either).
+(``cfg.held_experts`` reads either).  It serves ``models/swa_moe.py``
+too, whose router chooses by ``s + router_bias`` (an expert layer that
+holds the leaf ``router_bias`` (router width,)) and weighs by ``s``, and
+whose dense layers and sandwich residual are :func:`mlp` as it stands.
 
 ``w_e`` is normalised over all of ``T`` whether or not its experts are held
 here; what absent experts would add is left out (one rank's part under
@@ -337,19 +340,25 @@ def attention_output(o, x, layer, cfg):
 
 # -------------------------------------------------------------------- MLP
 
-def route(r2, w_router, cfg):
+def route(r2, w_router, cfg, *, bias=None):
     """``r2`` (T, H) -> the routing weights of the HELD experts
     (T, held experts) float32, zero where a held expert was not among
     the row's ``num_experts_per_tok``, and the chosen ids (T, k).  An
     expert's score is the block's ``ROUTER_SCORING``: its own
     ``"sigmoid"``, or a ``"softmax"`` over the router's whole width; the
     chosen scores are renormalised to sum to ``routed_scaling_factor``
-    either way."""
+    either way.  A selection ``bias`` (router width,) float32 is added to
+    the scores to CHOOSE the experts and is no part of their weights."""
     with jax.default_matmul_precision("highest"):
         s = r2.astype(jnp.float32) @ w_router.astype(jnp.float32)
     s = jax.nn.sigmoid(s) if cfg.block_module.ROUTER_SCORING == "sigmoid" \
         else jax.nn.softmax(s, axis=-1)
-    top, idx = lax.top_k(s, cfg.num_experts_per_tok)
+    if bias is None:
+        top, idx = lax.top_k(s, cfg.num_experts_per_tok)
+    else:
+        _, idx = lax.top_k(s + bias.astype(jnp.float32),
+                           cfg.num_experts_per_tok)
+        top = jnp.take_along_axis(s, idx, axis=-1)
     w = cfg.routed_scaling_factor * top \
         / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
     held = cfg.expert_offset + jnp.arange(cfg.held_experts)
@@ -383,7 +392,11 @@ def expert_mlp(r2, layer, *, cfg, valid=None):
     B, S, H = r2.shape
     rows = r2.reshape(B * S, H)
     with scope("moe_route"):
-        w_held, idx = route(rows, layer["w_router"], cfg)
+        # the selection bias, where the layer holds one (a keyword only
+        # then: what replaces ``route`` in a test takes three arguments)
+        bias = {"bias": layer["router_bias"]} if "router_bias" in layer \
+            else {}
+        w_held, idx = route(rows, layer["w_router"], cfg, **bias)
         ok = jnp.ones((B * S,), jnp.bool_) if valid is None \
             else valid.reshape(-1)
         counts = moe_counts(w_held, idx, ok, cfg)

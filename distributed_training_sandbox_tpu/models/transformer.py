@@ -204,6 +204,23 @@ class TransformerConfig:
     num_experts: int = 0
     shared_expert_intermediate_size: int = 0
     partial_rotary_factor: float = 1.0
+    # Sliding-window and full GQA attention mixed in one stack, output
+    # gated, over held and shared experts (``models/swa_moe.py``; names as
+    # in the published configs of that family).  ``sliding_window`` > 0
+    # selects it: layer i (0-based) attends its whole context where
+    # (i + 1) % ``global_attn_every_n_layers`` == 0 and its last
+    # ``sliding_window`` positions otherwise (rotary embedding on those
+    # layers alone); the first ``num_dense_layers`` layers have a SwiGLU of
+    # ``intermediate_size``, the rest a sigmoid router of ``router_width``
+    # with a selection bias, ``num_experts`` routed experts HELD here (ids
+    # ``expert_offset`` onwards) and ``num_shared_experts`` shared ones, all
+    # of ``moe_intermediate_size``; ``mup_enabled`` scales the embedding by
+    # sqrt(hidden).  Serving only and the cache-less ``forward``.
+    sliding_window: int = 0
+    global_attn_every_n_layers: int = 0
+    num_dense_layers: int = 0
+    num_shared_experts: int = 0
+    mup_enabled: bool = False
 
     def __post_init__(self):
         if self.block_module is not None:
@@ -266,6 +283,13 @@ class TransformerConfig:
         return self.gdn_hybrid and self.num_experts > 0
 
     @property
+    def swa_moe(self) -> bool:
+        """Sliding-window and full attention layers over held and shared
+        experts (``models/swa_moe.py``), whose window layers cache their
+        rows in a page class of their own."""
+        return self.sliding_window > 0
+
+    @property
     def held_experts(self) -> int:
         """Routed experts of an expert layer that this program holds, under
         whichever name the block's published config counts them."""
@@ -285,6 +309,9 @@ class TransformerConfig:
         if self.gdn_hybrid:
             from . import gdn_hybrid
             return gdn_hybrid
+        if self.swa_moe:
+            from . import swa_moe
+            return swa_moe
         return None
 
     def param_count(self) -> int:

@@ -123,10 +123,9 @@ def _head_rows(src, head):
                            axis=1)
 
 
-def _prefill_kernel(start_ref, end_ref, pages_ref, q_ref, pk_hbm, pv_hbm,
-                    o_ref, k_buf, v_buf, k32, v32, acc_ref, sems, *,
+def _prefill_kernel(start_ref, end_ref, pages_ref, *refs,
                     table_pages: int, block_pages: int, page: int,
-                    nkv: int, rep: int, probs_dtype):
+                    nkv: int, rep: int, probs_dtype, bounded: bool = False):
     """Float pool, one batch row's chunk.  ``start_ref``/``end_ref``
     (B,) and ``pages_ref`` (B * P,) are in SMEM: the chunk's first
     absolute position, one past the last position any of its rows may
@@ -137,13 +136,23 @@ def _prefill_kernel(start_ref, end_ref, pages_ref, q_ref, pk_hbm, pv_hbm,
     T = block_pages * page * n_kv slab rows, k32/v32 their float32
     staging (:func:`_staging`), acc_ref (n_kv, hd, R) the transposed float32
     accumulators, sems (2, 2) the K and V semaphores.  o_ref is
-    (1, n_kv, R, hd) float32."""
+    (1, n_kv, R, hd) float32.  ``bounded``: one more scalar-prefetched
+    operand leads ``refs``, ``lo_ref`` (B,), the first position the chunk's
+    FIRST row sees (a window layer's; it may be negative): the band slides
+    with the rows, row ``i`` sees from ``lo + i``, blocks wholly under
+    ``lo`` are not copied and keys under a row's bound are masked."""
+    lo_ref = refs[0] if bounded else None
+    (q_ref, pk_hbm, pv_hbm, o_ref, k_buf, v_buf, k32, v32, acc_ref,
+     sems) = refs[int(bounded):]
     b = pl.program_id(0)
     start, end = start_ref[b], end_ref[b]
     rows = page * nkv                          # pool rows a page
     span = block_pages * page                  # positions a block
     n_blocks = (end + span - 1) // span
     R, hd = q_ref.shape[2:]
+    # the first block read, and how far back of its own position a row sees
+    blk0 = jnp.maximum(lo_ref[b], 0) // span if bounded else 0
+    back = start - lo_ref[b] if bounded else None
 
     def copies(blk, slot):
         out = []
@@ -159,9 +168,9 @@ def _prefill_kernel(start_ref, end_ref, pages_ref, q_ref, pk_hbm, pv_hbm,
                 pv_hbm.at[pid], v_buf.at[slot, dst], sems.at[1, slot]))
         return out
 
-    @pl.when(n_blocks > 0)
+    @pl.when(n_blocks > blk0)
     def _():
-        for c in copies(0, 0):
+        for c in copies(blk0, blk0 % 2):
             c.start()
 
     # query row j (a lane) is chunk row j // rep: it sees positions up
@@ -186,6 +195,9 @@ def _prefill_kernel(start_ref, end_ref, pages_ref, q_ref, pk_hbm, pv_hbm,
         _stage(k32, k_buf[slot])
         _stage(v32, v_buf[slot])
         vis = blk * span + key_pos <= q_last                  # (span, R)
+        if bounded:
+            vis = jnp.logical_and(vis,
+                                  blk * span + key_pos >= q_last - back)
         out = []
         for g in range(nkv):
             m, l = carry[g]
@@ -198,7 +210,10 @@ def _prefill_kernel(start_ref, end_ref, pages_ref, q_ref, pk_hbm, pv_hbm,
             s = jnp.where(vis, s, -1e30)
             # block 0 holds position 0, which every row sees, so m is a
             # real score from the first block on and the -1e30 of a
-            # masked key underflows to exactly 0
+            # masked key underflows to exactly 0.  (Under a band a row may
+            # see nothing of the first blocks: its m is then -1e30, its l
+            # and acc finite, and the first block it does see multiplies
+            # both by exp(-1e30 - m_new) = 0.)
             m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
             corr = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new)
@@ -211,12 +226,12 @@ def _prefill_kernel(start_ref, end_ref, pages_ref, q_ref, pk_hbm, pv_hbm,
 
     m0 = jnp.full((1, R), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((1, R), jnp.float32)
-    stats = jax.lax.fori_loop(0, n_blocks, block, ((m0, l0),) * nkv)
+    stats = jax.lax.fori_loop(blk0, n_blocks, block, ((m0, l0),) * nkv)
     for g, (_, l) in enumerate(stats):
         o_ref[0, g] = (acc_ref[g] / jnp.where(l == 0.0, 1.0, l)).T
 
 
-def paged_flash_prefill(qg, pk, pv, pages, apos, *, valid=None,
+def paged_flash_prefill(qg, pk, pv, pages, apos, *, valid=None, lo=None,
                         probs_dtype=None, interpret: bool | None = None):
     """Chunked-prefill paged flash attention, pages read in place.
 
@@ -225,7 +240,9 @@ def paged_flash_prefill(qg, pk, pv, pages, apos, *, valid=None,
     table; apos (B, S) int32 absolute positions of the chunk's rows,
     CONSECUTIVE from ``apos[:, 0]`` (a chunk is); valid (B, S) bool, True
     for the rows that belong to the prompt, a prefix of each batch row
-    (default all).  A batch row reads its pages up to its last valid
+    (default all); lo (B, S) int32, the first position each row sees,
+    CONSECUTIVE too (a window layer's band; default: 0 for every row, and
+    the program is the one without the operand).  A batch row reads its pages up to its last valid
     row's position and no further; one with no valid row reads nothing
     and gets zeros; a padding row's output is finite and means nothing.
     Returns f32 (B, S, n_kv, rep, hd), the value of the reference
@@ -248,8 +265,11 @@ def paged_flash_prefill(qg, pk, pv, pages, apos, *, valid=None,
             f"serves it")
     start = apos[:, 0]
     n_valid = S if valid is None else jnp.sum(valid.astype(jnp.int32), 1)
+    # the band's lower bound slides with the rows: the first row's less
+    # its clamp at 0 is what the kernel is told (``lo[:, -1]`` of the last)
+    bound = () if lo is None else (lo[:, -1] - (S - 1),)
     return _prefill_float(
-        qg, pk, pv, pages, start, start + n_valid,
+        qg, pk, pv, pages, start, start + n_valid, *bound,
         block_pages=min(PAGES_PER_BLOCK, pages.shape[1]),
         probs_dtype=jnp.dtype(probs_dtype or qg.dtype),
         interpret=bool(interpret))
@@ -259,24 +279,26 @@ def paged_flash_prefill(qg, pk, pv, pages, apos, *, valid=None,
 # of one prefill program share one trace and one Mosaic lowering
 @functools.partial(jax.jit, static_argnames=("block_pages", "probs_dtype",
                                              "interpret"))
-def _prefill_float(qg, pk, pv, pages, start, end, *, block_pages: int,
-                   probs_dtype, interpret: bool):
+def _prefill_float(qg, pk, pv, pages, start, end, lo=None, *,
+                   block_pages: int, probs_dtype, interpret: bool):
     """The kernel's call: qg (B, S, n_kv, rep, hd), the pools as the
     engine holds them, pages (B, P), start/end (B,): the chunk's first
-    position and one past the last position it may see (0: nothing)."""
+    position and one past the last position it may see (0: nothing), and
+    where given lo (B,): the first position the chunk's first row sees."""
     B, S, nkv, rep, hd = qg.shape
     P = pages.shape[1]
     n_pages, page = pk.shape[:2]
     R, rows = S * rep, page * nkv
     T = block_pages * rows
+    bound = () if lo is None else (lo.astype(jnp.int32),)
     kernel = functools.partial(
         _prefill_kernel, table_pages=P, block_pages=block_pages, page=page,
-        nkv=nkv, rep=rep, probs_dtype=probs_dtype)
+        nkv=nkv, rep=rep, probs_dtype=probs_dtype, bounded=bool(bound))
     heads = pl.BlockSpec((1, nkv, R, hd), lambda b, *_: (b, 0, 0, 0))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=3 + len(bound),
             grid=(B,),
             in_specs=[heads, pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
@@ -293,7 +315,7 @@ def _prefill_float(qg, pk, pv, pages, start, end, *, block_pages: int,
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(start.astype(jnp.int32), end.astype(jnp.int32),
-      pages.reshape(-1).astype(jnp.int32),
+      pages.reshape(-1).astype(jnp.int32), *bound,
       qg.transpose(0, 2, 1, 3, 4).reshape(B, nkv, R, hd),
       pk.reshape(n_pages, rows, hd), pv.reshape(n_pages, rows, hd))
     return out.reshape(B, nkv, S, rep, hd).transpose(0, 2, 1, 3, 4)

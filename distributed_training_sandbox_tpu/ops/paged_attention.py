@@ -117,22 +117,31 @@ def decode_kernel_takes(dtype, head_dim: int, page_size: int) -> bool:
     return head_dim % 128 == 0 and page_size % (32 // dtype.itemsize) == 0
 
 
-def _decode_kernel(len_ref, pages_ref, q_ref, col_ref, pk_hbm, pv_hbm,
-                   o_ref, k_buf, v_buf, sems, *, table_pages: int,
-                   block_pages: int, page: int, rep: int, probs_dtype):
+def _decode_kernel(len_ref, pages_ref, *refs, table_pages: int,
+                   block_pages: int, page: int, rep: int, probs_dtype,
+                   bounded: bool = False):
     """Float pool, one batch slot (S == 1).  ``len_ref`` (B,) and
     ``pages_ref`` (B * P,) are in SMEM; q_ref (1, R, hd) holds the
     slot's R = n_kv * rep query rows; pk_hbm/pv_hbm are the pools as
     (n_pages, page * n_kv, hd), left in HBM; col_ref (2, T) says of each
     of a block's T = block_pages * page * n_kv columns its position
     within the block and its KV head; k_buf/v_buf (2, T, hd) are the two
-    halves of the double buffer; sems (2, 2) their K and V semaphores."""
+    halves of the double buffer; sems (2, 2) their K and V semaphores.
+    ``bounded``: one more scalar-prefetched operand leads ``refs``,
+    ``lo_ref`` (B,), the first position a slot sees (a window layer's):
+    blocks wholly under it are not copied, positions under it in its
+    block are masked."""
+    lo_ref = refs[0] if bounded else None
+    q_ref, col_ref, pk_hbm, pv_hbm, o_ref, k_buf, v_buf, sems = \
+        refs[int(bounded):]
     b = pl.program_id(0)
     length = len_ref[b]
     rows = k_buf.shape[1] // block_pages       # pool rows a page
     hd = q_ref.shape[-1]
     span = block_pages * page                  # positions a block
     n_blocks = (length + span - 1) // span
+    lo = jnp.maximum(lo_ref[b], 0) if bounded else None
+    blk0 = lo // span if bounded else 0        # the first block read
 
     def copies(blk, slot):
         out = []
@@ -148,9 +157,9 @@ def _decode_kernel(len_ref, pages_ref, q_ref, col_ref, pk_hbm, pv_hbm,
                 pv_hbm.at[pid], v_buf.at[slot, dst], sems.at[1, slot]))
         return out
 
-    @pl.when(n_blocks > 0)
+    @pl.when(n_blocks > blk0)
     def _():
-        for c in copies(0, 0):
+        for c in copies(blk0, blk0 % 2):
             c.start()
 
     q = q_ref[0]                                              # (R, hd)
@@ -174,9 +183,12 @@ def _decode_kernel(len_ref, pages_ref, q_ref, col_ref, pk_hbm, pv_hbm,
             q, k_buf[slot], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) / math.sqrt(hd)
         vis = jnp.logical_and(own_head, blk * span + col_pos < length)
+        if bounded:
+            vis = jnp.logical_and(vis, blk * span + col_pos >= lo)
         s = jnp.where(vis, s, -1e30)
         # every block the loop runs starts at a position under the
-        # length, so each row sees a real score in it and the -1e30 of a
+        # length (and the first holds ``lo``, itself under the length), so
+        # each row sees a real score in it and the -1e30 of a
         # masked column underflows to exactly 0
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m - m_new)
@@ -189,7 +201,7 @@ def _decode_kernel(len_ref, pages_ref, q_ref, col_ref, pk_hbm, pv_hbm,
     m0 = jnp.full((R, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((R, 1), jnp.float32)
     a0 = jnp.zeros((R, hd), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, n_blocks, block, (m0, l0, a0))
+    _, l, acc = jax.lax.fori_loop(blk0, n_blocks, block, (m0, l0, a0))
     o_ref[0] = acc / jnp.where(l == 0.0, 1.0, l)
 
 
@@ -223,7 +235,7 @@ def _decode_kernel_q8(pages_ref, q_ref, qs_ref, apos_ref, pk_ref, pv_ref,
 
 
 def paged_attention_decode(qg, pk, pv, pages, apos, *, valid=None,
-                           q_scale=None, pk_s=None, pv_s=None,
+                           q_scale=None, pk_s=None, pv_s=None, lo=None,
                            probs_dtype=None, interpret: bool | None = None):
     """Decode-step paged attention, pages read in place via the table.
 
@@ -233,7 +245,9 @@ def paged_attention_decode(qg, pk, pv, pages, apos, *, valid=None,
     for int8 pools.  pages (B, P) int32 page table; apos (B, 1) int32
     absolute position of the new row; valid (B, 1) bool, False for a
     slot that holds no request (float pools: it reads no page and gets
-    zeros; default all True).  Returns f32 (B, 1, n_kv, rep, hd), the
+    zeros; default all True); lo (B, 1) int32, float pools only: the first
+    position the row sees (a window layer's lower bound; default: 0,
+    and the program is the one without the operand).  Returns f32 (B, 1, n_kv, rep, hd), the
     value of the reference gather-then-einsum path: exactly for int8
     pools, to float32 summation order for float pools (caller applies
     the same ``astype`` epilogue).  ``interpret`` None: compiled on a
@@ -251,6 +265,8 @@ def paged_attention_decode(qg, pk, pv, pages, apos, *, valid=None,
         refuse_on_tpu("paged_attention_decode (int8 pool)")
         if q_scale is None or pk_s is None or pv_s is None:
             raise ValueError("int8 pool needs q_scale, pk_s and pv_s")
+        if lo is not None:
+            raise ValueError("the int8 decode kernel takes no lower bound")
         whole = lambda arr: pl.BlockSpec(
             arr.shape, lambda b: (0,) * arr.ndim)
         wide = lambda last: pl.BlockSpec(
@@ -275,8 +291,9 @@ def paged_attention_decode(qg, pk, pv, pages, apos, *, valid=None,
     lengths = apos[:, 0] + 1
     if valid is not None:
         lengths = jnp.where(valid[:, 0], lengths, 0)
+    bound = () if lo is None else (lo[:, 0],)
     return _decode_float(
-        qg, pk, pv, pages, lengths,
+        qg, pk, pv, pages, lengths, *bound,
         block_pages=min(PAGES_PER_BLOCK, P),
         probs_dtype=jnp.dtype(probs_dtype or qg.dtype),
         interpret=bool(interpret))
@@ -287,11 +304,12 @@ def paged_attention_decode(qg, pk, pv, pages, apos, *, valid=None,
 # layers added ~10 s to every engine's warm-up, compile cache or not
 @functools.partial(jax.jit, static_argnames=("block_pages", "probs_dtype",
                                              "interpret"))
-def _decode_float(qg, pk, pv, pages, lengths, *, block_pages: int,
+def _decode_float(qg, pk, pv, pages, lengths, lo=None, *, block_pages: int,
                   probs_dtype, interpret: bool):
     """The float kernel's call: qg (B, 1, n_kv, rep, hd), the pools as
     the engine holds them, pages (B, P), lengths (B,) with 0 for a slot
-    that reads nothing."""
+    that reads nothing, and where given lo (B,), the first position each
+    slot sees."""
     B, _, nkv, rep, hd = qg.shape
     P = pages.shape[1]
     n_pages, page = pk.shape[:2]
@@ -302,14 +320,15 @@ def _decode_float(qg, pk, pv, pages, lengths, *, block_pages: int,
     w = np.arange(T, dtype=np.int32) % rows
     cols = np.stack([(np.arange(T, dtype=np.int32) // rows) * page
                      + w // nkv, w % nkv])
+    bound = () if lo is None else (lo.astype(jnp.int32),)
     kernel = functools.partial(
         _decode_kernel, table_pages=P, block_pages=block_pages, page=page,
-        rep=rep, probs_dtype=probs_dtype)
+        rep=rep, probs_dtype=probs_dtype, bounded=bool(bound))
     slot = pl.BlockSpec((1, R, hd), lambda b, *_: (b, 0, 0))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=2 + len(bound),
             grid=(B,),
             in_specs=[slot, pl.BlockSpec((2, T), lambda b, *_: (0, 0)),
                       pl.BlockSpec(memory_space=pl.ANY),
@@ -323,7 +342,7 @@ def _decode_float(qg, pk, pv, pages, lengths, *, block_pages: int,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(lengths.astype(jnp.int32), pages.reshape(-1).astype(jnp.int32),
-      qg.reshape(B, R, hd), cols, pk.reshape(n_pages, rows, hd),
+      *bound, qg.reshape(B, R, hd), cols, pk.reshape(n_pages, rows, hd),
       pv.reshape(n_pages, rows, hd))
     return out.reshape(B, 1, nkv, rep, hd)
 

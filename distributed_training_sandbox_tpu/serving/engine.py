@@ -66,7 +66,7 @@ from ..ops import collectives as C
 from ..telemetry.spans import maybe_span
 from ..utils.profiling import scope
 from .kv_pool import (PagedKVPool, PoolBuffers, RadixPrefixCache,
-                      row_layout, slab_pool)
+                      ring_pages, ring_view, row_layout, slab_pool)
 from .scheduler import ContinuousBatcher, DECODE, PREFILL, Request
 
 __all__ = ["ServingEngine", "serve", "make_serve_decode_step",
@@ -153,7 +153,8 @@ def _paged_layer_body(x, layer, *, cfg, cos, sin, use_rope, pk, pv,
 
 
 def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
-                  valid, paged_kernel=False, kernel_scope=None, slab=False):
+                  valid, paged_kernel=False, kernel_scope=None, slab=False,
+                  view=None):
     """The new K/V rows into their pages, then causal attention of the
     rows at ``apos`` against their slots' pages:
 
@@ -177,7 +178,13 @@ def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
     ``w // nkv``, head ``w % nkv``; pages (B, P);
     apos, valid (B, S).  ``kernel_scope`` names one more scope beneath
     ``attn_core`` round the attention itself (a block that wants its paged
-    attention read apart; None opens none).  Returns the heads' outputs float32
+    attention read apart; None opens none).  ``view``: a WINDOW layer's
+    reading side, ``kv_pool.ring_view``'s ``(view table (B, R), apos in
+    view coordinates, lo)``: ``pages`` is then the slot's RING (position
+    ``p`` is written at ``pages[(p // page) % R]``), and the attention
+    reads the view table, a row at view position ``a`` seeing ``lo <= s <=
+    a``, through the same kernels or the same gather.  Returns the heads'
+    outputs float32
     (B, S, nkv, nq / nkv, hd) and the pools
     ``(pk, pv, pk_s, pv_s)``."""
     B, S, nq, hd = q.shape
@@ -191,7 +198,8 @@ def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
     # scatter targets there are fine — it's the trash page)
     quantized = pk.dtype == jnp.int8
     with scope("kv_write"):
-        pi = jnp.clip(apos // page, 0, P - 1)
+        pi = jnp.clip(apos // page, 0, P - 1) if view is None \
+            else (apos // page) % P
         pg = jnp.where(valid, jnp.take_along_axis(pages, pi, axis=1), 0)
         off = apos % page
         if quantized:
@@ -209,6 +217,10 @@ def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
             pk = pk.at[pg, off].set(k)
             pv = pv.at[pg, off].set(v)
 
+    lo = None
+    if view is not None:    # written through the ring, read through the view
+        pages, apos, lo = view
+    bound = {} if lo is None else {"lo": lo}
     rep = nq // nkv
     # the kernels take (n_pages, page, nkv, hd) and read it as the slab:
     # of a pool stored as the slab, a view and back
@@ -232,7 +244,7 @@ def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
             else:
                 attn = paged_attention_decode(
                     qg, as_pages(pk), as_pages(pv), pages, apos,
-                    valid=valid, probs_dtype=dtype)
+                    valid=valid, probs_dtype=dtype, **bound)
         return attn, (pk, pv, pk_s, pv_s)
 
     if paged_kernel and not quantized:
@@ -247,7 +259,7 @@ def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
             qg = q.reshape(B, S, nkv, rep, hd)
             attn = paged_flash_prefill(qg, as_pages(pk), as_pages(pv),
                                        pages, apos, valid=valid,
-                                       probs_dtype=dtype)
+                                       probs_dtype=dtype, **bound)
         return attn, (pk, pv, pk_s, pv_s)
 
     # gather the slot's pages into the contiguous head-major view the
@@ -276,6 +288,9 @@ def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
                 preferred_element_type=jnp.float32) / math.sqrt(hd)
         pos_kv = jnp.arange(V)
         vis = pos_kv[None, None, :] <= apos[:, :, None]      # (B, S, V)
+        if lo is not None:
+            vis = jnp.logical_and(vis,
+                                  pos_kv[None, None, :] >= lo[:, :, None])
         scores = jnp.where(vis[:, None, None], scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1)
         if quantized:
@@ -505,6 +520,60 @@ def _paged_hybrid_forward(params, ids, cfg, bufs: PoolBuffers, pages,
                             conv=tuple(tails)), live
 
 
+def _paged_swa_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
+                       valid, paged_kernel=False):
+    """``_paged_forward`` for the block of sliding-window and
+    full-attention layers over dense and then expert MLPs
+    (``models/swa_moe.py`` holds its pieces): ``params["layers"]`` is a
+    tuple of per-layer dicts, every layer caches K/V rows in its own pools
+    through :func:`_paged_attend`, and ``pages`` is a PAIR of tables, one a
+    page class (``kv_pool``): ``(full (B, P), ring (B, R))``.
+
+    A FULL layer is the dense block's storage and kernels over the first
+    table: written at ``full[p // page]``, read in order from position 0,
+    under ``attn_core/attn_paged``.  A WINDOW layer writes into its slot's
+    ring, ``ring[(p // page) % R]``, and reads ``(p - sliding_window, p]``
+    through the ordered view ``kv_pool.ring_view`` rotates out of the ring
+    once a launch, with a lower bound, under ``attn_core/attn_window``.
+
+    Returns ``(x', bufs', counts)``: the expert layers'
+    ``mla_moe.moe_counts`` summed, then the cached rows the valid rows of
+    this call read in ONE window layer and in ONE full layer (a decode
+    step's ``window_rows_read`` and ``full_rows_read``)."""
+    blk = cfg.block_module
+    full, ring = pages
+    page = bufs.k[0].shape[1]
+    W = cfg.sliding_window
+    with scope("embed"):
+        x = blk.embed(params, ids, cfg)
+        rope = _ragged_rope_tables(apos, cfg.resolved_head_dim,
+                                   cfg.rope_theta)
+        view = ring_view(ring, apos, W, page)
+    ks, vs = list(bufs.k), list(bufs.v)
+    moe = jnp.zeros((len(M.COUNTERS),), jnp.int32)
+    for li, layer in enumerate(params["layers"]):
+        window = blk.is_window_layer(li, cfg)
+        with scope("attn_qkv"):
+            q, k, v, gate = blk.attention_qkv(
+                x, layer, cfg=cfg, rope=rope if window else None)
+        attn, (ks[li], vs[li], _, _) = _paged_attend(
+            q, k, v, dtype=x.dtype, pk=ks[li], pv=vs[li], pk_s=None,
+            pv_s=None, pages=ring if window else full, apos=apos,
+            valid=valid, paged_kernel=paged_kernel,
+            kernel_scope=blk.WINDOW_ATTENTION_SCOPE if window
+            else blk.PAGED_ATTENTION_SCOPE, view=view if window else None)
+        with scope("attn_out"):
+            h = blk.attention_output(attn, gate, x, layer, cfg=cfg)
+        with scope("mlp"):
+            x, counts = blk.mlp(h, layer, cfg=cfg, li=li, valid=valid)
+            if counts is not None:
+                moe = moe + counts
+    seen = jnp.where(valid, apos + 1, 0)
+    rows = jnp.stack([jnp.sum(jnp.minimum(seen, W)), jnp.sum(seen)])
+    return x, bufs._replace(k=tuple(ks), v=tuple(vs)), \
+        jnp.concatenate([moe, rows.astype(jnp.int32)])
+
+
 def _paged_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
                    valid, tp_axis=None, paged_kernel=False, slot=None):
     """ids (B, S) → (hidden x (B, S, H), bufs', counts) through the
@@ -512,7 +581,9 @@ def _paged_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
     like ``generate._forward_cached``).  ``counts`` is None for the dense
     block, the expert layers' counters for the latent one and the live
     rows for the gated delta-rule hybrid, whose prefill chunk also names
-    the batch ``slot`` whose state it carries."""
+    the batch ``slot`` whose state it carries; for the block with window
+    layers ``pages`` is a pair of tables and ``counts`` end in the rows its
+    two kinds of layer read."""
     if cfg.mla_moe:
         return _paged_latent_forward(params, ids, cfg, bufs, pages, apos,
                                      valid, paged_kernel=paged_kernel)
@@ -520,6 +591,9 @@ def _paged_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
         return _paged_hybrid_forward(params, ids, cfg, bufs, pages, apos,
                                      valid, paged_kernel=paged_kernel,
                                      slot=slot)
+    if cfg.swa_moe:
+        return _paged_swa_forward(params, ids, cfg, bufs, pages, apos,
+                                  valid, paged_kernel=paged_kernel)
     with scope("embed"):
         x = params["embed"].astype(cfg.dtype)[ids]
         cos, sin = _ragged_rope_tables(apos, cfg.resolved_head_dim,
@@ -575,8 +649,11 @@ def _last_logits(params, x_last, cfg):
 def device_counters(cfg) -> tuple:
     """The names of what the block's decode program sums on the device,
     in the order they lead ``_decode_core``'s ``carry``: the expert
-    layers' ``mla_moe.COUNTERS``, then the hybrids' ``state_slot_steps``;
+    layers' ``mla_moe.COUNTERS``, then the hybrids' ``state_slot_steps`` or
+    the window block's ``window_rows_read`` and ``full_rows_read``;
     () for the dense block, whose ``carry`` is token rows alone."""
+    if cfg.swa_moe:     # the expert layers' four, the rows its layers read
+        return cfg.block_module.DEVICE_COUNTERS
     out = M.COUNTERS if cfg.mla_moe or cfg.gdn_moe else ()
     return out + (G.COUNTERS[:1] if cfg.gdn_hybrid else ())
 
@@ -888,17 +965,21 @@ class ServingEngine:
                  watchdog=None, telem=None):
         self.cfg = _decode_cfg(cfg)
         if self.cfg.block_module is not None:
-            # built for the latent block and for the gated delta-rule
-            # hybrid: chunked prefill, the decode burst, the paged
-            # kernels.  The rest is refused by name, never run as dense
-            # math (the hybrid's state slots have no snapshot for a
-            # prefix to share, no rollback for a rejected draft, no int8
-            # form, no head axis to shard and no hand-over between pools)
+            # built for the latent block, the gated delta-rule hybrids
+            # and the block with window layers: chunked prefill, the decode
+            # burst, the paged kernels.  The rest is refused by name, never
+            # run as dense math (the hybrid's state slots have no snapshot
+            # for a prefix to share, no rollback for a rejected draft, no
+            # int8 form, no head axis to shard and no hand-over between
+            # pools; nor has a window layer's ring, whose rows of a prefix
+            # are gone once the request has passed the window)
             for what, asked in (
                     ("kv_quant", kv_quant), ("a tp mesh", mesh is not None),
                     ("spec_k", spec_k), ("flash_prefill", flash_prefill),
                     ("disaggregate", disaggregate),
-                    ("prefix_cache", prefix_cache)):
+                    ("prefix_cache", prefix_cache),
+                    ("hbm_budget_gb", hbm_budget_gb is not None
+                     and self.cfg.swa_moe)):
                 if asked:
                     self.cfg.block_module.refuse(
                         self.cfg, f"ServingEngine with {what}")
@@ -1032,6 +1113,13 @@ class ServingEngine:
                 f"({self.pages_per_request} pages + null); raise the "
                 f"HBM budget or shrink max_seq_len")
         self.n_pages = int(n_pages)
+        # the window layers' page class (kv_pool): a ring a slot and the
+        # class's null page; 0 for a block with one class
+        self.ring_pages = self.n_pages_window = 0
+        if self.cfg.swa_moe:
+            self.ring_pages = ring_pages(self.cfg, self.page_size,
+                                         self.prefill_chunk)
+            self.n_pages_window = self.max_batch * self.ring_pages + 1
 
         devs = jax.devices()
         self._prefill_dev = self._decode_dev = None
@@ -1063,7 +1151,8 @@ class ServingEngine:
         self.pool = PagedKVPool(self.cfg, self.n_pages, self.page_size,
                                 kv_quant=self.kv_quant, mesh=mesh,
                                 tp_axis=tp_axis, device=self._decode_dev,
-                                n_slots=self.max_batch)
+                                n_slots=self.max_batch,
+                                n_pages_window=self.n_pages_window)
         # the draft model's own pool, addressed by the SAME page tables
         # as the target pool (no second allocator): position p of a
         # request's draft KV lives at the same (page, offset) as its
@@ -1091,7 +1180,9 @@ class ServingEngine:
             comps["draft_kv_pool"] = round(
                 tree_size_bytes(self.draft_pool.bufs) / GB, 3)
         self._mem_prediction = {
-            "predicted_gb": round(serve_waterline_gb(
+            # two page classes: the pools as built are the model
+            "predicted_gb": round((_wb + _pool_b) / GB, 3)
+            if self.n_pages_window else round(serve_waterline_gb(
                 self.cfg, self.n_pages, self.page_size, weight_bytes=_wb,
                 kv_quant=self.kv_quant, tp=tp,
                 draft_weight_bytes=_dwb, draft_cfg=self.draft_cfg,
@@ -1177,10 +1268,14 @@ class ServingEngine:
         self._h_stop = np.zeros(B, np.int32)
         self._h_active = np.zeros(B, np.bool_)
         self._h_pages = np.zeros((B, P), np.int32)
+        # the slots' rings in the window page class, where there is one
+        self._h_rings = np.zeros((B, self.ring_pages), np.int32) \
+            if self.ring_pages else None
 
-        self.batcher = ContinuousBatcher(self.max_batch,
-                                         self.pool.allocator,
-                                         self.page_size)
+        self.batcher = ContinuousBatcher(
+            self.max_batch, self.pool.allocator, self.page_size,
+            window_allocator=self.pool.window_allocator,
+            ring_pages=self.ring_pages)
         self.batcher.metrics = getattr(telem, "metrics", None)
         self.prefix_cache = None
         if prefix_cache:
@@ -1233,6 +1328,9 @@ class ServingEngine:
         if self.cfg.gdn_hybrid:
             self.stats.update(dict.fromkeys(
                 G.COUNTERS[1:] + ("lin_step_inplace_steps",), 0))
+        if self.cfg.swa_moe:    # its host counters (swa_moe.COUNTERS)
+            self.stats.update(dict.fromkeys(
+                self.cfg.block_module.COUNTERS, 0))
         self.stats.update(dict.fromkeys(self._device_counters, 0))
         # what every plain burst's carry starts from (_decode_core): the
         # counters at zero, then ``sync_every`` token rows that the
@@ -1288,6 +1386,10 @@ class ServingEngine:
         free = self.pool.allocator.free_pages
         if self.prefix_cache is not None:
             free += self.prefix_cache.reclaimable_pages
+        if self.pool.window_allocator is not None and \
+                self.pool.window_allocator.free_pages \
+                < self.batcher.pages_needed(req, window=True):
+            return False
         return free >= self.batcher.pages_needed(req)
 
     def in_flight(self) -> int:
@@ -1343,10 +1445,18 @@ class ServingEngine:
         return jnp.asarray(x)
 
     # ---- prefill ------------------------------------------------------
-    def _padded_row(self, pages: list[int]) -> np.ndarray:
-        row = np.zeros((1, self.pages_per_request), np.int32)
+    def _padded_row(self, pages: list[int], width: int | None = None
+                    ) -> np.ndarray:
+        row = np.zeros((1, width or self.pages_per_request), np.int32)
         row[0, :len(pages)] = pages
         return row
+
+    def _put_tables(self, full: np.ndarray, rings, device=None):
+        """The page tables a program takes, on the device: the full
+        class's, or with a window class the pair ``(full, rings)``."""
+        if rings is None:
+            return self._put(full, device)
+        return self._put(full, device), self._put(rings, device)
 
     def _prefill_one_chunk(self, req: Request, t0: float, k: int) -> None:
         """The ``k``-th prefill chunk of this round, of one request."""
@@ -1356,11 +1466,13 @@ class ServingEngine:
         stream, sp = self._stream, self._req_attrs(req)
         rows = min(Ck, req.n_prompt - pos)
         # what the stage ships: page row, ids, two scalars, and the
-        # hybrids' batch slot
-        n_put = 5 if self.cfg.gdn_hybrid else 4
+        # hybrids' batch slot or the window class's ring
+        slot_put = int(self.cfg.gdn_hybrid)
+        n_put = 4 + slot_put + bool(self.ring_pages)
         t_chunk = time.perf_counter()  # clock-ok
         with maybe_span(stream, "serve/prefill_stage", arrays=n_put,
-                        bytes=4 * (self.pages_per_request + Ck + n_put - 2),
+                        bytes=4 * (self.pages_per_request + Ck + 2
+                                   + slot_put + self.ring_pages),
                         **sp):
             chunk = req.prompt[pos:pos + Ck]
             ids = np.zeros((1, Ck), np.int32)
@@ -1371,9 +1483,17 @@ class ServingEngine:
             else:
                 row = self._padded_row(req.pages)
                 bufs = self.pool.bufs
-            args = (self._put(row, dev), self._put(ids, dev),
+            ring = self._padded_row(req.pages_window, self.ring_pages) \
+                if self.ring_pages else None
+            args = (self._put_tables(row, ring, dev), self._put(ids, dev),
                     self._put(np.int32(pos), dev),
                     self._put(np.int32(req.n_prompt), dev))
+            if self.ring_pages:
+                # (row, key) pairs a window layer's band holds for the
+                # chunk's valid rows: row t sees min(t + 1, window) keys
+                seen = np.minimum(np.arange(pos, pos + rows) + 1,
+                                  self.cfg.sliding_window)
+                self.stats["window_pairs_prefilled"] += int(seen.sum())
             if self.cfg.gdn_hybrid:
                 # the batch slot whose state the chunk carries on
                 args += (self._put(np.int32(req.slot), dev),)
@@ -1471,7 +1591,7 @@ class ServingEngine:
                 self.batcher.retire(req, now)
                 self.completed.append(req)
                 self._h_active[b] = False
-                self._h_pages[b] = 0
+                self._clear_tables(b)
                 return
             req.state = DECODE
             self._h_tokens[b] = first
@@ -1554,11 +1674,15 @@ class ServingEngine:
         """Ship the host mirrors a burst starts from: tokens, lengths,
         stop positions, active mask, page tables."""
         mirrors = (self._h_tokens, self._h_lengths, self._h_stop,
-                   self._h_active, self._h_pages)
+                   self._h_active)
+        tables = (self._h_pages,) if self._h_rings is None \
+            else (self._h_pages, self._h_rings)
         with maybe_span(self._stream, "serve/burst_stage",
-                        arrays=len(mirrors),
-                        bytes=sum(m.nbytes for m in mirrors), **self._sp):
-            return tuple(self._put(m) for m in mirrors)
+                        arrays=len(mirrors + tables),
+                        bytes=sum(m.nbytes for m in mirrors + tables),
+                        **self._sp):
+            return tuple(self._put(m) for m in mirrors) \
+                + (self._put_tables(self._h_pages, self._h_rings),)
 
     def _sync_burst(self, arrs: list) -> list[np.ndarray]:
         """The burst's one sync POINT: the host's wait for the burst's
@@ -1590,10 +1714,16 @@ class ServingEngine:
             req = self.batcher.slot_request(b)
             if req is not None and req.state == DECODE and not active[b]:
                 self.batcher.retire(req, now)
-                self._h_pages[b] = 0     # slot back to the null page
+                self._clear_tables(b)    # slot back to the null page
                 self.completed.append(req)
                 finished.append(req)
         return finished
+
+    def _clear_tables(self, b: int) -> None:
+        """Slot ``b``'s table rows back to the null page of each class."""
+        self._h_pages[b] = 0
+        if self._h_rings is not None:
+            self._h_rings[b] = 0
 
     def _decode_burst(self, t0: float) -> None:
         """A plain decode burst: ``sync_every`` launches back to back,
@@ -1821,14 +1951,24 @@ class ServingEngine:
             t0 = self._t0
             done_base = len(self.completed)
             t_admit = time.perf_counter()  # clock-ok
-            with maybe_span(self._stream, "serve/admit", **self._sp):
+            with maybe_span(self._stream, "serve/admit",
+                            **self._sp) as admit_span:
                 admitted = self.batcher.admit(now)
+                if self._h_rings is not None:
+                    # the round's grants from the two page classes
+                    admit_span.set_metadata(
+                        pages_full=sum(len(r.pages) for r in admitted),
+                        pages_window=sum(len(r.pages_window)
+                                         for r in admitted))
                 for req in admitted:
                     # install the slot's page-table row in the host
                     # mirror the decode burst ships (unused entries
                     # point at the null page)
-                    self._h_pages[req.slot] = 0
+                    self._clear_tables(req.slot)
                     self._h_pages[req.slot, :len(req.pages)] = req.pages
+                    if self._h_rings is not None:
+                        self._h_rings[req.slot, :len(req.pages_window)] \
+                            = req.pages_window
                     self.stats["queue_wait_s"] += req.t_admit - req.t_submit
                     if self.cfg.gdn_hybrid:
                         # the granted slot's state: its first prefill
@@ -1871,10 +2011,21 @@ class ServingEngine:
             self.stats["occupancy_sum"] += int(self._h_active.sum())
             self.stats["peak_pool_util"] = max(
                 self.stats["peak_pool_util"], self.pool.utilization)
+            if self._h_rings is not None:
+                self._note_class_peaks()
             if self._warm_sizes is None \
                     and self.stats["decode_steps"] > 0:
                 self._warm_sizes = self._jit_sizes()
             return self.completed[done_base:]
+
+    def _note_class_peaks(self) -> None:
+        """The most pages of each class granted at once, as of this
+        round's end."""
+        st, pool = self.stats, self.pool
+        st["full_pages_peak"] = max(st["full_pages_peak"],
+                                    pool.allocator.pages_in_use)
+        st["window_pages_peak"] = max(st["window_pages_peak"],
+                                      pool.window_allocator.pages_in_use)
 
     def run(self) -> list[Request]:
         def vt(r):
@@ -1913,6 +2064,8 @@ class ServingEngine:
                 self.pool_pre.allocator.free(self._pre_pages.pop(rid))
         self._h_active[:] = False
         self._h_pages[:] = 0
+        if self._h_rings is not None:
+            self._h_rings[:] = 0
         return orphans
 
     def swap_params(self, params) -> None:
@@ -2006,7 +2159,9 @@ class ServingEngine:
                      "page_size": self.page_size,
                      "bytes_per_token": self.pool.token_bytes,
                      "state_slot_bytes": self.pool.state_bytes,
-                     "peak_util": round(self.stats["peak_pool_util"], 4)},
+                     "peak_util": round(self.stats["peak_pool_util"], 4),
+                     "n_pages_window": self.n_pages_window,
+                     "ring_pages": self.ring_pages},
             "scheduler": {
                 "rounds": self.stats["rounds"],
                 "decode_steps": self.stats["decode_steps"],
@@ -2025,6 +2180,14 @@ class ServingEngine:
                     self.stats["occupancy_sum"]
                     / max(self.stats["rounds"], 1), 3),
                 "host_syncs": self.stats["host_sync_count"],
+                # the most of each page class granted at once, as a share
+                # of the class's usable pages
+                "peak_pool_util": {
+                    "full": round(self.stats["peak_pool_util"], 4),
+                    **({"window": round(
+                        self.stats["window_pages_peak"]
+                        / (self.n_pages_window - 1), 4)}
+                       if self.n_pages_window else {})},
                 # sync POINTS above; what crossed the host-device
                 # boundary, each crossing counted, here
                 "crossings": {k: self.stats[k] for k in (

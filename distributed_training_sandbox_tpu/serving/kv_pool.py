@@ -14,6 +14,16 @@ reallocating (or retracing) anything.  vLLM's paged layout, TPU-shaped:
   * a host-side PAGE TABLE per request slot: absolute position ``p`` of
     a request lives at ``(page_table[slot, p // page_size],
     p % page_size)``;
+  * TWO PAGE CLASSES where some layers attend through a sliding window
+    (``models/swa_moe.py``): a full-attention layer's pools are the pages
+    above, granted for a request's whole length; a WINDOW layer needs its
+    last ``sliding_window`` rows whatever the request's length, so the
+    window layers' pools are a class of their own with its own
+    ``n_pages``, its own :class:`PageAllocator` and a second table row a
+    slot, of ``ring_pages`` entries used as a RING: position ``p`` lives
+    at ``(table_w[slot, (p // page_size) % ring_pages], p % page_size)``
+    (:func:`ring_pages`, :func:`ring_view`).  Page 0 of each class is its
+    null page;
   * page 0 is RESERVED as the null page: writes for padded/inactive
     positions are diverted there (a scatter must always have a target —
     static shapes), and unassigned page-table entries point at it, so
@@ -122,11 +132,44 @@ def pool_shape(cfg, n_pages: int, page_size: int) -> tuple[int, ...]:
     return (n_pages, page_size) + row
 
 
+def ring_pages(cfg, page_size: int, prefill_chunk: int) -> int:
+    """Entries of a slot's WINDOW-class table row, the ring: ``(sliding_window
+    + prefill_chunk) / page_size``.  That many rows is the least ring in
+    which the last write of a prefill chunk cannot land on a row the chunk's
+    first query still sees (``tests/test_swa_moe.py`` shows a ring one page
+    shorter fail); a decode step needs less."""
+    if cfg.sliding_window % page_size or prefill_chunk % page_size:
+        raise ValueError(
+            f"sliding_window={cfg.sliding_window} and prefill_chunk="
+            f"{prefill_chunk} must be whole pages of {page_size}: the "
+            f"window layers' ring is counted in pages")
+    return (cfg.sliding_window + prefill_chunk) // page_size
+
+
+def ring_view(ring, apos, window: int, page: int):
+    """The reading side of a window layer, once a launch: the slots' ring
+    rows ``ring`` (B, R) rotated into ORDERED view tables for the rows at
+    the consecutive absolute positions ``apos`` (B, S).  Entry ``j`` of a
+    slot's view is the page that holds positions ``(p0 + j) * page``
+    onwards, ``p0`` the page of the first position its first row sees, so
+    the paged kernels and the gather path read it as they read a full
+    layer's table.  Returns ``(view (B, R), apos in view coordinates (B,
+    S), lo (B, S))``: ``lo`` is the first view position a row sees (the
+    rows of the view's first page before it are not the row's), and a row
+    at view position ``a`` sees ``lo <= s <= a``."""
+    p0 = jnp.maximum(apos[:, :1] - window + 1, 0) // page       # (B, 1)
+    R = ring.shape[1]
+    view = jnp.take_along_axis(
+        ring, (p0 + jnp.arange(R, dtype=jnp.int32)) % R, axis=1)
+    base = p0 * page
+    return view, apos - base, jnp.maximum(apos - window + 1, 0) - base
+
+
 def paged_layers(cfg) -> int:
     """Layers whose tokens cache a row in pages: what every sizing of the
-    pool multiplies :func:`token_row_bytes` by.  All of them, but for the
-    gated delta-rule hybrid's linear layers, which hold a state slot
-    instead."""
+    pool multiplies :func:`token_row_bytes` by.  All of them (of either
+    page class), but for the gated delta-rule hybrid's linear layers,
+    which hold a state slot instead."""
     if cfg.gdn_hybrid:
         from ..models.gdn_hybrid import full_layers
         return len(full_layers(cfg))
@@ -157,13 +200,17 @@ class PoolBuffers(NamedTuple):
     of L arrays, one per PAGED layer, mirroring ``KVCache``'s
     per-layer-buffer decision — a stacked (L, ...) layout would pay a
     dynamic-slice copy per layer per step), each ``(n_pages, page_size) +
-    row shape`` (:func:`row_layout`).  ``v`` is None for the latent block,
+    row shape`` (:func:`row_layout`); with two page classes a WINDOW
+    layer's arrays have the window class's ``n_pages_window`` pages and a
+    full layer's the full class's ``n_pages``, in layer order.  ``v`` is
+    None for the latent block,
     whose one row a token lives in ``k``.  ``k_scale``/``v_scale`` are the
     f32 row scales of the int8 pool, None for the ``cfg.dtype`` pool.
     ``state``/``conv`` are the state slots of the gated delta-rule
     hybrid's linear layers, one array a linear layer, None elsewhere."""
     k: tuple            # L × (n_pages, page_size, n_kv, hd) | (.., .., W)
     #                     | (n_pages, page_size * n_kv, hd): slab_pool
+    #                     | a window layer: (n_pages_window, page_size, ..)
     v: tuple | None
     k_scale: tuple | None   # L × (n_pages, page_size, n_kv, 1) f32
     v_scale: tuple | None
@@ -430,9 +477,16 @@ class PagedKVPool:
 
     def __init__(self, cfg, n_pages: int, page_size: int, *,
                  kv_quant: bool = False, mesh=None, tp_axis: str = "tp",
-                 device=None, n_slots: int = 0):
+                 device=None, n_slots: int = 0, n_pages_window: int = 0):
         if mesh is not None and device is not None:
             raise ValueError("pass mesh or device, not both")
+        if cfg.swa_moe and (kv_quant or mesh is not None
+                            or n_pages_window < 2):
+            raise ValueError(
+                "the window + full attention block's pool has a second "
+                "page class for its window layers: pass n_pages_window "
+                ">= 2 (max_batch rings of kv_pool.ring_pages + the null "
+                "page), and neither kv_quant nor a mesh")
         if cfg.gdn_hybrid and (kv_quant or mesh is not None
                                or n_slots < 1):
             raise ValueError(
@@ -455,8 +509,17 @@ class PagedKVPool:
         shape = pool_shape(cfg, self.n_pages, self.page_size)
         dt = jnp.int8 if kv_quant else cfg.dtype
         put = self._put
-        k = tuple(put(jnp.zeros(shape, dt)) for _ in range(L))
-        v = tuple(put(jnp.zeros(shape, dt)) for _ in range(L)) \
+        # the window layers' page class: pools of their own size, and the
+        # free list the scheduler grants their rings from
+        self.n_pages_window = int(n_pages_window) if cfg.swa_moe else 0
+        shapes = [shape] * L
+        if self.n_pages_window:
+            from ..models.swa_moe import window_layers
+            for li in window_layers(cfg):
+                shapes[li] = pool_shape(cfg, self.n_pages_window,
+                                        self.page_size)
+        k = tuple(put(jnp.zeros(sh, dt)) for sh in shapes)
+        v = tuple(put(jnp.zeros(sh, dt)) for sh in shapes) \
             if has_v else None
         # scales init to ones like init_cache's — unwritten rows then
         # dequantize to exact zeros, matching the one-shot cache
@@ -478,6 +541,8 @@ class PagedKVPool:
         self.bufs = PoolBuffers(k=k, v=v, k_scale=ks, v_scale=vs,
                                 state=state, conv=conv)
         self.allocator = PageAllocator(self.n_pages)
+        self.window_allocator = PageAllocator(self.n_pages_window) \
+            if self.n_pages_window else None
 
     def _row_spec(self):
         """One pool array's PartitionSpec: the KV-head axis over tp under

@@ -26,6 +26,13 @@ from zeros whatever the slot's last request left
 ``state_resets``.  The state needs no page, so ``pages_needed`` is the
 full-attention layers' alone.
 
+Where the pool has a second page class (``kv_pool``: the window layers'
+rings), a request is granted from BOTH allocators or not at all: its whole
+length in full-class pages as above, and ``min(total, ring rows)`` in
+window-class pages (``Request.pages_window``; a request shorter than the
+ring never wraps it).  FCFS and head-of-line blocking are unchanged: the
+head request waits while EITHER class is short, and retirement frees both.
+
 Timestamps are elapsed seconds on the engine's clock: ``t_submit`` is
 the request's (virtual) arrival, ``t_first`` when its first token
 resolved on the host (prefill is synchronous at admission, so TTFT is
@@ -75,6 +82,9 @@ class Request:
     #: ``pages`` (same order) — those pages are TRIE-owned, only
     #: ``pages[len(cache_nodes):]`` go back to the allocator at retire
     cache_nodes: list = field(default_factory=list)
+    #: the request's ring in the window page class, where the pool has
+    #: one (all request-owned: nothing of a ring is shared)
+    pages_window: list[int] | None = None
     prefill_pos: int = 0
     tokens: list[int] = field(default_factory=list)
     t_submit: float | None = None
@@ -107,10 +117,18 @@ class ContinuousBatcher:
     about devices."""
 
     def __init__(self, max_batch: int, allocator: PageAllocator,
-                 page_size: int):
+                 page_size: int, *,
+                 window_allocator: PageAllocator | None = None,
+                 ring_pages: int = 0):
         self.max_batch = int(max_batch)
         self.allocator = allocator
         self.page_size = int(page_size)
+        # the window page class (None: the pool has one class) and the
+        # most pages of it a request holds, its ring
+        self.window_allocator = window_allocator
+        self.ring_pages = int(ring_pages)
+        if window_allocator is not None and self.ring_pages < 1:
+            raise ValueError("a window page class needs ring_pages >= 1")
         self.waiting: deque[Request] = deque()
         self.slots: list[Request | None] = [None] * self.max_batch
         self.admitted_total = 0
@@ -136,9 +154,13 @@ class ContinuousBatcher:
                  if r is not None and r.state == PREFILL]
         return min(cands, key=lambda r: r.t_admit) if cands else None
 
-    def pages_needed(self, req: Request) -> int:
+    def pages_needed(self, req: Request, window: bool = False) -> int:
+        """Pages of one class ``req`` is granted at admission: its whole
+        length in the full class; in the ``window`` class no more than a
+        ring."""
         total = req.n_prompt + req.max_new_tokens
-        return -(-total // self.page_size)
+        need = -(-total // self.page_size)
+        return min(need, self.ring_pages) if window else need
 
     # ---- transitions --------------------------------------------------
     def submit(self, req: Request, now: float) -> None:
@@ -183,6 +205,13 @@ class ContinuousBatcher:
                 pages = self.allocator.alloc(need)
             if pages is None:
                 break
+            if self.window_allocator is not None:
+                ring = self.window_allocator.alloc(
+                    self.pages_needed(req, window=True))
+                if ring is None:        # both grants or neither
+                    self.allocator.free(pages)
+                    break
+                req.pages_window = ring
             self.waiting.popleft()
             req.pages = [n.page for n in nodes] + pages
             req.cache_nodes = list(nodes)
@@ -235,7 +264,9 @@ class ContinuousBatcher:
         owned = req.pages[len(req.cache_nodes):]
         if owned:
             self.allocator.free(owned)
-        req.pages = None
+        if req.pages_window:
+            self.window_allocator.free(req.pages_window)
+        req.pages = req.pages_window = None
         req.cache_nodes = []
 
     def release_all(self) -> list[Request]:
@@ -270,7 +301,7 @@ def reset_for_replay(req: Request) -> None:
     t_submit, trace_id) is preserved; runtime state is cleared."""
     req.state = WAITING
     req.slot = None
-    req.pages = None
+    req.pages = req.pages_window = None
     req.cache_nodes = []
     req.prefill_pos = 0
     req.tokens = []

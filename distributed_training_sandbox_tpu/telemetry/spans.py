@@ -112,8 +112,8 @@ class SpanStream:
         shows in the timeline)."""
         t0 = time.perf_counter()
         try:
-            with _annotation(name, attrs):
-                yield
+            with _annotation(name, attrs) as ann:
+                yield _OpenSpan(ann, attrs)
         finally:
             self.record(name, start_perf=t0,
                         end_perf=time.perf_counter(), cat=cat, **attrs)
@@ -175,6 +175,21 @@ class SpanStream:
                 self._f = None
 
 
+class _OpenSpan:
+    """What ``with stream.span(...) as sp`` binds: ``sp.set_metadata``
+    adds attributes learned inside the span to both sinks, as the bare
+    profiler annotation's own ``set_metadata`` does where no stream is
+    wired (``maybe_span``)."""
+
+    def __init__(self, ann, attrs: dict):
+        self._ann, self._attrs = ann, attrs
+
+    def set_metadata(self, **attrs) -> None:
+        attrs = {k: v for k, v in attrs.items() if v is not None}
+        self._attrs.update(attrs)
+        self._ann.set_metadata(**attrs)
+
+
 def _annotation(name: str, attrs: dict):
     """The profiler sink: attributes become the event's stats (``None``
     values are left out)."""
@@ -186,7 +201,8 @@ def maybe_span(stream, name: str, cat: str | None = None, **attrs):
     """The span primitive every call site uses: a profiler annotation
     always, and ``stream.span(...)`` on top when a stream is wired
     (``stream`` None: no file is touched) — so spans never impose a
-    telemetry dependency."""
+    telemetry dependency.  Either way ``with ... as sp`` binds an object
+    whose ``set_metadata(**attrs)`` adds what the body learned."""
     if stream is None:
         return _annotation(name, attrs)
     # forwarder: the caller's literal passes through (lint checks THEM)

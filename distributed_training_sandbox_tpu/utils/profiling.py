@@ -190,6 +190,22 @@ ATTENTION_SUBSCOPES = (
 )
 
 
+#: A second name beneath ``attn_core``, round the paged attention of the
+#: WINDOW layers of the block that mixes them with full-attention layers
+#: (``models/swa_moe.py``; ``serving/engine._paged_swa_forward`` asks
+#: :func:`_paged_attend` for it, in a decode step and in a prefill chunk,
+#: after ``kv_write``), whose full layers open ``attn_paged`` above: the
+#: two kinds of layer read different rows of a request and are read apart.
+#: A tuple of its own, since tests pin the older ones;
+#: ``benchmarks/layer_metrics/_winscopes.py`` holds a copy, pinned by a
+#: test.
+WINDOW_SUBSCOPES = (
+    "attn_window",  # the paged decode / flash prefill kernel's call over a
+                    # ring's ordered view with a lower bound (or, off the
+                    # chip, the gather path's scores, softmax and PV)
+)
+
+
 def scope(name: str):
     """Device-side marker for code *inside* jit: prefixes XLA op names so
     collectives/matmuls attribute to the phase in the trace.  Writes

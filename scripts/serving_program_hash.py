@@ -79,18 +79,26 @@ def programs(root: Path):
         params = jax.eval_shape(
             lambda: T.init_params(jax.random.key(0), mcfg))
         slots = {"n_slots": B} if mcfg.gdn_hybrid else {}
+        tables = lambda b: i32(b, P)  # noqa: E731
+        if getattr(mcfg, "swa_moe", False):
+            # a second page class: a ring a slot, a second table
+            from distributed_training_sandbox_tpu.serving.kv_pool import \
+                ring_pages
+            R = ring_pages(mcfg, page, eng["prefill_chunk"])
+            slots = {"n_pages_window": B * R + 1}
+            tables = lambda b: (i32(b, P), i32(b, R))  # noqa: E731
         bufs = jax.eval_shape(
             lambda: PagedKVPool(mcfg, B * P + 1, page, **slots).bufs)
         # the burst's carry, as the engine sizes it (a configuration may
         # set ``sync_every``)
         sync = cell.config["serve"]["engine"].get("sync_every", sync_default)
         decode = E.make_serve_decode_step(mcfg, paged_kernel=True).trace(
-            bufs, params, i32(B, P), i32(B), i32(B), i32(B),
+            bufs, params, tables(B), i32(B), i32(B), i32(B),
             sd((B,), jnp.bool_), i32(len(E.device_counters(mcfg)) + sync * B))
         prefill = E.make_serve_prefill_step(
             mcfg, paged_kernel=not mcfg.mla_moe).trace(
-            bufs, params, i32(1, P), i32(1, eng["prefill_chunk"]), i32(),
-            i32(), *((i32(),) if slots else ()))
+            bufs, params, tables(1), i32(1, eng["prefill_chunk"]), i32(),
+            i32(), *((i32(),) if mcfg.gdn_hybrid else ()))
         for name, traced in (("decode", decode), ("prefill", prefill)):
             yield cell.name, name, traced.lower(
                 lowering_platforms=("tpu",)).as_text()

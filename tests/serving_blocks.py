@@ -1,7 +1,7 @@
-"""The four blocks the serving engine builds, at a tiny size, float32,
+"""The five blocks the serving engine builds, at a tiny size, float32,
 seeded weights: the configurations that the blocks' own test files
 (``tests/test_mla_moe.py``, ``tests/test_gdn_hybrid.py``,
-``tests/test_gdn_moe.py``) and the tests that run over ALL blocks share,
+``tests/test_gdn_moe.py``, ``tests/test_swa_moe.py``) and the tests that run over ALL blocks share,
 keyed as the benchmark keys its plain float32 references
 (``benchmarks/reference/<architecture>.py``)."""
 
@@ -52,6 +52,15 @@ FIELDS = {
         num_experts_per_tok=3, moe_intermediate_size=32,
         shared_expert_intermediate_size=32, norm_topk_prob=True,
         partial_rotary_factor=0.25),
+    "swa_moe": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=96,
+        num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=16, rope_theta=1e4, rms_norm_eps=1e-5,
+        tie_word_embeddings=False, nope_interval=0, sliding_window=8,
+        global_attn_every_n_layers=2, num_dense_layers=1, num_experts=4,
+        router_width=16, expert_offset=4, num_experts_per_tok=3,
+        moe_intermediate_size=32, num_shared_experts=1, norm_topk_prob=True,
+        routed_scaling_factor=2.448, sandwich_norm=True, mup_enabled=True),
 }
 BLOCKS = tuple(FIELDS)
 

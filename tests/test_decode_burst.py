@@ -1,7 +1,7 @@
 """A plain decode burst brings its results back in one array (the carry
 ``_decode_core`` chains through the burst's steps), and the host replays
 the burst from its rows: the tokens a request receives do not depend on
-``sync_every``, for any of the four blocks, and are the plain float32
+``sync_every``, for any of the five blocks, and are the plain float32
 reference's greedy tokens."""
 
 import numpy as np
