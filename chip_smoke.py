@@ -325,7 +325,7 @@ def kernel_cases(cfg, seq: int):
     nq, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.resolved_head_dim)
     bf = jnp.bfloat16
-    keys = iter(jax.random.split(jax.random.PRNGKey(7), 24))
+    keys = iter(jax.random.split(jax.random.PRNGKey(7), 32))
     rnd = lambda *shape: jax.random.normal(next(keys), shape, bf)
     interpret = jax.default_backend() != "tpu"
 
@@ -389,7 +389,37 @@ def kernel_cases(cfg, seq: int):
                 [jnp.where(live[..., None], o, 0.0).ravel(), s.ravel()])
         return run
 
+    # the held experts' routed sum at ``cfg``'s hidden width: 48 rows, 4
+    # held experts of width 1,024 (the tiny model's: 128) of a router of
+    # 16, 2 chosen a row, against every row through every held expert
+    # (bf16 on the chip; the CPU has no bf16 x bf16 -> f32 dot)
+    from distributed_training_sandbox_tpu.ops.grouped_experts import (
+        routed_sum)
+    dt, few, wide = (jnp.float32 if interpret else bf), 4, min(1024, f)
+    ew = lambda *shape: jax.random.normal(next(keys), shape, dt) \
+        * shape[1] ** -0.5
+    top, chosen = jax.lax.top_k(
+        jax.random.uniform(next(keys), (48, 16)), 2)
+    expert_args = (
+        jax.random.normal(next(keys), (48, h), dt),
+        jnp.sum(jnp.where(chosen[:, :, None] == jnp.arange(few),
+                          top[:, :, None], 0.0), axis=1),
+        ew(few, h, wide), ew(few, h, wide), ew(few, wide, h))
+
+    def every_row_by_every_expert(x, w_held, wg, wu, wd):
+        g = jnp.einsum("th,ehf->etf", x, wg)
+        u = jnp.einsum("th,ehf->etf", x, wu)
+        y = jnp.einsum("etf,efh->eth", jax.nn.silu(g) * u, wd,
+                       preferred_element_type=jnp.float32)
+        return jnp.einsum("eth,te->th", y, w_held)
+
+    experts = lambda fn: lambda: jax.jit(fn)(*expert_args)
+
     return {
+        "grouped experts": (
+            experts(lambda *a: routed_sum(*a, per_row=2,
+                                          interpret=interpret)),
+            experts(every_row_by_every_expert)),
         "splash attention": (attention(T._attention_flash),
                              attention(T._attention_xla)),
         "int8 matmul": (int8(lambda *a: Q.int8_matmul_pallas(
@@ -421,7 +451,8 @@ def kernels_phase(cfg, seq: int) -> None:
     # default on a TPU at the smoke's pool geometry and chunk
     # (ServingEngine.paged_kernel=None), and the hybrid block's engine the
     # step kernel at its widths
-    default_path = {"paged decode", "flash prefill", "gdn decode step"}
+    default_path = {"paged decode", "flash prefill", "gdn decode step",
+                    "grouped experts"}
     if cfg.attention_impl == "flash":
         default_path.add("splash attention")
     # every output is bf16 (or f32 from bf16 probabilities): agreement to

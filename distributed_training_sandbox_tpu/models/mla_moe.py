@@ -47,10 +47,13 @@ whose dense layers and sandwich residual are :func:`mlp` as it stands.
 ``w_e`` is normalised over all of ``T`` whether or not its experts are held
 here; what absent experts would add is left out (one rank's part under
 expert parallelism; on one chip the layer runs without its exchange).  No
-token is dropped: every row goes through every held expert and the routing
-weight, zero where the expert was not chosen, masks it.  At the 64 to 256
-rows a step of the engine has, 8 held experts of 94 MB each make that
-product read-bound either way.  ``x <- x + norm(m; post_mlp_norm)``.
+token is dropped and no expert has a capacity: the routed sum is a grouped
+product over the (row, held expert) pairs the routing produced, sorted by
+expert (``ops/grouped_experts.py``), so a row is multiplied by the held
+experts it chose and an expert no row chose is not read; its list of
+visits has room for every pair a routing can produce.  ``g``, ``u`` and ``silu(g) . u``
+are in the rows' dtype, the down product, its weighting and the sum over
+a row's experts in float32.  ``x <- x + norm(m; post_mlp_norm)``.
 
 Parameter tree: ``embed`` (V, H), ``lm_head`` (H, V), ``final_norm`` (H,)
 and ``layers``, a tuple of one dict a layer, ``first_k_dense_replace``
@@ -77,6 +80,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.grouped_experts import routed_sum
 from ..utils.profiling import scope
 
 #: what the MLP of an expert layer counts on the device for the engine's
@@ -401,11 +405,10 @@ def expert_mlp(r2, layer, *, cfg, valid=None):
             else valid.reshape(-1)
         counts = moe_counts(w_held, idx, ok, cfg)
     with scope("moe_experts"):
-        g = jnp.einsum("th,ehf->etf", rows, layer["we_gate"])
-        u = jnp.einsum("th,ehf->etf", rows, layer["we_up"])
-        y = jnp.einsum("etf,efh->eth", jax.nn.silu(g) * u, layer["we_down"],
-                       preferred_element_type=jnp.float32)
-        routed = jnp.einsum("eth,te->th", y, w_held)
+        routed = routed_sum(
+            rows, w_held, layer["we_gate"], layer["we_up"],
+            layer["we_down"], valid=ok,
+            per_row=min(cfg.num_experts_per_tok, cfg.held_experts))
     with scope("moe_shared"):
         shared = _swiglu(r2, layer["ws_gate"], layer["ws_up"],
                          layer["ws_down"], _dense(cfg))

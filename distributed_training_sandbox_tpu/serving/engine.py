@@ -331,7 +331,11 @@ def _paged_latent_layer_body(x, layer, *, cfg, cos, sin, pool, pages,
       * ``attn_out``: at decode ``o~ . w_uv``, then ``wo`` and the
         post-attention norm; ``mlp``: both MLP norms and the MLP, its
         routing, held experts and shared expert under ``moe_route``,
-        ``moe_experts``, ``moe_shared`` (``profiling.SUBSCOPES``).
+        ``moe_experts``, ``moe_shared`` (``profiling.SUBSCOPES``);
+        ``moe_experts`` is the plan that sorts the routing's (row, held
+        expert) pairs by expert and the grouped product over them, one
+        Mosaic call that visits touched experts only and adds each
+        pair onto its row (``ops/grouped_experts.py``).
 
     x (B, S, H); pool (n_pages, page, W); pages (B, P); apos, valid
     (B, S).  Returns ``(x', pool', counts)``; ``counts`` is

@@ -159,7 +159,8 @@ SCOPES = (
 #: ``moe_*`` scopes are its own and are read by nothing.
 SUBSCOPES = (
     "moe_route",    # router matmul, sigmoid, top-k, weights, the counters
-    "moe_experts",  # held experts: their product over every row, combine
+    "moe_experts",  # held experts: the plan of visits over the (row,
+                    # expert) pairs and the grouped product's one call
     "moe_shared",   # the shared expert
 )
 
