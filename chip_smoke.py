@@ -389,6 +389,27 @@ def kernel_cases(cfg, seq: int):
                 [jnp.where(live[..., None], o, 0.0).ravel(), s.ravel()])
         return run
 
+    # the Mamba-2 recurrence's decode step at granite-4.0-h-small's widths
+    # (128 heads x 64, state 128: four lane groups a slot), the same eight
+    # slots; without the Dskip term, which is the caller's
+    from distributed_training_sandbox_tpu.models import ssm_moe as SM
+    from distributed_training_sandbox_tpu.ops.ssm_step import ssm_decode_step
+    sn, shd, sds = 128, 64, 128
+    ssm_args = (jnp.where(live[..., None], 0.1 * f32(8, sn, shd), 0.0),
+                f32(8, sds), f32(8, sds),
+                jnp.where(live, -jnp.abs(f32(8, sn)) - 0.01, 0.0),
+                f32(8, sds, sn * shd))
+
+    def ssm_step(fn):
+        def run():
+            o, s = jax.jit(fn)(*ssm_args)
+            return jnp.concatenate(
+                [jnp.where(live[..., None], o, 0.0).ravel(), s.ravel()])
+        return run
+
+    ssm_xla = lambda xd, b, c, g, s: SM.recurrent_step(  # noqa: E731
+        xd, b, c, g, jnp.zeros_like(xd), s)
+
     # the held experts' routed sum at ``cfg``'s hidden width: 48 rows, 4
     # held experts of width 1,024 (the tiny model's: 128) of a router of
     # 16, 2 chosen a row, against every row through every held expert
@@ -434,6 +455,7 @@ def kernel_cases(cfg, seq: int):
         "flash prefill": (paged(paged_flash_prefill, q_pre),
                           paged(paged_attention_xla, q_pre)),
         "gdn decode step": (step(gdn_decode_step), step(G.recurrent_step)),
+        "ssm decode step": (ssm_step(ssm_decode_step), ssm_step(ssm_xla)),
     }
 
 
@@ -452,7 +474,7 @@ def kernels_phase(cfg, seq: int) -> None:
     # (ServingEngine.paged_kernel=None), and the hybrid block's engine the
     # step kernel at its widths
     default_path = {"paged decode", "flash prefill", "gdn decode step",
-                    "grouped experts"}
+                    "ssm decode step", "grouped experts"}
     if cfg.attention_impl == "flash":
         default_path.add("splash attention")
     # every output is bf16 (or f32 from bf16 probabilities): agreement to

@@ -524,16 +524,42 @@ def linear_output(o, x, layer, *, cfg):
 # ------------------------------------------------- what a block brings
 #
 # The engine's ``_paged_hybrid_forward`` and :func:`hidden_states` run the
-# layer loop once for every block built on this linear mixer; what differs
-# between them (the residual path, the full-attention mixer, the MLP) they
-# call through ``cfg.block_module``, which is this module or
-# ``models/gdn_moe.py``: ``rope_tables``, ``mixer_input``,
-# ``attention_qkv``, ``attention_output``, ``linear_mixer_output``, ``mlp``,
-# ``final_norm``, and ``PAGED_ATTENTION_SCOPE``: the scope beneath
-# ``attn_core`` that the engine opens round the full-attention layers'
-# paged attention (``profiling.ATTENTION_SUBSCOPES``), or None for none.
+# layer loop once for every block whose requests keep STATE SLOTS beside
+# pages.  They ask two modules for what differs.
+#
+# What a LINEAR MIXER brings (``cfg.linear_mixer``: this module for the
+# two gated delta-rule blocks, ``models/ssm_moe.py`` for the Mamba-2 one):
+# the layer's kind (``is_full_layer``, ``full_layers``, ``linear_layers``),
+# the state's and the tail's shapes (``state_shape``, ``slot_shape`` as
+# stored, ``tail_shape``, ``slot_state_bytes``, ``pack_state`` /
+# ``unpack_state`` between the stored layout and the scan's),
+# ``linear_inputs(r, layer, tail, valid, cfg=)`` -> the recurrence's
+# operands ``(B, S, ...)`` and the new tail, the step and the scan
+# (``recurrent_step(*operands of one row, state as stored)``,
+# ``chunked_scan(*operands, state as unpacked)``, ``step_kernel``,
+# ``step_kernel_engages(*state_shape)``) and ``COUNTERS``.
+#
+# What a BLOCK brings (``cfg.block_module``: this module,
+# ``models/gdn_moe.py`` or ``models/ssm_moe.py``): the residual path, the
+# full-attention mixer and the MLP: ``embed``, ``rope_tables``,
+# ``mixer_input``, ``attention_qkv``, ``attention_scale``,
+# ``attention_output``, ``linear_mixer_output``, ``mlp``, ``final_norm``,
+# and ``PAGED_ATTENTION_SCOPE``: the scope beneath ``attn_core`` that the
+# engine opens round the full-attention layers' paged attention
+# (``profiling.ATTENTION_SUBSCOPES``), or None for none.
 
 PAGED_ATTENTION_SCOPE = None
+
+
+def embed(params, ids, cfg):
+    """``embed[ids]``, as it is."""
+    return params["embed"].astype(cfg.dtype)[ids]
+
+
+def attention_scale(cfg) -> None:
+    """What the attention scores are multiplied by: None, the paged
+    kernels' and the gather path's own ``1/sqrt(head_dim)``."""
+    return None
 
 
 def rope_tables(positions, cfg):
@@ -605,36 +631,37 @@ def final_norm(x, params, cfg):
 def hidden_states(params, input_ids, cfg):
     """(B, S) ids -> final-norm hidden states (B, S, H): the whole
     sequence at once, the chunked scan from a zero state and an empty
-    tail, materialised causal attention, no cache.  For every block built
-    on this linear mixer (``cfg.block_module``)."""
+    tail, materialised causal attention, no cache.  For every block whose
+    requests keep state slots (``cfg.block_module``, ``cfg.linear_mixer``)."""
     from .transformer import _attention_xla
-    blk = cfg.block_module
+    blk, lin = cfg.block_module, cfg.linear_mixer
     B, S = input_ids.shape
     with scope("embed"):
-        x = params["embed"].astype(cfg.dtype)[input_ids]
+        x = blk.embed(params, input_ids, cfg)
         rope = blk.rope_tables(jnp.broadcast_to(jnp.arange(S), (B, S)), cfg)
     valid = jnp.ones((B, S), jnp.bool_)
+    scale = blk.attention_scale(cfg) \
+        or 1.0 / math.sqrt(cfg.resolved_head_dim)
     for li, layer in enumerate(params["layers"]):
-        if is_full_layer(li, cfg):
+        if lin.is_full_layer(li, cfg):
             with scope("attn_qkv"):
                 q, k, v, gate = blk.attention_qkv(
                     blk.mixer_input(x, layer, cfg=cfg), layer, cfg=cfg,
                     rope=rope)
             with scope("attn_core"):
-                a = _attention_xla(q, k, v,
-                                   1.0 / math.sqrt(cfg.resolved_head_dim))
+                a = _attention_xla(q, k, v, scale)
             with scope("attn_out"):
                 h = blk.attention_output(a, gate, x, layer, cfg=cfg)
         else:
             with scope("attn_qkv"):
                 r = blk.mixer_input(x, layer, cfg=cfg)
-                q, k, v, g, beta, _ = linear_inputs(
-                    r, layer, jnp.zeros((B,) + tail_shape(cfg), cfg.dtype),
+                *ins, _ = lin.linear_inputs(
+                    r, layer, jnp.zeros((B,) + lin.tail_shape(cfg), cfg.dtype),
                     valid, cfg=cfg)
             with scope("attn_core"), scope("lin_scan"):
-                o, _ = chunked_scan(
-                    q, k, v, g, beta,
-                    jnp.zeros((B,) + state_shape(cfg), jnp.float32))
+                o, _ = lin.chunked_scan(*ins, lin.unpack_state(
+                    jnp.zeros((B,) + lin.slot_shape(cfg), jnp.float32),
+                    lin.state_shape(cfg)[0]))
             with scope("attn_out"):
                 h = blk.linear_mixer_output(o, r, x, layer, cfg=cfg)
         with scope("mlp"):

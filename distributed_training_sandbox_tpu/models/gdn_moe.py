@@ -77,7 +77,8 @@ import jax.numpy as jnp
 
 from . import gdn_hybrid as G
 from . import mla_moe as M
-from .gdn_hybrid import hidden_states  # noqa: F401  (the shared layer loop)
+from .gdn_hybrid import (attention_scale, embed,  # noqa: F401
+                         hidden_states)     # (the shared layer loop)
 
 #: what the engine counts for this block in ``stats``: the expert layers'
 #: four and the live states, summed on the device through a burst (the

@@ -221,8 +221,44 @@ class TransformerConfig:
     num_dense_layers: int = 0
     num_shared_experts: int = 0
     mup_enabled: bool = False
+    # Mamba-2 state-space layers beside GQA attention layers with no
+    # position embedding, over held and shared experts
+    # (``models/ssm_moe.py``; names as in the published configs of that
+    # family).  ``mamba_d_state`` > 0 selects it: layer i is an attention
+    # layer where ``layer_types[i] == "attention"`` and a Mamba-2 layer
+    # otherwise (the list may be the published one, longer than
+    # ``num_hidden_layers``: its first entries are run;
+    # ``mamba_n_heads`` heads of ``mamba_d_head`` = ``mamba_expand``
+    # x hidden, one B/C group of ``mamba_d_state``, a conv of
+    # ``mamba_d_conv`` with bias, the chunked form at blocks of
+    # ``mamba_chunk_size``), whose per-request state is one float32 (heads,
+    # head dim, state dim) tensor and a conv tail.  Every layer's MLP is a
+    # softmax router of ``router_width`` with ``num_local_experts`` routed
+    # experts of ``intermediate_size`` HELD here (ids ``expert_offset``
+    # onwards) and a shared expert of ``shared_intermediate_size``.  The
+    # family's four multipliers: the embedding times
+    # ``embedding_multiplier``, every mixer's and MLP's output times
+    # ``residual_multiplier``, attention scores times
+    # ``attention_multiplier`` (0: 1/sqrt(head_dim)), logits divided by
+    # ``logits_scaling``.  Serving only and the cache-less ``forward``.
+    layer_types: tuple = ()
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 0
+    mamba_n_groups: int = 1
+    mamba_expand: int = 2
+    mamba_chunk_size: int = 256
+    num_local_experts: int = 0
+    shared_intermediate_size: int = 0
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
 
     def __post_init__(self):
+        # a configuration file brings a list; the config must hash
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
         if self.block_module is not None:
             self.block_module.check_config(self)
         # Covers every construction path incl. dataclasses.replace: a
@@ -290,10 +326,39 @@ class TransformerConfig:
         return self.sliding_window > 0
 
     @property
+    def ssm_moe(self) -> bool:
+        """Mamba-2 state-space layers beside attention layers, over held
+        and shared experts (``models/ssm_moe.py``), whose requests hold
+        state slots beside pages as the gated delta-rule hybrids' do."""
+        return self.mamba_d_state > 0
+
+    @property
+    def linear_mixer(self):
+        """The module that holds the linear mixer of a block whose
+        requests keep STATE SLOTS beside pages (``gdn_hybrid``'s comment
+        "what a linear mixer brings": the layer's kind, the state's and the
+        tail's shapes, the step and the scan); None for a block whose every
+        layer is paged."""
+        if self.gdn_hybrid:
+            from . import gdn_hybrid
+            return gdn_hybrid
+        if self.ssm_moe:
+            from . import ssm_moe
+            return ssm_moe
+        return None
+
+    @property
+    def state_slots(self) -> bool:
+        """Whether a request of this block holds a state slot
+        (:attr:`linear_mixer`)."""
+        return self.gdn_hybrid or self.ssm_moe
+
+    @property
     def held_experts(self) -> int:
         """Routed experts of an expert layer that this program holds, under
         whichever name the block's published config counts them."""
-        return self.n_routed_experts or self.num_experts
+        return self.n_routed_experts or self.num_experts \
+            or self.num_local_experts
 
     @property
     def block_module(self):
@@ -312,6 +377,9 @@ class TransformerConfig:
         if self.swa_moe:
             from . import swa_moe
             return swa_moe
+        if self.ssm_moe:
+            from . import ssm_moe
+            return ssm_moe
         return None
 
     def param_count(self) -> int:
@@ -795,7 +863,11 @@ def forward(params: dict, input_ids: jax.Array, cfg: TransformerConfig,
     x = hidden_states(params, input_ids, cfg, layer_hook=layer_hook,
                       layer_body=layer_body)
     with scope("loss_head"):
-        return x @ _output_embedding(params, cfg).T
+        logits = x @ _output_embedding(params, cfg).T
+        if cfg.logits_scaling != 1.0:
+            logits = (logits.astype(jnp.float32)
+                      / cfg.logits_scaling).astype(logits.dtype)
+        return logits
 
 
 def hidden_states(params: dict, input_ids: jax.Array,

@@ -125,7 +125,8 @@ def _head_rows(src, head):
 
 def _prefill_kernel(start_ref, end_ref, pages_ref, *refs,
                     table_pages: int, block_pages: int, page: int,
-                    nkv: int, rep: int, probs_dtype, bounded: bool = False):
+                    nkv: int, rep: int, probs_dtype, bounded: bool = False,
+                    scale: float | None = None):
     """Float pool, one batch row's chunk.  ``start_ref``/``end_ref``
     (B,) and ``pages_ref`` (B * P,) are in SMEM: the chunk's first
     absolute position, one past the last position any of its rows may
@@ -206,7 +207,8 @@ def _prefill_kernel(start_ref, end_ref, pages_ref, *refs,
             vg = _head_rows(v32, head).T.astype(v_buf.dtype)  # (hd, span)
             s = jax.lax.dot_general(
                 kg, q_ref[0, g], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) / math.sqrt(hd)
+                preferred_element_type=jnp.float32)
+            s = s / math.sqrt(hd) if scale is None else s * scale
             s = jnp.where(vis, s, -1e30)
             # block 0 holds position 0, which every row sees, so m is a
             # real score from the first block on and the -1e30 of a
@@ -232,6 +234,7 @@ def _prefill_kernel(start_ref, end_ref, pages_ref, *refs,
 
 
 def paged_flash_prefill(qg, pk, pv, pages, apos, *, valid=None, lo=None,
+                        scale: float | None = None,
                         probs_dtype=None, interpret: bool | None = None):
     """Chunked-prefill paged flash attention, pages read in place.
 
@@ -242,7 +245,9 @@ def paged_flash_prefill(qg, pk, pv, pages, apos, *, valid=None, lo=None,
     for the rows that belong to the prompt, a prefix of each batch row
     (default all); lo (B, S) int32, the first position each row sees,
     CONSECUTIVE too (a window layer's band; default: 0 for every row, and
-    the program is the one without the operand).  A batch row reads its pages up to its last valid
+    the program is the one without the operand); ``scale``: what the
+    scores are multiplied by (default: divided by ``sqrt(hd)``).  A batch
+    row reads its pages up to its last valid
     row's position and no further; one with no valid row reads nothing
     and gets zeros; a padding row's output is finite and means nothing.
     Returns f32 (B, S, n_kv, rep, hd), the value of the reference
@@ -272,15 +277,17 @@ def paged_flash_prefill(qg, pk, pv, pages, apos, *, valid=None, lo=None,
         qg, pk, pv, pages, start, start + n_valid, *bound,
         block_pages=min(PAGES_PER_BLOCK, pages.shape[1]),
         probs_dtype=jnp.dtype(probs_dtype or qg.dtype),
-        interpret=bool(interpret))
+        interpret=bool(interpret),
+        **({} if scale is None else {"scale": float(scale)}))
 
 
 # jitted for the reason ``paged_attention._decode_float`` is: the layers
 # of one prefill program share one trace and one Mosaic lowering
 @functools.partial(jax.jit, static_argnames=("block_pages", "probs_dtype",
-                                             "interpret"))
+                                             "interpret", "scale"))
 def _prefill_float(qg, pk, pv, pages, start, end, lo=None, *,
-                   block_pages: int, probs_dtype, interpret: bool):
+                   block_pages: int, probs_dtype, interpret: bool,
+                   scale: float | None = None):
     """The kernel's call: qg (B, S, n_kv, rep, hd), the pools as the
     engine holds them, pages (B, P), start/end (B,): the chunk's first
     position and one past the last position it may see (0: nothing), and
@@ -293,7 +300,8 @@ def _prefill_float(qg, pk, pv, pages, start, end, lo=None, *,
     bound = () if lo is None else (lo.astype(jnp.int32),)
     kernel = functools.partial(
         _prefill_kernel, table_pages=P, block_pages=block_pages, page=page,
-        nkv=nkv, rep=rep, probs_dtype=probs_dtype, bounded=bool(bound))
+        nkv=nkv, rep=rep, probs_dtype=probs_dtype, bounded=bool(bound),
+        scale=scale)
     heads = pl.BlockSpec((1, nkv, R, hd), lambda b, *_: (b, 0, 0, 0))
     out = pl.pallas_call(
         kernel,

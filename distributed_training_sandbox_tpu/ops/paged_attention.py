@@ -119,7 +119,7 @@ def decode_kernel_takes(dtype, head_dim: int, page_size: int) -> bool:
 
 def _decode_kernel(len_ref, pages_ref, *refs, table_pages: int,
                    block_pages: int, page: int, rep: int, probs_dtype,
-                   bounded: bool = False):
+                   bounded: bool = False, scale: float | None = None):
     """Float pool, one batch slot (S == 1).  ``len_ref`` (B,) and
     ``pages_ref`` (B * P,) are in SMEM; q_ref (1, R, hd) holds the
     slot's R = n_kv * rep query rows; pk_hbm/pv_hbm are the pools as
@@ -181,7 +181,8 @@ def _decode_kernel(len_ref, pages_ref, *refs, table_pages: int,
             c.wait()
         s = jax.lax.dot_general(
             q, k_buf[slot], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) / math.sqrt(hd)
+            preferred_element_type=jnp.float32)
+        s = s / math.sqrt(hd) if scale is None else s * scale
         vis = jnp.logical_and(own_head, blk * span + col_pos < length)
         if bounded:
             vis = jnp.logical_and(vis, blk * span + col_pos >= lo)
@@ -236,6 +237,7 @@ def _decode_kernel_q8(pages_ref, q_ref, qs_ref, apos_ref, pk_ref, pv_ref,
 
 def paged_attention_decode(qg, pk, pv, pages, apos, *, valid=None,
                            q_scale=None, pk_s=None, pv_s=None, lo=None,
+                           scale: float | None = None,
                            probs_dtype=None, interpret: bool | None = None):
     """Decode-step paged attention, pages read in place via the table.
 
@@ -247,7 +249,9 @@ def paged_attention_decode(qg, pk, pv, pages, apos, *, valid=None,
     slot that holds no request (float pools: it reads no page and gets
     zeros; default all True); lo (B, 1) int32, float pools only: the first
     position the row sees (a window layer's lower bound; default: 0,
-    and the program is the one without the operand).  Returns f32 (B, 1, n_kv, rep, hd), the
+    and the program is the one without the operand); ``scale``, float
+    pools only: what the scores are multiplied by (default: divided by
+    ``sqrt(hd)``).  Returns f32 (B, 1, n_kv, rep, hd), the
     value of the reference gather-then-einsum path: exactly for int8
     pools, to float32 summation order for float pools (caller applies
     the same ``astype`` epilogue).  ``interpret`` None: compiled on a
@@ -265,8 +269,9 @@ def paged_attention_decode(qg, pk, pv, pages, apos, *, valid=None,
         refuse_on_tpu("paged_attention_decode (int8 pool)")
         if q_scale is None or pk_s is None or pv_s is None:
             raise ValueError("int8 pool needs q_scale, pk_s and pv_s")
-        if lo is not None:
-            raise ValueError("the int8 decode kernel takes no lower bound")
+        if lo is not None or scale is not None:
+            raise ValueError("the int8 decode kernel takes no lower bound "
+                             "and no scale of its own")
         whole = lambda arr: pl.BlockSpec(
             arr.shape, lambda b: (0,) * arr.ndim)
         wide = lambda last: pl.BlockSpec(
@@ -296,16 +301,17 @@ def paged_attention_decode(qg, pk, pv, pages, apos, *, valid=None,
         qg, pk, pv, pages, lengths, *bound,
         block_pages=min(PAGES_PER_BLOCK, P),
         probs_dtype=jnp.dtype(probs_dtype or qg.dtype),
-        interpret=bool(interpret))
+        interpret=bool(interpret),
+        **({} if scale is None else {"scale": float(scale)}))
 
 
 # jitted so that the layers of one decode program, which call it with the
 # same shapes, share one trace and one Mosaic lowering: unshared, 36
 # layers added ~10 s to every engine's warm-up, compile cache or not
 @functools.partial(jax.jit, static_argnames=("block_pages", "probs_dtype",
-                                             "interpret"))
+                                             "interpret", "scale"))
 def _decode_float(qg, pk, pv, pages, lengths, lo=None, *, block_pages: int,
-                  probs_dtype, interpret: bool):
+                  probs_dtype, interpret: bool, scale: float | None = None):
     """The float kernel's call: qg (B, 1, n_kv, rep, hd), the pools as
     the engine holds them, pages (B, P), lengths (B,) with 0 for a slot
     that reads nothing, and where given lo (B,), the first position each
@@ -323,7 +329,7 @@ def _decode_float(qg, pk, pv, pages, lengths, lo=None, *, block_pages: int,
     bound = () if lo is None else (lo.astype(jnp.int32),)
     kernel = functools.partial(
         _decode_kernel, table_pages=P, block_pages=block_pages, page=page,
-        rep=rep, probs_dtype=probs_dtype, bounded=bool(bound))
+        rep=rep, probs_dtype=probs_dtype, bounded=bool(bound), scale=scale)
     slot = pl.BlockSpec((1, R, hd), lambda b, *_: (b, 0, 0))
     out = pl.pallas_call(
         kernel,

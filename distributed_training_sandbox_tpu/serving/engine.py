@@ -154,7 +154,7 @@ def _paged_layer_body(x, layer, *, cfg, cos, sin, use_rope, pk, pv,
 
 def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
                   valid, paged_kernel=False, kernel_scope=None, slab=False,
-                  view=None):
+                  view=None, scale=None):
     """The new K/V rows into their pages, then causal attention of the
     rows at ``apos`` against their slots' pages:
 
@@ -183,7 +183,9 @@ def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
     view coordinates, lo)``: ``pages`` is then the slot's RING (position
     ``p`` is written at ``pages[(p // page) % R]``), and the attention
     reads the view table, a row at view position ``a`` seeing ``lo <= s <=
-    a``, through the same kernels or the same gather.  Returns the heads'
+    a``, through the same kernels or the same gather.  ``scale``: what a
+    float pool's scores are multiplied by where the block states one
+    (default: divided by ``sqrt(hd)``).  Returns the heads'
     outputs float32
     (B, S, nkv, nq / nkv, hd) and the pools
     ``(pk, pv, pk_s, pv_s)``."""
@@ -220,7 +222,11 @@ def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
     lo = None
     if view is not None:    # written through the ring, read through the view
         pages, apos, lo = view
-    bound = {} if lo is None else {"lo": lo}
+    # what the kernels take only where the layer has it: without either
+    # they lower to the programs they were
+    kernel_kw = {} if lo is None else {"lo": lo}
+    if scale is not None:
+        kernel_kw["scale"] = scale
     rep = nq // nkv
     # the kernels take (n_pages, page, nkv, hd) and read it as the slab:
     # of a pool stored as the slab, a view and back
@@ -244,7 +250,7 @@ def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
             else:
                 attn = paged_attention_decode(
                     qg, as_pages(pk), as_pages(pv), pages, apos,
-                    valid=valid, probs_dtype=dtype, **bound)
+                    valid=valid, probs_dtype=dtype, **kernel_kw)
         return attn, (pk, pv, pk_s, pv_s)
 
     if paged_kernel and not quantized:
@@ -259,7 +265,7 @@ def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
             qg = q.reshape(B, S, nkv, rep, hd)
             attn = paged_flash_prefill(qg, as_pages(pk), as_pages(pv),
                                        pages, apos, valid=valid,
-                                       probs_dtype=dtype, **bound)
+                                       probs_dtype=dtype, **kernel_kw)
         return attn, (pk, pv, pk_s, pv_s)
 
     # gather the slot's pages into the contiguous head-major view the
@@ -285,7 +291,9 @@ def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
         else:
             scores = jnp.einsum(
                 "bsgrh,bgkh->bgrsk", qg, vk,
-                preferred_element_type=jnp.float32) / math.sqrt(hd)
+                preferred_element_type=jnp.float32)
+            scores = scores / math.sqrt(hd) if scale is None \
+                else scores * scale
         pos_kv = jnp.arange(V)
         vis = pos_kv[None, None, :] <= apos[:, :, None]      # (B, S, V)
         if lo is not None:
@@ -399,13 +407,17 @@ def _paged_latent_forward(params, ids, cfg, bufs: PoolBuffers, pages,
 
 def _paged_hybrid_forward(params, ids, cfg, bufs: PoolBuffers, pages,
                           apos, valid, paged_kernel=False, slot=None):
-    """``_paged_forward`` for the blocks of gated delta-rule layers and
-    full-attention layers: ``models/gdn_hybrid.py`` holds the linear mixer
-    and the layer pattern for both, and ``cfg.block_module`` (that module,
-    or ``models/gdn_moe.py`` with an expert layer under every mixer) what
-    differs between them: the residual path, the full-attention mixer's
-    projections and output, the MLP.  ``params["layers"]`` is a tuple of
-    per-layer dicts of two kinds, and so is the per-request state.
+    """``_paged_forward`` for the blocks whose requests keep STATE SLOTS
+    beside pages: linear layers (gated delta-rule or Mamba-2) and
+    full-attention layers in one stack.  One loop serves the three of
+    them and asks two modules for what differs (``gdn_hybrid``'s comment
+    "what a block brings"): ``cfg.linear_mixer`` (``models/gdn_hybrid.py``
+    for the two delta-rule blocks, ``models/ssm_moe.py`` for the Mamba-2
+    one) for the layer's kind, the state's and the tail's shapes, the step
+    and the scan; ``cfg.block_module`` (``gdn_hybrid``, ``gdn_moe`` or
+    ``ssm_moe``) for the residual path, the full-attention mixer's
+    projections, scale and output, and the MLP.  ``params["layers"]`` is a
+    tuple of per-layer dicts of two kinds, and so is the per-request state.
 
     A FULL-ATTENTION layer caches K/V rows in its own page pools
     (``bufs.k[f]``/``bufs.v[f]``, ``f`` counting the full layers only)
@@ -413,40 +425,40 @@ def _paged_hybrid_forward(params, ids, cfg, bufs: PoolBuffers, pages,
     over a row that may end in zero heads (``kv_pool.padded_kv_heads``).
 
     A LINEAR layer reads and writes its STATE SLOTS ``bufs.state[j]``
-    (n_slots, dk, n * dv) float32, lane-dense (``gdn_hybrid.slot_shape``),
-    and ``bufs.conv[j]`` (n_slots, K - 1, C), ``j`` counting the linear
+    (n_slots,) + ``slot_shape`` float32, lane-dense as stored, and
+    ``bufs.conv[j]`` (n_slots, K - 1, C), ``j`` counting the linear
     layers only:
 
       * a decode step (``slot`` None; row ``b`` of x IS slot ``b``) runs
-        ``gdn_hybrid.recurrent_step`` on the slots as they are stored, in
-        place; a row with ``valid`` False has beta = 0 and alpha = 1 and
-        leaves its state and its tail bit-unchanged.  With
-        ``paged_kernel`` the step is the Pallas kernel of
-        ``ops/gdn_step.py`` (``gdn_hybrid.step_kernel``), which neither
-        reads nor writes such a slot;
+        the mixer's ``recurrent_step`` on the slots as they are stored, in
+        place; a row with ``valid`` False has operands that change nothing
+        and leaves its state and its tail bit-unchanged.  With
+        ``paged_kernel`` the step is the mixer's Pallas kernel
+        (``ops/gdn_step.py``, ``ops/ssm_step.py``; its ``step_kernel``),
+        which neither reads nor writes such a slot;
       * a prefill chunk of one request (``slot`` () int32; x is (1, C, H))
-        takes the slot's state (unpacked to ``(1, n, dk, dv)``: the one
-        conversion, 2.2 MB a layer a chunk) and tail, or ZEROS when the
-        chunk is the request's first (``apos[0, 0] == 0``: a granted slot
-        never inherits what its last request left), runs
-        ``gdn_hybrid.chunked_scan`` over the chunk and writes both back;
-        rows past the prompt's end change neither.
+        takes the slot's state (unpacked to the scan's layout: the one
+        conversion a layer a chunk) and tail, or ZEROS when the chunk is
+        the request's first (``apos[0, 0] == 0``: a granted slot never
+        inherits what its last request left), runs the mixer's
+        ``chunked_scan`` over the chunk and writes both back; rows past the
+        prompt's end change neither.
 
     Under the catalogue's scopes: ``attn_qkv`` (a pre-mixer norm,
-    projections, conv, SiLU, the norms of q and k, rotary embedding, beta
-    and alpha; the conv under ``lin_conv``), ``kv_write``, ``attn_core``
-    (attention, for the block with expert layers under ``attn_paged``; the
-    scan under ``lin_scan``, the step under ``lin_step``), ``attn_out``
-    (gates, ``w_o`` / ``wo``, a post-mixer norm), ``mlp`` (an expert layer
-    under ``moe_route`` / ``moe_experts`` / ``moe_shared``).  Returns
-    ``(x', bufs', counts)``; ``counts`` is int32: the expert layers'
-    ``mla_moe.moe_counts`` summed, where the block has them, and then the
-    rows of this call whose state was live, a decode step's
-    ``state_slot_steps``."""
-    blk = cfg.block_module
+    projections, conv, SiLU, the norms of q and k, rotary embedding, the
+    recurrence's gates; the conv under ``lin_conv``), ``kv_write``,
+    ``attn_core`` (attention, for the blocks with expert layers under
+    ``attn_paged``; the scan under ``lin_scan``, the step under
+    ``lin_step``), ``attn_out`` (gates, ``w_o`` / ``wo``, a post-mixer
+    norm), ``mlp`` (an expert layer under ``moe_route`` / ``moe_experts``
+    / ``moe_shared``).  Returns ``(x', bufs', counts)``; ``counts`` is
+    int32: the expert layers' ``mla_moe.moe_counts`` summed, where the
+    block has them, and then the rows of this call whose state was live, a
+    decode step's ``state_slot_steps``."""
+    blk, lin = cfg.block_module, cfg.linear_mixer
     decode = slot is None
     with scope("embed"):
-        x = params["embed"].astype(cfg.dtype)[ids]
+        x = blk.embed(params, ids, cfg)
         rope = blk.rope_tables(apos, cfg)
     B, S, _ = x.shape
     ks, vs = list(bufs.k), list(bufs.v)
@@ -455,7 +467,7 @@ def _paged_hybrid_forward(params, ids, cfg, bufs: PoolBuffers, pages,
     moe = None      # the expert layers' counters, where the block has them
     f = j = 0
     for li, layer in enumerate(params["layers"]):
-        if G.is_full_layer(li, cfg):
+        if lin.is_full_layer(li, cfg):
             with scope("attn_qkv"):
                 q, k, v, gate = blk.attention_qkv(
                     blk.mixer_input(x, layer, cfg=cfg), layer, cfg=cfg,
@@ -474,7 +486,8 @@ def _paged_hybrid_forward(params, ids, cfg, bufs: PoolBuffers, pages,
                 q, k, v, dtype=x.dtype, pk=ks[f], pv=vs[f], pk_s=None,
                 pv_s=None, pages=pages, apos=apos, valid=valid,
                 paged_kernel=paged_kernel,
-                kernel_scope=blk.PAGED_ATTENTION_SCOPE, slab=slab_pool(cfg))
+                kernel_scope=blk.PAGED_ATTENTION_SCOPE, slab=slab_pool(cfg),
+                scale=blk.attention_scale(cfg))
             with scope("attn_out"):
                 h = blk.attention_output(attn[:, :, :nkv], gate, x, layer,
                                          cfg=cfg)
@@ -483,31 +496,29 @@ def _paged_hybrid_forward(params, ids, cfg, bufs: PoolBuffers, pages,
             if decode:
                 s0, t0 = states[j], tails[j]
             else:
-                s0 = G.unpack_state(
+                s0 = lin.unpack_state(
                     jax.lax.dynamic_slice_in_dim(states[j], slot, 1),
-                    cfg.linear_num_value_heads)
+                    lin.state_shape(cfg)[0])
                 t0 = jax.lax.dynamic_slice_in_dim(tails[j], slot, 1)
                 s0 = jnp.where(fresh, jnp.zeros_like(s0), s0)
                 t0 = jnp.where(fresh, jnp.zeros_like(t0), t0)
             with scope("attn_qkv"):
                 r = blk.mixer_input(x, layer, cfg=cfg)
-                q, k, v, g, beta, t1 = G.linear_inputs(
-                    r, layer, t0, valid, cfg=cfg)
+                *ins, t1 = lin.linear_inputs(r, layer, t0, valid, cfg=cfg)
             with scope("attn_core"):
                 if decode:
-                    with scope("lin_step"), G.step_kernel(paged_kernel):
-                        o, s1 = G.recurrent_step(
-                            q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                            beta[:, 0], s0)
+                    with scope("lin_step"), lin.step_kernel(paged_kernel):
+                        o, s1 = lin.recurrent_step(
+                            *(a[:, 0] for a in ins), s0)
                         o = o[:, None]
                 else:
                     with scope("lin_scan"):
-                        o, s1 = G.chunked_scan(q, k, v, g, beta, s0)
+                        o, s1 = lin.chunked_scan(*ins, s0)
             if decode:
                 states[j], tails[j] = s1, t1
             else:
                 states[j] = jax.lax.dynamic_update_slice_in_dim(
-                    states[j], G.pack_state(s1), slot, axis=0)
+                    states[j], lin.pack_state(s1), slot, axis=0)
                 tails[j] = jax.lax.dynamic_update_slice_in_dim(
                     tails[j], t1.astype(tails[j].dtype), slot, axis=0)
             with scope("attn_out"):
@@ -584,14 +595,14 @@ def _paged_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
     UNROLLED layer stack (static layer index into the per-layer pools,
     like ``generate._forward_cached``).  ``counts`` is None for the dense
     block, the expert layers' counters for the latent one and the live
-    rows for the gated delta-rule hybrid, whose prefill chunk also names
+    rows for a block with state slots, whose prefill chunk also names
     the batch ``slot`` whose state it carries; for the block with window
     layers ``pages`` is a pair of tables and ``counts`` end in the rows its
     two kinds of layer read."""
     if cfg.mla_moe:
         return _paged_latent_forward(params, ids, cfg, bufs, pages, apos,
                                      valid, paged_kernel=paged_kernel)
-    if cfg.gdn_hybrid:
+    if cfg.state_slots:
         return _paged_hybrid_forward(params, ids, cfg, bufs, pages, apos,
                                      valid, paged_kernel=paged_kernel,
                                      slot=slot)
@@ -631,7 +642,7 @@ def _all_logits(params, x, cfg):
     unembedding are per-row ops, so row ``i`` is bitwise the
     single-position tail evaluated at that position — what lets the
     speculative verify step read k+1 greedy tokens from one forward."""
-    if cfg.gdn_hybrid:      # the block's own: plain, or zero-centred
+    if cfg.state_slots:     # the block's own: plain, or zero-centred
         x = cfg.block_module.final_norm(x, params, cfg)
     else:
         x = T.rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
@@ -641,7 +652,10 @@ def _all_logits(params, x, cfg):
         logits = prequantized_dense(x, uq)
     else:
         logits = x @ T._output_embedding(params, cfg).T
-    return logits.astype(jnp.float32)
+    logits = logits.astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def _last_logits(params, x_last, cfg):
@@ -658,8 +672,8 @@ def device_counters(cfg) -> tuple:
     () for the dense block, whose ``carry`` is token rows alone."""
     if cfg.swa_moe:     # the expert layers' four, the rows its layers read
         return cfg.block_module.DEVICE_COUNTERS
-    out = M.COUNTERS if cfg.mla_moe or cfg.gdn_moe else ()
-    return out + (G.COUNTERS[:1] if cfg.gdn_hybrid else ())
+    out = M.COUNTERS if cfg.mla_moe or cfg.gdn_moe or cfg.ssm_moe else ()
+    return out + (G.COUNTERS[:1] if cfg.state_slots else ())
 
 
 def _decode_core(bufs, params, pages, toks, lengths, stop_at, active,
@@ -1029,12 +1043,13 @@ class ServingEngine:
             self.prefill_kernel = prefill_kernel_takes(
                 self.cfg.dtype, self.cfg.resolved_head_dim,
                 self.page_size, self.prefill_chunk)
-        # whether the gated delta rule's decode step is the Pallas step
-        # kernel (ops/gdn_step.py): as decode resolved, for the shapes it
-        # takes
+        # whether a linear mixer's decode step is its Pallas step kernel
+        # (ops/gdn_step.py, ops/ssm_step.py): as decode resolved, for the
+        # shapes it takes
+        lin = self.cfg.linear_mixer
         self.lin_step_kernel = bool(
-            self.cfg.gdn_hybrid and self.paged_kernel
-            and G.step_kernel_engages(*G.state_shape(self.cfg)))
+            lin is not None and self.paged_kernel
+            and lin.step_kernel_engages(*lin.state_shape(self.cfg)))
         self.spec_k = int(spec_k)
         if self.flash_prefill and kv_quant:
             raise ValueError("the flash prefill kernel is float-only — "
@@ -1329,7 +1344,7 @@ class ServingEngine:
         # steps whose recurrence was the step kernel, which moves a live
         # state once in and once out in place and no other
         self._device_counters = device_counters(self.cfg)
-        if self.cfg.gdn_hybrid:
+        if self.cfg.state_slots:
             self.stats.update(dict.fromkeys(
                 G.COUNTERS[1:] + ("lin_step_inplace_steps",), 0))
         if self.cfg.swa_moe:    # its host counters (swa_moe.COUNTERS)
@@ -1471,7 +1486,7 @@ class ServingEngine:
         rows = min(Ck, req.n_prompt - pos)
         # what the stage ships: page row, ids, two scalars, and the
         # hybrids' batch slot or the window class's ring
-        slot_put = int(self.cfg.gdn_hybrid)
+        slot_put = int(self.cfg.state_slots)
         n_put = 4 + slot_put + bool(self.ring_pages)
         t_chunk = time.perf_counter()  # clock-ok
         with maybe_span(stream, "serve/prefill_stage", arrays=n_put,
@@ -1498,7 +1513,7 @@ class ServingEngine:
                 seen = np.minimum(np.arange(pos, pos + rows) + 1,
                                   self.cfg.sliding_window)
                 self.stats["window_pairs_prefilled"] += int(seen.sum())
-            if self.cfg.gdn_hybrid:
+            if self.cfg.state_slots:
                 # the batch slot whose state the chunk carries on
                 args += (self._put(np.int32(req.slot), dev),)
                 self.stats["lin_scan_rows"] += rows
@@ -1974,7 +1989,7 @@ class ServingEngine:
                         self._h_rings[req.slot, :len(req.pages_window)] \
                             = req.pages_window
                     self.stats["queue_wait_s"] += req.t_admit - req.t_submit
-                    if self.cfg.gdn_hybrid:
+                    if self.cfg.state_slots:
                         # the granted slot's state: its first prefill
                         # chunk starts from zeros (_paged_hybrid_forward)
                         self.stats["state_resets"] += 1

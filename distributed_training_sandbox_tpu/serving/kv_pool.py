@@ -57,7 +57,12 @@ block copy moves no padding; ``(heads, 96, 192)`` held every row of 192 in
 256 lanes, 2,949,120 bytes), and ``conv`` ``(n_slots, K - 1, channels)``
 per linear layer.  A slot's state never
 survives its request: the first prefill chunk of the next one starts from
-zeros whatever the slot held (``engine._prefill_core``).
+zeros whatever the slot held (``engine._prefill_core``).  The Mamba-2 +
+attention block (``models/ssm_moe.py``) holds its slots the same way, as
+``cfg.linear_mixer`` shapes them: ``state`` ``(n_slots, state dim, heads *
+head dim)`` float32 (``ssm_moe.slot_shape``: 128 x 8,192 at the published
+widths, 4,194,304 bytes of whole tiles as the state is laid out, so nothing
+had to be re-laid) in nine layers of ten, pages in the one attention layer.
 """
 
 from __future__ import annotations
@@ -98,7 +103,7 @@ def row_layout(cfg, tp: int = 1) -> tuple[tuple[int, ...], bool]:
         from ..models.mla_moe import row_width
         w = row_width(cfg)
         return (w + -w % LATENT_ROW_ALIGN,), False
-    if cfg.gdn_hybrid:
+    if cfg.state_slots:
         return (padded_kv_heads(cfg.num_key_value_heads, cfg.dtype),
                 cfg.resolved_head_dim), True
     return (cfg.num_key_value_heads // tp, cfg.resolved_head_dim), True
@@ -118,7 +123,7 @@ def slab_pool(cfg) -> bool:
     pool stays 4-D.  Only for the hybrid blocks, whose engine refuses what
     still indexes a pool by ``(page, offset, head)``: int8 rows with their
     scales, the hand-over between pools, a tp mesh over the head axis."""
-    if not cfg.gdn_hybrid:
+    if not cfg.state_slots:
         return False
     (heads, hd), _ = row_layout(cfg)
     return hd > 128 and heads % (32 // jnp.dtype(cfg.dtype).itemsize) != 0
@@ -168,20 +173,19 @@ def ring_view(ring, apos, window: int, page: int):
 def paged_layers(cfg) -> int:
     """Layers whose tokens cache a row in pages: what every sizing of the
     pool multiplies :func:`token_row_bytes` by.  All of them (of either
-    page class), but for the gated delta-rule hybrid's linear layers,
+    page class), but for the linear layers of a block with state slots,
     which hold a state slot instead."""
-    if cfg.gdn_hybrid:
-        from ..models.gdn_hybrid import full_layers
-        return len(full_layers(cfg))
+    if cfg.state_slots:
+        return len(cfg.linear_mixer.full_layers(cfg))
     return cfg.num_hidden_layers
 
 
 def slot_state_bytes(cfg) -> int:
     """Bytes ONE batch slot holds in state slots over all layers (0 for a
     block whose every layer is paged)."""
-    if cfg.gdn_hybrid:
-        from ..models import gdn_hybrid as G
-        return len(G.linear_layers(cfg)) * G.slot_state_bytes(cfg)
+    lin = cfg.linear_mixer
+    if lin is not None:
+        return len(lin.linear_layers(cfg)) * lin.slot_state_bytes(cfg)
     return 0
 
 
@@ -487,11 +491,11 @@ class PagedKVPool:
                 "page class for its window layers: pass n_pages_window "
                 ">= 2 (max_batch rings of kv_pool.ring_pages + the null "
                 "page), and neither kv_quant nor a mesh")
-        if cfg.gdn_hybrid and (kv_quant or mesh is not None
-                               or n_slots < 1):
+        if cfg.state_slots and (kv_quant or mesh is not None
+                                or n_slots < 1):
             raise ValueError(
-                "the gated delta-rule hybrid's pool holds a float state "
-                "slot per batch slot: pass n_slots >= 1, and neither "
+                "the pool of a block with linear layers holds a float "
+                "state slot per batch slot: pass n_slots >= 1, and neither "
                 "kv_quant nor a mesh")
         self.cfg = cfg
         self.n_pages = int(n_pages)
@@ -530,13 +534,13 @@ class PagedKVPool:
             vs = tuple(put(jnp.ones(shape[:-1] + (1,), jnp.float32))
                        for _ in range(L))
         state = conv = None
-        self.n_slots = int(n_slots) if cfg.gdn_hybrid else 0
+        self.n_slots = int(n_slots) if cfg.state_slots else 0
         if self.n_slots:
-            from ..models import gdn_hybrid as G
-            n_lin = len(G.linear_layers(cfg))
-            state = tuple(put(jnp.zeros((self.n_slots,) + G.slot_shape(cfg),
+            lin = cfg.linear_mixer
+            n_lin = len(lin.linear_layers(cfg))
+            state = tuple(put(jnp.zeros((self.n_slots,) + lin.slot_shape(cfg),
                                         jnp.float32)) for _ in range(n_lin))
-            conv = tuple(put(jnp.zeros((self.n_slots,) + G.tail_shape(cfg),
+            conv = tuple(put(jnp.zeros((self.n_slots,) + lin.tail_shape(cfg),
                                        cfg.dtype)) for _ in range(n_lin))
         self.bufs = PoolBuffers(k=k, v=v, k_scale=ks, v_scale=vs,
                                 state=state, conv=conv)

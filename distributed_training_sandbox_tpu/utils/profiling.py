@@ -164,8 +164,9 @@ SUBSCOPES = (
     "moe_shared",   # the shared expert
 )
 
-#: A declared second level of the gated delta-rule hybrid's linear layers
-#: (``models/gdn_hybrid.py``, ``serving/engine._paged_hybrid_forward``):
+#: A declared second level of the linear layers of the blocks with state
+#: slots (``models/gdn_hybrid.py`` and, since PR 40, the Mamba-2 layers of
+#: ``models/ssm_moe.py``; ``serving/engine._paged_hybrid_forward``):
 #: ``lin_conv`` beneath ``attn_qkv``, ``lin_scan`` and ``lin_step`` beneath
 #: ``attn_core``.  A reader of ``SCOPES`` books these ops to the catalogue
 #: name above them; ``benchmarks/layer_metrics/_linscopes.py`` holds a copy

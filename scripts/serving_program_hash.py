@@ -78,7 +78,7 @@ def programs(root: Path):
         P = -(-eng["max_seq_len"] // page)
         params = jax.eval_shape(
             lambda: T.init_params(jax.random.key(0), mcfg))
-        slots = {"n_slots": B} if mcfg.gdn_hybrid else {}
+        slots = {"n_slots": B} if mcfg.state_slots else {}
         tables = lambda b: i32(b, P)  # noqa: E731
         if getattr(mcfg, "swa_moe", False):
             # a second page class: a ring a slot, a second table
@@ -98,7 +98,7 @@ def programs(root: Path):
         prefill = E.make_serve_prefill_step(
             mcfg, paged_kernel=not mcfg.mla_moe).trace(
             bufs, params, tables(1), i32(1, eng["prefill_chunk"]), i32(),
-            i32(), *((i32(),) if mcfg.gdn_hybrid else ()))
+            i32(), *((i32(),) if mcfg.state_slots else ()))
         for name, traced in (("decode", decode), ("prefill", prefill)):
             yield cell.name, name, traced.lower(
                 lowering_platforms=("tpu",)).as_text()

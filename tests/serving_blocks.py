@@ -1,7 +1,8 @@
-"""The five blocks the serving engine builds, at a tiny size, float32,
+"""The six blocks the serving engine builds, at a tiny size, float32,
 seeded weights: the configurations that the blocks' own test files
 (``tests/test_mla_moe.py``, ``tests/test_gdn_hybrid.py``,
-``tests/test_gdn_moe.py``, ``tests/test_swa_moe.py``) and the tests that run over ALL blocks share,
+``tests/test_gdn_moe.py``, ``tests/test_swa_moe.py``,
+``tests/test_ssm_moe.py``) and the tests that run over ALL blocks share,
 keyed as the benchmark keys its plain float32 references
 (``benchmarks/reference/<architecture>.py``)."""
 
@@ -61,6 +62,16 @@ FIELDS = {
         router_width=16, expert_offset=4, num_experts_per_tok=3,
         moe_intermediate_size=32, num_shared_experts=1, norm_topk_prob=True,
         routed_scaling_factor=2.448, sandwich_norm=True, mup_enabled=True),
+    "ssm_moe": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=32,
+        num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=2,
+        rms_norm_eps=1e-5, tie_word_embeddings=True, nope_interval=0,
+        layer_types=("mamba", "mamba", "attention", "mamba"),
+        mamba_n_heads=8, mamba_d_head=16, mamba_d_state=16, mamba_d_conv=4,
+        num_local_experts=4, router_width=12, expert_offset=4,
+        num_experts_per_tok=3, shared_intermediate_size=48,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=0.03125, logits_scaling=4.0),
 }
 BLOCKS = tuple(FIELDS)
 
