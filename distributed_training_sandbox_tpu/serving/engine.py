@@ -664,6 +664,24 @@ def _last_logits(params, x_last, cfg):
     return _all_logits(params, x_last, cfg)[:, 0]
 
 
+def _first_token(params, x_last, is_final, cfg):
+    """(B, 1, H) hidden → (B,) greedy tokens when ``is_final`` (a scalar
+    the device computes from the chunk's own ``pos`` and ``plen``), zeros
+    otherwise: a prefill chunk that ends no prompt skips the final norm,
+    the read of the whole unembedding and the argmax, whose token nobody
+    reads.  One ``cond`` inside the one prefill program; under a tensor-
+    parallel mesh ``pos`` and ``plen`` are replicated, so every shard
+    takes the same branch."""
+    def head(x):
+        return jnp.argmax(_last_logits(params, x, cfg),
+                          axis=-1).astype(jnp.int32)
+
+    def no_head(x):
+        return jnp.zeros(x.shape[:1], jnp.int32)
+
+    return jax.lax.cond(is_final, head, no_head, x_last)
+
+
 def device_counters(cfg) -> tuple:
     """The names of what the block's decode program sums on the device,
     in the order they lead ``_decode_core``'s ``carry``: the expert
@@ -725,8 +743,8 @@ def _prefill_core(bufs, params, pages_row, ids, pos, plen, slot=None, *,
     divert to the null page.  A block with state slots also takes
     ``slot`` () int32, the request's batch slot, whose state the chunk
     carries on (from zeros when ``pos`` is 0).  Returns the greedy first
-    token — only meaningful on the FINAL chunk (position plen-1 falls
-    inside it)."""
+    token of the FINAL chunk (position plen-1 falls inside it); any other
+    chunk skips the head and returns 0."""
     Ck = ids.shape[1]
     apos = pos + jnp.arange(Ck, dtype=jnp.int32)[None, :]
     valid = apos < plen
@@ -736,8 +754,7 @@ def _prefill_core(bufs, params, pages_row, ids, pos, plen, slot=None, *,
     with scope("sample"):
         last = jnp.clip(plen - 1 - pos, 0, Ck - 1)
         xl = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
-        logits = _last_logits(params, xl, cfg)
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        tok = _first_token(params, xl, pos + Ck >= plen, cfg)
     return tok, bufs
 
 
@@ -748,7 +765,8 @@ def _prefill_batch_core(bufs, params, pages, ids, pos, plen, *, cfg,
     Pad rows carry ``plen == 0``: every position is invalid, scatters
     divert to the null page, and the (garbage) token output is never
     read.  Returns each row's greedy token at its final prompt position
-    — meaningful only for rows whose final chunk this is.  Rows are
+    — meaningful only for rows whose final chunk this is; a launch in
+    which no live row ends skips the head and returns zeros.  Rows are
     per-request bitwise-independent (the parity invariant), so batching
     requests changes nothing a single-row prefill would emit."""
     Bp, Ck = ids.shape
@@ -760,8 +778,10 @@ def _prefill_batch_core(bufs, params, pages, ids, pos, plen, *, cfg,
     with scope("sample"):
         last = jnp.clip(plen - 1 - pos, 0, Ck - 1)
         xl = jnp.take_along_axis(x, last[:, None, None], axis=1)
-        logits = _last_logits(params, xl, cfg)
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        # a pad row's plen is 0: it "ends" in every launch, so it is
+        # live rows alone that ask for the head
+        ends = jnp.any((plen > 0) & (pos + Ck >= plen))
+        tok = _first_token(params, xl, ends, cfg)
     return tok, bufs
 
 
@@ -964,7 +984,8 @@ class ServingEngine:
     ``*_dispatch``, with ``program`` and ``k``), :meth:`_put` and
     :meth:`_read` (one blocking read an array); the stage and sync
     spans say what they moved (``arrays``, ``bytes``), a dispatch span
-    the work it carries (``live`` slots, valid ``rows``)."""
+    the work it carries (``live`` slots, valid ``rows``, and for a
+    prefill chunk ``head``: 1 when it ends a prompt, so its head runs)."""
 
     def __init__(self, params, cfg, *, mesh=None, tp_axis: str = "tp",
                  max_batch: int = 4, page_size: int = 8,
@@ -1311,6 +1332,9 @@ class ServingEngine:
                       # decode steps whose attention read the pages in
                       # place (the paged kernel) and built no gather view
                       "decode_inplace_steps": 0, "prefill_chunks": 0,
+                      # prefill chunks that ended a prompt, so ran the
+                      # head: the rest skipped it on the device
+                      "prefill_head_chunks": 0,
                       # prefill chunks whose attention was the flash
                       # prefill kernel: likewise
                       "prefill_inplace_chunks": 0,
@@ -1517,7 +1541,9 @@ class ServingEngine:
                 # the batch slot whose state the chunk carries on
                 args += (self._put(np.int32(req.slot), dev),)
                 self.stats["lin_scan_rows"] += rows
-        with maybe_span(stream, "serve/prefill_dispatch", rows=rows, **sp):
+        final = pos + Ck >= req.n_prompt
+        with maybe_span(stream, "serve/prefill_dispatch", rows=rows,
+                        head=int(final), **sp):
             tok_d, bufs = self._launch(sp, "prefill", k, self._prefill,
                                        bufs, self._params_pre, *args)
             if self.disaggregate:
@@ -1534,8 +1560,8 @@ class ServingEngine:
                 self.draft_pool.bufs = dbufs
             req.prefill_pos = min(pos + Ck, req.n_prompt)
             self.stats["prefill_chunks"] += 1
+            self.stats["prefill_head_chunks"] += final
             self.stats["prefill_inplace_chunks"] += self.prefill_kernel
-            final = req.prefill_pos >= req.n_prompt
             if final and self.disaggregate:
                 # final chunk: hand the KV off to the decode slice
                 self._handoff(req, row, sp, k)
@@ -1649,9 +1675,12 @@ class ServingEngine:
                 else self.pool.bufs
             args = (self._put(pages, dev), self._put(ids, dev),
                     self._put(pos, dev), self._put(plen, dev))
+        finishing = [(i, r) for i, r in enumerate(reqs)
+                     if r.prefill_pos + Ck >= r.n_prompt]
         with maybe_span(stream, "serve/prefill_dispatch",
                         rows=sum(min(Ck, r.n_prompt - r.prefill_pos)
-                                 for r in reqs), **sp):
+                                 for r in reqs),
+                        head=int(bool(finishing)), **sp):
             tok_d, bufs = self._launch(
                 sp, "prefill_batch", k, self._prefill_batch, bufs,
                 self._params_pre, *args)
@@ -1666,12 +1695,10 @@ class ServingEngine:
                     self._draft_params, *args)
                 self.draft_pool.bufs = dbufs
             self.stats["prefill_chunks"] += 1
+            self.stats["prefill_head_chunks"] += bool(finishing)
             self.stats["prefill_inplace_chunks"] += self.prefill_kernel
-            finishing = []
-            for i, req in enumerate(reqs):
+            for req in reqs:
                 req.prefill_pos = min(req.prefill_pos + Ck, req.n_prompt)
-                if req.prefill_pos >= req.n_prompt:
-                    finishing.append((i, req))
             if self.disaggregate:
                 for i, req in finishing:
                     self._handoff(
@@ -2185,6 +2212,9 @@ class ServingEngine:
                 "rounds": self.stats["rounds"],
                 "decode_steps": self.stats["decode_steps"],
                 "prefill_chunks": self.stats["prefill_chunks"],
+                # of those, the chunks that ended a prompt and ran the
+                # head; 1 - this share skipped it
+                "prefill_head_chunks": self.stats["prefill_head_chunks"],
                 "admit_ms_total": round(1e3 * self.stats["admit_s"], 3),
                 "bookkeep_ms_total": round(
                     1e3 * self.stats["bookkeep_s"], 3),

@@ -144,7 +144,9 @@ SCOPES = (
     # the serving engine's programs (serving/engine.py)
     "kv_write",    # scatter of the new K/V rows into their pages
     "kv_gather",   # gather of a slot's pages into the contiguous view
-    "sample",      # final norm + unembedding at one position + argmax
+    "sample",      # final norm + unembedding at one position + argmax;
+                   # a prefill chunk's sits in a ``cond`` and runs only
+                   # when the chunk ends a prompt
     # the FSDP strategy step (parallel/fsdp.py)
     "fsdp_layer_gather", "fsdp_root_gather", "fsdp_pre_gather_layers",
     "loss_mean", "grad_mean",
