@@ -236,11 +236,16 @@ def analytic_waterline(cfg, *, batch: int, seq: int, ws: int = 1,
     saved /= tp_ways
     working /= tp_ways
 
-    # loss-phase buffers: streamed vocab chunk (fp32 logits chunk + the
-    # checkpointed backward's recompute) or the dense 3-spike trio
+    # loss-phase buffers: the streamed head's row block of fp32 logits
+    # over the whole vocabulary (tokens x chunk x 4 bytes is its budget)
+    # plus the fp32 dW carry summed over the blocks, or the dense
+    # 3-spike trio
     chunk = getattr(cfg, "loss_vocab_chunk", None)
     V = cfg.vocab_size
-    loss = micro * seq * (chunk or V) * 4 * (1.0 if chunk else 3.0)
+    if chunk:
+        loss = micro * seq * chunk * 4 + V * H * 4
+    else:
+        loss = micro * seq * V * 4 * 3.0
 
     batch_bytes = b * seq * 4 * 2                      # int32 ids+labels
     total = (params + grads + opt + boundaries + saved

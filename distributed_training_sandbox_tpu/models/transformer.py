@@ -100,10 +100,11 @@ class TransformerConfig:
     # (balanced stripes — ~half the ring's score FLOPs; batches must be
     # fed through parallel.sequence.zigzag_shuffle).
     ring_layout: str = "contiguous"
-    # Cross-entropy vocab chunk: None materializes full (B, S, vocab) fp32
-    # logits (the reference's documented ~4 GB spikes, README.md:28-33);
-    # an int streams the vocab through an online logsumexp in chunks of
-    # that size, capping loss memory at B·S·chunk fp32.
+    # Cross-entropy logits budget: None materializes full (B, S, vocab)
+    # fp32 logits (the reference's documented ~4 GB spikes,
+    # README.md:28-33); an int streams the tokens through the head in row
+    # blocks over the whole vocabulary, a block's fp32 logits capped at
+    # B·S·chunk·4 bytes (streamed_softmax_xent).
     loss_vocab_chunk: int | None = None
     # Projection-matmul precision: "bf16", or int8 with dynamic absmax
     # scaling (forward quantized, backward bf16) — the reference's fp8
@@ -406,7 +407,7 @@ SMOLLM3_3B = TransformerConfig()
 
 # Single-chip flagship: the 3B architecture (same hidden/heads/vocab/MLP
 # geometry, so per-layer compute is identical) truncated to 8 layers to fit
-# one 16 GB v5e with AdamW state; fused attention + streamed vocab loss.
+# one 16 GB v5e with AdamW state; fused attention + streamed loss head.
 SMOLLM3_3B_L8 = TransformerConfig(
     num_hidden_layers=8, attention_impl="flash", loss_vocab_chunk=16_032)
 
@@ -927,45 +928,102 @@ def _output_embedding(params: dict, cfg: TransformerConfig) -> jax.Array:
     return w.astype(cfg.dtype).T
 
 
-def chunked_softmax_xent(x: jax.Array, w_vocab: jax.Array,
-                         labels: jax.Array, chunk: int) -> jax.Array:
-    """Mean cross-entropy of ``x @ w_vocab.T`` against ``labels`` without
-    ever materializing the (B, S, vocab) logits: stream vocab-row chunks
-    through an online (running max/sum) logsumexp, gathering the gold logit
-    as its chunk passes.  ``jax.checkpoint`` on the chunk body keeps the
-    backward at one chunk of logits too.  This removes all three of the
-    reference's ~4 GB fp32 spikes (logits, log-probs, grad-wrt-log-probs —
-    README.md:28-33) at once."""
-    V, H = w_vocab.shape
-    n_chunks = -(-V // chunk)
-    pad = n_chunks * chunk - V
-    if pad:
-        w_vocab = jnp.pad(w_vocab, ((0, pad), (0, 0)))
-    B, S, _ = x.shape
+def _loss_row_block(tokens: int, vocab: int, chunk: int) -> int:
+    """Rows of one block of the streamed head.  ``chunk`` is the head's
+    float32 logits budget, ``tokens * chunk * 4`` bytes, spent on whole
+    vocabulary rows; a block of 128 rows or more is whole MXU tiles, and
+    the blocks are evened out so the last one's padding stays small."""
+    rows = min(tokens, max(1, tokens * chunk // vocab))
+    tile = 128 if rows >= 128 else 1
+    rows -= rows % tile
+    even = -(-tokens // -(-tokens // rows))
+    return -(-even // tile) * tile
 
-    def body(carry, c):
-        m, s, gold = carry
-        w_c = lax.dynamic_slice(w_vocab, (c * chunk, 0), (chunk, H))
-        logits = jnp.einsum("bsh,vh->bsv", x, w_c,
+
+def _xent_row_blocks(x, w_vocab, labels, tokens: int, with_grad: bool):
+    """One sweep over row blocks of ``x`` (n, R, H) against the whole
+    ``w_vocab`` (V, H); ``labels`` (n, R), negative on padding rows.  Gives
+    the mean over ``tokens`` of the negative log-likelihood and,
+    ``with_grad``, its gradients ``dx`` (n, R, H) and ``dW`` (V, H) made
+    from the same logits: three products with the vocabulary a block, and
+    no block's logits outlive it.  ``dW`` is summed over the blocks in
+    float32 and left so, for the caller to round once."""
+    def block(carry, scanned):
+        x_blk, lab = scanned
+        live = lab >= 0
+        logits = jnp.einsum("rh,vh->rv", x_blk, w_vocab,
                             preferred_element_type=jnp.float32)
-        col = c * chunk + jnp.arange(chunk)
-        logits = jnp.where(col < V, logits, -jnp.inf)
-        m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
-        s = s * jnp.exp(m - m_new) + jnp.sum(
-            jnp.exp(logits - m_new[..., None]), axis=-1)
-        idx = labels - c * chunk
-        hit = (idx >= 0) & (idx < chunk)
-        g = jnp.take_along_axis(logits, jnp.clip(idx, 0, chunk - 1)[..., None],
-                                axis=-1)[..., 0]
-        gold = gold + jnp.where(hit, g, 0.0)
-        return (m_new, s, gold), None
+        m = jnp.max(logits, axis=-1)
+        lse = m + jnp.log(jnp.sum(jnp.exp(logits - m[:, None]), axis=-1))
+        gold = jnp.take_along_axis(logits, jnp.maximum(lab, 0)[:, None],
+                                   axis=-1)[:, 0]
+        nll = jnp.sum(jnp.where(live, lse - gold, 0.0)) / tokens
+        if not with_grad:
+            return carry + nll, None
+        loss, dw = carry
+        # softmax / tokens as ONE exp of the shifted logits, 0 on padding
+        # rows: both products below recompute d from the logits as they
+        # read them, and a division there cost 22 ms a step on a v5e
+        shift = jnp.where(live, lse + math.log(tokens), jnp.inf)
+        hot = lab[:, None] == jnp.arange(logits.shape[-1])
+        d = jnp.exp(logits - shift[:, None]) - jnp.where(hot, 1 / tokens, 0.0)
+        d = d.astype(x_blk.dtype)
+        dx_blk = jnp.einsum("rv,vh->rh", d, w_vocab,
+                            preferred_element_type=jnp.float32)
+        dw = dw + jnp.einsum("rv,rh->vh", d, x_blk,
+                             preferred_element_type=jnp.float32)
+        return (loss + nll, dw), dx_blk.astype(x_blk.dtype)
 
-    init = (jnp.full((B, S), -jnp.inf, jnp.float32),
-            jnp.zeros((B, S), jnp.float32),
-            jnp.zeros((B, S), jnp.float32))
-    (m, s, gold), _ = lax.scan(jax.checkpoint(body, prevent_cse=False),
-                               init, jnp.arange(n_chunks))
-    return jnp.mean(jnp.log(s) + m - gold)
+    zero = jnp.zeros((), jnp.float32)
+    if not with_grad:
+        return lax.scan(block, zero, (x, labels))[0]
+    (loss, dw), dx = lax.scan(
+        block, (zero, jnp.zeros(w_vocab.shape, jnp.float32)), (x, labels))
+    return loss, dx, dw
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _streamed_xent(x, w_vocab, labels, tokens):
+    return _xent_row_blocks(x, w_vocab, labels, tokens, with_grad=False)
+
+
+def _streamed_xent_fwd(x, w_vocab, labels, tokens):
+    loss, dx, dw = _xent_row_blocks(x, w_vocab, labels, tokens,
+                                    with_grad=True)
+    return loss, (dx, dw)
+
+
+def _streamed_xent_bwd(tokens, grads, g):
+    # g is the loss's cotangent: 1 under a plain grad, anything under a
+    # scaled or weighted loss.  x and w_vocab share dx's dtype.
+    dx, dw = grads
+    return (g * dx).astype(dx.dtype), (g * dw).astype(dx.dtype), None
+
+
+_streamed_xent.defvjp(_streamed_xent_fwd, _streamed_xent_bwd)
+
+
+def streamed_softmax_xent(x: jax.Array, w_vocab: jax.Array,
+                          labels: jax.Array, chunk: int) -> jax.Array:
+    """Mean cross-entropy of ``x @ w_vocab.T`` against ``labels`` without
+    ever materializing the (tokens, vocab) logits: the tokens are
+    flattened to rows and swept in blocks (``_loss_row_block``) over the
+    WHOLE vocabulary, so each block's soft-max is complete and its
+    gradient is made in the same sweep (``_xent_row_blocks``); a
+    differentiated step multiplies by the vocabulary three times, a
+    loss-only call once.  This removes all three of the reference's ~4 GB
+    fp32 spikes (logits, log-probs, grad-wrt-log-probs, README.md:28-33)
+    at once."""
+    V, H = w_vocab.shape
+    tokens = labels.size
+    rows = _loss_row_block(tokens, V, chunk)
+    n_blocks = -(-tokens // rows)
+    pad = n_blocks * rows - tokens
+    x = jnp.pad(x.reshape(tokens, H), ((0, pad), (0, 0)))
+    labels = jnp.pad(labels.reshape(tokens), (0, pad), constant_values=-1)
+    return _streamed_xent(x.reshape(n_blocks, rows, H),
+                          w_vocab.astype(x.dtype),
+                          labels.reshape(n_blocks, rows), tokens)
 
 
 def lm_loss(params: dict, batch, cfg: TransformerConfig,
@@ -977,7 +1035,7 @@ def lm_loss(params: dict, batch, cfg: TransformerConfig,
     With ``cfg.loss_vocab_chunk`` unset this is the reference-faithful dense
     path: fp32 log-softmax over full (B, S, vocab) logits — the same memory
     spike the reference documents (README.md:28-33).  Set it to stream the
-    vocab instead (see chunked_softmax_xent).
+    head instead (see streamed_softmax_xent).
     """
     input_ids, labels = batch
     x, aux = hidden_states(params, input_ids, cfg, layer_hook=layer_hook,
@@ -993,11 +1051,11 @@ def lm_loss(params: dict, batch, cfg: TransformerConfig,
 def xent_from_hidden(x: jax.Array, w_vocab: jax.Array, labels: jax.Array,
                      *, chunk: int | None = None) -> jax.Array:
     """Mean causal-LM cross-entropy from final hidden states:
-    streamed-vocab when ``chunk`` is set, dense fp32 otherwise.
+    streamed in row blocks when ``chunk`` is set, dense fp32 otherwise.
     ``w_vocab``: (vocab, H) unembedding rows.  Shared by ``lm_loss`` and
     the pipeline's last stage so the numerics exist once."""
     if chunk:
-        return chunked_softmax_xent(x, w_vocab, labels, chunk)
+        return streamed_softmax_xent(x, w_vocab, labels, chunk)
     logits = (x @ w_vocab.T).astype(jnp.float32)
     logz = jax.scipy.special.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]

@@ -96,7 +96,7 @@ class PipelineStage:
         loss2 = loss_fn or (lambda out, y: jnp.mean((out - y) ** 2))
         # a loss may also take the stage params (3-arg form) — how the
         # transformer's last stage reaches its unembedding for the
-        # streamed-vocab loss.
+        # streamed loss.
         import inspect
         try:
             params_ = inspect.signature(loss2).parameters.values()
@@ -310,7 +310,7 @@ def build_transformer_pipeline(params: dict, cfg, n_stages: int,
             return x
 
         def lm_xent(hidden, labels, p):
-            # shared numerics with lm_loss (streamed vocab honored);
+            # shared numerics with lm_loss (streamed head honored);
             # lm_head is (H, vocab), xent wants (vocab, H) rows.
             return T.xent_from_hidden(
                 hidden, p["lm_head"].astype(cfg.dtype).T, labels,

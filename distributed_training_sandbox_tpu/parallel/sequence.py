@@ -14,7 +14,7 @@ Layout over a 2-D mesh ``("dp", "sp")``:
     scan — the explicit choreography of ``parallel/fsdp.py``) and
     replicated over ``sp``
   * forward: everything except attention is token-local (matmuls, norms,
-    the streamed-vocab loss); attention is the ring
+    the streamed loss); attention is the ring
   * backward: the dp all_gathers transpose to psum_scatters (FSDP's
     reduce-scatter), the ring's ppermutes transpose to reverse-direction
     ppermutes, and the sp-replicated param grads need one explicit

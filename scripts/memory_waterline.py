@@ -197,10 +197,12 @@ log-probs, grad-wrt-log-probs).
 * `streamed_loss` (this repo's `loss_vocab_chunk`
   = {cfg.loss_vocab_chunk}) plans
   {variants['streamed_loss']['temp_gb']:.2f} GB of temp at batch {B}:
-  the vocab streams through an online logsumexp in
-  {cfg.loss_vocab_chunk}-row chunks, so no (B, S, V) tensor ever exists
-  — forward OR backward.  This is what lets one 16 GB v5e train the
-  8-layer 3B geometry at seq 8192 at all.
+  the tokens stream through the head in row blocks over the whole
+  vocabulary (a block's fp32 logits are the budget B·S·{cfg.loss_vocab_chunk}·4
+  bytes), each block making its gradient from its own logits into a
+  fp32 (V, H) dW carry, so no (B, S, V) tensor ever exists — forward
+  OR backward.  This is what lets one 16 GB v5e train the 8-layer 3B
+  geometry at seq 8192 at all.
 * `streamed_no_remat` isolates rematerialisation: without
   `jax.checkpoint` on the layer scan the activation plan is
   {'**unplannable (exceeds HBM: ' + format(variants['streamed_no_remat'].get('needed_gb', 0), '.2f') + ' GB needed)**'

@@ -285,8 +285,8 @@ def test_fsdp_step_differs_from_the_parents_only_in_the_layer_scans(
     explicit-FSDP step (remat "full", the cells' policy) differs from the
     parent's in ``main`` (whose two layer scans stack and read the kept
     residuals), in the two layer bodies and in the kernel's forward
-    wrapper; the backward body lost its forward call; the loss head, its
-    backward and every helper are the parent's text."""
+    wrapper; the backward body lost its forward call; the loss head's
+    block and every helper are the parent's text."""
     parent = _lower_fsdp_step_for_tpu()
     monkeypatch.undo()
     change = _lower_fsdp_step_for_tpu()
@@ -310,4 +310,4 @@ def test_fsdp_step_differs_from_the_parents_only_in_the_layer_scans(
     assert (count(gone, "_splash_attention"),
             count(came, "_splash_attention")) == (2, 1)
     shared = [k for k, _ in was & now]
-    assert shared.count("closed_call") == 2      # loss head, its backward
+    assert shared.count("closed_call") == 1      # the loss head's one block

@@ -133,9 +133,9 @@ def test_synthetic_stream_deterministic_and_skewed():
     assert counts[np.argsort(counts)[-1]] > 5 * counts[counts > 0].mean()
 
 
-def test_chunked_loss_matches_dense(setup):
-    """Streamed-vocab cross-entropy == dense fp32 log-softmax, for chunk
-    sizes that do and don't divide the vocab (padding + mask path)."""
+def test_streamed_loss_matches_dense(setup):
+    """Streamed cross-entropy == dense fp32 log-softmax through the whole
+    model, for budgets that give several row blocks and one."""
     params, batch = setup
     dense = jax.jit(jax.value_and_grad(lambda p, b: T.lm_loss(p, b, CFG)))
     l0, g0 = dense(params, batch)
@@ -149,19 +149,117 @@ def test_chunked_loss_matches_dense(setup):
                                        np.asarray(b, np.float32), atol=1e-6)
 
 
-def test_chunked_softmax_xent_direct():
-    from distributed_training_sandbox_tpu.models.transformer import (
-        chunked_softmax_xent)
-    key = jax.random.PRNGKey(3)
-    x = jax.random.normal(key, (2, 8, 16))
-    w = jax.random.normal(jax.random.PRNGKey(4), (37, 16))  # odd vocab
-    labels = jax.random.randint(jax.random.PRNGKey(5), (2, 8), 0, 37)
-    logits = x @ w.T
-    want = float(jnp.mean(jax.scipy.special.logsumexp(logits, -1)
-                          - jnp.take_along_axis(logits, labels[..., None],
-                                                -1)[..., 0]))
-    got = float(chunked_softmax_xent(x, w, labels, chunk=10))
-    assert got == pytest.approx(want, abs=1e-5)
+#: (B, S, H, V, chunk, dtype) -> the rows a block the whole batch derives
+HEAD_CASES = {
+    "odd_vocab": (2, 8, 16, 37, 10, jnp.float32),        # 4 rows x 4
+    "batch_1": (1, 16, 16, 37, 37, jnp.float32),         # one block
+    "ragged_rows": (3, 14, 16, 512, 128, jnp.float32),   # 9 x 5, 3 padded
+    "tile_rows": (1, 300, 32, 512, 300, jnp.float32),    # 128 x 3, 84 padded
+    "bf16": (2, 96, 32, 512, 300, jnp.bfloat16),         # 96 x 2
+}
+
+
+def _dense_head(x, w, labels):
+    """3 * (dense float32 cross-entropy) + 1, its dx and dW."""
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    return jax.value_and_grad(
+        lambda x, w: 3 * T.xent_from_hidden(x, w, labels) + 1,
+        (0, 1))(f32(x), f32(w))
+
+
+@pytest.mark.parametrize("wrap", ["jit", "shard_map"])
+@pytest.mark.parametrize("head", ["tied", "untied"])
+@pytest.mark.parametrize("case", HEAD_CASES)
+def test_streamed_softmax_xent_matches_dense(case, head, wrap):
+    """The row-block head against the dense float32 path: loss, dx and dW
+    under a cotangent that is not 1 (``3 * loss + 1``); ``untied`` takes
+    the gradient through the transpose of an (H, V) leaf, as the
+    pipeline's last stage does; ``shard_map`` runs it on two devices'
+    halves of the sequence, each deriving its own block from local shapes
+    (the halves of ``ragged_rows`` pad too: 21 tokens in blocks of 5)."""
+    from distributed_training_sandbox_tpu.ops.collectives import smap
+    from distributed_training_sandbox_tpu.utils import make_mesh
+    from jax.sharding import PartitionSpec as P
+    B, S, H, V, chunk, dtype = HEAD_CASES[case]
+    x = jax.random.normal(jax.random.PRNGKey(3), (B, S, H)).astype(dtype)
+    w = jax.random.normal(jax.random.PRNGKey(4), (V, H)).astype(dtype)
+    labels = jax.random.randint(jax.random.PRNGKey(5), (B, S), 0, V)
+    want, (dx0, dw0) = _dense_head(x, w, labels)
+
+    leaf = w if head == "tied" else w.T
+    rows = (lambda a: a) if head == "tied" else (lambda a: a.T)
+
+    def streamed(x, leaf, labels):
+        return jax.value_and_grad(
+            lambda x, leaf: 3 * T.streamed_softmax_xent(
+                x, rows(leaf), labels, chunk) + 1, (0, 1))(x, leaf)
+
+    if wrap == "shard_map":
+        mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2],
+                         register=False)
+
+        def local(x, leaf, labels):
+            loss, (dx, dw) = streamed(x, leaf, labels)
+            return jax.lax.pmean(loss, "dp"), (
+                dx / 2, jax.lax.pmean(dw.astype(jnp.float32), "dp"))
+
+        seq = P(None, "dp")
+        fn = smap(local, mesh, in_specs=(seq, P(), seq),
+                  out_specs=(P(), (seq, P())))
+    else:
+        fn = streamed
+    got, (dx1, dw1) = jax.jit(fn)(x, leaf, labels)
+
+    assert dx1.dtype == dtype
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    assert float(got) == pytest.approx(float(want), rel=tol)
+    for a, b in ((dx0, dx1), (dw0, rows(dw1))):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(b, a, atol=tol * np.abs(a).max())
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, those of nested jaxprs included."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for x in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(x, "jaxpr", x)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+@pytest.mark.parametrize("differentiated, products", [(True, 3), (False, 1)])
+def test_streamed_head_multiplies_by_the_vocabulary(setup, differentiated,
+                                                    products):
+    """The mechanism is in the step: the gradient of ``lm_loss`` holds
+    exactly three products with the vocabulary as a dimension (logits, dx,
+    dW), the loss alone one (its primal makes no gradient), all inside the
+    block's scan; and no array of either outgrows one block of rows by the
+    vocabulary (or the (V, H) embedding and its gradient)."""
+    params, batch = setup
+    cfg = dataclasses.replace(CFG, loss_vocab_chunk=128)
+    V, H = cfg.vocab_size, cfg.hidden_size
+    tokens = batch[1].size
+    block = T._loss_row_block(tokens, V, cfg.loss_vocab_chunk)
+    assert 1 < block < tokens
+    fn = lambda p: T.lm_loss(p, batch, cfg)  # noqa: E731
+    jaxpr = jax.make_jaxpr(jax.grad(fn) if differentiated else fn)(params)
+
+    def shapes(e):
+        return [v.aval.shape for v in (*e.invars, *e.outvars)
+                if hasattr(v.aval, "shape")]
+
+    dots = [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "dot_general"
+            and any(V in s for s in shapes(e))]
+    assert len(dots) == products
+    top_level = [e for e in jaxpr.jaxpr.eqns
+                 if e.primitive.name == "dot_general"]
+    assert not any(V in s for e in top_level for s in shapes(e))
+    for e in _eqns(jaxpr.jaxpr):
+        for s in shapes(e):
+            if V in s:
+                assert np.prod(s) // V <= max(block, H), (e.primitive, s)
 
 
 @pytest.mark.parametrize("policy", ["save_attn", "save_dots"])
