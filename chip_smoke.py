@@ -288,7 +288,7 @@ def serve_phase(model_const: str, runs: Path, bitwise: bool) -> None:
 
 def paged_attention_xla(qg, pk, pv, pages, apos, probs_dtype):
     """The engine's gather-then-einsum attention core
-    (``serving.engine._paged_layer_body``), the reference both serving
+    (``serving.engine._paged_attend``), the reference both serving
     kernels replace."""
     import jax
     import jax.numpy as jnp

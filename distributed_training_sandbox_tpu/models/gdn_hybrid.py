@@ -125,6 +125,13 @@ SCAN_CHUNK = 64
 #: device, read at a burst's sync); slots whose state a grant reset; valid
 #: rows the prefill chunks scanned
 COUNTERS = ("state_slot_steps", "state_resets", "lin_scan_rows")
+DEVICE_COUNTERS = COUNTERS[:1]
+#: expert layers' counters, where a block with this mixer has them, are
+#: summed from the first layer's (every layer has them), not from zeros
+COUNTS_FROM_ZERO = False
+
+#: kinds of attention layer that are handed no rotary tables: none
+NOPE_KINDS = ()
 
 L2_EPS = 1e-6
 
@@ -178,20 +185,12 @@ def check_config(cfg) -> None:
                              f"py), which num_experts > 0 selects")
 
 
-def is_full_layer(li: int, cfg) -> bool:
-    return (li + 1) % cfg.full_attention_interval == 0
-
-
-def full_layers(cfg) -> tuple[int, ...]:
-    """Indices of the full-attention layers: the only ones with pages."""
-    return tuple(li for li in range(cfg.num_hidden_layers)
-                 if is_full_layer(li, cfg))
-
-
-def linear_layers(cfg) -> tuple[int, ...]:
-    """Indices of the linear layers: the only ones with state slots."""
-    return tuple(li for li in range(cfg.num_hidden_layers)
-                 if not is_full_layer(li, cfg))
+def layer_kinds(cfg) -> tuple[str, ...]:
+    """One entry a layer: ``"full"`` (K/V rows in whole-context pages)
+    where ``(i + 1) % full_attention_interval == 0``, else ``"linear"`` (a
+    state slot and a conv tail, no pages)."""
+    return tuple("linear" if (li + 1) % cfg.full_attention_interval
+                 else "full" for li in range(cfg.num_hidden_layers))
 
 
 def conv_channels(cfg) -> int:
@@ -251,7 +250,7 @@ def param_count(cfg) -> int:
     common = 3 * h * cfg.intermediate_size + 2 * h
     full = common + h * hd * (2 * nq + 2 * nkv) + hd * (nq + nkv)
     linear = common + linear_mixer_param_count(cfg)
-    n_full = len(full_layers(cfg))
+    n_full = layer_kinds(cfg).count("full")
     return n_full * full + (cfg.num_hidden_layers - n_full) * linear \
         + 2 * cfg.vocab_size * h + h
 
@@ -300,11 +299,11 @@ def init_params(key: jax.Array, cfg) -> dict:
 
     ones = lambda *shape: jnp.ones(shape, cfg.dtype)  # noqa: E731
 
-    def layer(li):
+    def layer(kind):
         out = {"post_attn_norm": ones(h), "post_mlp_norm": ones(h),
                "w_gate": tn((h, F)), "w_up": tn((h, F)),
                "w_down": tn((F, h), out_std)}
-        if is_full_layer(li, cfg):
+        if kind == "full":
             return {**out, "wq": tn((h, nq * hd)), "wk": tn((h, nkv * hd)),
                     "wv": tn((h, nkv * hd)), "wo": tn((nq * hd, h), out_std),
                     "q_norm": ones(nq * hd), "k_norm": ones(nkv * hd)}
@@ -312,7 +311,7 @@ def init_params(key: jax.Array, cfg) -> dict:
 
     return {
         "embed": tn((cfg.vocab_size, h)),
-        "layers": tuple(layer(li) for li in range(cfg.num_hidden_layers)),
+        "layers": tuple(layer(kind) for kind in layer_kinds(cfg)),
         "final_norm": ones(h),
         "lm_head": tn((h, cfg.vocab_size)),
     }
@@ -521,32 +520,9 @@ def linear_output(o, x, layer, *, cfg):
     return dense((y * gate).astype(x.dtype), layer["w_o"])
 
 
-# ------------------------------------------------- what a block brings
-#
-# The engine's ``_paged_hybrid_forward`` and :func:`hidden_states` run the
-# layer loop once for every block whose requests keep STATE SLOTS beside
-# pages.  They ask two modules for what differs.
-#
-# What a LINEAR MIXER brings (``cfg.linear_mixer``: this module for the
-# two gated delta-rule blocks, ``models/ssm_moe.py`` for the Mamba-2 one):
-# the layer's kind (``is_full_layer``, ``full_layers``, ``linear_layers``),
-# the state's and the tail's shapes (``state_shape``, ``slot_shape`` as
-# stored, ``tail_shape``, ``slot_state_bytes``, ``pack_state`` /
-# ``unpack_state`` between the stored layout and the scan's),
-# ``linear_inputs(r, layer, tail, valid, cfg=)`` -> the recurrence's
-# operands ``(B, S, ...)`` and the new tail, the step and the scan
-# (``recurrent_step(*operands of one row, state as stored)``,
-# ``chunked_scan(*operands, state as unpacked)``, ``step_kernel``,
-# ``step_kernel_engages(*state_shape)``) and ``COUNTERS``.
-#
-# What a BLOCK brings (``cfg.block_module``: this module,
-# ``models/gdn_moe.py`` or ``models/ssm_moe.py``): the residual path, the
-# full-attention mixer and the MLP: ``embed``, ``rope_tables``,
-# ``mixer_input``, ``attention_qkv``, ``attention_scale``,
-# ``attention_output``, ``linear_mixer_output``, ``mlp``, ``final_norm``,
-# and ``PAGED_ATTENTION_SCOPE``: the scope beneath ``attn_core`` that the
-# engine opens round the full-attention layers' paged attention
-# (``profiling.ATTENTION_SUBSCOPES``), or None for none.
+# ------------------------------------------------- what the block brings
+# (to the engine's ``_paged_block_forward``, whose docstring says what a
+# block and what a linear mixer bring, and to :func:`hidden_states`)
 
 PAGED_ATTENTION_SCOPE = None
 
@@ -570,7 +546,8 @@ def rope_tables(positions, cfg):
 
 def mixer_input(x, layer, *, cfg):
     """What a mixer reads: the residual stream itself (no norm before a
-    mixer)."""
+    mixer; in ``mla_moe`` and ``swa_moe``, whose this is too, the
+    attention's projections norm their input themselves)."""
     return x
 
 
@@ -642,8 +619,8 @@ def hidden_states(params, input_ids, cfg):
     valid = jnp.ones((B, S), jnp.bool_)
     scale = blk.attention_scale(cfg) \
         or 1.0 / math.sqrt(cfg.resolved_head_dim)
-    for li, layer in enumerate(params["layers"]):
-        if lin.is_full_layer(li, cfg):
+    for kind, layer in zip(blk.layer_kinds(cfg), params["layers"]):
+        if kind == "full":
             with scope("attn_qkv"):
                 q, k, v, gate = blk.attention_qkv(
                     blk.mixer_input(x, layer, cfg=cfg), layer, cfg=cfg,

@@ -12,8 +12,8 @@ a sigmoid-gated shared expert.  What is written here is what differs: the
 pre-norm residual path with zero-centred norms, the output-gated attention
 with per-head QK-norm and a partial rotary embedding, and the composition.
 The layer loop itself is ``gdn_hybrid.hidden_states`` and the engine's
-``_paged_hybrid_forward``, which call this module through
-``cfg.block_module`` (``gdn_hybrid``'s comment "what a block brings").
+``_paged_block_forward``, which call this module through
+``cfg.block_module`` (the engine loop's docstring: what a block brings).
 
 ``x`` is the residual stream ``(B, S, H)``; every norm is an RMSNorm of
 ``rms_norm_eps``; ZERO-CENTRED: ``norm(x; w) = x / rms(x) * (1 + w)``, ``w``
@@ -77,13 +77,14 @@ import jax.numpy as jnp
 
 from . import gdn_hybrid as G
 from . import mla_moe as M
-from .gdn_hybrid import (attention_scale, embed,  # noqa: F401
-                         hidden_states)     # (the shared layer loop)
+from .gdn_hybrid import (COUNTS_FROM_ZERO, NOPE_KINDS,  # noqa: F401
+                         attention_scale, embed, hidden_states, layer_kinds)
 
 #: what the engine counts for this block in ``stats``: the expert layers'
 #: four and the live states, summed on the device through a burst (the
 #: first five); slots reset at a grant and rows scanned, on the host
 COUNTERS = M.COUNTERS + G.COUNTERS
+DEVICE_COUNTERS = COUNTERS[:5]
 
 #: how this block's router scores an expert (``mla_moe.route``): a softmax
 #: over the router's whole width
@@ -149,7 +150,7 @@ def param_count(cfg) -> int:
         + 3 * h * Fs + h
     full = common + h * hd * (3 * nq + 2 * nkv) + 2 * hd
     linear = common + G.linear_mixer_param_count(cfg)
-    n_full = len(G.full_layers(cfg))
+    n_full = layer_kinds(cfg).count("full")
     return n_full * full + (cfg.num_hidden_layers - n_full) * linear \
         + 2 * cfg.vocab_size * h + h
 
@@ -177,14 +178,14 @@ def init_params(key: jax.Array, cfg) -> dict:
 
     zeros = lambda *shape: jnp.zeros(shape, cfg.dtype)  # noqa: E731
 
-    def layer(li):
+    def layer(kind):
         out = {"input_norm": zeros(h), "post_attn_norm": zeros(h),
                "w_router": tn((h, cfg.router_width)),
                "we_gate": tn((E, h, F)), "we_up": tn((E, h, F)),
                "we_down": tn((E, F, h), out_std),
                "ws_gate": tn((h, Fs)), "ws_up": tn((h, Fs)),
                "ws_down": tn((Fs, h), out_std), "ws_sigmoid": tn((h, 1))}
-        if G.is_full_layer(li, cfg):
+        if kind == "full":
             return {**out, "wq": tn((h, nq * 2 * hd)),
                     "wk": tn((h, nkv * hd)), "wv": tn((h, nkv * hd)),
                     "wo": tn((nq * hd, h), out_std),
@@ -193,7 +194,7 @@ def init_params(key: jax.Array, cfg) -> dict:
 
     return {
         "embed": tn((cfg.vocab_size, h)),
-        "layers": tuple(layer(li) for li in range(cfg.num_hidden_layers)),
+        "layers": tuple(layer(kind) for kind in layer_kinds(cfg)),
         "final_norm": zeros(h),
         "lm_head": tn((h, cfg.vocab_size)),
     }
@@ -209,13 +210,8 @@ def norm(x, w, cfg):
 
 def rope_tables(positions, cfg):
     """cos, sin (B, S, rot / 2) float32 of the absolute ``positions``
-    (B, S): the formula of ``transformer._rope_tables`` over the rotary
-    dims alone."""
-    rot = rotary_dim(cfg)
-    inv_freq = 1.0 / cfg.rope_theta ** (
-        jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
-    ang = positions.astype(jnp.float32)[..., None] * inv_freq
-    return jnp.cos(ang), jnp.sin(ang)
+    (B, S), over the rotary dims alone."""
+    return M.position_tables(positions, rotary_dim(cfg), cfg.rope_theta)
 
 
 def _partial_rope(x, rope, rot: int):

@@ -82,11 +82,19 @@ from jax import lax
 
 from ..ops.grouped_experts import routed_sum
 from ..utils.profiling import scope
+from .gdn_hybrid import embed, final_norm, mixer_input  # noqa: F401
 
 #: what the MLP of an expert layer counts on the device for the engine's
-#: ``stats``, in this order (``moe_counts``)
+#: ``stats``, in this order (``moe_counts``); the serving loop sums them over
+#: the layers from zeros (``COUNTS_FROM_ZERO``: a leading layer has none),
+#: and they are all this block counts
 COUNTERS = ("moe_assignments", "moe_assignments_held",
             "moe_experts_touched", "moe_expert_layer_steps")
+DEVICE_COUNTERS = COUNTERS
+COUNTS_FROM_ZERO = True
+
+#: kinds of attention layer that are handed no rotary tables: none
+NOPE_KINDS = ()
 
 
 #: how this block's router scores an expert: each its own sigmoid
@@ -145,6 +153,11 @@ def check_held_experts(cfg) -> None:
 def row_width(cfg) -> int:
     """Elements of the one row a token caches per layer: ``[c_kv | k_rope]``."""
     return cfg.kv_lora_rank + cfg.qk_rope_head_dim
+
+
+def layer_kinds(cfg) -> tuple[str, ...]:
+    """Every layer caches one latent row a token, in whole-context pages."""
+    return ("latent",) * cfg.num_hidden_layers
 
 
 def is_expert_layer(li: int, cfg) -> bool:
@@ -221,6 +234,28 @@ def init_params(key: jax.Array, cfg) -> dict:
 
 
 # -------------------------------------------------------------- attention
+
+def position_tables(positions, dim: int, theta: float):
+    """Per-BATCH rope tables: ``positions`` (B, S) int32 -> cos/sin
+    (B, S, dim / 2) float32.  Same inv_freq/angle formula as
+    ``transformer._rope_tables``, so a position's table row is bitwise
+    the one the one-shot path computes for it."""
+    inv_freq = 1.0 / theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32)
+                               / dim)
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def rope_tables(positions, cfg):
+    """The tables of the rotary dims, which queries and the shared key
+    have apart from the others."""
+    return position_tables(positions, cfg.qk_rope_head_dim, cfg.rope_theta)
+
+
+def attention_scale(cfg) -> float:
+    """What the scores are multiplied by: ``1/sqrt(nope + rope)``."""
+    return 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+
 
 def _rope(x, cos, sin):
     """Split-half rotation of the last axis of x (B, S, [n,] d);
@@ -333,9 +368,10 @@ def attend_paged(q_nope, q_rope, pool, pages, apos, layer, cfg):
     return (acc / l[..., None]).transpose(0, 2, 1, 3)
 
 
-def attention_output(o, x, layer, cfg):
+def attention_output(o, gate, x, layer, *, cfg):
     """Heads' outputs (B, S, n, v) through ``wo`` and the post-attention
-    sandwich norm, added to the residual stream."""
+    sandwich norm, added to the residual stream (``gate``: the heads'
+    output gate, which this block has not)."""
     from .transformer import _dense, rms_norm
     B, S = o.shape[:2]
     a = _dense(cfg)(o.astype(x.dtype).reshape(B, S, -1), layer["wo"])
@@ -419,13 +455,13 @@ def expert_mlp(r2, layer, *, cfg, valid=None):
     return shared + routed.astype(r2.dtype).reshape(B, S, H), counts
 
 
-def mlp(x, layer, *, cfg, expert: bool, valid=None):
+def mlp(x, layer, *, cfg, valid=None):
     """Pre-MLP norm, the layer's MLP, post-MLP sandwich norm, residual.
-    Returns the new ``x`` and, for an expert layer, its ``moe_counts``;
-    else None."""
+    Returns the new ``x`` and, for an expert layer (one that holds a
+    router), its ``moe_counts``; else None."""
     from .transformer import _dense, rms_norm
     r2 = rms_norm(x, layer["ln2"], cfg.rms_norm_eps)
-    if expert:
+    if "w_router" in layer:
         m, counts = expert_mlp(r2, layer, cfg=cfg, valid=valid)
     else:
         m, counts = _swiglu(r2, layer["w_gate"], layer["w_up"],
@@ -438,14 +474,14 @@ def mlp(x, layer, *, cfg, expert: bool, valid=None):
 def hidden_states(params, input_ids, cfg):
     """(B, S) ids -> final-norm hidden states (B, S, H): the whole
     sequence at once, materialised attention, no cache."""
-    from .transformer import _rope_tables, rms_norm
+    from .transformer import _rope_tables
     S = input_ids.shape[1]
     with scope("embed"):
-        x = params["embed"].astype(cfg.dtype)[input_ids]
+        x = embed(params, input_ids, cfg)
         cos, sin = _rope_tables(S, cfg.qk_rope_head_dim, cfg.rope_theta)
     causal = jnp.tril(jnp.ones((S, S), jnp.bool_))
 
-    for li, layer in enumerate(params["layers"]):
+    for layer in params["layers"]:
         with scope("attn_qkv"):
             q_nope, q_rope, rows = latent_qkv(x, layer, cfg=cfg, cos=cos,
                                               sin=sin)
@@ -455,8 +491,8 @@ def hidden_states(params, input_ids, cfg):
             o = jnp.einsum("bnsk,bkne->bsne", p.astype(v.dtype), v,
                            preferred_element_type=jnp.float32)
         with scope("attn_out"):
-            x = attention_output(o, x, layer, cfg)
+            x = attention_output(o, None, x, layer, cfg=cfg)
         with scope("mlp"):
-            x, _ = mlp(x, layer, cfg=cfg, expert=is_expert_layer(li, cfg))
+            x, _ = mlp(x, layer, cfg=cfg)
     with scope("loss_head"):
-        return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        return final_norm(x, params, cfg)

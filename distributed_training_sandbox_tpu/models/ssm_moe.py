@@ -10,13 +10,13 @@ experts' part of the routed sum as PR 39's grouped product, the shared
 expert, the counters) is ``models/mla_moe.py``'s ``expert_mlp`` with a
 softmax router; the depthwise causal conv and its tail are
 ``models/gdn_hybrid.py``'s ``causal_conv``; the layer loop is the engine's
-``_paged_hybrid_forward`` and ``gdn_hybrid.hidden_states``, which reach
-this module as ``cfg.block_module`` and ``cfg.linear_mixer`` (``gdn_hybrid``'s
-comment "what a linear mixer brings").  Written here: the Mamba-2 mixer
-(projections, the conv's bias, softplus ``dt``, the recurrence's two forms,
-``D``, the gated norm), attention at the scale ``attention_multiplier``
-with no rotary embedding, the multipliers, the tied scaled head, and the
-layer kinds from ``layer_types``.
+``_paged_block_forward`` and ``gdn_hybrid.hidden_states``, which reach
+this module as ``cfg.block_module`` and ``cfg.linear_mixer`` (the engine
+loop's docstring: what a block and what a linear mixer bring).  Written
+here: the Mamba-2 mixer (projections, the conv's bias, softplus ``dt``, the
+recurrence's two forms, ``D``, the gated norm), attention at the scale
+``attention_multiplier`` with no rotary embedding, the multipliers, the
+tied scaled head, and the layer kinds from ``layer_types``.
 
 ``x`` is the residual stream ``(B, S, H)``; every norm a plain RMSNorm
 (``x / rms(x) * w``, ``w`` initialised 1) of ``rms_norm_eps``; no bias but
@@ -109,7 +109,8 @@ from jax import lax
 from ..utils.profiling import scope
 from . import mla_moe as M
 from .gdn_hybrid import COUNTERS as _STATE_COUNTERS
-from .gdn_hybrid import causal_conv, hidden_states  # noqa: F401
+from .gdn_hybrid import (COUNTS_FROM_ZERO, NOPE_KINDS,  # noqa: F401
+                         causal_conv, hidden_states)
 
 #: the published ``mamba_chunk_size``, which ``check_config`` holds a config
 #: to, and the rows of one block of the chunked scan (arithmetic, not a
@@ -121,6 +122,7 @@ SCAN_BLOCK = MAMBA_CHUNK_SIZE
 #: four and the live states, summed on the device through a burst (the
 #: first five); slots reset at a grant and rows scanned, on the host
 COUNTERS = M.COUNTERS + _STATE_COUNTERS
+DEVICE_COUNTERS = COUNTERS[:5]
 
 #: how this block's router scores an expert (``mla_moe.route``)
 ROUTER_SCORING = "softmax"
@@ -133,8 +135,8 @@ PAGED_ATTENTION_SCOPE = "attn_paged"
 def refuse(cfg, what: str):
     raise NotImplementedError(
         f"the Mamba-2 + attention block with held experts (mamba_d_state="
-        f"{cfg.mamba_d_state}, {len(full_layers(cfg))} attention layers of "
-        f"{cfg.num_hidden_layers}, num_local_experts="
+        f"{cfg.mamba_d_state}, {layer_kinds(cfg).count('full')} attention "
+        f"layers of {cfg.num_hidden_layers}, num_local_experts="
         f"{cfg.num_local_experts} of {cfg.router_width} held) is served by "
         f"serving/engine.py and run cache-less by models/transformer."
         f"forward only; {what} is not built for it (ROADMAP: mechanisms "
@@ -183,22 +185,14 @@ def check_config(cfg) -> None:
                              f"{getattr(cfg, key)!r}")
 
 
-# ------------------------------------------------- what a linear mixer brings
+# --------------------------------------------- what the linear mixer brings
 
-def is_full_layer(li: int, cfg) -> bool:
-    return cfg.layer_types[li] == "attention"
-
-
-def full_layers(cfg) -> tuple[int, ...]:
-    """Indices of the attention layers: the only ones with pages."""
-    return tuple(li for li in range(cfg.num_hidden_layers)
-                 if is_full_layer(li, cfg))
-
-
-def linear_layers(cfg) -> tuple[int, ...]:
-    """Indices of the Mamba-2 layers: the only ones with state slots."""
-    return tuple(li for li in range(cfg.num_hidden_layers)
-                 if not is_full_layer(li, cfg))
+def layer_kinds(cfg) -> tuple[str, ...]:
+    """One entry a layer: ``"full"`` (K/V rows in whole-context pages)
+    where ``layer_types`` says "attention", else ``"linear"`` (a Mamba-2
+    layer: a state slot and a conv tail, no pages)."""
+    return tuple("full" if t == "attention" else "linear"
+                 for t in cfg.layer_types[:cfg.num_hidden_layers])
 
 
 def inner_width(cfg) -> int:
@@ -260,7 +254,7 @@ def param_count(cfg) -> int:
         + 3 * h * cfg.shared_intermediate_size
     attn = common + h * hd * (2 * nq + 2 * nkv)
     mamba = common + mamba_mixer_param_count(cfg)
-    n_attn = len(full_layers(cfg))
+    n_attn = layer_kinds(cfg).count("full")
     return n_attn * attn + (cfg.num_hidden_layers - n_attn) * mamba \
         + cfg.vocab_size * h + h
 
@@ -299,14 +293,14 @@ def init_params(key: jax.Array, cfg) -> dict:
 
     ones = lambda *shape: jnp.ones(shape, cfg.dtype)  # noqa: E731
 
-    def layer(li):
+    def layer(kind):
         out = {"input_norm": ones(h), "post_attn_norm": ones(h),
                "w_router": tn((h, cfg.router_width)),
                "we_gate": tn((E, h, F)), "we_up": tn((E, h, F)),
                "we_down": tn((E, F, h), out_std),
                "ws_gate": tn((h, Fs)), "ws_up": tn((h, Fs)),
                "ws_down": tn((Fs, h), out_std)}
-        if is_full_layer(li, cfg):
+        if kind == "full":
             return {**out, "wq": tn((h, nq * hd)), "wk": tn((h, nkv * hd)),
                     "wv": tn((h, nkv * hd)), "wo": tn((nq * hd, h), out_std)}
         dt = jnp.exp(uniform((n,), math.log(1e-3), math.log(1e-1)))
@@ -324,7 +318,7 @@ def init_params(key: jax.Array, cfg) -> dict:
 
     return {
         "embed": tn((cfg.vocab_size, h), 0.02 / cfg.embedding_multiplier),
-        "layers": tuple(layer(li) for li in range(cfg.num_hidden_layers)),
+        "layers": tuple(layer(kind) for kind in layer_kinds(cfg)),
         "final_norm": ones(h),
     }
 

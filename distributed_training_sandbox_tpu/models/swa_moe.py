@@ -64,7 +64,10 @@ import jax.numpy as jnp
 
 from ..utils.profiling import scope
 from . import mla_moe as M
+from .gdn_hybrid import attention_scale  # noqa: F401  (None: 1/sqrt(hd))
 from .gdn_moe import gate_heads
+from .mla_moe import (COUNTS_FROM_ZERO, final_norm,  # noqa: F401
+                      mixer_input, mlp)
 
 #: what the engine counts for this block in ``stats``.  The first six are
 #: summed on the device through a burst (``engine.device_counters``): the
@@ -86,6 +89,12 @@ ROUTER_SCORING = "sigmoid"
 #: names it, the window layers' apart (``profiling.WINDOW_SUBSCOPES``)
 PAGED_ATTENTION_SCOPE = "attn_paged"
 WINDOW_ATTENTION_SCOPE = "attn_window"
+
+#: the window layers' rotary tables are the serving engine's own, over the
+#: whole head at ``rope_theta`` as the dense block's (None: the caller's),
+#: and a full layer is handed none
+rope_tables = None
+NOPE_KINDS = ("full",)
 
 
 def refuse(cfg, what: str):
@@ -129,13 +138,12 @@ def check_config(cfg) -> None:
                              f"{getattr(cfg, key)!r}")
 
 
-def is_window_layer(li: int, cfg) -> bool:
-    return (li + 1) % cfg.global_attn_every_n_layers != 0
-
-
-def window_layers(cfg) -> list[int]:
-    return [li for li in range(cfg.num_hidden_layers)
-            if is_window_layer(li, cfg)]
+def layer_kinds(cfg) -> tuple[str, ...]:
+    """One entry a layer: ``"full"`` (K/V rows in whole-context pages)
+    where ``(i + 1) % global_attn_every_n_layers == 0``, else ``"window"``
+    (K/V rows in a ring of the window page class)."""
+    return tuple("window" if (li + 1) % cfg.global_attn_every_n_layers
+                 else "full" for li in range(cfg.num_hidden_layers))
 
 
 def is_expert_layer(li: int, cfg) -> bool:
@@ -240,13 +248,8 @@ def attention_output(attn, gate, x, layer, *, cfg):
     """The heads' outputs ``attn`` (B, S, ..heads.., hd) float32, each
     gated by ``sigmoid(gate)``, through ``wo`` and the post-attention
     sandwich norm onto the residual stream."""
-    return M.attention_output(gate_heads(attn, gate), x, layer, cfg)
-
-
-def mlp(x, layer, *, cfg, li: int, valid=None):
-    """``mla_moe.mlp`` of layer ``li``: dense or expert by its index."""
-    return M.mlp(x, layer, cfg=cfg, expert=is_expert_layer(li, cfg),
-                 valid=valid)
+    return M.attention_output(gate_heads(attn, gate), None, x, layer,
+                              cfg=cfg)
 
 
 # ------------------------------------------------- the cache-less forward
@@ -255,7 +258,7 @@ def hidden_states(params, input_ids, cfg):
     """(B, S) ids -> final-norm hidden states (B, S, H): the whole
     sequence at once, materialised attention under a causal mask, banded
     in the window layers; no cache, no ring."""
-    from .transformer import _rope_tables, rms_norm
+    from .transformer import _rope_tables
     S = input_ids.shape[1]
     hd = cfg.resolved_head_dim
     rep = cfg.num_attention_heads // cfg.num_key_value_heads
@@ -266,8 +269,8 @@ def hidden_states(params, input_ids, cfg):
     causal = t[None, :] <= t[:, None]
     band = jnp.logical_and(causal, t[None, :] > t[:, None] - cfg.sliding_window)
 
-    for li, layer in enumerate(params["layers"]):
-        window = is_window_layer(li, cfg)
+    for kind, layer in zip(layer_kinds(cfg), params["layers"]):
+        window = kind == "window"
         with scope("attn_qkv"):
             q, k, v, gate = attention_qkv(x, layer, cfg=cfg,
                                           rope=rope if window else None)
@@ -283,6 +286,6 @@ def hidden_states(params, input_ids, cfg):
         with scope("attn_out"):
             x = attention_output(o, gate, x, layer, cfg=cfg)
         with scope("mlp"):
-            x, _ = mlp(x, layer, cfg=cfg, li=li)
+            x, _ = mlp(x, layer, cfg=cfg)
     with scope("loss_head"):
-        return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        return final_norm(x, params, cfg)

@@ -336,10 +336,10 @@ class TransformerConfig:
     @property
     def linear_mixer(self):
         """The module that holds the linear mixer of a block whose
-        requests keep STATE SLOTS beside pages (``gdn_hybrid``'s comment
-        "what a linear mixer brings": the layer's kind, the state's and the
-        tail's shapes, the step and the scan); None for a block whose every
-        layer is paged."""
+        requests keep STATE SLOTS beside pages (what a linear mixer
+        brings: ``serving/engine._paged_block_forward``'s docstring; the
+        state's and the tail's shapes, the step and the scan); None for a
+        block whose every layer is paged."""
         if self.gdn_hybrid:
             from . import gdn_hybrid
             return gdn_hybrid
@@ -365,7 +365,9 @@ class TransformerConfig:
     def block_module(self):
         """The module that holds a block other than the dense GQA one
         (``check_config``, ``init_params``, ``param_count``,
-        ``hidden_states``, ``refuse``); None for the dense block."""
+        ``hidden_states``, ``refuse``, and for the serving loop
+        ``layer_kinds`` and what ``serving/engine._paged_block_forward``'s
+        docstring lists); None for the dense block."""
         if self.mla_moe:
             from . import mla_moe
             return mla_moe

@@ -2,7 +2,7 @@
 
 The serving engine's prefill attends a whole chunk of S query rows
 against the slot's visible KV window.  The reference path
-(``serving/engine._paged_layer_body``) gathers the slot's WHOLE page
+(``serving/engine._paged_attend``) gathers the slot's WHOLE page
 table into a contiguous ``(B, V, n_kv, hd)`` HBM view and runs two
 einsums with a full float32 ``(B, n_kv, rep, S, V)`` score tensor in
 between, whatever part of the view the chunk can see.

@@ -1,6 +1,6 @@
 """Paged-attention decode kernels (Pallas).
 
-``serving/engine._paged_layer_body`` attends against the paged KV pool
+``serving/engine._paged_attend`` attends against the paged KV pool
 by first gathering every slot's pages into a contiguous
 ``(B, V, n_kv, hd)`` HBM view (``pk[pages]``) and then contracting over
 it.  That gather is pure data movement: for a decode step (S == 1) it
@@ -208,7 +208,7 @@ def _decode_kernel(len_ref, pages_ref, *refs, table_pages: int,
 
 def _decode_kernel_q8(pages_ref, q_ref, qs_ref, apos_ref, pk_ref, pv_ref,
                       pks_ref, pvs_ref, o_ref, *, n_slot_pages: int):
-    """int8 pool: the quantized `_paged_layer_body` attention core with
+    """int8 pool: the quantized `_paged_attend` attention core with
     the per-page K/V scales folded in-kernel (scale-fold order matches
     the reference exactly for bitwise parity)."""
     from .quant import quantize_int8

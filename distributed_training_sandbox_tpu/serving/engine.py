@@ -58,7 +58,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models import gdn_hybrid as G
 from ..models import mla_moe as M
 from ..models import transformer as T
 from ..models.generate import _decode_cfg, _quant_kv
@@ -66,7 +65,7 @@ from ..ops import collectives as C
 from ..telemetry.spans import maybe_span
 from ..utils.profiling import scope
 from .kv_pool import (PagedKVPool, PoolBuffers, RadixPrefixCache,
-                      ring_pages, ring_view, row_layout, slab_pool)
+                      layer_kinds, ring_pages, ring_view)
 from .scheduler import ContinuousBatcher, DECODE, PREFILL, Request
 
 __all__ = ["ServingEngine", "serve", "make_serve_decode_step",
@@ -76,28 +75,9 @@ __all__ = ["ServingEngine", "serve", "make_serve_decode_step",
 
 # ---------------------------------------------------------------- layer math
 
-def _ragged_rope_tables(positions, head_dim: int, theta: float):
-    """Per-BATCH rope tables: ``positions`` (B, S) int32 → cos/sin
-    (B, S, hd/2) f32.  Same inv_freq/angle formula as
-    ``transformer._rope_tables`` so a position's table row is bitwise
-    the one the one-shot path computes for it."""
-    inv_freq = 1.0 / theta ** (jnp.arange(0, head_dim, 2,
-                                          dtype=jnp.float32) / head_dim)
-    ang = positions.astype(jnp.float32)[..., None] * inv_freq
-    return jnp.cos(ang), jnp.sin(ang)
-
-
-def _apply_rope_ragged(x, cos, sin):
-    """``transformer.apply_rope`` with per-batch tables: x (B, S, n, hd),
-    cos/sin (B, S, hd/2) — identical split-half rotation, broadcast over
-    heads instead of batch."""
-    dt = x.dtype
-    x = x.astype(jnp.float32)
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    c = cos[:, :, None, :]
-    s = sin[:, :, None, :]
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
-                           axis=-1).astype(dt)
+# per-batch rope tables, positions (B, S) -> cos/sin (B, S, hd/2): the
+# dense block's, and a block module's that declares none of its own
+_ragged_rope_tables = M.position_tables
 
 
 @contextlib.contextmanager
@@ -130,8 +110,8 @@ def _paged_layer_body(x, layer, *, cfg, cos, sin, use_rope, pk, pv,
         q = dense(r, layer["wq"]).reshape(B, S, nq, hd)
         k = dense(r, layer["wk"]).reshape(B, S, nkv, hd)
         v = dense(r, layer["wv"]).reshape(B, S, nkv, hd)
-        q = jnp.where(use_rope, _apply_rope_ragged(q, cos, sin), q)
-        k = jnp.where(use_rope, _apply_rope_ragged(k, cos, sin), k)
+        q = jnp.where(use_rope, M._rope(q, cos, sin), q)
+        k = jnp.where(use_rope, M._rope(k, cos, sin), k)
 
     attn, pools = _paged_attend(
         q, k, v, dtype=x.dtype, pk=pk, pv=pv, pk_s=pk_s, pv_s=pv_s,
@@ -173,7 +153,7 @@ def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
 
     q (B, S, nq, hd), k, v (B, S, nkv, hd) in ``dtype``; the pools
     (n_pages, page, nkv, hd) (+ scales of an int8 pool), or with ``slab``
-    (the caller's ``kv_pool.slab_pool(cfg)``) stored as the kernels' slab
+    (a pool as ``kv_pool.slab_pool`` has it stored) as the kernels' slab
     (n_pages, page * nkv, hd): a float pool's row ``w`` of a page is token
     ``w // nkv``, head ``w % nkv``; pages (B, P);
     apos, valid (B, S).  ``kernel_scope`` names one more scope beneath
@@ -314,48 +294,31 @@ def _paged_attend(q, k, v, *, dtype, pk, pv, pk_s, pv_s, pages, apos,
     return attn, (pk, pv, pk_s, pv_s)
 
 
-def _paged_latent_layer_body(x, layer, *, cfg, cos, sin, pool, pages,
-                             apos, valid, expert: bool,
-                             paged_kernel=False):
-    """One layer of the latent-attention + held-experts block
-    (``models/mla_moe.py`` holds its pieces) against the paged pool of
-    latent rows, under the catalogue's scopes:
+def _paged_latent_attend(q, rows, layer, *, cfg, dtype, pool, pages, apos,
+                         valid, in_kernel=False):
+    """:func:`_paged_attend` for a ``"latent"`` layer: the new cache rows
+    into their pages, then attention of the rows at ``apos`` against their
+    slots' pages, under the same scopes:
 
-      * ``attn_qkv``: both down-projections, their norms, the queries'
-        up-projection, RoPE, and at decode the absorption of ``w_uk``
-        into the queries;
       * ``kv_write``: ONE row ``[c_kv | k_rope]`` a token scatters into
         its page (zero-padded to the pool's row); invalid rows divert to
-        the null page 0 as in the dense body;
-      * ``attn_core``: a decode step (S == 1) with ``paged_kernel`` runs
-        the ABSORBED form in the Pallas kernel, which reads the slot's
-        live pages in place and never up-projects them.  Otherwise
-        (prefill chunks; decode off the chip) the MATERIALISED form:
-        cached rows are up-projected to keys and values a block of pages
-        at a time, up to the last position a row can see.  Prefill is
-        materialised because it costs 320 multiply-adds a head and key
-        against the absorbed form's 1,088, and up-projecting a key once
-        serves all of a chunk's rows;
-      * ``attn_out``: at decode ``o~ . w_uv``, then ``wo`` and the
-        post-attention norm; ``mlp``: both MLP norms and the MLP, its
-        routing, held experts and shared expert under ``moe_route``,
-        ``moe_experts``, ``moe_shared`` (``profiling.SUBSCOPES``);
-        ``moe_experts`` is the plan that sorts the routing's (row, held
-        expert) pairs by expert and the grouped product over them, one
-        Mosaic call that visits touched experts only and adds each
-        pair onto its row (``ops/grouped_experts.py``).
+        the null page 0 as K/V rows do;
+      * ``attn_core``: a decode step ``in_kernel`` runs the ABSORBED form
+        in the Pallas kernel, which reads the slot's live pages in place
+        and never up-projects them: ``q`` is then the absorbed queries
+        (B, 1, n, rank + rope) and what returns the probabilities' sum of
+        latents.  Otherwise (prefill chunks; decode off the chip) the
+        MATERIALISED form of the block's ``attend_paged``: ``q`` is the
+        pair ``(q_nope, q_rope)`` and cached rows are up-projected to keys
+        and values a block of pages at a time, up to the last position a
+        row can see.  Prefill is materialised because it costs 320
+        multiply-adds a head and key against the absorbed form's 1,088,
+        and up-projecting a key once serves all of a chunk's rows.
 
-    x (B, S, H); pool (n_pages, page, W); pages (B, P); apos, valid
-    (B, S).  Returns ``(x', pool', counts)``; ``counts`` is
-    ``mla_moe.moe_counts`` of the valid rows for an expert layer, else
-    None."""
+    rows (B, S, rank + rope); pool (n_pages, page, W); pages (B, P); apos,
+    valid (B, S).  Returns the heads' outputs float32 and the pool."""
+    blk = cfg.block_module
     page, P = pool.shape[1], pages.shape[1]
-    in_kernel = paged_kernel and x.shape[1] == 1
-    with scope("attn_qkv"):
-        q_nope, q_rope, rows = M.latent_qkv(x, layer, cfg=cfg, cos=cos,
-                                            sin=sin)
-        if in_kernel:
-            qa = M.absorb_queries(q_nope, q_rope, layer)
     with scope("kv_write"):
         pi = jnp.clip(apos // page, 0, P - 1)
         pg = jnp.where(valid, jnp.take_along_axis(pages, pi, axis=1), 0)
@@ -365,69 +328,48 @@ def _paged_latent_layer_body(x, layer, *, cfg, cos, sin, pool, pages,
     with scope("attn_core"):
         if in_kernel:
             from ..ops.paged_attention import paged_latent_attention_decode
-            o_lat = paged_latent_attention_decode(
-                qa[:, 0], pool, pages,
+            o = paged_latent_attention_decode(
+                q[:, 0], pool, pages,
                 jnp.where(valid[:, 0], apos[:, 0] + 1, 0),
-                rank=cfg.kv_lora_rank, probs_dtype=x.dtype,
-                scale=1.0 / math.sqrt(cfg.qk_nope_head_dim
-                                      + cfg.qk_rope_head_dim))[:, None]
+                rank=cfg.kv_lora_rank, probs_dtype=dtype,
+                scale=blk.attention_scale(cfg))[:, None]
         else:
-            o = M.attend_paged(q_nope, q_rope, pool, pages, apos, layer,
-                               cfg)
-    with scope("attn_out"):
-        if in_kernel:
-            o = M.unabsorb_values(o_lat, layer, x.dtype)
-        h = M.attention_output(o, x, layer, cfg)
-    with scope("mlp"):
-        out, counts = M.mlp(h, layer, cfg=cfg, expert=expert, valid=valid)
-    return out, pool, counts
+            o = blk.attend_paged(*q, pool, pages, apos, layer, cfg)
+    return o, pool
 
 
-def _paged_latent_forward(params, ids, cfg, bufs: PoolBuffers, pages,
-                          apos, valid, paged_kernel=False):
-    """``_paged_forward`` for the latent block: ``params["layers"]`` is
-    a tuple of per-layer dicts (dense ones, then expert ones; nothing
-    stacked), one pool of rows a layer, and the expert layers' counters
-    summed over the layers (int32 (4,), ``mla_moe.COUNTERS``)."""
-    with scope("embed"):
-        x = params["embed"].astype(cfg.dtype)[ids]
-        cos, sin = _ragged_rope_tables(apos, cfg.qk_rope_head_dim,
-                                       cfg.rope_theta)
-    pools = list(bufs.k)
-    total = jnp.zeros((len(M.COUNTERS),), jnp.int32)
-    for li, layer in enumerate(params["layers"]):
-        x, pools[li], counts = _paged_latent_layer_body(
-            x, layer, cfg=cfg, cos=cos, sin=sin, pool=pools[li],
-            pages=pages, apos=apos, valid=valid,
-            expert=M.is_expert_layer(li, cfg), paged_kernel=paged_kernel)
-        if counts is not None:
-            total = total + counts
-    return x, bufs._replace(k=tuple(pools)), total
+def _paged_block_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
+                         valid, paged_kernel=False, slot=None):
+    """``_paged_forward`` for every block that is a module of its own
+    (``cfg.block_module``): ONE loop over the block's declared
+    ``layer_kinds(cfg)``, which per layer runs ``qkv -> store and attend ->
+    mixer output -> mlp`` and asks the module for what differs.
+    ``params["layers"]`` is a tuple of per-layer dicts (nothing stacked);
+    ``bufs.k[p]`` / ``bufs.v[p]`` are the pools of the ``p``-th PAGED layer
+    and ``bufs.state[j]`` / ``bufs.conv[j]`` the slots of the ``j``-th
+    linear one.  By kind:
 
+    ``"full"``: K/V rows in whole-context pages through
+    :func:`_paged_attend`, the dense block's storage and kernels: written
+    at ``table[p // page]``, read in order from position 0; over a row
+    that may end in zero heads and a pool that may be stored as the
+    kernels' slab (``kv_pool.padded_kv_heads``, ``slab_pool``: both read
+    off the pool array).
 
-def _paged_hybrid_forward(params, ids, cfg, bufs: PoolBuffers, pages,
-                          apos, valid, paged_kernel=False, slot=None):
-    """``_paged_forward`` for the blocks whose requests keep STATE SLOTS
-    beside pages: linear layers (gated delta-rule or Mamba-2) and
-    full-attention layers in one stack.  One loop serves the three of
-    them and asks two modules for what differs (``gdn_hybrid``'s comment
-    "what a block brings"): ``cfg.linear_mixer`` (``models/gdn_hybrid.py``
-    for the two delta-rule blocks, ``models/ssm_moe.py`` for the Mamba-2
-    one) for the layer's kind, the state's and the tail's shapes, the step
-    and the scan; ``cfg.block_module`` (``gdn_hybrid``, ``gdn_moe`` or
-    ``ssm_moe``) for the residual path, the full-attention mixer's
-    projections, scale and output, and the MLP.  ``params["layers"]`` is a
-    tuple of per-layer dicts of two kinds, and so is the per-request state.
+    ``"window"``: the same, through the second table: ``pages`` is then a
+    PAIR, one a page class (``kv_pool``), ``(full (B, P), ring (B, R))``.
+    The layer writes into its slot's ring, ``ring[(p // page) % R]``, and
+    reads ``(p - sliding_window, p]`` through the ordered view
+    ``kv_pool.ring_view`` rotates out of the ring once a launch, with a
+    lower bound.
 
-    A FULL-ATTENTION layer caches K/V rows in its own page pools
-    (``bufs.k[f]``/``bufs.v[f]``, ``f`` counting the full layers only)
-    through :func:`_paged_attend`: the dense block's storage and kernels,
-    over a row that may end in zero heads (``kv_pool.padded_kv_heads``).
+    ``"latent"``: one row a token and no V pool, through
+    :func:`_paged_latent_attend`; a decode step in the kernel absorbs
+    ``w_uk`` into the queries before it and applies ``w_uv`` after.
 
-    A LINEAR layer reads and writes its STATE SLOTS ``bufs.state[j]``
-    (n_slots,) + ``slot_shape`` float32, lane-dense as stored, and
-    ``bufs.conv[j]`` (n_slots, K - 1, C), ``j`` counting the linear
-    layers only:
+    ``"linear"``: no pages; the layer reads and writes its STATE SLOTS
+    ``bufs.state[j]`` (n_slots,) + ``slot_shape`` float32, lane-dense as
+    stored, and ``bufs.conv[j]`` (n_slots, K - 1, C):
 
       * a decode step (``slot`` None; row ``b`` of x IS slot ``b``) runs
         the mixer's ``recurrent_step`` on the slots as they are stored, in
@@ -444,55 +386,83 @@ def _paged_hybrid_forward(params, ids, cfg, bufs: PoolBuffers, pages,
         ``chunked_scan`` over the chunk and writes both back; rows past the
         prompt's end change neither.
 
-    Under the catalogue's scopes: ``attn_qkv`` (a pre-mixer norm,
-    projections, conv, SiLU, the norms of q and k, rotary embedding, the
-    recurrence's gates; the conv under ``lin_conv``), ``kv_write``,
-    ``attn_core`` (attention, for the blocks with expert layers under
-    ``attn_paged``; the scan under ``lin_scan``, the step under
-    ``lin_step``), ``attn_out`` (gates, ``w_o`` / ``wo``, a post-mixer
-    norm), ``mlp`` (an expert layer under ``moe_route`` / ``moe_experts``
-    / ``moe_shared``).  Returns ``(x', bufs', counts)``; ``counts`` is
-    int32: the expert layers' ``mla_moe.moe_counts`` summed, where the
-    block has them, and then the rows of this call whose state was live, a
-    decode step's ``state_slot_steps``."""
+    What a BLOCK brings (``cfg.block_module``; ``tests/test_block_seam.py``
+    holds every module to the list): ``layer_kinds``; the residual path,
+    the attention mixer and the MLP: ``embed(params, ids, cfg)``,
+    ``rope_tables(apos, cfg)`` (or None for this module's own tables over
+    the whole head, the dense block's) and ``NOPE_KINDS``, the kinds whose
+    layers are handed no tables; ``mixer_input(x, layer, cfg=)``;
+    ``attention_qkv(r, layer, cfg=, rope=)`` -> ``q, k, v, gate``;
+    ``attention_scale(cfg)`` (None: the kernels' and the gather path's own
+    ``1/sqrt(head_dim)``); ``attention_output(attn, gate, x, layer, cfg=)``
+    -> ``h``; where it has linear layers ``linear_mixer_output(o, r, x,
+    layer, cfg=)``; ``mlp(h, layer, cfg=, valid=)`` -> the new ``x`` and the
+    layer's counters or None; ``final_norm(x, params, cfg)``
+    (:func:`_all_logits`); ``PAGED_ATTENTION_SCOPE`` and, with window
+    layers, ``WINDOW_ATTENTION_SCOPE``: the scope beneath ``attn_core``
+    round a layer's paged attention (``profiling.ATTENTION_SUBSCOPES``), or
+    None for none; ``COUNTERS`` (every name the engine counts for the block
+    in ``stats``), ``DEVICE_COUNTERS`` (those this loop returns, in order)
+    and ``COUNTS_FROM_ZERO``.  With latent layers also ``latent_qkv``,
+    ``absorb_queries``, ``attend_paged``, ``unabsorb_values`` and
+    ``row_width`` (the pool's).
+
+    What a LINEAR MIXER brings (``cfg.linear_mixer``:
+    ``models/gdn_hybrid.py`` for the two gated delta-rule blocks,
+    ``models/ssm_moe.py`` for the Mamba-2 one): the state's and the tail's
+    shapes (``state_shape``, ``slot_shape`` as stored, ``tail_shape``,
+    ``slot_state_bytes``, ``pack_state`` / ``unpack_state`` between the
+    stored layout and the scan's), ``linear_inputs(r, layer, tail, valid,
+    cfg=)`` -> the recurrence's operands ``(B, S, ...)`` and the new tail,
+    the step and the scan (``recurrent_step(*operands of one row, state as
+    stored)``, ``chunked_scan(*operands, state as unpacked)``,
+    ``step_kernel``, ``step_kernel_engages(*state_shape)``) and
+    ``COUNTERS``.
+
+    Under the catalogue's scopes: ``embed``; ``attn_qkv`` (norms,
+    projections, rotary embedding; a latent layer's down- and
+    up-projections and at decode the absorption; a linear layer's conv under
+    ``lin_conv``, SiLU and the recurrence's gates), ``kv_write``,
+    ``attn_core`` (attention, for a block that names it under
+    ``attn_paged`` / ``attn_window``; the scan under ``lin_scan``, the step
+    under ``lin_step``), ``attn_out`` (gates, ``w_uv`` at a latent decode,
+    ``wo`` / ``w_o``, a post-mixer norm), ``mlp`` (both MLP norms and the
+    MLP; an expert layer's routing, held experts and shared expert under
+    ``moe_route`` / ``moe_experts`` / ``moe_shared``
+    (``profiling.SUBSCOPES``): ``moe_experts`` is the plan that sorts the
+    routing's (row, held expert) pairs by expert and the grouped product
+    over them, one Mosaic call that visits touched experts only
+    (``ops/grouped_experts.py``)).
+
+    Returns ``(x', bufs', counts)``; ``counts`` is int32, the block's
+    ``DEVICE_COUNTERS``: the expert layers' ``mla_moe.moe_counts`` summed
+    over the layers, where the block has them (from zeros or from the first
+    layer's, as the block's accepted programs do: ``COUNTS_FROM_ZERO``);
+    with linear layers the rows of this call whose state was live, a decode
+    step's ``state_slot_steps``; with window layers the cached rows the
+    valid rows of this call read in ONE window layer and in ONE full layer
+    (``window_rows_read``, ``full_rows_read``)."""
     blk, lin = cfg.block_module, cfg.linear_mixer
+    kinds = blk.layer_kinds(cfg)
     decode = slot is None
+    S = ids.shape[1]
+    full, ring = pages if "window" in kinds else (pages, None)
     with scope("embed"):
         x = blk.embed(params, ids, cfg)
-        rope = blk.rope_tables(apos, cfg)
-    B, S, _ = x.shape
-    ks, vs = list(bufs.k), list(bufs.v)
-    states, tails = list(bufs.state), list(bufs.conv)
-    fresh = None if decode else apos[0, 0] == 0
-    moe = None      # the expert layers' counters, where the block has them
-    f = j = 0
-    for li, layer in enumerate(params["layers"]):
-        if lin.is_full_layer(li, cfg):
-            with scope("attn_qkv"):
-                q, k, v, gate = blk.attention_qkv(
-                    blk.mixer_input(x, layer, cfg=cfg), layer, cfg=cfg,
-                    rope=rope)
-                # the pool's row may hold zero heads after the real ones
-                # (kv_pool.padded_kv_heads): their keys and values are 0,
-                # their queries' outputs are dropped
-                nkv, rep = k.shape[2], q.shape[2] // k.shape[2]
-                extra = row_layout(cfg)[0][0] - nkv
-                if extra:
-                    k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, extra), (0, 0)))
-                            for a in (k, v))
-                    q = jnp.pad(q, ((0, 0), (0, 0), (0, extra * rep),
-                                    (0, 0)))
-            attn, (ks[f], vs[f], _, _) = _paged_attend(
-                q, k, v, dtype=x.dtype, pk=ks[f], pv=vs[f], pk_s=None,
-                pv_s=None, pages=pages, apos=apos, valid=valid,
-                paged_kernel=paged_kernel,
-                kernel_scope=blk.PAGED_ATTENTION_SCOPE, slab=slab_pool(cfg),
-                scale=blk.attention_scale(cfg))
-            with scope("attn_out"):
-                h = blk.attention_output(attn[:, :, :nkv], gate, x, layer,
-                                         cfg=cfg)
-            f += 1
-        else:
+        rope = blk.rope_tables(apos, cfg) if blk.rope_tables \
+            else _ragged_rope_tables(apos, cfg.resolved_head_dim,
+                                     cfg.rope_theta)
+        if ring is not None:    # window pools are 4-D: dim 1 is the page
+            view = ring_view(ring, apos, cfg.sliding_window,
+                             bufs.k[0].shape[1])
+    ks, vs, states, tails = (list(t or ()) for t in (
+        bufs.k, bufs.v, bufs.state, bufs.conv))
+    fresh = apos[0, 0] == 0 if states and not decode else None
+    moe = jnp.zeros((len(M.COUNTERS),), jnp.int32) \
+        if blk.COUNTS_FROM_ZERO else None
+    p = j = 0
+    for kind, layer in zip(kinds, params["layers"]):
+        if kind == "linear":
             if decode:
                 s0, t0 = states[j], tails[j]
             else:
@@ -524,69 +494,69 @@ def _paged_hybrid_forward(params, ids, cfg, bufs: PoolBuffers, pages,
             with scope("attn_out"):
                 h = blk.linear_mixer_output(o, r, x, layer, cfg=cfg)
             j += 1
+        elif kind == "latent":
+            in_kernel = paged_kernel and S == 1
+            with scope("attn_qkv"):
+                q_nope, q_rope, rows = blk.latent_qkv(
+                    blk.mixer_input(x, layer, cfg=cfg), layer, cfg=cfg,
+                    cos=rope[0], sin=rope[1])
+                q = blk.absorb_queries(q_nope, q_rope, layer) if in_kernel \
+                    else (q_nope, q_rope)
+            attn, ks[p] = _paged_latent_attend(
+                q, rows, layer, cfg=cfg, dtype=x.dtype, pool=ks[p],
+                pages=full, apos=apos, valid=valid, in_kernel=in_kernel)
+            with scope("attn_out"):
+                if in_kernel:
+                    attn = blk.unabsorb_values(attn, layer, x.dtype)
+                h = blk.attention_output(attn, None, x, layer, cfg=cfg)
+            p += 1
+        else:
+            window = kind == "window"
+            with scope("attn_qkv"):
+                q, k, v, gate = blk.attention_qkv(
+                    blk.mixer_input(x, layer, cfg=cfg), layer, cfg=cfg,
+                    rope=None if kind in blk.NOPE_KINDS else rope)
+                # the pool's row may hold zero heads after the real ones
+                # (kv_pool.padded_kv_heads): their keys and values are 0,
+                # their queries' outputs are dropped.  A pool stored as the
+                # kernels' slab (3-D) holds none
+                slab = ks[p].ndim == 3
+                nkv, rep = k.shape[2], q.shape[2] // k.shape[2]
+                extra = 0 if slab else ks[p].shape[2] - nkv
+                if extra:
+                    k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, extra), (0, 0)))
+                            for a in (k, v))
+                    q = jnp.pad(q, ((0, 0), (0, 0), (0, extra * rep),
+                                    (0, 0)))
+            attn, (ks[p], vs[p], _, _) = _paged_attend(
+                q, k, v, dtype=x.dtype, pk=ks[p], pv=vs[p], pk_s=None,
+                pv_s=None, pages=ring if window else full, apos=apos,
+                valid=valid, paged_kernel=paged_kernel,
+                kernel_scope=blk.WINDOW_ATTENTION_SCOPE if window
+                else blk.PAGED_ATTENTION_SCOPE, slab=slab,
+                view=view if window else None,
+                scale=blk.attention_scale(cfg))
+            with scope("attn_out"):
+                h = blk.attention_output(attn[:, :, :nkv], gate, x, layer,
+                                         cfg=cfg)
+            p += 1
         with scope("mlp"):
             x, counts = blk.mlp(h, layer, cfg=cfg, valid=valid)
             if counts is not None:
                 moe = counts if moe is None else moe + counts
-    live = jnp.sum(jnp.any(valid, axis=1).astype(jnp.int32))[None]
-    if moe is not None:
-        live = jnp.concatenate([moe, live])
-    return x, bufs._replace(k=tuple(ks), v=tuple(vs), state=tuple(states),
-                            conv=tuple(tails)), live
-
-
-def _paged_swa_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
-                       valid, paged_kernel=False):
-    """``_paged_forward`` for the block of sliding-window and
-    full-attention layers over dense and then expert MLPs
-    (``models/swa_moe.py`` holds its pieces): ``params["layers"]`` is a
-    tuple of per-layer dicts, every layer caches K/V rows in its own pools
-    through :func:`_paged_attend`, and ``pages`` is a PAIR of tables, one a
-    page class (``kv_pool``): ``(full (B, P), ring (B, R))``.
-
-    A FULL layer is the dense block's storage and kernels over the first
-    table: written at ``full[p // page]``, read in order from position 0,
-    under ``attn_core/attn_paged``.  A WINDOW layer writes into its slot's
-    ring, ``ring[(p // page) % R]``, and reads ``(p - sliding_window, p]``
-    through the ordered view ``kv_pool.ring_view`` rotates out of the ring
-    once a launch, with a lower bound, under ``attn_core/attn_window``.
-
-    Returns ``(x', bufs', counts)``: the expert layers'
-    ``mla_moe.moe_counts`` summed, then the cached rows the valid rows of
-    this call read in ONE window layer and in ONE full layer (a decode
-    step's ``window_rows_read`` and ``full_rows_read``)."""
-    blk = cfg.block_module
-    full, ring = pages
-    page = bufs.k[0].shape[1]
-    W = cfg.sliding_window
-    with scope("embed"):
-        x = blk.embed(params, ids, cfg)
-        rope = _ragged_rope_tables(apos, cfg.resolved_head_dim,
-                                   cfg.rope_theta)
-        view = ring_view(ring, apos, W, page)
-    ks, vs = list(bufs.k), list(bufs.v)
-    moe = jnp.zeros((len(M.COUNTERS),), jnp.int32)
-    for li, layer in enumerate(params["layers"]):
-        window = blk.is_window_layer(li, cfg)
-        with scope("attn_qkv"):
-            q, k, v, gate = blk.attention_qkv(
-                x, layer, cfg=cfg, rope=rope if window else None)
-        attn, (ks[li], vs[li], _, _) = _paged_attend(
-            q, k, v, dtype=x.dtype, pk=ks[li], pv=vs[li], pk_s=None,
-            pv_s=None, pages=ring if window else full, apos=apos,
-            valid=valid, paged_kernel=paged_kernel,
-            kernel_scope=blk.WINDOW_ATTENTION_SCOPE if window
-            else blk.PAGED_ATTENTION_SCOPE, view=view if window else None)
-        with scope("attn_out"):
-            h = blk.attention_output(attn, gate, x, layer, cfg=cfg)
-        with scope("mlp"):
-            x, counts = blk.mlp(h, layer, cfg=cfg, li=li, valid=valid)
-            if counts is not None:
-                moe = moe + counts
-    seen = jnp.where(valid, apos + 1, 0)
-    rows = jnp.stack([jnp.sum(jnp.minimum(seen, W)), jnp.sum(seen)])
-    return x, bufs._replace(k=tuple(ks), v=tuple(vs)), \
-        jnp.concatenate([moe, rows.astype(jnp.int32)])
+    counted = [] if moe is None else [moe]
+    if states:
+        counted.append(
+            jnp.sum(jnp.any(valid, axis=1).astype(jnp.int32))[None])
+    if ring is not None:
+        seen = jnp.where(valid, apos + 1, 0)
+        counted.append(jnp.stack([
+            jnp.sum(jnp.minimum(seen, cfg.sliding_window)),
+            jnp.sum(seen)]).astype(jnp.int32))
+    new = {name: tuple(a) for name, a in (
+        ("k", ks), ("v", vs), ("state", states), ("conv", tails)) if a}
+    return x, bufs._replace(**new), \
+        counted[0] if len(counted) == 1 else jnp.concatenate(counted)
 
 
 def _paged_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
@@ -594,21 +564,15 @@ def _paged_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
     """ids (B, S) → (hidden x (B, S, H), bufs', counts) through the
     UNROLLED layer stack (static layer index into the per-layer pools,
     like ``generate._forward_cached``).  ``counts`` is None for the dense
-    block, the expert layers' counters for the latent one and the live
-    rows for a block with state slots, whose prefill chunk also names
-    the batch ``slot`` whose state it carries; for the block with window
-    layers ``pages`` is a pair of tables and ``counts`` end in the rows its
-    two kinds of layer read."""
-    if cfg.mla_moe:
-        return _paged_latent_forward(params, ids, cfg, bufs, pages, apos,
-                                     valid, paged_kernel=paged_kernel)
-    if cfg.state_slots:
-        return _paged_hybrid_forward(params, ids, cfg, bufs, pages, apos,
-                                     valid, paged_kernel=paged_kernel,
-                                     slot=slot)
-    if cfg.swa_moe:
-        return _paged_swa_forward(params, ids, cfg, bufs, pages, apos,
-                                  valid, paged_kernel=paged_kernel)
+    block, whose stacked tree and one kind of layer are below; every other
+    block is :func:`_paged_block_forward`'s: its ``counts`` are the block's
+    ``DEVICE_COUNTERS``, a prefill chunk of a block with state slots also
+    names the batch ``slot`` whose state it carries, and with window layers
+    ``pages`` is a pair of tables."""
+    if cfg.block_module is not None:
+        return _paged_block_forward(params, ids, cfg, bufs, pages, apos,
+                                    valid, paged_kernel=paged_kernel,
+                                    slot=slot)
     with scope("embed"):
         x = params["embed"].astype(cfg.dtype)[ids]
         cos, sin = _ragged_rope_tables(apos, cfg.resolved_head_dim,
@@ -642,7 +606,7 @@ def _all_logits(params, x, cfg):
     unembedding are per-row ops, so row ``i`` is bitwise the
     single-position tail evaluated at that position — what lets the
     speculative verify step read k+1 greedy tokens from one forward."""
-    if cfg.state_slots:     # the block's own: plain, or zero-centred
+    if cfg.block_module is not None:    # its own: plain, or zero-centred
         x = cfg.block_module.final_norm(x, params, cfg)
     else:
         x = T.rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
@@ -684,14 +648,11 @@ def _first_token(params, x_last, is_final, cfg):
 
 def device_counters(cfg) -> tuple:
     """The names of what the block's decode program sums on the device,
-    in the order they lead ``_decode_core``'s ``carry``: the expert
-    layers' ``mla_moe.COUNTERS``, then the hybrids' ``state_slot_steps`` or
-    the window block's ``window_rows_read`` and ``full_rows_read``;
-    () for the dense block, whose ``carry`` is token rows alone."""
-    if cfg.swa_moe:     # the expert layers' four, the rows its layers read
-        return cfg.block_module.DEVICE_COUNTERS
-    out = M.COUNTERS if cfg.mla_moe or cfg.gdn_moe or cfg.ssm_moe else ()
-    return out + (G.COUNTERS[:1] if cfg.state_slots else ())
+    in the order they lead ``_decode_core``'s ``carry``: the block
+    module's ``DEVICE_COUNTERS`` (:func:`_paged_block_forward`); () for the
+    dense block, whose ``carry`` is token rows alone."""
+    blk = cfg.block_module
+    return blk.DEVICE_COUNTERS if blk is not None else ()
 
 
 def _decode_core(bufs, params, pages, toks, lengths, stop_at, active,
@@ -1003,6 +964,7 @@ class ServingEngine:
                  disaggregate: bool = False, device=None,
                  watchdog=None, telem=None):
         self.cfg = _decode_cfg(cfg)
+        kinds = layer_kinds(self.cfg)
         if self.cfg.block_module is not None:
             # built for the latent block, the gated delta-rule hybrids
             # and the block with window layers: chunked prefill, the decode
@@ -1018,7 +980,7 @@ class ServingEngine:
                     ("disaggregate", disaggregate),
                     ("prefix_cache", prefix_cache),
                     ("hbm_budget_gb", hbm_budget_gb is not None
-                     and self.cfg.swa_moe)):
+                     and "window" in kinds)):
                 if asked:
                     self.cfg.block_module.refuse(
                         self.cfg, f"ServingEngine with {what}")
@@ -1055,10 +1017,11 @@ class ServingEngine:
         # the flash prefill kernel
         self.flash_prefill = bool(flash_prefill)
         # whether a prefill chunk's attention is the flash prefill
-        # kernel: as decode resolved, for a float pool of the dense
-        # block, and on a TPU for a chunk length it compiles for too
+        # kernel: as decode resolved, for a float pool of K/V rows (a
+        # latent layer's chunk is materialised), and on a TPU for a chunk
+        # length it compiles for too
         self.prefill_kernel = self.flash_prefill or (
-            self.paged_kernel and not (self.kv_quant or self.cfg.mla_moe))
+            self.paged_kernel and not (self.kv_quant or "latent" in kinds))
         if self.prefill_kernel and on_tpu and not self.flash_prefill:
             from ..ops.flash_prefill import prefill_kernel_takes
             self.prefill_kernel = prefill_kernel_takes(
@@ -1156,7 +1119,7 @@ class ServingEngine:
         # the window layers' page class (kv_pool): a ring a slot and the
         # class's null page; 0 for a block with one class
         self.ring_pages = self.n_pages_window = 0
-        if self.cfg.swa_moe:
+        if "window" in kinds:
             self.ring_pages = ring_pages(self.cfg, self.page_size,
                                          self.prefill_chunk)
             self.n_pages_window = self.max_batch * self.ring_pages + 1
@@ -1356,25 +1319,24 @@ class ServingEngine:
                       # arrays read back, one blocking read each (_read)
                       "launches": 0, "h2d_puts": 0, "h2d_bytes": 0,
                       "d2h_reads": 0, "d2h_bytes": 0}
-        # what a block counts on the device over the decode steps and a
-        # burst's one read brings back.  The latent block's expert layers
+        # what the block counts (its module's ``COUNTERS``).  The device
+        # sums some over the decode steps and a burst's one read brings
+        # them back (``DEVICE_COUNTERS``).  An expert layer's
         # (mla_moe.moe_counts): (row, chosen expert) pairs over the
         # router's whole width, those whose expert is held here, held
         # experts that got a row (summed over layers and steps), expert
-        # layers x steps.  The gated delta-rule hybrid: the live states a
-        # step read and wrote (``state_slot_steps``); its other two
+        # layers x steps.  With state slots: the live states a
+        # step read and wrote (``state_slot_steps``); the other two
         # counters are the host's (slots reset at a grant, valid rows the
         # prefill chunks scanned), as is ``lin_step_inplace_steps``: decode
         # steps whose recurrence was the step kernel, which moves a live
         # state once in and once out in place and no other
         self._device_counters = device_counters(self.cfg)
-        if self.cfg.state_slots:
-            self.stats.update(dict.fromkeys(
-                G.COUNTERS[1:] + ("lin_step_inplace_steps",), 0))
-        if self.cfg.swa_moe:    # its host counters (swa_moe.COUNTERS)
+        if self.cfg.block_module is not None:
             self.stats.update(dict.fromkeys(
                 self.cfg.block_module.COUNTERS, 0))
-        self.stats.update(dict.fromkeys(self._device_counters, 0))
+        if lin is not None:
+            self.stats["lin_step_inplace_steps"] = 0
         # what every plain burst's carry starts from (_decode_core): the
         # counters at zero, then ``sync_every`` token rows that the
         # burst's steps shift out.  One put, here: no step donates it
@@ -2018,7 +1980,7 @@ class ServingEngine:
                     self.stats["queue_wait_s"] += req.t_admit - req.t_submit
                     if self.cfg.state_slots:
                         # the granted slot's state: its first prefill
-                        # chunk starts from zeros (_paged_hybrid_forward)
+                        # chunk starts from zeros (_paged_block_forward)
                         self.stats["state_resets"] += 1
                     if self.disaggregate:
                         n = -(-req.n_prompt // self.page_size)

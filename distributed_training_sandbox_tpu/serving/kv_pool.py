@@ -7,7 +7,7 @@ FIXED pool whose blocks are reassigned between requests without
 reallocating (or retracing) anything.  vLLM's paged layout, TPU-shaped:
 
   * per-layer POOLS of page blocks, ``(n_pages, page_size, n_kv, hd)``
-    (the latent block: ``(n_pages, page_size, W)``, see ``row_layout``)
+    (a ``"latent"`` layer: ``(n_pages, page_size, W)``, see ``row_layout``)
     in ``cfg.dtype`` — or int8 codes + ``(n_pages, page_size, n_kv, 1)``
     f32 row scales via the same ``_quant_kv`` row quantizer the one-shot
     int8 cache uses;
@@ -15,7 +15,7 @@ reallocating (or retracing) anything.  vLLM's paged layout, TPU-shaped:
     a request lives at ``(page_table[slot, p // page_size],
     p % page_size)``;
   * TWO PAGE CLASSES where some layers attend through a sliding window
-    (``models/swa_moe.py``): a full-attention layer's pools are the pages
+    (kind ``"window"``): a ``"full"`` layer's pools are the pages
     above, granted for a request's whole length; a WINDOW layer needs its
     last ``sliding_window`` rows whatever the request's length, so the
     window layers' pools are a class of their own with its own
@@ -37,32 +37,36 @@ The device arrays live in a :class:`PoolBuffers` namedtuple that the
 jitted decode/prefill steps DONATE and return — the pool object just
 tracks the current buffers plus the free list.
 
-What ONE token caches in one layer is the pool's ROW, and
-:func:`row_layout` is the one place that states it: K and V rows of
-``(n_kv, hd)`` each for the dense GQA block; for the latent block
-(``models/mla_moe.py``) ONE row ``[c_kv | k_rope]`` and no V pool.  Pages,
-the allocator and the prefix trie are page-granular and do not look inside
-a row.
+What a layer keeps for a request follows from its KIND, and
+:func:`layer_kinds` is the one list everything here is sized from (a block
+module declares it: ``cfg.block_module.layer_kinds``; every layer of the
+dense GQA block is ``"full"``):
 
-Not every layer has pages, and pages are not the only per-request state.
-In the gated delta-rule hybrid block (``models/gdn_hybrid.py``) only the
-full-attention layers cache rows (:func:`paged_layers`); a linear layer
-keeps, per REQUEST and whatever its length, one float32 state matrix and a
-conv tail.  Those live beside the pages in the same :class:`PoolBuffers`
-as fixed-size STATE SLOTS indexed by the batch slot the scheduler grants:
-``state`` ``(n_slots, key dim, heads * value dim)`` float32, lane-dense
-(``gdn_hybrid.slot_shape``: at the published widths 96 x 5,760, whole
-(8, 128) tiles, so that a slot occupies its 2,211,840 bytes and a kernel's
-block copy moves no padding; ``(heads, 96, 192)`` held every row of 192 in
-256 lanes, 2,949,120 bytes), and ``conv`` ``(n_slots, K - 1, channels)``
-per linear layer.  A slot's state never
-survives its request: the first prefill chunk of the next one starts from
-zeros whatever the slot held (``engine._prefill_core``).  The Mamba-2 +
-attention block (``models/ssm_moe.py``) holds its slots the same way, as
-``cfg.linear_mixer`` shapes them: ``state`` ``(n_slots, state dim, heads *
-head dim)`` float32 (``ssm_moe.slot_shape``: 128 x 8,192 at the published
-widths, 4,194,304 bytes of whole tiles as the state is laid out, so nothing
-had to be re-laid) in nine layers of ten, pages in the one attention layer.
+  * ``"full"``: K and V rows of ``(n_kv, hd)`` a token, in whole-context
+    pages;
+  * ``"window"``: the same rows in a ring of the window page class;
+  * ``"latent"``: ONE row ``[c_kv | k_rope]`` a token
+    (``block_module.row_width``) and no V pool;
+  * ``"linear"``: no pages (:func:`paged_layers` leaves it out) but, per
+    REQUEST and whatever its length, one float32 state matrix and a conv
+    tail.  Those live beside the pages in the same :class:`PoolBuffers` as
+    fixed-size STATE SLOTS indexed by the batch slot the scheduler grants,
+    shaped as ``cfg.linear_mixer`` says: ``state`` ``(n_slots,) +
+    slot_shape`` float32, lane-dense (the gated delta rule's ``(key dim,
+    heads * value dim)``: 96 x 5,760 at the published widths, whole (8, 128)
+    tiles, so that a slot occupies its 2,211,840 bytes and a kernel's block
+    copy moves no padding, where ``(heads, 96, 192)`` held every row of 192
+    in 256 lanes, 2,949,120 bytes; Mamba-2's ``(state dim, heads * head
+    dim)``: 128 x 8,192, 4,194,304 bytes of whole tiles as the state is laid
+    out), and ``conv`` ``(n_slots, K - 1, channels)``.  A slot's state never
+    survives its request: the first prefill chunk of the next one starts
+    from zeros whatever the slot held (``engine._prefill_core``).
+
+What ONE token caches in one paged layer is the pool's ROW, which
+:func:`row_layout` states; the pool alone knows how a row is padded to the
+chip's tiles (``LATENT_ROW_ALIGN``, :func:`padded_kv_heads`,
+:func:`slab_pool`).  Pages, the allocator and the prefix trie are
+page-granular and do not look inside a row.
 """
 
 from __future__ import annotations
@@ -79,9 +83,19 @@ import jax.numpy as jnp
 LATENT_ROW_ALIGN = 128
 
 
+def layer_kinds(cfg) -> tuple[str, ...]:
+    """One entry a layer, of ``"full"``, ``"window"``, ``"latent"``,
+    ``"linear"`` (module docstring): the block module's declaration; every
+    layer of the dense block is ``"full"``."""
+    blk = cfg.block_module
+    if blk is None:
+        return ("full",) * cfg.num_hidden_layers
+    return blk.layer_kinds(cfg)
+
+
 def padded_kv_heads(n_kv: int, dtype) -> int:
-    """KV heads a row of the gated delta-rule hybrid's full-attention
-    pools holds: ``n_kv``, rounded up to whole sublane tiles of the dtype
+    """KV heads a row of the full-attention pools holds beside state
+    slots: ``n_kv``, rounded up to whole sublane tiles of the dtype
     (8 rows of 32 bits: 16 heads of bfloat16) unless it divides one.  The
     paged kernels read a page as ONE ``(page_size * heads, hd)`` slab; a TPU
     holds the pool's ``(heads, hd)`` minor dims in such tiles, so with 30
@@ -99,11 +113,11 @@ def row_layout(cfg, tp: int = 1) -> tuple[tuple[int, ...], bool]:
     """``(row shape, has_v)`` of one token in one PAGED layer's pool(s):
     the trailing dims of every pool array after ``(n_pages, page_size)``,
     and whether a V pool of the same shape stands beside the K pool."""
-    if cfg.mla_moe:
-        from ..models.mla_moe import row_width
-        w = row_width(cfg)
+    kinds = layer_kinds(cfg)
+    if "latent" in kinds:
+        w = cfg.block_module.row_width(cfg)
         return (w + -w % LATENT_ROW_ALIGN,), False
-    if cfg.state_slots:
+    if "linear" in kinds:   # no int8 rows, no tp mesh: see ``slab_pool``
         return (padded_kv_heads(cfg.num_key_value_heads, cfg.dtype),
                 cfg.resolved_head_dim), True
     return (cfg.num_key_value_heads // tp, cfg.resolved_head_dim), True
@@ -120,10 +134,12 @@ def slab_pool(cfg) -> bool:
     whole pool to make one, for every layer in every step (compiled for a
     v5e: twelve 268 MB copies a decode step).  At a head dim of 128 the
     4-D array's bytes ARE the slab's, whatever the head count, and the
-    pool stays 4-D.  Only for the hybrid blocks, whose engine refuses what
+    pool stays 4-D.  Only beside state slots, where the engine refuses what
     still indexes a pool by ``(page, offset, head)``: int8 rows with their
-    scales, the hand-over between pools, a tp mesh over the head axis."""
-    if not cfg.state_slots:
+    scales, the hand-over between pools, a tp mesh over the head axis.  A
+    slab's rows hold the model's heads and no zero head (heads that were
+    padded fill whole sublane tiles), which the engine relies on."""
+    if "linear" not in layer_kinds(cfg):
         return False
     (heads, hd), _ = row_layout(cfg)
     return hd > 128 and heads % (32 // jnp.dtype(cfg.dtype).itemsize) != 0
@@ -173,20 +189,16 @@ def ring_view(ring, apos, window: int, page: int):
 def paged_layers(cfg) -> int:
     """Layers whose tokens cache a row in pages: what every sizing of the
     pool multiplies :func:`token_row_bytes` by.  All of them (of either
-    page class), but for the linear layers of a block with state slots,
-    which hold a state slot instead."""
-    if cfg.state_slots:
-        return len(cfg.linear_mixer.full_layers(cfg))
-    return cfg.num_hidden_layers
+    page class), but for the linear layers, which hold a state slot
+    instead."""
+    return sum(kind != "linear" for kind in layer_kinds(cfg))
 
 
 def slot_state_bytes(cfg) -> int:
     """Bytes ONE batch slot holds in state slots over all layers (0 for a
     block whose every layer is paged)."""
-    lin = cfg.linear_mixer
-    if lin is not None:
-        return len(lin.linear_layers(cfg)) * lin.slot_state_bytes(cfg)
-    return 0
+    n_lin = layer_kinds(cfg).count("linear")
+    return n_lin * cfg.linear_mixer.slot_state_bytes(cfg) if n_lin else 0
 
 
 def token_row_bytes(cfg, *, kv_quant: bool = False, tp: int = 1) -> int:
@@ -207,11 +219,11 @@ class PoolBuffers(NamedTuple):
     row shape`` (:func:`row_layout`); with two page classes a WINDOW
     layer's arrays have the window class's ``n_pages_window`` pages and a
     full layer's the full class's ``n_pages``, in layer order.  ``v`` is
-    None for the latent block,
-    whose one row a token lives in ``k``.  ``k_scale``/``v_scale`` are the
+    None where the layers are ``"latent"``, whose one row a token lives in
+    ``k``.  ``k_scale``/``v_scale`` are the
     f32 row scales of the int8 pool, None for the ``cfg.dtype`` pool.
-    ``state``/``conv`` are the state slots of the gated delta-rule
-    hybrid's linear layers, one array a linear layer, None elsewhere."""
+    ``state``/``conv`` are the state slots of the ``"linear"`` layers, one
+    array a linear layer, None without."""
     k: tuple            # L × (n_pages, page_size, n_kv, hd) | (.., .., W)
     #                     | (n_pages, page_size * n_kv, hd): slab_pool
     #                     | a window layer: (n_pages_window, page_size, ..)
@@ -484,15 +496,16 @@ class PagedKVPool:
                  device=None, n_slots: int = 0, n_pages_window: int = 0):
         if mesh is not None and device is not None:
             raise ValueError("pass mesh or device, not both")
-        if cfg.swa_moe and (kv_quant or mesh is not None
-                            or n_pages_window < 2):
+        kinds = layer_kinds(cfg)
+        if "window" in kinds and (kv_quant or mesh is not None
+                                  or n_pages_window < 2):
             raise ValueError(
                 "the window + full attention block's pool has a second "
                 "page class for its window layers: pass n_pages_window "
                 ">= 2 (max_batch rings of kv_pool.ring_pages + the null "
                 "page), and neither kv_quant nor a mesh")
-        if cfg.state_slots and (kv_quant or mesh is not None
-                                or n_slots < 1):
+        if "linear" in kinds and (kv_quant or mesh is not None
+                                  or n_slots < 1):
             raise ValueError(
                 "the pool of a block with linear layers holds a float "
                 "state slot per batch slot: pass n_slots >= 1, and neither "
@@ -515,13 +528,11 @@ class PagedKVPool:
         put = self._put
         # the window layers' page class: pools of their own size, and the
         # free list the scheduler grants their rings from
-        self.n_pages_window = int(n_pages_window) if cfg.swa_moe else 0
-        shapes = [shape] * L
-        if self.n_pages_window:
-            from ..models.swa_moe import window_layers
-            for li in window_layers(cfg):
-                shapes[li] = pool_shape(cfg, self.n_pages_window,
-                                        self.page_size)
+        self.n_pages_window = int(n_pages_window) if "window" in kinds \
+            else 0
+        window = pool_shape(cfg, self.n_pages_window, self.page_size)
+        shapes = [window if kind == "window" else shape
+                  for kind in kinds if kind != "linear"]
         k = tuple(put(jnp.zeros(sh, dt)) for sh in shapes)
         v = tuple(put(jnp.zeros(sh, dt)) for sh in shapes) \
             if has_v else None
@@ -534,10 +545,10 @@ class PagedKVPool:
             vs = tuple(put(jnp.ones(shape[:-1] + (1,), jnp.float32))
                        for _ in range(L))
         state = conv = None
-        self.n_slots = int(n_slots) if cfg.state_slots else 0
+        n_lin = kinds.count("linear")
+        self.n_slots = int(n_slots) if n_lin else 0
         if self.n_slots:
             lin = cfg.linear_mixer
-            n_lin = len(lin.linear_layers(cfg))
             state = tuple(put(jnp.zeros((self.n_slots,) + lin.slot_shape(cfg),
                                         jnp.float32)) for _ in range(n_lin))
             conv = tuple(put(jnp.zeros((self.n_slots,) + lin.tail_shape(cfg),

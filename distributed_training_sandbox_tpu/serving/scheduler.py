@@ -18,11 +18,11 @@ page-fault paths.  Eviction (page + slot release) happens at the sync
 point where a request's emission count reaches ``max_new``.
 
 A granted SLOT is also the grant of that slot's fixed-size state in a
-block that keeps some (the gated delta-rule hybrid's linear layers:
+block that keeps some (its ``"linear"`` layers:
 ``kv_pool.PoolBuffers.state/conv``, one entry a batch slot).  Nothing is
 copied or zeroed on the host: the request's first prefill chunk starts
 from zeros whatever the slot's last request left
-(``engine._paged_hybrid_forward``), and the engine counts the grant as a
+(``engine._paged_block_forward``), and the engine counts the grant as a
 ``state_resets``.  The state needs no page, so ``pages_needed`` is the
 full-attention layers' alone.
 

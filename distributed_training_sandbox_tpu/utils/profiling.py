@@ -168,7 +168,7 @@ SUBSCOPES = (
 
 #: A declared second level of the linear layers of the blocks with state
 #: slots (``models/gdn_hybrid.py`` and, since PR 40, the Mamba-2 layers of
-#: ``models/ssm_moe.py``; ``serving/engine._paged_hybrid_forward``):
+#: ``models/ssm_moe.py``; ``serving/engine._paged_block_forward``):
 #: ``lin_conv`` beneath ``attn_qkv``, ``lin_scan`` and ``lin_step`` beneath
 #: ``attn_core``.  A reader of ``SCOPES`` books these ops to the catalogue
 #: name above them; ``benchmarks/layer_metrics/_linscopes.py`` holds a copy
@@ -196,7 +196,7 @@ ATTENTION_SUBSCOPES = (
 
 #: A second name beneath ``attn_core``, round the paged attention of the
 #: WINDOW layers of the block that mixes them with full-attention layers
-#: (``models/swa_moe.py``; ``serving/engine._paged_swa_forward`` asks
+#: (``models/swa_moe.py``; ``serving/engine._paged_block_forward`` asks
 #: :func:`_paged_attend` for it, in a decode step and in a prefill chunk,
 #: after ``kv_write``), whose full layers open ``attn_paged`` above: the
 #: two kinds of layer read different rows of a request and are read apart.

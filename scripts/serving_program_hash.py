@@ -62,7 +62,8 @@ def programs(root: Path):
     from benchmarks import harness
     from distributed_training_sandbox_tpu.models import transformer as T
     from distributed_training_sandbox_tpu.serving import engine as E
-    from distributed_training_sandbox_tpu.serving.kv_pool import PagedKVPool
+    from distributed_training_sandbox_tpu.serving.kv_pool import (
+        PagedKVPool, layer_kinds, ring_pages)
     jax.default_backend = lambda: "tpu"     # the engine's kernels, as there
     sd = jax.ShapeDtypeStruct
     i32 = lambda *s: sd(s, jnp.int32)  # noqa: E731
@@ -78,12 +79,12 @@ def programs(root: Path):
         P = -(-eng["max_seq_len"] // page)
         params = jax.eval_shape(
             lambda: T.init_params(jax.random.key(0), mcfg))
-        slots = {"n_slots": B} if mcfg.state_slots else {}
+        # the facts the engine reads: state slots beside pages, a second
+        # page class (a ring a slot, a second table), latent rows
+        kinds = layer_kinds(mcfg)
+        slots = {"n_slots": B} if "linear" in kinds else {}
         tables = lambda b: i32(b, P)  # noqa: E731
-        if getattr(mcfg, "swa_moe", False):
-            # a second page class: a ring a slot, a second table
-            from distributed_training_sandbox_tpu.serving.kv_pool import \
-                ring_pages
+        if "window" in kinds:
             R = ring_pages(mcfg, page, eng["prefill_chunk"])
             slots = {"n_pages_window": B * R + 1}
             tables = lambda b: (i32(b, P), i32(b, R))  # noqa: E731
@@ -96,9 +97,9 @@ def programs(root: Path):
             bufs, params, tables(B), i32(B), i32(B), i32(B),
             sd((B,), jnp.bool_), i32(len(E.device_counters(mcfg)) + sync * B))
         prefill = E.make_serve_prefill_step(
-            mcfg, paged_kernel=not mcfg.mla_moe).trace(
+            mcfg, paged_kernel="latent" not in kinds).trace(
             bufs, params, tables(1), i32(1, eng["prefill_chunk"]), i32(),
-            i32(), *((i32(),) if mcfg.state_slots else ()))
+            i32(), *((i32(),) if "linear" in kinds else ()))
         for name, traced in (("decode", decode), ("prefill", prefill)):
             yield cell.name, name, traced.lower(
                 lowering_platforms=("tpu",)).as_text()

@@ -59,7 +59,7 @@ def small_sub_chunks(monkeypatch):
 def test_two_kinds_of_layer_and_the_count(model):
     _, cfg, params = model
     assert set(params) == {"embed", "layers", "final_norm", "lm_head"}
-    assert G.full_layers(cfg) == (3,) and G.linear_layers(cfg) == (0, 1, 2)
+    assert G.layer_kinds(cfg) == ("linear",) * 3 + ("full",)
     lin, full = params["layers"][0], params["layers"][3]
     assert lin["w_q"].shape == (64, 24) and lin["w_v"].shape == (64, 48)
     assert lin["conv_w"].shape == (4, 96) and lin["o_norm"].shape == (16,)
@@ -626,7 +626,8 @@ def test_both_programs_lower_for_tpu_at_published_widths(monkeypatch):
     # one call of the step kernel a linear layer, each aliasing the state
     # it is given; the XLA form's state-sized operands (k, q, alpha spread
     # over 64 x 96 x (30 x 192)) and its reductions over them nowhere
-    assert text.count("call @_step(") == 3 == len(G.linear_layers(cfg))
+    assert text.count("call @_step(") == 3 \
+        == G.layer_kinds(cfg).count("linear")
     assert text.count('kernel_name = "_step_kernel"') == 1   # one lowering
     assert "output_tuple_indices = [1], operand_index = 4" in text
     assert "64x96x30x192" not in text

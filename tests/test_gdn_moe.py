@@ -75,7 +75,7 @@ def test_the_block_is_selected_and_counted(model):
     _, cfg, params = model
     assert cfg.gdn_moe and cfg.gdn_hybrid and not cfg.mla_moe
     assert cfg.block_module is N and cfg.held_experts == 4
-    assert G.full_layers(cfg) == (3,) and G.linear_layers(cfg) == (0, 1, 2)
+    assert N.layer_kinds(cfg) == ("linear",) * 3 + ("full",)
     lin, full = params["layers"][0], params["layers"][3]
     assert lin["w_q"].shape == (64, 16) and lin["w_v"].shape == (64, 64)
     assert lin["w_b"].shape == (64, 4) and lin["conv_w"].shape == (4, 96)
@@ -591,7 +591,8 @@ def test_both_programs_lower_for_tpu_at_published_widths(monkeypatch):
     assert "_decode_float" in text
     assert f"tensor<{B}x{P * page}x2x256" not in text      # no gathered view
     assert f"tensor<{B * P + 1}x16x2x256xbf16>" in text   # the kernels' view
-    assert text.count("call @_step(") == 3 == len(G.linear_layers(cfg))
+    assert text.count("call @_step(") == 3 \
+        == N.layer_kinds(cfg).count("linear")
     assert text.count('kernel_name = "_step_kernel"') == 1
     assert "tensor<64x128x32xf32>" in text                 # k | q: 2 x 16 columns
     assert "64x128x32x128" not in text                     # no XLA form

@@ -90,7 +90,7 @@ def test_the_block_is_selected_and_counted(model):
         cfg.mla_moe or cfg.gdn_hybrid or cfg.gdn_moe or cfg.swa_moe)
     assert cfg.block_module is S and cfg.linear_mixer is S
     assert cfg.held_experts == 4
-    assert S.full_layers(cfg) == (2,) and S.linear_layers(cfg) == (0, 1, 3)
+    assert S.layer_kinds(cfg) == ("linear", "linear", "full", "linear")
     ssm, attn = params["layers"][0], params["layers"][2]
     assert ssm["w_z"].shape == (64, 128) and ssm["w_xbc"].shape == (64, 160)
     assert ssm["w_dt"].shape == (64, 8) and ssm["conv_w"].shape == (4, 160)
@@ -123,8 +123,7 @@ def test_layer_types_is_the_pattern_and_a_list_hashes(model):
     again = T.TransformerConfig(**{**FIELDS, "layer_types": list(
         FIELDS["layer_types"])}, dtype=jnp.float32, remat=False)
     assert again == cfg and hash(again) == hash(cfg)
-    assert [S.is_full_layer(i, cfg) for i in range(4)] == [False, False,
-                                                           True, False]
+    assert S.layer_kinds(again) == ("linear", "linear", "full", "linear")
 
 
 # ------------------------------------------ the two forms of the recurrence
@@ -626,7 +625,8 @@ def test_both_programs_lower_for_tpu_at_published_widths(monkeypatch):
          sd((5,), jnp.int32)))
     assert "_decode_float" in text
     assert f"tensor<{B}x{P * page}x8x128" not in text      # no gathered view
-    assert text.count("call @_step(") == 2 == len(S.linear_layers(cfg))
+    assert text.count("call @_step(") == 2 \
+        == S.layer_kinds(cfg).count("linear")
     assert text.count('kernel_name = "_step_kernel"') == 1
     assert f"tensor<{B}x128x2xf32>" in text                # B | C columns
     assert text.count("_visit_kernel") >= 1                # grouped experts

@@ -73,7 +73,7 @@ def test_the_block_is_selected_and_counted(model):
     _, cfg, params = model
     assert cfg.swa_moe and not (cfg.mla_moe or cfg.gdn_hybrid or cfg.gdn_moe)
     assert cfg.block_module is W and cfg.held_experts == 4
-    assert W.window_layers(cfg) == [0, 1, 2, 4]
+    assert W.layer_kinds(cfg) == ("window",) * 3 + ("full", "window")
     assert [W.is_expert_layer(li, cfg) for li in range(5)] \
         == [False, True, True, True, True]
     dense, expert = params["layers"][0], params["layers"][3]
