@@ -396,10 +396,13 @@ class CompileWatch:
 
 def result_line(*, correct: bool, attempted: int, failed: int,
                 metrics: dict, device: dict,
-                breakdown: dict | None = None) -> str:
+                breakdown: dict | None = None,
+                compared: dict | None = None) -> str:
     """The contract's one JSON object.  ``metrics`` maps name to
     ``(value, unit)``; a value that is not a finite number is a fault of
-    the run, not a result."""
+    the run, not a result.  ``compared`` is what ``correct`` was decided
+    from, ``{name: [number, limit]}`` (a band: ``[number, least, most]``),
+    under a key of its own that comes last."""
     out = {}
     for name, (value, unit) in metrics.items():
         if value is None or not math.isfinite(float(value)):
@@ -409,6 +412,8 @@ def result_line(*, correct: bool, attempted: int, failed: int,
             "failed": int(failed), "metrics": out, "device": device}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    if compared is not None:
+        line["compared"] = compared
     return json.dumps(line)
 
 

@@ -1,5 +1,6 @@
-"""Kernels: device time of the splash-attention Mosaic calls (forward, its
-remat re-run, dq, dkv) per step, averaged over the chips."""
+"""Kernels: device time of the splash-attention Mosaic calls (forward, dq,
+dkv: the forward runs once a layer, its output and log-sum-exp are kept
+across the remat boundary) per step, averaged over the chips."""
 from benchmarks.layer_metrics import _attn
 
 LAYER = "kernels"

@@ -1,6 +1,7 @@
 """Model step: device self time of the decode program's ``moe_experts``
-subscope per launch: the held experts' product over every row and its
-combine, all expert layers of one decode step."""
+subscope per launch: the held experts' grouped product over the (row, held
+expert) pairs the routing made and its combine, all expert layers of one
+decode step."""
 from benchmarks.layer_metrics import _subscopes
 
 LAYER = "model step"

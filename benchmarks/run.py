@@ -7,7 +7,9 @@
 
 The last line of standard output is the one JSON object the contract asks
 for.  ``--trace 0`` gives the cell's end-to-end metrics, ``--trace 1`` its
-per-layer metrics from a short traced window.  Without a TPU holding the
+per-layer metrics from a short traced window.  Its last key, ``compared``,
+holds each number ``correct`` was decided from beside its limit; the same
+pairs are the last lines of standard error.  Without a TPU holding the
 chips the cell asks for the program exits 2 and prints no result.
 
 ``--rehearse-cpu`` runs the cell's control flow and its reference check on
@@ -122,10 +124,20 @@ def main(argv=None) -> int:
     if not args.trace:
         setup = (time.perf_counter() - T_ENTRY) - obs["window_wall_s"]
         metrics["setup_s"] = (setup, "s")
+    # each number ``correct`` was decided from beside its limit: the last
+    # lines on standard error, and the last key of the result line
+    compared = {**obs["check"].get("compared", {}),
+                "failed": [obs["failed"], 0],
+                "compiles_in_window": [obs["compiles_in_window"], 0]}
+    for name, (number, *limit) in compared.items():
+        print(f"[bench] compared {name}: {number!r} limit "
+              f"{' to '.join(repr(x) for x in limit)}", file=sys.stderr)
     sys.stdout.flush()
+    sys.stderr.flush()
     print(harness.result_line(
         correct=correct, attempted=obs["attempted"], failed=obs["failed"],
-        metrics=metrics, device=device, breakdown=breakdown), flush=True)
+        metrics=metrics, device=device, breakdown=breakdown,
+        compared=compared), flush=True)
     return 0
 
 

@@ -326,6 +326,14 @@ def run(cell, *, ref, seed: int, seconds: float, trace: bool,
     slo = None if rehearse else cell.traffic.get("slo")
     if slo:
         check.update(first_token_gate(w["judged"], slo))
+    # each number compared beside its limit, flat; a share has a least
+    check["compared"] = {
+        "requests_checked_min": [check["requests_checked"], 1],
+        **{k: [check[k], v] for k, v in check.get("limits", {}).items()},
+        "retraces_after_warmup": [retraces, 0],
+        **({"ttft_within_limit_share_min": [check["ttft_within_limit_share"],
+                                            float(slo["min_share"])]}
+           if slo else {})}
     return {
         "attempted": len(w["judged"]), "failed": w["failed"],
         "correct": bool(check["ok"] and w["failed"] == 0

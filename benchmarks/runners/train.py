@@ -241,6 +241,21 @@ def check_step(loss0: float, loss1: float, ref0: float, tol: dict,
     return out
 
 
+def compared(check: dict) -> dict:
+    """Each number the check compared beside its limit, flat:
+    ``{name: [number, limit]}``; the drop ``[number, least, most]``."""
+    lim = check["limits"]
+    out = {"loss_abs": [check["step_loss_abs_diff"], lim["loss_abs"]]}
+    if "loss_abs_diff" in check:        # the pass on ``positions``
+        out["loss_abs.positions"] = [check["loss_abs_diff"], lim["loss_abs"]]
+    for key in ("grad_norm_rel", "update_norm_rel"):
+        for group, gap in check.get(key + "_diff", {}).items():
+            out[f"{key}.{group}"] = [gap, lim[key][group]]
+    if lim.get("step_drop"):
+        out["step_drop"] = [check["step_drop"], *lim["step_drop"]]
+    return out
+
+
 def run(cell, *, ref, seed: int, seconds: float, trace: bool,
         rehearse: bool, watch, phases) -> dict:
     import jax
@@ -342,6 +357,7 @@ def run(cell, *, ref, seed: int, seconds: float, trace: bool,
                            ("loss_abs", "grad_norm_rel", "update_norm_rel",
                             "step_drop")
                            if k in tol}
+        check["compared"] = compared(check)
 
         if rehearse:
             n_steps = 3

@@ -59,6 +59,19 @@ def test_result_line_has_the_contracts_keys_and_all_digits():
     assert line["metrics"]["x_ms"] == {"value": 1.23456789012, "unit": "ms"}
 
 
+def test_result_line_ends_with_what_was_compared_beside_its_limits():
+    line = json.loads(harness.result_line(
+        correct=False, attempted=3, failed=0, metrics={"x_ms": (1.0, "ms")},
+        device={"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+                "memory_peak_bytes": 5},
+        breakdown={"device_ops": [], "idle_gaps": []},
+        compared={"grad_norm_rel.mlp": [0.31, 0.0004],
+                  "step_drop": [3.5, 3.1, 4.1]}))
+    assert list(line)[-1] == "compared"
+    assert line["compared"]["grad_norm_rel.mlp"] == [0.31, 0.0004]
+    assert line["compared"]["step_drop"] == [3.5, 3.1, 4.1]
+
+
 @pytest.mark.parametrize("bad", [math.nan, math.inf, None])
 def test_result_line_refuses_a_value_that_is_no_number(bad):
     with pytest.raises(harness.BenchmarkError):

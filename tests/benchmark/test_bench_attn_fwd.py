@@ -103,12 +103,18 @@ def test_on_the_recorded_v5e_step_with_and_without_the_rerun(reader):
 
 
 def test_attn_fwd_ms_is_listed_for_the_training_cells():
+    """The three cells it was accepted in, in their order; a training cell
+    that a later PR appends joins both lists."""
     bm = json.loads((ROOT / "BENCHMARK.json").read_text())
     (entry,) = [m for m in bm["per_layer"] if m["name"] == "attn_fwd_ms"]
+    listed = entry.pop("workloads")
     assert entry == {
         "name": "attn_fwd_ms", "unit": "ms/step", "better": "lower",
         "source": "device_trace", "layer": "kernels",
-        "moves": "train_tokens_per_s", "workloads": TRAIN_CELLS}
-    assert [m for m in bm["end_to_end"]
-            if m["name"] == "train_tokens_per_s"][0]["workloads"] == \
-        TRAIN_CELLS
+        "moves": "train_tokens_per_s"}
+    (rate,) = [m for m in bm["end_to_end"]
+               if m["name"] == "train_tokens_per_s"]
+    for cells in (listed, rate["workloads"]):
+        assert [c for c in cells if c in TRAIN_CELLS] == TRAIN_CELLS
+    # every cell the kernel's time is listed in reports the rate it moves
+    assert set(listed) <= set(rate["workloads"])

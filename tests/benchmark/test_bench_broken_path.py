@@ -41,9 +41,15 @@ def test_a_sound_step_is_correct_under_the_rehearsal_band(cell):
     obs = _drive(cell)
     assert obs["correct"], obs["check"]
     assert band[0] < obs["check"]["step_drop"] < band[1]
+    # what was compared stands beside its limits, flat, for the result line
+    flat = obs["check"]["compared"]
+    assert flat["step_drop"] == [obs["check"]["step_drop"], *band]
+    assert flat["loss_abs"] == [obs["check"]["step_loss_abs_diff"], 0.003]
+    assert all(v[0] <= v[1] for name, v in flat.items() if name != "step_drop")
 
 
-@pytest.mark.parametrize("cell", ["train-dense-8k", "train-dense-32k"])
+@pytest.mark.parametrize("cell", ["train-dense-8k", "train-dense-32k",
+                                  "train-dense-2k"])
 def test_a_step_that_returns_its_state_unchanged_is_not_correct(
         cell, monkeypatch):
     import jax
@@ -71,14 +77,17 @@ def test_a_step_that_returns_its_state_unchanged_is_not_correct(
     assert check["step_loss_abs_diff"] <= 1e-4
 
 
+@pytest.mark.parametrize("cell,far_from", [("train-dense-32k", 128),
+                                           ("train-dense-2k", 16)])
 def test_a_backward_pass_that_is_wrong_at_long_range_is_not_correct(
-        monkeypatch):
-    """``train-dense-32k`` holds the gradient the timed step's optimizer got
-    at the cell's own length.  Here the program's attention keeps its
-    forward and loses the gradient of every score whose key lies 128
-    positions or more behind its query: the loss still agrees with the
-    reference and a comparison on a window's first 128 positions could not
-    see it, and ``correct`` comes out false."""
+        cell, far_from, monkeypatch):
+    """``train-dense-32k`` and ``train-dense-2k`` hold the gradient the
+    timed step's optimizer got, at the cell's own batch and length.  Here
+    the program's attention keeps its forward and loses the gradient of
+    every score whose key lies ``far_from`` positions or more behind its
+    query: the loss still agrees with the reference and a comparison on a
+    window's first positions could not see it, and ``correct`` comes out
+    false."""
     import jax
     import jax.numpy as jnp
     from distributed_training_sandbox_tpu.models import transformer as T
@@ -89,14 +98,14 @@ def test_a_backward_pass_that_is_wrong_at_long_range_is_not_correct(
         s = jnp.einsum("bqnh,bknh->bnqk", q, k,
                        preferred_element_type=jnp.float32) * scale
         back = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
-        s = jnp.where(back >= 128, jax.lax.stop_gradient(s), s)
+        s = jnp.where(back >= far_from, jax.lax.stop_gradient(s), s)
         p = jax.nn.softmax(jnp.where(back >= 0, s, -1e30), axis=-1)
-        far = jnp.where(back >= 128, p, 0.0).astype(q.dtype)
+        far = jnp.where(back >= far_from, p, 0.0).astype(q.dtype)
         return jnp.einsum("bnqk,bknh->bqnh", p.astype(q.dtype) - far, v) \
             + jnp.einsum("bnqk,bknh->bqnh", far, jax.lax.stop_gradient(v))
 
     monkeypatch.setattr(T, "_attention_xla", cut_at_long_range)
-    obs = _drive("train-dense-32k")
+    obs = _drive(cell)
     check = obs["check"]
     assert check["gradient"] == "step"
     assert check["step_loss_abs_diff"] <= 1e-5 and check["step_ok"]
@@ -105,7 +114,9 @@ def test_a_backward_pass_that_is_wrong_at_long_range_is_not_correct(
     assert check["ok"] is False and obs["correct"] is False
 
 
-def test_an_update_at_a_third_of_its_strength_is_not_correct(monkeypatch):
+@pytest.mark.parametrize("cell", ["train-dense-32k", "train-dense-2k"])
+def test_an_update_at_a_third_of_its_strength_is_not_correct(
+        cell, monkeypatch):
     """The gradient is sound and the loss still drops inside its band; the
     norm of the change the first update made to the parameters is a third
     of the reference's plain Adam update."""
@@ -117,7 +128,7 @@ def test_an_update_at_a_third_of_its_strength_is_not_correct(monkeypatch):
         return real(*args, lr=1e-4, **kw)
 
     monkeypatch.setattr(fsdp, "make_fsdp_train_step", make_weak)
-    obs = _drive("train-dense-32k")
+    obs = _drive(cell)
     check = obs["check"]
     assert max(check["grad_norm_rel_diff"].values()) < 1e-5
     assert check["step_ok"]
@@ -126,7 +137,47 @@ def test_an_update_at_a_third_of_its_strength_is_not_correct(monkeypatch):
     assert check["ok"] is False and obs["correct"] is False
 
 
-@pytest.mark.parametrize("cell", ["train-dense-8k", "train-dense-32k"])
+def test_a_step_that_trains_on_half_of_its_rows_is_not_correct(monkeypatch):
+    """``train-dense-2k`` feeds 16 rows a step (8 in the rehearsal).  Here
+    the step leaves the second half of them out and takes its mean over
+    the first half (fed twice, so no shape changes).  Adam's first update
+    is normalised, so the drop of the loss and the update's norm barely
+    move and a gradient pass on one row's first positions would never see
+    the batch; the norm of the gradient the timed step's optimizer got,
+    read from its first moment, is the noisier mean of half as many rows
+    and ``correct`` comes out false.  (On the chip at 16 x 2048 it reads
+    0.28 to 0.42 in every group against limits of 5e-4 to 8e-4: PERF.md.)"""
+    import jax
+    import jax.numpy as jnp
+    from distributed_training_sandbox_tpu.parallel import fsdp
+    real = fsdp.make_fsdp_train_step
+
+    @functools.wraps(real)      # the runner reads the program's defaults
+    def make_half(*args, **kw):
+        step = real(*args, **kw)
+
+        def half(params, opt_state, batch):
+            batch = jax.tree.map(lambda x: jax.device_put(jnp.concatenate(
+                [x[:x.shape[0] // 2]] * 2, 0), x.sharding), batch)
+            return step(params, opt_state, batch)
+
+        return half
+
+    monkeypatch.setattr(fsdp, "make_fsdp_train_step", make_half)
+    obs = _drive("train-dense-2k")
+    check = obs["check"]
+    assert check["gradient"] == "step"
+    for group, gap in check["grad_norm_rel_diff"].items():
+        assert gap > 10 * check["limits"]["grad_norm_rel"][group], group
+    # what the positions check had of the timed step would have passed
+    band = check["limits"]["step_drop"]
+    assert band[0] <= check["step_drop"] <= band[1]
+    assert max(check["update_norm_rel_diff"].values()) < 1e-3
+    assert check["ok"] is False and obs["correct"] is False
+
+
+@pytest.mark.parametrize("cell", ["train-dense-8k", "train-dense-32k",
+                                  "train-dense-2k"])
 def test_the_int8_control_moves_what_the_check_compares(cell):
     """The control of the training cells (``matmul_precision`` int8, the
     step below the configuration's bf16) at the rehearsal's size.  At 64
@@ -162,4 +213,6 @@ def test_a_token_altered_where_it_is_read_back_is_not_correct(
     assert check["tokens_checked"] > 0
     tol = harness.load_cell(cell).check
     assert check["gap_sigma_mean"] > tol["gap_sigma_mean"]
+    assert check["compared"]["gap_sigma_mean"] == [check["gap_sigma_mean"],
+                                                   tol["gap_sigma_mean"]]
     assert check["ok"] is False and obs["correct"] is False

@@ -1,6 +1,10 @@
 """The harness is driven by data: a cell, a configuration, a traffic mix or
 a per-layer metric is added by adding files and entries, editing no file
-that is there.  And ``BENCHMARK.json`` keeps to its contract's limits."""
+that is there.  And ``BENCHMARK.json`` keeps to its contract's limits: the
+structural checks take the benchmark's root as a fixture and run on the
+tree and on a copy of it with a serving cell, a training cell and a reader
+appended as data, so no test of this directory can come to need an edit
+for a cell or a reader that a later PR adds."""
 
 import json
 import os
@@ -23,8 +27,113 @@ SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
 @pytest.fixture(scope="module")
-def bm():
+def tree_bm():
     return json.loads((ROOT / "BENCHMARK.json").read_text())
+
+
+# ------------------------------------- additions, as data and nothing else
+
+#: what a later PR may append: a serving cell and a training cell, each an
+#: accepted configuration under an accepted traffic file (``_appended``)
+ADDED_CELLS = [("appended-serve-cell", "smollm3-3b-serve"),
+               ("appended-train-cell", "smollm3-3b-fsdp4-train")]
+ADDED_READER = "appended_rounds_tput"
+
+
+def _appended(bm: dict, name: str, config: str) -> tuple[dict, str]:
+    """The row of a new cell of ``config``, and the accepted cell whose
+    metrics' lists it joins (the configuration's last): under the first
+    traffic file of a cell of the same runner that no cell pairs with
+    ``config`` yet, on its configuration's chips."""
+    def runner(c):
+        row = next(r for r in bm["configs"] if r["name"] == c)
+        return json.loads((ROOT / row["file"]).read_text())["runner"]
+    like = [w for w in bm["workloads"] if w["config"] == config][-1]
+    paired = {w["traffic"] for w in bm["workloads"] if w["config"] == config}
+    traffic = next(w["traffic"] for w in bm["workloads"]
+                   if runner(w["config"]) == runner(config)
+                   and w["traffic"] not in paired)
+    return {"name": name, "config": config, "traffic": traffic,
+            "chips": like["chips"],
+            "why": "a cell a later PR appends"}, like["name"]
+
+
+def _overlay(root: Path) -> None:
+    """``root/benchmarks``: every file of the tree's through a link, and one
+    reader more.  No file that is there is written."""
+    b = root / "benchmarks"
+    (b / "layer_metrics").mkdir(parents=True)
+    for child in (ROOT / "benchmarks").iterdir():
+        if child.name not in ("layer_metrics", "__pycache__"):
+            (b / child.name).symlink_to(child)
+    for f in (ROOT / "benchmarks/layer_metrics").glob("*.py"):
+        (b / "layer_metrics" / f.name).symlink_to(f)
+    (b / "layer_metrics" / f"{ADDED_READER}.py").write_text(
+        'LAYER = "scheduler"\nUNIT = "rounds"\n'
+        'MOVES = "serve_tokens_per_s"\nRUNNERS = ("serve",)\n\n\n'
+        'def read(ctx):\n'
+        '    return ctx.counters["stats"].get("rounds") or None\n')
+
+
+def _with_additions(root: Path) -> Path:
+    """A benchmark root whose ``BENCHMARK.json`` is the tree's with two
+    cells appended to ``workloads`` and to the lists of every metric their
+    configuration's accepted cell reports, and one entry appended at the
+    end of ``per_layer``."""
+    new = json.loads((ROOT / "BENCHMARK.json").read_text())
+    for name, config in ADDED_CELLS:
+        row, like = _appended(new, name, config)
+        new["workloads"].append(row)
+        for m in new["end_to_end"] + new["per_layer"]:
+            if like in m.get("workloads", []):
+                m["workloads"].append(name)
+    new["per_layer"].append({
+        "name": ADDED_READER, "unit": "rounds", "better": "higher",
+        "source": "program_counter", "layer": "scheduler",
+        "moves": "serve_tokens_per_s", "workloads": [ADDED_CELLS[0][0]]})
+    _overlay(root)
+    (root / "BENCHMARK.json").write_text(json.dumps(new, indent=1))
+    return root
+
+
+@pytest.fixture(scope="module")
+def with_additions(tmp_path_factory):
+    return _with_additions(tmp_path_factory.mktemp("additions"))
+
+
+@pytest.fixture(scope="module", params=["tree", "tree_with_additions"])
+def root(request):
+    """The benchmark's root: this tree, and the copy with additions."""
+    return ROOT if request.param == "tree" \
+        else request.getfixturevalue("with_additions")
+
+
+@pytest.fixture(scope="module")
+def bm(root):
+    return harness.load_benchmark(root)
+
+
+def test_appended_cells_and_a_reader_load_with_their_readers(with_additions):
+    """The property itself: a serving cell, a training cell and a per-layer
+    entry appended as data load, each cell with every reader of the
+    accepted cell whose lists it joined (and the serving one with the new
+    reader), and the accepted cells report what they did."""
+    root = with_additions
+    names = lambda cell, r: [m.name for m in  # noqa: E731
+                             harness.load_cell(cell, r).per_layer]
+    for name, config in ADDED_CELLS:
+        row, like = _appended(harness.load_benchmark(ROOT), name, config)
+        cell = harness.load_cell(name, root)
+        assert (cell.config_name, cell.traffic_name, cell.chips) == (
+            config, row["traffic"], row["chips"])
+        want = names(like, ROOT)
+        assert [n for n in names(name, root) if n != ADDED_READER] == want
+        assert [m.name for m in cell.end_to_end] == [
+            m.name for m in harness.load_cell(like, ROOT).end_to_end]
+    assert names(ADDED_CELLS[0][0], root)[-1] == ADDED_READER
+    assert ADDED_READER not in names(ADDED_CELLS[1][0], root)
+    for w in harness.load_benchmark(ROOT)["workloads"]:
+        assert names(w["name"], root) == names(w["name"], ROOT)
 
 
 def _list(root):
@@ -35,15 +144,19 @@ def _list(root):
     return {r["cell"]: r for r in map(json.loads, out.stdout.splitlines())}
 
 
-def test_list_shows_every_cell_with_its_metrics(bm):
-    cells = _list(ROOT)
+def test_list_shows_every_cell_with_its_metrics(root, bm):
+    # ``run.py`` lists the tree it stands in; the copy's readers are links
+    # into this tree, so the copy is listed in this process
+    cells = _list(ROOT) if root == ROOT else {
+        r["cell"]: r for r in harness.list_cells(root)}
     assert set(cells) == {w["name"] for w in bm["workloads"]}
     for row in cells.values():
         assert row["end_to_end"][0] == "setup_s" and len(row["end_to_end"]) > 1
         assert row["per_layer"]
 
 
-def test_adding_files_and_entries_adds_a_cell_and_a_metric(tmp_path, bm):
+def test_adding_files_and_entries_adds_a_cell_and_a_metric(tmp_path,
+                                                           tree_bm):
     """One config file, one workload file, one reader, three entries: the
     copy's ``run.py --list`` shows them, and no file that was there
     changed."""
@@ -64,7 +177,7 @@ def test_adding_files_and_entries_adds_a_cell_and_a_metric(tmp_path, bm):
         'def read(ctx):\n'
         '    return max((d for _, d in ctx.counters["queue_depth"]),'
         ' default=None)\n')
-    new = json.loads(json.dumps(bm))
+    new = json.loads(json.dumps(tree_bm))
     new["configs"].append({"name": "new-model-serve", "source": "paper",
                            "file": "benchmarks/configs/new-model-serve.json",
                            "reduced": [], "why": "a new configuration"})
@@ -119,7 +232,7 @@ def _add_cell(new, name, arch):
 
 
 @pytest.fixture(scope="module")
-def new_arch(tmp_path_factory, bm):
+def new_arch(tmp_path_factory, tree_bm):
     """A copy of the benchmark that gains ONLY new files: a reference and a
     counts module under two new architecture names, configurations that
     name them, entries.  ``root/BENCHMARK.json`` lists the two sound new
@@ -135,7 +248,7 @@ def new_arch(tmp_path_factory, bm):
     for arch in ("new_block", "one_hot"):
         shutil.copy(b / "counts/dense_gqa.py", b / f"counts/{arch}.py")
     cfg = json.loads((b / "configs/smollm3-3b-serve.json").read_text())
-    sound, faulty = json.loads(json.dumps(bm)), json.loads(json.dumps(bm))
+    sound, faulty = (json.loads(json.dumps(tree_bm)) for _ in range(2))
     for arch, name, new in (("new-block", "new-block-serve", sound),
                             ("one-hot", "one-hot-serve", sound),
                             ("no-such-block", "no-module-serve", faulty),
@@ -246,17 +359,19 @@ def test_the_new_architecture_changed_no_file_that_was_there(new_arch):
         "configs/no-module-serve.json", "configs/no-arch-serve.json"}
 
 
-def test_every_configuration_names_an_architecture_that_is_there(bm):
+def test_every_configuration_names_an_architecture_that_is_there(root, bm):
     for c in bm["configs"]:
-        arch = json.loads((ROOT / c["file"]).read_text())["architecture"]
+        arch = json.loads((root / c["file"]).read_text())["architecture"]
         for kind in ("reference", "counts"):
-            assert harness.module_path(kind, arch).is_file()
+            assert harness.module_path(kind, arch,
+                                       root / "benchmarks").is_file()
 
 
-def test_a_reader_that_disagrees_with_its_entry_is_refused(tmp_path, bm):
+def test_a_reader_that_disagrees_with_its_entry_is_refused(tmp_path,
+                                                           tree_bm):
     shutil.copytree(ROOT / "benchmarks", tmp_path / "benchmarks",
                     ignore=shutil.ignore_patterns("__pycache__"))
-    bad = json.loads(json.dumps(bm))
+    bad = json.loads(json.dumps(tree_bm))
     bad["per_layer"][0]["unit"] = "furlongs"
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bad))
     with pytest.raises(harness.BenchmarkError, match="furlongs"):
@@ -270,7 +385,7 @@ def test_an_unknown_cell_names_the_known_ones():
 
 # ------------------------------------------------ the contract's own limits
 
-def test_top_level_keys_and_command(bm):
+def test_top_level_keys_and_command(root, bm):
     assert set(bm) == {"command", "paths", "run_seconds", "configs",
                        "workloads", "end_to_end", "per_layer"}
     assert isinstance(bm["run_seconds"], int) and 1 <= bm["run_seconds"] <= 51
@@ -279,7 +394,7 @@ def test_top_level_keys_and_command(bm):
     script = [w for w in bm["command"] if "/" in w]
     assert all(any(w.startswith(p + "/") for p in bm["paths"])
                and not w.startswith("/") and ".." not in w for w in script)
-    assert len((ROOT / "BENCHMARK.json").read_bytes()) <= 64 * 1024
+    assert len((root / "BENCHMARK.json").read_bytes()) <= 64 * 1024
 
 
 def test_names_units_and_lines(bm):
@@ -342,13 +457,14 @@ def test_cells_configs_and_metrics_hang_together(bm):
         assert all(m["moves"] in mine for m in layer), cell
 
 
-def test_config_files_state_their_cut_and_keep_published_widths(bm):
+def test_config_files_state_their_cut_and_keep_published_widths(root, bm):
     widths = ("hidden_size", "intermediate_size", "num_attention_heads",
               "num_key_value_heads", "vocab_size")
     for c in bm["configs"]:
-        f = json.loads((ROOT / c["file"]).read_text())
+        f = json.loads((root / c["file"]).read_text())
         assert f["source"] == c["source"] and f["reduced"] == c["reduced"]
-        assert f["runner"] in ("train", "serve")
+        assert harness.module_path("runners", f["runner"],
+                                   root / "benchmarks").is_file()
         for k in widths:
             assert f["fields"][k] == f["published"][k], (c["name"], k)
         changed = {k for k, v in f["published"].items()
@@ -359,7 +475,23 @@ def test_config_files_state_their_cut_and_keep_published_widths(bm):
 
 
 def test_layers_are_spelled_one_way(bm):
+    """A metric's ``layer`` is a row of ``PERF.md``'s table of layers (the
+    table headed ``| layer |`` in its section 3, and no other table or
+    section), letter for letter; the seven accepted names stay.  A PR that
+    brings a layer adds its row there."""
     layers = {m["layer"] for m in bm["per_layer"]}
-    assert layers == {"host runtime", "model step", "kernels",
+    assert layers >= {"host runtime", "model step", "kernels",
                       "strategy / collectives", "scheduler", "device",
                       "load generator"}
+    perf = (ROOT / "PERF.md").read_text().splitlines()
+    start = next(i for i, line in enumerate(perf)
+                 if line.startswith("## 3. Layers"))
+    head = next(i for i in range(start, len(perf))
+                if perf[i].startswith("| layer |"))
+    end = next(i for i in range(head, len(perf))
+               if not perf[i].startswith("|"))
+    assert not any(line.startswith("## ") for line in perf[start + 1:end])
+    rows = {line.split("|")[1].strip() for line in perf[head + 2:end]}
+    assert layers <= rows, layers - rows
+    # cells, metrics and configurations are rows of other tables: no layer
+    assert not rows & {w["name"] for w in bm["workloads"]}

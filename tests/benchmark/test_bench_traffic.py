@@ -48,11 +48,18 @@ def test_packed_batches_are_windows_with_shifted_labels():
     assert any((b == PD.DOC_END).any() for b in seen)
 
 
-def test_the_cells_training_stream_has_the_cells_shape():
-    p = _traffic("packed-docs-8k")["params"]
-    ids, labels = next(PD.batches(p, 1, 128256))
-    assert ids.shape == (4, 8192)
-    assert ids.max() < 128256
+@pytest.mark.parametrize("name,shape", [("packed-docs-8k", (4, 8192)),
+                                        ("packed-docs-32k", (1, 32768)),
+                                        ("packed-docs-2k", (16, 2048))])
+def test_the_cells_training_stream_has_the_cells_shape(name, shape):
+    """Every training mix is the same 32,768 tokens a step of the same
+    documents; only the window's length differs."""
+    t = _traffic(name)
+    ids, labels = next(PD.batches(t["params"], 1, 128256))
+    assert ids.shape == labels.shape == shape
+    assert ids.size == 32768 and ids.max() < 128256
+    assert t["params"]["doc_len"] == _traffic("packed-docs-8k")["params"][
+        "doc_len"]
 
 
 @pytest.mark.parametrize("name", ["chat-poisson", "doc-backlog"])
