@@ -42,7 +42,11 @@ held experts as ``num_experts``, this block as ``n_routed_experts``
 (``cfg.held_experts`` reads either).  It serves ``models/swa_moe.py``
 too, whose router chooses by ``s + router_bias`` (an expert layer that
 holds the leaf ``router_bias`` (router width,)) and weighs by ``s``, and
-whose dense layers and sandwich residual are :func:`mlp` as it stands.
+whose dense layers and sandwich residual are :func:`mlp` as it stands; and
+``models/cca_moe.py``, which brings the router's LOGITS itself (an MLP:
+``router_logits``), chooses one expert, weighs it by its softmax
+probability as it is (``NORM_TOPK_PROB = False``) and has no shared expert
+(an expert layer without the leaf ``ws_gate``).
 
 ``w_e`` is normalised over all of ``T`` whether or not its experts are held
 here; what absent experts would add is left out (one rank's part under
@@ -380,27 +384,35 @@ def attention_output(o, gate, x, layer, *, cfg):
 
 # -------------------------------------------------------------------- MLP
 
-def route(r2, w_router, cfg, *, bias=None):
+def route(r2, w_router, cfg, *, bias=None, logits=None):
     """``r2`` (T, H) -> the routing weights of the HELD experts
     (T, held experts) float32, zero where a held expert was not among
-    the row's ``num_experts_per_tok``, and the chosen ids (T, k).  An
+    the row's ``num_experts_per_tok``, and the chosen ids (T, k).  The
+    router's logits are ``r2 w_router`` in float32, or ``logits`` (T,
+    router width) where the block makes its own (``expert_mlp``).  An
     expert's score is the block's ``ROUTER_SCORING``: its own
     ``"sigmoid"``, or a ``"softmax"`` over the router's whole width; the
-    chosen scores are renormalised to sum to ``routed_scaling_factor``
-    either way.  A selection ``bias`` (router width,) float32 is added to
-    the scores to CHOOSE the experts and is no part of their weights."""
-    with jax.default_matmul_precision("highest"):
-        s = r2.astype(jnp.float32) @ w_router.astype(jnp.float32)
-    s = jax.nn.sigmoid(s) if cfg.block_module.ROUTER_SCORING == "sigmoid" \
-        else jax.nn.softmax(s, axis=-1)
+    chosen scores are renormalised to sum to ``routed_scaling_factor``,
+    unless the block declares ``NORM_TOPK_PROB = False``: a chosen score
+    times ``routed_scaling_factor`` is then the weight as it is (a top-1
+    weight renormalised would be 1 whatever the router says).  A selection
+    ``bias`` (router width,) float32 is added to the scores to CHOOSE the
+    experts and is no part of their weights."""
+    blk = cfg.block_module
+    if logits is None:
+        with jax.default_matmul_precision("highest"):
+            logits = r2.astype(jnp.float32) @ w_router.astype(jnp.float32)
+    s = jax.nn.sigmoid(logits) if blk.ROUTER_SCORING == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
     if bias is None:
         top, idx = lax.top_k(s, cfg.num_experts_per_tok)
     else:
         _, idx = lax.top_k(s + bias.astype(jnp.float32),
                            cfg.num_experts_per_tok)
         top = jnp.take_along_axis(s, idx, axis=-1)
-    w = cfg.routed_scaling_factor * top \
-        / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+    w = cfg.routed_scaling_factor * top
+    if getattr(blk, "NORM_TOPK_PROB", True):
+        w = w / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
     held = cfg.expert_offset + jnp.arange(cfg.held_experts)
     hit = idx[:, :, None] == held[None, None, :]                # (T, k, E)
     return jnp.sum(jnp.where(hit, w[:, :, None], 0.0), axis=1), idx
@@ -423,20 +435,25 @@ def _swiglu(r, gate, up, down, dense):
 
 
 def expert_mlp(r2, layer, *, cfg, valid=None):
-    """An expert layer's MLP on the normed rows ``r2`` (B, S, H): the
-    shared expert (gated a token where the layer holds ``ws_sigmoid``) plus
-    this program's held experts' part of the routed sum.  Returns it
-    (before any post-MLP norm) and ``moe_counts`` of the rows ``valid``
-    (B, S) marks (all rows when None)."""
+    """An expert layer's MLP on the normed rows ``r2`` (B, S, H): this
+    program's held experts' part of the routed sum plus, where the layer
+    holds one (``ws_gate``), the shared expert (gated a token where the
+    layer holds ``ws_sigmoid``).  The router is the layer's ``w_router``,
+    or the block's ``router_logits(rows, layer)`` where it brings one.
+    Returns the MLP's output (before any post-MLP norm) and ``moe_counts``
+    of the rows ``valid`` (B, S) marks (all rows when None)."""
     from .transformer import _dense
     B, S, H = r2.shape
     rows = r2.reshape(B * S, H)
     with scope("moe_route"):
-        # the selection bias, where the layer holds one (a keyword only
-        # then: what replaces ``route`` in a test takes three arguments)
-        bias = {"bias": layer["router_bias"]} if "router_bias" in layer \
+        # the selection bias and the block's own logits, where there are
+        # any (keywords only then: what replaces ``route`` in a test takes
+        # three arguments)
+        kw = {"bias": layer["router_bias"]} if "router_bias" in layer \
             else {}
-        w_held, idx = route(rows, layer["w_router"], cfg, **bias)
+        if hasattr(cfg.block_module, "router_logits"):
+            kw["logits"] = cfg.block_module.router_logits(rows, layer)
+        w_held, idx = route(rows, layer.get("w_router"), cfg, **kw)
         ok = jnp.ones((B * S,), jnp.bool_) if valid is None \
             else valid.reshape(-1)
         counts = moe_counts(w_held, idx, ok, cfg)
@@ -445,6 +462,8 @@ def expert_mlp(r2, layer, *, cfg, valid=None):
             rows, w_held, layer["we_gate"], layer["we_up"],
             layer["we_down"], valid=ok,
             per_row=min(cfg.num_experts_per_tok, cfg.held_experts))
+    if "ws_gate" not in layer:
+        return routed.astype(r2.dtype).reshape(B, S, H), counts
     with scope("moe_shared"):
         shared = _swiglu(r2, layer["ws_gate"], layer["ws_up"],
                          layer["ws_down"], _dense(cfg))

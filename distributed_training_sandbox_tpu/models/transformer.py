@@ -256,6 +256,24 @@ class TransformerConfig:
     residual_multiplier: float = 1.0
     attention_multiplier: float = 0.0
     logits_scaling: float = 1.0
+    # Compressed convolutional attention over a top-1 expert layer with an
+    # MLP router and no shared expert (``models/cca_moe.py``; names as in
+    # the published configs of that family).  ``cca_time0`` > 0 selects it:
+    # queries and keys are latents that two causal convolutions mix over
+    # the sequence (a depthwise one of kernel ``cca_time0``, then one
+    # grouped by head of kernel ``cca_time1``), half of a token's values
+    # are the previous token's, the first ``partial_rotary_factor`` of a
+    # head's dims rotate; every layer's MLP is a router MLP of
+    # ``router_hidden_size`` over ``router_width`` experts, of which
+    # ``num_experts`` of ``moe_intermediate_size`` are HELD here (ids
+    # ``expert_offset`` onwards) and ONE is chosen a token, weighed by its
+    # softmax probability as it is (``norm_topk_prob`` False).  A request
+    # holds K/V pages AND a conv tail a slot in every layer.  The family
+    # has no dense MLP: ``intermediate_size`` is None.  Serving only and
+    # the cache-less ``forward``.
+    cca_time0: int = 0
+    cca_time1: int = 0
+    router_hidden_size: int = 0
 
     def __post_init__(self):
         # a configuration file brings a list; the config must hash
@@ -334,6 +352,13 @@ class TransformerConfig:
         return self.mamba_d_state > 0
 
     @property
+    def cca_moe(self) -> bool:
+        """Compressed convolutional attention over a top-1 expert layer
+        (``models/cca_moe.py``), whose every layer's requests hold a conv
+        tail in a slot beside their K/V pages."""
+        return self.cca_time0 > 0
+
+    @property
     def linear_mixer(self):
         """The module that holds the linear mixer of a block whose
         requests keep STATE SLOTS beside pages (what a linear mixer
@@ -350,9 +375,10 @@ class TransformerConfig:
 
     @property
     def state_slots(self) -> bool:
-        """Whether a request of this block holds a state slot
-        (:attr:`linear_mixer`)."""
-        return self.gdn_hybrid or self.ssm_moe
+        """Whether a request of this block holds a slot beside its pages:
+        a linear mixer's state and conv tail (:attr:`linear_mixer`), or a
+        conv tail alone under paged attention (:attr:`cca_moe`)."""
+        return self.gdn_hybrid or self.ssm_moe or self.cca_moe
 
     @property
     def held_experts(self) -> int:
@@ -383,6 +409,9 @@ class TransformerConfig:
         if self.ssm_moe:
             from . import ssm_moe
             return ssm_moe
+        if self.cca_moe:
+            from . import cca_moe
+            return cca_moe
         return None
 
     def param_count(self) -> int:

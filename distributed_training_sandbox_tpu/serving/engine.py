@@ -346,8 +346,9 @@ def _paged_block_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
     mixer output -> mlp`` and asks the module for what differs.
     ``params["layers"]`` is a tuple of per-layer dicts (nothing stacked);
     ``bufs.k[p]`` / ``bufs.v[p]`` are the pools of the ``p``-th PAGED layer
-    and ``bufs.state[j]`` / ``bufs.conv[j]`` the slots of the ``j``-th
-    linear one.  By kind:
+    and ``bufs.state[j]`` the state slots of the ``j``-th linear one,
+    ``bufs.conv[t]`` the conv tails of the ``t``-th layer that holds one
+    (a linear layer or a ``"conv_full"`` one).  By kind:
 
     ``"full"``: K/V rows in whole-context pages through
     :func:`_paged_attend`, the dense block's storage and kernels: written
@@ -362,6 +363,15 @@ def _paged_block_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
     reads ``(p - sliding_window, p]`` through the ordered view
     ``kv_pool.ring_view`` rotates out of the ring once a launch, with a
     lower bound.
+
+    ``"conv_full"``: K/V rows in whole-context pages as ``"full"``, AND a
+    conv tail a slot ``bufs.conv[t]`` (n_slots,) + the block's
+    ``tail_shape``, under a linear layer's rules: the block's
+    ``attention_qkv`` is handed the tail and ``valid`` and returns the new
+    one.  A decode step (row ``b`` IS slot ``b``) reads and writes every
+    slot's tail, a row with ``valid`` False getting its own back bit for
+    bit; a prefill chunk takes its ``slot``'s, or ZEROS when the chunk is
+    the request's first, and writes back what the prompt's rows leave.
 
     ``"latent"``: one row a token and no V pool, through
     :func:`_paged_latent_attend`; a decode step in the kernel absorbs
@@ -392,7 +402,9 @@ def _paged_block_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
     ``rope_tables(apos, cfg)`` (or None for this module's own tables over
     the whole head, the dense block's) and ``NOPE_KINDS``, the kinds whose
     layers are handed no tables; ``mixer_input(x, layer, cfg=)``;
-    ``attention_qkv(r, layer, cfg=, rope=)`` -> ``q, k, v, gate``;
+    ``attention_qkv(r, layer, cfg=, rope=)`` -> ``q, k, v, gate`` (of a
+    ``"conv_full"`` layer: ``(..., tail=, valid=)`` -> ``q, k, v, gate,
+    new tail``, and the module states ``tail_shape(cfg)``);
     ``attention_scale(cfg)`` (None: the kernels' and the gather path's own
     ``1/sqrt(head_dim)``); ``attention_output(attn, gate, x, layer, cfg=)``
     -> ``h``; where it has linear layers ``linear_mixer_output(o, r, x,
@@ -438,8 +450,9 @@ def _paged_block_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
     ``DEVICE_COUNTERS``: the expert layers' ``mla_moe.moe_counts`` summed
     over the layers, where the block has them (from zeros or from the first
     layer's, as the block's accepted programs do: ``COUNTS_FROM_ZERO``);
-    with linear layers the rows of this call whose state was live, a decode
-    step's ``state_slot_steps``; with window layers the cached rows the
+    with slots (linear or ``"conv_full"`` layers) the rows of this call
+    whose state or tail was live, a decode step's ``state_slot_steps`` or
+    ``conv_tail_slot_steps``; with window layers the cached rows the
     valid rows of this call read in ONE window layer and in ONE full layer
     (``window_rows_read``, ``full_rows_read``)."""
     blk, lin = cfg.block_module, cfg.linear_mixer
@@ -457,19 +470,19 @@ def _paged_block_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
                              bufs.k[0].shape[1])
     ks, vs, states, tails = (list(t or ()) for t in (
         bufs.k, bufs.v, bufs.state, bufs.conv))
-    fresh = apos[0, 0] == 0 if states and not decode else None
+    fresh = apos[0, 0] == 0 if tails and not decode else None
     moe = jnp.zeros((len(M.COUNTERS),), jnp.int32) \
         if blk.COUNTS_FROM_ZERO else None
-    p = j = 0
+    p = j = t = 0
     for kind, layer in zip(kinds, params["layers"]):
         if kind == "linear":
             if decode:
-                s0, t0 = states[j], tails[j]
+                s0, t0 = states[j], tails[t]
             else:
                 s0 = lin.unpack_state(
                     jax.lax.dynamic_slice_in_dim(states[j], slot, 1),
                     lin.state_shape(cfg)[0])
-                t0 = jax.lax.dynamic_slice_in_dim(tails[j], slot, 1)
+                t0 = jax.lax.dynamic_slice_in_dim(tails[t], slot, 1)
                 s0 = jnp.where(fresh, jnp.zeros_like(s0), s0)
                 t0 = jnp.where(fresh, jnp.zeros_like(t0), t0)
             with scope("attn_qkv"):
@@ -485,15 +498,15 @@ def _paged_block_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
                     with scope("lin_scan"):
                         o, s1 = lin.chunked_scan(*ins, s0)
             if decode:
-                states[j], tails[j] = s1, t1
+                states[j], tails[t] = s1, t1
             else:
                 states[j] = jax.lax.dynamic_update_slice_in_dim(
                     states[j], lin.pack_state(s1), slot, axis=0)
-                tails[j] = jax.lax.dynamic_update_slice_in_dim(
-                    tails[j], t1.astype(tails[j].dtype), slot, axis=0)
+                tails[t] = jax.lax.dynamic_update_slice_in_dim(
+                    tails[t], t1.astype(tails[t].dtype), slot, axis=0)
             with scope("attn_out"):
                 h = blk.linear_mixer_output(o, r, x, layer, cfg=cfg)
-            j += 1
+            j, t = j + 1, t + 1
         elif kind == "latent":
             in_kernel = paged_kernel and S == 1
             with scope("attn_qkv"):
@@ -513,9 +526,22 @@ def _paged_block_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
         else:
             window = kind == "window"
             with scope("attn_qkv"):
-                q, k, v, gate = blk.attention_qkv(
-                    blk.mixer_input(x, layer, cfg=cfg), layer, cfg=cfg,
-                    rope=None if kind in blk.NOPE_KINDS else rope)
+                r = blk.mixer_input(x, layer, cfg=cfg)
+                rope_l = None if kind in blk.NOPE_KINDS else rope
+                if kind == "conv_full":
+                    t0 = tails[t]
+                    if not decode:
+                        t0 = jax.lax.dynamic_slice_in_dim(t0, slot, 1)
+                        t0 = jnp.where(fresh, jnp.zeros_like(t0), t0)
+                    q, k, v, gate, t1 = blk.attention_qkv(
+                        r, layer, cfg=cfg, rope=rope_l, tail=t0, valid=valid)
+                    tails[t] = t1 if decode \
+                        else jax.lax.dynamic_update_slice_in_dim(
+                            tails[t], t1, slot, axis=0)
+                    t += 1
+                else:
+                    q, k, v, gate = blk.attention_qkv(r, layer, cfg=cfg,
+                                                      rope=rope_l)
                 # the pool's row may hold zero heads after the real ones
                 # (kv_pool.padded_kv_heads): their keys and values are 0,
                 # their queries' outputs are dropped.  A pool stored as the
@@ -545,7 +571,7 @@ def _paged_block_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
             if counts is not None:
                 moe = counts if moe is None else moe + counts
     counted = [] if moe is None else [moe]
-    if states:
+    if tails:
         counted.append(
             jnp.sum(jnp.any(valid, axis=1).astype(jnp.int32))[None])
     if ring is not None:
@@ -973,7 +999,9 @@ class ServingEngine:
             # for a prefix to share, no rollback for a rejected draft, no
             # int8 form, no head axis to shard and no hand-over between
             # pools; nor has a window layer's ring, whose rows of a prefix
-            # are gone once the request has passed the window)
+            # are gone once the request has passed the window, nor a conv
+            # tail beside a layer's pages, which a shared prefix would
+            # need as it stood at the prefix's end)
             for what, asked in (
                     ("kv_quant", kv_quant), ("a tp mesh", mesh is not None),
                     ("spec_k", spec_k), ("flash_prefill", flash_prefill),
@@ -1326,9 +1354,11 @@ class ServingEngine:
         # router's whole width, those whose expert is held here, held
         # experts that got a row (summed over layers and steps), expert
         # layers x steps.  With state slots: the live states a
-        # step read and wrote (``state_slot_steps``); the other two
-        # counters are the host's (slots reset at a grant, valid rows the
-        # prefill chunks scanned), as is ``lin_step_inplace_steps``: decode
+        # step read and wrote (``state_slot_steps``; with conv tails
+        # beside pages and no state, ``conv_tail_slot_steps``); the other
+        # two counters are the host's, for a block with a linear mixer
+        # (slots reset at a grant, valid rows the prefill chunks
+        # scanned), as is ``lin_step_inplace_steps``: decode
         # steps whose recurrence was the step kernel, which moves a live
         # state once in and once out in place and no other
         self._device_counters = device_counters(self.cfg)
@@ -1471,7 +1501,7 @@ class ServingEngine:
         stream, sp = self._stream, self._req_attrs(req)
         rows = min(Ck, req.n_prompt - pos)
         # what the stage ships: page row, ids, two scalars, and the
-        # hybrids' batch slot or the window class's ring
+        # batch slot of a block with slots or the window class's ring
         slot_put = int(self.cfg.state_slots)
         n_put = 4 + slot_put + bool(self.ring_pages)
         t_chunk = time.perf_counter()  # clock-ok
@@ -1500,9 +1530,10 @@ class ServingEngine:
                                   self.cfg.sliding_window)
                 self.stats["window_pairs_prefilled"] += int(seen.sum())
             if self.cfg.state_slots:
-                # the batch slot whose state the chunk carries on
+                # the batch slot whose state or tail the chunk carries on
                 args += (self._put(np.int32(req.slot), dev),)
-                self.stats["lin_scan_rows"] += rows
+                if self.cfg.linear_mixer is not None:
+                    self.stats["lin_scan_rows"] += rows
         final = pos + Ck >= req.n_prompt
         with maybe_span(stream, "serve/prefill_dispatch", rows=rows,
                         head=int(final), **sp):
@@ -1978,7 +2009,7 @@ class ServingEngine:
                         self._h_rings[req.slot, :len(req.pages_window)] \
                             = req.pages_window
                     self.stats["queue_wait_s"] += req.t_admit - req.t_submit
-                    if self.cfg.state_slots:
+                    if self.cfg.linear_mixer is not None:
                         # the granted slot's state: its first prefill
                         # chunk starts from zeros (_paged_block_forward)
                         self.stats["state_resets"] += 1

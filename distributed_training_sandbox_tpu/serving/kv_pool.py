@@ -83,10 +83,20 @@ import jax.numpy as jnp
 LATENT_ROW_ALIGN = 128
 
 
+#: kinds whose layers hold something in a request's batch SLOT
+SLOT_KINDS = ("linear", "conv_full")
+
+
+def slot_kinds(cfg) -> list[str]:
+    """The kinds of the layers that hold a slot, in layer order (empty for
+    a block whose every layer only pages)."""
+    return [kind for kind in layer_kinds(cfg) if kind in SLOT_KINDS]
+
+
 def layer_kinds(cfg) -> tuple[str, ...]:
     """One entry a layer, of ``"full"``, ``"window"``, ``"latent"``,
-    ``"linear"`` (module docstring): the block module's declaration; every
-    layer of the dense block is ``"full"``."""
+    ``"linear"``, ``"conv_full"`` (module docstring): the block module's
+    declaration; every layer of the dense block is ``"full"``."""
     blk = cfg.block_module
     if blk is None:
         return ("full",) * cfg.num_hidden_layers
@@ -117,7 +127,7 @@ def row_layout(cfg, tp: int = 1) -> tuple[tuple[int, ...], bool]:
     if "latent" in kinds:
         w = cfg.block_module.row_width(cfg)
         return (w + -w % LATENT_ROW_ALIGN,), False
-    if "linear" in kinds:   # no int8 rows, no tp mesh: see ``slab_pool``
+    if slot_kinds(cfg):     # no int8 rows, no tp mesh: see ``slab_pool``
         return (padded_kv_heads(cfg.num_key_value_heads, cfg.dtype),
                 cfg.resolved_head_dim), True
     return (cfg.num_key_value_heads // tp, cfg.resolved_head_dim), True
@@ -134,12 +144,12 @@ def slab_pool(cfg) -> bool:
     whole pool to make one, for every layer in every step (compiled for a
     v5e: twelve 268 MB copies a decode step).  At a head dim of 128 the
     4-D array's bytes ARE the slab's, whatever the head count, and the
-    pool stays 4-D.  Only beside state slots, where the engine refuses what
-    still indexes a pool by ``(page, offset, head)``: int8 rows with their
-    scales, the hand-over between pools, a tp mesh over the head axis.  A
-    slab's rows hold the model's heads and no zero head (heads that were
-    padded fill whole sublane tiles), which the engine relies on."""
-    if "linear" not in layer_kinds(cfg):
+    pool stays 4-D.  Only beside slots (``SLOT_KINDS``), where the engine
+    refuses what still indexes a pool by ``(page, offset, head)``: int8 rows
+    with their scales, the hand-over between pools, a tp mesh over the head
+    axis.  A slab's rows hold the model's heads and no zero head (heads that
+    were padded fill whole sublane tiles), which the engine relies on."""
+    if not slot_kinds(cfg):
         return False
     (heads, hd), _ = row_layout(cfg)
     return hd > 128 and heads % (32 // jnp.dtype(cfg.dtype).itemsize) != 0
@@ -194,11 +204,21 @@ def paged_layers(cfg) -> int:
     return sum(kind != "linear" for kind in layer_kinds(cfg))
 
 
+def tail_shape(cfg, kind: str) -> tuple[int, ...]:
+    """One slot's conv tail in one layer of a slot ``kind``, as the linear
+    mixer or, for ``"conv_full"``, the block module states it."""
+    owner = cfg.linear_mixer if kind == "linear" else cfg.block_module
+    return owner.tail_shape(cfg)
+
+
 def slot_state_bytes(cfg) -> int:
-    """Bytes ONE batch slot holds in state slots over all layers (0 for a
-    block whose every layer is paged)."""
-    n_lin = layer_kinds(cfg).count("linear")
-    return n_lin * cfg.linear_mixer.slot_state_bytes(cfg) if n_lin else 0
+    """Bytes ONE batch slot holds in slots over all layers (0 for a block
+    that holds nothing a slot): a linear layer's state and tail, a
+    ``"conv_full"`` layer's tail."""
+    item = jnp.dtype(cfg.dtype).itemsize
+    return sum(cfg.linear_mixer.slot_state_bytes(cfg) if kind == "linear"
+               else math.prod(tail_shape(cfg, kind)) * item
+               for kind in slot_kinds(cfg))
 
 
 def token_row_bytes(cfg, *, kv_quant: bool = False, tp: int = 1) -> int:
@@ -222,8 +242,10 @@ class PoolBuffers(NamedTuple):
     None where the layers are ``"latent"``, whose one row a token lives in
     ``k``.  ``k_scale``/``v_scale`` are the
     f32 row scales of the int8 pool, None for the ``cfg.dtype`` pool.
-    ``state``/``conv`` are the state slots of the ``"linear"`` layers, one
-    array a linear layer, None without."""
+    ``state`` are the state slots of the ``"linear"`` layers, one array a
+    linear layer, ``conv`` the conv tails of the layers that hold one
+    (``"linear"`` and ``"conv_full"``), one array a such layer in layer
+    order; None without."""
     k: tuple            # L × (n_pages, page_size, n_kv, hd) | (.., .., W)
     #                     | (n_pages, page_size * n_kv, hd): slab_pool
     #                     | a window layer: (n_pages_window, page_size, ..)
@@ -232,6 +254,7 @@ class PoolBuffers(NamedTuple):
     v_scale: tuple | None
     state: tuple | None = None  # (n_slots, dk, heads * dv) f32 a linear layer
     conv: tuple | None = None   # (n_slots, K - 1, channels) a linear layer
+    #                             | (n_slots, 2 C + hd) a conv_full layer
 
 
 class PageAllocator:
@@ -504,12 +527,12 @@ class PagedKVPool:
                 "page class for its window layers: pass n_pages_window "
                 ">= 2 (max_batch rings of kv_pool.ring_pages + the null "
                 "page), and neither kv_quant nor a mesh")
-        if "linear" in kinds and (kv_quant or mesh is not None
-                                  or n_slots < 1):
+        slotted = slot_kinds(cfg)
+        if slotted and (kv_quant or mesh is not None or n_slots < 1):
             raise ValueError(
-                "the pool of a block with linear layers holds a float "
-                "state slot per batch slot: pass n_slots >= 1, and neither "
-                "kv_quant nor a mesh")
+                "the pool of a block with linear layers, or with conv "
+                "tails beside its pages, holds a float slot per batch "
+                "slot: pass n_slots >= 1, and neither kv_quant nor a mesh")
         self.cfg = cfg
         self.n_pages = int(n_pages)
         self.page_size = int(page_size)
@@ -546,13 +569,15 @@ class PagedKVPool:
                        for _ in range(L))
         state = conv = None
         n_lin = kinds.count("linear")
-        self.n_slots = int(n_slots) if n_lin else 0
+        self.n_slots = int(n_slots) if slotted else 0
         if self.n_slots:
-            lin = cfg.linear_mixer
-            state = tuple(put(jnp.zeros((self.n_slots,) + lin.slot_shape(cfg),
-                                        jnp.float32)) for _ in range(n_lin))
-            conv = tuple(put(jnp.zeros((self.n_slots,) + lin.tail_shape(cfg),
-                                       cfg.dtype)) for _ in range(n_lin))
+            if n_lin:
+                slot = (self.n_slots,) + cfg.linear_mixer.slot_shape(cfg)
+                state = tuple(put(jnp.zeros(slot, jnp.float32))
+                              for _ in range(n_lin))
+            conv = tuple(put(jnp.zeros(
+                (self.n_slots,) + tail_shape(cfg, kind), cfg.dtype))
+                for kind in slotted)
         self.bufs = PoolBuffers(k=k, v=v, k_scale=ks, v_scale=vs,
                                 state=state, conv=conv)
         self.allocator = PageAllocator(self.n_pages)

@@ -210,6 +210,19 @@ WINDOW_SUBSCOPES = (
 )
 
 
+#: A declared second level beneath ``attn_qkv`` in the block of compressed
+#: convolutional attention (``models/cca_moe.py``, whose ``attention_qkv``
+#: opens it, in a decode step and in a prefill chunk): what stands between
+#: the latents' projection and the heads' norms.  A tuple of its own, since
+#: tests pin the older ones; ``benchmarks/layer_metrics/_ccascopes.py``
+#: holds a copy, pinned by a test.
+CCA_SUBSCOPES = (
+    "cca_conv",  # both causal convolutions over the latents continued from
+                 # the slot's tail, the q-k mean, the value shift, the
+                 # tail's update
+)
+
+
 def scope(name: str):
     """Device-side marker for code *inside* jit: prefixes XLA op names so
     collectives/matmuls attribute to the phase in the trace.  Writes

@@ -82,7 +82,7 @@ def programs(root: Path):
         # the facts the engine reads: state slots beside pages, a second
         # page class (a ring a slot, a second table), latent rows
         kinds = layer_kinds(mcfg)
-        slots = {"n_slots": B} if "linear" in kinds else {}
+        slots = {"n_slots": B} if mcfg.state_slots else {}
         tables = lambda b: i32(b, P)  # noqa: E731
         if "window" in kinds:
             R = ring_pages(mcfg, page, eng["prefill_chunk"])
@@ -99,7 +99,7 @@ def programs(root: Path):
         prefill = E.make_serve_prefill_step(
             mcfg, paged_kernel="latent" not in kinds).trace(
             bufs, params, tables(1), i32(1, eng["prefill_chunk"]), i32(),
-            i32(), *((i32(),) if "linear" in kinds else ()))
+            i32(), *((i32(),) if mcfg.state_slots else ()))
         for name, traced in (("decode", decode), ("prefill", prefill)):
             yield cell.name, name, traced.lower(
                 lowering_platforms=("tpu",)).as_text()

@@ -1,8 +1,8 @@
-"""The six blocks the serving engine builds, at a tiny size, float32,
+"""The seven blocks the serving engine builds, at a tiny size, float32,
 seeded weights: the configurations that the blocks' own test files
 (``tests/test_mla_moe.py``, ``tests/test_gdn_hybrid.py``,
 ``tests/test_gdn_moe.py``, ``tests/test_swa_moe.py``,
-``tests/test_ssm_moe.py``) and the tests that run over ALL blocks share,
+``tests/test_ssm_moe.py``, ``tests/test_cca_moe.py``) and the tests that run over ALL blocks share,
 keyed as the benchmark keys its plain float32 references
 (``benchmarks/reference/<architecture>.py``)."""
 
@@ -72,6 +72,14 @@ FIELDS = {
         num_experts_per_tok=3, shared_intermediate_size=48,
         embedding_multiplier=12.0, residual_multiplier=0.22,
         attention_multiplier=0.03125, logits_scaling=4.0),
+    "cca_moe": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=None,
+        num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=16, rope_theta=5e6, rms_norm_eps=1e-5,
+        tie_word_embeddings=True, nope_interval=0, cca_time0=2, cca_time1=2,
+        partial_rotary_factor=0.5, router_hidden_size=32, num_experts=4,
+        router_width=8, expert_offset=4, num_experts_per_tok=1,
+        moe_intermediate_size=32),
 }
 BLOCKS = tuple(FIELDS)
 

@@ -6,6 +6,7 @@ read of a block module.  Import-only: no engine is built, nothing compiles.
 
 import importlib
 import json
+import math
 import re
 from pathlib import Path
 
@@ -26,9 +27,10 @@ BLOCKS = {
     "gdn_moe": "qwen3-next-80b-ep16-l24-serve",
     "swa_moe": "trinity-large-ep32-l5-serve",
     "ssm_moe": "granite-4.0-h-small-ep4-l10-serve",
+    "cca_moe": "zaya1-8b-l16-serve",
 }
 
-KINDS = {"full", "window", "latent", "linear"}
+KINDS = {"full", "window", "latent", "linear", "conv_full"}
 
 #: what ``serving/`` reads of every block module ...
 BLOCK_NAMES = (
@@ -42,6 +44,7 @@ NAMES_BY_KIND = {
     "latent": ("latent_qkv", "absorb_queries", "attend_paged",
                "unabsorb_values", "row_width"),
     "linear": ("linear_mixer_output",),
+    "conv_full": ("attention_qkv", "PAGED_ATTENTION_SCOPE", "tail_shape"),
 }
 #: what they read of ``cfg.linear_mixer``
 MIXER_NAMES = (
@@ -75,7 +78,9 @@ def test_a_block_declares_its_layer_kinds_and_what_the_loop_reads(block):
     assert [name for name in wanted if not hasattr(mod, name)] == []
     assert set(mod.NOPE_KINDS) <= KINDS
     lin = cfg.linear_mixer
-    assert (lin is not None) == ("linear" in kinds) == bool(cfg.state_slots)
+    assert (lin is not None) == ("linear" in kinds)
+    assert bool(cfg.state_slots) == bool(
+        set(kinds) & set(kv_pool.SLOT_KINDS))
     if lin is not None:
         assert [name for name in MIXER_NAMES if not hasattr(lin, name)] == []
 
@@ -84,24 +89,30 @@ def test_a_block_declares_its_layer_kinds_and_what_the_loop_reads(block):
     assert E.device_counters(cfg) == device
     assert device == mod.COUNTERS[:len(device)]
     tail = ("state_slot_steps",) * ("linear" in kinds) \
+        + ("conv_tail_slot_steps",) * ("conv_full" in kinds) \
         + ("window_rows_read", "full_rows_read") * ("window" in kinds)
     assert device[len(device) - len(tail):] == tail
 
     # the pool is sized from the same list
     n_paged = sum(kind != "linear" for kind in kinds)
     n_linear, n_window = kinds.count("linear"), kinds.count("window")
+    n_conv = kinds.count("conv_full")
     assert kv_pool.paged_layers(cfg) == n_paged
     assert kv_pool.slot_state_bytes(cfg) == (
-        n_linear * lin.slot_state_bytes(cfg) if n_linear else 0)
+        n_linear * lin.slot_state_bytes(cfg) if n_linear else 0) + (
+        n_conv * math.prod(mod.tail_shape(cfg))
+        * jnp.dtype(cfg.dtype).itemsize if n_conv else 0)
     bufs = jax.eval_shape(lambda: kv_pool.PagedKVPool(
-        cfg, 5, 16, **({"n_slots": 2} if n_linear else {}),
+        cfg, 5, 16, **({"n_slots": 2} if n_linear + n_conv else {}),
         **({"n_pages_window": 3} if n_window else {})).bufs)
     assert len(bufs.k) == n_paged
     assert [a.shape[0] for a in bufs.k] == [
         3 if kind == "window" else 5 for kind in kinds if kind != "linear"]
     assert (bufs.v is None) == ("latent" in kinds)
-    assert len(bufs.state or ()) == len(bufs.conv or ()) == n_linear
-    assert all(a.shape[0] == 2 for a in bufs.state or ())
+    assert len(bufs.state or ()) == n_linear
+    assert len(bufs.conv or ()) == n_linear + n_conv
+    assert all(a.shape[0] == 2
+               for a in (bufs.state or ()) + (bufs.conv or ()))
 
 
 def test_every_layer_of_the_dense_block_is_full():
@@ -116,7 +127,7 @@ def test_serving_asks_a_config_facts_and_never_a_blocks_name():
     """``serving/`` and the program-hash script read ``layer_kinds`` and
     what a block module declares; none tests ``cfg.<block's name>``."""
     by_name = re.compile(
-        r"cfg\.(mla_moe|gdn_hybrid|gdn_moe|swa_moe|ssm_moe)\b")
+        r"cfg\.(mla_moe|gdn_hybrid|gdn_moe|swa_moe|ssm_moe|cca_moe)\b")
     files = sorted((ROOT / "distributed_training_sandbox_tpu"
                     / "serving").glob("*.py"))
     files.append(ROOT / "scripts" / "serving_program_hash.py")
