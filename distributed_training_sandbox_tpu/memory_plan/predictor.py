@@ -228,9 +228,23 @@ def analytic_waterline(cfg, *, batch: int, seq: int, ws: int = 1,
     # one layer's transient working set (freed before the loss phase);
     # low-precision matmuls add the live microbatch's quantize buffers
     working = micro * seq * dot_bytes * (1.5 if lp_mm else 1.0)
-    if getattr(cfg, "attention_impl", "xla") == "xla":
+    impl = getattr(cfg, "attention_impl", "xla")
+    if impl == "xla":
         # unfused attention materializes fp32 scores (B, n, S, S)
         working += micro * nq * seq * seq * 4
+    elif impl == "flash" and seq % 128 == 0:
+        # the splash kernel's fused backward writes one dq partial a KV
+        # block, each of q's shape and dtype, and sums them outside it
+        # (a window that is not lane-aligned the kernel refuses).  They
+        # live beside q, k, v, the output, its cotangent and the three
+        # gradients, not beside the MLP's tensors, so the phase counts
+        # only where it is the larger one: a v5e's compiler plans no
+        # more for SMOLLM3_3B_L8's step with 1 GB of them than without
+        from ..models.transformer import flash_backward_blocks
+        _, block_kv, _ = flash_backward_blocks(seq)
+        partials = (seq // block_kv) * nq
+        working = max(working, micro * seq * hd * itemsize * (
+            4 * (nq + cfg.num_key_value_heads) + partials))
     # tp shards every projection output (and its heads) column-wise, so
     # both the policy-saved dots and the live working set divide by it
     saved /= tp_ways

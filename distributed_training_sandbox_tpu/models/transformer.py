@@ -635,11 +635,37 @@ def _attention_xla(q, k, v, scale: float) -> jax.Array:
 FLASH_RESIDUALS = "splash_residuals"
 
 
+def flash_backward_blocks(S: int) -> tuple[int, int, int]:
+    """``(block_q_dkv, block_kv_dkv, block_kv_dkv_compute)`` of the splash
+    kernel's fused backward at a window of ``S`` positions.  That kernel
+    writes one dq partial a KV block, ``(S / block_kv_dkv, n_q, S, hd)``
+    in q's dtype, so the KV block follows the window: the smallest
+    lane-aligned divisor of ``S`` that is at least an eighth of it (eight
+    partials or fewer), between 1024 and 4096.  A larger block divides
+    the partials' bytes and multiplies the masked work on the diagonal
+    (~``block_kv_dkv / S`` of the kernel's); past 4096 rows at head_dim
+    128 the kernel's K, V and float32 dk / dv blocks no longer fit a
+    v5e's scoped VMEM, and nor does a q block over 1024 beside a 512-row
+    compute block.  Swept on the v5e at 2k, 8k and 32k, the kernel alone
+    and the step (PERF.md section 6, PR 48)."""
+    lanes = S // 128
+    least = min(S, 4096, max(1024, S / 8))
+    bkv = next(128 * d for d in range(1, lanes + 1)
+               if lanes % d == 0 and 128 * d >= least)
+    # the compute block must itself be a multiple of 128 that divides bkv
+    bkv_c = next(c for c in (512, 384, 256, 128) if bkv % c == 0)
+    return min(1024, S), bkv, bkv_c
+
+
 def _attention_flash(q, k, v, scale: float) -> jax.Array:
     """Fused Pallas TPU attention (splash kernel): never materializes the
     S×S score matrix in HBM, handles GQA natively (no kv repeat), causal
     blocks skipped above the diagonal.  Block sizes 512/1024 measured ~2×
-    over the kernel defaults at seq 8192 on v5e.  The seq-8192 path."""
+    over the kernel defaults at seq 8192 on v5e.  The backward is ONE
+    kernel (the library's fused form) that forms dS once and takes dk, dv
+    and a dq partial a KV block from it; the partials are summed outside
+    it (``jnp.sum``, which accumulates bfloat16 in float32).  The
+    seq-8192 path."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
     B, S, nq, hd = q.shape
@@ -653,14 +679,15 @@ def _attention_flash(q, k, v, scale: float) -> jax.Array:
     bq, bkv = min(512, S), min(1024, S)
     # block_kv_compute must itself be a multiple of 128
     bkv_c = bkv // 2 if bkv % 256 == 0 else bkv
+    bq_dkv, bkv_dkv, bkv_dkv_c = flash_backward_blocks(S)
     mask = sm.MultiHeadMask([sm.CausalMask((S, S)) for _ in range(nq)])
     kernel = sk.make_splash_mha_single_device(
         mask=mask,
         block_sizes=sk.BlockSizes(
             block_q=bq, block_kv=bkv, block_kv_compute=bkv_c,
-            block_q_dkv=bq, block_kv_dkv=bkv,
-            block_kv_dkv_compute=bkv_c,
-            block_q_dq=bq, block_kv_dq=bkv),
+            block_q_dkv=bq_dkv, block_kv_dkv=bkv_dkv,
+            block_kv_dkv_compute=bkv_dkv_c,
+            use_fused_bwd_kernel=True),
         residual_checkpoint_name=FLASH_RESIDUALS)
 
     def one(q1, k1, v1):  # (S, n, hd) -> kernel layout (n, S, hd)

@@ -1,7 +1,11 @@
 """The splash kernel's forward runs once a step: under
 ``attention_impl="flash"`` every remat policy keeps the kernel's own
 residuals (its output and its log-sum-exp, ``T.FLASH_RESIDUALS``), so the
-layer's recomputation in the backward scan does not re-run it.
+layer's recomputation in the backward scan does not re-run it.  And its
+backward is one kernel a layer (the library's fused form, which makes
+dq beside dk and dv): the rule that sizes its KV block from the window,
+the calls counted in the jaxpr, and its gradients against the plain
+attention's in the library's interpret mode.
 
 CPU only, at the jaxpr / StableHLO level: tracing a ``pallas_call`` and
 lowering it for the TPU platform need no chip; nothing here is a time."""
@@ -12,6 +16,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax._src.ad_checkpoint import saved_residuals
 from jax._src.config import traceback_in_locations_limit
@@ -57,6 +62,24 @@ def as_parent(monkeypatch):
     monkeypatch.setattr(sk, "make_splash_mha_single_device", make_unnamed)
 
 
+@pytest.fixture
+def unfused_backward(monkeypatch):
+    """The kernel as it was built before the backward was fused: a dq
+    kernel of its own beside dkv, both at the forward's blocks."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+    blocks = sk.BlockSizes
+
+    def unfused(*, use_fused_bwd_kernel, block_q, block_kv, **kw):
+        assert use_fused_bwd_kernel
+        kw.update(block_q_dkv=block_q, block_kv_dkv=block_kv,
+                  block_kv_dkv_compute=kw["block_kv_compute"])
+        return blocks(block_q=block_q, block_kv=block_kv,
+                      block_q_dq=block_q, block_kv_dq=block_kv, **kw)
+
+    monkeypatch.setattr(sk, "BlockSizes", unfused)
+
+
 def _param_shapes(cfg):
     return jax.eval_shape(lambda k: T.init_params(k, cfg), jax.random.key(0))
 
@@ -74,15 +97,17 @@ def _subjaxprs(eqn):
                 yield inner
 
 
-def _kernels(jaxpr) -> list[str]:
-    """Names of the Pallas calls in ``jaxpr``, nested ones included."""
-    out = []
+def _pallas_eqns(jaxpr):
+    """The Pallas calls in ``jaxpr``, nested ones included."""
     for e in jaxpr.eqns:
         if e.primitive.name == "pallas_call":
-            out.append(e.params["name"])
+            yield e
         for j in _subjaxprs(e):
-            out += _kernels(j)
-    return out
+            yield from _pallas_eqns(j)
+
+
+def _kernels(jaxpr) -> list[str]:
+    return [e.params["name"] for e in _pallas_eqns(jaxpr)]
 
 
 def _kernel_scans(jaxpr) -> list[tuple[list[str], list]]:
@@ -107,12 +132,12 @@ def _kernel_scans(jaxpr) -> list[tuple[list[str], list]]:
 def test_flash_forward_runs_once_under_every_policy(policy):
     """Forward scan: the forward kernel, once, with its two residuals
     among the scan's stacked outputs at (n_q, S, hd) bf16 and (n_q, S)
-    float32 a layer; backward scan: dq and dkv and no forward."""
+    float32 a layer; backward scan: ONE kernel, the fused dkv that makes
+    dq too, and no forward."""
     cfg = dataclasses.replace(FLASH, remat=True, remat_policy=policy)
     (fwd, stacked), (bwd, _) = _kernel_scans(_grad_jaxpr(cfg).jaxpr)
     assert fwd == ["splash_mha_fwd_residuals"]
-    assert sorted(bwd) == ["splash_mha_dkv_no_residuals",
-                           "splash_mha_dq_no_residuals"]
+    assert bwd == ["splash_mha_dkv_no_residuals"]
     assert ((L, 1, NQ, S, HD), "bfloat16") in stacked
     assert ((L, 1, NQ, S), "float32") in stacked
 
@@ -124,7 +149,6 @@ def test_the_parent_ran_the_forward_in_both_scans(policy, as_parent):
     (fwd, stacked), (bwd, _) = _kernel_scans(_grad_jaxpr(cfg).jaxpr)
     assert fwd == ["splash_mha_fwd_residuals"]
     assert sorted(bwd) == ["splash_mha_dkv_no_residuals",
-                           "splash_mha_dq_no_residuals",
                            "splash_mha_fwd_residuals"]
     assert ((L, 1, NQ, S), "float32") not in stacked
 
@@ -250,7 +274,7 @@ def test_offload_parks_the_named_saves_and_keeps_the_residuals(
 
 # ------------------------------------------------- the FSDP step, for a TPU
 
-def _lower_fsdp_step_for_tpu() -> str:
+def _fsdp_step_and_arguments():
     mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2], register=False)
     params = T.init_params(jax.random.key(0), FLASH)
     shardings = jax.tree.map(lambda s: NamedSharding(mesh, s),
@@ -260,8 +284,12 @@ def _lower_fsdp_step_for_tpu() -> str:
     step = fsdp.make_fsdp_train_step(shards, FLASH, mesh)
     batch = jax.device_put((jnp.zeros((2, S), jnp.int32),) * 2,
                            NamedSharding(mesh, P("dp")))
-    return _tpu_text(step.trace(shards, fsdp.init_fsdp_opt_state(shards),
-                                batch))
+    return step, (shards, fsdp.init_fsdp_opt_state(shards), batch)
+
+
+def _lower_fsdp_step_for_tpu() -> str:
+    step, arguments = _fsdp_step_and_arguments()
+    return _tpu_text(step.trace(*arguments))
 
 
 def _functions(module: str) -> collections.Counter:
@@ -291,7 +319,7 @@ def test_fsdp_step_differs_from_the_parents_only_in_the_layer_scans(
     monkeypatch.undo()
     change = _lower_fsdp_step_for_tpu()
     for kernel, (was, now) in {"splash_mha_fwd": (2, 1),
-                               "splash_mha_dq": (1, 1),
+                               "splash_mha_dq": (0, 0),
                                "splash_mha_dkv": (1, 1)}.items():
         assert (parent.count(kernel), change.count(kernel)) == (was, now)
     was, now = _functions(parent), _functions(change)
@@ -311,3 +339,111 @@ def test_fsdp_step_differs_from_the_parents_only_in_the_layer_scans(
             count(came, "_splash_attention")) == (2, 1)
     shared = [k for k, _ in was & now]
     assert shared.count("closed_call") == 1      # the loss head's one block
+
+
+# ------------------------------------------- the backward is one kernel
+
+@pytest.mark.parametrize("policy", POLICIES)
+def test_the_parent_ran_two_backward_kernels(policy, unfused_backward):
+    """What the fused backward removed, and that the fixture is the
+    parent: a dq kernel beside dkv in the backward scan."""
+    cfg = dataclasses.replace(FLASH, remat=True, remat_policy=policy)
+    (fwd, _), (bwd, _) = _kernel_scans(_grad_jaxpr(cfg).jaxpr)
+    assert fwd == ["splash_mha_fwd_residuals"]
+    assert sorted(bwd) == ["splash_mha_dkv_no_residuals",
+                           "splash_mha_dq_no_residuals"]
+
+
+def _fsdp_step_kernels() -> list[list[str]]:
+    """The Pallas calls of the explicit-FSDP step's layer scans, forward
+    scan first, from the step's jaxpr (the step does not run)."""
+    step, arguments = _fsdp_step_and_arguments()
+    return [names for names, _ in
+            _kernel_scans(step.trace(*arguments).jaxpr.jaxpr)]
+
+
+def test_fsdp_step_backward_holds_one_attention_kernel():
+    assert _fsdp_step_kernels() == [["splash_mha_fwd_residuals"],
+                                    ["splash_mha_dkv_no_residuals"]]
+
+
+def test_the_parents_fsdp_step_backward_held_two(unfused_backward):
+    fwd, bwd = _fsdp_step_kernels()
+    assert fwd == ["splash_mha_fwd_residuals"]
+    assert sorted(bwd) == ["splash_mha_dkv_no_residuals",
+                           "splash_mha_dq_no_residuals"]
+
+
+@pytest.mark.parametrize("seq", [256, 2048, 8192, 32768])
+def test_backward_kv_block_follows_the_window(seq):
+    """The rule by itself, and the kernel the library builds from it, by
+    shape only: the KV block divides the window, its compute block is a
+    multiple of 128 that divides it, and the fused kernel writes at most
+    8 dq partials, each of q's shape and dtype."""
+    bq, bkv, bkv_c = T.flash_backward_blocks(seq)
+    assert seq % bkv == 0 and seq % bq == 0
+    assert bkv_c % 128 == 0 and bkv % bkv_c == 0
+    assert seq // bkv <= 8 and bkv >= min(seq, 1024)
+    q = jax.ShapeDtypeStruct((1, seq, NQ, HD), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, seq, 1, HD), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: T._attention_flash(q, k, v, 1.0).sum().astype(
+            jnp.float32), argnums=(0, 1, 2)))(q, kv, kv)
+    fwd, bwd = _pallas_eqns(jaxpr.jaxpr)
+    assert bwd.params["name"] == "splash_mha_dkv_no_residuals"
+    # (a batch of one is squeezed away by the call's batching rule)
+    shapes = [(v.aval.shape[-4:], str(v.aval.dtype)) for v in bwd.outvars]
+    assert ((seq // bkv, NQ, seq, HD), "bfloat16") in shapes
+
+
+@pytest.mark.parametrize("seq,want", [(128, 128), (640, 640), (1152, 1152),
+                                      (3072, 1024), (12288, 1536),
+                                      (16384, 2048), (65536, 4096)])
+def test_backward_kv_block_at_any_lane_aligned_window(seq, want):
+    """Any multiple of 128: the smallest lane-aligned divisor of the
+    window that is at least an eighth of it, between 1024 and 4096."""
+    bq, bkv, bkv_c = T.flash_backward_blocks(seq)
+    assert bkv == want and bq == min(1024, seq)
+    assert bkv % bkv_c == 0 and bkv_c % 128 == 0
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """The library's kernels in its ``interpret=True`` mode, and a KV
+    block of half the window for the backward, so that there ARE two dq
+    partials to sum at a size the CPU runs."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+    make = sk.make_splash_mha_single_device
+    monkeypatch.setattr(
+        sk, "make_splash_mha_single_device",
+        lambda *a, **kw: make(*a, interpret=True, **kw))
+    monkeypatch.setattr(T, "flash_backward_blocks",
+                        lambda seq: (128, seq // 2, 128))
+
+
+@pytest.mark.kernels
+@pytest.mark.parametrize("batch,hd", [(1, 128), (2, 64)])
+def test_fused_backward_matches_plain_attention(batch, hd, interpreted):
+    """dq, dk, dv of ``_attention_flash`` against ``_attention_xla``'s in
+    float32, GQA with 4 query heads on 2 KV heads over 512 positions;
+    the second case has a batch, which the kernel is vmapped over."""
+    seq, nq, nkv = 512, 4, 2
+    ks = jax.random.split(jax.random.key(batch), 4)
+    q = jax.random.normal(ks[0], (batch, seq, nq, hd), jnp.float32)
+    k, v = (jax.random.normal(x, (batch, seq, nkv, hd), jnp.float32)
+            for x in ks[1:3])
+    w = jax.random.normal(ks[3], (batch, seq, nq, hd), jnp.float32)
+    scale = hd ** -0.5
+
+    def grads(attend):
+        return jax.grad(lambda *a: jnp.sum(attend(*a, scale) * w),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    jaxpr = jax.make_jaxpr(lambda: grads(T._attention_flash))()
+    _, bwd = _pallas_eqns(jaxpr.jaxpr)
+    assert (2, nq, seq, hd) in [v.aval.shape[-4:] for v in bwd.outvars]
+    for got, want in zip(grads(T._attention_flash), grads(T._attention_xla)):
+        want = np.asarray(want)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=0,
+                                   atol=2e-6 * np.abs(want).max())

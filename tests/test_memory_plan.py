@@ -479,3 +479,28 @@ def test_analytic_counts_the_flash_residuals_under_every_policy(policy):
         # the named tensors park on the host; the kernel's stay
         assert saved(flash, offload="opt_act") == pytest.approx(kept,
                                                                 rel=1e-9)
+
+
+@pytest.mark.parametrize("seq,micro", [(2048, 16), (8192, 4), (32768, 1)])
+def test_analytic_counts_the_fused_backwards_dq_partials(seq, micro):
+    """The splash kernel's fused backward writes one dq partial a KV
+    block, each of q's shape, beside q, k, v, the output, its cotangent
+    and their three gradients: ``micro·seq·hd·itemsize·(4·(n_q + n_kv) +
+    (seq / block_kv)·n_q)`` at the KV block the kernel's own rule gives
+    that window.  A layer's working set is that phase where it is larger
+    than the projections' (a narrow MLP), and the projections' where it
+    is not (the flagship's widths, at every window of the cells)."""
+    working = lambda cfg: MP.analytic_waterline(  # noqa: E731
+        cfg, batch=micro, seq=seq, ws=1).components["layer_working"]
+    scores = micro * 16 * seq * seq * 4 / GB    # the plain path's own
+    plain = lambda cfg: working(dataclasses.replace(  # noqa: E731
+        cfg, attention_impl="xla")) - scores
+    wide = T.SMOLLM3_3B_L8
+    assert wide.attention_impl == "flash"
+    _, block_kv, _ = T.flash_backward_blocks(seq)
+    phase = micro * seq * 128 * 2 * (4 * (16 + 4)
+                                     + (seq // block_kv) * 16) / GB
+    assert plain(wide) > phase and working(wide) == plain(wide)
+    narrow = dataclasses.replace(wide, intermediate_size=2048)
+    assert plain(narrow) < phase
+    assert working(narrow) == pytest.approx(phase, rel=1e-9)
