@@ -315,6 +315,7 @@ def _build_serve_decode(strategy: str, *, mesh=None, scale: int = 100,
     from ..models.generate import _decode_cfg
     from ..parallel import tensor
     from ..serving import PagedKVPool, make_serve_decode_step
+    from ..serving.engine import _dense_serving_tree
     from ..utils import make_mesh, set_seed
     from .hlo_lint import param_shapes
 
@@ -331,7 +332,9 @@ def _build_serve_decode(strategy: str, *, mesh=None, scale: int = 100,
     shapes = param_shapes(params, min_numel=1024)
     ctx = ContractContext.capture(params=params, mesh=mesh,
                                   n_layers=mcfg.num_hidden_layers)
-    shards = tensor.shard_params_tp(params, mesh)
+    # the tree an engine's programs read: the fused q, k, v leaves too
+    shards = _dense_serving_tree(tensor.shard_params_tp(params, mesh),
+                                 _decode_cfg(mcfg), mesh, "tp")
     page_size, pages_per = 8, 4
     pool = PagedKVPool(_decode_cfg(mcfg),
                        batch_size * pages_per + 1, page_size,
@@ -373,6 +376,7 @@ def _build_serve_frontier(strategy: str, *, mesh=None, scale: int = 100,
     from ..parallel import tensor
     from ..serving import (PagedKVPool, make_serve_prefill_batch_step,
                            make_serve_spec_verify_step)
+    from ..serving.engine import _dense_serving_tree
     from ..utils import make_mesh, set_seed
     from .hlo_lint import param_shapes
 
@@ -389,7 +393,9 @@ def _build_serve_frontier(strategy: str, *, mesh=None, scale: int = 100,
     shapes = param_shapes(params, min_numel=1024)
     ctx = ContractContext.capture(params=params, mesh=mesh,
                                   n_layers=mcfg.num_hidden_layers)
-    shards = tensor.shard_params_tp(params, mesh)
+    # the tree an engine's programs read: the fused q, k, v leaves too
+    shards = _dense_serving_tree(tensor.shard_params_tp(params, mesh),
+                                 _decode_cfg(mcfg), mesh, "tp")
     page_size, pages_per = 8, 4
     pool = PagedKVPool(_decode_cfg(mcfg),
                        batch_size * pages_per + 1, page_size,

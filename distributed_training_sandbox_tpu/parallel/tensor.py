@@ -56,16 +56,18 @@ def tp_specs(params, axis: str = "tp") -> dict:
     row-parallel ones (wo, w_down) shard dim 1.  MoE expert leaves are
     (L, E, in, out): the SAME column/row roles one dim later — each
     expert's FFN is Megatron-split across the tp group (w_router, like
-    every other dense leaf, replicated)."""
+    every other dense leaf, replicated).  A serving engine's fused
+    per-layer ``wqkv`` leaves (``serving/engine.py:_dense_serving_tree``)
+    are (in, out) with each shard's q, k and v columns side by side:
+    column-parallel too."""
     row = {"wo", "w_down"}
-    col = {"wq", "wk", "wv", "w_gate", "w_up"}
+    col = {"wq", "wk", "wv", "wqkv", "w_gate", "w_up"}
 
     def leaf_spec(path, leaf):
         name = next((getattr(k, "key", None) for k in reversed(path)
                      if getattr(k, "key", None)), None)
-        if name in col:
-            return (P(None, None, None, axis) if leaf.ndim == 4
-                    else P(None, None, axis))
+        if name in col:     # the output dim is the last one
+            return P(*(None,) * (leaf.ndim - 1), axis)
         if name in row:
             return (P(None, None, axis, None) if leaf.ndim == 4
                     else P(None, axis, None))

@@ -77,8 +77,10 @@ def programs(root: Path):
         eng = cell.traffic["engine"]
         B, page = eng["max_batch"], eng["page_size"]
         P = -(-eng["max_seq_len"] // page)
-        params = jax.eval_shape(
-            lambda: T.init_params(jax.random.key(0), mcfg))
+        # the tree the engine's programs read (the dense block's holds
+        # its fused q, k, v leaves beside the caller's)
+        params = jax.eval_shape(lambda: E._dense_serving_tree(
+            T.init_params(jax.random.key(0), mcfg), E._decode_cfg(mcfg)))
         # the facts the engine reads: state slots beside pages, a second
         # page class (a ring a slot, a second table), latent rows
         kinds = layer_kinds(mcfg)
