@@ -274,6 +274,18 @@ class TransformerConfig:
     cca_time0: int = 0
     cca_time1: int = 0
     router_hidden_size: int = 0
+    # A dense stack whose layers run several times with the same weights
+    # (``models/loop_dense.py``; names as in the published configs of that
+    # family).  ``total_ut_steps`` > 0 selects it: every token runs the
+    # ``num_hidden_layers`` weight layers that many times, a pass after a
+    # pass, each pass with K/V of its own in every layer
+    # (:attr:`layer_passes`); sandwich norms, the final norm and an exit
+    # gate at the end of every pass, and the head reads the state of the
+    # first pass whose cumulated exit probability reaches
+    # ``early_exit_threshold`` (1.0: the last pass's).  Untied head.
+    # Serving only and the cache-less ``forward``.
+    total_ut_steps: int = 0
+    early_exit_threshold: float = 1.0
 
     def __post_init__(self):
         # a configuration file brings a list; the config must hash
@@ -359,6 +371,20 @@ class TransformerConfig:
         return self.cca_time0 > 0
 
     @property
+    def loop_dense(self) -> bool:
+        """A dense stack whose layers run ``total_ut_steps`` times with the
+        same weights (``models/loop_dense.py``), each pass with K/V pages
+        of its own."""
+        return self.total_ut_steps > 0
+
+    @property
+    def layer_passes(self) -> int:
+        """How many times a token runs the weight layers, each pass with
+        caches of its own: a layer holds this many caches a request
+        (``serving/kv_pool.py``).  1 for every block but the looped one."""
+        return self.total_ut_steps or 1
+
+    @property
     def linear_mixer(self):
         """The module that holds the linear mixer of a block whose
         requests keep STATE SLOTS beside pages (what a linear mixer
@@ -412,6 +438,9 @@ class TransformerConfig:
         if self.cca_moe:
             from . import cca_moe
             return cca_moe
+        if self.loop_dense:
+            from . import loop_dense
+            return loop_dense
         return None
 
     def param_count(self) -> int:

@@ -360,7 +360,23 @@ def _paged_block_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
     ``bufs.k[p]`` / ``bufs.v[p]`` are the pools of the ``p``-th PAGED layer
     and ``bufs.state[j]`` the state slots of the ``j``-th linear one,
     ``bufs.conv[t]`` the conv tails of the ``t``-th layer that holds one
-    (a linear layer or a ``"conv_full"`` one).  By kind:
+    (a linear layer or a ``"conv_full"`` one).
+
+    A WEIGHT layer and a CACHE are not the same walk.  A block whose layers
+    run ``cfg.layer_passes`` = T times with the same weights
+    (``models/loop_dense.py``) walks ``params["layers"]`` T times, the
+    indices into ``bufs`` running on: pass ``n`` of weight layer ``l``
+    reads and writes cache ``n . L + l`` and no other pass's, under the
+    one page table.  Such a block also brings what happens BETWEEN passes
+    and after the last: ``pass_end(x, params, n, exits, cfg=)`` -> the
+    state the next pass starts from and the running exit choice (its
+    final norm and exit gate; ``exits`` None before pass 0), and
+    ``exit_choice(exits, valid, cfg=)`` -> the ONE state a row that the
+    head reads, already normed (the module's ``final_norm`` on the seam
+    leaves it alone, and :func:`_all_logits` / :func:`_first_token` run the
+    head once a row, never once a pass), and its device counters.  Both
+    under ``sample`` / ``loop_gate`` (``profiling.LOOP_SUBSCOPES``).  By
+    kind:
 
     ``"full"``: K/V rows in whole-context pages through
     :func:`_paged_attend`, the dense block's storage and kernels: written
@@ -466,7 +482,10 @@ def _paged_block_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
     whose state or tail was live, a decode step's ``state_slot_steps`` or
     ``conv_tail_slot_steps``; with window layers the cached rows the
     valid rows of this call read in ONE window layer and in ONE full layer
-    (``window_rows_read``, ``full_rows_read``)."""
+    (``window_rows_read``, ``full_rows_read``); of a looped block, over the
+    valid rows of this call, the passes run, the 1-based pass whose state
+    reached the head and the rows where that was not the last
+    (``ut_passes``, ``exit_step_sum``, ``early_exit_rows``)."""
     blk, lin = cfg.block_module, cfg.linear_mixer
     kinds = blk.layer_kinds(cfg)
     decode = slot is None
@@ -486,7 +505,13 @@ def _paged_block_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
     moe = jnp.zeros((len(M.COUNTERS),), jnp.int32) \
         if blk.COUNTS_FROM_ZERO else None
     p = j = t = 0
-    for kind, layer in zip(kinds, params["layers"]):
+    # the walk over CACHES: ``cfg.layer_passes`` passes over the weight
+    # layers, cache ``i`` being pass ``i // L`` of weight layer ``i % L``
+    # (one pass, so one cache a layer, for every block but a looped one)
+    L, passes = len(kinds), cfg.layer_passes
+    looped, exits = hasattr(blk, "pass_end"), None
+    for i, (kind, layer) in enumerate(zip(kinds * passes,
+                                          params["layers"] * passes)):
         if kind == "linear":
             if decode:
                 s0, t0 = states[j], tails[t]
@@ -582,7 +607,14 @@ def _paged_block_forward(params, ids, cfg, bufs: PoolBuffers, pages, apos,
             x, counts = blk.mlp(h, layer, cfg=cfg, valid=valid)
             if counts is not None:
                 moe = counts if moe is None else moe + counts
+        if looped and (i + 1) % L == 0:
+            with _scopes("sample", "loop_gate"):
+                x, exits = blk.pass_end(x, params, i // L, exits, cfg=cfg)
     counted = [] if moe is None else [moe]
+    if looped:
+        with _scopes("sample", "loop_gate"):
+            x, exit_counts = blk.exit_choice(exits, valid, cfg=cfg)
+        counted.append(exit_counts)
     if tails:
         counted.append(
             jnp.sum(jnp.any(valid, axis=1).astype(jnp.int32))[None])
@@ -1111,12 +1143,16 @@ class ServingEngine:
             # pools; nor has a window layer's ring, whose rows of a prefix
             # are gone once the request has passed the window, nor a conv
             # tail beside a layer's pages, which a shared prefix would
-            # need as it stood at the prefix's end)
+            # need as it stood at the prefix's end).  A block whose every
+            # cache is whole-context K/V rows under the one table says so
+            # (``PREFIX_CACHE``): a prefix's pages alias in all its caches
+            # at once, however many passes a layer runs
             for what, asked in (
                     ("kv_quant", kv_quant), ("a tp mesh", mesh is not None),
                     ("spec_k", spec_k), ("flash_prefill", flash_prefill),
                     ("disaggregate", disaggregate),
-                    ("prefix_cache", prefix_cache),
+                    ("prefix_cache", prefix_cache and not getattr(
+                        self.cfg.block_module, "PREFIX_CACHE", False)),
                     ("hbm_budget_gb", hbm_budget_gb is not None
                      and "window" in kinds)):
                 if asked:
@@ -1467,7 +1503,10 @@ class ServingEngine:
         # (slots reset at a grant, valid rows the prefill chunks
         # scanned), as is ``lin_step_inplace_steps``: decode
         # steps whose recurrence was the step kernel, which moves a live
-        # state once in and once out in place and no other
+        # state once in and once out in place and no other.  A looped
+        # block's: passes run, the 1-based pass whose state reached the
+        # head, and rows whose state was not the last pass's, over the
+        # rows the decode steps sampled
         self._device_counters = device_counters(self.cfg)
         if self.cfg.block_module is not None:
             self.stats.update(dict.fromkeys(

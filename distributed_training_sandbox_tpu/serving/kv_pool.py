@@ -40,7 +40,12 @@ tracks the current buffers plus the free list.
 What a layer keeps for a request follows from its KIND, and
 :func:`layer_kinds` is the one list everything here is sized from (a block
 module declares it: ``cfg.block_module.layer_kinds``; every layer of the
-dense GQA block is ``"full"``):
+dense GQA block is ``"full"``).  It lists CACHES, which are not always one
+a weight layer: a block whose layers run ``cfg.layer_passes`` = T times
+with the same weights (``models/loop_dense.py``) keeps pass ``t``'s rows
+apart from every other pass's, T caches a weight layer, ``T x L`` arrays in
+:class:`PoolBuffers`.  The page table and the allocators do not change with
+it: one grant of pages serves every cache, as it serves every layer.
 
   * ``"full"``: K and V rows of ``(n_kv, hd)`` a token, in whole-context
     pages;
@@ -94,13 +99,17 @@ def slot_kinds(cfg) -> list[str]:
 
 
 def layer_kinds(cfg) -> tuple[str, ...]:
-    """One entry a layer, of ``"full"``, ``"window"``, ``"latent"``,
-    ``"linear"``, ``"conv_full"`` (module docstring): the block module's
-    declaration; every layer of the dense block is ``"full"``."""
+    """One entry a CACHE, of ``"full"``, ``"window"``, ``"latent"``,
+    ``"linear"``, ``"conv_full"`` (module docstring), in the order the
+    serving loop walks them: the block module's declaration, one entry a
+    weight layer, once a pass (``cfg.layer_passes``: cache ``t . L + l`` is
+    pass ``t`` of weight layer ``l``; one pass, so one cache a layer, for
+    every block but the looped one); every layer of the dense block is
+    ``"full"``."""
     blk = cfg.block_module
     if blk is None:
         return ("full",) * cfg.num_hidden_layers
-    return blk.layer_kinds(cfg)
+    return blk.layer_kinds(cfg) * cfg.layer_passes
 
 
 def padded_kv_heads(n_kv: int, dtype) -> int:
@@ -197,9 +206,9 @@ def ring_view(ring, apos, window: int, page: int):
 
 
 def paged_layers(cfg) -> int:
-    """Layers whose tokens cache a row in pages: what every sizing of the
-    pool multiplies :func:`token_row_bytes` by.  All of them (of either
-    page class), but for the linear layers, which hold a state slot
+    """Caches in which a token holds a row in pages: what every sizing of
+    the pool multiplies :func:`token_row_bytes` by.  One a layer a pass (of
+    either page class), but for the linear layers, which hold a state slot
     instead."""
     return sum(kind != "linear" for kind in layer_kinds(cfg))
 
@@ -232,8 +241,8 @@ def token_row_bytes(cfg, *, kv_quant: bool = False, tp: int = 1) -> int:
 
 
 class PoolBuffers(NamedTuple):
-    """The device half of the pool: per-layer page-block arrays (tuples
-    of L arrays, one per PAGED layer, mirroring ``KVCache``'s
+    """The device half of the pool: per-cache page-block arrays (tuples
+    of L arrays, one per PAGED layer and pass, mirroring ``KVCache``'s
     per-layer-buffer decision — a stacked (L, ...) layout would pay a
     dynamic-slice copy per layer per step), each ``(n_pages, page_size) +
     row shape`` (:func:`row_layout`); with two page classes a WINDOW

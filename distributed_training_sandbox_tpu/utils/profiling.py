@@ -223,6 +223,21 @@ CCA_SUBSCOPES = (
 )
 
 
+#: A declared second level beneath ``sample`` in the looped dense block
+#: (``models/loop_dense.py``; ``serving/engine._paged_block_forward`` opens
+#: it at the end of every pass and once after the last, in a decode step and
+#: in a prefill chunk): the model's final norm where a pass ends, the exit
+#: gate, the exit distribution and the choice of the one state a row that
+#: the head reads.  A reader of ``SCOPES`` books these ops to ``sample``
+#: (final norm + unembedding + argmax); ``benchmarks/layer_metrics/
+#: _loopscopes.py`` holds a copy of this tuple, pinned by a test, and reads
+#: them apart.
+LOOP_SUBSCOPES = (
+    "loop_gate",  # pass-end final norm, sigmoid gate, cumulated exit
+                  # probability, the running choice of h_e, the counters
+)
+
+
 def scope(name: str):
     """Device-side marker for code *inside* jit: prefixes XLA op names so
     collectives/matmuls attribute to the phase in the trace.  Writes

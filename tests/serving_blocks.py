@@ -1,8 +1,9 @@
-"""The seven blocks the serving engine builds, at a tiny size, float32,
+"""The eight blocks the serving engine builds, at a tiny size, float32,
 seeded weights: the configurations that the blocks' own test files
 (``tests/test_mla_moe.py``, ``tests/test_gdn_hybrid.py``,
 ``tests/test_gdn_moe.py``, ``tests/test_swa_moe.py``,
-``tests/test_ssm_moe.py``, ``tests/test_cca_moe.py``) and the tests that run over ALL blocks share,
+``tests/test_ssm_moe.py``, ``tests/test_cca_moe.py``,
+``tests/test_loop_dense.py``) and the tests that run over ALL blocks share,
 keyed as the benchmark keys its plain float32 references
 (``benchmarks/reference/<architecture>.py``)."""
 
@@ -80,6 +81,14 @@ FIELDS = {
         partial_rotary_factor=0.5, router_hidden_size=32, num_experts=4,
         router_width=8, expert_offset=4, num_experts_per_tok=1,
         moe_intermediate_size=32),
+    # the exit threshold below the published 1.0, so that rows leave at
+    # different passes and every device counter moves
+    "loop_dense": dict(
+        vocab_size=512, hidden_size=64, intermediate_size=160,
+        num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=4,
+        head_dim=16, rope_theta=1e6, rms_norm_eps=1e-6,
+        tie_word_embeddings=False, nope_interval=0, total_ut_steps=3,
+        early_exit_threshold=0.5),
 }
 BLOCKS = tuple(FIELDS)
 
@@ -105,3 +114,57 @@ def reference_tokens(block: str, fields, params, prompt, tokens) -> list:
     z = ref.logits_at(params, jnp.asarray(seq, jnp.int32), jnp.asarray(pos),
                       fields, block=len(seq))
     return [int(t) for t in np.asarray(jnp.argmax(z, axis=-1))]
+
+
+def serve_logits(params, cfg, prompt, n_new, *, kernel=False, chunk=16,
+                 slots=3, slot=1, bufs=None, page=8, seq=64):
+    """Chunked prefill and then decode of ONE request of a block module
+    through the engine's own cores (``engine._paged_forward``), tapped for
+    logits: ``(logits (n_new, V), the pool's buffers afterwards, the
+    device-side counters summed over the decode steps, the request's page
+    row)``.  ``bufs``: the pool to start from (default: a zeroed one)."""
+    from distributed_training_sandbox_tpu.serving import engine as E
+    from distributed_training_sandbox_tpu.serving.kv_pool import PagedKVPool
+    P = seq // page
+    pool = PagedKVPool(cfg, slots * P + 1, page,
+                       **({"n_slots": slots} if cfg.state_slots else {}))
+    pages = np.zeros((slots, P), np.int32)
+    pages[slot] = pool.allocator.alloc(P)
+    bufs = pool.bufs if bufs is None else bufs
+
+    @jax.jit
+    def prefill(bufs, ids, pos, plen):
+        apos = pos + jnp.arange(chunk, dtype=jnp.int32)[None, :]
+        x, bufs, _ = E._paged_forward(
+            params, ids, cfg, bufs, jnp.asarray(pages[slot:slot + 1]), apos,
+            apos < plen, paged_kernel=kernel, slot=jnp.int32(slot))
+        return E._all_logits(params, x, cfg), bufs
+
+    @jax.jit
+    def decode(bufs, toks, lengths, active):
+        x, bufs, counts = E._paged_forward(
+            params, toks[:, None], cfg, bufs, jnp.asarray(pages),
+            lengths[:, None], active[:, None], paged_kernel=kernel)
+        return E._last_logits(params, x, cfg), bufs, counts
+
+    n = len(prompt)
+    for pos in range(0, n, chunk):
+        ids = np.zeros((1, chunk), np.int32)
+        part = prompt[pos:pos + chunk]
+        ids[0, :len(part)] = part
+        z, bufs = prefill(bufs, jnp.asarray(ids), jnp.int32(pos),
+                          jnp.int32(n))
+    out = [z[0, (n - 1) % chunk]]
+    active = np.zeros(slots, bool)
+    active[slot] = True
+    counted = np.zeros(len(E.device_counters(cfg)), np.int64)
+    for i in range(n_new - 1):
+        toks = np.full(slots, 7, np.int32)      # inactive slots: any token
+        toks[slot] = int(jnp.argmax(out[-1]))
+        lengths = np.zeros(slots, np.int32)
+        lengths[slot] = n + i
+        z, bufs, counts = decode(bufs, jnp.asarray(toks),
+                                 jnp.asarray(lengths), jnp.asarray(active))
+        out.append(z[slot])
+        counted += np.asarray(counts)
+    return jnp.stack(out), bufs, counted, pages[slot]

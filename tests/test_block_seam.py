@@ -28,6 +28,7 @@ BLOCKS = {
     "swa_moe": "trinity-large-ep32-l5-serve",
     "ssm_moe": "granite-4.0-h-small-ep4-l10-serve",
     "cca_moe": "zaya1-8b-l16-serve",
+    "loop_dense": "ouro-2.6b-serve",
 }
 
 KINDS = {"full", "window", "latent", "linear", "conv_full"}
@@ -46,6 +47,8 @@ NAMES_BY_KIND = {
     "linear": ("linear_mixer_output",),
     "conv_full": ("attention_qkv", "PAGED_ATTENTION_SCOPE", "tail_shape"),
 }
+#: ... and of one whose layers run several passes (``cfg.layer_passes``)
+PASS_NAMES = ("pass_end", "exit_choice")
 #: what they read of ``cfg.linear_mixer``
 MIXER_NAMES = (
     "state_shape", "slot_shape", "tail_shape", "slot_state_bytes",
@@ -68,9 +71,14 @@ def test_a_block_declares_its_layer_kinds_and_what_the_loop_reads(block):
     cfg = _benchmark_config(BLOCKS[block])
     assert cfg.block_module is mod
 
+    # one entry a WEIGHT layer; the pool counts caches, one a layer a pass
     kinds = mod.layer_kinds(cfg)
     assert isinstance(kinds, tuple) and set(kinds) <= KINDS
     assert len(kinds) == cfg.num_hidden_layers
+    passes = cfg.layer_passes
+    assert passes == (4 if block == "loop_dense" else 1)
+    assert all(hasattr(mod, name) for name in PASS_NAMES) == (passes > 1)
+    kinds = kinds * passes
     assert kv_pool.layer_kinds(cfg) == kinds
 
     wanted = BLOCK_NAMES + tuple(
@@ -90,7 +98,8 @@ def test_a_block_declares_its_layer_kinds_and_what_the_loop_reads(block):
     assert device == mod.COUNTERS[:len(device)]
     tail = ("state_slot_steps",) * ("linear" in kinds) \
         + ("conv_tail_slot_steps",) * ("conv_full" in kinds) \
-        + ("window_rows_read", "full_rows_read") * ("window" in kinds)
+        + ("window_rows_read", "full_rows_read") * ("window" in kinds) \
+        + ("ut_passes", "exit_step_sum", "early_exit_rows") * (passes > 1)
     assert device[len(device) - len(tail):] == tail
 
     # the pool is sized from the same list
@@ -127,7 +136,8 @@ def test_serving_asks_a_config_facts_and_never_a_blocks_name():
     """``serving/`` and the program-hash script read ``layer_kinds`` and
     what a block module declares; none tests ``cfg.<block's name>``."""
     by_name = re.compile(
-        r"cfg\.(mla_moe|gdn_hybrid|gdn_moe|swa_moe|ssm_moe|cca_moe)\b")
+        r"cfg\.(mla_moe|gdn_hybrid|gdn_moe|swa_moe|ssm_moe|cca_moe|"
+        r"loop_dense)\b")
     files = sorted((ROOT / "distributed_training_sandbox_tpu"
                     / "serving").glob("*.py"))
     files.append(ROOT / "scripts" / "serving_program_hash.py")

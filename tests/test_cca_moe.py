@@ -28,6 +28,7 @@ from distributed_training_sandbox_tpu.serving import engine as E  # noqa: E402
 from distributed_training_sandbox_tpu.serving import kv_pool  # noqa: E402
 from distributed_training_sandbox_tpu.serving.kv_pool import PagedKVPool  # noqa: E402
 from tests.serving_blocks import FIELDS as BLOCK_FIELDS  # noqa: E402
+from tests.serving_blocks import serve_logits  # noqa: E402
 
 FIELDS = BLOCK_FIELDS["cca_moe"]
 #: latent channels and tail width of the tiny model: (4 + 2) x 16, 2 C + hd
@@ -107,52 +108,9 @@ def _pool(cfg, slots, page=8, seq=64):
     return PagedKVPool(cfg, slots * P + 1, page, n_slots=slots), P
 
 
-def _serve_logits(params, cfg, prompt, n_new, *, kernel=False, chunk=16,
-                  slots=3, slot=1, bufs=None):
-    """Chunked prefill and then decode of ONE request through the engine's
-    own cores, tapped for logits; the pool's buffers afterwards; and the
-    device-side counters summed over the decode steps."""
-    pool, P = _pool(cfg, slots)
-    pages = np.zeros((slots, P), np.int32)
-    pages[slot] = pool.allocator.alloc(P)
-    bufs = pool.bufs if bufs is None else bufs
-
-    @jax.jit
-    def prefill(bufs, ids, pos, plen):
-        apos = pos + jnp.arange(chunk, dtype=jnp.int32)[None, :]
-        x, bufs, _ = E._paged_forward(
-            params, ids, cfg, bufs, jnp.asarray(pages[slot:slot + 1]), apos,
-            apos < plen, paged_kernel=kernel, slot=jnp.int32(slot))
-        return E._all_logits(params, x, cfg), bufs
-
-    @jax.jit
-    def decode(bufs, toks, lengths, active):
-        x, bufs, counts = E._paged_forward(
-            params, toks[:, None], cfg, bufs, jnp.asarray(pages),
-            lengths[:, None], active[:, None], paged_kernel=kernel)
-        return E._last_logits(params, x, cfg), bufs, counts
-
-    n = len(prompt)
-    for pos in range(0, n, chunk):
-        ids = np.zeros((1, chunk), np.int32)
-        part = prompt[pos:pos + chunk]
-        ids[0, :len(part)] = part
-        z, bufs = prefill(bufs, jnp.asarray(ids), jnp.int32(pos),
-                          jnp.int32(n))
-    out = [z[0, (n - 1) % chunk]]
-    active = np.zeros(slots, bool)
-    active[slot] = True
-    counted = np.zeros(5, np.int64)
-    for i in range(n_new - 1):
-        toks = np.full(slots, 7, np.int32)      # inactive slots: any token
-        toks[slot] = int(jnp.argmax(out[-1]))
-        lengths = np.zeros(slots, np.int32)
-        lengths[slot] = n + i
-        z, bufs, counts = decode(bufs, jnp.asarray(toks),
-                                 jnp.asarray(lengths), jnp.asarray(active))
-        out.append(z[slot])
-        counted += np.asarray(counts)
-    return jnp.stack(out), bufs, counted
+def _serve_logits(*args, **kw):
+    """``serving_blocks.serve_logits`` without the request's page row."""
+    return serve_logits(*args, **kw)[:3]
 
 
 def _reference_logits(params, fields, prompt, z):
