@@ -286,10 +286,10 @@ def serve_phase(model_const: str, runs: Path, bitwise: bool) -> None:
 
 # ---------------------------------------------------------------- kernels
 
-def paged_attention_xla(qg, pk, pv, pages, apos, probs_dtype):
+def paged_attention_xla(qg, pk, pv, pages, apos, probs_dtype, lo=None):
     """The engine's gather-then-einsum attention core
     (``serving.engine._paged_attend``), the reference both serving
-    kernels replace."""
+    kernels replace; ``lo`` (B, S): a window layer's lower bound."""
     import jax
     import jax.numpy as jnp
     B, S, nkv, rep, hd = qg.shape
@@ -299,6 +299,9 @@ def paged_attention_xla(qg, pk, pv, pages, apos, probs_dtype):
     scores = jnp.einsum("bsgrh,bgkh->bgrsk", qg, vk,
                         preferred_element_type=jnp.float32) / math.sqrt(hd)
     vis = jnp.arange(V)[None, None, :] <= apos[:, :, None]
+    if lo is not None:
+        vis = jnp.logical_and(vis, jnp.arange(V)[None, None, :]
+                              >= lo[:, :, None])
     scores = jnp.where(vis[:, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bgrsk,bgkh->bsgrh", probs.astype(probs_dtype), vv,

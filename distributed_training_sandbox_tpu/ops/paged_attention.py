@@ -15,11 +15,27 @@ engine's default decode path on a TPU; the design of
 ``jax.experimental.pallas.ops.tpu.paged_attention``, adapted to this
 repo's page-major pool).  The page table and the per-slot lengths ride
 in by scalar prefetch (SMEM); the pools stay in HBM; one grid step per
-slot copies that slot's pages to VMEM by async DMA in double-buffered
-blocks of ``PAGES_PER_BLOCK`` pages and runs an online softmax over the
-blocks, in a loop bounded by the slot's own length: a slot of length 0
-starts no copy and emits zeros, and no block past a slot's length is
-read.  One page of the pool is one contiguous
+slot multiplies that slot's pages, which async DMAs bring to VMEM in
+blocks of ``PAGES_PER_BLOCK`` pages, and runs an online softmax over the
+blocks.  The copy schedule (:func:`pages_copied` states it):
+
+* of a block, only the pages that hold a position the slot sees are
+  copied, i.e. table entries ``lo // page_size`` up to the one that holds
+  the slot's last position: no page past a slot's length, and for a
+  window layer none wholly under its lower bound.  The rows of the
+  buffer behind a page that is not copied keep what they held; their
+  columns are masked, and the V halves are zeroed once a call so that
+  what they held is never a NaN;
+* the copies run ahead of the products ACROSS grid steps: while a block
+  is multiplied the next one is in flight into the other half of the
+  double buffer, and after a slot's last block that is the first block
+  of the next slot that reads anything (slots of length 0 are skipped
+  over: they start nothing, wait for nothing and emit zeros).  Only the
+  call's first block is waited for with nothing else to do.  Which half
+  is in flight is carried from grid step to grid step in SMEM scratch,
+  as the library's kernel does.
+
+One page of the pool is one contiguous
 ``(page_size * n_kv, hd)`` slab that carries every KV head, so it is one
 copy; the kernel contracts the slot's ``n_kv * rep`` query rows against
 ALL the slab's rows on the MXU and masks the columns of the other KV
@@ -54,7 +70,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["paged_attention_decode", "paged_latent_attention_decode",
-           "decode_kernel_takes", "refuse_on_tpu"]
+           "decode_kernel_takes", "pages_copied", "refuse_on_tpu"]
 
 # What Pallas' TPU lowering says to the int8 decode kernel, and said to
 # the flash-prefill kernel before PR 27 (jax 0.9.0, libtpu 0.0.34, TPU v5
@@ -96,13 +112,31 @@ def _gather_pool(pool_ref, pages_ref, n_slot_pages: int, page: int):
     return jax.lax.fori_loop(0, n_slot_pages, load, acc0)
 
 
-# Pages per DMA block of the float kernel: 16 pages of 16 tokens are 256
+# Pages per DMA block of the kernels: 16 pages of 16 tokens are 256
 # positions a block, 1,024 score columns with 4 KV heads, 1 MB of VMEM
-# for both pools' double buffers.  On a v5e 4 and 8 were slower
-# everywhere, 32 slower for chat's ~300-token slots (a block is read
-# whole) and faster only past ~4k tokens a slot (PERF.md §6, PR 24).
-# Tables shorter than that are one block.
+# for both pools' double buffers.  The block is what one product
+# multiplies and what the double buffer holds, no longer what is copied:
+# the float kernel copies a block's live pages alone (the latent kernel
+# still copies it whole).  16 was chosen on a v5e under the float kernel's
+# OLD schedule (whole blocks, a wait at every slot's head: 4 and 8 slower
+# everywhere, 32 slower for chat's ~300-token slots and faster only past
+# ~4k tokens a slot; PERF.md §6, PR 24) and has not been swept again
+# under this one (PERF.md §7, PR 51).  Tables shorter than that are one
+# block.
 PAGES_PER_BLOCK = 16
+
+
+def pages_copied(length, page_size: int, lo=0):
+    """The float decode kernel's copy schedule as a count: how many pages
+    of each pool (K and V alike) the kernel copies for a slot that sees
+    positions ``lo <= s < length``: its table entries from
+    ``lo // page_size`` up to and including the one that holds position
+    ``length - 1``, each once; 0 for a slot of length 0.  Pure, numpy,
+    broadcasting over ``length`` and ``lo``: the engine counts with it
+    (``stats["paged_pages_copied"]``) and tests/test_kernels.py holds the
+    kernel to it."""
+    length, lo = np.asarray(length), np.maximum(np.asarray(lo), 0)
+    return np.maximum(-(-length // page_size) - lo // page_size, 0)
 
 
 def decode_kernel_takes(dtype, head_dim: int, page_size: int) -> bool:
@@ -120,47 +154,120 @@ def decode_kernel_takes(dtype, head_dim: int, page_size: int) -> bool:
 def _decode_kernel(len_ref, pages_ref, *refs, table_pages: int,
                    block_pages: int, page: int, rep: int, probs_dtype,
                    bounded: bool = False, scale: float | None = None):
-    """Float pool, one batch slot (S == 1).  ``len_ref`` (B,) and
-    ``pages_ref`` (B * P,) are in SMEM; q_ref (1, R, hd) holds the
+    """Float pool, one batch slot (S == 1) a grid step.  ``len_ref`` (B,)
+    and ``pages_ref`` (B * P,) are in SMEM; q_ref (1, R, hd) holds the
     slot's R = n_kv * rep query rows; pk_hbm/pv_hbm are the pools as
     (n_pages, page * n_kv, hd), left in HBM; col_ref (2, T) says of each
     of a block's T = block_pages * page * n_kv columns its position
     within the block and its KV head; k_buf/v_buf (2, T, hd) are the two
-    halves of the double buffer; sems (2, 2) their K and V semaphores.
+    halves of the double buffer; sems (2, 2) their K and V semaphores;
+    ``flight`` (2,) in SMEM carries the copy schedule from one grid step
+    to the next: the half that the next block to be multiplied is copied
+    into, and whether any block has been started yet.
     ``bounded``: one more scalar-prefetched operand leads ``refs``,
     ``lo_ref`` (B,), the first position a slot sees (a window layer's):
-    blocks wholly under it are not copied, positions under it in its
-    block are masked."""
+    blocks and pages wholly under it are not copied, positions under it
+    in its page are masked."""
     lo_ref = refs[0] if bounded else None
-    q_ref, col_ref, pk_hbm, pv_hbm, o_ref, k_buf, v_buf, sems = \
+    q_ref, col_ref, pk_hbm, pv_hbm, o_ref, k_buf, v_buf, sems, flight = \
         refs[int(bounded):]
-    b = pl.program_id(0)
-    length = len_ref[b]
+    b, n_slots = pl.program_id(0), pl.num_programs(0)
     rows = k_buf.shape[1] // block_pages       # pool rows a page
     hd = q_ref.shape[-1]
     span = block_pages * page                  # positions a block
-    n_blocks = (length + span - 1) // span
-    lo = jnp.maximum(lo_ref[b], 0) if bounded else None
-    blk0 = lo // span if bounded else 0        # the first block read
 
-    def copies(blk, slot):
-        out = []
-        for i in range(block_pages):
-            # a table that is no multiple of the block ends in repeats of
-            # its last entry; their positions are past every length
-            at = jnp.minimum(blk * block_pages + i, table_pages - 1)
-            pid = pages_ref[b * table_pages + at]
+    def div(x, d: int):
+        """``x // d`` of a scalar ``x >= 0``: a shift where ``d`` is a power
+        of two (the scalar core divides in many cycles, and this runs
+        between a block's copies and its product)."""
+        return x >> (d.bit_length() - 1) if d & (d - 1) == 0 else x // d
+
+    def live_pages(s):
+        """The table entries [first, end) of slot ``s`` that hold a
+        position it sees: what :func:`pages_copied` counts."""
+        end = jnp.minimum(div(len_ref[s] + page - 1, page), table_pages)
+        first = div(jnp.maximum(lo_ref[s], 0), page) if bounded else 0
+        return first, end
+
+    def live_blocks(s):
+        """The blocks [first, end) that hold those pages; none: end <=
+        first."""
+        first, end = live_pages(s)
+        return (div(first, block_pages) if bounded else 0,
+                div(end + block_pages - 1, block_pages))
+
+    def copies(s, blk, half, go):
+        """``go`` (start or wait) the copy of each page of slot ``s``'s
+        block ``blk`` that holds a position the slot sees, into ``half``;
+        the rows behind the others keep what the half held.  A block
+        whose every page is seen (all but a long slot's last) takes one
+        branch, not one a page."""
+        first, end = live_pages(s)
+        base = blk * block_pages
+
+        def page(i):
+            pid = pages_ref[s * table_pages + base + i]
             dst = pl.ds(i * rows, rows)
-            out.append(pltpu.make_async_copy(
-                pk_hbm.at[pid], k_buf.at[slot, dst], sems.at[0, slot]))
-            out.append(pltpu.make_async_copy(
-                pv_hbm.at[pid], v_buf.at[slot, dst], sems.at[1, slot]))
-        return out
+            go(pltpu.make_async_copy(
+                pk_hbm.at[pid], k_buf.at[half, dst], sems.at[0, half]))
+            go(pltpu.make_async_copy(
+                pv_hbm.at[pid], v_buf.at[half, dst], sems.at[1, half]))
 
-    @pl.when(n_blocks > blk0)
+        whole = base + block_pages <= end
+        if bounded:
+            whole = jnp.logical_and(whole, base >= first)
+
+        @pl.when(whole)
+        def _():
+            for i in range(block_pages):
+                page(i)
+
+        @pl.when(jnp.logical_not(whole))
+        def _():
+            for i in range(block_pages):
+                seen = base + i < end
+                if bounded:
+                    seen = jnp.logical_and(seen, base + i >= first)
+                pl.when(seen)(functools.partial(page, i))
+
+    start = lambda c: c.start()
+    wait = lambda c: c.wait()
+    length = len_ref[b]
+    lo = jnp.maximum(lo_ref[b], 0) if bounded else None
+    blk0, n_blocks = live_blocks(b)
+
+    @pl.when(b == 0)
     def _():
-        for c in copies(blk0, blk0 % 2):
-            c.start()
+        flight[0] = 0
+        flight[1] = 0
+        # a page that is not copied leaves its rows of the half as they
+        # were: other slots' rows later, but whatever the memory held in
+        # a call's first blocks, and a probability of 0.0 times a NaN is
+        # a NaN in ``p @ V``.  (K needs none: its scores are replaced,
+        # not multiplied, where a column is masked.)
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    half0 = flight[0]
+
+    # the call's first block that reads anything starts its own copies;
+    # every later one was started while its predecessor was multiplied
+    @pl.when(jnp.logical_and(n_blocks > blk0, flight[1] == 0))
+    def _():
+        copies(b, blk0, half0, start)
+
+    def after(blk):
+        """The (slot, block) multiplied after this slot's ``blk``: its
+        next block, or the first block of the next slot that reads
+        anything; slot ``n_slots`` when there is none."""
+        def reads_nothing(s):
+            first, end = live_blocks(jnp.minimum(s, n_slots - 1))
+            return jnp.logical_and(s < n_slots, end <= first)
+
+        def next_slot():
+            s = jax.lax.while_loop(reads_nothing, lambda s: s + 1, b + 1)
+            return s, live_blocks(jnp.minimum(s, n_slots - 1))[0]
+        return jax.lax.cond(blk + 1 < n_blocks,
+                            lambda: (b, blk + 1), next_slot)
 
     q = q_ref[0]                                              # (R, hd)
     R = q.shape[0]
@@ -170,32 +277,30 @@ def _decode_kernel(len_ref, pages_ref, *refs, table_pages: int,
 
     def block(blk, carry):
         m, l, acc = carry
-        slot = blk % 2
+        half = (half0 + blk - blk0) & 1
+        nxt_slot, nxt_blk = after(blk)
 
-        @pl.when(blk + 1 < n_blocks)
+        @pl.when(nxt_slot < n_slots)
         def _():
-            for c in copies(blk + 1, 1 - slot):
-                c.start()
+            copies(nxt_slot, nxt_blk, 1 - half, start)
 
-        for c in copies(blk, slot):
-            c.wait()
+        copies(b, blk, half, wait)
         s = jax.lax.dot_general(
-            q, k_buf[slot], (((1,), (1,)), ((), ())),
+            q, k_buf[half], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         s = s / math.sqrt(hd) if scale is None else s * scale
         vis = jnp.logical_and(own_head, blk * span + col_pos < length)
         if bounded:
             vis = jnp.logical_and(vis, blk * span + col_pos >= lo)
         s = jnp.where(vis, s, -1e30)
-        # every block the loop runs starts at a position under the
-        # length (and the first holds ``lo``, itself under the length), so
-        # each row sees a real score in it and the -1e30 of a
-        # masked column underflows to exactly 0
+        # every block the loop runs holds a position the slot sees, so
+        # each row sees a real score in it and the -1e30 of a masked
+        # column underflows to exactly 0
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         corr = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
         l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * corr + jnp.dot(p.astype(probs_dtype), v_buf[slot],
+        acc = acc * corr + jnp.dot(p.astype(probs_dtype), v_buf[half],
                                    preferred_element_type=jnp.float32)
         return m_new, l, acc
 
@@ -204,6 +309,11 @@ def _decode_kernel(len_ref, pages_ref, *refs, table_pages: int,
     a0 = jnp.zeros((R, hd), jnp.float32)
     _, l, acc = jax.lax.fori_loop(blk0, n_blocks, block, (m0, l0, a0))
     o_ref[0] = acc / jnp.where(l == 0.0, 1.0, l)
+
+    @pl.when(n_blocks > blk0)
+    def _():
+        flight[0] = (half0 + n_blocks - blk0) & 1
+        flight[1] = 1
 
 
 def _decode_kernel_q8(pages_ref, q_ref, qs_ref, apos_ref, pk_ref, pv_ref,
@@ -342,7 +452,8 @@ def _decode_float(qg, pk, pv, pages, lengths, lo=None, *, block_pages: int,
             out_specs=slot,
             scratch_shapes=[pltpu.VMEM((2, T, hd), pk.dtype),
                             pltpu.VMEM((2, T, hd), pv.dtype),
-                            pltpu.SemaphoreType.DMA((2, 2))]),
+                            pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.SMEM((2,), jnp.int32)]),
         out_shape=jax.ShapeDtypeStruct((B, R, hd), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),

@@ -1513,6 +1513,15 @@ class ServingEngine:
                 self.cfg.block_module.COUNTERS, 0))
         if lin is not None:
             self.stats["lin_step_inplace_steps"] = 0
+        # where the decode steps' whole-context layers read their pages
+        # through the float paged kernel: pages that hold a live position,
+        # and pages that kernel's schedule copies for them, summed over
+        # the plain bursts' steps and live slots (once a step, whatever
+        # the layers): ``_count_paged_pages``
+        self._counts_paged_pages = self.paged_kernel and not self.kv_quant \
+            and any(kind in ("full", "conv_full") for kind in kinds)
+        if self._counts_paged_pages:
+            self.stats.update(paged_pages_live=0, paged_pages_copied=0)
         # what every plain burst's carry starts from (_decode_core): the
         # counters at zero, then ``sync_every`` token rows that the
         # burst's steps shift out.  One put, here: no step donates it
@@ -1910,6 +1919,21 @@ class ServingEngine:
         if self._h_rings is not None:
             self._h_rings[b] = 0
 
+    def _count_paged_pages(self, L0, A0) -> None:
+        """What a plain burst from the mirrors ``L0``, ``A0`` will have
+        the paged decode kernel read, from the host's side alone: step
+        ``j`` sees ``L0 + j + 1`` positions of every slot still live in it
+        (``_decode_core``'s chain: a slot leaves once its length reaches
+        its stop), so no step is read back for it."""
+        from ..ops.paged_attention import pages_copied
+        step = np.arange(self.sync_every)[:, None]
+        live = A0 & ((step == 0) | (L0 + step < self._h_stop))
+        seen = np.where(live, L0 + step + 1, 0)
+        self.stats["paged_pages_live"] += int(
+            (-(-seen // self.page_size)).sum())
+        self.stats["paged_pages_copied"] += int(
+            pages_copied(seen, self.page_size).sum())
+
     def _decode_burst(self, t0: float) -> None:
         """A plain decode burst: ``sync_every`` launches back to back,
         one blocking read of what they carried (``_sync_burst``), and the
@@ -1944,6 +1968,8 @@ class ServingEngine:
                 self.stats["decode_inplace_steps"] += sync
             if self.lin_step_kernel:
                 self.stats["lin_step_inplace_steps"] += sync
+        if self._counts_paged_pages:     # while the device runs the burst
+            self._count_paged_pages(L0, A0)
         mat, = self._sync_burst([carry])
         n = len(self._device_counters)
         for name, count in zip(self._device_counters, mat[:n]):

@@ -386,27 +386,31 @@ def test_paged_attention_rejects_multi_token():
 # ----------------------------- the float decode kernel, on its own
 
 # name -> (page, n_kv, rep, hd, pages a slot, dtype): tiny widths, a
-# table longer than one DMA block that is no multiple of it, and the
-# serving cells' geometry
+# table longer than one DMA block that is no multiple of it, one of three
+# blocks, the serving cells' geometry, and the looped model's call (group
+# size 1, a 40-page table: three blocks)
 _DECODE_GEOMETRY = {
     "tiny": (4, 2, 2, 8, 3, jnp.float32),
     "tiny-blocks": (4, 1, 4, 8, 19, jnp.float32),
+    "tiny-3-blocks": (4, 2, 2, 8, 40, jnp.float32),
     "cell": (16, 4, 4, 128, 20, jnp.float32),
+    "loop-cell": (16, 16, 1, 128, 40, jnp.float32),
 }
 
 
-def _decode_case(geometry, seed=0):
+def _decode_case(geometry, seed=0, lens=None):
     """A pool, a page table and ragged lengths for ``geometry``: an
     inactive slot (0), 1, a page boundary and its neighbours, a DMA
     block boundary and its neighbours where the table has one, and the
-    full view."""
+    full view; or the lengths given."""
     from distributed_training_sandbox_tpu.ops.paged_attention import (
         PAGES_PER_BLOCK)
     page, nkv, rep, hd, P, dt = _DECODE_GEOMETRY[geometry]
     V, span = P * page, PAGES_PER_BLOCK * page
-    lens = [0, 1, page - 1, page, page + 1, V - 1, V]
-    if span < V:
-        lens += [span - 1, span, span + 1]
+    if lens is None:
+        lens = [0, 1, page - 1, page, page + 1, V - 1, V]
+        if span < V:
+            lens += [span - 1, span, span + 1]
     lens = np.asarray(lens, np.int32)
     B = len(lens)
     n_pages = B * P + 1
@@ -419,6 +423,33 @@ def _decode_case(geometry, seed=0):
     apos = jnp.asarray(np.maximum(lens - 1, 0)[:, None])
     valid = jnp.asarray((lens > 0)[:, None])
     return qg, pk, pv, jnp.asarray(pages), apos, valid, lens
+
+
+def _planted(pools, pages, lens, value, whole_pages, los=0):
+    """``pools`` with ``value`` planted where no slot may look, the null
+    page included: in every position past a slot's length (its last live
+    page's tail among them), or with ``whole_pages`` in every table entry
+    that ``pages_copied`` says the kernel does not copy (past the last
+    live page and, under a lower bound ``los``, before the page that
+    holds it) and in those alone."""
+    from distributed_training_sandbox_tpu.ops.paged_attention import (
+        pages_copied)
+    n_pages, page = pools[0].shape[:2]
+    P = pages.shape[1]
+    # position v of slot b lives at (pages[b, v // page], v % page)
+    if whole_pages:
+        first = (np.zeros_like(lens) + los)[:, None] // page
+        entry = np.arange(P)[None, :]
+        copied = (entry >= first) & (
+            entry < first + pages_copied(lens, page, los)[:, None])
+        past = np.repeat(~copied, page, axis=1)
+    else:
+        past = np.arange(P * page)[None, :] >= lens[:, None]   # (B, V)
+    junk = np.zeros((n_pages, page), bool)
+    junk[np.asarray(pages), :] = past.reshape(len(lens), P, page)
+    junk[0] = True
+    return [jnp.where(jnp.asarray(junk)[:, :, None, None], value, pool)
+            for pool in pools]
 
 
 @pytest.mark.parametrize("geometry", list(_DECODE_GEOMETRY))
@@ -450,20 +481,144 @@ def test_paged_decode_kernel_ignores_what_lies_past_a_length(geometry):
         paged_attention_decode)
 
     qg, pk, pv, pages, apos, valid, lens = _decode_case(geometry, seed=1)
-    page, P = pk.shape[1], pages.shape[1]
     clean = paged_attention_decode(qg, pk, pv, pages, apos, valid=valid)
-    # position v of slot b lives at (pages[b, v // page], v % page)
-    past = np.arange(P * page)[None, :] >= lens[:, None]       # (B, V)
-    junk = np.zeros(pk.shape[:2], bool)
-    junk[np.asarray(pages), :] = past.reshape(len(lens), P, page)
-    junk[0] = True
     noise = 1e4 * jax.random.normal(jax.random.PRNGKey(9), pk.shape,
                                     pk.dtype)
-    dirty = lambda pool: jnp.where(jnp.asarray(junk)[:, :, None, None],
-                                   noise, pool)
-    out = paged_attention_decode(qg, dirty(pk), dirty(pv), pages, apos,
-                                 valid=valid)
+    out = paged_attention_decode(
+        qg, *_planted((pk, pv), pages, lens, noise, whole_pages=False),
+        pages, apos, valid=valid)
     np.testing.assert_array_equal(np.asarray(clean), np.asarray(out))
+
+
+@pytest.mark.parametrize("geometry", list(_DECODE_GEOMETRY))
+def test_paged_decode_kernel_copies_no_page_past_a_length(geometry):
+    """NaN in every page the schedule (``pages_copied``) leaves out (a
+    slot's table entries past its last live page, whole unused tables,
+    the null page) changes no bit of the output: a page copied after all
+    brings its NaN to ``0.0 * NaN`` in ``p @ V``, and so does a row of the
+    buffer that no copy of this call wrote and nothing zeroed (interpret
+    mode hands the kernel its scratch full of NaN)."""
+    from distributed_training_sandbox_tpu.ops.paged_attention import (
+        paged_attention_decode)
+
+    qg, pk, pv, pages, apos, valid, lens = _decode_case(geometry, seed=2)
+    clean = np.asarray(
+        paged_attention_decode(qg, pk, pv, pages, apos, valid=valid))
+    assert np.isfinite(clean).all()
+    out = paged_attention_decode(
+        qg, *_planted((pk, pv), pages, lens, jnp.nan, whole_pages=True),
+        pages, apos, valid=valid)
+    np.testing.assert_array_equal(clean, np.asarray(out))
+
+
+# slots of 1, 2 and 3 blocks (in blocks; 0: an empty slot), so that the
+# half in flight at a hand-over alternates, with empty slots between
+# live ones, first and last
+_HANDOVERS = {
+    "interleaved-1-3": [0, 1, 0, 0, 3, 0],
+    "interleaved-2-1": [0, 2, 0, 0, 1, 0],
+    "interleaved-3-2": [0, 3, 0, 0, 2, 0],
+    "every-parity": [1, 1, 2, 2, 3, 3, 1, 3, 2, 1],
+    "all-empty": [0, 0, 0, 0, 0, 0],
+}
+
+
+@pytest.mark.parametrize("blocks", list(_HANDOVERS))
+@pytest.mark.parametrize("geometry", ["tiny-3-blocks", "loop-cell"])
+def test_paged_decode_kernel_hands_its_copies_from_slot_to_slot(geometry,
+                                                                blocks):
+    """A slot's first block is started while its predecessor's last one
+    is multiplied, over empty slots too: against the gather reference
+    with live slots of one, two and three blocks in every order of the
+    two halves, empty slots first, last and between, and a call whose
+    every slot is empty (zeros, nothing started, nothing waited for)."""
+    from chip_smoke import paged_attention_xla
+    from distributed_training_sandbox_tpu.ops.paged_attention import (
+        PAGES_PER_BLOCK, paged_attention_decode)
+
+    page, P = _DECODE_GEOMETRY[geometry][0], _DECODE_GEOMETRY[geometry][4]
+    span = PAGES_PER_BLOCK * page
+    # n blocks: a length that ends 3 positions into the n-th, or the table
+    lens = [min((n - 1) * span + 3 + 5 * i, P * page) if n else 0
+            for i, n in enumerate(_HANDOVERS[blocks])]
+    qg, pk, pv, pages, apos, valid, lens = _decode_case(
+        geometry, seed=3, lens=lens)
+    out = np.asarray(paged_attention_decode(
+        qg, *_planted((pk, pv), pages, lens, jnp.nan, whole_pages=True),
+        pages, apos, valid=valid))
+    ref = np.asarray(paged_attention_xla(qg, pk, pv, pages, apos, qg.dtype))
+    live = lens > 0
+    if live.any():
+        _assert_f32_dot_close(ref[live], out[live])
+    assert not out[~live].any()
+
+
+# the window variant's lower bounds, in positions of "tiny-3-blocks"
+# (blocks of 64: 0-63, 64-127, 128-159), one a live slot
+_LOWER_BOUNDS = {
+    "inside-the-first-block": [5, 17, 40],
+    "at-a-block-edge": [64, 128, 64],
+    "past-the-first-block": [70, 130, 100],
+    "mixed": [0, 129, 63],
+}
+
+
+@pytest.mark.parametrize("bounds", list(_LOWER_BOUNDS))
+def test_paged_decode_kernel_bounded_starts_at_the_lower_bounds_block(bounds):
+    """``lo``: the first block copied is the one that holds it, for the
+    slot a predecessor prefetches for as for the call's first; pages
+    wholly under it are not copied (NaN there changes nothing), positions
+    under it in its page are masked: against the engine's gather core
+    with the same bound, empty slots between."""
+    from chip_smoke import paged_attention_xla
+    from distributed_training_sandbox_tpu.ops.paged_attention import (
+        paged_attention_decode)
+
+    lens = np.asarray([0, 150, 0, 160, 131, 0], np.int32)
+    los = np.zeros_like(lens)
+    los[lens > 0] = _LOWER_BOUNDS[bounds]
+    qg, pk, pv, pages, apos, valid, lens = _decode_case(
+        "tiny-3-blocks", seed=4, lens=lens)
+    lo = jnp.asarray(los[:, None])
+    ref = np.asarray(paged_attention_xla(qg, pk, pv, pages, apos, qg.dtype,
+                                         lo=lo))
+    out = np.asarray(paged_attention_decode(
+        qg, *_planted((pk, pv), pages, lens, jnp.nan, whole_pages=True,
+                      los=los),
+        pages, apos, valid=valid, lo=lo))
+    live = lens > 0
+    _assert_f32_dot_close(ref[live], out[live])
+    assert not out[~live].any()
+
+
+@pytest.mark.parametrize("geometry", ["tiny-blocks", "cell", "loop-cell"])
+def test_pages_copied_is_the_kernels_schedule_counted_block_by_block(
+        geometry):
+    """``pages_copied`` against a brute-force walk of the kernel's loops
+    (block by block, page by page, the guard it puts round a copy) at
+    every length the table holds, and with a lower bound at every page
+    edge and one position either side of it."""
+    from distributed_training_sandbox_tpu.ops.paged_attention import (
+        PAGES_PER_BLOCK, pages_copied)
+
+    page, _, _, _, P, _ = _DECODE_GEOMETRY[geometry]
+    bp = min(PAGES_PER_BLOCK, P)
+
+    def walked(length, lo=0):
+        first, end = lo // page, min(-(-length // page), P)
+        return sum(first <= blk * bp + i < end
+                   for blk in range(first // bp, -(-end // bp))
+                   for i in range(bp))
+
+    lengths = np.arange(P * page + 1)
+    assert pages_copied(lengths, page).tolist() == [
+        walked(n) for n in lengths]
+    assert pages_copied(0, page) == 0 and pages_copied(1, page) == 1
+    los = sorted({max(e + d, 0) for e in range(0, P * page, page)
+                  for d in (-1, 0, 1)})
+    for n in (P * page, P * page - page - 1, 2 * page + 1):
+        assert pages_copied(n, page, np.asarray(los)).tolist() == [
+            walked(n, lo) for lo in los]
 
 
 # ----------------------------- the flash prefill kernel, on its own

@@ -308,6 +308,40 @@ def test_decode_inplace_steps_counts_the_kernels_steps(paged_kernel):
     assert eng.retraces_after_warmup() == 0
 
 
+@pytest.mark.parametrize("sync_every", [1, 4])
+def test_paged_pages_counters_are_the_requests_lengths_page_by_page(
+        sync_every):
+    """``stats["paged_pages_live"]`` is, over every decode step of every
+    request, the pages that hold a position the step sees (a request of
+    ``n`` prompt tokens decodes ``new - 1`` steps at ``n + 1 .. n + new -
+    1`` positions), counted from the host's mirrors whatever the burst's
+    length; ``["paged_pages_copied"]`` is ``pages_copied`` of the same
+    lengths, which copies live pages alone.  The engine makes both where
+    its decode program is the float paged kernel and nowhere else."""
+    from distributed_training_sandbox_tpu.ops.paged_attention import (
+        pages_copied)
+    cfg = T.TINY_LM
+    params = _chaotic_params(cfg)
+    rng = np.random.default_rng(4)
+    sizes = ((5, 7), (17, 12), (9, 2), (30, 9), (8, 1))
+    kw = dict(max_batch=2, page_size=8, max_seq_len=48, prefill_chunk=16,
+              sync_every=sync_every)
+    eng = ServingEngine(params, cfg, paged_kernel=True, **kw)
+    before = set(ServingEngine(params, cfg, **kw).stats)     # the gather path
+    assert set(eng.stats) - before == {"paged_pages_live",
+                                       "paged_pages_copied"}
+    assert "paged_pages_live" not in ServingEngine(
+        params, cfg, paged_kernel=True, kv_quant=True, **kw).stats
+    for n, new in sizes:
+        eng.submit(rng.integers(1, cfg.vocab_size, size=n).astype(np.int32),
+                   max_new_tokens=new)
+    eng.run()
+    seen = np.concatenate([np.arange(n + 1, n + new) for n, new in sizes])
+    assert eng.stats["paged_pages_live"] == int((-(-seen // 8)).sum()) > 0
+    assert eng.stats["paged_pages_copied"] \
+        == int(pages_copied(seen, 8).sum()) == eng.stats["paged_pages_live"]
+
+
 def test_tp_sharded_engine_parity():
     """Heads sharded over tp=2: same tokens, bitwise."""
     cfg = T.TINY_LM
