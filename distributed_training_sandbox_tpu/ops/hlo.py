@@ -82,8 +82,11 @@ _SHAPE_RE = re.compile(r"([a-z]+\d*(?:e\d+m\d+(?:fn)?)?)\[([\d,]*)\]")
 # opcode is a sync collective or its async "-start" half ("-done" never
 # matches: the char after the stem is "-", not "(" — same trick as
 # _PATTERNS).
+# A tuple shape compiled for a TPU holds parentheses of its own in its
+# layouts ("bf16[48,2048]{1,0:T(8,128)(2,1)S(1)}"), so it ends at the ")"
+# the opcode follows, not at the first one.
 _INSTR_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*(?P<shape>\([^)]*\)|\S+)\s+"
+    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*(?P<shape>\(.*?\)|\S+)\s+"
     r"(?P<op>all-reduce|all-gather|reduce-scatter|collective-permute|"
     r"all-to-all)(?P<start>-start)?\(")
 

@@ -6,20 +6,58 @@ registry (every expected collective site with approximate payload), but
 nothing measured those sites on the real timeline.  This module closes
 the loop:
 
-  1. ``utils.trace_analysis.collective_event_stats`` extracts one
-     record per compiled-HLO collective *instruction* from the
-     chrome-trace (trace event names ARE instruction names);
-  2. the records are joined against ``ops.hlo.collective_instances`` of
-     the same program's compiled text — attaching payload bytes, dtype,
-     replica groups and the mesh axis each instruction spans;
+  1. ``utils.trace_analysis.collective_event_stats`` reads the
+     profiler's ``.xplane.pb`` and gives one record per collective
+     *event name*: the instruction the profiler records the collective
+     under, in flight from an async one's start to its done;
+  2. :func:`collective_sites` reads the same names out of the compiled
+     text — which instruction EXECUTES each collective, with the payload
+     bytes, dtype, replica groups and mesh axis of the collective it
+     stands for — and the two are joined by name;
   3. achieved algorithm- and bus-bandwidth per instruction follow from
      nccl-tests accounting (``ops.busbench.bus_factor``), aggregated by
      (op kind, pow-2 payload bucket, mesh axis);
   4. the ledger is joined against the strategy's serialized
-     ``CollectiveContract`` verdict: every expected site must be
-     measured (zero ``missing_from_trace``), nothing measured may be
-     outside the program (zero ``unmatched_measured``), and the distinct
-     compiled site count must not exceed the contract's expected range.
+     ``CollectiveContract`` verdict: every site must be measured (zero
+     ``missing_from_trace``), nothing measured may be outside the
+     program (zero ``unmatched_measured``), and the compiled site count
+     of the collectives the program wrote must not exceed the contract's
+     expected range.
+
+What executes a collective on a TPU, and the rule of the join each form
+needed (the four-chip FSDP step, PR 52: before them 14 of its 55
+compiled collective instructions joined, the 14 synchronous ones XLA
+left standing under their own names):
+
+  * *a fusion around it* (41 of the 55): an instruction inside a fused
+    computation never has an event; the FUSION that ``calls=`` its
+    computation has.  The site is named after the caller.
+  * *an all-reduce-scatter fusion* (4 of those): XLA:TPU runs a
+    reduce-scatter as ``fusion(...), calls=%all-reduce-scatter.N``, an
+    all-reduce and the slice of it in one kernel.  The site is a
+    ``reduce_scatter`` whose message is the all-reduce's whole tensor.
+  * *an async collective fusion* (37 of those, 12 collectives): one
+    all-gather is split over ``%async-collective-start.N``, one or two
+    compute fusions that carry it forward
+    (``calls=%async_collective_fusion.M``) and
+    ``%async-collective-done.N``, each holding a copy of the
+    instruction.  ONE site, named after the start; the copies in the
+    continuation and done fusions are the same message, not more bytes.
+  * *a tuple-shaped instruction* (5 events the old join called "outside
+    the program"): ``collective-permute-start`` and a combined
+    ``all-reduce`` have tuple shapes whose TPU layouts hold parentheses;
+    ``ops.hlo._INSTR_RE`` now reads them.  An async ``-start`` of an
+    all-gather or a permute returns ``(operand, result, ...)``: its
+    message is the result alone.
+  * *a ``while`` body*: a site in a scanned layer executes once a trip;
+    how often comes from the trace (``occurrences``), never from the
+    text.
+  * *the compiler's own collectives*: an instruction with no ``op_name``
+    was written by XLA, not by the strategy (a combiner's product, a
+    small reduce-scatter run as an all-reduce, the halo permutes that
+    re-align a padded reduce-scatter's shards).  It is measured and
+    listed like any other and is NOT counted against the contract,
+    whose lowered sites it does not map onto one-to-one.
 
 ``TelemetryRun.finalize`` writes the result as ``collectives.json`` in
 the run dir and lands the measured verdict in ``manifest.json`` beside
@@ -37,6 +75,7 @@ from __future__ import annotations
 import json
 import math
 import os
+import re
 from dataclasses import asdict, dataclass, field
 
 LEDGER_FILENAME = "collectives.json"
@@ -94,6 +133,9 @@ class LedgerEntry:
     axis: str = "?"
     algbw_gbps: float = 0.0
     busbw_gbps: float = 0.0
+    # written by XLA, not by the strategy (no op_name): measured like any
+    # other, not counted against the contract
+    compiler_made: bool = False
 
     def to_dict(self) -> dict:
         return asdict(self)
@@ -115,17 +157,17 @@ class CollectiveLedger:
 
     # ---- derived --------------------------------------------------------
     def sites_by_kind(self, measured_only: bool = True) -> dict[str, int]:
-        """Distinct instruction count per kind.  With
-        ``measured_only=False`` the unmeasured program instructions are
-        included — that total is what the contract range is checked
-        against."""
+        """Distinct site count per kind, of the collectives the program
+        wrote (the compiler's own are left out).  With
+        ``measured_only=False`` the unmeasured sites are included — that
+        total is what the contract range is checked against."""
         out: dict[str, int] = {}
-        for e in self.entries:
-            out[e.kind] = out.get(e.kind, 0) + 1
+        recs = [e.to_dict() for e in self.entries]
         if not measured_only:
-            for rec in self.unmeasured_instances:
-                k = rec["kind"] if isinstance(rec, dict) else rec.kind
-                out[k] = out.get(k, 0) + 1
+            recs += list(self.unmeasured_instances)
+        for rec in recs:
+            if not rec.get("compiler_made"):
+                out[rec["kind"]] = out.get(rec["kind"], 0) + 1
         return out
 
     def aggregates(self) -> dict[str, dict]:
@@ -194,31 +236,96 @@ class CollectiveLedger:
         return path
 
 
+# ------------------------------------------------------------------ sites
+
+@dataclass(frozen=True)
+class CollectiveSite:
+    """One collective of a compiled program under the name of the
+    instruction that executes it (module docstring: the join's rules)."""
+    name: str
+    kind: str
+    payload_bytes: int      # nccl-tests message: the full logical tensor
+    dtype: str = ""
+    group_size: int = 0     # 0: the text gives no replica groups
+    path: str = ""          # the collective's own op_name
+    compiler_made: bool = False
+
+
+_NAME_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
+
+
+def collective_sites(hlo_text: str) -> list[CollectiveSite]:
+    """Every collective of compiled HLO text, once, under the name its
+    trace events carry.  Message sizing follows nccl-tests so the GB/s are
+    column-comparable with the reference's NCCL numbers: the full logical
+    tensor — an instruction's *output* bytes for all_reduce / all_gather /
+    all_to_all / collective_permute, and output × group_size for a
+    reduce_scatter (whose output is the already-scattered shard)."""
+    from ..ops.hlo import _DTYPE_BYTES, _OP_NAME_RE, collective_instances
+    from ..utils.trace_analysis import (ASYNC_FUSION_RE, CALLS_RE,
+                                        hlo_computations)
+
+    where: dict[str, str] = {}       # instruction -> its computation
+    caller: dict[str, str] = {}      # fused computation -> the fusion
+    for comp, lines in hlo_computations(hlo_text).items():
+        for line in lines:
+            m = _NAME_RE.match(line)
+            if not m:
+                continue
+            where[m.group(1)] = comp
+            for callee in CALLS_RE.findall(line):
+                caller[callee] = m.group(1)
+
+    sites = []
+    for inst in collective_instances(hlo_text):
+        if not inst.name:
+            continue
+        name, kind, nbytes = inst.name, inst.kind, inst.bytes
+        group = len(inst.replica_groups[0]) if inst.replica_groups else 0
+        if kind == "reduce_scatter":
+            nbytes *= max(group, 1)
+        elif inst.is_async_start and len(inst.shapes) > 1 \
+                and kind in ("all_gather", "collective_permute"):
+            # (operand, result, context...): the message is the result
+            nbytes = math.prod(inst.shapes[1]) \
+                * _DTYPE_BYTES.get(inst.dtypes[1], 4)
+        comp = where.get(inst.name, "")
+        executes = caller.get(comp)
+        if executes is not None:        # a fusion around it has the event
+            fused = ASYNC_FUSION_RE.match(executes)
+            if comp.startswith("async_collective_fusion") \
+                    or (fused and fused.group(1) == "done"):
+                continue     # a copy of a collective its start already is
+            name = executes
+            if comp.startswith("all-reduce-scatter"):
+                kind = "reduce_scatter"   # the all-reduce's whole tensor
+        op_name = _OP_NAME_RE.search(inst.line)
+        sites.append(CollectiveSite(
+            name=name, kind=kind, payload_bytes=int(nbytes),
+            dtype=inst.dtypes[0] if inst.dtypes else "", group_size=group,
+            path=op_name.group(1) if op_name else "",
+            compiler_made=op_name is None))
+    return sites
+
+
 # ------------------------------------------------------------------ build
 
 def build_ledger(event_stats: dict, hlo_text: str,
                  axis_sizes: dict | None = None) -> CollectiveLedger:
-    """Join per-instruction trace stats (``collective_event_stats``)
-    against the compiled program's collective instructions.
-
-    Payload accounting follows nccl-tests message sizing so the GB/s are
-    column-comparable with the reference's NCCL numbers: the message is
-    the full logical tensor — an instruction's *output* bytes for
-    all_reduce / all_gather / all_to_all / collective_permute, and
-    output × group_size for reduce_scatter (whose output is the
-    already-scattered shard)."""
+    """Join per-event-name trace stats (``collective_event_stats``)
+    against the compiled program's collective sites
+    (:func:`collective_sites`, whose payloads are nccl-tests messages)."""
     from ..ops.busbench import bus_factor
-    from ..ops.hlo import collective_instances
 
     axis_sizes = {k: int(v) for k, v in (axis_sizes or {}).items()}
     ws = int(math.prod(axis_sizes.values())) if axis_sizes else 1
-    instances = {i.name: i for i in collective_instances(hlo_text) if i.name}
+    sites = {s.name: s for s in collective_sites(hlo_text)}
 
     led = CollectiveLedger(axis_sizes=axis_sizes)
     matched = set()
     for name, stats in sorted(event_stats.items()):
-        inst = instances.get(name)
-        if inst is None:
+        site = sites.get(name)
+        if site is None:
             if name.split(".")[0].endswith(_DONE_SUFFIXES):
                 led.async_done_us += float(stats["total_us"])
             else:
@@ -228,23 +335,21 @@ def build_ledger(event_stats: dict, hlo_text: str,
         count = int(stats["count"])
         total_us = float(stats["total_us"])
         mean_us = total_us / count if count else 0.0
-        group = len(inst.replica_groups[0]) if inst.replica_groups \
-            else max(ws, 1)
-        payload = inst.bytes * (group if inst.kind == "reduce_scatter"
-                                else 1)
-        algbw = payload / mean_us / 1e3 if mean_us else 0.0
+        group = site.group_size or max(ws, 1)
+        algbw = site.payload_bytes / mean_us / 1e3 if mean_us else 0.0
         led.entries.append(LedgerEntry(
-            name=name, kind=inst.kind, occurrences=count,
+            name=name, kind=site.kind, occurrences=count,
             total_us=round(total_us, 3), mean_us=round(mean_us, 4),
-            payload_bytes=int(payload),
-            dtype=inst.dtypes[0] if inst.dtypes else "",
+            payload_bytes=site.payload_bytes, dtype=site.dtype,
             group_size=group,
             axis=_axis_for_group(group, axis_sizes),
             algbw_gbps=round(algbw, 4),
-            busbw_gbps=round(algbw * bus_factor(inst.kind, group), 4)))
+            busbw_gbps=round(algbw * bus_factor(site.kind, group), 4),
+            compiler_made=site.compiler_made))
     led.unmeasured_instances = [
-        {"name": n, "kind": i.kind, "payload_bytes": i.bytes}
-        for n, i in sorted(instances.items()) if n not in matched]
+        {"name": n, "kind": s.kind, "payload_bytes": s.payload_bytes,
+         "compiler_made": s.compiler_made}
+        for n, s in sorted(sites.items()) if n not in matched]
     return led
 
 
@@ -254,8 +359,8 @@ def ledger_from_trace(trace_dir: str, hlo_text: str,
     """Convenience: locate the (owned) trace file under ``trace_dir``
     and build the ledger.  None when no trace exists."""
     from ..utils.trace_analysis import (collective_event_stats,
-                                        latest_trace_file)
-    tf = latest_trace_file(trace_dir, session=session)
+                                        latest_xplane_file)
+    tf = latest_xplane_file(trace_dir, session=session)
     if tf is None:
         return None
     return build_ledger(collective_event_stats(tf), hlo_text, axis_sizes)
@@ -272,12 +377,14 @@ def join_contract(ledger: CollectiveLedger, expected: dict,
       * every program collective was measured (no ``missing_from_trace``),
       * no collective-named trace event fell outside the program
         (no ``unmatched_measured``), and
-      * the compiled site count per kind is no higher than the
-        expected range allows, and nonzero where the range is.  The
-        range itself counts lowered (StableHLO) sites; XLA's collective
-        combiners merge same-kind sites afterwards, so a compiled
-        program may hold as few as one site for many lowered ones — and
-        none at all on a mesh of one device.
+      * the compiled site count per kind, of the collectives the program
+        wrote, is no higher than the expected range allows, and nonzero
+        where the range is.  The range itself counts lowered (StableHLO)
+        sites; XLA's collective combiners merge same-kind sites
+        afterwards and XLA:TPU rewrites some into collectives of its own
+        (``compiler_made``, counted apart), so a compiled program may
+        hold as few as one site for many lowered ones — and none at all
+        on a mesh of one device.
 
     The verdict is stored back on the ledger (``contract_join``) and
     returned."""
@@ -311,6 +418,9 @@ def join_contract(ledger: CollectiveLedger, expected: dict,
         "expected": exp_out,
         "compiled_sites": compiled_sites,
         "measured_sites": measured_sites,
+        "compiler_made_sites": sum(
+            1 for r in [e.to_dict() for e in ledger.entries]
+            + list(ledger.unmeasured_instances) if r.get("compiler_made")),
         "missing_from_trace": missing,
         "unmatched_measured": unmatched,
         "violations": violations,

@@ -50,22 +50,22 @@ def _load_json(path: str) -> dict | None:
 
 
 def find_trace_file(run_dir: str) -> str | None:
-    """The device trace this run owns: the session recorded in its
-    manifest when present, else newest under the summary's trace dir."""
-    from distributed_training_sandbox_tpu.utils.trace_analysis import (
-        latest_trace_file)
+    """The device trace this run owns, as the chrome-trace the profiler
+    writes beside its ``.xplane.pb`` (jax 0.9 does, on a TPU and on the
+    CPU simulator alike): the session recorded in its manifest when
+    present, else newest under the summary's trace dir."""
     manifest = _load_json(os.path.join(run_dir, "manifest.json")) or {}
     summary = _load_json(os.path.join(run_dir, "summary.json")) or {}
     sessions = manifest.get("profile_sessions") or \
         summary.get("profile_sessions") or []
-    for sess in reversed(sessions):
-        files = glob.glob(os.path.join(sess, "**", "*.trace.json.gz"),
+    trace_dir = summary.get("trace_dir")
+    for root in [*reversed(sessions), trace_dir]:
+        if not root or not os.path.isdir(root):
+            continue
+        files = glob.glob(os.path.join(root, "**", "*.trace.json.gz"),
                           recursive=True)
         if files:
             return max(files, key=os.path.getmtime)
-    trace_dir = summary.get("trace_dir")
-    if trace_dir and os.path.isdir(trace_dir):
-        return latest_trace_file(trace_dir)
     return None
 
 

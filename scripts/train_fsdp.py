@@ -21,6 +21,21 @@ Usage:
   python scripts/train_fsdp.py --num-steps 20 --sequence-length 8192 \
       [--model smollm3-3b|smollm3-350m|tiny] [--variant explicit|auto] \
       [--no-reshard-after-forward] [--cpu-devices 8] [--batch-size N]
+
+The collective ledger on a TPU: profiling is on by default (steps 5.. are
+traced), so any run of eight steps or more leaves ``collectives.json`` in
+its run dir (``./runs/<run_id>/``: every collective site of the compiled
+step with its payload, in-flight time and bus GB/s, joined against the
+FSDP contract) and the comm/compute split in ``summary.json``, both read
+from the profiler's ``.xplane.pb``; ``python scripts/report.py runs``
+renders the NCCL-vs-ICI table from them.  On the four chips of a v5e 2x2:
+
+  HF_HUB_OFFLINE=1 python scripts/train_fsdp.py --model smollm3-3b \
+      --attention flash --sequence-length 4096 --num-steps 8
+
+(``HF_HUB_OFFLINE=1`` takes the synthetic token stream at once on a
+machine with no network; at 8,192 tokens a chip the memory planner
+refuses the preset, whose loss head is not streamed.)
 """
 
 from __future__ import annotations

@@ -1253,7 +1253,7 @@ def test_new_contracts_get_measured_ledger_verdict(strategy, tmp_path):
     from distributed_training_sandbox_tpu.telemetry.ledger import (
         build_ledger, join_contract)
     from distributed_training_sandbox_tpu.utils.trace_analysis import (
-        collective_event_stats, latest_trace_file)
+        collective_event_stats, latest_xplane_file)
 
     b = build_strategy(strategy)
     lowered = b.step.lower(*b.args)
@@ -1269,7 +1269,7 @@ def test_new_contracts_get_measured_ledger_verdict(strategy, tmp_path):
             args = b.advance(args, out)
         jax.block_until_ready(out)
 
-    tf = latest_trace_file(str(tmp_path))
+    tf = latest_xplane_file(str(tmp_path))
     assert tf is not None, "profiler wrote no trace"
     led = build_ledger(collective_event_stats(tf), hlo,
                        dict(b.mesh.shape))

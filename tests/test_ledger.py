@@ -3,8 +3,12 @@ contract join, trace-file ownership, host spans + the merged timeline
 export, and the bandwidth regression gate.
 
 The deterministic half runs against checked-in fixtures
-(``tests/fixtures/ledger/``: a hand-built chrome-trace gz + the matching
-compiled-HLO text, numbers chosen so every bandwidth is exact in float).
+(``tests/fixtures/ledger/``: hand-built traces in the JSON form of
+``trace_analysis.load_trace`` + the matching compiled-HLO text, numbers
+chosen so every bandwidth is exact in float: ``trace.json`` /
+``step.hlo.txt`` as the CPU simulator names things, ``trace_v5e.json`` /
+``step_v5e.hlo.txt`` as XLA:TPU compiles and traces the four-chip FSDP
+step, one instance of every form the join has a rule for).
 The live half lowers the real strategy fixtures on the 8-way CPU mesh,
 profiles a few steps, and demands the ledger account for every
 contract-expected collective site — zero unmatched, zero unmeasured.
@@ -23,11 +27,11 @@ from distributed_training_sandbox_tpu.ops.busbench import bus_factor
 from distributed_training_sandbox_tpu.ops.hlo import collective_instances
 from distributed_training_sandbox_tpu.telemetry.ledger import (
     CollectiveLedger, LedgerEntry, build_ledger, check_bandwidth_regressions,
-    join_contract, load_ledger_dict, payload_bucket)
+    collective_sites, join_contract, load_ledger_dict, payload_bucket)
 from distributed_training_sandbox_tpu.telemetry.spans import (
     SpanStream, maybe_span, read_spans)
 from distributed_training_sandbox_tpu.utils.trace_analysis import (
-    collective_event_stats, latest_trace_file, normalize_event_name,
+    collective_event_stats, latest_xplane_file, normalize_event_name,
     profile_session_dirs)
 
 pytestmark = pytest.mark.ledger
@@ -35,7 +39,9 @@ pytestmark = pytest.mark.ledger
 FIX = Path(__file__).parent / "fixtures" / "ledger"
 SCRIPTS = Path(__file__).resolve().parent.parent / "scripts"
 HLO = (FIX / "step.hlo.txt").read_text()
-TRACE = str(FIX / "trace.json.gz")
+TRACE = str(FIX / "trace.json")
+HLO_V5E = (FIX / "step_v5e.hlo.txt").read_text()
+TRACE_V5E = str(FIX / "trace_v5e.json")
 
 
 def fixture_stats():
@@ -70,13 +76,14 @@ def test_bus_factor_nccl_accounting():
 
 # ----------------------------------------------- fixture trace ⋈ fixture HLO
 
-def test_fixture_event_stats_merge_name_forms():
-    """% and scope/ prefixed events pool into one instruction record."""
+def test_fixture_event_stats_pool_an_instructions_events():
+    """Every plane's events of one instruction pool into one record (the
+    name forms an event may carry are ``test_normalize_event_name``'s)."""
     stats = fixture_stats()
-    # 8 bare + 4 %-prefixed + 4 scoped = 16 events of all-reduce.1
+    # 8 planes once + 4 of them twice more = 16 events of all-reduce.1
     assert stats["all-reduce.1"] == {"count": 16, "total_us": 160.0}
     assert stats["all-gather.2"]["count"] == 8
-    # the async wait half is present as its own record...
+    # a wait half whose start the window missed is its own record...
     assert stats["all-reduce-done.9"]["count"] == 2
     # ...and non-collective events (fusion/copy) never appear
     assert not any(n.startswith(("fusion", "copy")) for n in stats)
@@ -185,6 +192,89 @@ def test_join_contract_range_violation():
     # "any" never constrains
     assert join_contract(led, dict(EXPECTED, all_reduce="any"),
                          "fixture")["ok"]
+
+
+# ---------------------------- the four-chip FSDP step as XLA:TPU runs it
+
+EXPECTED_V5E = {"all_reduce": 1, "all_gather": 2, "reduce_scatter": 2,
+                "collective_permute": 0}
+
+
+@pytest.mark.parametrize("name, kind, payload, compiler_made", [
+    # an async collective fusion: start, continuation and done hold a copy
+    # of ONE all-gather; the site is the start's
+    ("async-collective-start", "all_gather", 2048 * 512 * 2, False),
+    # synchronous instructions under their own names; a reduce-scatter's
+    # message is its output times the group
+    ("all-gather.247", "all_gather", 2048 * 2, False),
+    ("reduce_scatter.196", "reduce_scatter", 2048 * 11008 * 2, False),
+    # an all-reduce-scatter fusion: the fusion's name, the all-reduce's
+    # (padded) tensor, a reduce_scatter
+    ("fusion.371", "reduce_scatter", 2112 * 2048 * 2, True),
+    # tuple shapes whose layouts hold parentheses; a permute-start's
+    # message is its result alone, a combined all-reduce's every array
+    ("collective-permute-start.1", "collective_permute", 48 * 2048 * 2,
+     True),
+    ("all-reduce.19", "all_reduce", 2 * 2048 * 2, True),
+    ("psum.7", "all_reduce", 4, False),
+])
+def test_collective_sites_name_what_executes(name, kind, payload,
+                                             compiler_made):
+    sites = {s.name: s for s in collective_sites(HLO_V5E)}
+    assert len(sites) == 7          # of 11 collective instructions
+    s = sites[name]
+    assert (s.kind, s.payload_bytes, s.compiler_made) \
+        == (kind, payload, compiler_made)
+    assert bool(s.path) != compiler_made
+
+
+def test_the_join_holds_on_the_tpu_forms():
+    """Every site measured, nothing measured outside the program, and the
+    compiler's own collectives (a fused reduce-scatter with no op_name,
+    the halo permute, the combined all-reduce) listed and measured but
+    not held against the contract's count."""
+    led = build_ledger(collective_event_stats(TRACE_V5E), HLO_V5E, {"dp": 4})
+    v = join_contract(led, EXPECTED_V5E, "fsdp")
+    assert v["ok"], v["violations"]
+    assert v["missing_from_trace"] == [] and v["unmatched_measured"] == []
+    assert v["compiled_sites"] == {"all_gather": 2, "reduce_scatter": 1,
+                                   "all_reduce": 1}
+    assert v["compiler_made_sites"] == 3
+    by = {e.name: e for e in led.entries}
+    # in flight from the start's beginning to the done's end: 1.06 ms
+    ag = by["async-collective-start"]
+    assert (ag.occurrences, ag.mean_us, ag.group_size) == (4, 1060.0, 4)
+    assert ag.busbw_gbps == pytest.approx(2097152 / 1060.0 / 1e3 * 3 / 4,
+                                          rel=1e-4)
+    rs = by["fusion.371"]
+    assert rs.kind == "reduce_scatter" and rs.compiler_made
+    assert rs.busbw_gbps == pytest.approx(8650752 / 100.0 / 1e3 * 3 / 4,
+                                          rel=1e-4)
+    # a while body's site executes once a trip: twice a chip here
+    assert by["reduce_scatter.196"].occurrences == 4
+    assert by["psum.7"].occurrences == 2
+
+
+def test_a_contract_that_forbids_a_written_collective_still_fails():
+    led = build_ledger(collective_event_stats(TRACE_V5E), HLO_V5E, {"dp": 4})
+    v = join_contract(led, dict(EXPECTED_V5E, all_gather=1), "fsdp")
+    assert not v["ok"]
+    assert any("all_gather: 2 compiled sites, contract allows 1..1" in s
+               for s in v["violations"])
+    # the compiler's permute is no violation of "collective_permute: 0"
+    assert not any("collective_permute" in s for s in v["violations"])
+
+
+def test_a_chain_the_trace_missed_is_named_after_its_start():
+    stats = collective_event_stats(TRACE_V5E)
+    del stats["async-collective-start"]
+    stats["fusion.999"] = {"count": 2, "total_us": 8.0}
+    led = build_ledger(stats, HLO_V5E, {"dp": 4})
+    v = join_contract(led, EXPECTED_V5E, "fsdp")
+    assert v["missing_from_trace"] == ["async-collective-start"]
+    assert v["unmatched_measured"] == ["fusion.999"]
+    assert v["compiled_sites"]["all_gather"] == 2
+    assert v["measured_sites"]["all_gather"] == 1
 
 
 # ------------------------------------------------------ regression gate
@@ -308,9 +398,8 @@ def test_checked_in_busbench_baseline_is_dict_form():
 def _fake_session(trace_dir, stamp, mtime):
     sd = trace_dir / "plugins" / "profile" / stamp
     sd.mkdir(parents=True)
-    tf = sd / f"host.{stamp}.trace.json.gz"
-    with gzip.open(tf, "wt") as f:
-        json.dump({"traceEvents": []}, f)
+    tf = sd / f"host.{stamp}.xplane.pb"
+    tf.write_bytes(b"")
     os.utime(tf, (mtime, mtime))
     return str(sd), str(tf)
 
@@ -322,10 +411,10 @@ def test_owned_session_beats_newer_trace(tmp_path):
                                      mtime=1000.0)
     _, other_tf = _fake_session(tmp_path, "2026_01_01_00_00_02",
                                 mtime=2000.0)
-    assert latest_trace_file(str(tmp_path)) == other_tf     # bare mtime
-    assert latest_trace_file(str(tmp_path), session=mine_sd) == mine_tf
+    assert latest_xplane_file(str(tmp_path)) == other_tf     # bare mtime
+    assert latest_xplane_file(str(tmp_path), session=mine_sd) == mine_tf
     # relative session names resolve against trace_dir too
-    assert latest_trace_file(
+    assert latest_xplane_file(
         str(tmp_path),
         session=os.path.join("plugins", "profile",
                              "2026_01_01_00_00_01")) == mine_tf
@@ -447,7 +536,7 @@ def test_live_ledger_accounts_for_every_contract_site(strategy, tmp_path):
             args = b.advance(args, out)
         jax.block_until_ready(out)
 
-    tf = latest_trace_file(str(tmp_path))
+    tf = latest_xplane_file(str(tmp_path))
     assert tf is not None, "profiler wrote no trace"
     led = build_ledger(collective_event_stats(tf), hlo,
                        dict(b.mesh.shape))

@@ -3,6 +3,7 @@ engine programs (metadata only: the programs do not change), and host spans
 that are profiler annotations whether or not a telemetry run is wired."""
 
 import contextlib
+import functools
 import re
 
 import jax
@@ -60,10 +61,21 @@ def _lower_train_step():
     return step.lower(shards, fsdp.init_fsdp_opt_state(shards), batch)
 
 
+@functools.cache
+def _weights(cfg):
+    """``cfg``'s weights, made once a module run (a config hashes by its
+    fields)."""
+    return jax.tree.map(lambda x: (x * 3.0).astype(x.dtype),
+                        T.init_params(jax.random.key(0), cfg))
+
+
+@functools.cache
+def _block(block: str):
+    return make(block)
+
+
 def _engine(cfg=TINY, **kw):
-    params = jax.tree.map(lambda x: (x * 3.0).astype(x.dtype),
-                          T.init_params(jax.random.key(0), cfg))
-    return ServingEngine(params, cfg, max_batch=2, page_size=8,
+    return ServingEngine(_weights(cfg), cfg, max_batch=2, page_size=8,
                          max_seq_len=32, prefill_chunk=8, sync_every=2, **kw)
 
 
@@ -295,15 +307,40 @@ def small_scan(monkeypatch):
     monkeypatch.setattr(gdn_hybrid, "SCAN_CHUNK", 4)
 
 
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """``served(block)``: ONE engine a block that has served ``_served``'s
+    four prompts under a telemetry run, with its ``serve`` spans.  The tests
+    that only read what that service left (counters, spans, the programs'
+    caches) share it; a test that serves something else builds its own."""
+    from distributed_training_sandbox_tpu.models import gdn_hybrid
+    made = {}
+
+    def get(block: str):
+        if block not in made:
+            t = TelemetryRun("serving", config={"num_steps": 0},
+                             results_dir=str(tmp_path_factory.mktemp(block)),
+                             run_name="crossings")
+            with pytest.MonkeyPatch.context() as m, t as telem:
+                m.setattr(gdn_hybrid, "SCAN_CHUNK", 4)
+                eng = _served({"dense": TINY, "hybrid": HYBRID}[block],
+                              telem=telem)
+                telem.finalize()
+            made[block] = eng, [s for s in read_spans(t.run_dir)
+                                if s["cat"] == "serve"]
+        return made[block]
+
+    return get
+
+
 @pytest.mark.parametrize("block", ["dense", "hybrid"])
-def test_crossings_are_counted_where_they_happen(block, small_scan):
+def test_crossings_are_counted_where_they_happen(block, served):
     """Launches, reads and puts are what the round structure implies: one
     launch a decode step and a prefill chunk; a burst's sync point reads
     ONE array (the carry: the device's counters and a token row a step), a
     finished prompt one; a burst ships five mirrors and a chunk four
     arrays, five where the prefill program takes the batch slot."""
-    cfg = {"dense": TINY, "hybrid": HYBRID}[block]
-    eng = _served(cfg)
+    eng, _ = served(block)
     s, counters = eng.stats, bool(eng._device_counters)
     assert counters == (block == "hybrid")
     bursts, rem = divmod(s["decode_steps"], eng.sync_every)
@@ -327,11 +364,13 @@ def test_crossings_are_counted_where_they_happen(block, small_scan):
 def _parent_burst(self, t0):
     """A decode burst as it crossed the boundary before the carry held the
     token rows: every step's row an array of its own and the device's
-    counters a chain beside them, each read by itself."""
+    counters a chain beside them, each read by itself.  The chain is the
+    engine's own carry (the counters lead it; the rows it also collects
+    are not read), so both crossings run ONE decode executable."""
     L0, A0 = self._h_lengths.copy(), self._h_active.copy()
     toks_d, len_d, stop_d, act_d, pages_d = self._stage_burst()
     bufs = self.pool.bufs
-    counted = jnp.zeros(len(self._device_counters), jnp.int32)
+    counted = self._carry_zero
     rows = []
     for _ in range(self.sync_every):
         toks_d, len_d, act_d, bufs, _occ, counted = self._decode(
@@ -360,21 +399,27 @@ def test_a_plain_burst_is_one_read_of_one_array(block, sync_every, small_scan,
     holds exactly one ``_read`` of one array, no ``pump/`` span opens (a
     plain burst hands the pump nothing), and tokens and device counters
     are what the parent's crossing, a read a step, gives."""
-    _, cfg, params = make(block)
+    _, cfg, params = _block(block)
     rng = np.random.default_rng(0)
     prompts = [rng.integers(1, cfg.vocab_size, size=n).astype(np.int32)
                for n in (5, 19, 7)]
+    # both crossings run the same two programs: one engine serves the
+    # prompts twice, and a service is read as the counters' rise over it
+    eng = ServingEngine(params, cfg, max_batch=2, page_size=8,
+                        max_seq_len=32, prefill_chunk=16,
+                        sync_every=sync_every)
 
     def serve(**patch):
-        eng = ServingEngine(params, cfg, max_batch=2, page_size=8,
-                            max_seq_len=32, prefill_chunk=16,
-                            sync_every=sync_every)
+        before = dict(eng.stats)
         for name, fn in patch.items():
             setattr(eng, name, fn.__get__(eng))
         reqs = [eng.submit(p, max_new_tokens=new)
                 for p, new in zip(prompts, (6, 3, 11))]
         eng.run()
-        return eng, [r.tokens for r in reqs]
+        for name in patch:
+            delattr(eng, name)      # the class's own method again
+        return ({k: v - before[k] for k, v in eng.stats.items()
+                 if isinstance(v, int)}, [r.tokens for r in reqs])
 
     want, want_tokens = serve(_decode_burst=_parent_burst)
 
@@ -401,22 +446,22 @@ def test_a_plain_burst_is_one_read_of_one_array(block, sync_every, small_scan,
 
     with monkeypatch.context() as m:
         m.setattr(jax.profiler, "TraceAnnotation", Recorder)
-        eng, tokens = serve(_read=counting_read)
-    bursts = eng.stats["decode_steps"] // sync_every
+        stats, tokens = serve(_read=counting_read)
+    bursts = stats["decode_steps"] // sync_every
     n = len(eng._device_counters)
     assert (n > 0) == (block != "dense_gqa")
     assert [r for r in reads if r[0] == "serve/burst_sync"] \
         == [("serve/burst_sync", [(n + sync_every * eng.max_batch,)])] * bursts
-    assert len(reads) == bursts + len(prompts) == eng.stats["d2h_reads"]
+    assert len(reads) == bursts + len(prompts) == stats["d2h_reads"]
     syncs = [kw for name, kw in opened if name == "serve/burst_sync"]
     assert len(syncs) == bursts > 0 and {kw["arrays"] for kw in syncs} == {1}
     assert not [name for name, _ in opened if name.startswith("pump/")]
-    assert eng.stats["host_sync_count"] == bursts + len(prompts)
+    assert stats["host_sync_count"] == bursts + len(prompts)
     # against the parent's crossing
     assert tokens == want_tokens
-    assert eng.stats["decode_steps"] == want.stats["decode_steps"]
+    assert stats["decode_steps"] == want["decode_steps"]
     for name in eng._device_counters:
-        assert eng.stats[name] == want.stats[name] > 0, name
+        assert stats[name] == want[name] > 0, name
 
 
 def test_a_speculative_burst_launches_draft_verify_and_accept():
@@ -433,14 +478,8 @@ def test_a_speculative_burst_launches_draft_verify_and_accept():
 
 @pytest.mark.parametrize("block", ["dense", "hybrid"])
 def test_spans_carry_what_crossed_and_every_launch_is_in_a_dispatch(
-        block, small_scan, tmp_path):
-    cfg = {"dense": TINY, "hybrid": HYBRID}[block]
-    t = TelemetryRun("serving", config={"num_steps": 0},
-                     results_dir=str(tmp_path), run_name="crossings")
-    with t as telem:
-        eng = _served(cfg, telem=telem)
-        telem.finalize()
-    spans = [s for s in read_spans(t.run_dir) if s["cat"] == "serve"]
+        block, served):
+    eng, spans = served(block)
     by = lambda name: [s for s in spans if s["name"] == name]  # noqa: E731
     stats = eng.stats
     # puts and reads: the spans' attributes add up to the counters
@@ -481,11 +520,11 @@ def test_spans_carry_what_crossed_and_every_launch_is_in_a_dispatch(
 
 
 @pytest.mark.parametrize("which", ["decode", "prefill"])
-def test_the_launch_helper_leaves_the_programs_as_they_were(which):
+def test_the_launch_helper_leaves_the_programs_as_they_were(which, served):
     """No jitted function changed: after rounds served through
     ``_launch`` each program has the one executable it warmed up with,
     and lowers to the text a fresh engine's does."""
-    eng = _served()
+    eng, _ = served("dense")
     assert eng.retraces_after_warmup() == 0
     assert {"decode": eng._decode, "prefill": eng._prefill}[
         which]._cache_size() == 1
@@ -500,8 +539,8 @@ def test_the_launch_helper_leaves_the_programs_as_they_were(which):
         == _lower_engine(which).as_text()
 
 
-def test_the_report_carries_the_crossings():
-    eng = _served(lengths=(5,))
+def test_the_report_carries_the_crossings(served):
+    eng, _ = served("dense")
     crossings = eng.slo_report()["scheduler"]["crossings"]
     assert crossings == {k: eng.stats[k] for k in (
         "launches", "h2d_puts", "h2d_bytes", "d2h_reads", "d2h_bytes")}
